@@ -1,0 +1,771 @@
+#!/usr/bin/env python3
+"""Chip smoke: the scan -> filter -> aggregate path on a real TPU.
+
+One process, the entry points a user calls (``ct.Cluster(dir)``,
+``cl.copy_from``, ``cl.execute``) and nothing beneath them.  It loads a
+TPC-H ``lineitem`` at the SF10 row count (8 shards per device), runs the
+legs listed in ``main`` and checks every answer against a plain numpy
+reference that is accumulated while the data is generated and shares no
+code with the engine.  Each leg also names the kernel slot it must have
+used and asserts it from the query's exported trace, its ``explain``
+dict and counter deltas.
+
+It fails loudly: no accelerator, a leg that raises, a wrong answer or a
+leg that ran on another path than the one named all exit non-zero and
+print no result line.  The last line of a successful run is
+
+    {"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}
+
+The CPU rehearsal (``--rehearse-on-cpu``, tiny ``--rows``) exists to
+debug the script where there is no chip; it stamps ``"platform":
+"cpu", "rehearsal": true`` and is never chosen automatically.  Every
+time printed here is "elapsed, not a metric": nothing is repeated, the
+first call of each leg compiles, and a one-chip machine shares its
+host's cores.
+"""
+
+import argparse
+import datetime
+import decimal
+import json
+import os
+import shutil
+import subprocess
+import sys
+import tempfile
+import threading
+import time
+
+import numpy as np
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+#: TPC-H lineitem cardinality at scale factor 10 (BASELINE.json config 2)
+SF10_LINEITEM_ROWS = 59_986_052
+SHARDS_PER_DEVICE = 8
+CHUNK_ROWS = 4_000_000
+
+EPOCH = datetime.date(1970, 1, 1)
+SHIP_LO = 8036            # 1992-01-02 in days since the epoch
+SHIP_DAYS = 2526          # distinct l_shipdate values (TPC-H 4.2.3)
+RETURNFLAGS = np.array(["A", "N", "R"])
+LINESTATUSES = np.array(["F", "O"])
+
+#: relative tolerance for float64 results: the TPU has no native f64,
+#: XLA emulates it, and partial sums combine in another order than
+#: numpy's pairwise summation
+F64_RTOL = 1e-9
+#: DDSketch's value error bound (planner/aggregates.py documents ~2.7%)
+#: plus room for an emulated-f64 log landing one bucket off at an edge
+SKETCH_RTOL = 0.06
+
+LINEITEM_DDL = """CREATE TABLE lineitem (
+    l_orderkey bigint NOT NULL, l_quantity decimal(12,2),
+    l_extendedprice decimal(12,2), l_discount decimal(12,2),
+    l_tax decimal(12,2), l_returnflag text, l_linestatus text,
+    l_shipdate date)"""
+
+ORDERS_DDL = """CREATE TABLE orders (
+    o_orderkey bigint NOT NULL, o_custkey bigint NOT NULL,
+    o_totalprice decimal(12,2))"""
+
+# TPC-H Q1 and Q6 as published (substitution parameters 90 / 1994, .06, 24)
+Q1 = """SELECT l_returnflag, l_linestatus,
+  sum(l_quantity) AS sum_qty,
+  sum(l_extendedprice) AS sum_base_price,
+  sum(l_extendedprice * (1 - l_discount)) AS sum_disc_price,
+  sum(l_extendedprice * (1 - l_discount) * (1 + l_tax)) AS sum_charge,
+  avg(l_quantity) AS avg_qty, avg(l_extendedprice) AS avg_price,
+  avg(l_discount) AS avg_disc, count(*) AS count_order
+FROM lineitem WHERE l_shipdate <= date '1998-12-01' - interval '90' day
+GROUP BY l_returnflag, l_linestatus
+ORDER BY l_returnflag, l_linestatus"""
+Q1_SHIP_HI = (datetime.date(1998, 12, 1) - datetime.timedelta(days=90)
+              - EPOCH).days
+
+Q6_TEMPLATE = """SELECT sum(l_extendedprice * l_discount) AS revenue
+FROM lineitem
+WHERE l_shipdate >= date '{y}-01-01'
+  AND l_shipdate < date '{y1}-01-01'
+  AND l_discount BETWEEN {dlo} AND {dhi} AND l_quantity < {qty}"""
+#: (year, discount lo cents, discount hi cents, quantity): the published
+#: Q6 first, then a literal variant of the same family for the coalesced
+#: pair
+Q6_VARIANTS = [(1994, 5, 7, 24), (1995, 2, 4, 25), (1996, 6, 8, 24)]
+
+Q_DIRECT = """SELECT l_shipdate, count(*) AS n, sum(l_quantity) AS sum_qty,
+  max(l_extendedprice) AS max_price
+FROM lineitem GROUP BY l_shipdate"""
+
+Q_HASH = """SELECT l_shipdate, l_discount, l_tax, count(*) AS n,
+  sum(l_quantity) AS sum_qty
+FROM lineitem GROUP BY l_shipdate, l_discount, l_tax"""
+
+Q_ROUTER = """SELECT l_orderkey, l_quantity, l_extendedprice, l_shipdate,
+  l_returnflag
+FROM lineitem WHERE l_orderkey = $1"""
+
+JOIN_SHIP_HI = SHIP_LO + 31
+Q_JOIN = f"""SELECT count(*), sum(l.l_quantity), sum(o.o_totalprice)
+FROM lineitem l JOIN orders o ON l.l_orderkey = o.o_orderkey
+WHERE l.l_shipdate < date '{EPOCH + datetime.timedelta(days=JOIN_SHIP_HI)}'"""
+
+# float64 lanes: the TPU holds them as float32 pairs, so the hash
+# fingerprint and the HLL hash of a float value take a path of their own
+MEASURES_DDL = "CREATE TABLE measures (m_key bigint NOT NULL, x double precision)"
+MEASURES_ROWS = 1_000_000
+Q_FLOAT_KEYS = "SELECT x, count(*), sum(x) FROM measures GROUP BY x"
+Q_FLOAT_HLL = "SELECT approx_count_distinct(x) FROM measures"
+#: HLL with 128 registers: standard error 9%; three of them
+HLL_RTOL = 0.3
+
+Q_STDDEV = """SELECT l_returnflag, stddev(l_quantity) FROM lineitem
+GROUP BY l_returnflag ORDER BY l_returnflag"""
+Q_MEDIAN = """SELECT approx_percentile(0.5) WITHIN GROUP (ORDER BY l_quantity)
+FROM lineitem"""
+Q_ROUTER_COUNT = "SELECT count(*) FROM lineitem WHERE l_orderkey = $1"
+
+
+class SmokeFailure(Exception):
+    pass
+
+
+def check(cond, message):
+    if not cond:
+        raise SmokeFailure(message)
+
+
+def q6_sql(variant):
+    y, dlo, dhi, qty = variant
+    return Q6_TEMPLATE.format(y=y, y1=y + 1, dlo=f"0.{dlo:02d}",
+                              dhi=f"0.{dhi:02d}", qty=qty)
+
+
+def days(d: datetime.date) -> int:
+    return (d - EPOCH).days
+
+
+def dec(cents: int, scale: int) -> decimal.Decimal:
+    return decimal.Decimal(int(cents)).scaleb(-scale)
+
+
+def avg_dec(total: int, n: int, scale: int) -> decimal.Decimal:
+    """SQL avg of a decimal(…, scale): exact quotient at scale + 6,
+    rounded half up — in integers, so no float is involved."""
+    q, r = divmod(total * 10 ** 6, n)
+    if 2 * r >= n:
+        q += 1
+    return dec(q, scale + 6)
+
+
+def order_totalprice_cents(orderkey):
+    """o_totalprice as a function of the key, so the join reference
+    needs no lookup table."""
+    return (orderkey * 7919 + 104_729) % 50_000_000 + 100
+
+
+# ---------------------------------------------------------------- reference
+
+
+class Reference:
+    """Plain numpy answers, accumulated chunk by chunk at ingest.  All
+    money columns are integer cents, so every sum here is exact."""
+
+    def __init__(self):
+        self.router_key = None            # the first row's l_orderkey
+        self.q1 = {}                      # (rf, ls) -> running sums
+        self.q6 = [0] * len(Q6_VARIANTS)
+        self.q6_hits = [0] * len(Q6_VARIANTS)
+        self.d_n = np.zeros(SHIP_DAYS, np.int64)
+        self.d_qty = np.zeros(SHIP_DAYS, np.int64)
+        self.d_max = np.zeros(SHIP_DAYS, np.int64)
+        self.h_n = np.zeros(SHIP_DAYS * 99, np.int64)
+        self.h_qty = np.zeros(SHIP_DAYS * 99, np.int64)
+        self.router_rows = []
+        self.join = [0, 0, 0]             # count, sum qty, sum totalprice
+        self.sd = [[0, 0, 0] for _ in range(3)]  # per flag: n, sum, sumsq
+        self.qty_hist = np.zeros(5100, np.int64)
+
+    def add(self, c):
+        okey, qty, price = c["okey"], c["qty"], c["price"]
+        disc, tax, rf, ls, ship = (c["disc"], c["tax"], c["rf"], c["ls"],
+                                   c["ship"])
+        if self.router_key is None:
+            self.router_key = int(okey[0])
+        # Q1
+        keep = ship <= Q1_SHIP_HI
+        disc_price = price * (100 - disc)
+        charge = disc_price * (100 + tax)
+        for r in range(3):
+            for s in range(2):
+                m = keep & (rf == r) & (ls == s)
+                n = int(m.sum())
+                if not n:
+                    continue
+                acc = self.q1.setdefault((r, s), [0] * 6)
+                for i, a in enumerate((qty, price, disc_price, charge, disc)):
+                    acc[i] += int(a[m].sum())
+                acc[5] += n
+        # Q6 family
+        for i, (y, dlo, dhi, q) in enumerate(Q6_VARIANTS):
+            m = ((ship >= days(datetime.date(y, 1, 1)))
+                 & (ship < days(datetime.date(y + 1, 1, 1)))
+                 & (disc >= dlo) & (disc <= dhi) & (qty < q * 100))
+            self.q6[i] += int((price[m] * disc[m]).sum())
+            self.q6_hits[i] += int(m.sum())
+        # GROUP BY l_shipdate: per-chunk float64 bincounts are exact (every
+        # partial sum is an integer below 2**53)
+        d = (ship - SHIP_LO).astype(np.int64)
+        self.d_n += np.bincount(d, minlength=SHIP_DAYS)
+        self.d_qty += np.bincount(d, weights=qty, minlength=SHIP_DAYS
+                                  ).astype(np.int64)
+        np.maximum.at(self.d_max, d, price)
+        # GROUP BY l_shipdate, l_discount, l_tax
+        g = d * 99 + disc * 9 + tax
+        self.h_n += np.bincount(g, minlength=SHIP_DAYS * 99)
+        self.h_qty += np.bincount(g, weights=qty, minlength=SHIP_DAYS * 99
+                                  ).astype(np.int64)
+        # router key
+        for i in np.nonzero(okey == self.router_key)[0]:
+            self.router_rows.append((
+                int(okey[i]), dec(qty[i], 2), dec(price[i], 2),
+                EPOCH + datetime.timedelta(days=int(ship[i])),
+                str(RETURNFLAGS[rf[i]])))
+        # join: every l_orderkey has exactly one order
+        m = ship < JOIN_SHIP_HI
+        self.join[0] += int(m.sum())
+        self.join[1] += int(qty[m].sum())
+        self.join[2] += int(order_totalprice_cents(okey[m]).sum())
+        # stddev(l_quantity) per returnflag, median of l_quantity
+        for r in range(3):
+            q = qty[rf == r]
+            self.sd[r][0] += int(q.size)
+            self.sd[r][1] += int(q.sum())
+            self.sd[r][2] += int((q * q).sum())
+        self.qty_hist += np.bincount(qty, minlength=5100)
+
+    def q1_rows(self):
+        out = []
+        for (r, s) in sorted(self.q1):
+            qty, price, dprice, charge, disc, n = self.q1[(r, s)]
+            out.append((str(RETURNFLAGS[r]), str(LINESTATUSES[s]),
+                        dec(qty, 2), dec(price, 2), dec(dprice, 4),
+                        dec(charge, 6), avg_dec(qty, n, 2),
+                        avg_dec(price, n, 2), avg_dec(disc, n, 2), n))
+        return out
+
+    def direct_rows(self):
+        return {EPOCH + datetime.timedelta(days=SHIP_LO + int(i)):
+                (int(self.d_n[i]), dec(self.d_qty[i], 2),
+                 dec(self.d_max[i], 2))
+                for i in np.nonzero(self.d_n)[0]}
+
+    def hash_rows(self):
+        out = {}
+        for g in np.nonzero(self.h_n)[0]:
+            d, rest = divmod(int(g), 99)
+            disc, tax = divmod(rest, 9)
+            out[(EPOCH + datetime.timedelta(days=SHIP_LO + d),
+                 dec(disc, 2), dec(tax, 2))] = (int(self.h_n[g]),
+                                                dec(self.h_qty[g], 2))
+        return out
+
+    def stddev_rows(self):
+        out = []
+        for r in range(3):
+            n, s, ss = self.sd[r]
+            # sample variance of cents, exactly, then to the column's unit
+            var_c = (ss * n - s * s) / (n * (n - 1))
+            out.append((str(RETURNFLAGS[r]), (var_c ** 0.5) / 100.0))
+        return out
+
+    def median_qty(self):
+        cum = np.cumsum(self.qty_hist)
+        return int(np.searchsorted(cum, (cum[-1] + 1) // 2)) / 100.0
+
+
+def make_chunk(rng, n, n_orders):
+    return {
+        "okey": rng.integers(0, n_orders, n),
+        "qty": rng.integers(100, 5100, n),
+        "price": rng.integers(90_000, 10_500_000, n),
+        "disc": rng.integers(0, 11, n),
+        "tax": rng.integers(0, 9, n),
+        "rf": rng.integers(0, 3, n),
+        "ls": rng.integers(0, 2, n),
+        "ship": (rng.integers(0, SHIP_DAYS, n) + SHIP_LO).astype(np.int32),
+    }
+
+
+def load_lineitem(cl, ref, rng, rows, n_orders):
+    """Generate, reference and ingest lineitem chunk by chunk."""
+    done = 0
+    while done < rows:
+        n = min(CHUNK_ROWS, rows - done)
+        c = make_chunk(rng, n, n_orders)
+        ref.add(c)
+        cl.copy_from("lineitem", columns={
+            "l_orderkey": c["okey"],
+            "l_quantity": c["qty"] / 100.0,
+            "l_extendedprice": c["price"] / 100.0,
+            "l_discount": c["disc"] / 100.0,
+            "l_tax": c["tax"] / 100.0,
+            "l_returnflag": RETURNFLAGS[c["rf"]].tolist(),
+            "l_linestatus": LINESTATUSES[c["ls"]].tolist(),
+            "l_shipdate": c["ship"],
+        })
+        done += n
+
+
+def load_orders(cl, n_orders):
+    for start in range(0, n_orders, CHUNK_ROWS):
+        key = np.arange(start, min(start + CHUNK_ROWS, n_orders),
+                        dtype=np.int64)
+        cl.copy_from("orders", columns={
+            "o_orderkey": key,
+            "o_custkey": (key * 31 + 7) % (n_orders // 10 + 1),
+            "o_totalprice": order_totalprice_cents(key) / 100.0,
+        })
+
+
+# ------------------------------------------------------------------- legs
+
+
+class Runner:
+    """Runs one statement through ``cl.execute`` and hands back what the
+    engine says about how it ran: the result, counter deltas, the wall
+    time to rows on the host, and the spans of its exported trace."""
+
+    def __init__(self, cl, trace_dir):
+        self.cl = cl
+        self.trace_dir = trace_dir
+        self.legs = []
+
+    def traces(self):
+        return {n for n in os.listdir(self.trace_dir) if n.endswith(".json")}
+
+    def run_many(self, statements):
+        """Run ``[(sql, params)]`` concurrently, one client thread each
+        (a single statement runs on the calling thread).  -> (results,
+        counter deltas, wall time to the last row, trace events)."""
+        before = self.traces()
+        c0 = self.cl.counters.snapshot()
+        results = [None] * len(statements)
+
+        def client(i):
+            sql, params = statements[i]
+            results[i] = self.cl.execute(sql, params=params)
+
+        t0 = time.perf_counter()
+        if len(statements) == 1:
+            client(0)
+        else:
+            threads = [threading.Thread(target=client, args=(i,))
+                       for i in range(len(statements))]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join()
+        elapsed = time.perf_counter() - t0
+        c1 = self.cl.counters.snapshot()
+        check(all(r is not None for r in results),
+              "a client thread died (its traceback is above)")
+        events = []
+        for name in sorted(self.traces() - before):
+            with open(os.path.join(self.trace_dir, name)) as fh:
+                events += json.load(fh)["traceEvents"]
+        delta = {k: c1[k] - c0.get(k, 0) for k in c1 if c1[k] != c0.get(k, 0)}
+        return results, delta, elapsed, events
+
+    def run(self, sql, params=None):
+        (r,), delta, elapsed, events = self.run_many([(sql, params)])
+        return r, delta, elapsed, events
+
+    def record(self, name, slot, elapsed, delta, **extra):
+        leg = {"leg": name, "ok": True, "kernel_slot": slot,
+               "elapsed_s_not_a_metric": round(elapsed, 3),
+               "compile_s": round(delta.get("kernel_compile_ms", 0) / 1e3, 3)}
+        leg.update(extra)
+        self.legs.append(leg)
+        print("leg " + json.dumps(leg), flush=True)
+
+
+def kernel_slots(events):
+    return sorted({e["args"]["slot"] for e in events
+                   if e.get("name") == "kernel"})
+
+
+def scan_slot(n_dev):
+    """The slot a scalar/direct aggregate scan compiles under."""
+    return "mesh_run" if n_dev > 1 else "jit_fused"
+
+
+def check_groups(leg, got, want, n_rows):
+    diff = next(((k, got.get(k), want[k]) for k in want
+                 if got.get(k) != want[k]), None)
+    check(n_rows == len(want) and got == want,
+          f"{leg}: {n_rows} groups vs {len(want)}; first diff {diff}")
+
+
+def leg_q1_cold(run, ref, n_dev):
+    r, d, el, ev = run.run(Q1)
+    slot = scan_slot(n_dev)
+    check(r.explain["strategy"] == "direct", f"Q1 strategy {r.explain}")
+    check(slot in kernel_slots(ev), f"Q1 cold: kernel slots {kernel_slots(ev)}")
+    check(d.get("device_cache_hits", 0) == 0, "Q1 cold hit the device cache")
+    h2d = r.explain["pipeline"].get("h2d_bytes", 0)
+    check(h2d > 0, f"Q1 cold: no H2D bytes {r.explain['pipeline']}")
+    if n_dev == 1:
+        check(d.get("fused_dispatches", 0) > 0
+              and r.explain["pipeline"].get("fused_dispatches", 0) > 0,
+              f"Q1 cold: no fused dispatch {d}")
+    else:
+        check(d.get("fused_dispatches", 0) == 0,
+              "Q1 cold took the single-device loop on a mesh")
+    check(r.rows == ref.q1_rows(),
+          f"Q1 cold answer\n got {r.rows}\nwant {ref.q1_rows()}")
+    run.record("1 cold Q1 (streaming)", slot, el, d, h2d_bytes=h2d,
+               dispatches=len([e for e in ev if e["name"] == "device_round"]))
+    return h2d
+
+
+def leg_q1_warm(run, ref, n_dev):
+    r, d, el, _ = run.run(Q1)
+    check(d.get("device_cache_hits", 0) > 0,
+          f"Q1 warm: no device cache hit {d}")
+    check(d.get("kernel_compile_ms", 0) == 0,
+          f"Q1 warm compiled: {d.get('kernel_compile_ms')} ms")
+    if n_dev == 1:
+        check(d.get("fused_dispatches", 0) > 0, "Q1 warm: no fused dispatch")
+    check(r.rows == ref.q1_rows(), f"Q1 warm answer {r.rows}")
+    run.record("2 warm Q1 (HBM-resident)", scan_slot(n_dev), el, d)
+
+
+def leg_q6(run, ref, n_dev):
+    r, d, el, ev = run.run(q6_sql(Q6_VARIANTS[0]))
+    slot = scan_slot(n_dev)
+    check(r.explain["strategy"] == "scalar", f"Q6 strategy {r.explain}")
+    check(slot in kernel_slots(ev), f"Q6: kernel slots {kernel_slots(ev)}")
+    check(ref.q6_hits[0] > 0, "Q6 reference matched no row")
+    check(r.rows == [(dec(ref.q6[0], 4),)],
+          f"Q6 answer {r.rows} want {dec(ref.q6[0], 4)}")
+    run.record("3 Q6 (scalar)", slot, el, d)
+
+
+def leg_q6_coalesced(run, ref):
+    """Two literal variants of Q6 from two threads inside one window:
+    one vmap-lifted dispatch serves both (single-device by design)."""
+    cl = run.cl
+    cl.execute("SET citus.megabatch_max_size = 2")
+    cl.execute("SET citus.megabatch_window_ms = 10000")
+    results, delta, elapsed, events = run.run_many(
+        [(q6_sql(Q6_VARIANTS[i]), None) for i in (1, 2)])
+    cl.execute("SET citus.megabatch_window_ms = 0")
+    cl.execute("SET citus.megabatch_max_size = 32")
+    check(delta.get("megabatch_batches", 0) == 1
+          and delta.get("megabatch_queries", 0) == 2,
+          f"Q6 pair did not coalesce: {delta}")
+    check("batched:jit_fused" in kernel_slots(events),
+          f"coalesced pair: kernel slots {kernel_slots(events)}")
+    for i, r in zip((1, 2), results):
+        check(ref.q6_hits[i] > 0, "Q6 variant reference matched no row")
+        check(r.rows == [(dec(ref.q6[i], 4),)],
+              f"coalesced Q6 variant {i}: {r.rows} want {dec(ref.q6[i], 4)}")
+    run.record("3b Q6 literal pair (coalesced)", "batched:jit_fused",
+               elapsed, delta)
+
+
+def leg_direct(run, ref, n_dev):
+    r, d, el, ev = run.run(Q_DIRECT)
+    slot = scan_slot(n_dev)
+    check(r.explain["strategy"] == "direct", f"direct leg: {r.explain}")
+    check(slot in kernel_slots(ev), f"direct leg: slots {kernel_slots(ev)}")
+    want = ref.direct_rows()
+    check_groups("direct leg", {row[0]: tuple(row[1:]) for row in r.rows},
+                 want, len(r.rows))
+    run.record("4 GROUP BY l_shipdate (direct, one-hot)", slot, el, d,
+               groups=len(want))
+
+
+def leg_hash(run, ref):
+    cl = run.cl
+    cl.execute("SET citus.hash_agg_slots = auto")
+    r, d, el, ev = run.run(Q_HASH)
+    check(r.explain["strategy"] == "hash_host", f"hash leg: {r.explain}")
+    check("jit_hash_fused" in kernel_slots(ev),
+          f"hash leg: slots {kernel_slots(ev)}")
+    check(d.get("hash_fused_dispatches", 0) > 0, f"hash leg: no dispatch {d}")
+    want = ref.hash_rows()
+    check_groups("hash leg", {tuple(row[:3]): tuple(row[3:]) for row in r.rows},
+                 want, len(r.rows))
+    pl = r.explain["pipeline"]
+    run.record("5 GROUP BY l_shipdate, l_discount, l_tax (hash)",
+               "jit_hash_fused", el, d, groups=len(want),
+               hash_slots=pl.get("hash_slots"),
+               hash_occupancy_pct=pl.get("hash_occupancy_pct"),
+               hash_spill_rows=d.get("hash_spill_rows", 0))
+
+
+def leg_float_lanes(run, rng, shards, n_dev, rows):
+    """GROUP BY a float64 key (hash mode: floats never take the direct
+    path) and an HLL over the same column, on a small side table.  The
+    values are halves, which a float32 pair holds exactly, so the keys
+    must come back equal; NaN and -0.0 ride along as SQL groups them."""
+    cl = run.cl
+    cl.execute(MEASURES_DDL)
+    cl.execute(f"SELECT create_distributed_table('measures', 'm_key', {shards})")
+    x = rng.integers(0, 1000, rows) / 2.0
+    x[::1000] = np.nan
+    x[1::1000] = -0.0
+    cl.copy_from("measures", columns={
+        "m_key": np.arange(rows, dtype=np.int64), "x": x})
+    want = {}
+    vals, counts = np.unique(x[~np.isnan(x)] + 0.0, return_counts=True)
+    for v, n in zip(vals, counts):
+        want[float(v)] = (int(n), float(v) * int(n))
+    want["nan"] = (int(np.isnan(x).sum()), None)
+
+    r, d, el, ev = run.run(Q_FLOAT_KEYS)
+    check(r.explain["strategy"] == "hash_host", f"float keys: {r.explain}")
+    check("jit_hash_fused" in kernel_slots(ev),
+          f"float keys: slots {kernel_slots(ev)}")
+    got = {("nan" if row[0] != row[0] else row[0]): row[1:] for row in r.rows}
+    check(sorted(got, key=str) == sorted(want, key=str),
+          f"float keys: {len(got)} groups vs {len(want)}")
+    for k, (n, total) in want.items():
+        check(got[k][0] == n, f"float key {k}: count {got[k][0]} want {n}")
+        if total is not None:
+            check(abs(got[k][1] - total) <= F64_RTOL * abs(total),
+                  f"float key {k}: sum {got[k][1]} want {total}")
+    run.record("5b GROUP BY a float64 key (hash, float lanes)",
+               "jit_hash_fused", el, d, groups=len(want), rtol=F64_RTOL)
+
+    r, d, el, ev = run.run(Q_FLOAT_HLL)
+    slot = scan_slot(n_dev)
+    check(slot in kernel_slots(ev), f"float HLL: slots {kernel_slots(ev)}")
+    check(close(r.rows[0][0], len(vals) + 1, HLL_RTOL),
+          f"approx_count_distinct(x) {r.rows} want about {len(vals) + 1}")
+    run.record("5c approx_count_distinct over float64 (HLL, float lanes)",
+               slot, el, d, rtol=HLL_RTOL, got=int(r.rows[0][0]),
+               want=len(vals) + 1)
+
+
+def leg_router(run, ref):
+    r, d, el, ev = run.run(Q_ROUTER, params=[ref.router_key])
+    check(r.explain["strategy"] == "projection" and r.explain["router"]
+          and r.explain["shards"] == 1, f"router leg: {r.explain}")
+    check("jit_filter" in kernel_slots(ev),
+          f"router leg: slots {kernel_slots(ev)}")
+    check(ref.router_rows and sorted(r.rows) == sorted(ref.router_rows),
+          f"router leg: {sorted(r.rows)} want {sorted(ref.router_rows)}")
+    run.record("6 projection WHERE l_orderkey = $1 (router)", "jit_filter",
+               el, d, rows=len(r.rows))
+
+
+def close(got, want, rtol):
+    return got is not None and abs(float(got) - want) <= rtol * abs(want)
+
+
+def leg_mesh(run, ref, devices, q1_h2d_bytes):
+    """More than one device: where the cached Q1 stack lives, then the
+    repartition join and the mesh aggregates the single-device legs do
+    not reach."""
+    report = {}
+    stats = [dv.memory_stats() for dv in devices]
+    if all(s is not None for s in stats):
+        in_use = [int(s["bytes_in_use"]) for s in stats]
+        share = q1_h2d_bytes // len(devices)
+        check(all(b >= share // 2 for b in in_use),
+              f"cached stack is not spread over the devices: {in_use} "
+              f"bytes in use, expected about {share} each")
+        report["bytes_in_use_per_device"] = in_use
+    else:
+        report["bytes_in_use_per_device"] = "memory_stats unavailable"
+
+    r, d, el, _ = run.run(Q_JOIN)
+    check(r.explain["strategy"] == "join:repartition", f"join: {r.explain}")
+    check("devjoin" in r.explain.get("shuffle", ""),
+          f"join did not run on the device: {r.explain}")
+    want = [(ref.join[0], dec(ref.join[1], 2), dec(ref.join[2], 2))]
+    check(r.rows == want, f"join answer {r.rows} want {want}")
+    run.record("7a repartition join (all_to_all + device sort join)",
+               "build_repartition_join", el, d, shuffle=r.explain["shuffle"],
+               pairs=ref.join[0])
+
+    r, d, el, ev = run.run(Q_STDDEV)
+    want = ref.stddev_rows()
+    check(len(r.rows) == 3 and all(
+        g[0] == w[0] and close(g[1], w[1], F64_RTOL)
+        for g, w in zip(r.rows, want)), f"stddev {r.rows} want {want}")
+    check("mesh_run" in kernel_slots(ev), f"stddev: {kernel_slots(ev)}")
+    run.record("7b stddev(l_quantity) (f64 partials over psum)", "mesh_run",
+               el, d, rtol=F64_RTOL)
+
+    r, d, el, ev = run.run(Q_MEDIAN)
+    want = ref.median_qty()
+    check(close(r.rows[0][0], want, SKETCH_RTOL),
+          f"approx_percentile {r.rows} want {want} within {SKETCH_RTOL}")
+    check("mesh_run" in kernel_slots(ev), f"sketch: {kernel_slots(ev)}")
+    run.record("7c approx_percentile (DDSketch buckets over psum)",
+               "mesh_run", el, d, rtol=SKETCH_RTOL, got=float(r.rows[0][0]),
+               want=want)
+
+    r, d, el, ev = run.run(Q_ROUTER_COUNT, params=[ref.router_key])
+    check(r.explain["router"] is True
+          and r.rows == [(len(ref.router_rows),)],
+          f"routed count {r.rows} {r.explain}")
+    check("jit_fused" in kernel_slots(ev), f"routed count: {kernel_slots(ev)}")
+    run.record("7d routed count(*) WHERE l_orderkey = $1", "jit_fused", el, d)
+    return report
+
+
+# ------------------------------------------------------------------- main
+
+
+def build_native():
+    """Build the native columnar IO library from its tracked sources
+    before JAX is imported, so this process starts no child once it can
+    hold the chip."""
+    subprocess.run(["make", "-C", os.path.join(HERE, "citus_tpu", "native")],
+                   check=True, stdout=subprocess.DEVNULL)
+
+
+def cache_entries(path):
+    return len(os.listdir(path)) if os.path.isdir(path) else 0
+
+
+def versions():
+    import importlib.metadata as md
+    out = {}
+    for pkg in ("jax", "jaxlib", "libtpu", "numpy"):
+        try:
+            out[pkg] = md.version(pkg)
+        except md.PackageNotFoundError:
+            out[pkg] = "not installed"
+    return out
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rows", type=int, default=SF10_LINEITEM_ROWS,
+                    help="lineitem rows (default: TPC-H SF10)")
+    ap.add_argument("--seed", type=int, default=7)
+    ap.add_argument("--shards", type=int, default=0,
+                    help="shard count (default: 8 per device)")
+    ap.add_argument("--out", default=os.path.join(HERE, "chiprun_out",
+                                                  "chip_smoke"))
+    ap.add_argument("--rehearse-on-cpu", action="store_true",
+                    help="debug the script on the CPU backend; the report "
+                         "is stamped as a rehearsal and proves nothing "
+                         "about the chip")
+    args = ap.parse_args()
+
+    if args.rehearse_on_cpu:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+    build_native()
+    t_start = time.perf_counter()
+
+    import jax
+    import citus_tpu as ct
+    from citus_tpu.native import get_lib
+
+    devices = jax.devices()
+    platform = devices[0].platform
+    if platform != "tpu" and not args.rehearse_on_cpu:
+        print(f"chip_smoke: no TPU found (jax.devices() -> {devices}); "
+              "refusing to run on another platform", file=sys.stderr)
+        return 2
+    n_dev = len(devices)
+    shards = args.shards or SHARDS_PER_DEVICE * n_dev
+    n_orders = max(args.rows // 4, 1)
+
+    trace_dir = os.path.join(args.out, "traces")
+    shutil.rmtree(args.out, ignore_errors=True)
+    os.makedirs(trace_dir)
+    data_dir = tempfile.mkdtemp(prefix="citus_chip_smoke_")
+    try:
+        cl = ct.Cluster(data_dir)
+        native_io = get_lib() is not None
+        check(native_io, "native columnar IO library did not load")
+        from citus_tpu.executor.device_cache import GLOBAL_CACHE
+        cache_dir = jax.config.jax_compilation_cache_dir
+        header = {
+            "platform": platform, "device_kind": devices[0].device_kind,
+            "device_count": n_dev, "rehearsal": args.rehearse_on_cpu,
+            "versions": versions(), "native_io": native_io,
+            "rows": args.rows, "shards": shards, "seed": args.seed,
+            "device_cache_capacity_bytes": GLOBAL_CACHE.capacity,
+            "compile_cache_dir": cache_dir,
+            "compile_cache_entries_at_start": cache_entries(cache_dir),
+            "assumed": [
+                "lineitem carries 8 of TPC-H's 16 columns",
+                "keys and values are uniform draws (numpy default_rng), "
+                "not dbgen's distributions",
+                "orders (4-chip join leg) carries 3 columns",
+            ],
+            "reduced": ([] if args.rows == SF10_LINEITEM_ROWS else
+                        [f"rows cut from {SF10_LINEITEM_ROWS} to {args.rows}"]),
+        }
+        print("header " + json.dumps(header), flush=True)
+
+        cl.execute(LINEITEM_DDL)
+        cl.execute("SELECT create_distributed_table('lineitem', "
+                   f"'l_orderkey', {shards})")
+        rng = np.random.default_rng(args.seed)
+        ref = Reference()
+        t0 = time.perf_counter()
+        load_lineitem(cl, ref, rng, args.rows, n_orders)
+        if n_dev > 1:
+            cl.execute(ORDERS_DDL)
+            cl.execute("SELECT create_distributed_table('orders', "
+                       f"'o_custkey', {shards})")
+            load_orders(cl, n_orders)
+        load_s = time.perf_counter() - t0
+        print(f"setup: generated, referenced and ingested {args.rows} rows "
+              f"in {load_s:.1f} s (set-up, not a metric)", flush=True)
+
+        cl.execute(f"SET citus.trace_export_dir = '{trace_dir}'")
+        cl.execute("SET citus.trace_sample_rate = 1")
+        run = Runner(cl, trace_dir)
+        h2d = leg_q1_cold(run, ref, n_dev)
+        leg_q1_warm(run, ref, n_dev)
+        mesh_report = leg_mesh(run, ref, devices, h2d) if n_dev > 1 else None
+        leg_q6(run, ref, n_dev)
+        leg_q6_coalesced(run, ref)
+        leg_direct(run, ref, n_dev)
+        leg_hash(run, ref)
+        leg_float_lanes(run, rng, shards, n_dev,
+                        min(MEASURES_ROWS, args.rows))
+        leg_router(run, ref)
+
+        memory = []
+        for dv in devices:
+            s = dv.memory_stats() or {}
+            memory.append({"id": dv.id,
+                           "bytes_limit": s.get("bytes_limit"),
+                           "peak_bytes_in_use": s.get("peak_bytes_in_use")})
+        report = dict(header, legs=run.legs, mesh=mesh_report, memory=memory,
+                      setup_load_s=round(load_s, 1),
+                      setup_compile_s=round(
+                          sum(l["compile_s"] for l in run.legs), 3),
+                      compile_cache_entries_at_end=cache_entries(cache_dir),
+                      wall_s_not_a_metric=round(
+                          time.perf_counter() - t_start, 1))
+        with open(os.path.join(args.out, "report.json"), "w") as fh:
+            json.dump(report, fh, indent=1, default=str)
+        print("report " + json.dumps(report, default=str), flush=True)
+        cl.close()
+    finally:
+        shutil.rmtree(data_dir, ignore_errors=True)
+
+    device = {"platform": platform, "kind": devices[0].device_kind,
+              "count": n_dev}
+    final = {"ok": True, "device": device}
+    if args.rehearse_on_cpu:
+        final["rehearsal"] = True
+    print(json.dumps(final), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -643,8 +643,11 @@ class Cluster:
         if hosted_nodes is not None:
             self.catalog.hosted_nodes = set(hosted_nodes)
         if n_nodes is None:
+            # one node per executor device; raises when JAX found no
+            # accelerator and the cpu platform was not asked for by name
+            from citus_tpu.parallel.mesh import executor_devices
             n_nodes = 0 if hosted_nodes is not None \
-                else max(len(jax.devices()), 1)
+                else len(executor_devices())
         if n_nodes:
             self.catalog.ensure_nodes(n_nodes)
         self.catalog.commit()
@@ -679,8 +682,7 @@ class Cluster:
         from citus_tpu.planner.plan_cache import PlanCache
         self._plan_cache = PlanCache()
         GLOBAL_KERNELS.set_capacity(self.settings.executor.kernel_cache_size)
-        if self.settings.executor.jit_cache_dir:
-            configure_persistent_cache(self.settings.executor.jit_cache_dir)
+        configure_persistent_cache()
         self._background_jobs = None
         self._maintenance = None
         # per-thread implicit sessions: {thread ident: (Thread, Session)}

@@ -122,7 +122,6 @@ _GUCS = {
     # planner/auto_param.py)
     "citus.plan_cache_mode": ("planner", "plan_cache_mode", _plan_cache_mode),
     "citus.kernel_cache_size": ("executor", "kernel_cache_size", int),
-    "citus.jit_cache_dir": ("executor", "jit_cache_dir", str),
     # same-family query coalescing (executor/megabatch.py): dispatch
     # window (ms; 0 = off, byte-identical serial path) and per-batch
     # occupancy bound
@@ -294,9 +293,6 @@ def _execute_set(cl, stmt: A.SetConfig) -> Result:
     elif key == "citus.decode_threads":
         from citus_tpu.storage.reader import set_decode_threads
         set_decode_threads(int(v))
-    elif key == "citus.jit_cache_dir":
-        from citus_tpu.executor.kernel_cache import configure_persistent_cache
-        configure_persistent_cache(v)
     elif key == "citus.flight_recorder_interval_ms":
         cl.flight_recorder.apply()  # start/stop the sampler to match
     elif key == "citus.rollup_refresh_interval_ms":

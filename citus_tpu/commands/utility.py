@@ -361,7 +361,6 @@ _HEALTH_SEVERITY = {
     "catchup_stall": "warning",
     "pool_saturation": "critical",
     "dead_node": "critical",
-    "device_probe_wedged": "warning",
     "metadata_sync_lag": "warning",
     "autopilot_action": "info",
 }

@@ -7,8 +7,11 @@ chunk sizes) can be overridden at table level, mirroring
 ``columnar_internal.options``.
 
 ``task_executor_backend`` selects where per-shard scan kernels run:
-``"tpu"`` (default: whatever accelerator JAX sees) or ``"cpu"``
-(host-side numpy reference path, used as the correctness oracle).
+``"tpu"`` (default: jitted kernels on the accelerator JAX finds — on
+the host platform only when ``JAX_PLATFORMS`` names ``cpu``; no
+accelerator and no such request is an error,
+parallel/mesh.py ``executor_devices``) or ``"cpu"`` (host-side numpy
+reference path, used as the correctness oracle; needs no device).
 """
 
 from __future__ import annotations
@@ -62,7 +65,8 @@ class PlannerSettings:
 
 @dataclass
 class ExecutorSettings:
-    # "tpu" = JAX backend (accelerator or CPU mesh); "cpu" = numpy oracle.
+    # "tpu" = JAX backend (the accelerator; the CPU mesh only when
+    # JAX_PLATFORMS names cpu); "cpu" = numpy oracle.
     task_executor_backend: str = "tpu"
     # Max shard-kernel invocations in flight per device — the streaming
     # prefetch window (analog of citus.max_adaptive_executor_pool_size).
@@ -93,12 +97,6 @@ class ExecutorSettings:
     # citus.use_secondary_nodes='always' analog; failover to the
     # primary still applies when no replica answers.
     use_secondary_nodes: bool = False
-    # Lower the scan->filter->partial-agg worker through a Pallas
-    # kernel (VMEM row blocks, on-core accumulation) instead of the
-    # XLA-fused jnp worker.  Off by default: the fused path is the
-    # reference; this is the hand-scheduled alternative (interpreter
-    # mode off-TPU).  Scope: the SINGLE-DEVICE streaming path only —
-    # the multi-device mesh path always runs the fused sharded worker.
     # Pad scan batches to power-of-two row counts to bound recompiles.
     batch_row_buckets: bool = True
     # Smallest padded batch (rows) a kernel will ever see.
@@ -119,9 +117,6 @@ class ExecutorSettings:
     # structural plan fingerprint (executor/kernel_cache.py) —
     # citus.kernel_cache_size.
     kernel_cache_size: int = 512
-    # Directory for JAX's persistent on-disk XLA compilation cache so
-    # process restarts skip compiles — citus.jit_cache_dir ("" = off).
-    jit_cache_dir: str = ""
     # Same-family query coalescing (executor/megabatch.py): queries
     # whose plans share a fingerprint and arrive within this window
     # (ms) stack into ONE vmap-lifted device dispatch —

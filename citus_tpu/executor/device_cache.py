@@ -9,8 +9,10 @@ and naturally invalidates, and the generation keys out the two windows
 version alone misses (the version is committed before the stripe flip,
 and a torn scan's put must not satisfy the seqlock retry after it).
 
-A simple byte-bounded LRU keeps us inside HBM (v5e ~16 GB); eviction
-drops the device reference and lets JAX free the buffers.  Beyond the
+A simple byte-bounded LRU keeps us inside HBM (the capacity is a
+constant, not yet read off the device: chip_smoke.py prints the chip's
+``bytes_limit`` beside it); eviction drops the device reference and
+lets JAX free the buffers.  Beyond the
 hit/miss/evicted counters the cache now keeps an HBM ledger: live
 resident bytes, the high-water mark, and per-(table, tenant)
 attribution — surfaced through ``citus_device_memory()``, the

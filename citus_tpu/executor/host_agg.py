@@ -131,13 +131,10 @@ class HostGroupAccumulator:
                 continue
             if op.kind == "hll":
                 from citus_tpu.planner.aggregates import (
-                    HLL_M, hll_rho_buckets,
+                    HLL_M, hll_rho_buckets, hll_value_bits,
                 )
                 v, ok = arg_np[op.arg_index]
-                v = np.asarray(v)
-                bits = v.astype(np.float64).view(np.int64) \
-                    if np.issubdtype(v.dtype, np.floating) else v.astype(np.int64)
-                bucket, rho = hll_rho_buckets(np, bits, ok)
+                bucket, rho = hll_rho_buckets(np, hll_value_bits(np, v), ok)
                 flat = np.zeros(L * HLL_M, np.int32)
                 nz = np.nonzero(ok)[0]
                 if nz.size:

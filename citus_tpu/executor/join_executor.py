@@ -221,10 +221,9 @@ def _get_mesh(settings: Settings):
     """The multi-device mesh, or None (single device / cpu oracle)."""
     if settings.executor.task_executor_backend == "cpu":
         return None
-    import jax
-    if len(jax.devices()) <= 1:
+    from citus_tpu.parallel.mesh import default_mesh, executor_devices
+    if len(executor_devices()) <= 1:
         return None
-    from citus_tpu.parallel.mesh import default_mesh
     return default_mesh()
 
 

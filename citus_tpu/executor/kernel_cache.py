@@ -26,16 +26,17 @@ program — this module makes that sharing explicit and process-wide:
 - ``jit_compile(fn)`` — the ONLY ``jax.jit`` call site in the package
   (CI-enforced, tests/test_ci_invariants.py); wraps the jitted callable
   to attribute trace+compile time to the ``kernel_compile_ms`` counter.
-- ``configure_persistent_cache(dir)`` — JAX's on-disk XLA compilation
-  cache (``citus.jit_cache_dir``) so process restarts skip compiles.
+- ``configure_persistent_cache()`` — JAX's on-disk XLA compilation
+  cache, on at every Cluster open, so process restarts skip compiles.
 """
 
 from __future__ import annotations
 
 import hashlib
+import os
 import threading
 from collections import OrderedDict
-from typing import Callable, Optional
+from typing import Callable
 
 from citus_tpu.observability import trace as _trace
 from citus_tpu.observability.trace import clock
@@ -210,30 +211,25 @@ def get_kernel(plan, slot: str, build: Callable[[], object],
     return k
 
 
-_persistent_dir: Optional[str] = None
+#: where the on-disk XLA compilation cache lives when the environment
+#: does not place it: a fixed directory of the checkout, never a temp
+#: name — a cache directory that moves between runs never hits
+DEFAULT_PERSISTENT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def configure_persistent_cache(path: Optional[str]) -> bool:
-    """Point JAX's on-disk XLA compilation cache at ``path`` so a process
-    restart reuses serialized executables (citus.jit_cache_dir; empty =
-    leave disabled).  Thresholds drop to zero so even small analytical
-    kernels persist.  Best-effort: older jax builds without the config
-    knobs simply skip it."""
-    global _persistent_dir
-    if not path:
-        return False
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", str(path))
-    except Exception:
-        return False
-    for knob, v in (("jax_persistent_cache_min_compile_time_secs", 0),
-                    ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            import jax
-            jax.config.update(knob, v)
-        # lint: disable=SWL01 -- tuning knob only; older jax builds lack it and the cache works without it
-        except Exception:
-            pass
-    _persistent_dir = str(path)
-    return True
+def configure_persistent_cache() -> None:
+    """Switch on JAX's on-disk XLA compilation cache so a process
+    restart reuses serialized executables; called at every Cluster
+    open.  ``JAX_COMPILATION_CACHE_DIR`` places the cache from outside:
+    JAX reads it into ``jax_compilation_cache_dir`` itself, so when it
+    is set no directory is set in code.  Otherwise the cache lives at
+    ``DEFAULT_PERSISTENT_CACHE_DIR``.  Thresholds drop to zero so even
+    the small analytical kernels persist."""
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          DEFAULT_PERSISTENT_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)

@@ -112,8 +112,6 @@ METRIC_HELP = {
     "health_catchup_stall": "active catch-up-stall health events",
     "health_pool_saturation": "active pool-saturation health events",
     "health_dead_node": "active dead-node health events",
-    "health_device_probe_wedged":
-        "active wedged-device-probe health events",
     "health_metadata_sync_lag": "active metadata-sync-lag health events",
     "health_autopilot_action": "active autopilot-action health events",
     "autopilot_ticks": "autopilot evaluation ticks run",
@@ -320,7 +318,6 @@ def _gauges(cluster) -> dict:
     g["health_catchup_stall"] = active.get("catchup_stall", 0)
     g["health_pool_saturation"] = active.get("pool_saturation", 0)
     g["health_dead_node"] = active.get("dead_node", 0)
-    g["health_device_probe_wedged"] = active.get("device_probe_wedged", 0)
     g["health_metadata_sync_lag"] = active.get("metadata_sync_lag", 0)
     g["health_autopilot_action"] = active.get("autopilot_action", 0)
     return g

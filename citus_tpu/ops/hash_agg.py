@@ -42,6 +42,7 @@ from typing import Callable
 import numpy as np
 
 from citus_tpu.planner.bound import _as_mask, compile_expr, param_env_names, predicate_mask
+from citus_tpu.planner.aggregates import float_bits
 from citus_tpu.planner.physical import PhysicalPlan
 from citus_tpu.ops.scan_agg import _sentinel
 
@@ -65,10 +66,8 @@ def _fingerprint(xp, keys, shape):
     h = xp.full(shape, _FNV, np.uint64)
     for kv, kvm in keys:
         kv = xp.asarray(kv)
-        if kv.dtype == np.dtype(np.float64):
-            bits = kv.view(np.uint64)
-        elif np.issubdtype(kv.dtype, np.floating):
-            bits = kv.astype(np.float64).view(np.uint64)
+        if np.issubdtype(kv.dtype, np.floating):
+            bits = float_bits(xp, kv)
         else:
             bits = kv.astype(np.int64).view(np.uint64)
         bits = xp.where(kvm, bits, _GOLD)

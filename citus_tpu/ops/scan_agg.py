@@ -141,13 +141,10 @@ def build_worker_fn(plan: PhysicalPlan, xp) -> Callable:
                     # shards with the same elementwise-max collective as
                     # plain max partials
                     from citus_tpu.planner.aggregates import (
-                        HLL_M, hll_rho_buckets,
+                        HLL_M, hll_rho_buckets, hll_value_bits,
                     )
-                    v = xp.asarray(v)
-                    bits = v.astype(np.float64).view(np.int64) \
-                        if np.issubdtype(v.dtype, np.floating) \
-                        else v.astype(np.int64)
-                    bucket, rho = hll_rho_buckets(xp, bits, ok)
+                    bucket, rho = hll_rho_buckets(
+                        xp, hll_value_bits(xp, v), ok)
                     onehot = bucket[None, :] == xp.arange(
                         HLL_M, dtype=np.int32)[:, None]
                     outs.append(xp.max(

@@ -16,31 +16,43 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from citus_tpu.errors import ExecutionError
 from citus_tpu.executor.kernel_cache import jit_compile
 
 SHARD_AXIS = "shard"
 
 
-def default_mesh(n: Optional[int] = None) -> Mesh:
+def executor_devices() -> list:
+    """The devices the JAX executor runs on — the package's one answer
+    to that question (Cluster node count, scan loop, join mesh and
+    ``default_mesh`` all ask here).
+
+    The CPU platform counts only when it was asked for by name
+    (``JAX_PLATFORMS`` / ``jax_platforms`` lists ``cpu``, as the test
+    harness does).  When the platform choice was left to JAX and it
+    came back with ``cpu``, the accelerator is missing or unreachable:
+    raise instead of computing on the host under the accelerator's
+    name.  ``task_executor_backend = "cpu"`` (the numpy arm) never
+    calls this."""
     devs = jax.devices()
+    if devs[0].platform == "cpu":
+        asked = (jax.config.jax_platforms or "").lower().split(",")
+        if "cpu" not in asked:
+            raise ExecutionError(
+                "no accelerator found: JAX fell back to the cpu platform "
+                "without being asked to (set JAX_PLATFORMS=cpu to run "
+                "the executor on the host deliberately)")
+    return devs
+
+
+def default_mesh(n: Optional[int] = None) -> Mesh:
+    devs = executor_devices()
     n = n or len(devs)
     return Mesh(devs[:n], (SHARD_AXIS,))
 
 
 def shard_axis_size(mesh: Mesh) -> int:
     return mesh.shape[SHARD_AXIS]
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map across jax versions: older releases expose it as
-    jax.experimental.shard_map with the replication check named
-    check_rep instead of check_vma."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
 
 
 def sharded_partial_agg(worker, combine_kinds: list[str], mesh: Mesh) -> Callable:
@@ -83,8 +95,8 @@ def sharded_partial_agg(worker, combine_kinds: list[str], mesh: Mesh) -> Callabl
             P(SHARD_AXIS) if kind == "none" else P()
             for kind in combine_kinds
         )
-        fn = shard_map_compat(per_shard, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
+        fn = jax.shard_map(per_shard, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         return fn(cols, valids, row_mask)
 
     return jit_compile(run)

@@ -26,7 +26,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from citus_tpu.executor.kernel_cache import jit_compile
-from citus_tpu.parallel.mesh import SHARD_AXIS, shard_map_compat
+from citus_tpu.parallel.mesh import SHARD_AXIS
 
 
 def _pack_blocks(values: tuple, target: jnp.ndarray, mask: jnp.ndarray,
@@ -87,8 +87,8 @@ def build_repartition(mesh: Mesh, n_cols: int, capacity: int):
 
     in_specs = (tuple(P(SHARD_AXIS) for _ in range(n_cols)), P(SHARD_AXIS), P(SHARD_AXIS))
     out_specs = (tuple(P(SHARD_AXIS) for _ in range(n_cols)), P(SHARD_AXIS), P())
-    fn = shard_map_compat(per_device, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return jit_compile(fn)
 
 
@@ -172,8 +172,8 @@ def build_repartition_join(mesh: Mesh, n_lcols: int, n_rcols: int,
     in_specs = (cols(n_lcols), P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS),
                 cols(n_rcols), P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS))
     out_specs = (cols(n_lcols), cols(n_rcols), P(SHARD_AXIS), P())
-    fn = shard_map_compat(per_device, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return jit_compile(fn)
 
 

@@ -370,6 +370,45 @@ HLL_M = 128                      # registers; error ~ 1.04/sqrt(m) ≈ 9%
 HLL_ALPHA = 0.7213 / (1 + 1.079 / HLL_M)
 
 
+def float_bits(xp, v):
+    """Float values -> uint64 lanes for hashing, with no 64-bit bitcast.
+
+    A TPU holds float64 as a pair of float32 and XLA refuses
+    ``bitcast-convert`` on it, so the IEEE bit pattern is not to be had
+    on the device.  The lanes are instead the float32 head of the value
+    and the float32 residual, each viewed as uint32 — the same on numpy
+    and on any XLA backend, deterministic, and one-to-one on everything
+    a float32 pair can hold (values it cannot tell apart merely share a
+    lane, which every caller treats as a hash collision).  All NaNs
+    share one lane, and so do the two zeros."""
+    v = xp.asarray(v).astype(np.float64)
+    with np.errstate(invalid="ignore", over="ignore"):
+        hi = v.astype(np.float32)
+        fin = xp.isfinite(hi)
+        lo = (xp.where(fin, v, np.float64(0.0))
+              - xp.where(fin, hi, np.float32(0.0)).astype(np.float64)
+              ).astype(np.float32)
+    # backends differ on float32 subnormals (XLA flushes them, numpy
+    # keeps them): below the smallest normal, both halves read as zero
+    tiny = np.finfo(np.float32).tiny
+    hi = xp.where(xp.abs(hi) < tiny, np.float32(0.0), hi)
+    lo = xp.where(xp.abs(lo) < tiny, np.float32(0.0), lo)
+    bits = (hi.view(np.uint32).astype(np.uint64) << np.uint64(32)) \
+        | lo.view(np.uint32).astype(np.uint64)
+    return xp.where(xp.isnan(v), np.uint64(0x7FF8000000000000), bits)
+
+
+def hll_value_bits(xp, v):
+    """Values -> the int64 lanes the HLL hash consumes: integers their
+    own value, floats ``float_bits``.  The device scan, the host
+    grouping path and the rollup kernels all come through here, so
+    their registers stay mergeable."""
+    v = xp.asarray(v)
+    if np.issubdtype(v.dtype, np.floating):
+        return float_bits(xp, v).view(np.int64)
+    return v.astype(np.int64)
+
+
 def hll_rho_buckets(xp, bits, ok):
     """int64 value bits -> (bucket [N] int32, rho [N] int32); invalid
     rows get rho 0 (neutral under max)."""

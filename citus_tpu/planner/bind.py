@@ -400,8 +400,13 @@ class Binder:
             return BLiteral(t.to_physical(d), t)
         raise AnalysisError(f"cannot coerce string literal to {target}")
 
-    def _align(self, left: BExpr, right: BExpr) -> tuple[BExpr, BExpr]:
-        """Insert scale/cast adjustments so both sides share physical space."""
+    def _align(self, left: BExpr, right: BExpr,
+               scales: bool = True) -> tuple[BExpr, BExpr]:
+        """Insert scale/cast adjustments so both sides share physical
+        space.  ``scales=False`` (multiplication) keeps each decimal at
+        its own scale: scales add on multiply, so aligning them first
+        would only inflate the product — Q1's sum_charge came out at
+        scale 8 instead of 6 and overflowed int64 past ~6 M rows."""
         lt, rt = left.type, right.type
         # string literal coercion
         if isinstance(right, BLiteral) and rt.is_text and not lt.is_text \
@@ -448,7 +453,8 @@ class Binder:
         # decimal scale alignment (comparisons, +, -)
         ls = lt.scale if lt.is_decimal else 0
         rs = rt.scale if rt.is_decimal else 0
-        if (lt.is_decimal or rt.is_decimal) and not (lt.is_float or rt.is_float):
+        if scales and (lt.is_decimal or rt.is_decimal) \
+                and not (lt.is_float or rt.is_float):
             if ls < rs:
                 left = self._rescale(left, rs)
             elif rs < ls:
@@ -554,7 +560,7 @@ class Binder:
             enum_cmp = self._try_enum_ordered(op, left, right)
             if enum_cmp is not None:
                 return enum_cmp
-        left, right = self._align(left, right)
+        left, right = self._align(left, right, scales=op != "*")
         if op in ("=", "<>", "<", "<=", ">", ">=") \
                 and (left.type.kind == T.UUID or right.type.kind == T.UUID):
             return self._bind_uuid_compare(op, left, right)

@@ -23,18 +23,15 @@ import numpy as np
 from citus_tpu.executor.kernel_cache import GLOBAL_KERNELS, jit_compile
 from citus_tpu.planner.aggregates import (
     DDSK_M, HLL_M, TOPK_M, TOPK_SENTINEL, ddsk_bucket_indexes,
-    hll_rho_buckets, topk_buckets,
+    hll_rho_buckets, hll_value_bits, topk_buckets,
 )
 
 
 def value_bits(arr: np.ndarray) -> np.ndarray:
-    """Values -> the int64 bit pattern the hash sketches consume (must
-    match ops/scan_agg.py: floats hash their float64 bits, everything
-    else its int64 value, so rollup and raw-scan estimates agree)."""
-    a = np.asarray(arr)
-    if np.issubdtype(a.dtype, np.floating):
-        return a.astype(np.float64).view(np.int64)
-    return a.astype(np.int64)
+    """Values -> the int64 lanes the hash sketches consume — the same
+    function the raw scan uses, so rollup and raw-scan estimates
+    agree."""
+    return hll_value_bits(np, arr)
 
 
 def _pad_to(n: int) -> int:

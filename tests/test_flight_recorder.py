@@ -185,21 +185,6 @@ def test_shed_spike_and_catchup_stall_events(cl, monkeypatch):
     assert rec.active_counts()["catchup_stall"] == 1
 
 
-def test_wedge_marker_raises_and_clears_event(cl, tmp_path, monkeypatch):
-    marker = tmp_path / "wedge_marker"
-    monkeypatch.setenv("CITUS_WEDGE_MARKER", str(marker))
-    rec = cl.flight_recorder
-    marker.write_text('{"event":"tunnel_wedged"}\n')
-    rec.run_once()
-    assert rec.active_counts()["device_probe_wedged"] == 1
-    from citus_tpu.observability.export import prometheus_text
-    assert "citus_health_device_probe_wedged 1" in prometheus_text(cl)
-    marker.unlink()
-    rec.run_once()
-    assert rec.active_counts()["device_probe_wedged"] == 0
-    assert "citus_health_device_probe_wedged 0" in prometheus_text(cl)
-
-
 def test_emit_event_rejects_unknown_kind(cl):
     with pytest.raises(ValueError, match="unknown health-event kind"):
         cl.flight_recorder.emit_event("made_up", "x", 1, 0, "detail")
