@@ -25,6 +25,7 @@ host's cores.
 """
 
 import argparse
+import contextlib
 import datetime
 import decimal
 import json
@@ -487,10 +488,40 @@ def leg_direct(run, ref, n_dev):
                groups=len(want))
 
 
-def leg_hash(run, ref):
+@contextlib.contextmanager
+def memory_growth(devices):
+    """Which devices the body allocates on: every device's
+    ``bytes_in_use`` is polled from a thread while the body runs, and
+    ``["per_device"]`` of what is yielded becomes each device's highest
+    reading above the one taken before the body."""
+    out = {"per_device": "memory_stats unavailable"}
+    if any(dv.memory_stats() is None for dv in devices):   # the CPU backend
+        yield out
+        return
+    base = [int(dv.memory_stats()["bytes_in_use"]) for dv in devices]
+    peak = list(base)
+    stop = threading.Event()
+
+    def poll():
+        while not stop.wait(0.05):
+            for i, dv in enumerate(devices):
+                peak[i] = max(peak[i], int(dv.memory_stats()["bytes_in_use"]))
+
+    t = threading.Thread(target=poll)
+    t.start()
+    try:
+        yield out
+    finally:
+        stop.set()
+        t.join()
+    out["per_device"] = [p - b for p, b in zip(peak, base)]
+
+
+def leg_hash(run, ref, devices):
     cl = run.cl
     cl.execute("SET citus.hash_agg_slots = auto")
-    r, d, el, ev = run.run(Q_HASH)
+    with memory_growth(devices) as grew:
+        r, d, el, ev = run.run(Q_HASH)
     check(r.explain["strategy"] == "hash_host", f"hash leg: {r.explain}")
     check("jit_hash_fused" in kernel_slots(ev),
           f"hash leg: slots {kernel_slots(ev)}")
@@ -503,7 +534,8 @@ def leg_hash(run, ref):
                "jit_hash_fused", el, d, groups=len(want),
                hash_slots=pl.get("hash_slots"),
                hash_occupancy_pct=pl.get("hash_occupancy_pct"),
-               hash_spill_rows=d.get("hash_spill_rows", 0))
+               hash_spill_rows=d.get("hash_spill_rows", 0),
+               bytes_in_use_growth_per_device=grew["per_device"])
 
 
 def leg_float_lanes(run, rng, shards, n_dev, rows):
@@ -650,8 +682,6 @@ def main() -> int:
     ap.add_argument("--rows", type=int, default=SF10_LINEITEM_ROWS,
                     help="lineitem rows (default: TPC-H SF10)")
     ap.add_argument("--seed", type=int, default=7)
-    ap.add_argument("--shards", type=int, default=0,
-                    help="shard count (default: 8 per device)")
     ap.add_argument("--out", default=os.path.join(HERE, "chiprun_out",
                                                   "chip_smoke"))
     ap.add_argument("--rehearse-on-cpu", action="store_true",
@@ -676,7 +706,7 @@ def main() -> int:
               "refusing to run on another platform", file=sys.stderr)
         return 2
     n_dev = len(devices)
-    shards = args.shards or SHARDS_PER_DEVICE * n_dev
+    shards = SHARDS_PER_DEVICE * n_dev
     n_orders = max(args.rows // 4, 1)
 
     trace_dir = os.path.join(args.out, "traces")
@@ -733,7 +763,7 @@ def main() -> int:
         leg_q6(run, ref, n_dev)
         leg_q6_coalesced(run, ref)
         leg_direct(run, ref, n_dev)
-        leg_hash(run, ref)
+        leg_hash(run, ref, devices)
         leg_float_lanes(run, rng, shards, n_dev,
                         min(MEASURES_ROWS, args.rows))
         leg_router(run, ref)

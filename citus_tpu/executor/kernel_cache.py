@@ -24,8 +24,9 @@ program — this module makes that sharing explicit and process-wide:
   plan-cache hit costs a dict lookup and a plan-cache miss that lands on
   a known fingerprint skips XLA entirely (kernel_cache_hits counter).
 - ``jit_compile(fn)`` — the ONLY ``jax.jit`` call site in the package
-  (CI-enforced, tests/test_ci_invariants.py); wraps the jitted callable
-  to attribute trace+compile time to the ``kernel_compile_ms`` counter.
+  (CI-enforced, tests/test_ci_invariants.py); asks the no-silent-CPU
+  device guard, then wraps the jitted callable to attribute
+  trace+compile time to the ``kernel_compile_ms`` counter.
 - ``configure_persistent_cache()`` — JAX's on-disk XLA compilation
   cache, on at every Cluster open, so process restarts skip compiles.
 """
@@ -111,8 +112,13 @@ class _TimedJit:
 
 
 def jit_compile(fn: Callable, **jit_kwargs) -> _TimedJit:
-    """The package's single jax.jit entry point."""
+    """The package's single jax.jit entry point — and so the one point
+    every kernel slot passes before it can exist: the no-silent-CPU
+    guard (parallel/mesh.py ``executor_devices``) is asked here, once
+    per kernel built, whatever path reached the build."""
     import jax
+    from citus_tpu.parallel.mesh import executor_devices
+    executor_devices()
     return _TimedJit(jax.jit(fn, **jit_kwargs))
 
 

@@ -24,16 +24,19 @@ SHARD_AXIS = "shard"
 
 def executor_devices() -> list:
     """The devices the JAX executor runs on — the package's one answer
-    to that question (Cluster node count, scan loop, join mesh and
-    ``default_mesh`` all ask here).
+    to that question.  Cluster node count, scan loop, join mesh and
+    ``default_mesh`` ask here for the list; ``kernel_cache.jit_compile``
+    asks before it builds any kernel, so no slot (projection, hash
+    aggregate, megabatch, rollup, a hosted worker's task) can compute
+    without having passed this check.
 
     The CPU platform counts only when it was asked for by name
     (``JAX_PLATFORMS`` / ``jax_platforms`` lists ``cpu``, as the test
     harness does).  When the platform choice was left to JAX and it
     came back with ``cpu``, the accelerator is missing or unreachable:
     raise instead of computing on the host under the accelerator's
-    name.  ``task_executor_backend = "cpu"`` (the numpy arm) never
-    calls this."""
+    name.  A query under ``task_executor_backend = "cpu"`` (the numpy
+    arm) builds no kernel and never calls this."""
     devs = jax.devices()
     if devs[0].platform == "cpu":
         asked = (jax.config.jax_platforms or "").lower().split(",")

@@ -380,7 +380,17 @@ def float_bits(xp, v):
     and on any XLA backend, deterministic, and one-to-one on everything
     a float32 pair can hold (values it cannot tell apart merely share a
     lane, which every caller treats as a hash collision).  All NaNs
-    share one lane, and so do the two zeros."""
+    share one lane, and so do the two zeros.
+
+    The limit is float32's range, on every backend, numpy included:
+    finite doubles beyond ±3.4e38 share the infinities' lanes and those
+    below 1.2e-38 in magnitude share zero's.  Where float64 is real
+    (numpy, XLA:CPU) hash GROUP BY stays exact there, because the
+    stored keys verify every claim; ``approx_count_distinct`` counts
+    each of those classes as one value (tests/test_chip_bringup.py pins
+    both).  The lanes differ from the IEEE bit pattern used before, so
+    HLL registers over float columns persisted by an older build do not
+    merge with new ones: refresh such a rollup in full."""
     v = xp.asarray(v).astype(np.float64)
     with np.errstate(invalid="ignore", over="ignore"):
         hi = v.astype(np.float32)

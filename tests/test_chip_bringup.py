@@ -68,6 +68,30 @@ def test_queries_raise_instead_of_computing_on_the_cpu(tmp_path):
         assert cl.execute(agg).rows == want
 
 
+def test_every_kernel_slot_asks_the_guard(tmp_path):
+    """A Cluster opened with an explicit node count skips the guard at
+    open; its projection (``jit_filter``) and float-key hash GROUP BY
+    (``jit_hash_fused``) never meet the scan loop's check.  Every
+    kernel compiles through ``jit_compile``, which asks.  The
+    column names are this test's own, so no other test has left a
+    compiled kernel of the same fingerprint behind."""
+    cl = ct.Cluster(str(tmp_path / "db"), n_nodes=2)
+    cl.execute("CREATE TABLE g (guard_k bigint, guard_x double precision)")
+    cl.execute("SELECT create_distributed_table('g', 'guard_k', 4)")
+    cl.copy_from("g", columns={"guard_k": np.arange(100),
+                               "guard_x": np.arange(100) / 2.0})
+    with platform_left_to_jax():
+        for sql in ("SELECT guard_k, guard_x FROM g WHERE guard_k = 5",
+                    "SELECT guard_x, count(*) FROM g GROUP BY guard_x",
+                    "SELECT count(*), sum(guard_x) FROM g"):
+            with pytest.raises(ExecutionError, match="no accelerator found"):
+                cl.execute(sql)
+        with pytest.raises(ExecutionError, match="no accelerator found"):
+            kernel_cache.jit_compile(lambda a: a + 1)
+    assert cl.execute("SELECT guard_x, count(*) FROM g GROUP BY guard_x "
+                      "ORDER BY 1 LIMIT 2").rows == [(0.0, 1), (0.5, 1)]
+
+
 # --------------------------------------------------------- compile cache
 
 
@@ -215,3 +239,22 @@ def test_float_bits_need_no_64_bit_float_bitcast():
     f32 = v[:100].astype(np.float32)
     assert np.array_equal(float_bits(np, f32),
                           float_bits(np, f32.astype(np.float64)))
+
+
+def test_float_lanes_end_at_float32_range(tmp_path):
+    """The stated limit of ``float_bits``: doubles outside float32's
+    range share lanes.  GROUP BY stays exact (stored keys verify each
+    claim); approx_count_distinct counts each class once."""
+    cl = ct.Cluster(str(tmp_path / "db"))
+    cl.execute("CREATE TABLE f (k bigint, x double precision)")
+    cl.execute("SELECT create_distributed_table('f', 'k', 2)")
+    x = np.array([1e300, 2e300, 1e300, 1e-40, 3e-40, 0.0, 1.5] * 10)
+    cl.copy_from("f", columns={"k": np.arange(len(x)), "x": x})
+    for backend in ("tpu", "cpu"):
+        cl.execute(f"SET citus.task_executor_backend = '{backend}'")
+        assert cl.execute("SELECT x, count(*) FROM f GROUP BY x ORDER BY x"
+                          ).rows == [(0.0, 10), (1e-40, 10), (3e-40, 10),
+                                     (1.5, 10), (1e300, 20), (2e300, 10)]
+        # {0, 1e-40, 3e-40}, {1.5}, {1e300, 2e300}
+        assert cl.execute("SELECT approx_count_distinct(x) FROM f"
+                          ).rows == [(3,)]
