@@ -2025,27 +2025,33 @@ class Cluster:
 
     def execute(self, sql: str, params: Optional[Sequence[Any]] = None,
                 role: Optional[str] = None, session=None) -> Result:
-        from citus_tpu.observability.trace import clock as _clock
-        if session is None:
-            session = self._default_session()
-        if session.txn is None:
-            # inside a transaction the catalog object must stay stable
-            # (statements hold references into it; PostgreSQL blocks
-            # conflicting DDL with locks instead)
-            self._maybe_reload_catalog()
         # sampling gate: None on the unsampled hot path (no Span ever
         # allocates); a nested execute() (EXECUTE of a prepared
-        # statement) joins the outer trace instead of rooting a new one
+        # statement) joins the outer trace instead of rooting a new one.
+        # The root opens first and closes last — after the statement's
+        # statistics are booked — so everything this call does lies
+        # under it
         qt = None
         if _trace.current() is None:
             qt = _trace.begin_query(sql, self.settings.observability)
         try:
-            with _trace.span("parse"):
-                stmts = parse_sql(sql)
-        except BaseException:
+            return self._execute(sql, params, role, session)
+        finally:
             if qt is not None:
-                qt.finish()
-            raise
+                self._finish_query_trace(qt, sql)
+
+    def _execute(self, sql: str, params, role, session) -> Result:
+        from citus_tpu.observability.trace import clock as _clock
+        with _trace.span("session"):
+            if session is None:
+                session = self._default_session()
+            if session.txn is None:
+                # inside a transaction the catalog object must stay
+                # stable (statements hold references into it;
+                # PostgreSQL blocks conflicting DDL with locks instead)
+                self._maybe_reload_catalog()
+        with _trace.span("parse"):
+            stmts = parse_sql(sql)
         if role is not None:
             for stmt in stmts:
                 self._check_privileges(role, stmt)
@@ -2121,11 +2127,11 @@ class Cluster:
             _trace.pop_phase_sink()
             _stats.pop_wait_sink()
             self.activity.exit(gpid)
-            if qt is not None:
-                self._finish_query_trace(qt, sql)
         # the nested execute() of an EXECUTE already recorded the
         # underlying statement — don't double-count the wrapper
-        if not (len(stmts) == 1 and isinstance(stmts[0], A.ExecutePrepared)):
+        if len(stmts) == 1 and isinstance(stmts[0], A.ExecutePrepared):
+            return result
+        with _trace.span("book_stats"):
             executor = result.explain.get("strategy", "utility") if result.explain else "utility"
             elapsed = _clock() - t0
             rkey = result.explain.get("router_key") if result.explain else None
@@ -2152,7 +2158,10 @@ class Cluster:
     def _finish_query_trace(self, qt, sql: str) -> None:
         """Close a sampled query's trace: slow-log capture at the
         citus.log_min_duration_ms threshold, Chrome-trace export when
-        citus.trace_export_dir is set, last-trace debug hook."""
+        citus.trace_export_dir is set, last-trace debug hook.  The
+        export runs after the root closed, under a bare
+        ``citus.trace_export`` annotation: it is the tracing's own
+        cost and a profile should show it as that."""
         from citus_tpu.observability.export import write_chrome_trace
         from citus_tpu.observability.slowlog import GLOBAL_SLOW_LOG
         obs = self.settings.observability
@@ -2165,7 +2174,8 @@ class Cluster:
             _trace.set_last(qt.trace)
             if obs.trace_export_dir:
                 try:
-                    write_chrome_trace(qt.trace, obs.trace_export_dir)
+                    with _trace.bare_annotation("trace_export"):
+                        write_chrome_trace(qt.trace, obs.trace_export_dir)
                 except OSError:
                     pass  # export is best-effort; never fail the query
 
@@ -2875,9 +2885,16 @@ class Cluster:
     def profile(self, sql: str, trace_dir: str) -> Result:
         """Execute under the JAX/XLA profiler (the tracing-integration
         analog of SURVEY §5.1); view the trace with TensorBoard or
-        xprof."""
+        xprof.  The statement is traced whatever the sampling rate, so
+        its spans lie in the profile as ``citus.*`` annotations under
+        the device ops."""
         with jax.profiler.trace(trace_dir):
-            return self.execute(sql)
+            qt = _trace.begin_query(sql, self.settings.observability,
+                                    force=True)
+            try:
+                return self.execute(sql)    # joins the forced trace
+            finally:
+                self._finish_query_trace(qt, sql)
 
     def _execute_explain(self, stmt):
         from citus_tpu.commands.explain import _execute_explain

@@ -15,6 +15,7 @@ from typing import Iterator, Optional
 import numpy as np
 
 from citus_tpu.catalog import Catalog, TableMeta
+from citus_tpu.observability import trace as _trace
 from citus_tpu.planner.physical import PhysicalPlan
 from citus_tpu.storage import ShardReader
 from citus_tpu.storage.writer import _load_meta
@@ -29,6 +30,13 @@ class ShardBatch:
     n_rows: int                      # real rows
     padded_rows: int
     shard_index: int
+
+    @property
+    def nbytes(self) -> int:
+        """Bytes of the padded arrays: what one H2D copy of it ships."""
+        return int(sum(c.nbytes for c in self.cols)
+                   + sum(v.nbytes for v in self.valids)
+                   + self.row_mask.nbytes)
 
 
 def bucket_rows(n: int, min_rows: int) -> int:
@@ -120,46 +128,73 @@ def load_shard_batches(
     # NOTE: under the pipelined executor this generator runs on the
     # host decode thread (executor/pipeline.py HostPrefetcher), so the
     # decode_batch fault point below fires there — delays injected on
-    # it model slow host-side decompression overlapping device compute
-    for batch in source:
-        for c in cols:
-            pend_v[c].append(batch.values[c])
-            m = batch.validity[c]
-            pend_m[c].append(np.ones(batch.row_count, bool) if m is None else m)
-        pend_rows += batch.row_count
-        if pend_rows >= max_batch_rows:
+    # it model slow host-side decompression overlapping device compute.
+    # Spans: one stripe_read (file read + decompress of every chunk of
+    # the batch) and one concat per batch, never one per chunk, and
+    # none held across the yield.
+    source = iter(source)
+    exhausted = False
+    while not exhausted:
+        with _trace.span("stripe_read") as sp:
+            chunks = 0
+            for batch in source:
+                for c in cols:
+                    pend_v[c].append(batch.values[c])
+                    m = batch.validity[c]
+                    pend_m[c].append(np.ones(batch.row_count, bool)
+                                     if m is None else m)
+                pend_rows += batch.row_count
+                chunks += 1
+                if pend_rows >= max_batch_rows:
+                    break
+            else:
+                exhausted = True
+            if sp.recording:
+                sp.set(chunks=chunks, rows=pend_rows)
+        if pend_rows:
             FAULTS.hit("decode_batch", f"{table.name}:{shard.shard_id}")
-            yield _drain(cols, pend_v, pend_m, pend_rows)
+            out = _drain(cols, pend_v, pend_m, pend_rows)
             pend_v = {c: [] for c in cols}
             pend_m = {c: [] for c in cols}
             pend_rows = 0
-    if pend_rows:
-        FAULTS.hit("decode_batch", f"{table.name}:{shard.shard_id}")
-        yield _drain(cols, pend_v, pend_m, pend_rows)
+            yield out
 
 
 def _drain(cols, pend_v, pend_m, pend_rows):
-    values = {c: np.concatenate(pend_v[c]) if len(pend_v[c]) > 1 else pend_v[c][0] for c in cols}
-    masks = {c: np.concatenate(pend_m[c]) if len(pend_m[c]) > 1 else pend_m[c][0] for c in cols}
+    with _trace.span("concat") as sp:
+        values = {c: np.concatenate(pend_v[c]) if len(pend_v[c]) > 1
+                  else pend_v[c][0] for c in cols}
+        masks = {c: np.concatenate(pend_m[c]) if len(pend_m[c]) > 1
+                 else pend_m[c][0] for c in cols}
+        if sp.recording:
+            sp.set(chunks=len(pend_v[cols[0]]) if cols else 0,
+                   bytes=int(sum(v.nbytes for v in values.values())
+                             + sum(m.nbytes for m in masks.values())))
     return values, masks, pend_rows
 
 
 def pad_to_batch(table: TableMeta, plan: PhysicalPlan, values: dict, masks: dict,
                  n_rows: int, padded_rows: int, shard_index: int) -> ShardBatch:
     cols_out, valids_out = [], []
-    for c in plan.scan_columns:
-        dt = table.schema.scan_dtype(c, device=True)
-        v = values[c].astype(dt, copy=False)
-        m = masks[c]
-        if padded_rows != n_rows:
-            v = np.concatenate([v, np.zeros(padded_rows - n_rows, dt)])
-            m = np.concatenate([m, np.ones(padded_rows - n_rows, bool)])
-        cols_out.append(v)
-        valids_out.append(m)
-    row_mask = np.zeros(padded_rows, bool)
-    row_mask[:n_rows] = True
-    return ShardBatch(tuple(cols_out), tuple(valids_out), row_mask,
-                      n_rows, padded_rows, shard_index)
+    with _trace.span("pad") as sp:
+        for c in plan.scan_columns:
+            dt = table.schema.scan_dtype(c, device=True)
+            v = values[c].astype(dt, copy=False)
+            m = masks[c]
+            if padded_rows != n_rows:
+                v = np.concatenate([v, np.zeros(padded_rows - n_rows, dt)])
+                m = np.concatenate([m, np.ones(padded_rows - n_rows, bool)])
+            cols_out.append(v)
+            valids_out.append(m)
+        row_mask = np.zeros(padded_rows, bool)
+        row_mask[:n_rows] = True
+        out = ShardBatch(tuple(cols_out), tuple(valids_out), row_mask,
+                         n_rows, padded_rows, shard_index)
+        if sp.recording:
+            sp.set(bytes_in=int(sum(values[c].nbytes + masks[c].nbytes
+                                    for c in plan.scan_columns)),
+                   bytes_out=out.nbytes)
+    return out
 
 
 def empty_batch(table: TableMeta, plan: PhysicalPlan, padded_rows: int,

@@ -15,6 +15,7 @@ the coordinator.
 
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -50,13 +51,62 @@ GLOBAL_COUNTERS = StatCounters()
 def _block_ready(x) -> None:
     """block_until_ready under a device_round wait bracket: the stretch
     the backend spends blocked on device backpressure shows up in the
-    activity view and the wait_device_round_ms counter."""
+    activity view and the wait_device_round_ms counter.  On a TPU a
+    wait costs a few tenths of a millisecond even when every array is
+    ready (PERF.md, PR 23): where the arrays are the outputs of one
+    dispatch, which become ready together, callers pass one of them."""
     import jax
     wtok = begin_wait("device_round")
     try:
         jax.block_until_ready(x)
     finally:
         end_wait(wtok)
+
+
+def _nbytes(arrays) -> int:
+    return int(sum(a.nbytes for a in arrays))
+
+
+def _combine(plan: PhysicalPlan, partial_sets: list):
+    """combine_partials_host under its span: the host half of the
+    partial-agg -> combine step, wherever the partial sets came from
+    (mesh rounds, remote tasks, the numpy arm's batches)."""
+    with _trace.span("combine") as sp:
+        if sp.recording:
+            sp.set(partial_sets=len(partial_sets))
+        return combine_partials_host(plan, partial_sets)
+
+
+def _fetch_rounds(plan: PhysicalPlan, acc: list):
+    """Tail of both mesh loops: wait until the chip has finished the
+    first round, copy the rounds' partial states back one by one,
+    combine them on the host — a span each.  Only the first round is
+    waited for: the copy of round i runs while the chip works on round
+    i + 1, which hides most of it on four chips, and a wait costs a few
+    tenths of a millisecond even for arrays that are ready (PERF.md,
+    PR 23: one wait for ALL rounds before the fetch cost 35 ms of an
+    85 ms query, a wait per round 2.6 ms).  A later round the chip has
+    not finished yet is waited for inside its ``fetch``."""
+    _block_ready(acc[0][-1:])
+    acc_np = []
+    for out in acc:
+        with _trace.span("fetch") as sp:
+            acc_np.append(tuple(np.asarray(o) for o in out))
+            if sp.recording:
+                sp.set(arrays=len(out), bytes=_nbytes(acc_np[-1]))
+    return _combine(plan, acc_np)
+
+
+def _fetch_acc(acc_dev):
+    """Tail of both single-device loops: wait for the chip, then copy
+    the partial states back (one device_get)."""
+    import jax
+    _block_ready(acc_dev[-1:])
+    with _trace.span("fetch") as sp:
+        out = tuple(np.asarray(o) for o in jax.device_get(acc_dev))
+        if sp.recording:
+            sp.set(arrays=len(out), bytes=_nbytes(out))
+    return out
 
 
 @dataclass
@@ -157,7 +207,7 @@ def _run_partials_cpu(cat: Catalog, plan: PhysicalPlan, settings: Settings,
                                         np.ones(n, bool)))
     if not shard_results:
         shard_results.append(_empty_partials(plan, np))
-    return combine_partials_host(plan, shard_results)
+    return _combine(plan, shard_results)
 
 
 def _empty_partials(plan: PhysicalPlan, xp):
@@ -209,16 +259,45 @@ def _iter_padded_batches(cat: Catalog, plan: PhysicalPlan, settings: Settings):
     columnar_reader.c:323).  Full batches share one shape; only tail
     batches differ, so the per-shape jit cache stays small."""
     from citus_tpu.testing.faults import FAULTS
-    for si in plan.shard_indexes:
-        FAULTS.hit("dispatch_task", f"{plan.bound.table.name}:{si}")
-        GLOBAL_COUNTERS.bump("tasks_dispatched")
-        for values, masks, n in load_shard_batches(
-                cat, plan, si,
-                min_batch_rows=settings.executor.min_batch_rows,
-                prefer_secondary=settings.executor.use_secondary_nodes):
-            bucket = bucket_rows(n, settings.executor.min_batch_rows)
-            yield pad_to_batch(plan.bound.table, plan, values, masks, n,
-                               bucket, si)
+
+    def raw_batches():
+        for si in plan.shard_indexes:
+            FAULTS.hit("dispatch_task", f"{plan.bound.table.name}:{si}")
+            GLOBAL_COUNTERS.bump("tasks_dispatched")
+            for values, masks, n in load_shard_batches(
+                    cat, plan, si,
+                    min_batch_rows=settings.executor.min_batch_rows,
+                    prefer_secondary=settings.executor.use_secondary_nodes):
+                yield si, values, masks, n
+
+    # one decode_batch span per batch, on whichever thread pulls this
+    # generator (the decode thread under the prefetcher), closed BEFORE
+    # the yield: a span held across a yield would stay on the puller's
+    # span stack while the consumer runs.  The pull that finds the
+    # stream exhausted is a last, childless span (eof).
+    raw = raw_batches()
+    try:
+        while True:
+            with _trace.span("decode_batch") as sp:
+                item = next(raw, None)
+                if item is not None:
+                    si, values, masks, n = item
+                    hb = pad_to_batch(
+                        plan.bound.table, plan, values, masks, n,
+                        bucket_rows(n, settings.executor.min_batch_rows),
+                        si)
+                if sp.recording:
+                    sp.set(thread=threading.current_thread().name)
+                    if item is None:
+                        sp.set(eof=True)
+                    else:
+                        sp.set(shard_index=int(si), rows=int(n),
+                               bytes=hb.nbytes)
+            if item is None:
+                return
+            yield hb
+    finally:
+        raw.close()
 
 
 def _repad_batch(b: ShardBatch, bucket: int) -> ShardBatch:
@@ -243,30 +322,36 @@ def _run_mesh_round(plan, run, buf: list, n_dev: int, shard_sharding,
     # delay injections here model device-side round latency for the
     # host/device overlap tests (the decode half is decode_batch)
     FAULTS.hit("device_round", plan.bound.table.name)
-    t0_round = clock()
-    n_real = len(buf)
-    bucket = max(b.padded_rows for b in buf)
-    while len(buf) < n_dev:
-        buf.append(empty_batch(plan.bound.table, plan, bucket, -1))
-    buf = [_repad_batch(b, bucket) for b in buf]
-    cols = tuple(np.stack([b.cols[i] for b in buf])
-                 for i in range(len(plan.scan_columns)))
-    valids = tuple(np.stack([b.valids[i] for b in buf])
-                   for i in range(len(plan.scan_columns)))
-    mask = np.stack([b.row_mask for b in buf])
-    dcols = tuple(jax.device_put(c, shard_sharding) for c in cols)
-    dvalids = tuple(jax.device_put(v, shard_sharding) for v in valids)
-    dmask = jax.device_put(mask, shard_sharding)
-    out = run(dcols + p_stack, dvalids + pv_stack, dmask)
-    nbytes = (sum(c.nbytes for c in cols) + sum(v.nbytes for v in valids)
-              + mask.nbytes)
-    if collect is not None:
-        collect.append((dcols, dvalids, dmask))
-    ctx = _trace.current()
-    if ctx is not None:
-        tr, parent = ctx
-        tr.add_closed("device_round", parent.span_id, t0_round, clock(),
-                      {"batches": n_real, "bytes": int(nbytes)})
+    with _trace.span("device_round") as rsp:
+        n_real = len(buf)
+        with _trace.span("stack") as sp:
+            bucket = max(b.padded_rows for b in buf)
+            while len(buf) < n_dev:
+                buf.append(empty_batch(plan.bound.table, plan, bucket, -1))
+            buf = [_repad_batch(b, bucket) for b in buf]
+            cols = tuple(np.stack([b.cols[i] for b in buf])
+                         for i in range(len(plan.scan_columns)))
+            valids = tuple(np.stack([b.valids[i] for b in buf])
+                           for i in range(len(plan.scan_columns)))
+            mask = np.stack([b.row_mask for b in buf])
+            nbytes = _nbytes(cols) + _nbytes(valids) + mask.nbytes
+            if sp.recording:
+                sp.set(bytes=nbytes)
+        with _trace.span("h2d") as sp:
+            dcols = tuple(jax.device_put(c, shard_sharding) for c in cols)
+            dvalids = tuple(jax.device_put(v, shard_sharding)
+                            for v in valids)
+            dmask = jax.device_put(mask, shard_sharding)
+            if sp.recording:
+                sp.set(bytes=nbytes)
+        with _trace.span("dispatch") as sp:
+            out = run(dcols + p_stack, dvalids + pv_stack, dmask)
+            if sp.recording:
+                sp.set(slot="mesh_run")
+        if collect is not None:
+            collect.append((dcols, dvalids, dmask))
+        if rsp.recording:
+            rsp.set(batches=n_real, bytes=nbytes, resident=False)
     return out, nbytes
 
 
@@ -297,22 +382,25 @@ def _run_partials_jax(cat: Catalog, plan: PhysicalPlan, settings: Settings,
         default_mesh, executor_devices, sharded_partial_agg, shard_axis_size,
     )
 
-    pcols, pvalids = params
-    devices = executor_devices()
-    kinds = combine_kinds(plan)
-    pstats = PipelineStats()
-    _trace.set_phase("device")
-
     from citus_tpu.executor.device_cache import GLOBAL_CACHE, plan_cache_key
     from citus_tpu.storage.overlay import current_overlay
 
-    # an open transaction's staged writes change what a scan sees
-    # without bumping table.version — bypass the HBM cache for tables
-    # the transaction touched (other tables still hit it)
-    txn = current_overlay()
-    overlaid = txn is not None and plan.bound.table.name in txn.tables
-    key = plan_cache_key(plan, cat.data_dir)
-    cached = None if overlaid else GLOBAL_CACHE.get(key)
+    pcols, pvalids = params
+    with _trace.span("scan_setup"):
+        devices = executor_devices()
+        kinds = combine_kinds(plan)
+        pstats = PipelineStats()
+        _trace.set_phase("device")
+        # an open transaction's staged writes change what a scan sees
+        # without bumping table.version — bypass the HBM cache for
+        # tables the transaction touched (other tables still hit it)
+        txn = current_overlay()
+        overlaid = txn is not None and plan.bound.table.name in txn.tables
+    with _trace.span("cache_lookup") as sp:
+        key = plan_cache_key(plan, cat.data_dir)
+        cached = None if overlaid else GLOBAL_CACHE.get(key)
+        if sp.recording:
+            sp.set(hit=cached is not None, mesh=False)
     # HBM attribution: resident entries are charged to the tenant whose
     # query pinned them (the shared bucket for non-router scans)
     from citus_tpu.workload import tenant_key
@@ -324,13 +412,17 @@ def _run_partials_jax(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     # mesh machinery when no such entry exists
     if len(devices) > 1 and cached is None:
         from collections import deque
-        mesh = default_mesh()
-        n_dev = shard_axis_size(mesh)
+        with _trace.span("scan_setup"):
+            mesh = default_mesh()
+            n_dev = shard_axis_size(mesh)
         # mesh cache entries are device-sharded stacks — a different
         # structure than the single-device ShardBatch list, so they key
         # separately
         mkey = key + ("mesh", n_dev)
-        mcached = None if overlaid else GLOBAL_CACHE.get(mkey)
+        with _trace.span("cache_lookup") as sp:
+            mcached = None if overlaid else GLOBAL_CACHE.get(mkey)
+            if sp.recording:
+                sp.set(hit=mcached is not None, mesh=True)
         run = get_kernel(
             plan, "mesh_run",
             lambda: sharded_partial_agg(build_worker_fn(plan, jnp), kinds,
@@ -338,14 +430,23 @@ def _run_partials_jax(cat: Catalog, plan: PhysicalPlan, settings: Settings,
             extra=("mesh", n_dev))
         # parameters replicate across the shard axis ([n_dev] stacks of
         # the 0-d values); never cached — they change per execution
-        p_stack = tuple(np.stack([p] * n_dev) for p in pcols)
-        pv_stack = tuple(np.stack([v] * n_dev) for v in pvalids)
+        with _trace.span("bind_params"):
+            p_stack = tuple(np.stack([p] * n_dev) for p in pcols)
+            pv_stack = tuple(np.stack([v] * n_dev) for v in pvalids)
         acc: list = []
         if mcached is not None:
             for dcols, dvalids, dmask in mcached:
-                acc.append(run(dcols + p_stack, dvalids + pv_stack, dmask))
-            return combine_partials_host(
-                plan, [tuple(np.asarray(o) for o in out) for out in acc])
+                with _trace.span("device_round") as rsp:
+                    with _trace.span("dispatch") as sp:
+                        acc.append(run(dcols + p_stack, dvalids + pv_stack,
+                                       dmask))
+                        if sp.recording:
+                            sp.set(slot="mesh_run")
+                    if rsp.recording:
+                        rsp.set(batches=n_dev, resident=True,
+                                bytes=_nbytes(dcols) + _nbytes(dvalids)
+                                + dmask.nbytes)
+            return _fetch_rounds(plan, acc)
         # streaming mesh path: group the lazy host stream into device
         # rounds of n_dev, re-padded to the round's max bucket — the
         # host never materializes more than one round plus the bounded
@@ -362,7 +463,7 @@ def _run_partials_jax(cat: Catalog, plan: PhysicalPlan, settings: Settings,
         t_peek = clock()
         first = next(stream, None)
         if first is None:
-            return combine_partials_host(plan, [_empty_partials(plan, np)])
+            return _combine(plan, [_empty_partials(plan, np)])
         second = next(stream, None)
         pstats.host_decode_s += clock() - t_peek
         if second is None:
@@ -411,9 +512,11 @@ def _run_partials_jax(cat: Catalog, plan: PhysicalPlan, settings: Settings,
                 host_iter_m.close()
             if collect is not None and nbytes <= GLOBAL_CACHE.capacity:
                 _block_ready([r[0] for r in collect])
-                GLOBAL_CACHE.put(mkey, collect, nbytes, tenant=cache_tenant)
+                with _trace.span("cache_put"):
+                    GLOBAL_CACHE.put(mkey, collect, nbytes,
+                                     tenant=cache_tenant)
             t_dev = clock()
-            acc_np = [tuple(np.asarray(o) for o in out) for out in acc]
+            partials = _fetch_rounds(plan, acc)
             pstats.device_s += clock() - t_dev
             pstats.h2d_bytes = nbytes
             GLOBAL_COUNTERS.bump("bytes_scanned", nbytes)
@@ -424,7 +527,7 @@ def _run_partials_jax(cat: Catalog, plan: PhysicalPlan, settings: Settings,
             # split across the round's shard members
             plan.runtime_cache["mesh_task_times"] = mesh_task_times
             pstats.publish(plan)
-            return combine_partials_host(plan, acc_np)
+            return partials
 
     # ---- single-device path: fused streaming pipeline + HBM pinning --
     task_times: list = []
@@ -440,16 +543,27 @@ def _run_partials_jax(cat: Catalog, plan: PhysicalPlan, settings: Settings,
         plan, "jit_fused",
         lambda: jit_compile(build_fused_worker_fn(plan, jnp),
                             donate_argnums=0))
-    acc_dev = tuple(jax.device_put(p) for p in _empty_partials(plan, np))
+    with _trace.span("init_acc") as sp:
+        acc_dev = tuple(jax.device_put(p) for p in _empty_partials(plan, np))
+        if sp.recording:
+            sp.set(arrays=len(acc_dev), bytes=_nbytes(acc_dev))
     n_dispatch = 0
     if cached is not None:
         for b in cached:
-            t0 = clock()
-            acc_dev = fused(acc_dev, b.cols + pcols, b.valids + pvalids,
-                            b.row_mask)
-            n_dispatch += 1
-            task_times.append((b.shard_index, b.n_rows,
-                               clock() - t0))
+            with _trace.span("device_round") as rsp:
+                t0 = clock()
+                with _trace.span("dispatch") as sp:
+                    acc_dev = fused(acc_dev, b.cols + pcols,
+                                    b.valids + pvalids, b.row_mask)
+                    if sp.recording:
+                        sp.set(slot="jit_fused")
+                n_dispatch += 1
+                task_times.append((b.shard_index, b.n_rows,
+                                   clock() - t0))
+                if rsp.recording:
+                    rsp.set(shard_index=int(b.shard_index),
+                            rows=int(b.n_rows), resident=True,
+                            bytes=b.nbytes)
     else:
         # stream: decompress batch i+1 on the host and transfer it while
         # batch i computes — double-buffering: the H2D copy stream and
@@ -476,60 +590,65 @@ def _run_partials_jax(cat: Catalog, plan: PhysicalPlan, settings: Settings,
                                      pstats)
         try:
             for hb in host_iter:
-                t_dev = clock()
-                FAULTS.hit("device_round", plan.bound.table.name)
-                db = ShardBatch(tuple(jax.device_put(c) for c in hb.cols),
-                                tuple(jax.device_put(v) for v in hb.valids),
-                                jax.device_put(hb.row_mask), hb.n_rows,
-                                hb.padded_rows, hb.shard_index)
-                t0 = clock()
-                acc_dev = fused(acc_dev, db.cols + pcols,
-                                db.valids + pvalids, db.row_mask)
-                n_dispatch += 1
-                task_times.append((db.shard_index, db.n_rows,
-                                   clock() - t0))
-                bb = (sum(c.nbytes for c in hb.cols)
-                      + sum(v.nbytes for v in hb.valids)
-                      + hb.row_mask.nbytes)
-                nbytes += bb
-                task_bytes.append((db.shard_index, bb))
-                if collect is not None:
-                    collect.append(db)
-                    if nbytes > GLOBAL_CACHE.capacity:
-                        collect = None  # working set exceeds HBM cache
-                if collect is None:
-                    # bound in-flight device memory: the accumulator
-                    # chain orders every fused round, so syncing the
-                    # current registers retires all admitted batches —
-                    # at most `depth` batches are ever un-synced (the
-                    # double-buffer window the peak-HBM test bounds)
-                    window_bytes += bb
-                    window_peak = max(window_peak, window_bytes)
-                    since_sync += 1
-                    if since_sync >= depth:
-                        _block_ready(acc_dev)
-                        since_sync = 0
-                        window_bytes = 0
-                pstats.device_s += clock() - t_dev
-                ctx = _trace.current()
-                if ctx is not None:
-                    tr, parent = ctx
-                    tr.add_closed(
-                        "device_round", parent.span_id, t_dev, clock(),
-                        {"shard_index": int(hb.shard_index),
-                         "rows": int(hb.n_rows)})
+                with _trace.span("device_round") as rsp:
+                    t_dev = clock()
+                    FAULTS.hit("device_round", plan.bound.table.name)
+                    bb = hb.nbytes
+                    with _trace.span("h2d") as sp:
+                        db = ShardBatch(
+                            tuple(jax.device_put(c) for c in hb.cols),
+                            tuple(jax.device_put(v) for v in hb.valids),
+                            jax.device_put(hb.row_mask), hb.n_rows,
+                            hb.padded_rows, hb.shard_index)
+                        if sp.recording:
+                            sp.set(bytes=bb)
+                    t0 = clock()
+                    with _trace.span("dispatch") as sp:
+                        acc_dev = fused(acc_dev, db.cols + pcols,
+                                        db.valids + pvalids, db.row_mask)
+                        if sp.recording:
+                            sp.set(slot="jit_fused")
+                    n_dispatch += 1
+                    task_times.append((db.shard_index, db.n_rows,
+                                       clock() - t0))
+                    nbytes += bb
+                    task_bytes.append((db.shard_index, bb))
+                    if collect is not None:
+                        collect.append(db)
+                        if nbytes > GLOBAL_CACHE.capacity:
+                            collect = None  # working set exceeds HBM cache
+                    if collect is None:
+                        # bound in-flight device memory: the accumulator
+                        # chain orders every fused round, so syncing the
+                        # current registers retires all admitted batches
+                        # — at most `depth` batches are ever un-synced
+                        # (the double-buffer window the peak-HBM test
+                        # bounds)
+                        window_bytes += bb
+                        window_peak = max(window_peak, window_bytes)
+                        since_sync += 1
+                        if since_sync >= depth:
+                            _block_ready(acc_dev)
+                            since_sync = 0
+                            window_bytes = 0
+                    pstats.device_s += clock() - t_dev
+                    if rsp.recording:
+                        rsp.set(shard_index=int(hb.shard_index),
+                                rows=int(hb.n_rows), bytes=bb,
+                                resident=False)
         finally:
             host_iter.close()
         if n_dispatch == 0:
-            return combine_partials_host(plan, [_empty_partials(plan, np)])
+            return _combine(plan, [_empty_partials(plan, np)])
         if collect is not None:
             _block_ready([b.cols for b in collect])
-            GLOBAL_CACHE.put(key, collect, nbytes, tenant=cache_tenant)
+            with _trace.span("cache_put"):
+                GLOBAL_CACHE.put(key, collect, nbytes, tenant=cache_tenant)
         pstats.h2d_bytes = nbytes
         GLOBAL_COUNTERS.bump("bytes_scanned", nbytes)
         GLOBAL_COUNTERS.bump("device_hbm_touched_bytes", nbytes)
         t_dev = clock()
-        partials = tuple(np.asarray(o) for o in jax.device_get(acc_dev))
+        partials = _fetch_acc(acc_dev)
         pstats.device_s += clock() - t_dev
         pstats.publish(plan)
         GLOBAL_COUNTERS.bump("fused_dispatches", n_dispatch)
@@ -544,7 +663,7 @@ def _run_partials_jax(cat: Catalog, plan: PhysicalPlan, settings: Settings,
         n_dispatch
     plan.runtime_cache["task_times"] = task_times
     plan.runtime_cache["task_bytes"] = task_bytes
-    return tuple(np.asarray(o) for o in jax.device_get(acc_dev))
+    return _fetch_acc(acc_dev)
 
 
 def _decode_direct_keys(plan: PhysicalPlan, rows: np.ndarray):
@@ -573,7 +692,11 @@ def _run_agg(cat: Catalog, plan: PhysicalPlan, settings: Settings,
         # associative, so the split changes nothing in the result.
         from citus_tpu.executor.pipeline import dispatch_remote_tasks
         run = _run_partials_cpu if backend == "cpu" else _run_partials_jax
-        local, dispatch = dispatch_remote_tasks(cat, plan, settings, params)
+        with _trace.span("remote_dispatch") as sp:
+            local, dispatch = dispatch_remote_tasks(cat, plan, settings,
+                                                    params)
+            if sp.recording:
+                sp.set(tasks=len(plan.shard_indexes) - len(local))
         run_plan = plan
         if local != plan.shard_indexes:
             import dataclasses
@@ -583,7 +706,10 @@ def _run_agg(cat: Catalog, plan: PhysicalPlan, settings: Settings,
         except BaseException:
             dispatch.abort()  # no RPC thread outlives the attempt
             raise
-        fallback, remote_partials = dispatch.collect()
+        with _trace.span("remote_dispatch") as sp:
+            fallback, remote_partials = dispatch.collect()
+            if sp.recording:
+                sp.set(tasks=len(remote_partials), fallback=len(fallback))
         if fallback:
             import dataclasses
             tt = list(plan.runtime_cache.get("task_times", []))
@@ -596,21 +722,27 @@ def _run_agg(cat: Catalog, plan: PhysicalPlan, settings: Settings,
             plan.runtime_cache["task_bytes"] = (
                 tb + list(plan.runtime_cache.get("task_bytes", [])))
         if remote_partials:
-            partials = combine_partials_host(
-                plan, [partials, *remote_partials])
-        if mode == "scalar":
-            # one group: scalars become length-1 arrays; vector partials
-            # (HLL registers) gain a leading group axis
-            partials = tuple(
-                np.asarray(p).reshape(1) if np.asarray(p).ndim == 0
-                else np.asarray(p)[None, ...] for p in partials)
-            return finalize_groups(plan, cat, [], partials, params_env=penv)
-        *parts, rows = partials
-        keys, occupied = _decode_direct_keys(plan, rows)
-        if occupied.size == 0:
-            return []
-        sel_parts = tuple(np.asarray(p)[occupied] for p in parts)
-        return finalize_groups(plan, cat, keys, sel_parts, params_env=penv)
+            partials = _combine(plan, [partials, *remote_partials])
+        with _trace.span("finalize_groups") as sp:
+            if mode == "scalar":
+                # one group: scalars become length-1 arrays; vector
+                # partials (HLL registers) gain a leading group axis
+                partials = tuple(
+                    np.asarray(p).reshape(1) if np.asarray(p).ndim == 0
+                    else np.asarray(p)[None, ...] for p in partials)
+                out = finalize_groups(plan, cat, [], partials,
+                                      params_env=penv)
+            else:
+                *parts, rows = partials
+                keys, occupied = _decode_direct_keys(plan, rows)
+                if occupied.size == 0:
+                    return []
+                sel_parts = tuple(np.asarray(p)[occupied] for p in parts)
+                out = finalize_groups(plan, cat, keys, sel_parts,
+                                      params_env=penv)
+            if sp.recording:
+                sp.set(groups=len(out))
+            return out
     # unbounded-cardinality GROUP BY: per-shard hash tables merge on the
     # host, so the whole strategy renders as one host_agg span
     with _trace.span("host_agg", shards=len(plan.shard_indexes)):
@@ -706,37 +838,42 @@ def _stream_hash_batches(cat: Catalog, plan: PhysicalPlan, settings: Settings,
                                  read_ahead_depth(settings), pstats)
     try:
         for hb in host_iter:
-            t_dev = clock()
-            FAULTS.hit("device_round", plan.bound.table.name)
-            db = ShardBatch(tuple(jax.device_put(c) for c in hb.cols),
-                            tuple(jax.device_put(v) for v in hb.valids),
-                            jax.device_put(hb.row_mask), hb.n_rows,
-                            hb.padded_rows, hb.shard_index)
-            t0 = clock()
-            state, spill = fused(state, db.cols + pcols,
-                                 db.valids + pvalids, db.row_mask)
-            hs["n_dispatch"] += 1
-            hs["task_times"].append((db.shard_index, db.n_rows, clock() - t0))
-            bb = (sum(c.nbytes for c in hb.cols)
-                  + sum(v.nbytes for v in hb.valids) + hb.row_mask.nbytes)
-            hs["nbytes"] += bb
-            hs["task_bytes"].append((db.shard_index, bb))
-            pending.append((hb, spill))
-            window_bytes += bb
-            hs["window_peak"] = max(hs["window_peak"], window_bytes)
-            since_sync += 1
-            if since_sync >= depth:
-                _block_ready(state)
-                _drain()
-                since_sync = 0
-                window_bytes = 0
-            pstats.device_s += clock() - t_dev
-            ctx = _trace.current()
-            if ctx is not None:
-                tr, parent = ctx
-                tr.add_closed("device_round", parent.span_id, t_dev, clock(),
-                              {"shard_index": int(hb.shard_index),
-                               "rows": int(hb.n_rows)})
+            with _trace.span("device_round") as rsp:
+                t_dev = clock()
+                FAULTS.hit("device_round", plan.bound.table.name)
+                bb = hb.nbytes
+                with _trace.span("h2d") as sp:
+                    db = ShardBatch(
+                        tuple(jax.device_put(c) for c in hb.cols),
+                        tuple(jax.device_put(v) for v in hb.valids),
+                        jax.device_put(hb.row_mask), hb.n_rows,
+                        hb.padded_rows, hb.shard_index)
+                    if sp.recording:
+                        sp.set(bytes=bb)
+                t0 = clock()
+                with _trace.span("dispatch") as sp:
+                    state, spill = fused(state, db.cols + pcols,
+                                         db.valids + pvalids, db.row_mask)
+                    if sp.recording:
+                        sp.set(slot="jit_hash_fused")
+                hs["n_dispatch"] += 1
+                hs["task_times"].append((db.shard_index, db.n_rows,
+                                         clock() - t0))
+                hs["nbytes"] += bb
+                hs["task_bytes"].append((db.shard_index, bb))
+                pending.append((hb, spill))
+                window_bytes += bb
+                hs["window_peak"] = max(hs["window_peak"], window_bytes)
+                since_sync += 1
+                if since_sync >= depth:
+                    _block_ready(state)
+                    _drain()
+                    since_sync = 0
+                    window_bytes = 0
+                pstats.device_s += clock() - t_dev
+                if rsp.recording:
+                    rsp.set(shard_index=int(hb.shard_index),
+                            rows=int(hb.n_rows), bytes=bb, resident=False)
     finally:
         host_iter.close()
     _drain()
@@ -823,7 +960,9 @@ def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
                     merge_hash_tables_into(acc, plan, sk, sp, sr)
                 GLOBAL_COUNTERS.bump("hash_partials_pushed")
     t_dev = clock()
-    fetched = jax.device_get(state)
+    _block_ready(state)
+    with _trace.span("fetch"):
+        fetched = jax.device_get(state)
     pstats.device_s += clock() - t_dev
     h_keys = [(np.asarray(kv), np.asarray(kf)) for kv, kf in fetched[0]]
     h_partials = tuple(np.asarray(p) for p in fetched[1])
@@ -1086,8 +1225,13 @@ def execute_select(cat: Catalog, bound: BoundSelect, settings: Settings,
     t0 = clock()
     _guard_remote_written(cat, [bound.table.name])
     if plan is None:
-        plan = plan_select(cat, bound, direct_limit=settings.planner.direct_gid_limit)
-    params = encode_params(cat, bound, param_values)
+        with _trace.span("plan_physical"):
+            plan = plan_select(
+                cat, bound, direct_limit=settings.planner.direct_gid_limit)
+    with _trace.span("bind_params") as sp:
+        params = encode_params(cat, bound, param_values)
+        if sp.recording:
+            sp.set(params=len(params[0]))
     _exec_span = _trace.span("execute")
     _exec_span.__enter__()
     try:
@@ -1198,11 +1342,12 @@ def _finish_select(bound: BoundSelect, plan: PhysicalPlan, rows: list[tuple],
     mesh_times = plan.runtime_cache.pop("mesh_task_times", [])
     from citus_tpu.observability.load_attribution import GLOBAL_ATTRIBUTION
     from citus_tpu.workload import tenant_key
-    GLOBAL_ATTRIBUTION.book_query(
-        bound.table, tenant_key(plan.router_key),
-        task_times + mesh_times, task_bytes,
-        len(rows), remote_tasks,
-        head_si=plan.shard_indexes[0] if plan.shard_indexes else None)
+    with _trace.span("book_stats"):
+        GLOBAL_ATTRIBUTION.book_query(
+            bound.table, tenant_key(plan.router_key),
+            task_times + mesh_times, task_bytes,
+            len(rows), remote_tasks,
+            head_si=plan.shard_indexes[0] if plan.shard_indexes else None)
     explain = {
         "strategy": plan.group_mode.kind if bound.has_aggs else "projection",
         "shards": len(plan.shard_indexes),

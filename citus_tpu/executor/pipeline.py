@@ -137,6 +137,9 @@ class HostPrefetcher:
         # the transaction overlay is thread-local: the decode thread
         # must see the consumer's staged writes, not a bare snapshot
         self._txn = current_overlay()
+        # so is the trace context: the decode thread's spans hang under
+        # the span the consumer had open (the query's ``execute``)
+        self._trace_ctx = _trace.capture()
         self._thread = threading.Thread(target=self._produce, daemon=True,
                                         name="citus-host-decode")
         self._finished = False
@@ -159,7 +162,11 @@ class HostPrefetcher:
     def _produce(self) -> None:
         from citus_tpu.storage.overlay import transaction_overlay
         with transaction_overlay(self._txn):
-            self._produce_inner()
+            if self._trace_ctx is None:
+                self._produce_inner()
+            else:
+                with _trace.activate(*self._trace_ctx):
+                    self._produce_inner()
 
     def _produce_inner(self) -> None:
         try:

@@ -3,8 +3,10 @@
 - Chrome trace-event JSON: one file per sampled query under
   ``citus.trace_export_dir``; loads directly in Perfetto / chrome://
   tracing.  Coordinator spans render as process 1, every remote host's
-  grafted ``execute_task`` subtree as its own process row, and each
-  event's args carry span_id/parent_id so the tree survives the format.
+  grafted ``execute_task`` subtree as its own process row, each thread
+  (the caller's, the decode thread's) as its own row within it, and
+  each event's args carry span_id/parent_id so the tree survives the
+  format.
 - Prometheus text exposition: all StatCounters as counters, cache
   occupancy as gauges, and per-query-family latency histograms from
   ``QueryStats`` (scripts/metrics_exporter.py + SHOW citus.metrics).
@@ -28,6 +30,8 @@ def chrome_trace_events(trace) -> dict:
     ("X" complete events, ts/dur in microseconds)."""
     events = []
     pids = {COORD_PID: "coordinator"}
+    # small stable thread rows: 1 is the thread that opened the trace
+    tids: dict = {}
     for s in trace.spans:
         t1 = s.t1 if s.t1 is not None else s.t0
         host = s.attrs.get("host")
@@ -48,14 +52,15 @@ def chrome_trace_events(trace) -> dict:
             "ts": round((trace.t0_wall + (s.t0 - trace.t0)) * 1e6, 3),
             "dur": round(max(0.0, t1 - s.t0) * 1e6, 3),
             "pid": pid,
-            "tid": 1,
+            "tid": tids.setdefault((pid, s.tid), len(tids) + 1),
             "args": args,
         })
     for pid, name in sorted(pids.items()):
         events.append({"name": "process_name", "ph": "M", "pid": pid,
                        "tid": 1, "args": {"name": name}})
     return {"traceEvents": events,
-            "otherData": {"trace_id": trace.trace_id}}
+            "otherData": {"trace_id": trace.trace_id,
+                          "thread_rows": len(tids)}}
 
 
 def write_chrome_trace(trace, export_dir: str) -> str:

@@ -21,8 +21,13 @@ This module is ALSO the package's single span-timing clock: every
 subsystem times through ``clock`` (CI-enforced — no other module under
 citus_tpu/ may call time.perf_counter).
 
-On close, spans fold their duration into StatCounters deltas so the
-aggregate view (citus_stat_counters) stays consistent with the trees.
+Every span records the thread it ran on (``tid``): a span's self time
+is its duration minus what its children ON THE SAME THREAD cover — the
+decode thread's ``decode_batch`` spans run beside the consumer's spans,
+not inside them.  While a context-managed span is open it also holds a
+``jax.profiler.TraceAnnotation("citus." + name)``, so a profiler
+session (``Cluster.profile()``, the benchmark's ``--trace 1``) shows
+the same tree on the profiler's clock, under the device ops.
 """
 
 from __future__ import annotations
@@ -56,26 +61,49 @@ def _counters():
     return GLOBAL_COUNTERS
 
 
-#: span name -> StatCounters bucket its duration folds into on close
-#: (keeps citus_stat_counters consistent with the trees; names here
-#: satisfy the dead-counter lint by construction)
-_SPAN_MS = {
-    "parse": "span_parse_ms",
-    "plan": "span_plan_ms",
-    "execute": "span_execute_ms",
-    "finalize": "span_finalize_ms",
-    "remote_task": "span_remote_task_ms",
-    "megabatch": "span_megabatch_ms",
-}
+#: ``jax.profiler.TraceAnnotation``, imported at the first real span so
+#: this module loads without JAX; False when JAX has no profiler.  An
+#: annotation costs a flag test while no profiler session runs.
+_annotation_cls = None
+
+
+def _enter_annotation(name: str):
+    """Open ``citus.<name>`` on the profiler's clock for this thread;
+    -> the entered annotation (leave it with ``__exit__``) or None."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        try:
+            from jax.profiler import TraceAnnotation as cls
+        except ImportError:
+            cls = False
+        _annotation_cls = cls
+    if not cls:
+        return None
+    ann = cls("citus." + name)
+    ann.__enter__()
+    return ann
+
+
+@contextlib.contextmanager
+def bare_annotation(name: str):
+    """A ``citus.<name>`` annotation that belongs to no trace: the
+    tracing's own work after the root closed (the export)."""
+    ann = _enter_annotation(name)
+    try:
+        yield
+    finally:
+        if ann is not None:
+            ann.__exit__(None, None, None)
 
 
 class Span:
     """One timed node of a trace.  Context manager: ``__enter__``
-    activates it for the current thread, ``__exit__`` closes it (and
-    folds the duration into the counters)."""
+    activates it for the current thread and opens its profiler
+    annotation, ``__exit__`` leaves both and closes it."""
 
     __slots__ = ("name", "span_id", "parent_id", "t0", "t1", "attrs",
-                 "_trace")
+                 "tid", "_trace", "_ann")
 
     def __init__(self, trace: "Trace", name: str,
                  parent_id: Optional[str], attrs: dict):
@@ -85,6 +113,8 @@ class Span:
         self.name = name
         self.span_id = os.urandom(4).hex()
         self.parent_id = parent_id
+        self.tid = threading.get_ident()
+        self._ann = None
         self.t0 = clock()
         self.t1: Optional[float] = None
         self.attrs = attrs
@@ -104,9 +134,13 @@ class Span:
 
     def __enter__(self) -> "Span":
         _stack().append((self._trace, self))
+        self._ann = _enter_annotation(self.name)
         return self
 
     def __exit__(self, *exc) -> bool:
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
         st = _stack()
         if st and st[-1][1] is self:
             st.pop()
@@ -163,11 +197,6 @@ class Trace:
         if s.t1 is not None:
             return
         s.t1 = end if end is not None else clock()
-        c = _counters()
-        c.bump("trace_spans_recorded")
-        bucket = _SPAN_MS.get(s.name)
-        if bucket is not None:
-            c.bump(bucket, max(1, int((s.t1 - s.t0) * 1000)))
 
     def add_closed(self, name: str, parent_id: Optional[str],
                    t0: float, t1: float,
@@ -207,7 +236,7 @@ class Trace:
         to this trace's start (the coordinator re-anchors on graft)."""
         with self._mu:
             return [{"name": s.name, "sid": s.span_id, "pid": s.parent_id,
-                     "t0": s.t0 - self.t0,
+                     "tid": s.tid, "t0": s.t0 - self.t0,
                      "t1": (s.t1 if s.t1 is not None else clock()) - self.t0,
                      "attrs": dict(s.attrs)} for s in self.spans]
 
@@ -230,6 +259,7 @@ class Trace:
         for d in span_dicts:
             s = Span(self, str(d["name"]), d["pid"], dict(d["attrs"]))
             s.span_id = str(d["sid"])
+            s.tid = int(d.get("tid", 0))
             s.t0 = base + float(d["t0"])
             s.t1 = base + float(d["t1"])
             grafted.append(s)
@@ -338,18 +368,17 @@ class QueryTrace:
         return bool(self.trace.reasons & {"rate", "forced"})
 
     def enter(self) -> None:
-        _stack().append((self.trace, self.root))
+        self.root.__enter__()
         self._entered = True
 
     def finish(self) -> float:
-        """Close the root, restore the thread context; returns the
+        """Close the root (the last span of the trace to close), restore
+        the thread context and book the trace's span count; returns the
         query duration in ms."""
         if self._entered:
-            st = _stack()
-            if st and st[-1] == (self.trace, self.root):
-                st.pop()
             self._entered = False
-        self.trace.close_span(self.root)
+            self.root.__exit__(None, None, None)
+            _counters().bump("trace_spans_recorded", len(self.trace.spans))
         return (self.root.t1 - self.root.t0) * 1000.0
 
 

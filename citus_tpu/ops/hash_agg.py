@@ -195,7 +195,9 @@ def build_fused_hash_worker(plan: PhysicalPlan, xp,
     partial_ops = plan.partial_ops
     key_dtypes = tuple(np.dtype(d) for d in key_dtypes)
 
-    def fused(table_state, cols, valids, row_mask):
+    # named for its kernel slot: the XLA module in a device trace is
+    # jit_hash_fused, apart from the scan kernel's jit_fused
+    def hash_fused(table_state, cols, valids, row_mask):
         key_tables, partials, rows = table_state
         key_tables = list(key_tables)
         env = {n: (c, v) for n, c, v in zip(names, cols, valids)}
@@ -232,7 +234,7 @@ def build_fused_hash_worker(plan: PhysicalPlan, xp,
                             else prior.at[slot].max(upd))
         rows = rows.at[slot].add(xp.where(placed, 1, 0).astype(np.int64))
         return (tuple(key_tables), tuple(outs), rows), spill
-    return fused
+    return hash_fused
 
 
 def build_fused_entry_merge(plan: PhysicalPlan, xp,

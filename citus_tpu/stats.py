@@ -69,16 +69,11 @@ class StatCounters:
         "device_cache_misses",
         "device_cache_evicted_bytes",
         # distributed tracing (observability/): sampled query roots,
-        # spans recorded, slow-ring entries, and per-phase wall time
-        # folded from span close (observability/trace.py _SPAN_MS)
+        # spans recorded (booked once per trace, at its close) and
+        # slow-ring entries
         "trace_queries_sampled",
         "trace_spans_recorded",
         "slow_queries_logged",
-        "span_parse_ms",
-        "span_plan_ms",
-        "span_execute_ms",
-        "span_finalize_ms",
-        "span_remote_task_ms",
         # cross-host ingest routed through the data plane (cluster.py)
         "rows_ingested_remote",
         # data-plane connection pool: send/recv/connect failures that
@@ -103,12 +98,10 @@ class StatCounters:
         "wait_megabatch_ms",
         # same-family query coalescing (executor/megabatch.py):
         # queries that rode a batch, device dispatches issued for them,
-        # and groups that fell back to the serial path; span_megabatch_ms
-        # folds each query's enqueue->scatter stretch from its trace span
+        # and groups that fell back to the serial path
         "megabatch_queries",
         "megabatch_batches",
         "megabatch_fallbacks",
-        "span_megabatch_ms",
         # cluster stat fan-out (observability/cluster_stats.py): probes
         # issued and per-node failures degraded to node_unreachable rows
         "stat_fanout_probes",
@@ -248,9 +241,12 @@ class StatCounters:
 # branch brackets it with begin_wait/end_wait.  The event name feeds the
 # activity view's wait_event column through a thread-local sink stack
 # (mirroring trace.py's phase sinks — nested execute() restores), and
-# the blocked wall time folds into a cumulative wait_*_ms counter.  The
-# seam costs nothing on non-blocking paths: call sites only reach it
-# AFTER the fast path (queue non-empty, lock granted first try) failed.
+# the blocked wall time folds into a cumulative wait_*_ms counter.  On a
+# sampled query the bracket is also a span ``wait:<event>`` (and so an
+# annotation on the profiler's clock): every wait shows in the trace
+# with no call site of its own.  The seam costs nothing on non-blocking
+# paths: call sites only reach it AFTER the fast path (queue non-empty,
+# lock granted first try) failed.
 
 #: registered wait events -> their cumulative counters.  cituslint CNT03
 #: cross-checks every begin_wait("...") literal in the package against
@@ -281,6 +277,9 @@ WAIT_COUNTERS = {
 }
 
 WAIT_EVENTS = tuple(sorted(WAIT_COUNTERS))
+
+#: span name of each event, built once: the unsampled path formats nothing
+_WAIT_SPANS = {event: "wait:" + event for event in WAIT_COUNTERS}
 
 _wait_tls = threading.local()
 
@@ -318,16 +317,17 @@ def begin_wait(event: str):
             pass
     if _san._ACTIVE:  # one attribute read when the sanitizer is off
         _san.on_begin_wait(event)
-    from citus_tpu.observability.trace import clock
-    return event, clock()
+    from citus_tpu.observability import trace
+    return event, trace.clock(), trace.span(_WAIT_SPANS[event]).__enter__()
 
 
 def end_wait(token) -> float:
     """Close a begin_wait() bracket: clear the backend's wait_event and
     fold the blocked wall time into the event's counter.  Returns ms."""
-    event, t0 = token
+    event, t0, span = token
     from citus_tpu.observability.trace import clock
     ms = (clock() - t0) * 1000.0
+    span.__exit__(None, None, None)
     _counters().bump(WAIT_COUNTERS[event], max(1, int(ms)))
     sinks = getattr(_wait_tls, "sinks", None)
     if sinks:

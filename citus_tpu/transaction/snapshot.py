@@ -110,18 +110,22 @@ def flip_generation(data_dir: str, table_meta):
 def read_generation(data_dir: str, table_meta) -> tuple[int, bool]:
     """Reader side: (generation, flip_in_progress).  Reaps dead
     writers' registrations under the micro-flock."""
+    from citus_tpu.observability import trace as _trace
     from citus_tpu.utils.filelock import FileLock
     res = group_resource(table_meta)
     path, lock = _snap_paths(data_dir, res)
-    st = _load(path)
-    if not st["writers"]:
-        return st["gen"], False
-    # somebody mid-flip: reap the dead before reporting busy
-    with FileLock(lock):
+    # a file read before and after every scan: named, so a resident
+    # query's trace shows it apart from the scan
+    with _trace.span("snapshot_check"):
         st = _load(path)
-        if _reap_dead(st):
-            _store(path, st)
-    return st["gen"], bool(st["writers"])
+        if not st["writers"]:
+            return st["gen"], False
+        # somebody mid-flip: reap the dead before reporting busy
+        with FileLock(lock):
+            st = _load(path)
+            if _reap_dead(st):
+                _store(path, st)
+        return st["gen"], bool(st["writers"])
 
 
 def snapshot_read_multi(data_dir: str, tables, attempt_fn, *,

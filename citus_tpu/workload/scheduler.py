@@ -47,6 +47,7 @@ from collections import deque
 from typing import Optional
 
 from citus_tpu.errors import AdmissionShedError, ExecutionError
+from citus_tpu.observability import trace as _trace
 from citus_tpu.stats import LatencyHistogram, begin_wait, end_wait
 from citus_tpu.utils.clock import now as wall_now
 from citus_tpu.workload.registry import (
@@ -308,7 +309,8 @@ class TenantScheduler:
 
         @contextlib.contextmanager
         def _ctx():
-            self.acquire(settings, tenant, timeout=timeout)
+            with _trace.span("admission"):
+                self.acquire(settings, tenant, timeout=timeout)
             try:
                 yield
             finally:

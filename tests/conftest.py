@@ -44,6 +44,18 @@ def devices():
     return jax.devices()
 
 
+@pytest.fixture()
+def limit_devices(monkeypatch):
+    """``limit_devices(n)``: run the executor on the first ``n`` of the
+    harness's 8 virtual devices — 1 takes the single-device scan loops,
+    4 the mesh loops."""
+    def limit(n):
+        from citus_tpu.parallel import mesh
+        devs = jax.devices()[:n]
+        monkeypatch.setattr(mesh, "executor_devices", lambda: devs)
+    return limit
+
+
 @pytest.fixture(scope="session", autouse=True)
 def _sanitizer_teardown_gate():
     """When the suite runs under CITUS_SANITIZE, an empty

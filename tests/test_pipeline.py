@@ -297,3 +297,122 @@ def test_prefetch_depth_guc_roundtrip(tmp_cluster):
         "SHOW citus.max_adaptive_executor_pool_size").rows == [("16",)]
     cl.execute("SET citus.max_tasks_in_flight = 4")
     assert cl.execute("SHOW citus.max_tasks_in_flight").rows == [("4",)]
+
+
+# ------------------------------------------------ spans of the decode thread
+#
+# Structure and counts only (no duration, no ordering of two threads).
+
+
+def _streaming_table(cl, name, n=20000, shards=8):
+    cl.execute(f"CREATE TABLE {name} (k bigint NOT NULL, v bigint)")
+    cl.execute(f"SELECT create_distributed_table('{name}', 'k', {shards})")
+    cl.copy_from(name, columns={"k": np.arange(n), "v": np.arange(n) * 3})
+    return [(n, 3 * n * (n - 1) // 2)]
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_streaming_scan_spans_cover_both_threads(tmp_cluster, limit_devices,
+                                                 n_dev):
+    """A streaming scan's trace: decode_batch spans from the decode
+    thread hang under the query's execute, each with one stripe_read,
+    concat and pad; every device_round holds h2d + dispatch; the stall
+    the consumer sat in is a wait:prefetch_stall span from the seam."""
+    from citus_tpu.observability import trace as T
+    limit_devices(n_dev)
+    cl = tmp_cluster
+    exp = _streaming_table(cl, "sp")
+    q = "SELECT count(*), sum(v) FROM sp"
+    assert cl.execute(q).rows == exp                 # compile
+    cl.execute("SET citus.executor_prefetch_depth = 2")
+    cl.execute("SET citus.trace_sample_rate = 1.0")
+    GLOBAL_CACHE.clear()
+    FAULTS.arm("decode_batch", delay_s=0.02, match="sp")
+    try:
+        assert cl.execute(q).rows == exp
+    finally:
+        FAULTS.disarm()
+    tr = T.last_trace()
+    by_id = {s.span_id: s for s in tr.spans}
+    assert [s for s in tr.spans if s.parent_id not in by_id] == [tr.root()]
+    assert all(s.t1 is not None for s in tr.spans)
+    root, ex = tr.root(), tr.find("execute")
+    kids = {}
+    for s in tr.spans:
+        kids.setdefault(s.parent_id, []).append(s.name)
+    batches = [s for s in tr.find_all("decode_batch")
+               if not s.attrs.get("eof")]
+    assert len(batches) == 8                         # one per shard
+    assert len(tr.find_all("decode_batch")) == 9     # and the last pull
+    for s in batches:
+        assert s.parent_id == ex.span_id
+        assert kids[s.span_id] == ["stripe_read", "concat", "pad"]
+        assert s.attrs["rows"] > 0 and s.attrs["bytes"] > 0
+    other = [s for s in batches if s.tid != root.tid]
+    # the mesh loop peeks two batches on its own thread before it
+    # starts the decode thread; the single-device loop none
+    assert len(other) == (8 if n_dev == 1 else 6)
+    assert {s.attrs["thread"] for s in other} == {"citus-host-decode"}
+    assert len({s.tid for s in other}) == 1
+    rounds = tr.find_all("device_round")
+    assert len(rounds) == (8 if n_dev == 1 else 2)
+    for r in rounds:
+        assert r.parent_id == ex.span_id and r.attrs["resident"] is False
+        assert kids[r.span_id][:3] == (["h2d", "dispatch"] if n_dev == 1 else
+                                       ["stack", "h2d", "dispatch"])[:3]
+    stalls = tr.find_all("wait:prefetch_stall")
+    assert stalls and all(s.parent_id == ex.span_id and s.tid == root.tid
+                          for s in stalls)
+    assert cl.counters.snapshot()["wait_prefetch_stall_ms"] > 0
+
+
+def test_decode_thread_spans_are_closed_when_the_consumer_dies(
+        tmp_cluster, limit_devices):
+    """No span is held open across a generator's yield: when the
+    consumer fails mid-scan and HostPrefetcher.close() has returned,
+    every span the decode thread opened is closed, and the decode
+    thread's next query starts from an empty span stack."""
+    from citus_tpu.observability import trace as T
+    limit_devices(1)
+    cl = tmp_cluster
+    exp = _streaming_table(cl, "sd")
+    q = "SELECT count(*), sum(v) FROM sd"
+    assert cl.execute(q).rows == exp
+    cl.execute("SET citus.trace_sample_rate = 1.0")
+    GLOBAL_CACHE.clear()
+    FAULTS.arm("device_round", error=RuntimeError("chip fell off"),
+               match="sd", after=2)
+    try:
+        with pytest.raises(Exception, match="chip fell off"):
+            cl.execute(q)
+    finally:
+        FAULTS.disarm()
+    tr = T.last_trace()
+    assert tr.find("decode_batch") is not None
+    assert all(s.t1 is not None for s in tr.spans), \
+        [s.name for s in tr.spans if s.t1 is None]
+    assert tr.root().t1 == max(s.t1 for s in tr.spans)
+    assert T.current() is None
+    GLOBAL_CACHE.clear()
+    assert cl.execute(q).rows == exp
+    ids = {s.span_id for s in T.last_trace().spans}
+    assert all(s.parent_id in ids for s in T.last_trace().spans
+               if s.parent_id is not None)
+
+
+def test_inline_decode_has_the_same_spans_on_the_callers_thread(
+        tmp_cluster, limit_devices):
+    from citus_tpu.observability import trace as T
+    limit_devices(1)
+    cl = tmp_cluster
+    exp = _streaming_table(cl, "si")
+    cl.execute("SET citus.executor_prefetch_depth = 0")
+    cl.execute("SET citus.trace_sample_rate = 1.0")
+    GLOBAL_CACHE.clear()
+    assert cl.execute("SELECT count(*), sum(v) FROM si").rows == exp
+    tr = T.last_trace()
+    batches = [s for s in tr.find_all("decode_batch")
+               if not s.attrs.get("eof")]
+    assert len(batches) == 8
+    assert {s.tid for s in tr.spans} == {tr.root().tid}
+    assert not tr.find_all("wait:prefetch_stall")

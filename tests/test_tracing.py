@@ -236,3 +236,204 @@ def test_two_pc_spans_on_cross_host_write(pair):
     names = {s.name for s in tr.spans}
     assert "2pc_prepare" in names and "2pc_commit_point" in names, names
     assert "2pc_decide" in names, names
+
+
+# ------------------------------------------- spans at every layer boundary
+#
+# Structure and counts only: no test here asserts a duration, a share or
+# an ordering of two threads.
+
+
+@pytest.fixture()
+def cl8(tmp_path):
+    """Eight shards with rows in each: 8 cached batches on one device,
+    2 cached rounds on a mesh of 4."""
+    c = ct.Cluster(str(tmp_path / "db8"))
+    c.execute("CREATE TABLE t (k bigint NOT NULL, v bigint, c text)")
+    c.execute("SELECT create_distributed_table('t', 'k', 8)")
+    c.copy_from("t", columns={"k": np.arange(4000),
+                              "v": np.arange(4000) * 2,
+                              "c": ["x", "y", "z", "x"] * 1000})
+    yield c
+    c.close()
+
+
+def tree(tr):
+    """-> (by_id, children-by-parent-id), after checking that the trace
+    has one root and no orphan and that every span is closed."""
+    by_id = {s.span_id: s for s in tr.spans}
+    roots = [s for s in tr.spans if s.parent_id not in by_id]
+    assert roots == [tr.root()] and roots[0].name == "query", \
+        [s.name for s in roots]
+    assert all(s.t1 is not None for s in tr.spans)
+    kids = {}
+    for s in tr.spans:
+        kids.setdefault(s.parent_id, []).append(s)
+    return by_id, kids
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_resident_loops_have_a_span_per_round_and_a_named_tail(
+        cl8, limit_devices, n_dev):
+    limit_devices(n_dev)
+    cl8.execute("SET citus.trace_sample_rate = 1.0")
+    q = "SELECT c, count(*), sum(v) FROM t WHERE v < 6000 GROUP BY c"
+    first = cl8.execute(q)
+    hits = cl8.counters.snapshot()["device_cache_hits"]
+    assert sorted(cl8.execute(q).rows) == sorted(first.rows)
+    assert cl8.counters.snapshot()["device_cache_hits"] == hits + 1
+    tr = T.last_trace()
+    by_id, kids = tree(tr)
+    ex = tr.find("execute")
+    under_execute = [s.name for s in kids[ex.span_id]]
+    rounds = tr.find_all("device_round")
+    # one per cached batch on one device, one per cached round on the mesh
+    assert len(rounds) == {1: 8, 4: 2}[n_dev]
+    for r in rounds:
+        assert r.parent_id == ex.span_id and r.attrs["resident"] is True
+        assert [k.name for k in kids[r.span_id]] == ["dispatch"]
+        slot = kids[r.span_id][0].attrs["slot"]
+        assert slot == ("jit_fused" if n_dev == 1 else "mesh_run")
+    tail = under_execute[under_execute.index("device_round") + len(rounds):]
+    # one device: one wait, one fetch; mesh: a wait for the first
+    # round, a fetch per round (the copy of a round runs beside the
+    # next round on the chip), combine
+    want = ["wait:device_round"] + ["fetch"] * (1 if n_dev == 1 else
+                                                len(rounds))
+    want += ["combine"] if n_dev == 4 else []
+    assert tail[:len(want)] == want, under_execute
+    assert ("combine" in under_execute) == (n_dev == 4)
+    for name in ("finalize_groups", "finalize", "book_stats", "admission",
+                 "cache_lookup", "remote_dispatch"):
+        assert name in under_execute, (name, under_execute)
+    assert ("init_acc" in under_execute) == (n_dev == 1)
+    lookups = [s.attrs for s in tr.find_all("cache_lookup")]
+    assert {"hit": True, "mesh": n_dev == 4} in lookups
+    assert tr.find("finalize_groups").attrs["groups"] == 3
+    assert by_id[tr.find("bind_params").parent_id].name == "query"
+
+
+def test_root_closes_last_and_statement_stats_lie_under_it(cl):
+    cl.execute("SET citus.trace_sample_rate = 1.0")
+    before = cl.counters.snapshot()["trace_spans_recorded"]
+    cl.execute("SELECT count(*), sum(v) FROM t WHERE v < 3000")
+    tr = T.last_trace()
+    root = tr.root()
+    assert root.t1 == max(s.t1 for s in tr.spans)
+    assert root.t0 == min(s.t0 for s in tr.spans)
+    booked = [s for s in tr.find_all("book_stats")
+              if s.parent_id == root.span_id]
+    assert len(booked) == 1          # query_stats / tenant / scheduler
+    # spans are booked once per trace, when it closes, by their number
+    assert cl.counters.snapshot()["trace_spans_recorded"] \
+        == before + len(tr.spans)
+
+
+class AnnotationRecorder:
+    """Stands in for jax.profiler.TraceAnnotation."""
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        import threading
+        self.log.append(("enter", self.name, threading.get_ident()))
+
+    def __exit__(self, *exc):
+        import threading
+        self.log.append(("exit", self.name, threading.get_ident()))
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_every_real_span_holds_one_annotation_on_its_own_thread(
+        cl8, monkeypatch, limit_devices, tmp_path, n_dev):
+    from collections import Counter
+    from citus_tpu.executor.device_cache import GLOBAL_CACHE
+    limit_devices(n_dev)
+    q = "SELECT count(*), sum(v) FROM t"
+    cl8.execute(q)                                   # compile unsampled
+    AnnotationRecorder.log = log = []
+    monkeypatch.setattr(T, "_annotation_cls", AnnotationRecorder)
+    cl8.execute(q)
+    assert log == []                                 # rate 0: none made
+    cl8.execute("SET citus.trace_sample_rate = 1.0")
+    del log[:]
+    GLOBAL_CACHE.clear()                             # stream: two threads
+    cl8.execute(q)
+    tr = T.last_trace()
+    tree(tr)
+    assert len({s.tid for s in tr.spans}) == 2
+    entered = Counter((n, t) for kind, n, t in log if kind == "enter")
+    left = Counter((n, t) for kind, n, t in log if kind == "exit")
+    assert entered == left
+    assert entered == Counter(("citus." + s.name, s.tid) for s in tr.spans)
+    # annotations nest per thread: each exit closes that thread's last
+    open_ = {}
+    for kind, name, tid in log:
+        if kind == "enter":
+            open_.setdefault(tid, []).append(name)
+        else:
+            assert open_[tid].pop() == name
+    # the export after the root closed is a bare annotation of its own
+    cl8.execute(f"SET citus.trace_export_dir = '{tmp_path}/traces'")
+    del log[:]
+    cl8.execute(q)
+    names = [n for kind, n, _ in log if kind == "enter"]
+    assert names[0] == "citus.query" and names[-1] == "citus.trace_export"
+    assert names.count("citus.trace_export") == 1
+
+
+def test_unsampled_resident_streaming_and_mesh_queries_allocate_no_span(
+        cl8, limit_devices):
+    from citus_tpu.executor.device_cache import GLOBAL_CACHE
+    cl8.execute("SET citus.trace_sample_rate = 0")
+    q = "SELECT c, count(*), sum(v) FROM t GROUP BY c"
+    for n_dev in (1, 4):
+        limit_devices(n_dev)
+        GLOBAL_CACHE.clear()
+        cl8.execute(q)                               # compiles, streams
+        before = T.span_allocations()
+        cl8.execute(q)                               # resident
+        GLOBAL_CACHE.clear()
+        cl8.execute(q)                               # streaming again
+        assert T.span_allocations() == before, n_dev
+
+
+def test_chrome_export_puts_each_thread_on_its_own_row(cl8, limit_devices,
+                                                       tmp_path):
+    from citus_tpu.executor.device_cache import GLOBAL_CACHE
+    limit_devices(1)
+    export = tmp_path / "traces"
+    cl8.execute("SET citus.trace_sample_rate = 1.0")
+    cl8.execute(f"SET citus.trace_export_dir = '{export}'")
+    GLOBAL_CACHE.clear()
+    cl8.execute("SELECT count(*), sum(v) FROM t")
+    tr = T.last_trace()
+    doc = json.load(open(export / f"trace_{tr.trace_id}.json"))
+    assert doc["otherData"]["thread_rows"] == 2
+    evts = {e["args"]["span_id"]: e for e in doc["traceEvents"]
+            if e["ph"] == "X"}
+    assert len(evts) == len(tr.spans)
+    root = tr.root()
+    assert evts[root.span_id]["tid"] == 1
+    for s in tr.spans:
+        e = evts[s.span_id]
+        assert (e["tid"] == 1) == (s.tid == root.tid)
+        assert e["args"].get("parent_id") == s.parent_id
+    assert {e["tid"] for e in evts.values()
+            if e["name"] == "decode_batch"} == {2}
+
+
+def test_profile_traces_its_statement_whatever_the_sampling_rate(
+        cl, monkeypatch, tmp_path):
+    AnnotationRecorder.log = log = []
+    monkeypatch.setattr(T, "_annotation_cls", AnnotationRecorder)
+    cl.execute("SET citus.trace_sample_rate = 0")
+    assert log == []
+    r = cl.profile("SELECT count(*) FROM t", str(tmp_path / "prof"))
+    assert r.rows == [(2000,)]
+    names = [n for kind, n, _ in log if kind == "enter"]
+    assert names[0] == "citus.query" and names.count("citus.query") == 1
+    assert {"citus.execute", "citus.fetch", "citus.dispatch"} <= set(names)
+    assert T.current() is None and "forced" in T.last_trace().reasons
