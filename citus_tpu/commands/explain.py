@@ -263,6 +263,10 @@ def _run_analyze(cl, stmt: A.Explain) -> list[str]:
             f"H2D {pl.get('h2d_bytes', 0)} bytes, "
             f"stalls host={pl.get('host_stalls', 0)} "
             f"device={pl.get('device_stalls', 0)}")
+        if pl.get("batch_rows_real"):
+            # rows shipped and scanned per table row (1.0 = no padding)
+            line += (", pad_share "
+                     f"{pl['batch_rows_padded'] / pl['batch_rows_real']:.3f}")
         if "fused_dispatches" in pl:
             # the 1-dispatch-per-batch claim, visible per statement
             line += f", fused dispatches {pl['fused_dispatches']}"

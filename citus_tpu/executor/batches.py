@@ -1,16 +1,18 @@
 """Shard data -> padded device batches.
 
 The host-side half of the scan: read pruned chunks (decompressed on the
-host), concatenate, and pad to a power-of-two row bucket so XLA sees a
-small, stable set of shapes (the recompile-pressure discipline the
-reference gets from prepared-statement plan caching).  Padding rows carry
-``row_mask=False`` and zeroed values.
+host), cut the stream at exactly ``max_batch_rows`` rows, and assemble
+each cut once into a buffer padded to a power-of-two row bucket, so XLA
+sees a small, stable set of shapes (the recompile-pressure discipline
+the reference gets from prepared-statement plan caching).  A full batch
+is its bucket (no padding); only a shard's last batch is padded.
+Padding rows carry ``row_mask=False`` and zeroed values.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Iterator, Optional
+from typing import Iterator, NamedTuple, Optional
 
 import numpy as np
 
@@ -39,11 +41,28 @@ class ShardBatch:
                    + self.row_mask.nbytes)
 
 
-def bucket_rows(n: int, min_rows: int) -> int:
+class _Piece(NamedTuple):
+    """A chunk, or the slice of one that a batch cut fell into (views,
+    no copy)."""
+    values: dict[str, np.ndarray]
+    validity: dict[str, Optional[np.ndarray]]   # None = all valid
+    rows: int
+
+    def cut(self, lo: int, hi: int) -> "_Piece":
+        return _Piece(
+            {c: v[lo:hi] for c, v in self.values.items()},
+            {c: None if m is None else m[lo:hi]
+             for c, m in self.validity.items()}, hi - lo)
+
+
+def bucket_rows(n: int, min_rows: int, max_rows: int) -> int:
+    """The power-of-two bucket (min_rows * 2**k) of a batch of
+    n <= max_rows rows, never above max_rows: a full batch is its own
+    bucket whatever min_rows is."""
     b = max(min_rows, 1)
     while b < n:
         b *= 2
-    return b
+    return min(b, max(max_rows, min_rows))
 
 
 def _pull_placement_fallback(cat: Catalog, table: TableMeta, shard,
@@ -59,14 +78,9 @@ def _pull_placement_fallback(cat: Catalog, table: TableMeta, shard,
         table.name, shard.shard_id, node, cat.node_endpoint(node))
 
 
-def load_shard_batches(
-    cat: Catalog, plan: PhysicalPlan, shard_index: int, *,
-    min_batch_rows: int = 8192, max_batch_rows: int = 1 << 22,
-    node_override: Optional[int] = None,
-    prefer_secondary: bool = False,
-) -> Iterator[tuple[dict[str, np.ndarray], dict[str, np.ndarray], int]]:
-    """Yield (values, valids, n_rows) raw column groups of at most
-    max_batch_rows rows for one shard placement."""
+def _shard_chunks(cat: Catalog, plan: PhysicalPlan, shard_index: int,
+                  node_override: Optional[int], prefer_secondary: bool):
+    """Yield the pruned chunks of one shard placement, in order."""
     table = plan.bound.table
     shard = table.shards[shard_index]
     from citus_tpu.testing.faults import FAULTS
@@ -117,82 +131,141 @@ def load_shard_batches(
     if reader is None:
         return
     cols = plan.scan_columns
-    pend_v: dict[str, list[np.ndarray]] = {c: [] for c in cols}
-    pend_m: dict[str, list[np.ndarray]] = {c: [] for c in cols}
-    pend_rows = 0
     if plan.index_eq is not None:
         col, value, _name = plan.index_eq
-        source = reader.lookup_eq(cols, col, value, plan.intervals)
+        yield from reader.lookup_eq(cols, col, value, plan.intervals)
     else:
-        source = reader.scan(cols, plan.intervals)
+        yield from reader.scan(cols, plan.intervals)
+
+
+def _cut_batches(cat: Catalog, plan: PhysicalPlan, shard_index: int,
+                 max_batch_rows: int, node_override: Optional[int],
+                 prefer_secondary: bool):
+    """Yield (pieces, n_rows) with 0 < n_rows <= max_batch_rows: the
+    shard's rows in order, cut at exactly max_batch_rows.  Chunk sizes
+    are arbitrary (deletes shorten them), so a cut may fall anywhere in
+    a chunk, and more than once in a large one."""
+    from citus_tpu.testing.faults import FAULTS
+    table = plan.bound.table
+    fault_key = f"{table.name}:{table.shards[shard_index].shard_id}"
+    chunks = _shard_chunks(cat, plan, shard_index, node_override,
+                           prefer_secondary)
     # NOTE: under the pipelined executor this generator runs on the
     # host decode thread (executor/pipeline.py HostPrefetcher), so the
     # decode_batch fault point below fires there — delays injected on
     # it model slow host-side decompression overlapping device compute.
-    # Spans: one stripe_read (file read + decompress of every chunk of
-    # the batch) and one concat per batch, never one per chunk, and
-    # none held across the yield.
-    source = iter(source)
+    # Spans: one stripe_read per batch (file read + decompress of every
+    # chunk it pulls), never one per chunk, and none held across the
+    # yield.
+    rest = None            # what the last cut left of its chunk
     exhausted = False
     while not exhausted:
+        pieces, rows = [], 0
         with _trace.span("stripe_read") as sp:
-            chunks = 0
-            for batch in source:
-                for c in cols:
-                    pend_v[c].append(batch.values[c])
-                    m = batch.validity[c]
-                    pend_m[c].append(np.ones(batch.row_count, bool)
-                                     if m is None else m)
-                pend_rows += batch.row_count
-                chunks += 1
-                if pend_rows >= max_batch_rows:
-                    break
-            else:
-                exhausted = True
+            while rows < max_batch_rows:
+                piece = rest
+                rest = None
+                if piece is None:
+                    b = next(chunks, None)
+                    if b is None:
+                        exhausted = True
+                        break
+                    piece = _Piece(b.values, b.validity, b.row_count)
+                room = max_batch_rows - rows
+                if piece.rows > room:
+                    rest = piece.cut(room, piece.rows)
+                    piece = piece.cut(0, room)
+                if piece.rows:
+                    pieces.append(piece)
+                    rows += piece.rows
             if sp.recording:
-                sp.set(chunks=chunks, rows=pend_rows)
-        if pend_rows:
-            FAULTS.hit("decode_batch", f"{table.name}:{shard.shard_id}")
-            out = _drain(cols, pend_v, pend_m, pend_rows)
-            pend_v = {c: [] for c in cols}
-            pend_m = {c: [] for c in cols}
-            pend_rows = 0
-            yield out
+                sp.set(chunks=len(pieces), rows=rows)
+        if rows:
+            FAULTS.hit("decode_batch", fault_key)
+            yield pieces, rows
 
 
-def _drain(cols, pend_v, pend_m, pend_rows):
-    with _trace.span("concat") as sp:
-        values = {c: np.concatenate(pend_v[c]) if len(pend_v[c]) > 1
-                  else pend_v[c][0] for c in cols}
-        masks = {c: np.concatenate(pend_m[c]) if len(pend_m[c]) > 1
-                 else pend_m[c][0] for c in cols}
-        if sp.recording:
-            sp.set(chunks=len(pend_v[cols[0]]) if cols else 0,
-                   bytes=int(sum(v.nbytes for v in values.values())
-                             + sum(m.nbytes for m in masks.values())))
-    return values, masks, pend_rows
+def load_shard_batches(
+    cat: Catalog, plan: PhysicalPlan, shard_index: int, *,
+    min_batch_rows: int = 8192, max_batch_rows: int = 1 << 22,
+    node_override: Optional[int] = None,
+    prefer_secondary: bool = False,
+) -> Iterator[tuple[dict[str, np.ndarray], dict[str, np.ndarray], int]]:
+    """Yield (values, valids, n_rows) raw column groups of at most
+    max_batch_rows rows for one shard placement: unpadded, in the
+    stored dtypes (the host paths' input; the device paths take
+    load_padded_batches)."""
+    cols = plan.scan_columns
+    for pieces, n in _cut_batches(cat, plan, shard_index, max_batch_rows,
+                                  node_override, prefer_secondary):
+        with _trace.span("concat") as sp:
+            values = {c: _concat([p.values[c] for p in pieces]) for c in cols}
+            masks = {c: _concat([np.ones(p.rows, bool) if p.validity[c] is None
+                                 else p.validity[c] for p in pieces])
+                     for c in cols}
+            if sp.recording:
+                sp.set(chunks=len(pieces),
+                       bytes=int(sum(v.nbytes for v in values.values())
+                                 + sum(m.nbytes for m in masks.values())))
+        yield values, masks, n
 
 
-def pad_to_batch(table: TableMeta, plan: PhysicalPlan, values: dict, masks: dict,
-                 n_rows: int, padded_rows: int, shard_index: int) -> ShardBatch:
+def _concat(arrays: list) -> np.ndarray:
+    return arrays[0] if len(arrays) == 1 else np.concatenate(arrays)
+
+
+def load_padded_batches(
+    cat: Catalog, plan: PhysicalPlan, shard_index: int, *,
+    min_batch_rows: int = 8192, max_batch_rows: int = 1 << 22,
+    prefer_secondary: bool = False,
+) -> Iterator[ShardBatch]:
+    """Yield one shard placement's rows as ShardBatches of at most
+    max_batch_rows rows, each assembled ONCE: per scan column one array
+    of padded_rows elements in the device dtype that the chunk slices
+    are written into (the cast happens in that write).  Full batches
+    have n_rows == padded_rows; the shard's last batch is padded to its
+    bucket with zeroed values, validity True and row_mask False."""
+    for pieces, n in _cut_batches(cat, plan, shard_index, max_batch_rows,
+                                  None, prefer_secondary):
+        yield _assemble(plan, pieces, n,
+                        bucket_rows(n, min_batch_rows, max_batch_rows),
+                        shard_index)
+
+
+def _assemble(plan: PhysicalPlan, pieces: list, n_rows: int,
+              padded_rows: int, shard_index: int) -> ShardBatch:
+    schema = plan.bound.table.schema
     cols_out, valids_out = [], []
     with _trace.span("pad") as sp:
         for c in plan.scan_columns:
-            dt = table.schema.scan_dtype(c, device=True)
-            v = values[c].astype(dt, copy=False)
-            m = masks[c]
-            if padded_rows != n_rows:
-                v = np.concatenate([v, np.zeros(padded_rows - n_rows, dt)])
-                m = np.concatenate([m, np.ones(padded_rows - n_rows, bool)])
+            dt = schema.scan_dtype(c, device=True)
+            if len(pieces) == 1 and padded_rows == n_rows:
+                # one chunk that fills its bucket: no copy unless it casts
+                m = pieces[0].validity[c]
+                cols_out.append(pieces[0].values[c].astype(dt, copy=False))
+                valids_out.append(np.ones(n_rows, bool) if m is None else m)
+                continue
+            v = np.empty(padded_rows, dt)
+            m = np.empty(padded_rows, bool)
+            at = 0
+            for p in pieces:
+                v[at:at + p.rows] = p.values[c]
+                m[at:at + p.rows] = \
+                    True if p.validity[c] is None else p.validity[c]
+                at += p.rows
+            v[n_rows:] = 0
+            m[n_rows:] = True
             cols_out.append(v)
             valids_out.append(m)
-        row_mask = np.zeros(padded_rows, bool)
-        row_mask[:n_rows] = True
+        row_mask = np.ones(padded_rows, bool)
+        row_mask[n_rows:] = False
         out = ShardBatch(tuple(cols_out), tuple(valids_out), row_mask,
                          n_rows, padded_rows, shard_index)
         if sp.recording:
-            sp.set(bytes_in=int(sum(values[c].nbytes + masks[c].nbytes
-                                    for c in plan.scan_columns)),
+            sp.set(bytes_in=int(sum(
+                p.values[c].nbytes
+                + (0 if p.validity[c] is None else p.validity[c].nbytes)
+                for p in pieces for c in plan.scan_columns)),
                    bytes_out=out.nbytes)
     return out
 

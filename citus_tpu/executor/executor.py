@@ -26,7 +26,7 @@ from citus_tpu.catalog import Catalog
 from citus_tpu.config import Settings
 from citus_tpu.errors import ExecutionError
 from citus_tpu.executor.batches import (
-    ShardBatch, bucket_rows, empty_batch, load_shard_batches, pad_to_batch,
+    ShardBatch, empty_batch, load_padded_batches, load_shard_batches,
 )
 from citus_tpu.executor.finalize import finalize_groups, order_and_limit, project_rows
 from citus_tpu.executor.kernel_cache import get_kernel, jit_compile
@@ -124,26 +124,6 @@ class Result:
 
     def __iter__(self):
         return iter(self.rows)
-
-
-def _load_all_batches(cat: Catalog, plan: PhysicalPlan, settings: Settings) -> list[ShardBatch]:
-    """Load every (shard, batch) padded to a common power-of-two bucket."""
-    from citus_tpu.testing.faults import FAULTS
-    raw = []
-    for si in plan.shard_indexes:
-        FAULTS.hit("dispatch_task", f"{plan.bound.table.name}:{si}")
-        GLOBAL_COUNTERS.bump("tasks_dispatched")
-        for values, masks, n in load_shard_batches(
-                cat, plan, si,
-                min_batch_rows=settings.executor.min_batch_rows,
-                prefer_secondary=settings.executor.use_secondary_nodes):
-            raw.append((si, values, masks, n))
-    if not raw:
-        return []
-    bucket = max(bucket_rows(n, settings.executor.min_batch_rows)
-                 for _, _, _, n in raw)
-    return [pad_to_batch(plan.bound.table, plan, v, m, n, bucket, si)
-            for si, v, m, n in raw]
 
 
 # ------------------------------------------------------------ agg paths
@@ -252,52 +232,51 @@ def _prefetch_depth(settings: Settings) -> int:
 
 
 def _iter_padded_batches(cat: Catalog, plan: PhysicalPlan, settings: Settings):
-    """Lazily yield host ShardBatches, each padded to its own
-    power-of-two bucket.  Unlike _load_all_batches, nothing is
+    """Lazily yield host ShardBatches of at most 1 << 22 rows, nothing
     materialized up front — the streaming scan path's host half
     (reference analog: ColumnarReadNextRow never materializes a stripe,
-    columnar_reader.c:323).  Full batches share one shape; only tail
-    batches differ, so the per-shape jit cache stays small."""
+    columnar_reader.c:323).  A full batch is exactly its bucket; only a
+    shard's last batch is padded, to its own power-of-two bucket, so
+    the per-shape jit cache stays small.  Books real against padded
+    rows: process counters, and the statement's EXPLAIN pad_share."""
     from citus_tpu.testing.faults import FAULTS
 
-    def raw_batches():
+    def shard_batches():
         for si in plan.shard_indexes:
             FAULTS.hit("dispatch_task", f"{plan.bound.table.name}:{si}")
             GLOBAL_COUNTERS.bump("tasks_dispatched")
-            for values, masks, n in load_shard_batches(
-                    cat, plan, si,
-                    min_batch_rows=settings.executor.min_batch_rows,
-                    prefer_secondary=settings.executor.use_secondary_nodes):
-                yield si, values, masks, n
+            yield from load_padded_batches(
+                cat, plan, si,
+                min_batch_rows=settings.executor.min_batch_rows,
+                prefer_secondary=settings.executor.use_secondary_nodes)
 
     # one decode_batch span per batch, on whichever thread pulls this
     # generator (the decode thread under the prefetcher), closed BEFORE
     # the yield: a span held across a yield would stay on the puller's
     # span stack while the consumer runs.  The pull that finds the
-    # stream exhausted is a last, childless span (eof).
-    raw = raw_batches()
+    # stream exhausted is a last span without a batch (eof).
+    batches = shard_batches()
+    pl = plan.runtime_cache.setdefault("pipeline", {})
     try:
         while True:
             with _trace.span("decode_batch") as sp:
-                item = next(raw, None)
-                if item is not None:
-                    si, values, masks, n = item
-                    hb = pad_to_batch(
-                        plan.bound.table, plan, values, masks, n,
-                        bucket_rows(n, settings.executor.min_batch_rows),
-                        si)
+                hb = next(batches, None)
                 if sp.recording:
                     sp.set(thread=threading.current_thread().name)
-                    if item is None:
+                    if hb is None:
                         sp.set(eof=True)
                     else:
-                        sp.set(shard_index=int(si), rows=int(n),
-                               bytes=hb.nbytes)
-            if item is None:
+                        sp.set(shard_index=int(hb.shard_index),
+                               rows=int(hb.n_rows), bytes=hb.nbytes)
+            if hb is None:
                 return
+            for name, rows in (("batch_rows_real", hb.n_rows),
+                               ("batch_rows_padded", hb.padded_rows)):
+                GLOBAL_COUNTERS.bump(name, rows)
+                pl[name] = pl.get(name, 0) + rows
             yield hb
     finally:
-        raw.close()
+        batches.close()
 
 
 def _repad_batch(b: ShardBatch, bucket: int) -> ShardBatch:

@@ -95,6 +95,8 @@ TOP_FAMILIES = 20
 METRIC_HELP = {
     "queries_executed": "SQL statements executed by this process",
     "bytes_scanned": "columnar bytes staged for device scans",
+    "batch_rows_real": "table rows in the padded scan batches made",
+    "batch_rows_padded": "rows of those batches' power-of-two buckets",
     "wait_remote_rpc_ms": "ms blocked on remote RPC round trips",
     "wait_lock_ms": "ms blocked acquiring advisory locks",
     "wait_prefetch_stall_ms": "ms the device starved for host decode",

@@ -36,6 +36,10 @@ class StatCounters:
         "chunks_total",
         "chunks_selected",
         "bytes_scanned",
+        # rows of the padded scan batches made (executor/batches.py):
+        # real rows against the rows of their power-of-two buckets
+        "batch_rows_real",
+        "batch_rows_padded",
         "plan_cache_hits",
         "plan_cache_misses",
         "connection_failovers",

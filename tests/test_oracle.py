@@ -123,8 +123,8 @@ def test_mesh_path_is_used(loaded):
     from citus_tpu.planner.physical import plan_select
     bound = bind_select(cl.catalog, parse_sql("SELECT kind, count(*) FROM events GROUP BY kind")[0])
     plan = plan_select(cl.catalog, bound)
-    from citus_tpu.executor.executor import _load_all_batches
-    batches = _load_all_batches(cl.catalog, plan, cl.settings)
+    from citus_tpu.executor.executor import _iter_padded_batches
+    batches = list(_iter_padded_batches(cl.catalog, plan, cl.settings))
     assert len(batches) > 1  # multi-batch -> shard_map + psum path
 
 

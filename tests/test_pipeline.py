@@ -315,8 +315,9 @@ def _streaming_table(cl, name, n=20000, shards=8):
 def test_streaming_scan_spans_cover_both_threads(tmp_cluster, limit_devices,
                                                  n_dev):
     """A streaming scan's trace: decode_batch spans from the decode
-    thread hang under the query's execute, each with one stripe_read,
-    concat and pad; every device_round holds h2d + dispatch; the stall
+    thread hang under the query's execute, each with one stripe_read
+    and one pad (the batch is assembled once: no concat); every
+    device_round holds h2d + dispatch; the stall
     the consumer sat in is a wait:prefetch_stall span from the seam."""
     from citus_tpu.observability import trace as T
     limit_devices(n_dev)
@@ -346,7 +347,7 @@ def test_streaming_scan_spans_cover_both_threads(tmp_cluster, limit_devices,
     assert len(tr.find_all("decode_batch")) == 9     # and the last pull
     for s in batches:
         assert s.parent_id == ex.span_id
-        assert kids[s.span_id] == ["stripe_read", "concat", "pad"]
+        assert kids[s.span_id] == ["stripe_read", "pad"]
         assert s.attrs["rows"] > 0 and s.attrs["bytes"] > 0
     other = [s for s in batches if s.tid != root.tid]
     # the mesh loop peeks two batches on its own thread before it

