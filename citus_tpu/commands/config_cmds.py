@@ -61,8 +61,9 @@ def _window_ms(v) -> float:
 
 
 def _hash_slots(v) -> int:
-    """citus.hash_agg_slots = <slots> | auto (stored as 0: sized from
-    catalog row-count stats at execution)."""
+    """citus.hash_agg_slots = <slots> | auto (the default, stored as 0:
+    sized at execution from catalog row-count stats and the device's
+    free memory)."""
     if str(v).lower() == "auto":
         return 0
     n = int(v)

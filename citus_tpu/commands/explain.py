@@ -278,7 +278,9 @@ def _run_analyze(cl, stmt: A.Explain) -> list[str]:
             lines.append(
                 f"    Hash: hash slots {pl['hash_slots']}, "
                 f"occupancy {pl.get('hash_occupancy_pct', 0):g}%, "
-                f"spilled {pl.get('hash_spilled_rows', 0)} rows")
+                f"spilled {pl.get('hash_spilled_rows', 0)} rows, "
+                f"groups {pl.get('hash_groups_out', 0)}, "
+                f"fetched {pl.get('hash_table_bytes_fetched', 0)} bytes")
         if "remote_wait_ms" in pl:
             wire = f", wire {pl['wire_format']}" \
                 if pl.get("wire_format") else ""

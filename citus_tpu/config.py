@@ -44,10 +44,12 @@ class PlannerSettings:
     # Direct-gid when the composite key domain is provably <= this bound
     # (exact, collision-free scatter-add).
     direct_gid_limit: int = 65536
-    # Slot count for the fingerprint hash-aggregate fallback; 0 = auto
-    # (SET citus.hash_agg_slots = auto): sized from catalog row-count
-    # stats, next power of two clamped [1024, 1<<20].
-    hash_agg_slots: int = 8192
+    # Slot count of the device hash-aggregate table; 0 = auto, the
+    # default (SET citus.hash_agg_slots = auto): the next power of two at
+    # or above the catalog's row count, at least 1024, capped by what a
+    # stated share of the device's free memory holds
+    # (executor/executor.py _hash_slots).  A positive value fixes it.
+    hash_agg_slots: int = 0
     # Enable repartition (all_to_all) joins; reference GUC
     # citus.enable_repartition_joins.
     enable_repartition_joins: bool = True

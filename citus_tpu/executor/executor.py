@@ -744,21 +744,42 @@ def _hash_has_exact(plan: PhysicalPlan) -> bool:
                for op in plan.partial_ops)
 
 
-def _hash_slots(cat: Catalog, plan: PhysicalPlan, settings: Settings) -> int:
-    """citus.hash_agg_slots; 0 (= auto) sizes the table from catalog
-    row-count stats — next power of two, clamped [1024, 1<<20] — so
-    small tables don't pay a megaslot fetch and big ones don't spill
-    every other row."""
+#: share of the device's free memory one query's hash tables may take:
+#: the donated state, the kernel's per-probe claim tables beside it and
+#: the scan batches in flight all have to fit next to the batch cache
+HASH_STATE_MEMORY_SHARE = 0.25
+#: free bytes assumed where the platform reports no memory statistics
+#: (the CPU platform: the tables then live in host memory)
+_UNREPORTED_FREE_BYTES = 4 << 30
+
+
+def _hash_slots(cat: Catalog, plan: PhysicalPlan, settings: Settings,
+                key_dtypes: tuple, tables: int = 1) -> int:
+    """Slots of a query's device hash table.  ``citus.hash_agg_slots =
+    n`` fixes them; ``auto`` (0, the default) derives them: the next
+    power of two at or above the catalog's row count (every row may be
+    a group), at least 1024, and at most what ``HASH_STATE_MEMORY_SHARE``
+    of the device's free memory holds of ``tables`` such tables at this
+    plan's bytes per slot.  Groups beyond the table spill to the host
+    accumulator, exactly."""
     S = settings.planner.hash_agg_slots
     if S > 0:
         return S
     from citus_tpu.catalog.stats import table_row_count
+    from citus_tpu.ops.hash_agg import empty_hash_state, hash_state_bytes
+    from citus_tpu.parallel.mesh import executor_devices
     try:
         n = table_row_count(cat, cat.table(plan.bound.table.name))
     except Exception:
         n = 0
-    n = max(1, int(n))
-    return min(1 << 20, max(1024, 1 << (n - 1).bit_length()))
+    want = max(1024, 1 << (max(1, int(n)) - 1).bit_length())
+    stats = executor_devices()[0].memory_stats()
+    free = (stats["bytes_limit"] - stats["bytes_in_use"] if stats
+            else _UNREPORTED_FREE_BYTES)
+    slot_bytes = hash_state_bytes(empty_hash_state(plan, 1, key_dtypes))
+    fit = int(free * HASH_STATE_MEMORY_SHARE) // (slot_bytes * tables)
+    cap = 1 << max(10, fit.bit_length() - 1)    # power of two at or under
+    return min(want, cap)
 
 
 def _hash_key_dtypes(plan: PhysicalPlan, penv: dict) -> tuple:
@@ -797,18 +818,26 @@ def _stream_hash_batches(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     pending: list = []   # (host batch, device spill mask) awaiting drain
 
     def _drain():
-        for hb, sp in pending:
-            sp = np.asarray(sp)
-            if sp.any():
-                n_sp = int(sp.sum())
-                GLOBAL_COUNTERS.bump("hash_spill_rows", n_sp)
-                hs["spilled"] += n_sp
-                env = {n: (np.asarray(c), np.asarray(v))
-                       for n, c, v in zip(plan.scan_columns, hb.cols,
-                                          hb.valids)}
-                env.update(penv)
-                acc.add_batch(sp, [f(env) for f in hs["key_fns_np"]],
-                              [f(env) for f in hs["arg_fns_np"]])
+        # one span per drained window: the wait for the window's spill
+        # masks (the device is behind them) and the host re-aggregation
+        if not pending:
+            return
+        with _trace.span("spill_drain") as dsp:
+            n_window = 0
+            for hb, sp in pending:
+                sp = np.asarray(sp)
+                if sp.any():
+                    n_window += int(sp.sum())
+                    env = {n: (np.asarray(c), np.asarray(v))
+                           for n, c, v in zip(plan.scan_columns, hb.cols,
+                                              hb.valids)}
+                    env.update(penv)
+                    acc.add_batch(sp, [f(env) for f in hs["key_fns_np"]],
+                                  [f(env) for f in hs["arg_fns_np"]])
+            GLOBAL_COUNTERS.bump("hash_spill_rows", n_window)
+            hs["spilled"] += n_window
+            if dsp.recording:
+                dsp.set(batches=len(pending), rows=n_window)
         pending.clear()
 
     window_bytes = 0
@@ -844,15 +873,17 @@ def _stream_hash_batches(cat: Catalog, plan: PhysicalPlan, settings: Settings,
                 window_bytes += bb
                 hs["window_peak"] = max(hs["window_peak"], window_bytes)
                 since_sync += 1
-                if since_sync >= depth:
+                window_full = since_sync >= depth
+                if window_full:
                     _block_ready(state)
-                    _drain()
                     since_sync = 0
                     window_bytes = 0
                 pstats.device_s += clock() - t_dev
                 if rsp.recording:
                     rsp.set(shard_index=int(hb.shard_index),
                             rows=int(hb.n_rows), bytes=bb, resident=False)
+            if window_full:
+                _drain()    # host work: beside the round, not inside it
     finally:
         host_iter.close()
     _drain()
@@ -879,7 +910,6 @@ def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
 
     pstats = PipelineStats()
     _trace.set_phase("device")
-    S = _hash_slots(cat, plan, settings)
     key_dtypes = _hash_key_dtypes(plan, penv)
     fused = get_kernel(
         plan, "jit_hash_fused",
@@ -889,7 +919,11 @@ def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
           "task_times": [], "task_bytes": [],
           "key_fns_np": [_ce(k, np) for k in plan.bound.group_keys],
           "arg_fns_np": [_ce(a, np) for a in plan.agg_args]}
-    state = jax.device_put(empty_hash_state(plan, S, key_dtypes))
+    with _trace.span("hash_init") as sp:
+        S = _hash_slots(cat, plan, settings, key_dtypes)
+        state = jax.device_put(empty_hash_state(plan, S, key_dtypes))
+        if sp.recording:
+            sp.set(slots=S)
 
     dispatch = None
     run_plan = plan
@@ -1008,6 +1042,34 @@ def _run_hash_partial_state(cat: Catalog, plan: PhysicalPlan,
     return table, spilled
 
 
+def _finish_hash_agg(cat: Catalog, plan: PhysicalPlan, acc, table,
+                     penv: dict) -> list[tuple]:
+    """The exact tail of a device hash aggregation, on the caller's
+    thread: the fetched ``table`` (key tables, partials, rows) merges
+    into ``acc``, which holds the spilled rows' groups already; then the
+    accumulator's arrays, HAVING and the rendering of the kept groups."""
+    from citus_tpu.ops.hash_agg import (
+        hash_state_bytes, merge_hash_tables_into,
+    )
+    fetched = hash_state_bytes(table)
+    pl = plan.runtime_cache.setdefault("pipeline", {})
+    with _trace.span("hash_merge"):
+        merge_hash_tables_into(acc, plan, *table)
+    with _trace.span("hash_finalize") as sp:
+        key_arrays, parts = acc.finalize(
+            [k.type for k in plan.bound.group_keys],
+            scalar=not plan.bound.group_keys)
+        GLOBAL_COUNTERS.bump("hash_groups_out", acc.n_groups)
+        GLOBAL_COUNTERS.bump("hash_table_bytes_fetched", fetched)
+        pl["hash_groups_out"] = acc.n_groups
+        pl["hash_table_bytes_fetched"] = fetched
+        out = [] if parts is None else finalize_groups(
+            plan, cat, key_arrays, parts, params_env=penv)
+        if sp.recording:
+            sp.set(groups=acc.n_groups, rows=len(out))
+        return out
+
+
 def _run_agg_hash_host(cat: Catalog, plan: PhysicalPlan, settings: Settings,
                        params=((), ())) -> list[tuple]:
     """Unbounded GROUP BY cardinality.
@@ -1027,17 +1089,9 @@ def _run_agg_hash_host(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     penv = _params_env(plan, params)
 
     if backend != "cpu" and not _hash_has_exact(plan):
-        from citus_tpu.ops.hash_agg import merge_hash_tables_into
-        h_keys, h_partials, h_rows = _run_hash_device(
-            cat, plan, settings, params, acc, penv, push_remote=True)
-        merge_hash_tables_into(acc, plan, h_keys, h_partials, h_rows)
-        key_arrays, partials = acc.finalize(
-            [k.type for k in plan.bound.group_keys],
-            scalar=not plan.bound.group_keys)
-        if partials is None:
-            return []
-        return finalize_groups(plan, cat, key_arrays, partials,
-                               params_env=penv)
+        table = _run_hash_device(cat, plan, settings, params, acc, penv,
+                                 push_remote=True)
+        return _finish_hash_agg(cat, plan, acc, table, penv)
 
     # exact value-set partials (or the cpu oracle backend) stay host-only
     # and are not elementwise-combinable — remote-only shards pull

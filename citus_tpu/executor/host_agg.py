@@ -40,6 +40,10 @@ class HostGroupAccumulator:
         self._key_vals: list[list] = []
         self._accs: list[list] = []
 
+    @property
+    def n_groups(self) -> int:
+        return len(self._key_vals)
+
     def _new_group(self, kvs) -> int:
         idx = len(self._key_vals)
         self._key_vals.append(kvs)
@@ -259,8 +263,29 @@ class HostGroupAccumulator:
         else:
             enc = np.zeros((sel.size, 0), np.int64)
         pv = [np.asarray(p)[sel] for p in partial_values]
-        for r in range(sel.size):
-            kb = enc[r].tobytes()
+        width = enc.shape[1] * 8
+        raw = enc.tobytes()
+        kbs = [raw[o:o + width] for o in range(0, sel.size * width, width)] \
+            if width else [b""] * sel.size
+        known = [self._groups.get(kb) for kb in kbs]
+        new = [r for r, gi in enumerate(known) if gi is None]
+        if len(new) > 1 and len({kbs[r] for r in new}) == len(new):
+            # the usual case, a table's entries being distinct keys: the
+            # groups this call creates start as their partial state, so
+            # they are appended column-wise and skip the loop below
+            at = np.asarray(new)
+            base = len(self._key_vals)
+            self._groups.update(zip((kbs[r] for r in new),
+                                    range(base, base + len(new))))
+            self._key_vals.extend(map(list, zip(*(
+                zip(list(kv[at]), kvalid[at].tolist())
+                for kv, kvalid in kv_np))))
+            self._accs.extend(map(list, zip(*(list(p[at]) for p in pv))))
+            todo = [r for r, gi in enumerate(known) if gi is not None]
+        else:
+            todo = range(sel.size)
+        for r in todo:
+            kb = kbs[r]
             gi = self._groups.get(kb)
             if gi is None:
                 kvs = [(kv[r], bool(kvalid[r])) for kv, kvalid in kv_np]

@@ -429,8 +429,8 @@ def _batched_hash_agg(cat, plan, settings, group: list[_Waiter]) -> list:
     n_cols = len(plan.scan_columns)
     n_params = len(param_env_names(plan.bound.param_specs))
     axes = (None,) * n_cols + (0,) * n_params
-    S = _hash_slots(cat, plan, settings)
     key_dtypes = _hash_key_dtypes(plan, penvs[0])
+    S = _hash_slots(cat, plan, settings, key_dtypes, tables=qp)
 
     def _build():
         # table state maps over the query axis (donated, stays
@@ -611,19 +611,9 @@ def _finalize_hash_agg(cat, plan, data, params) -> list[tuple]:
     """Per-query exact merge + finalize of a hash_host rider's table
     slice — the exact tail of the serial _run_agg_hash_host, run on the
     caller's own thread."""
-    from citus_tpu.executor.executor import _params_env
-    from citus_tpu.executor.finalize import finalize_groups
-    from citus_tpu.ops.hash_agg import merge_hash_tables_into
-    state, acc = data
-    key_tables, partials, rows = state
-    penv = _params_env(plan, params)
-    merge_hash_tables_into(acc, plan, key_tables, partials, rows)
-    key_arrays, parts = acc.finalize(
-        [k.type for k in plan.bound.group_keys],
-        scalar=not plan.bound.group_keys)
-    if parts is None:
-        return []
-    return finalize_groups(plan, cat, key_arrays, parts, params_env=penv)
+    from citus_tpu.executor.executor import _finish_hash_agg, _params_env
+    table, acc = data
+    return _finish_hash_agg(cat, plan, acc, table, _params_env(plan, params))
 
 
 def maybe_megabatch(cat, bound, settings, plan, params, t0, exec_span):

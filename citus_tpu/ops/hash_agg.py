@@ -306,6 +306,13 @@ def empty_hash_state(plan: PhysicalPlan, slots: int, key_dtypes: tuple):
     return tuple(key_tables), tuple(partials), np.zeros((S,), np.int64)
 
 
+def hash_state_bytes(state) -> int:
+    """Bytes a table state (``empty_hash_state``'s layout) occupies."""
+    key_tables, partials, rows = state
+    return (sum(kv.nbytes + kf.nbytes for kv, kf in key_tables)
+            + sum(p.nbytes for p in partials) + rows.nbytes)
+
+
 def merge_hash_tables_into(acc, plan: PhysicalPlan, key_tables, partials, rows,
                            entry_mask=None):
     """Feed a device hash table (or its spilled entries) into a

@@ -173,11 +173,15 @@ class StatCounters:
         # executor/megabatch.py, ops/hash_agg.py): fused hash-table
         # kernel rounds (1 per batch, table donated in), rows that lost
         # a fingerprint-collision probe and drained into the exact host
-        # accumulator, and remote hash-table partials merged back
-        # through the device merge door (executor/pipeline.py push path)
+        # accumulator, remote hash-table partials merged back through
+        # the device merge door (executor/pipeline.py push path), groups
+        # after the host merge of table and spills (before HAVING), and
+        # bytes of device hash table fetched to the host
         "hash_fused_dispatches",
         "hash_spill_rows",
         "hash_partials_pushed",
+        "hash_groups_out",
+        "hash_table_bytes_fetched",
         # pull-path placement syncs skipped because the control plane's
         # data-invalidation epoch proved the local mirror current
         # (net/data_plane.py sync_placement fast path)

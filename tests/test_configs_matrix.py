@@ -56,7 +56,7 @@ def run_battery(tmp_path, cfg):
         executor=ExecutorSettings(task_executor_backend=cfg["backend"]),
         planner=PlannerSettings(
             enable_repartition_joins=cfg.get("repartition", True),
-            hash_agg_slots=cfg.get("hash_slots", 8192),
+            hash_agg_slots=cfg.get("hash_slots", 0),   # 0: derived
             direct_gid_limit=cfg.get("direct_limit", 65536)),
     )
     tag = "_".join(str(v) for v in cfg.values())

@@ -144,3 +144,53 @@ def test_device_table_combine_across_batches(tmp_path):
         "SELECT sum(c), count(*) FROM (SELECT g, count(*) AS c FROM big GROUP BY g) z")
     assert tot.rows == [(n, len(np.unique(g)))]
     cl.close()
+
+
+@pytest.mark.parametrize("case", ["all_new", "some_known", "duplicates",
+                                  "null_keys", "one_entry"])
+def test_merge_partials_column_wise_equals_entry_by_entry(case):
+    """``HostGroupAccumulator.merge_partials`` appends the groups a
+    table creates column-wise when its entries are distinct keys, and
+    goes entry by entry otherwise: both give what a dictionary gives."""
+    from citus_tpu.executor.host_agg import HostGroupAccumulator
+    from citus_tpu.planner.physical import PartialOp
+    ops = [PartialOp("sum", 0, "int64", ()), PartialOp("count", 0, "int64", ()),
+           PartialOp("min", 0, "int64", ()), PartialOp("max", 0, "float64", ())]
+    rng = np.random.default_rng(11)
+    n = 1 if case == "one_entry" else 400
+    keys = rng.choice(10 ** 12, n, replace=False)
+    if case == "duplicates":
+        keys[n // 2:] = keys[:n - n // 2]
+    valid = np.ones(n, bool)
+    if case == "null_keys":
+        valid[::7] = False          # every null key is ONE group
+    acc = HostGroupAccumulator(1, ops)
+    truth = {}
+
+    def merge(k, ok, seed):
+        r = np.random.default_rng(seed)
+        parts = [r.integers(-50, 50, k.size), r.integers(1, 5, k.size),
+                 r.integers(-9, 9, k.size), r.random(k.size)]
+        mask = r.random(k.size) < 0.9
+        acc.merge_partials(mask, [(k, ok)], parts, mask.astype(np.int64))
+        for i in np.nonzero(mask)[0]:
+            key = (int(k[i]), True) if ok[i] else (0, False)
+            s, c, lo, hi = (p[i] for p in parts)
+            t = truth.setdefault(key, [0, 0, lo, hi])
+            truth[key] = [t[0] + s, t[1] + c, min(t[2], lo), max(t[3], hi)]
+
+    if case == "some_known":
+        merge(keys[:150], valid[:150], 1)       # a first table: all new
+    merge(keys, valid, 2)
+    merge(keys[::3], valid[::3], 3)             # every key known already
+
+    class KeyType:
+        device_dtype = np.int64
+    (kv, kvalid), = acc.finalize([KeyType])[0]
+    partials = acc.finalize([KeyType])[1]
+    got = {(int(kv[i]) if kvalid[i] else 0, bool(kvalid[i])):
+           [p[i] for p in partials] for i in range(acc.n_groups)}
+    assert len(got) == acc.n_groups == len(truth)
+    assert got == truth
+    assert [p.dtype for p in partials] == [np.int64, np.int64, np.int64,
+                                           np.float64]

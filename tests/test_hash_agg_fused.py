@@ -169,21 +169,23 @@ def test_streaming_peak_window_bounded(cl, one_device):
 
 def test_auto_slots_and_explain_hash_line(cl, one_device):
     _fill_groups(cl, 25_000, 900)
+    # auto is the default; a SET of a number and back restores it
+    assert cl.execute("SHOW citus.hash_agg_slots").rows == [("0",)]
+    cl.execute("SET citus.hash_agg_slots = 2048")
+    assert cl.execute("SHOW citus.hash_agg_slots").rows == [("2048",)]
     cl.execute("SET citus.hash_agg_slots = auto")
     assert cl.execute("SHOW citus.hash_agg_slots").rows == [("0",)]
     r = cl.execute(f"EXPLAIN ANALYZE {SQL}")
     text = "\n".join(l for (l,) in r.rows)
     m = re.search(r"hash slots (\d+), occupancy ([\d.]+)%, "
-                  r"spilled (\d+) rows", text)
+                  r"spilled (\d+) rows, groups (\d+), fetched (\d+) bytes",
+                  text)
     assert m, text
-    S = int(m.group(1))
-    # auto: next pow2 of the catalog row count, clamped [1024, 1<<20]
-    assert 1024 <= S <= 1 << 20 and S & (S - 1) == 0
-    assert S >= 25_000 or S == 1 << 20
+    # auto: the next power of two at or above the catalog's row count
+    # (at least 1024, at most what the device's free memory holds)
+    assert int(m.group(1)) == 32_768
     assert 0.0 <= float(m.group(2)) <= 100.0
-    cl.execute("SET citus.hash_agg_slots = 2048")
-    assert cl.execute("SHOW citus.hash_agg_slots").rows == [("2048",)]
-    cl.execute("SET citus.hash_agg_slots = 8192")
+    assert int(m.group(4)) == 900
 
 
 def test_float_keys_negative_zero_and_nan_group_once(cl, one_device):

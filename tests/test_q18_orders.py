@@ -1,0 +1,134 @@
+"""The benchmark's Q18 block (``benchmarks/queries/q18_orders.json``)
+through ``cl.execute`` against the benchmark's own plain reference
+(``benchmarks/references/q18_orders.py``), on the benchmark's own
+generator, on the CPU.
+
+4,000 orders, as two chunks of 2,000 from the two ends of a 40,000-order
+table: order keys then span 1..160,000, wider than ``direct_gid_limit``,
+so the planner cannot prove the key domain small and takes the device
+hash table -- as it does at SF1, where the cell runs.  (The first 4,000
+orders alone have keys 1..16,000 and would take the direct-group-id
+kernel.)
+
+The tolerance is equality: ``l_quantity`` is a decimal held as a scaled
+int64, sums of it are integer sums, and HAVING compares integers; there
+is no float anywhere between the rows and the answer.
+"""
+
+import json
+import os
+import re
+import sys
+
+import numpy as np
+import pytest
+
+# the driver runs ``python -m pytest tests/`` under xdist: the working
+# directory is not to be trusted to hold the root of the repo
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+import citus_tpu as ct  # noqa: E402
+from benchmarks.generators import tpch_lineitem_orders as gen  # noqa: E402
+from benchmarks.references import q18_orders  # noqa: E402
+from benchmarks.references.common import dec  # noqa: E402
+
+PARAMS = {"orders": 40_000, "parts": 200_000, "chunk_orders": 2_000,
+          "lookup_sample_orders": 64}
+CHUNKS = (0, 19)
+DATA_SEED = 22
+# 300 is the specification's validation value and 312..315 its range;
+# at 4,000 orders few or no orders pass those, so 250 and 275 (the
+# reference keeps every order from 250.00) make the answer non-empty
+QUANTITIES = (250, 275, 300, 312, 313, 314, 315)
+MODES = ("defaults", "forced_spill", "four_devices")
+
+with open(os.path.join(ROOT, "benchmarks", "queries", "q18_orders.json")) as fh:
+    QUERY = json.load(fh)
+with open(os.path.join(ROOT, "benchmarks", "configs",
+                       "tpch_sf1_1chip.json")) as fh:
+    CONFIG = json.load(fh)
+
+
+@pytest.fixture(scope="module")
+def table():
+    stats = gen.Statistics(PARAMS)
+    chunks = [gen.generate_chunk(PARAMS, DATA_SEED, i) for i in CHUNKS]
+    for c in chunks:
+        stats.add(c)
+    return chunks, stats.arrays()
+
+
+@pytest.fixture()
+def cluster(tmp_path, table, limit_devices, request):
+    mode = request.param
+    limit_devices(4 if mode == "four_devices" else 1)
+    cl = ct.Cluster(str(tmp_path / "db"))
+    cl.execute(CONFIG["ddl"])
+    cl.execute(f"SELECT create_distributed_table('{CONFIG['table']}', "
+               f"'{CONFIG['distribution_column']}', 8)")
+    for c in table[0]:
+        cl.copy_from(CONFIG["table"], columns=gen.copy_columns(c))
+    if mode == "forced_spill":
+        cl.execute("SET citus.hash_agg_slots = 1024")
+    yield cl, mode
+    cl.close()
+
+
+def _hash_line(cl, sql):
+    text = "\n".join(l for (l,) in cl.execute(f"EXPLAIN ANALYZE {sql}").rows)
+    m = re.search(r"Hash: hash slots (\d+), occupancy ([\d.]+)%, spilled "
+                  r"(\d+) rows, groups (\d+), fetched (\d+) bytes", text)
+    assert m, text
+    return [int(m.group(1)), float(m.group(2))] + [int(g) for g in
+                                                   m.group(3, 4, 5)]
+
+
+@pytest.mark.parametrize("quantity", QUANTITIES)
+@pytest.mark.parametrize("cluster", MODES, indirect=True)
+def test_engine_equals_the_plain_reference(cluster, table, quantity):
+    cl, mode = cluster
+    stats = table[1]
+    sql = QUERY["sql"].format(QUANTITY=quantity)
+    want = sorted(q18_orders.expected(stats, {"QUANTITY": quantity}))
+    got = sorted(tuple(r) for r in cl.execute(sql).rows)
+    assert got == want          # every key and every decimal sum, equal
+    if quantity == 250:
+        assert len(want) >= 5   # the comparison is not of two empty lists
+    rows, orders = int(stats["rows"]), int(stats["orders"])
+    slots, _occupancy, spilled, groups, fetched = _hash_line(cl, sql)
+    assert groups == orders == 4_000
+    if mode == "forced_spill":
+        assert slots == 1024 and spilled > rows // 2
+    else:
+        # derived: the next power of two at or above the catalog's rows
+        assert slots == 1 << (rows - 1).bit_length() == 16_384
+        assert spilled < 0.05 * rows
+    # one int64 key + its int8 flag, sum / count / float64 shadow, rows
+    assert fetched == slots * 41
+
+
+@pytest.mark.parametrize("cluster", MODES, indirect=True)
+def test_every_order_sum_equals_numpy(cluster, table):
+    """The block without its HAVING: all 4,000 groups, against a numpy
+    sum over the generated rows (no statistics involved)."""
+    cl, _ = cluster
+    okey = np.concatenate([c["okey"] for c in table[0]])
+    qty = np.concatenate([c["qty"] for c in table[0]])
+    keys, inverse = np.unique(okey, return_inverse=True)
+    sums = np.zeros(keys.size, np.int64)
+    np.add.at(sums, inverse, qty)
+    want = [(int(k), dec(s, 2)) for k, s in zip(keys, sums)]
+    c0 = cl.counters.snapshot()
+    got = sorted(cl.execute("SELECT l_orderkey, sum(l_quantity) FROM lineitem "
+                            "GROUP BY l_orderkey").rows)
+    c1 = cl.counters.snapshot()
+    assert got == want
+    assert c1["hash_groups_out"] - c0["hash_groups_out"] == keys.size
+    assert c1["hash_fused_dispatches"] > c0["hash_fused_dispatches"]
+
+
+def test_reference_refuses_a_quantity_under_what_it_keeps(table):
+    with pytest.raises(ValueError):
+        q18_orders.expected(table[1], {"QUANTITY": 249})

@@ -437,3 +437,87 @@ def test_profile_traces_its_statement_whatever_the_sampling_rate(
     assert names[0] == "citus.query" and names.count("citus.query") == 1
     assert {"citus.execute", "citus.fetch", "citus.dispatch"} <= set(names)
     assert T.current() is None and "forced" in T.last_trace().reasons
+
+
+# ------------------------------------------------- the hash-aggregation path
+
+
+@pytest.fixture()
+def clh(tmp_path):
+    """6,000 rows in 1,500 groups whose key domain is far wider than
+    ``direct_gid_limit``: the GROUP BY takes the device hash table."""
+    c = ct.Cluster(str(tmp_path / "dbh"))
+    c.execute("CREATE TABLE t (k bigint NOT NULL, g bigint, v bigint)")
+    c.execute("SELECT create_distributed_table('t', 'k', 8)")
+    g = np.random.default_rng(5).choice(10 ** 12, 1500, replace=False)
+    c.copy_from("t", columns={"k": np.arange(6000), "g": np.tile(g, 4),
+                              "v": np.arange(6000) % 7})
+    yield c
+    c.close()
+
+
+HASH_Q = "SELECT g, sum(v) FROM t GROUP BY g"
+
+
+@pytest.mark.parametrize("depth", [1, 3, None])
+def test_hash_path_has_its_four_spans_on_the_callers_thread(
+        clh, limit_devices, depth):
+    from citus_tpu.executor.executor import _prefetch_depth
+    limit_devices(1)
+    if depth is not None:
+        clh.execute(f"SET citus.executor_prefetch_depth = {depth}")
+        clh.execute(f"SET citus.max_tasks_in_flight = {depth}")
+    depth = _prefetch_depth(clh.settings)
+    clh.execute("SET citus.trace_sample_rate = 1.0")
+    assert len(clh.execute(HASH_Q).rows) == 1500
+    tr = T.last_trace()
+    by_id, kids = tree(tr)
+    agg = tr.find("host_agg")
+    under = [s.name for s in kids[agg.span_id]]
+    rounds = tr.find_all("device_round")
+    assert len(rounds) == 8                         # a batch per shard
+    # one per query; a drain per window of ``depth`` batches, the last
+    # one short
+    for name, n in (("hash_init", 1), ("hash_merge", 1), ("hash_finalize", 1),
+                    ("spill_drain", -(-len(rounds) // depth))):
+        spans = tr.find_all(name)
+        assert len(spans) == n, (name, under)
+        for s in spans:
+            assert s.parent_id == agg.span_id and s.tid == tr.root().tid
+    assert under.index("hash_init") < under.index("device_round")
+    assert under[-3:] == ["fetch", "hash_merge", "hash_finalize"], under
+    assert sum(s.attrs["batches"] for s in tr.find_all("spill_drain")) == 8
+    assert tr.find("hash_init").attrs["slots"] == 8192
+    assert tr.find("hash_finalize").attrs == {"groups": 1500, "rows": 1500}
+
+
+def test_unsampled_hash_query_allocates_no_span(clh, limit_devices):
+    limit_devices(1)
+    clh.execute("SET citus.trace_sample_rate = 0")
+    clh.execute(HASH_Q)                                  # compiles
+    before = T.span_allocations()
+    clh.execute(HASH_Q)
+    assert T.span_allocations() == before
+
+
+@pytest.mark.parametrize("slots", [None, 1024, 64])
+def test_hash_counters_for_a_known_table(clh, limit_devices, slots):
+    limit_devices(1)
+    if slots is not None:
+        clh.execute(f"SET citus.hash_agg_slots = {slots}")
+    c0 = clh.counters.snapshot()
+    r = clh.execute(HASH_Q)
+    c1 = clh.counters.snapshot()
+    pl = r.explain["pipeline"]
+    # derived: the next power of two at or above the table's 6,000 rows
+    assert pl["hash_slots"] == (slots or 8192)
+    # groups after the merge of table and spills, however many spilled
+    assert c1["hash_groups_out"] - c0["hash_groups_out"] == 1500
+    assert pl["hash_groups_out"] == 1500
+    assert (c1["hash_spill_rows"] - c0["hash_spill_rows"]
+            == pl["hash_spilled_rows"])
+    assert (pl["hash_spilled_rows"] > 3000) == (slots == 64)
+    # a slot: int64 key + int8 flag, int64 sum + int64 count + float64
+    # shadow sum, int64 rows
+    fetched = c1["hash_table_bytes_fetched"] - c0["hash_table_bytes_fetched"]
+    assert fetched == pl["hash_table_bytes_fetched"] == pl["hash_slots"] * 41
