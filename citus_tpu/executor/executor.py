@@ -9,8 +9,10 @@ Maps a PhysicalPlan onto the available backend:
   analog where the event loop is replaced by XLA's async dispatch)
 
 Partial states from multiple rounds (more shards/batches than devices)
-merge on the host, exactly like the reference merges per-task tuples on
-the coordinator.
+merge on the device, in an accumulator every round folds into; the host
+fetches it once a query.  Only the states of other coordinators' tasks
+merge on the host, like the reference merges per-task tuples on the
+coordinator.
 """
 
 from __future__ import annotations
@@ -77,29 +79,17 @@ def _combine(plan: PhysicalPlan, partial_sets: list):
         return combine_partials_host(plan, partial_sets)
 
 
-def _fetch_rounds(plan: PhysicalPlan, acc: list):
-    """Tail of both mesh loops: wait until the chip has finished the
-    first round, copy the rounds' partial states back one by one,
-    combine them on the host — a span each.  Only the first round is
-    waited for: the copy of round i runs while the chip works on round
-    i + 1, which hides most of it on four chips, and a wait costs a few
-    tenths of a millisecond even for arrays that are ready (PERF.md,
-    PR 23: one wait for ALL rounds before the fetch cost 35 ms of an
-    85 ms query, a wait per round 2.6 ms).  A later round the chip has
-    not finished yet is waited for inside its ``fetch``."""
-    _block_ready(acc[0][-1:])
-    acc_np = []
-    for out in acc:
-        with _trace.span("fetch") as sp:
-            acc_np.append(tuple(np.asarray(o) for o in out))
-            if sp.recording:
-                sp.set(arrays=len(out), bytes=_nbytes(acc_np[-1]))
-    return _combine(plan, acc_np)
-
-
 def _fetch_acc(acc_dev):
-    """Tail of both single-device loops: wait for the chip, then copy
-    the partial states back (one device_get)."""
+    """Tail of all four device loops: wait for the chip, then copy the
+    partial states back (one device_get).  On the mesh ``acc_dev`` is
+    replicated, and a copy comes from one chip.
+
+    PR 23 measured +42 % for "wait for all mesh rounds, then fetch".
+    That trap is gone with its cause: every round then had 13 outputs
+    of its own, so 104 copies ran one after another behind the kernel,
+    where a copy per round had run beside the next round.  With the
+    states folded on the chips there are 13 arrays to copy, once, and
+    nothing to copy before the last round has run."""
     import jax
     _block_ready(acc_dev[-1:])
     with _trace.span("fetch") as sp:
@@ -192,32 +182,30 @@ def _run_partials_cpu(cat: Catalog, plan: PhysicalPlan, settings: Settings,
 
 def _empty_partials(plan: PhysicalPlan, xp):
     """Zero-row partial states (so empty tables still produce a row for
-    global aggregates)."""
+    global aggregates); with jax.numpy, traced: the mesh loop fills its
+    first accumulator on the chips."""
     from citus_tpu.ops.scan_agg import _sentinel
+    from citus_tpu.planner.aggregates import DDSK_M, HLL_M, TOPK_M
     G = plan.group_mode.n_groups if plan.group_mode.kind == "direct" else None
     outs = []
     for op in plan.partial_ops:
         dt = np.dtype(op.dtype)
         if op.kind == "hll":
-            from citus_tpu.planner.aggregates import HLL_M
-            outs.append(np.zeros((HLL_M,), np.int32))
+            outs.append(xp.zeros((HLL_M,), np.int32))
         elif op.kind == "ddsk":
-            from citus_tpu.planner.aggregates import DDSK_M
-            outs.append(np.zeros((DDSK_M,), np.int64))
+            outs.append(xp.zeros((DDSK_M,), np.int64))
         elif op.kind == "topk":
-            from citus_tpu.planner.aggregates import TOPK_M
-            outs.append(np.zeros((TOPK_M,), np.int64))
+            outs.append(xp.zeros((TOPK_M,), np.int64))
         elif op.kind == "topkv":
-            from citus_tpu.planner.aggregates import TOPK_M
-            outs.append(np.full((TOPK_M,), np.iinfo(np.int64).min, np.int64))
+            outs.append(xp.full((TOPK_M,), np.iinfo(np.int64).min, np.int64))
         elif op.kind in ("sum", "count"):
             base = np.int64(0) if op.kind == "count" else dt.type(0)
-            outs.append(np.zeros((G,), dt) if G else np.asarray(base, dt))
+            outs.append(xp.zeros((G,), dt) if G else xp.asarray(base, dt))
         else:
             sent = dt.type(_sentinel(op.kind, dt))
-            outs.append(np.full((G,), sent, dt) if G else np.asarray(sent, dt))
+            outs.append(xp.full((G,), sent, dt) if G else xp.asarray(sent, dt))
     if G:
-        outs.append(np.zeros((G,), np.int64))
+        outs.append(xp.zeros((G,), np.int64))
     return tuple(outs)
 
 
@@ -291,11 +279,12 @@ def _repad_batch(b: ShardBatch, bucket: int) -> ShardBatch:
     return ShardBatch(cols, valids, mask, b.n_rows, bucket, b.shard_index)
 
 
-def _run_mesh_round(plan, run, buf: list, n_dev: int, shard_sharding,
-                    p_stack, pv_stack, collect):
-    """Stack one round of host batches onto the mesh, run the sharded
-    worker+collective, and (optionally) retain the device-sharded inputs
-    for the HBM cache.  -> (device outputs, input bytes)."""
+def _run_mesh_round(plan, run, acc_dev, buf: list, n_dev: int,
+                    shard_sharding, p_stack, pv_stack, collect):
+    """Stack one round of host batches onto the mesh, fold it into the
+    accumulator (sharded worker + collective + merge, one dispatch),
+    and (optionally) retain the device-sharded inputs for the HBM
+    cache.  -> (the next accumulator, input bytes)."""
     import jax
     from citus_tpu.testing.faults import FAULTS
     # delay injections here model device-side round latency for the
@@ -324,14 +313,29 @@ def _run_mesh_round(plan, run, buf: list, n_dev: int, shard_sharding,
             if sp.recording:
                 sp.set(bytes=nbytes)
         with _trace.span("dispatch") as sp:
-            out = run(dcols + p_stack, dvalids + pv_stack, dmask)
+            acc_dev = run(acc_dev, dcols + p_stack, dvalids + pv_stack,
+                          dmask)
             if sp.recording:
                 sp.set(slot="mesh_run")
         if collect is not None:
             collect.append((dcols, dvalids, dmask))
         if rsp.recording:
             rsp.set(batches=n_real, bytes=nbytes, resident=False)
-    return out, nbytes
+    return acc_dev, nbytes
+
+
+def _mesh_rounds(batches, n_dev: int):
+    """Group a stream of host batches into mesh rounds of ``n_dev``;
+    the last round holds what is left (``_run_mesh_round`` fills it up
+    with empty batches)."""
+    buf: list = []
+    for hb in batches:
+        buf.append(hb)
+        if len(buf) == n_dev:
+            yield buf
+            buf = []
+    if buf:
+        yield buf
 
 
 def _book_mesh_round(buf: list, nb: int, round_s: float,
@@ -350,6 +354,14 @@ def _book_mesh_round(buf: list, nb: int, round_s: float,
             (mb.shard_index, mb.n_rows, round_s / len(real)))
 
 
+def _book_fused_dispatches(plan: PhysicalPlan, n: int) -> None:
+    """Rounds folded into a device-resident accumulator (one dispatch
+    each, on one chip or on the mesh): the process counter and EXPLAIN
+    ANALYZE's pipeline line."""
+    GLOBAL_COUNTERS.bump("fused_dispatches", n)
+    plan.runtime_cache.setdefault("pipeline", {})["fused_dispatches"] = n
+
+
 def _run_partials_jax(cat: Catalog, plan: PhysicalPlan, settings: Settings,
                       params=((), ())):
     import jax
@@ -359,6 +371,7 @@ def _run_partials_jax(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     )
     from citus_tpu.parallel.mesh import (
         default_mesh, executor_devices, sharded_partial_agg, shard_axis_size,
+        zero_partials,
     )
 
     from citus_tpu.executor.device_cache import GLOBAL_CACHE, plan_cache_key
@@ -390,54 +403,75 @@ def _run_partials_jax(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     # single-device path below without touching disk — only enter the
     # mesh machinery when no such entry exists
     if len(devices) > 1 and cached is None:
-        from collections import deque
         with _trace.span("scan_setup"):
             mesh = default_mesh()
             n_dev = shard_axis_size(mesh)
         # mesh cache entries are device-sharded stacks — a different
         # structure than the single-device ShardBatch list, so they key
         # separately
-        mkey = key + ("mesh", n_dev)
+        mesh_tag = ("mesh", n_dev)
+        mkey = key + mesh_tag
         with _trace.span("cache_lookup") as sp:
             mcached = None if overlaid else GLOBAL_CACHE.get(mkey)
             if sp.recording:
                 sp.set(hit=mcached is not None, mesh=True)
+        # one mesh ROUND is run(acc, inputs) -> acc': every chip runs
+        # the worker on its batch, the collective merges the chips'
+        # partial states, and the result is folded into ``acc``, which
+        # is replicated, donated, and stays on the chips from round to
+        # round.  The query ends as on one chip: one wait, one fetch
+        # (_fetch_acc says why PR 23's +42 % for that does not apply)
         run = get_kernel(
             plan, "mesh_run",
             lambda: sharded_partial_agg(build_worker_fn(plan, jnp), kinds,
                                         mesh),
-            extra=("mesh", n_dev))
+            extra=mesh_tag)
+        zero = get_kernel(
+            plan, "mesh_zero",
+            lambda: zero_partials(lambda: _empty_partials(plan, jnp), mesh),
+            extra=mesh_tag)
+        from jax.sharding import NamedSharding, PartitionSpec
+        shard_sharding = NamedSharding(mesh, PartitionSpec("shard"))
         # parameters replicate across the shard axis ([n_dev] stacks of
-        # the 0-d values); never cached — they change per execution
+        # the 0-d values), put on the mesh once for all rounds; never
+        # cached — they change per execution
         with _trace.span("bind_params"):
-            p_stack = tuple(np.stack([p] * n_dev) for p in pcols)
-            pv_stack = tuple(np.stack([v] * n_dev) for v in pvalids)
-        acc: list = []
+            p_stack, pv_stack = jax.device_put(
+                (tuple(np.stack([p] * n_dev) for p in pcols),
+                 tuple(np.stack([v] * n_dev) for v in pvalids)),
+                shard_sharding)
+
+        def init_acc():
+            with _trace.span("init_acc") as sp:
+                acc_dev = zero()
+                if sp.recording:
+                    sp.set(arrays=len(acc_dev), bytes=_nbytes(acc_dev))
+            return acc_dev
+
         if mcached is not None:
+            acc_dev = init_acc()
             for dcols, dvalids, dmask in mcached:
                 with _trace.span("device_round") as rsp:
                     with _trace.span("dispatch") as sp:
-                        acc.append(run(dcols + p_stack, dvalids + pv_stack,
-                                       dmask))
+                        acc_dev = run(acc_dev, dcols + p_stack,
+                                      dvalids + pv_stack, dmask)
                         if sp.recording:
                             sp.set(slot="mesh_run")
                     if rsp.recording:
                         rsp.set(batches=n_dev, resident=True,
                                 bytes=_nbytes(dcols) + _nbytes(dvalids)
                                 + dmask.nbytes)
-            return _fetch_rounds(plan, acc)
+            _book_fused_dispatches(plan, len(mcached))
+            return _fetch_acc(acc_dev)
         # streaming mesh path: group the lazy host stream into device
         # rounds of n_dev, re-padded to the round's max bucket — the
         # host never materializes more than one round plus the bounded
         # in-flight window (SURVEY §2.4 "Pipelined ingest"; closes the
         # round-3 gap where the mesh path loaded every batch up front)
-        from jax.sharding import NamedSharding, PartitionSpec
-        shard_sharding = NamedSharding(mesh, PartitionSpec("shard"))
         collect: Optional[list] = None if overlaid else []
         nbytes = 0
         task_bytes: list = []
         mesh_task_times: list = []
-        inflight: deque = deque()
         stream = _iter_padded_batches(cat, plan, settings)
         t_peek = clock()
         first = next(stream, None)
@@ -455,47 +489,40 @@ def _run_partials_jax(cat: Catalog, plan: PhysicalPlan, settings: Settings,
             host_iter_m = prefetch_batches(
                 _it.chain([first, second], stream),
                 read_ahead_depth(settings) * n_dev, pstats)
-            buf: list = []
+            acc_dev = init_acc()
+            n_rounds = since_sync = 0
+            depth = _prefetch_depth(settings)
             try:
-                for hb in host_iter_m:
-                    buf.append(hb)
-                    if len(buf) < n_dev:
-                        continue
+                for buf in _mesh_rounds(host_iter_m, n_dev):
                     t_dev = clock()
-                    out, nb = _run_mesh_round(
-                        plan, run, buf, n_dev, shard_sharding,
+                    acc_dev, nb = _run_mesh_round(
+                        plan, run, acc_dev, buf, n_dev, shard_sharding,
                         p_stack, pv_stack, collect)
-                    acc.append(out)
+                    n_rounds += 1
                     nbytes += nb
                     _book_mesh_round(buf, nb, clock() - t_dev,
                                      task_bytes, mesh_task_times)
-                    buf = []
                     if collect is not None and nbytes > GLOBAL_CACHE.capacity:
                         collect = None  # working set exceeds HBM cache: stream
                     if collect is None:
-                        inflight.append(out)
-                        if len(inflight) > _prefetch_depth(settings):
-                            _block_ready(inflight.popleft())
-                    pstats.device_s += clock() - t_dev
-                if buf:
-                    t_dev = clock()
-                    out, nb = _run_mesh_round(
-                        plan, run, buf, n_dev, shard_sharding,
-                        p_stack, pv_stack, collect)
-                    acc.append(out)
-                    nbytes += nb
-                    _book_mesh_round(buf, nb, clock() - t_dev,
-                                     task_bytes, mesh_task_times)
+                        # bound in-flight device memory as the one-chip
+                        # streaming loop does: the accumulator chain
+                        # orders the rounds, so a wait for the current
+                        # one retires every round admitted before it
+                        since_sync += 1
+                        if since_sync >= depth:
+                            _block_ready(acc_dev[-1:])
+                            since_sync = 0
                     pstats.device_s += clock() - t_dev
             finally:
                 host_iter_m.close()
-            if collect is not None and nbytes <= GLOBAL_CACHE.capacity:
+            if collect is not None:
                 _block_ready([r[0] for r in collect])
                 with _trace.span("cache_put"):
                     GLOBAL_CACHE.put(mkey, collect, nbytes,
                                      tenant=cache_tenant)
             t_dev = clock()
-            partials = _fetch_rounds(plan, acc)
+            partials = _fetch_acc(acc_dev)
             pstats.device_s += clock() - t_dev
             pstats.h2d_bytes = nbytes
             GLOBAL_COUNTERS.bump("bytes_scanned", nbytes)
@@ -506,6 +533,7 @@ def _run_partials_jax(cat: Catalog, plan: PhysicalPlan, settings: Settings,
             # split across the round's shard members
             plan.runtime_cache["mesh_task_times"] = mesh_task_times
             pstats.publish(plan)
+            _book_fused_dispatches(plan, n_rounds)
             return partials
 
     # ---- single-device path: fused streaming pipeline + HBM pinning --
@@ -630,16 +658,13 @@ def _run_partials_jax(cat: Catalog, plan: PhysicalPlan, settings: Settings,
         partials = _fetch_acc(acc_dev)
         pstats.device_s += clock() - t_dev
         pstats.publish(plan)
-        GLOBAL_COUNTERS.bump("fused_dispatches", n_dispatch)
-        pl = plan.runtime_cache.setdefault("pipeline", {})
-        pl["fused_dispatches"] = n_dispatch
-        pl["stream_window_peak_bytes"] = window_peak
+        _book_fused_dispatches(plan, n_dispatch)
+        plan.runtime_cache["pipeline"]["stream_window_peak_bytes"] = \
+            window_peak
         plan.runtime_cache["task_times"] = task_times
         plan.runtime_cache["task_bytes"] = task_bytes
         return partials
-    GLOBAL_COUNTERS.bump("fused_dispatches", n_dispatch)
-    plan.runtime_cache.setdefault("pipeline", {})["fused_dispatches"] = \
-        n_dispatch
+    _book_fused_dispatches(plan, n_dispatch)
     plan.runtime_cache["task_times"] = task_times
     plan.runtime_cache["task_bytes"] = task_bytes
     return _fetch_acc(acc_dev)

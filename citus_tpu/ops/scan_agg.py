@@ -271,6 +271,14 @@ def combine_kinds(plan: PhysicalPlan) -> list[str]:
     return kinds
 
 
+def fold_partials(xp, kinds: list[str], acc, out) -> tuple:
+    """acc (+) out, elementwise per combine kind: the running merge of
+    the device loops (one chip: inside ``jit_fused``; mesh: inside
+    ``jit_run``, behind the round's collective)."""
+    fold = {"sum": lambda a, o: a + o, "min": xp.minimum, "max": xp.maximum}
+    return tuple(fold[kind](a, o) for a, o, kind in zip(acc, out, kinds))
+
+
 def build_fused_worker_fn(plan: PhysicalPlan, xp) -> Callable:
     """Fused single-dispatch hot loop: decode→filter→partial-agg AND
     the running cross-batch merge in one kernel.
@@ -290,16 +298,7 @@ def build_fused_worker_fn(plan: PhysicalPlan, xp) -> Callable:
     kinds = combine_kinds(plan)
 
     def fused(acc, cols, valids, row_mask):
-        out = worker(cols, valids, row_mask)
-        new = []
-        for a, o, kind in zip(acc, out, kinds):
-            if kind == "sum":
-                new.append(a + o)
-            elif kind == "min":
-                new.append(xp.minimum(a, o))
-            else:
-                new.append(xp.maximum(a, o))
-        return tuple(new)
+        return fold_partials(xp, kinds, acc, worker(cols, valids, row_mask))
 
     return fused
 

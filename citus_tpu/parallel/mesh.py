@@ -2,10 +2,10 @@
 
 ``sharded_partial_agg`` is the north-star lowering (SURVEY §2.4): each
 mesh slot runs the worker kernel on its shard's batch, then the partial
-states are combined in-mesh with psum/pmin/pmax so every device (and the
-host) sees the merged table after one collective — the reference needs a
-coordinator gather plus a combine query for the same step
-(multi_logical_optimizer.c MasterExtendedOpNode).
+states are combined in-mesh with psum / all_gather and folded into the
+running states of the rounds before, which stay on the chips — the
+reference needs a coordinator gather plus a combine query for the same
+step (multi_logical_optimizer.c MasterExtendedOpNode).
 """
 
 from __future__ import annotations
@@ -14,10 +14,11 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from citus_tpu.errors import ExecutionError
 from citus_tpu.executor.kernel_cache import jit_compile
+from citus_tpu.ops.scan_agg import fold_partials
 
 SHARD_AXIS = "shard"
 
@@ -59,16 +60,31 @@ def shard_axis_size(mesh: Mesh) -> int:
 
 
 def sharded_partial_agg(worker, combine_kinds: list[str], mesh: Mesh) -> Callable:
-    """Wrap a worker fn (cols, valids, row_mask) -> partial tuple into a
-    shard_map'd program over stacked inputs [n_dev, N]:
+    """Wrap a worker fn (cols, valids, row_mask) -> partial tuple into
+    one mesh ROUND over stacked inputs [n_dev, N]:
 
-      out[i] = combine_over_shards(worker(inputs[shard]))   (replicated)
+      run(acc, cols, valids, row_mask) -> acc'
+      acc'[i] = acc[i] (+) combine_over_shards(worker(inputs[shard]))[i]
 
-    combine_kinds[i] in {sum, min, max, none} selects the collective per
-    output position; 'none' outputs are returned stacked per-shard.
-    """
+    ``acc`` holds the running partial states of the rounds before this
+    one: replicated (``P()``: every chip holds and folds its own copy),
+    donated, shaped like ``zero_partials``' output.  combine_kinds[i]
+    in {sum, min, max} selects the collective per position and the
+    elementwise fold (+) behind it (``fold_partials``, the rule the
+    one-chip ``jit_fused`` loop uses).  The states never leave the
+    chips between rounds; the caller fetches the last ``acc`` once.
 
-    def per_shard(cols, valids, row_mask):
+    The jitted function is called ``run`` and so its XLA module
+    ``jit_run``: the device trace is read by that name (kernel time,
+    roofline share, dispatches per query)."""
+    bad = sorted(set(combine_kinds) - {"sum", "min", "max"})
+    if bad:
+        # a stacked per-shard output has no elementwise fold: refuse
+        # rather than merge it wrongly
+        raise ValueError(f"partial states of kind {bad} cannot be folded "
+                         "on the mesh")
+
+    def per_shard(acc, cols, valids, row_mask):
         cols = tuple(c[0] for c in cols)      # strip the leading shard dim
         valids = tuple(v[0] for v in valids)
         row_mask = row_mask[0]
@@ -77,29 +93,37 @@ def sharded_partial_agg(worker, combine_kinds: list[str], mesh: Mesh) -> Callabl
         for p, kind in zip(partials, combine_kinds):
             if kind == "sum":
                 outs.append(jax.lax.psum(p, SHARD_AXIS))
-            elif kind in ("min", "max"):
+            else:
                 # TPU lowers only Sum all-reduces; min/max combine as an
                 # all_gather over ICI followed by a local reduction
                 g = jax.lax.all_gather(p, SHARD_AXIS)
-                outs.append(jnp.min(g, axis=0) if kind == "min" else jnp.max(g, axis=0))
-            else:
-                outs.append(p[None])
-        return tuple(outs)
+                outs.append(jnp.min(g, axis=0) if kind == "min"
+                            else jnp.max(g, axis=0))
+        return fold_partials(jnp, combine_kinds, acc, outs)
 
-    n_in = None  # in_specs built per call from pytree structure
-
-    def run(cols, valids, row_mask):
+    def run(acc, cols, valids, row_mask):
         in_specs = (
+            tuple(P() for _ in acc),
             tuple(P(SHARD_AXIS) for _ in cols),
             tuple(P(SHARD_AXIS) for _ in valids),
             P(SHARD_AXIS),
         )
-        out_specs = tuple(
-            P(SHARD_AXIS) if kind == "none" else P()
-            for kind in combine_kinds
-        )
         fn = jax.shard_map(per_shard, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False)
-        return fn(cols, valids, row_mask)
+                           out_specs=tuple(P() for _ in acc),
+                           check_vma=False)
+        return fn(acc, cols, valids, row_mask)
 
-    return jit_compile(run)
+    return jit_compile(run, donate_argnums=0)
+
+
+def zero_partials(empty_partials: Callable[[], tuple], mesh: Mesh) -> Callable:
+    """() -> the partial states of zero rows, replicated on the mesh:
+    the first round's ``acc``.  ``empty_partials`` builds them with
+    jax.numpy, so they are filled on the chips: one small dispatch a
+    query instead of a host copy per state and chip.  A step of its own
+    (module ``jit_zero_acc``) because ``run`` donates its ``acc``."""
+
+    def zero_acc():
+        return empty_partials()
+
+    return jit_compile(zero_acc, out_shardings=NamedSharding(mesh, P()))
