@@ -467,8 +467,7 @@ def _stream_insert_select(cl, ing, target, bound, plan, fns, ffn,
     from citus_tpu.planner.bound import predicate_mask
     total = 0
     for si in plan.shard_indexes:
-        for values, masks, n in load_shard_batches(
-                cl.catalog, plan, si, min_batch_rows=1):
+        for values, masks, n in load_shard_batches(cl.catalog, plan, si):
             env = {c: (values[c].astype(
                         bound.table.schema.scan_dtype(c, device=True), copy=False),
                        masks[c]) for c in plan.scan_columns}

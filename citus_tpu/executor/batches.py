@@ -187,7 +187,7 @@ def _cut_batches(cat: Catalog, plan: PhysicalPlan, shard_index: int,
 
 def load_shard_batches(
     cat: Catalog, plan: PhysicalPlan, shard_index: int, *,
-    min_batch_rows: int = 8192, max_batch_rows: int = 1 << 22,
+    max_batch_rows: int = 1 << 22,
     node_override: Optional[int] = None,
     prefer_secondary: bool = False,
 ) -> Iterator[tuple[dict[str, np.ndarray], dict[str, np.ndarray], int]]:

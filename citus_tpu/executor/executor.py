@@ -28,10 +28,14 @@ from citus_tpu.catalog import Catalog
 from citus_tpu.config import Settings
 from citus_tpu.errors import ExecutionError
 from citus_tpu.executor.batches import (
-    ShardBatch, empty_batch, load_padded_batches, load_shard_batches,
+    load_padded_batches, load_shard_batches,
 )
 from citus_tpu.executor.finalize import finalize_groups, order_and_limit, project_rows
 from citus_tpu.executor.kernel_cache import get_kernel, jit_compile
+from citus_tpu.executor.scan_loop import (
+    OneDevice, Step, _block_ready, _nbytes, _prefetch_depth,
+    choose_placement, drive,
+)
 from citus_tpu.observability import trace as _trace
 from citus_tpu.observability.trace import clock
 from citus_tpu.ops.scan_agg import (
@@ -43,30 +47,11 @@ from citus_tpu.planner.bind import BoundSelect
 from citus_tpu.planner.physical import (
     PhysicalPlan, _index_eq, extract_intervals, plan_select, prune_shards,
 )
-from citus_tpu.stats import StatCounters, begin_wait, end_wait
+from citus_tpu.stats import StatCounters
 
 # process-wide counters (the citus_stat_counters analog); Cluster exposes
 # a view over this
 GLOBAL_COUNTERS = StatCounters()
-
-
-def _block_ready(x) -> None:
-    """block_until_ready under a device_round wait bracket: the stretch
-    the backend spends blocked on device backpressure shows up in the
-    activity view and the wait_device_round_ms counter.  On a TPU a
-    wait costs a few tenths of a millisecond even when every array is
-    ready (PERF.md, PR 23): where the arrays are the outputs of one
-    dispatch, which become ready together, callers pass one of them."""
-    import jax
-    wtok = begin_wait("device_round")
-    try:
-        jax.block_until_ready(x)
-    finally:
-        end_wait(wtok)
-
-
-def _nbytes(arrays) -> int:
-    return int(sum(a.nbytes for a in arrays))
 
 
 def _combine(plan: PhysicalPlan, partial_sets: list):
@@ -80,7 +65,7 @@ def _combine(plan: PhysicalPlan, partial_sets: list):
 
 
 def _fetch_acc(acc_dev):
-    """Tail of all four device loops: wait for the chip, then copy the
+    """Tail of every aggregate scan: wait for the chip, then copy the
     partial states back (one device_get).  On the mesh ``acc_dev`` is
     replicated, and a copy comes from one chip.
 
@@ -167,8 +152,7 @@ def _run_partials_cpu(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     pcols, pvalids = params
     shard_results = []
     for si in plan.shard_indexes:
-        for values, masks, n in load_shard_batches(
-                cat, plan, si, min_batch_rows=1):
+        for values, masks, n in load_shard_batches(cat, plan, si):
             cols = tuple(values[c].astype(
                 plan.bound.table.schema.scan_dtype(c, device=True),
                 copy=False) for c in plan.scan_columns)
@@ -207,16 +191,6 @@ def _empty_partials(plan: PhysicalPlan, xp):
     if G:
         outs.append(xp.zeros((G,), np.int64))
     return tuple(outs)
-
-
-def _prefetch_depth(settings: Settings) -> int:
-    """Device-side in-flight window: streaming mode keeps at most this
-    many batch outputs un-synced ahead of the kernel consuming them.
-    Governed by SET citus.executor_prefetch_depth (floor of 1 so the
-    depth-0 'decode inline' setting still double-buffers the device);
-    max_tasks_in_flight raises the window further."""
-    return max(1, settings.executor.executor_prefetch_depth,
-               settings.executor.max_tasks_in_flight)
 
 
 def _iter_padded_batches(cat: Catalog, plan: PhysicalPlan, settings: Settings):
@@ -267,120 +241,47 @@ def _iter_padded_batches(cat: Catalog, plan: PhysicalPlan, settings: Settings):
         batches.close()
 
 
-def _repad_batch(b: ShardBatch, bucket: int) -> ShardBatch:
-    """Grow a padded batch to a larger bucket (mesh rounds stack, so all
-    members share one shape)."""
-    pad = bucket - b.padded_rows
-    if pad <= 0:
-        return b
-    cols = tuple(np.concatenate([c, np.zeros(pad, c.dtype)]) for c in b.cols)
-    valids = tuple(np.concatenate([v, np.ones(pad, bool)]) for v in b.valids)
-    mask = np.concatenate([b.row_mask, np.zeros(pad, bool)])
-    return ShardBatch(cols, valids, mask, b.n_rows, bucket, b.shard_index)
-
-
-def _run_mesh_round(plan, run, acc_dev, buf: list, n_dev: int,
-                    shard_sharding, p_stack, pv_stack, collect):
-    """Stack one round of host batches onto the mesh, fold it into the
-    accumulator (sharded worker + collective + merge, one dispatch),
-    and (optionally) retain the device-sharded inputs for the HBM
-    cache.  -> (the next accumulator, input bytes)."""
+def _agg_step(plan: PhysicalPlan, placement):
+    """The scan-aggregate round kernel for where the rounds live, and
+    the maker of its first state.  XLA's fusion of the jitted body is
+    the kernel: it folds the per-batch worker AND the running merge into
+    ONE dispatch, the partial-agg registers riding along as a donated
+    argument, so the accumulators never leave the device until the
+    final device_get.  On the mesh a round is run(acc, inputs) -> acc':
+    every chip runs the worker on its batch, the collective merges the
+    chips' partial states and the result folds into the replicated
+    ``acc``; the first one is filled on the chips (one dispatch)."""
     import jax
-    from citus_tpu.testing.faults import FAULTS
-    # delay injections here model device-side round latency for the
-    # host/device overlap tests (the decode half is decode_batch)
-    FAULTS.hit("device_round", plan.bound.table.name)
-    with _trace.span("device_round") as rsp:
-        n_real = len(buf)
-        with _trace.span("stack") as sp:
-            bucket = max(b.padded_rows for b in buf)
-            while len(buf) < n_dev:
-                buf.append(empty_batch(plan.bound.table, plan, bucket, -1))
-            buf = [_repad_batch(b, bucket) for b in buf]
-            cols = tuple(np.stack([b.cols[i] for b in buf])
-                         for i in range(len(plan.scan_columns)))
-            valids = tuple(np.stack([b.valids[i] for b in buf])
-                           for i in range(len(plan.scan_columns)))
-            mask = np.stack([b.row_mask for b in buf])
-            nbytes = _nbytes(cols) + _nbytes(valids) + mask.nbytes
-            if sp.recording:
-                sp.set(bytes=nbytes)
-        with _trace.span("h2d") as sp:
-            dcols = tuple(jax.device_put(c, shard_sharding) for c in cols)
-            dvalids = tuple(jax.device_put(v, shard_sharding)
-                            for v in valids)
-            dmask = jax.device_put(mask, shard_sharding)
-            if sp.recording:
-                sp.set(bytes=nbytes)
-        with _trace.span("dispatch") as sp:
-            acc_dev = run(acc_dev, dcols + p_stack, dvalids + pv_stack,
-                          dmask)
-            if sp.recording:
-                sp.set(slot="mesh_run")
-        if collect is not None:
-            collect.append((dcols, dvalids, dmask))
-        if rsp.recording:
-            rsp.set(batches=n_real, bytes=nbytes, resident=False)
-    return acc_dev, nbytes
-
-
-def _mesh_rounds(batches, n_dev: int):
-    """Group a stream of host batches into mesh rounds of ``n_dev``;
-    the last round holds what is left (``_run_mesh_round`` fills it up
-    with empty batches)."""
-    buf: list = []
-    for hb in batches:
-        buf.append(hb)
-        if len(buf) == n_dev:
-            yield buf
-            buf = []
-    if buf:
-        yield buf
-
-
-def _book_mesh_round(buf: list, nb: int, round_s: float,
-                     task_bytes: list, mesh_task_times: list) -> None:
-    """Split one mesh round's H2D bytes and device time across the
-    round's REAL shard members for attribution (``_run_mesh_round``
-    appends shard_index=-1 pad batches into ``buf`` in place; their
-    padding overhead belongs to the shards that forced the round).  The
-    byte remainder lands on the first member so the ledger total stays
-    exactly equal to the bytes_scanned counter bump."""
-    real = [mb for mb in buf if mb.shard_index >= 0] or buf
-    share, rem = divmod(int(nb), len(real))
-    for i, mb in enumerate(real):
-        task_bytes.append((mb.shard_index, share + (rem if i == 0 else 0)))
-        mesh_task_times.append(
-            (mb.shard_index, mb.n_rows, round_s / len(real)))
-
-
-def _book_fused_dispatches(plan: PhysicalPlan, n: int) -> None:
-    """Rounds folded into a device-resident accumulator (one dispatch
-    each, on one chip or on the mesh): the process counter and EXPLAIN
-    ANALYZE's pipeline line."""
-    GLOBAL_COUNTERS.bump("fused_dispatches", n)
-    plan.runtime_cache.setdefault("pipeline", {})["fused_dispatches"] = n
+    import jax.numpy as jnp
+    if placement.mesh is None:
+        fused = get_kernel(
+            plan, "jit_fused",
+            lambda: jit_compile(build_fused_worker_fn(plan, jnp),
+                                donate_argnums=0))
+        return (Step(fused, "jit_fused", "fused_dispatches"),
+                lambda: tuple(jax.device_put(p)
+                              for p in _empty_partials(plan, np)))
+    from citus_tpu.parallel.mesh import sharded_partial_agg, zero_partials
+    run = get_kernel(
+        plan, "mesh_run",
+        lambda: sharded_partial_agg(build_worker_fn(plan, jnp),
+                                    combine_kinds(plan), placement.mesh),
+        extra=placement.key_suffix)
+    zero = get_kernel(
+        plan, "mesh_zero",
+        lambda: zero_partials(lambda: _empty_partials(plan, jnp),
+                              placement.mesh),
+        extra=placement.key_suffix)
+    return Step(run, "mesh_run", "fused_dispatches"), zero
 
 
 def _run_partials_jax(cat: Catalog, plan: PhysicalPlan, settings: Settings,
                       params=((), ())):
-    import jax
-    import jax.numpy as jnp
-    from citus_tpu.executor.pipeline import (
-        PipelineStats, prefetch_batches, read_ahead_depth,
-    )
-    from citus_tpu.parallel.mesh import (
-        default_mesh, executor_devices, sharded_partial_agg, shard_axis_size,
-        zero_partials,
-    )
-
-    from citus_tpu.executor.device_cache import GLOBAL_CACHE, plan_cache_key
+    from citus_tpu.executor.pipeline import PipelineStats
     from citus_tpu.storage.overlay import current_overlay
+    from citus_tpu.workload import tenant_key
 
-    pcols, pvalids = params
     with _trace.span("scan_setup"):
-        devices = executor_devices()
-        kinds = combine_kinds(plan)
         pstats = PipelineStats()
         _trace.set_phase("device")
         # an open transaction's staged writes change what a scan sees
@@ -388,286 +289,27 @@ def _run_partials_jax(cat: Catalog, plan: PhysicalPlan, settings: Settings,
         # tables the transaction touched (other tables still hit it)
         txn = current_overlay()
         overlaid = txn is not None and plan.bound.table.name in txn.tables
-    with _trace.span("cache_lookup") as sp:
-        key = plan_cache_key(plan, cat.data_dir)
-        cached = None if overlaid else GLOBAL_CACHE.get(key)
-        if sp.recording:
-            sp.set(hit=cached is not None, mesh=False)
-    # HBM attribution: resident entries are charged to the tenant whose
-    # query pinned them (the shared bucket for non-router scans)
-    from citus_tpu.workload import tenant_key
-    cache_tenant = tenant_key(plan.router_key)
-
-    host_iter = None
-    # a single-batch table cached under the non-mesh key serves from the
-    # single-device path below without touching disk — only enter the
-    # mesh machinery when no such entry exists
-    if len(devices) > 1 and cached is None:
-        with _trace.span("scan_setup"):
-            mesh = default_mesh()
-            n_dev = shard_axis_size(mesh)
-        # mesh cache entries are device-sharded stacks — a different
-        # structure than the single-device ShardBatch list, so they key
-        # separately
-        mesh_tag = ("mesh", n_dev)
-        mkey = key + mesh_tag
-        with _trace.span("cache_lookup") as sp:
-            mcached = None if overlaid else GLOBAL_CACHE.get(mkey)
-            if sp.recording:
-                sp.set(hit=mcached is not None, mesh=True)
-        # one mesh ROUND is run(acc, inputs) -> acc': every chip runs
-        # the worker on its batch, the collective merges the chips'
-        # partial states, and the result is folded into ``acc``, which
-        # is replicated, donated, and stays on the chips from round to
-        # round.  The query ends as on one chip: one wait, one fetch
-        # (_fetch_acc says why PR 23's +42 % for that does not apply)
-        run = get_kernel(
-            plan, "mesh_run",
-            lambda: sharded_partial_agg(build_worker_fn(plan, jnp), kinds,
-                                        mesh),
-            extra=mesh_tag)
-        zero = get_kernel(
-            plan, "mesh_zero",
-            lambda: zero_partials(lambda: _empty_partials(plan, jnp), mesh),
-            extra=mesh_tag)
-        from jax.sharding import NamedSharding, PartitionSpec
-        shard_sharding = NamedSharding(mesh, PartitionSpec("shard"))
-        # parameters replicate across the shard axis ([n_dev] stacks of
-        # the 0-d values), put on the mesh once for all rounds; never
-        # cached — they change per execution
-        with _trace.span("bind_params"):
-            p_stack, pv_stack = jax.device_put(
-                (tuple(np.stack([p] * n_dev) for p in pcols),
-                 tuple(np.stack([v] * n_dev) for v in pvalids)),
-                shard_sharding)
-
-        def init_acc():
-            with _trace.span("init_acc") as sp:
-                acc_dev = zero()
-                if sp.recording:
-                    sp.set(arrays=len(acc_dev), bytes=_nbytes(acc_dev))
-            return acc_dev
-
-        if mcached is not None:
-            acc_dev = init_acc()
-            for dcols, dvalids, dmask in mcached:
-                with _trace.span("device_round") as rsp:
-                    with _trace.span("dispatch") as sp:
-                        acc_dev = run(acc_dev, dcols + p_stack,
-                                      dvalids + pv_stack, dmask)
-                        if sp.recording:
-                            sp.set(slot="mesh_run")
-                    if rsp.recording:
-                        rsp.set(batches=n_dev, resident=True,
-                                bytes=_nbytes(dcols) + _nbytes(dvalids)
-                                + dmask.nbytes)
-            _book_fused_dispatches(plan, len(mcached))
-            return _fetch_acc(acc_dev)
-        # streaming mesh path: group the lazy host stream into device
-        # rounds of n_dev, re-padded to the round's max bucket — the
-        # host never materializes more than one round plus the bounded
-        # in-flight window (SURVEY §2.4 "Pipelined ingest"; closes the
-        # round-3 gap where the mesh path loaded every batch up front)
-        collect: Optional[list] = None if overlaid else []
-        nbytes = 0
-        task_bytes: list = []
-        mesh_task_times: list = []
-        stream = _iter_padded_batches(cat, plan, settings)
-        t_peek = clock()
-        first = next(stream, None)
-        if first is None:
-            return _combine(plan, [_empty_partials(plan, np)])
-        second = next(stream, None)
-        pstats.host_decode_s += clock() - t_peek
-        if second is None:
-            host_iter = iter([first])  # 1 batch: default-device path
-        else:
-            import itertools as _it
-            # host/device overlap: the decode thread prepares the NEXT
-            # round (up to executor_prefetch_depth rounds of n_dev
-            # batches) while the device executes the current one
-            host_iter_m = prefetch_batches(
-                _it.chain([first, second], stream),
-                read_ahead_depth(settings) * n_dev, pstats)
-            acc_dev = init_acc()
-            n_rounds = since_sync = 0
-            depth = _prefetch_depth(settings)
-            try:
-                for buf in _mesh_rounds(host_iter_m, n_dev):
-                    t_dev = clock()
-                    acc_dev, nb = _run_mesh_round(
-                        plan, run, acc_dev, buf, n_dev, shard_sharding,
-                        p_stack, pv_stack, collect)
-                    n_rounds += 1
-                    nbytes += nb
-                    _book_mesh_round(buf, nb, clock() - t_dev,
-                                     task_bytes, mesh_task_times)
-                    if collect is not None and nbytes > GLOBAL_CACHE.capacity:
-                        collect = None  # working set exceeds HBM cache: stream
-                    if collect is None:
-                        # bound in-flight device memory as the one-chip
-                        # streaming loop does: the accumulator chain
-                        # orders the rounds, so a wait for the current
-                        # one retires every round admitted before it
-                        since_sync += 1
-                        if since_sync >= depth:
-                            _block_ready(acc_dev[-1:])
-                            since_sync = 0
-                    pstats.device_s += clock() - t_dev
-            finally:
-                host_iter_m.close()
-            if collect is not None:
-                _block_ready([r[0] for r in collect])
-                with _trace.span("cache_put"):
-                    GLOBAL_CACHE.put(mkey, collect, nbytes,
-                                     tenant=cache_tenant)
-            t_dev = clock()
-            partials = _fetch_acc(acc_dev)
-            pstats.device_s += clock() - t_dev
-            pstats.h2d_bytes = nbytes
-            GLOBAL_COUNTERS.bump("bytes_scanned", nbytes)
-            GLOBAL_COUNTERS.bump("device_hbm_touched_bytes", nbytes)
-            plan.runtime_cache["task_bytes"] = task_bytes
-            # attribution-only (not the EXPLAIN Tasks section, which
-            # renders single-device dispatches): per-round device time
-            # split across the round's shard members
-            plan.runtime_cache["mesh_task_times"] = mesh_task_times
-            pstats.publish(plan)
-            _book_fused_dispatches(plan, n_rounds)
-            return partials
-
-    # ---- single-device path: fused streaming pipeline + HBM pinning --
-    task_times: list = []
-    task_bytes: list = []
-    # XLA's fusion of this jitted body is the kernel: there is no
-    # hand-written variant to keep in step with the compiler.  It folds
-    # the per-batch worker AND the running merge into ONE dispatch: the
-    # partial-agg registers ride along as a donated argument (acc
-    # buffers are reused in place by XLA), so each batch costs a single
-    # kernel launch and the accumulators never leave the device until
-    # the final device_get.
-    fused = get_kernel(
-        plan, "jit_fused",
-        lambda: jit_compile(build_fused_worker_fn(plan, jnp),
-                            donate_argnums=0))
+    placement, key, cached, stream = choose_placement(
+        plan, cat.data_dir, not overlaid,
+        lambda: _iter_padded_batches(cat, plan, settings), pstats)
+    step, first_state = _agg_step(plan, placement)
+    placement.bind(params)
     with _trace.span("init_acc") as sp:
-        acc_dev = tuple(jax.device_put(p) for p in _empty_partials(plan, np))
+        acc_dev = first_state()
         if sp.recording:
             sp.set(arrays=len(acc_dev), bytes=_nbytes(acc_dev))
-    n_dispatch = 0
-    if cached is not None:
-        for b in cached:
-            with _trace.span("device_round") as rsp:
-                t0 = clock()
-                with _trace.span("dispatch") as sp:
-                    acc_dev = fused(acc_dev, b.cols + pcols,
-                                    b.valids + pvalids, b.row_mask)
-                    if sp.recording:
-                        sp.set(slot="jit_fused")
-                n_dispatch += 1
-                task_times.append((b.shard_index, b.n_rows,
-                                   clock() - t0))
-                if rsp.recording:
-                    rsp.set(shard_index=int(b.shard_index),
-                            rows=int(b.n_rows), resident=True,
-                            bytes=b.nbytes)
-    else:
-        # stream: decompress batch i+1 on the host and transfer it while
-        # batch i computes — double-buffering: the H2D copy stream and
-        # the compute stream overlap under XLA's async dispatch, and
-        # the donated accumulator chain serializes only the (tiny)
-        # register update, not the batch transfers.  Collect device
-        # references opportunistically and pin them only if the whole
-        # working set fits the cache — past capacity, throughput
-        # degrades to the pipeline rate instead of collapsing (SURVEY
-        # §2.4 "Pipelined ingest")
-        from citus_tpu.testing.faults import FAULTS
-        collect: Optional[list] = None if overlaid else []
-        nbytes = 0
-        depth = _prefetch_depth(settings)
-        window_bytes = 0       # un-synced streamed bytes on device
-        window_peak = 0
-        since_sync = 0
-        if host_iter is None:
-            host_iter = _iter_padded_batches(cat, plan, settings)
-        # host/device overlap: the decode thread runs the host half of
-        # the scan (read_ahead_depth batches ahead) while this thread
-        # feeds the device
-        host_iter = prefetch_batches(host_iter, read_ahead_depth(settings),
-                                     pstats)
-        try:
-            for hb in host_iter:
-                with _trace.span("device_round") as rsp:
-                    t_dev = clock()
-                    FAULTS.hit("device_round", plan.bound.table.name)
-                    bb = hb.nbytes
-                    with _trace.span("h2d") as sp:
-                        db = ShardBatch(
-                            tuple(jax.device_put(c) for c in hb.cols),
-                            tuple(jax.device_put(v) for v in hb.valids),
-                            jax.device_put(hb.row_mask), hb.n_rows,
-                            hb.padded_rows, hb.shard_index)
-                        if sp.recording:
-                            sp.set(bytes=bb)
-                    t0 = clock()
-                    with _trace.span("dispatch") as sp:
-                        acc_dev = fused(acc_dev, db.cols + pcols,
-                                        db.valids + pvalids, db.row_mask)
-                        if sp.recording:
-                            sp.set(slot="jit_fused")
-                    n_dispatch += 1
-                    task_times.append((db.shard_index, db.n_rows,
-                                       clock() - t0))
-                    nbytes += bb
-                    task_bytes.append((db.shard_index, bb))
-                    if collect is not None:
-                        collect.append(db)
-                        if nbytes > GLOBAL_CACHE.capacity:
-                            collect = None  # working set exceeds HBM cache
-                    if collect is None:
-                        # bound in-flight device memory: the accumulator
-                        # chain orders every fused round, so syncing the
-                        # current registers retires all admitted batches
-                        # — at most `depth` batches are ever un-synced
-                        # (the double-buffer window the peak-HBM test
-                        # bounds)
-                        window_bytes += bb
-                        window_peak = max(window_peak, window_bytes)
-                        since_sync += 1
-                        if since_sync >= depth:
-                            _block_ready(acc_dev)
-                            since_sync = 0
-                            window_bytes = 0
-                    pstats.device_s += clock() - t_dev
-                    if rsp.recording:
-                        rsp.set(shard_index=int(hb.shard_index),
-                                rows=int(hb.n_rows), bytes=bb,
-                                resident=False)
-        finally:
-            host_iter.close()
-        if n_dispatch == 0:
-            return _combine(plan, [_empty_partials(plan, np)])
-        if collect is not None:
-            _block_ready([b.cols for b in collect])
-            with _trace.span("cache_put"):
-                GLOBAL_CACHE.put(key, collect, nbytes, tenant=cache_tenant)
-        pstats.h2d_bytes = nbytes
-        GLOBAL_COUNTERS.bump("bytes_scanned", nbytes)
-        GLOBAL_COUNTERS.bump("device_hbm_touched_bytes", nbytes)
-        t_dev = clock()
-        partials = _fetch_acc(acc_dev)
+    # HBM attribution: resident entries are charged to the tenant whose
+    # query pinned them (the shared bucket for non-router scans)
+    acc_dev = drive(plan, settings, placement, step, acc_dev, pstats,
+                    cached=cached, stream=stream, cache_key=key,
+                    cache_tenant=tenant_key(plan.router_key))
+    placement.publish(plan)
+    t_dev = clock()
+    partials = _fetch_acc(acc_dev)
+    if cached is None:
         pstats.device_s += clock() - t_dev
         pstats.publish(plan)
-        _book_fused_dispatches(plan, n_dispatch)
-        plan.runtime_cache["pipeline"]["stream_window_peak_bytes"] = \
-            window_peak
-        plan.runtime_cache["task_times"] = task_times
-        plan.runtime_cache["task_bytes"] = task_bytes
-        return partials
-    _book_fused_dispatches(plan, n_dispatch)
-    plan.runtime_cache["task_times"] = task_times
-    plan.runtime_cache["task_bytes"] = task_bytes
-    return _fetch_acc(acc_dev)
+    return partials
 
 
 def _decode_direct_keys(plan: PhysicalPlan, rows: np.ndarray):
@@ -824,95 +466,45 @@ def _hash_key_dtypes(plan: PhysicalPlan, penv: dict) -> tuple:
     return tuple(dts)
 
 
-def _stream_hash_batches(cat: Catalog, plan: PhysicalPlan, settings: Settings,
-                         params, fused, state, acc, penv, pstats, hs):
-    """Stream the plan's shards through the fused hash kernel.
+class _SpillDrain:
+    """The hash scan's sync hook: at the points where the loop waits
+    for the device anyway (one per prefetch window, not per batch) the
+    window's spill masks come back and their rows re-aggregate on the
+    host, exactly — rider by rider (one accumulator and parameter env
+    each; the serial scan is one rider with an ``[N]`` mask, a
+    megabatched group ``[qp, N]``).  ``rows`` counts what spilled."""
 
-    One dispatch per batch against the DONATED running table ``state``;
-    spill masks drain into ``acc`` per prefetch window (not per batch),
-    at the same sync points that bound the un-synced H2D window — so
-    peak device footprint stays O(slots) + depth × batch bytes and the
-    host never materializes the scan.  ``hs`` accumulates dispatch /
-    window / spill bookkeeping across calls (local + fallback passes).
-    """
-    import jax
-    from citus_tpu.executor.pipeline import prefetch_batches, read_ahead_depth
-    from citus_tpu.testing.faults import FAULTS
-    pcols, pvalids = params
-    depth = _prefetch_depth(settings)
-    pending: list = []   # (host batch, device spill mask) awaiting drain
+    def __init__(self, plan: PhysicalPlan, accs: list, penvs: list):
+        from citus_tpu.planner.bound import compile_expr
+        self.columns = plan.scan_columns
+        self.key_fns = [compile_expr(k, np) for k in plan.bound.group_keys]
+        self.arg_fns = [compile_expr(a, np) for a in plan.agg_args]
+        self.accs, self.penvs = accs, penvs
+        self.rows = 0
 
-    def _drain():
+    def __call__(self, pending: list) -> None:
         # one span per drained window: the wait for the window's spill
         # masks (the device is behind them) and the host re-aggregation
         if not pending:
             return
         with _trace.span("spill_drain") as dsp:
             n_window = 0
-            for hb, sp in pending:
-                sp = np.asarray(sp)
-                if sp.any():
-                    n_window += int(sp.sum())
-                    env = {n: (np.asarray(c), np.asarray(v))
-                           for n, c, v in zip(plan.scan_columns, hb.cols,
-                                              hb.valids)}
-                    env.update(penv)
-                    acc.add_batch(sp, [f(env) for f in hs["key_fns_np"]],
-                                  [f(env) for f in hs["arg_fns_np"]])
+            for (hb,), masks in pending:
+                masks = np.atleast_2d(np.asarray(masks))[:len(self.accs)]
+                if not masks.any():
+                    continue
+                base = {n: (np.asarray(c), np.asarray(v))
+                        for n, c, v in zip(self.columns, hb.cols, hb.valids)}
+                for acc, penv, sp in zip(self.accs, self.penvs, masks):
+                    if sp.any():
+                        n_window += int(sp.sum())
+                        env = {**base, **penv}
+                        acc.add_batch(sp, [f(env) for f in self.key_fns],
+                                      [f(env) for f in self.arg_fns])
             GLOBAL_COUNTERS.bump("hash_spill_rows", n_window)
-            hs["spilled"] += n_window
+            self.rows += n_window
             if dsp.recording:
                 dsp.set(batches=len(pending), rows=n_window)
-        pending.clear()
-
-    window_bytes = 0
-    since_sync = 0
-    host_iter = prefetch_batches(_iter_padded_batches(cat, plan, settings),
-                                 read_ahead_depth(settings), pstats)
-    try:
-        for hb in host_iter:
-            with _trace.span("device_round") as rsp:
-                t_dev = clock()
-                FAULTS.hit("device_round", plan.bound.table.name)
-                bb = hb.nbytes
-                with _trace.span("h2d") as sp:
-                    db = ShardBatch(
-                        tuple(jax.device_put(c) for c in hb.cols),
-                        tuple(jax.device_put(v) for v in hb.valids),
-                        jax.device_put(hb.row_mask), hb.n_rows,
-                        hb.padded_rows, hb.shard_index)
-                    if sp.recording:
-                        sp.set(bytes=bb)
-                t0 = clock()
-                with _trace.span("dispatch") as sp:
-                    state, spill = fused(state, db.cols + pcols,
-                                         db.valids + pvalids, db.row_mask)
-                    if sp.recording:
-                        sp.set(slot="jit_hash_fused")
-                hs["n_dispatch"] += 1
-                hs["task_times"].append((db.shard_index, db.n_rows,
-                                         clock() - t0))
-                hs["nbytes"] += bb
-                hs["task_bytes"].append((db.shard_index, bb))
-                pending.append((hb, spill))
-                window_bytes += bb
-                hs["window_peak"] = max(hs["window_peak"], window_bytes)
-                since_sync += 1
-                window_full = since_sync >= depth
-                if window_full:
-                    _block_ready(state)
-                    since_sync = 0
-                    window_bytes = 0
-                pstats.device_s += clock() - t_dev
-                if rsp.recording:
-                    rsp.set(shard_index=int(hb.shard_index),
-                            rows=int(hb.n_rows), bytes=bb, resident=False)
-            if window_full:
-                _drain()    # host work: beside the round, not inside it
-    finally:
-        host_iter.close()
-    _drain()
-    return state
 
 
 def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
@@ -931,24 +523,33 @@ def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
         build_fused_hash_worker, build_fused_entry_merge, empty_hash_state,
         merge_hash_tables_into,
     )
-    from citus_tpu.planner.bound import compile_expr as _ce
 
     pstats = PipelineStats()
     _trace.set_phase("device")
     key_dtypes = _hash_key_dtypes(plan, penv)
-    fused = get_kernel(
+    step = Step(get_kernel(
         plan, "jit_hash_fused",
         lambda: jit_compile(build_fused_hash_worker(plan, jnp, key_dtypes),
-                            donate_argnums=0))
-    hs = {"n_dispatch": 0, "window_peak": 0, "nbytes": 0, "spilled": 0,
-          "task_times": [], "task_bytes": [],
-          "key_fns_np": [_ce(k, np) for k in plan.bound.group_keys],
-          "arg_fns_np": [_ce(a, np) for a in plan.agg_args]}
+                            donate_argnums=0)),
+        "jit_hash_fused", "hash_fused_dispatches")
+    # one table on the default device, also on a multi-chip host; both
+    # passes (local, then push fallbacks) book into one placement
+    placement = OneDevice()
+    placement.bind(params)
+    drain = _SpillDrain(plan, [acc], [penv])
     with _trace.span("hash_init") as sp:
         S = _hash_slots(cat, plan, settings, key_dtypes)
         state = jax.device_put(empty_hash_state(plan, S, key_dtypes))
         if sp.recording:
             sp.set(slots=S)
+
+    def scan(shard_plan, state):
+        # never cached (no key): the window bounds the un-synced H2D
+        # bytes from the first round on, so peak device footprint stays
+        # O(slots) + depth x batch bytes
+        return drive(shard_plan, settings, placement, step, state, pstats,
+                     stream=_iter_padded_batches(cat, shard_plan, settings),
+                     on_sync=drain)
 
     dispatch = None
     run_plan = plan
@@ -959,8 +560,7 @@ def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
             import dataclasses
             run_plan = dataclasses.replace(plan, shard_indexes=local)
     try:
-        state = _stream_hash_batches(cat, run_plan, settings, params, fused,
-                                     state, acc, penv, pstats, hs)
+        state = scan(run_plan, state)
     except BaseException:
         if dispatch is not None:
             dispatch.abort()  # no RPC thread outlives the attempt
@@ -970,8 +570,7 @@ def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
         if fallback:
             import dataclasses
             fb_plan = dataclasses.replace(plan, shard_indexes=fallback)
-            state = _stream_hash_batches(cat, fb_plan, settings, params,
-                                         fused, state, acc, penv, pstats, hs)
+            state = scan(fb_plan, state)
         if remote:
             merge_jit = get_kernel(
                 plan, "jit_hash_merge",
@@ -1005,19 +604,12 @@ def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     h_keys = [(np.asarray(kv), np.asarray(kf)) for kv, kf in fetched[0]]
     h_partials = tuple(np.asarray(p) for p in fetched[1])
     h_rows = np.asarray(fetched[2])
-    GLOBAL_COUNTERS.bump("bytes_scanned", hs["nbytes"])
-    GLOBAL_COUNTERS.bump("device_hbm_touched_bytes", hs["nbytes"])
-    GLOBAL_COUNTERS.bump("hash_fused_dispatches", hs["n_dispatch"])
-    pstats.h2d_bytes = hs["nbytes"]
     pstats.publish(plan)
+    placement.publish(plan)
     pl = plan.runtime_cache.setdefault("pipeline", {})
-    pl["fused_dispatches"] = hs["n_dispatch"]
-    pl["stream_window_peak_bytes"] = hs["window_peak"]
     pl["hash_slots"] = S
     pl["hash_occupancy_pct"] = round(100.0 * int((h_rows > 0).sum()) / S, 1)
-    pl["hash_spilled_rows"] = hs["spilled"]
-    plan.runtime_cache["task_times"] = hs["task_times"]
-    plan.runtime_cache["task_bytes"] = hs["task_bytes"]
+    pl["hash_spilled_rows"] = drain.rows
     return h_keys, h_partials, h_rows
 
 
@@ -1040,8 +632,7 @@ def _run_hash_partial_state(cat: Catalog, plan: PhysicalPlan,
         pcols, pvalids = params
         worker = build_worker_fn(plan, np)
         for si in plan.shard_indexes:
-            for values, masks, n in load_shard_batches(
-                    cat, plan, si, min_batch_rows=1):
+            for values, masks, n in load_shard_batches(cat, plan, si):
                 cols = tuple(values[c].astype(
                     plan.bound.table.schema.scan_dtype(c, device=True),
                     copy=False) for c in plan.scan_columns)
@@ -1123,8 +714,7 @@ def _run_agg_hash_host(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     note_inexpressible(cat, plan, settings)
     worker = build_worker_fn(plan, np)
     for si in plan.shard_indexes:
-        for values, masks, n in load_shard_batches(
-                cat, plan, si, min_batch_rows=1):
+        for values, masks, n in load_shard_batches(cat, plan, si):
             cols = tuple(values[c].astype(plan.bound.table.schema.scan_dtype(c, device=True),
                                           copy=False) for c in plan.scan_columns)
             valids = tuple(masks[c] for c in plan.scan_columns)
@@ -1170,8 +760,7 @@ def _run_projection(cat: Catalog, plan: PhysicalPlan, settings: Settings,
 
     def _scan_shards(rp, out: list) -> None:
         for si in rp.shard_indexes:
-            for values, masks, n in load_shard_batches(
-                    cat, plan, si, min_batch_rows=1):
+            for values, masks, n in load_shard_batches(cat, plan, si):
                 cols = tuple(values[c].astype(plan.bound.table.schema.scan_dtype(c, device=True),
                                               copy=False) for c in plan.scan_columns)
                 valids = tuple(masks[c] for c in plan.scan_columns)

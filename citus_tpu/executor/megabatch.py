@@ -325,16 +325,15 @@ def _batched_agg(cat, plan, settings, group: list[_Waiter]) -> list:
 
     from citus_tpu.executor.device_cache import GLOBAL_CACHE, plan_cache_key
     from citus_tpu.executor.executor import (
-        _empty_partials, _iter_padded_batches,
+        _empty_partials, _fetch_acc, _iter_padded_batches,
     )
     from citus_tpu.executor.kernel_cache import get_kernel, jit_compile
-    from citus_tpu.executor.batches import ShardBatch
+    from citus_tpu.executor.pipeline import PipelineStats
+    from citus_tpu.executor.scan_loop import OneDevice, Step, drive
     from citus_tpu.ops.scan_agg import build_fused_worker_fn
-    from citus_tpu.testing.faults import FAULTS
 
     q = len(group)
     qp = _q_pad(q)
-    pcols, pvalids = _stacked_params(group, qp)
     from citus_tpu.planner.bound import param_env_names
     n_cols = len(plan.scan_columns)
     n_params = len(param_env_names(plan.bound.param_specs))
@@ -350,81 +349,54 @@ def _batched_agg(cat, plan, settings, group: list[_Waiter]) -> list:
         return jit_compile(jax.vmap(build_fused_worker_fn(plan, jnp),
                                     in_axes=(0, axes, axes, None)),
                            donate_argnums=0)
-    batched = get_kernel(plan, "batched:jit_fused", _build)
+    step = Step(get_kernel(plan, "batched:jit_fused", _build),
+                "batched:jit_fused", "fused_dispatches")
 
     _trace.set_phase("device")
+    # the [qp] parameter stacks ride along as one device's parameters do
+    placement = OneDevice()
+    placement.bind(_stacked_params(group, qp))
     # interval-free scan: the device-cache entry is the family-wide
-    # full-shard batch set, shared by every literal variant
+    # full-shard batch set, shared by every literal variant and
+    # attributed to the shared tenant bucket, not one rider
     key = plan_cache_key(plan, cat.data_dir)
     cached = GLOBAL_CACHE.get(key)
     # [qp]-stacked accumulator registers, one slot per rider (padding
     # slots replay rider 0's params; their results are sliced off)
     acc = tuple(jax.device_put(np.stack([p] * qp))
                 for p in _empty_partials(plan, np))
-    n_dispatch = 0
-    if cached is not None:
-        for b in cached:
-            FAULTS.hit("device_round", plan.bound.table.name)
-            acc = batched(acc, b.cols + pcols, b.valids + pvalids,
-                          b.row_mask)
-            n_dispatch += 1
-    else:
-        collect: Optional[list] = []
-        nbytes = 0
-        for hb in _iter_padded_batches(cat, plan, settings):
-            FAULTS.hit("device_round", plan.bound.table.name)
-            db = ShardBatch(tuple(jax.device_put(c) for c in hb.cols),
-                            tuple(jax.device_put(v) for v in hb.valids),
-                            jax.device_put(hb.row_mask), hb.n_rows,
-                            hb.padded_rows, hb.shard_index)
-            acc = batched(acc, db.cols + pcols, db.valids + pvalids,
-                          db.row_mask)
-            n_dispatch += 1
-            nbytes += (sum(c.nbytes for c in hb.cols)
-                       + sum(v.nbytes for v in hb.valids)
-                       + hb.row_mask.nbytes)
-            if collect is not None:
-                collect.append(db)
-                if nbytes > GLOBAL_CACHE.capacity:
-                    collect = None
-        _counters().bump("bytes_scanned", nbytes)
-        _counters().bump("device_hbm_touched_bytes", nbytes)
-        if collect is not None and n_dispatch:
-            from citus_tpu.executor.executor import _block_ready
-            _block_ready([b.cols for b in collect])
-            # family-wide entry shared across every literal variant:
-            # attributed to the shared tenant bucket, not one rider
-            GLOBAL_CACHE.put(key, collect, nbytes)
-    if n_dispatch:
-        _counters().bump("fused_dispatches", n_dispatch)
-    host = tuple(np.asarray(o) for o in jax.device_get(acc))
+    acc = drive(plan, settings, placement, step, acc, PipelineStats(),
+                cached=cached, cache_key=key,
+                stream=None if cached is not None
+                else _iter_padded_batches(cat, plan, settings))
+    host = _fetch_acc(acc)
     return [("agg", [tuple(o[qi] for o in host)]) for qi in range(q)]
 
 
 def _batched_hash_agg(cat, plan, settings, group: list[_Waiter]) -> list:
     """Shared scan + ONE vmap-lifted fused hash dispatch per batch over
     [qp]-stacked donated hash tables (kernel slot
-    ``batched:jit_hash_fused``).  Spill masks drain per batch into
-    per-query HostGroupAccumulators with each rider's own params env;
-    scatter hands every waiter its table slice + accumulator and the
-    exact host merge + finalize run on the callers' threads."""
+    ``batched:jit_hash_fused``).  Spill masks drain per prefetch window
+    into per-query HostGroupAccumulators with each rider's own params
+    env; scatter hands every waiter its table slice + accumulator and
+    the exact host merge + finalize run on the callers' threads."""
     import jax
     import jax.numpy as jnp
 
-    from citus_tpu.executor.batches import ShardBatch
     from citus_tpu.executor.executor import (
-        _hash_key_dtypes, _hash_slots, _iter_padded_batches, _params_env,
+        _SpillDrain, _hash_key_dtypes, _hash_slots, _iter_padded_batches,
+        _params_env,
     )
     from citus_tpu.executor.host_agg import HostGroupAccumulator
     from citus_tpu.executor.kernel_cache import get_kernel, jit_compile
+    from citus_tpu.executor.pipeline import PipelineStats
+    from citus_tpu.executor.scan_loop import OneDevice, Step, drive
     from citus_tpu.ops.hash_agg import build_fused_hash_worker, \
         empty_hash_state
-    from citus_tpu.planner.bound import compile_expr, param_env_names
-    from citus_tpu.testing.faults import FAULTS
+    from citus_tpu.planner.bound import param_env_names
 
     q = len(group)
     qp = _q_pad(q)
-    pcols, pvalids = _stacked_params(group, qp)
     penvs = [_params_env(plan, w.params) for w in group]
     n_cols = len(plan.scan_columns)
     n_params = len(param_env_names(plan.bound.param_specs))
@@ -440,50 +412,20 @@ def _batched_hash_agg(cat, plan, settings, group: list[_Waiter]) -> list:
             jax.vmap(build_fused_hash_worker(plan, jnp, key_dtypes),
                      in_axes=(0, axes, axes, None)),
             donate_argnums=0)
-    batched = get_kernel(plan, "batched:jit_hash_fused", _build)
+    step = Step(get_kernel(plan, "batched:jit_hash_fused", _build),
+                "batched:jit_hash_fused", "hash_fused_dispatches")
 
-    key_fns_np = [compile_expr(k, np) for k in plan.bound.group_keys]
-    arg_fns_np = [compile_expr(a, np) for a in plan.agg_args]
     accs = [HostGroupAccumulator(len(plan.bound.group_keys),
                                  plan.partial_ops) for _ in group]
 
     _trace.set_phase("device")
+    placement = OneDevice()
+    placement.bind(_stacked_params(group, qp))
     state = jax.device_put(jax.tree_util.tree_map(
         lambda a: np.stack([a] * qp), empty_hash_state(plan, S, key_dtypes)))
-    n_dispatch = 0
-    nbytes = 0
-    spilled = 0
-    for hb in _iter_padded_batches(cat, plan, settings):
-        FAULTS.hit("device_round", plan.bound.table.name)
-        db = ShardBatch(tuple(jax.device_put(c) for c in hb.cols),
-                        tuple(jax.device_put(v) for v in hb.valids),
-                        jax.device_put(hb.row_mask), hb.n_rows,
-                        hb.padded_rows, hb.shard_index)
-        state, spills = batched(state, db.cols + pcols, db.valids + pvalids,
-                                db.row_mask)
-        n_dispatch += 1
-        nbytes += (sum(c.nbytes for c in hb.cols)
-                   + sum(v.nbytes for v in hb.valids) + hb.row_mask.nbytes)
-        spills = np.asarray(spills)  # [qp, N]; syncs this round
-        if spills[:q].any():
-            base = {n: (np.asarray(c), np.asarray(v))
-                    for n, c, v in zip(plan.scan_columns, hb.cols, hb.valids)}
-            for qi in range(q):
-                sp = spills[qi]
-                if not sp.any():
-                    continue
-                spilled += int(sp.sum())
-                env = dict(base)
-                env.update(penvs[qi])
-                accs[qi].add_batch(sp, [f(env) for f in key_fns_np],
-                                   [f(env) for f in arg_fns_np])
-    c = _counters()
-    c.bump("bytes_scanned", nbytes)
-    c.bump("device_hbm_touched_bytes", nbytes)
-    if n_dispatch:
-        c.bump("hash_fused_dispatches", n_dispatch)
-    if spilled:
-        c.bump("hash_spill_rows", spilled)
+    state = drive(plan, settings, placement, step, state, PipelineStats(),
+                  stream=_iter_padded_batches(cat, plan, settings),
+                  on_sync=_SpillDrain(plan, accs, penvs))
     host = jax.device_get(state)
     return [("hash_agg",
              (jax.tree_util.tree_map(lambda a: np.asarray(a)[qi], host),
@@ -530,8 +472,7 @@ def _batched_projection(cat, plan, settings, group: list[_Waiter]) -> list:
     schema = plan.bound.table.schema
     per_query: list[list] = [[] for _ in group]
     for si in plan.shard_indexes:
-        for values, masks, n in load_shard_batches(cat, plan, si,
-                                                   min_batch_rows=1):
+        for values, masks, n in load_shard_batches(cat, plan, si):
             cols = tuple(values[c].astype(schema.scan_dtype(c, device=True),
                                           copy=False)
                          for c in plan.scan_columns)

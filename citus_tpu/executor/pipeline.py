@@ -57,6 +57,8 @@ class PipelineStats:
         self.h2d_bytes = 0         # bytes shipped host -> device
         self.host_stalls = 0       # consumer found the queue empty
         self.device_stalls = 0     # producer found the queue full
+        self.rounds = 0            # device rounds dispatched
+        self.window_peak_bytes = 0  # most un-synced streamed bytes on device
 
     def as_dict(self) -> dict:
         return {

@@ -1,0 +1,374 @@
+"""The device scan loop: one driver, two placements, kernels as the step.
+
+Every device scan takes a round of host batches, puts it on the
+device(s), dispatches a kernel that takes the DONATED running state and
+returns the next one, keeps a bounded number of rounds un-synced and may
+keep the device inputs for the HBM batch cache.  ``drive`` is that loop,
+and the only one.  What differs between the scans is data:
+
+- a **placement** says where a round's inputs live: how many batches
+  make a round, how they are put, how parameters ride along, the cache
+  key's suffix, how a round's bytes and seconds are attributed.
+  ``OneDevice`` and ``MeshPlacement``; ``choose_placement`` picks;
+- a **step** is the compiled kernel, ``state' = fn(state, cols, valids,
+  row_mask)`` — or ``(state', aux)`` beside a sync hook — with the slot
+  and counter names its rounds are booked under;
+- the first state and the tail (``_fetch_acc``) are the caller's.
+"""
+
+from __future__ import annotations
+
+import itertools
+from typing import Callable, Iterator, NamedTuple, Optional
+
+import numpy as np
+
+from citus_tpu.executor.batches import ShardBatch, empty_batch
+from citus_tpu.executor.device_cache import GLOBAL_CACHE, plan_cache_key
+from citus_tpu.executor.pipeline import (
+    PipelineStats, prefetch_batches, read_ahead_depth,
+)
+from citus_tpu.observability import trace as _trace
+from citus_tpu.observability.trace import clock
+from citus_tpu.stats import begin_wait, end_wait
+
+
+def _block_ready(x) -> None:
+    """block_until_ready under a device_round wait bracket: the stretch
+    the backend spends blocked on device backpressure shows up in the
+    activity view and the wait_device_round_ms counter.  On a TPU a
+    wait costs a few tenths of a millisecond even when every array is
+    ready (PERF.md, PR 23): where the arrays are the outputs of one
+    dispatch, which become ready together, callers pass one of them."""
+    import jax
+    wtok = begin_wait("device_round")
+    try:
+        jax.block_until_ready(x)
+    finally:
+        end_wait(wtok)
+
+
+def _nbytes(arrays) -> int:
+    return int(sum(a.nbytes for a in arrays))
+
+
+def _prefetch_depth(settings) -> int:
+    """Device-side in-flight window: streaming mode keeps at most this
+    many rounds un-synced ahead of the kernel consuming them.
+    Governed by SET citus.executor_prefetch_depth (floor of 1 so the
+    depth-0 'decode inline' setting still double-buffers the device);
+    max_tasks_in_flight raises the window further."""
+    return max(1, settings.executor.executor_prefetch_depth,
+               settings.executor.max_tasks_in_flight)
+
+
+class Step(NamedTuple):
+    fn: Callable
+    slot: str       # on the round's ``dispatch`` span
+    counter: str    # process counter of its rounds
+
+
+# ------------------------------------------------------------ placements
+
+
+class OneDevice:
+    """A round is one host batch, put on the default device as a
+    ShardBatch of device arrays (which is also its cache entry);
+    parameters ride along as the host arrays they are."""
+
+    round_size = 1
+    key_suffix: tuple = ()
+    mesh = None
+
+    def __init__(self) -> None:
+        self.task_times: list = []
+        self.task_bytes: list = []
+
+    def bind(self, params) -> None:
+        self.pcols, self.pvalids = params
+
+    def put(self, plan, members: list):
+        import jax
+        hb, = members
+        nbytes = hb.nbytes
+        with _trace.span("h2d") as sp:
+            db = ShardBatch(tuple(jax.device_put(c) for c in hb.cols),
+                            tuple(jax.device_put(v) for v in hb.valids),
+                            jax.device_put(hb.row_mask), hb.n_rows,
+                            hb.padded_rows, hb.shard_index)
+            if sp.recording:
+                sp.set(bytes=nbytes)
+        return db, nbytes
+
+    def args(self, b: ShardBatch) -> tuple:
+        return b.cols + self.pcols, b.valids + self.pvalids, b.row_mask
+
+    def describe(self, members, b: ShardBatch) -> dict:
+        return {"shard_index": int(b.shard_index), "rows": int(b.n_rows),
+                "bytes": b.nbytes}
+
+    def book(self, members, b: ShardBatch, nbytes: int, round_s: float,
+             dispatch_s: float) -> None:
+        self.task_times.append((b.shard_index, b.n_rows, dispatch_s))
+        if members is not None:
+            self.task_bytes.append((b.shard_index, nbytes))
+
+    def publish(self, plan) -> None:
+        plan.runtime_cache["task_times"] = self.task_times
+        plan.runtime_cache["task_bytes"] = self.task_bytes
+
+
+def _repad_batch(b: ShardBatch, bucket: int) -> ShardBatch:
+    """Grow a padded batch to a larger bucket (mesh rounds stack, so all
+    members share one shape)."""
+    pad = bucket - b.padded_rows
+    if pad <= 0:
+        return b
+    cols = tuple(np.concatenate([c, np.zeros(pad, c.dtype)]) for c in b.cols)
+    valids = tuple(np.concatenate([v, np.ones(pad, bool)]) for v in b.valids)
+    mask = np.concatenate([b.row_mask, np.zeros(pad, bool)])
+    return ShardBatch(cols, valids, mask, b.n_rows, bucket, b.shard_index)
+
+
+class MeshPlacement:
+    """A round is up to ``n_dev`` host batches, re-padded to the round's
+    largest bucket, filled up with empty batches and stacked along the
+    shard axis: one ``(cols, valids, row_mask)`` triple of device-sharded
+    stacks, a different structure than the one-device ShardBatch list, so
+    its cache entries key apart.  Parameters replicate across the shard
+    axis as ``[n_dev]`` stacks, put on the mesh once a query (never
+    cached: they change per execution)."""
+
+    def __init__(self, mesh) -> None:
+        from jax.sharding import NamedSharding, PartitionSpec
+        from citus_tpu.parallel.mesh import shard_axis_size
+        self.mesh = mesh
+        self.round_size = shard_axis_size(mesh)
+        self.key_suffix = ("mesh", self.round_size)
+        self.sharding = NamedSharding(mesh, PartitionSpec("shard"))
+        self.task_bytes: list = []
+        self.mesh_task_times: list = []
+
+    def bind(self, params) -> None:
+        import jax
+        n = self.round_size
+        with _trace.span("bind_params"):
+            self.pcols, self.pvalids = jax.device_put(
+                (tuple(np.stack([p] * n) for p in params[0]),
+                 tuple(np.stack([v] * n) for v in params[1])),
+                self.sharding)
+
+    def put(self, plan, members: list):
+        import jax
+        n_cols = range(len(plan.scan_columns))
+        with _trace.span("stack") as sp:
+            bucket = max(b.padded_rows for b in members)
+            buf = [_repad_batch(b, bucket) for b in members]
+            buf += [empty_batch(plan.bound.table, plan, bucket, -1)
+                    ] * (self.round_size - len(buf))
+            cols = tuple(np.stack([b.cols[i] for b in buf]) for i in n_cols)
+            valids = tuple(np.stack([b.valids[i] for b in buf])
+                           for i in n_cols)
+            mask = np.stack([b.row_mask for b in buf])
+            nbytes = _nbytes(cols) + _nbytes(valids) + mask.nbytes
+            if sp.recording:
+                sp.set(bytes=nbytes)
+        with _trace.span("h2d") as sp:
+            inputs = (tuple(jax.device_put(c, self.sharding) for c in cols),
+                      tuple(jax.device_put(v, self.sharding) for v in valids),
+                      jax.device_put(mask, self.sharding))
+            if sp.recording:
+                sp.set(bytes=nbytes)
+        return inputs, nbytes
+
+    def args(self, inputs: tuple) -> tuple:
+        dcols, dvalids, dmask = inputs
+        return dcols + self.pcols, dvalids + self.pvalids, dmask
+
+    def describe(self, members, inputs: tuple) -> dict:
+        dcols, dvalids, dmask = inputs
+        return {"batches": len(members) if members else self.round_size,
+                "bytes": _nbytes(dcols) + _nbytes(dvalids) + dmask.nbytes}
+
+    def book(self, members, inputs, nbytes: int, round_s: float,
+             dispatch_s: float) -> None:
+        """Split a streamed round's H2D bytes and device time across its
+        shard members for attribution (the filler batches' padding
+        belongs to the shards that forced the round).  The byte
+        remainder lands on the first member so the ledger total stays
+        exactly equal to the bytes_scanned counter bump."""
+        if members is None:
+            return
+        share, rem = divmod(int(nbytes), len(members))
+        for i, mb in enumerate(members):
+            self.task_bytes.append(
+                (mb.shard_index, share + (rem if i == 0 else 0)))
+            self.mesh_task_times.append(
+                (mb.shard_index, mb.n_rows, round_s / len(members)))
+
+    def publish(self, plan) -> None:
+        plan.runtime_cache["task_bytes"] = self.task_bytes
+        # attribution-only (not the EXPLAIN Tasks section, which renders
+        # single-device dispatches): per-round device time split across
+        # the round's shard members
+        plan.runtime_cache["mesh_task_times"] = self.mesh_task_times
+
+
+def _lookup(make_key: Callable[[], Optional[tuple]], mesh: bool):
+    """-> (cache key | None, the device inputs cached under it | None)."""
+    with _trace.span("cache_lookup") as sp:
+        key = make_key()
+        cached = None if key is None else GLOBAL_CACHE.get(key)
+        if sp.recording:
+            sp.set(hit=cached is not None, mesh=mesh)
+    return key, cached
+
+
+def choose_placement(plan, data_dir: str, use_cache: bool,
+                     open_stream: Callable[[], Iterator],
+                     pstats: PipelineStats):
+    """Where this scan's rounds live, from what the code observes: the
+    device count, a hit under the one-device key, a stream of a single
+    batch.  -> (placement, cache key | None, cached device inputs |
+    None, host stream | None); with ``use_cache`` false nothing is
+    looked up and the key is None (nothing is kept either)."""
+    from citus_tpu.parallel.mesh import default_mesh, executor_devices
+    with _trace.span("scan_setup"):
+        devices = executor_devices()
+    key, cached = _lookup(
+        lambda: plan_cache_key(plan, data_dir) if use_cache else None, False)
+    # a single-batch table cached under the one-device key serves from
+    # there without touching disk: the mesh is entered only when no such
+    # entry exists
+    if len(devices) == 1 or cached is not None:
+        return (OneDevice(), key, cached,
+                None if cached is not None else open_stream())
+    with _trace.span("scan_setup"):
+        placement = MeshPlacement(default_mesh())
+    # the key holds the snapshot generation (three file reads): made once
+    mkey, mcached = _lookup(lambda: key and key + placement.key_suffix, True)
+    if mcached is not None:
+        return placement, mkey, mcached, None
+    stream = open_stream()
+    t_peek = clock()
+    head = list(itertools.islice(stream, 2))
+    pstats.host_decode_s += clock() - t_peek
+    if len(head) < 2:
+        return OneDevice(), key, None, iter(head)   # 0 or 1 batch
+    return placement, mkey, None, itertools.chain(head, stream)
+
+
+# ---------------------------------------------------------------- driver
+
+
+def _rounds(batches: Iterator, n: int) -> Iterator[list]:
+    """Group a stream of host batches into rounds of ``n``; the last
+    round holds what is left."""
+    while True:
+        members = list(itertools.islice(batches, n))
+        if not members:
+            return
+        yield members
+
+
+def drive(plan, settings, placement, step: Step, state, pstats: PipelineStats,
+          *, cached: Optional[list] = None, stream: Optional[Iterator] = None,
+          cache_key: Optional[tuple] = None, cache_tenant: Optional[str] = None,
+          on_sync: Optional[Callable[[list], None]] = None):
+    """Fold every round into ``state`` and return the last state.
+
+    The rounds are ``cached`` (device inputs a previous scan kept:
+    replayed as they are) or come from ``stream`` (host batches, pulled
+    through the decode thread and grouped by the placement's round
+    size).  A streamed scan keeps its device inputs and puts them in
+    the HBM batch cache under ``cache_key`` when the whole working set
+    fits it; past the capacity (or with no key) it streams: at most
+    ``_prefetch_depth`` rounds are un-synced, and since the donated
+    state chain orders the rounds, a wait for one output of the newest
+    state retires every round admitted before it.
+
+    With ``on_sync`` the step returns ``(state', aux)``; the hook gets
+    the ``[(host members, aux)]`` of the rounds since the last sync at
+    each such wait — beside the round, not inside it — and once at the
+    end."""
+    import jax
+    from citus_tpu.executor.executor import GLOBAL_COUNTERS
+    from citus_tpu.testing.faults import FAULTS
+
+    streamed = cached is None
+    if streamed:
+        # host/device overlap: the decode thread prepares the next
+        # rounds while the device executes the current one
+        source = prefetch_batches(
+            stream, read_ahead_depth(settings) * placement.round_size, pstats)
+        todo = ((m, None) for m in _rounds(source, placement.round_size))
+    else:
+        todo = ((None, inputs) for inputs in cached)
+    collect: Optional[list] = [] if streamed and cache_key is not None else None
+    depth = _prefetch_depth(settings)
+    table = plan.bound.table.name
+    pending: list = []
+    rounds = nbytes = since_sync = window_bytes = 0
+    try:
+        for members, inputs in todo:
+            synced = False
+            with _trace.span("device_round") as rsp:
+                t_dev = clock()
+                # delay injections here model device-side round latency
+                # for the host/device overlap tests (the decode half is
+                # decode_batch)
+                FAULTS.hit("device_round", table)
+                nb = 0
+                if streamed:
+                    inputs, nb = placement.put(plan, members)
+                t0 = clock()
+                with _trace.span("dispatch") as sp:
+                    state = step.fn(state, *placement.args(inputs))
+                    if sp.recording:
+                        sp.set(slot=step.slot)
+                t1 = clock()
+                if on_sync is not None:
+                    state, aux = state
+                    pending.append((members, aux))
+                rounds += 1
+                nbytes += nb
+                placement.book(members, inputs, nb, t1 - t_dev, t1 - t0)
+                if collect is not None:
+                    collect.append(inputs)
+                    if nbytes > GLOBAL_CACHE.capacity:
+                        collect = None  # working set exceeds the HBM cache
+                if streamed and collect is None:
+                    window_bytes += nb
+                    pstats.window_peak_bytes = max(pstats.window_peak_bytes,
+                                                   window_bytes)
+                    since_sync += 1
+                    if since_sync >= depth:
+                        _block_ready(jax.tree_util.tree_leaves(state)[-1:])
+                        since_sync = window_bytes = 0
+                        synced = True
+                pstats.device_s += clock() - t_dev
+                if rsp.recording:
+                    rsp.set(resident=not streamed,
+                            **placement.describe(members, inputs))
+            if synced and on_sync is not None:
+                on_sync(pending)
+                pending = []
+    finally:
+        if streamed:
+            source.close()
+    if on_sync is not None:
+        on_sync(pending)
+    if collect:
+        _block_ready([placement.args(i)[0] for i in collect])
+        with _trace.span("cache_put"):
+            GLOBAL_CACHE.put(cache_key, collect, nbytes, tenant=cache_tenant)
+    pstats.rounds += rounds
+    GLOBAL_COUNTERS.bump(step.counter, rounds)
+    pl = plan.runtime_cache.setdefault("pipeline", {})
+    pl["fused_dispatches"] = pstats.rounds
+    if streamed:
+        pstats.h2d_bytes += nbytes
+        GLOBAL_COUNTERS.bump("bytes_scanned", nbytes)
+        GLOBAL_COUNTERS.bump("device_hbm_touched_bytes", nbytes)
+        pl["stream_window_peak_bytes"] = pstats.window_peak_bytes
+    return state

@@ -448,7 +448,7 @@ def _run_task_projection(cat, plan: PhysicalPlan, params,
     masks_out: dict = {c: [] for c in plan.scan_columns}
     total = 0
     for values, masks, n in load_shard_batches(
-            cat, plan, plan.shard_indexes[0], min_batch_rows=1):
+            cat, plan, plan.shard_indexes[0]):
         cols = tuple(
             values[c].astype(t.schema.scan_dtype(c, device=True),
                              copy=False) for c in plan.scan_columns)

@@ -33,8 +33,8 @@ node.  The sanitizer maintains:
 Everything is a no-op until ``install()`` activates: module state is
 plain constants, ``on_begin_wait`` is guarded by the ``_ACTIVE`` flag
 at the call site, and ``threading.Lock`` stays the C fast path — the
-off mode is zero-cost by construction (bench.py's BENCH_SANITIZE
-section asserts it).
+off mode is zero-cost by construction (tests/test_sanitizer.py asserts
+the passthrough).
 """
 from __future__ import annotations
 

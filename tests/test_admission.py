@@ -6,6 +6,7 @@ connections; optional acquisitions fail fast, required ones wait."""
 
 import threading
 import time
+import traceback
 
 import pytest
 
@@ -131,16 +132,26 @@ def test_queries_bounded_end_to_end(tmp_path):
     cl.execute("CREATE TABLE t (k bigint, v bigint)")
     cl.execute("SELECT create_distributed_table('t', 'k', 8)")
     cl.copy_from("t", rows=[(i, i) for i in range(20000)])
-    results = []
+    results, errors = [], []
 
     def q():
-        results.append(cl.execute("SELECT sum(v) FROM t").rows[0][0])
+        # a failure in a thread would otherwise show only as a short
+        # ``results``: keep it, so that the test can say what failed
+        try:
+            results.append(cl.execute("SELECT sum(v) FROM t").rows[0][0])
+        except BaseException:
+            errors.append(traceback.format_exc())
 
     threads = [threading.Thread(target=q) for _ in range(6)]
     for t in threads:
         t.start()
+    deadline = time.monotonic() + 240
     for t in threads:
-        t.join()
+        t.join(max(0.0, deadline - time.monotonic()))
+    alive = sum(t.is_alive() for t in threads)
+    assert not errors and not alive, (
+        f"{len(errors)} of 6 queries raised, {alive} still running after "
+        f"240 s; the first:\n{errors[0] if errors else '(none raised)'}")
     assert results == [sum(range(20000))] * 6
     view = cl.execute("SELECT citus_stat_pool()")
     row = dict(zip(view.columns, view.rows[0]))

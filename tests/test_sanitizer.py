@@ -241,6 +241,8 @@ def test_remote_dispatch_never_submits_under_its_lock():
             def event_loop():
                 return None
 
+    from citus_tpu.executor.admission import GLOBAL_POOL
+    held = GLOBAL_POOL.in_use
     d = RemoteTaskDispatch(_Cat(), _NS(), Settings(), [], False)
     loop = _StubLoop()
     loop.dispatch = d
@@ -266,6 +268,13 @@ def test_remote_dispatch_never_submits_under_its_lock():
     assert len(loop.calls) == 3  # completion relaunched the pending task
     assert loop.locked_during_submit == [False, False, False]
     assert d._settled == 1 and 0 in d._raw
+    # settle what is still in flight: each such RPC holds an optional
+    # slot of the PROCESS-WIDE pool, and a slot left behind here starves
+    # the next test of this worker that caps the pool
+    # (test_admission.py::test_queries_bounded_end_to_end, ROADMAP C12)
+    for call in loop.calls[1:]:
+        call[3](_Fut({}, b"frame"))
+    assert d._inflight_total == 0 and GLOBAL_POOL.in_use == held
 
 
 def test_remote_dispatch_abort_waits_out_planned_tasks():
