@@ -275,12 +275,17 @@ def _run_analyze(cl, stmt: A.Explain) -> list[str]:
                      f"{pl['stream_window_peak_bytes']} bytes")
         lines.append(line)
         if "hash_slots" in pl:
+            # each batch is sorted by key and segment-reduced on the
+            # device, then offered to the table in chunks: U is the sum
+            # of the batches' distinct keys, R the rows they held
             lines.append(
                 f"    Hash: hash slots {pl['hash_slots']}, "
                 f"occupancy {pl.get('hash_occupancy_pct', 0):g}%, "
                 f"spilled {pl.get('hash_spilled_rows', 0)} rows, "
                 f"groups {pl.get('hash_groups_out', 0)}, "
-                f"fetched {pl.get('hash_table_bytes_fetched', 0)} bytes")
+                f"fetched {pl.get('hash_table_bytes_fetched', 0)} bytes, "
+                f"table updates {pl.get('hash_table_updates', 0)} "
+                f"({pl.get('hash_rows_in', 0)} rows)")
         if "remote_wait_ms" in pl:
             wire = f", wire {pl['wire_format']}" \
                 if pl.get("wire_format") else ""

@@ -469,42 +469,57 @@ def _hash_key_dtypes(plan: PhysicalPlan, penv: dict) -> tuple:
 class _SpillDrain:
     """The hash scan's sync hook: at the points where the loop waits
     for the device anyway (one per prefetch window, not per batch) the
-    window's spill masks come back and their rows re-aggregate on the
-    host, exactly — rider by rider (one accumulator and parameter env
-    each; the serial scan is one rider with an ``[N]`` mask, a
-    megabatched group ``[qp, N]``).  ``rows`` counts what spilled."""
+    window's spills come back — per batch two scalars (entries offered
+    to the table, entries that lost both probes) and, only where some
+    did, the spill mask and the entries it marks — and those merge into
+    the host accumulator, exactly: rider by rider (one accumulator
+    each; the serial scan's outputs are ``[n]``, a megabatched group's
+    ``[qp, n]``).  ``rows`` counts the rows of the spilled entries,
+    ``updates`` the entries offered."""
 
-    def __init__(self, plan: PhysicalPlan, accs: list, penvs: list):
-        from citus_tpu.planner.bound import compile_expr
-        self.columns = plan.scan_columns
-        self.key_fns = [compile_expr(k, np) for k in plan.bound.group_keys]
-        self.arg_fns = [compile_expr(a, np) for a in plan.agg_args]
-        self.accs, self.penvs = accs, penvs
-        self.rows = 0
+    def __init__(self, plan: PhysicalPlan, accs: list):
+        self.plan, self.accs = plan, accs
+        self.rows = self.updates = 0
 
     def __call__(self, pending: list) -> None:
-        # one span per drained window: the wait for the window's spill
-        # masks (the device is behind them) and the host re-aggregation
+        # one span per drained window: the wait for the window's
+        # counts (the device is behind them) and the host merge
         if not pending:
             return
+        import jax
+        from citus_tpu.ops.hash_agg import merge_hash_tables_into
+        q = len(self.accs)
         with _trace.span("spill_drain") as dsp:
-            n_window = 0
-            for (hb,), masks in pending:
-                masks = np.atleast_2d(np.asarray(masks))[:len(self.accs)]
-                if not masks.any():
+            n_rows = n_updates = 0
+            for _, (offered, n_spilled, lost, *entries) in pending:
+                offered = np.asarray(offered)
+                serial = offered.ndim == 0
+                n_updates += int(np.atleast_1d(offered)[:q].sum())
+                if not np.atleast_1d(np.asarray(n_spilled))[:q].any():
                     continue
-                base = {n: (np.asarray(c), np.asarray(v))
-                        for n, c, v in zip(self.columns, hb.cols, hb.valids)}
-                for acc, penv, sp in zip(self.accs, self.penvs, masks):
-                    if sp.any():
-                        n_window += int(sp.sum())
-                        env = {**base, **penv}
-                        acc.add_batch(sp, [f(env) for f in self.key_fns],
-                                      [f(env) for f in self.arg_fns])
-            GLOBAL_COUNTERS.bump("hash_spill_rows", n_window)
-            self.rows += n_window
+                lost = np.atleast_2d(np.asarray(lost))
+                for qi, acc in enumerate(self.accs):
+                    at = np.flatnonzero(lost[qi])
+                    if not at.size:
+                        continue
+                    # the marked entries are gathered on the device, in
+                    # a power-of-two count so few shapes ever compile:
+                    # some tens of KB come back, not the batch's lanes
+                    fill = max(1024, 1 << (at.size - 1).bit_length())
+                    idx = jax.device_put(np.concatenate(
+                        [at, np.full(fill - at.size, at[0])]))
+                    keys, parts, rows = jax.device_get(jax.tree_util.tree_map(
+                        lambda a: (a if serial else a[qi])[idx], entries))
+                    real = np.arange(fill) < at.size
+                    n_rows += int(rows[real].sum())
+                    merge_hash_tables_into(acc, self.plan, keys, parts,
+                                           rows, entry_mask=real)
+            GLOBAL_COUNTERS.bump("hash_spill_rows", n_rows)
+            GLOBAL_COUNTERS.bump("hash_table_updates", n_updates)
+            self.rows += n_rows
+            self.updates += n_updates
             if dsp.recording:
-                dsp.set(batches=len(pending), rows=n_window)
+                dsp.set(batches=len(pending), rows=n_rows)
 
 
 def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
@@ -536,7 +551,7 @@ def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     # passes (local, then push fallbacks) book into one placement
     placement = OneDevice()
     placement.bind(params)
-    drain = _SpillDrain(plan, [acc], [penv])
+    drain = _SpillDrain(plan, [acc])
     with _trace.span("hash_init") as sp:
         S = _hash_slots(cat, plan, settings, key_dtypes)
         state = jax.device_put(empty_hash_state(plan, S, key_dtypes))
@@ -610,6 +625,8 @@ def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     pl["hash_slots"] = S
     pl["hash_occupancy_pct"] = round(100.0 * int((h_rows > 0).sum()) / S, 1)
     pl["hash_spilled_rows"] = drain.rows
+    pl["hash_table_updates"] = drain.updates
+    pl["hash_rows_in"] = sum(n for _, n, _ in placement.task_times)
     return h_keys, h_partials, h_rows
 
 
@@ -693,7 +710,7 @@ def _run_agg_hash_host(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     tpu backend: streaming fused device hash aggregation
     (ops/hash_agg.py build_fused_hash_worker) — one donated HBM-resident
     table, one dispatch per batch, exact host merge of the final table
-    and of spilled rows; remote-only shards push hash tasks and ship
+    and of spilled entries; remote-only shards push hash tasks and ship
     table partials back over CTFR frames.  cpu backend (and exact
     value-set partials): full host grouping over the pull path."""
     from citus_tpu.executor.host_agg import HostGroupAccumulator

@@ -425,7 +425,7 @@ def _batched_hash_agg(cat, plan, settings, group: list[_Waiter]) -> list:
         lambda a: np.stack([a] * qp), empty_hash_state(plan, S, key_dtypes)))
     state = drive(plan, settings, placement, step, state, PipelineStats(),
                   stream=_iter_padded_batches(cat, plan, settings),
-                  on_sync=_SpillDrain(plan, accs, penvs))
+                  on_sync=_SpillDrain(plan, accs))
     host = jax.device_get(state)
     return [("hash_agg",
              (jax.tree_util.tree_map(lambda a: np.asarray(a)[qi], host),

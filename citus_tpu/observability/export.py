@@ -98,6 +98,7 @@ METRIC_HELP = {
     "batch_rows_real": "table rows in the padded scan batches made",
     "batch_rows_padded": "rows of those batches' power-of-two buckets",
     "hash_groups_out": "groups of hash aggregations after the host merge",
+    "hash_table_updates": "entries (a batch's distinct keys) offered to device hash tables",
     "hash_table_bytes_fetched": "bytes of device hash tables fetched",
     "wait_remote_rpc_ms": "ms blocked on remote RPC round trips",
     "wait_lock_ms": "ms blocked acquiring advisory locks",

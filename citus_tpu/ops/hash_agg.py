@@ -3,21 +3,43 @@
 When the key domain can't be proven small (no direct-gid mode), the
 executor aggregates on device into ONE fixed-size open-addressed hash
 table that lives in HBM for the whole scan: ``build_fused_hash_worker``
-composes filter→fingerprint→claim→insert *and* the merge into the prior
-table state in a single traced body, so the executor jits it with
-``donate_argnums=0`` (kernel-cache slot ``jit_hash_fused``) and XLA
-reuses the table buffers in place — one dispatch per batch, no per-batch
-tables, no concatenate+re-insert merge kernels.
+composes the whole of a batch's work in a single traced body, so the
+executor jits it with ``donate_argnums=0`` (kernel-cache slot
+``jit_hash_fused``) and XLA reuses the table buffers in place — one
+dispatch per batch, no per-batch tables, no concatenate+re-insert merge
+kernels.
 
-Placement is exact, never probabilistic: a row claims a slot by 64-bit
-key fingerprint (minimum fingerprint wins the scatter race), but the
-claim only counts when the slot's stored *key values* match the row's
-keys exactly.  Each fingerprint gets two candidate slots (a
-second-chance probe through a remixed hash); rows that lose both are
-reported in a spill mask and re-aggregated exactly on the host
-(HostGroupAccumulator) — the static-shape analog of a hash-agg spilling
-to disk.  Occupancy only grows and the probe sequence is deterministic,
-so a group keeps matching the slot it first landed in across batches.
+A scatter is a serial loop on a TPU (about 120 ns an update on 64-bit
+lanes, 20 ns on 32-bit ones), so a batch is aggregated by key BEFORE it
+meets the table and the table takes one update per distinct key of the
+batch, nothing per padded or repeated row:
+
+1. filter → canonical keys → fingerprint → SORT the row indexes by 31
+   fingerprint bits, masked-out rows last, and gather the rows into
+   that order;
+2. SEGMENT REDUCE: neighbours with exactly equal keys form a segment,
+   and a log-step segmented scan (shifts and selects, no scatter) leaves
+   every segment's count/sum/min/max on its last row;
+3. a second sort brings the D segment ends to the front;
+4. CHUNKED MATCH-OR-CLAIM: a loop whose trip count is ceil(D /
+   ``ENTRY_CHUNK``) at run time offers the entries to the table and
+   merges their partial states into their slots.
+
+Placement is exact, never probabilistic: an entry takes a slot only
+when the slot's stored *key values* equal its keys exactly or the slot
+is empty, and among the entries of one chunk a scatter race (lowest
+entry index wins) gives each slot to one of them, so a slot's partial
+state is read, merged and written back without a combining scatter.
+Segments are cut on the key values, not on the sort bits, so two keys
+that share them stay two entries (and a key whose rows interleave with
+another's becomes several entries of one key: the first takes the slot,
+the repeats spill).  Each fingerprint gets two candidate slots (a
+second-chance probe through a remixed hash); entries that lose both are
+returned with their merged partial states and re-aggregated exactly on
+the host (HostGroupAccumulator) — the static-shape analog of a hash-agg
+spilling to disk.  Occupancy only grows and the probe sequence is
+deterministic, so a group keeps matching the slot it first landed in
+across batches.
 
 Float keys are canonicalized before fingerprinting and storage
 (``-0.0`` → ``0.0``, every NaN payload → the canonical quiet NaN) so
@@ -28,8 +50,9 @@ group space.
 The merged table is fixed-shape arrays, which also makes it a wire
 value: workers ship (key values, key flags, partial tables, rows) as
 CTFR frame columns (net/data_plane.py encode_hash_partials) and the
-coordinator re-inserts remote entries through the same claim/match core
-(``build_fused_entry_merge``, slot ``jit_hash_merge``) — the reference's
+coordinator re-inserts remote entries through the same match-or-claim
+core (``build_fused_entry_merge``, slot ``jit_hash_merge``; a peer's
+entries are distinct already and need no sort) — the reference's
 two-stage worker_partial_agg / coord_combine_agg seam
 (multi_logical_optimizer.c), with O(slots) on the wire instead of
 O(rows).
@@ -115,50 +138,95 @@ def _stored_eq(xp, kvt, kft, slot, kv, kvm):
     return eq & (kft[slot] == kvm.astype(np.int8) + 1)
 
 
+def _combine(xp, kind, a, b):
+    """Two partial states of a ``kind`` ("sum", "count", "min", "max")
+    merged: accumulators add, extrema keep the extreme."""
+    if kind in ("min", "max"):
+        return xp.minimum(a, b) if kind == "min" else xp.maximum(a, b)
+    return a + b
+
+
+def _set_distinct(xp, table, at, values):
+    """``table`` with ``values`` written at the DISTINCT slots ``at``
+    (a slot past the end: dropped).  A 64-bit scatter costs a TPU six
+    times a 32-bit one (120 ns an update against 20), so an int64 table
+    is written as its two 32-bit halves."""
+    if table.dtype != np.int64:
+        return table.at[at].set(values.astype(table.dtype), mode="drop")
+    low = np.int64(0xFFFFFFFF)
+    values = values.astype(np.int64)
+    lo = (table & low).astype(np.uint32).at[at].set(
+        (values & low).astype(np.uint32), mode="drop")
+    hi = (table >> 32).astype(np.int32).at[at].set(
+        (values >> 32).astype(np.int32), mode="drop")
+    return (hi.astype(np.int64) << 32) | lo.astype(np.int64)
+
+
 def _insert_keys(xp, keys, mask, h, key_tables, occ):
-    """Two-probe match-or-claim into a RUNNING table.
+    """Two-probe match-or-claim of entries into a RUNNING table, each
+    placed entry in a slot of its own.
 
     keys are canonical (``_canon_keys``); ``occ`` marks slots occupied
-    before this batch.  Each probe round first matches rows against the
-    stored entry at their candidate slot, then lets unmatched rows claim
-    an UNOCCUPIED slot (min fingerprint wins; stored key values verify
-    the claim exactly — fingerprint collisions lose and spill).  Returns
-    ``(slot, placed, key_tables, occ)`` with the updated tables; rows
-    with ``placed`` False must spill to the host.
+    before these entries.  In each probe round an entry whose candidate
+    slot stores exactly its key, or is empty, enters a scatter race for
+    the slot (lowest entry index wins; int32, the cheap kind of scatter)
+    and the winner is placed, writing its key where the slot was empty.
+    A loser with another key (it shares the slot, or the fingerprint)
+    tries its second slot and spills after that; a loser that finds its
+    OWN key there — a key repeated among the entries — spills at once,
+    so no key ever sits in two slots and no slot takes two entries of
+    one call.  Returns ``(slot, placed, key_tables)`` with the updated
+    tables; entries with ``placed`` False must spill to the host.
     """
     S = occ.shape[0]
-    sent = np.uint64(0xFFFFFFFFFFFFFFFF)
-    fslot = None
-    placed = xp.zeros(mask.shape, bool)
+    none = np.int32(np.iinfo(np.int32).max)
+    ids = xp.arange(mask.shape[0], dtype=np.int32)
+
+    def stores(tables, slot):
+        eq = xp.ones(mask.shape, bool)
+        for (kv, kvm), (kvt, kft) in zip(keys, tables):
+            eq = eq & _stored_eq(xp, kvt, kft, slot, kv, kvm)
+        return eq
+
+    fslot = xp.zeros(mask.shape, np.int32)
+    placed = repeated = xp.zeros(mask.shape, bool)
     for hp in (h, _mix(xp, h, _GOLD)):
-        cand = (hp % np.uint64(S)).astype(np.int32)
-        want = mask & ~placed
-        cand = xp.where(want, cand, 0)
-        match = want & occ[cand]
-        for (kv, kvm), (kvt, kft) in zip(keys, key_tables):
-            match = match & _stored_eq(xp, kvt, kft, cand, kv, kvm)
-        wants_claim = want & ~match & ~occ[cand]
-        claimed = xp.full((S,), sent, np.uint64).at[cand].min(
-            xp.where(wants_claim, hp, sent))
-        claim_ok = wants_claim & (claimed[cand] == hp)
-        new_tables = []
-        for (kv, kvm), (kvt, kft) in zip(keys, key_tables):
-            ksent = _key_sentinel(kvt.dtype)
-            kvt = kvt.at[cand].max(
-                xp.where(claim_ok, kv, ksent).astype(kvt.dtype))
-            kft = kft.at[cand].max(
-                xp.where(claim_ok, kvm.astype(np.int8) + 1, 0).astype(np.int8))
-            new_tables.append((kvt, kft))
-        verified = claim_ok
-        for (kv, kvm), (kvt, kft) in zip(keys, new_tables):
-            verified = verified & _stored_eq(xp, kvt, kft, cand, kv, kvm)
-        key_tables = new_tables
-        occ = occ | (xp.zeros((S,), np.int32).at[cand].add(
-            verified.astype(np.int32)) > 0)
-        took = match | verified
-        fslot = cand if fslot is None else xp.where(took, cand, fslot)
-        placed = placed | took
-    return fslot, placed, key_tables, occ
+        want = mask & ~placed & ~repeated
+        cand = xp.where(want, (hp % np.uint64(S)).astype(np.int32), 0)
+        free = ~occ[cand]
+        enter = want & (free | stores(key_tables, cand))
+        first = xp.full((S,), none, np.int32).at[cand].min(
+            xp.where(enter, ids, none))
+        won = enter & (first[cand] == ids)
+        at = xp.where(won & free, cand, S)
+        key_tables = [
+            (_set_distinct(xp, kvt, at, kv),
+             _set_distinct(xp, kft, at, kvm.astype(np.int8) + 1))
+            for (kv, kvm), (kvt, kft) in zip(keys, key_tables)]
+        repeated = repeated | (enter & ~won & stores(key_tables, cand))
+        occ = occ | (first != none)
+        fslot = xp.where(won, cand, fslot)
+        placed = placed | won
+    return fslot, placed, key_tables
+
+
+def _merge_entries(xp, partial_ops, table_state, keys, mask,
+                   partial_entries, row_entries):
+    """Match-or-claim ``keys`` (canonical) into the table and MERGE the
+    placed entries' partial states into their slots (count/sum add their
+    accumulators, min/max keep extrema, rows adds the entry counts):
+    read the slot, combine, write it back, since ``_insert_keys`` gives
+    each placed entry a slot of its own.  -> (table_state', placed)."""
+    key_tables, partials, rows = table_state
+    h = _fingerprint(xp, keys, mask.shape)
+    slot, placed, key_tables = _insert_keys(
+        xp, keys, mask, h, list(key_tables), rows > 0)
+    at = xp.where(placed, slot, rows.shape[0])
+    outs = [_set_distinct(xp, prior, at, _combine(
+                xp, op.kind, prior[slot], xp.asarray(p).astype(prior.dtype)))
+            for op, prior, p in zip(partial_ops, partials, partial_entries)]
+    rows = _set_distinct(xp, rows, at, rows[slot] + row_entries)
+    return (tuple(key_tables), tuple(outs), rows), placed
 
 
 def _eval_keys(xp, key_fns, key_dtypes, env, shape):
@@ -175,18 +243,62 @@ def _eval_keys(xp, key_fns, key_dtypes, env, shape):
     return _canon_keys(xp, keys)
 
 
+def _shift_in(xp, x, d, fill):
+    """``x`` moved ``d`` places to the right, ``fill`` shifted in."""
+    return xp.concatenate([xp.full((d,), fill, x.dtype), x[:-d]])
+
+
+def _segment_scan(xp, start, lanes):
+    """Inclusive scan of every lane within its segment (``start`` marks
+    a segment's first row), so a segment's last row holds its reduction.
+    ``lanes`` is [(values, "sum" | "min" | "max")].  Log-step shifts and
+    elementwise combines: no scatter, no prefix-sum difference (a float
+    sum is a tree of additions within its segment alone), and nothing
+    that XLA for TPU is slow to compile (``cumsum`` and
+    ``associative_scan`` over a million rows take it a minute each)."""
+    vals = [v for v, _ in lanes]
+    flag, d = start, 1
+    while d < start.shape[0]:
+        vals = [xp.where(flag, v, _combine(
+                    xp, kind, _shift_in(xp, v, d, _sentinel(kind, v.dtype)),
+                    v))
+                for v, (_, kind) in zip(vals, lanes)]
+        flag = flag | _shift_in(xp, flag, d, True)
+        d *= 2
+    return vals
+
+
+#: entries offered to the table per round of the kernel's insert loop:
+#: the static shape of its scatters, so their cost follows the batch's
+#: distinct keys in steps of this many
+ENTRY_CHUNK = 1 << 16
+
+
 def build_fused_hash_worker(plan: PhysicalPlan, xp,
                             key_dtypes: tuple) -> Callable:
-    """Fused streaming insert: (table_state, cols, valids, row_mask) ->
-    (table_state', spill_mask[N]).
+    """Fused streaming aggregation of one batch into the table:
+    (table_state, cols, valids, row_mask) -> (table_state', spill).
 
     ``table_state`` is ``(key_tables [(vals[S], flags[S] int8)...],
     partial tables tuple [S], rows[S] int64)`` (see ``empty_hash_state``)
-    and is meant to be DONATED: every output array derives from an
-    in-place ``.at[]`` update of the matching input, so XLA reuses the
-    table's HBM buffers across batches.  The slot count is read off the
+    and is meant to be DONATED: every output array is the matching
+    input with some slots rewritten, so XLA reuses the table's HBM
+    buffers across batches.  The slot count is read off the
     state shapes, not baked into the closure — one cached kernel serves
-    any ``citus.hash_agg_slots`` setting."""
+    any ``citus.hash_agg_slots`` setting.
+
+    The batch is aggregated by key BEFORE it meets the table (module
+    docstring): sort, segment scan, compaction, then the insert loop
+    over the batch's D entries.  ``spill`` is ``(D, n_spilled,
+    spill_mask, key_entries, partial_entries, row_entries)``: the
+    scalars first, then the batch's entries compacted to the front of
+    arrays a whole number of ``ENTRY_CHUNK`` long (the layout
+    ``merge_hash_tables_into`` reads), of which ``spill_mask`` marks the
+    ones that lost both probes: the host fetches the scalars, the mask
+    only if ``n_spilled`` is not 0, and of the lanes the marked entries
+    alone."""
+    from jax import lax
+
     filter_fn = compile_expr(plan.bound.filter, xp) \
         if plan.bound.filter is not None else None
     key_fns = [compile_expr(k, xp) for k in plan.bound.group_keys]
@@ -194,46 +306,121 @@ def build_fused_hash_worker(plan: PhysicalPlan, xp,
     names = plan.scan_columns + param_env_names(plan.bound.param_specs)
     partial_ops = plan.partial_ops
     key_dtypes = tuple(np.dtype(d) for d in key_dtypes)
+    used_args = sorted({op.arg_index for op in partial_ops
+                        if op.arg_index >= 0})
+
+    def same_as_left(v):
+        eq = v[1:] == v[:-1]
+        if np.issubdtype(v.dtype, np.floating):
+            eq = eq | (xp.isnan(v[1:]) & xp.isnan(v[:-1]))
+        return xp.concatenate([xp.zeros((1,), bool), eq])
 
     # named for its kernel slot: the XLA module in a device trace is
     # jit_hash_fused, apart from the scan kernel's jit_fused
     def hash_fused(table_state, cols, valids, row_mask):
-        key_tables, partials, rows = table_state
-        key_tables = list(key_tables)
+        N = row_mask.shape[0]
         env = {n: (c, v) for n, c, v in zip(names, cols, valids)}
         mask = row_mask
         if filter_fn is not None:
             mask = mask & predicate_mask(xp, filter_fn, env, row_mask)
-        keys = _eval_keys(xp, key_fns, key_dtypes, env, row_mask.shape)
-        h = _fingerprint(xp, keys, row_mask.shape)
-        slot, placed, key_tables, _ = _insert_keys(
-            xp, keys, mask, h, key_tables, rows > 0)
-        spill = mask & ~placed
-        outs = []
-        for op, prior in zip(partial_ops, partials):
+
+        # 1. sort: 31 bits of the fingerprint bring equal keys together
+        # and the masked-out rows last.  One uint32 key and the row
+        # index: XLA for TPU compiles a sort in 10-15 s PER 32-bit lane
+        # (five lanes: 78 s), so the rows follow by gather — the scan
+        # columns a key or an argument reads (XLA drops the others),
+        # their validity bits packed 31 to a lane — and keys and
+        # arguments are evaluated on the rows in that order.
+        h = _fingerprint(
+            xp, _eval_keys(xp, key_fns, key_dtypes, env, (N,)), (N,))
+        last = np.uint32(1 << 31)
+        order, perm = lax.sort(
+            (xp.where(mask, (h >> np.uint64(33)).astype(np.uint32), last),
+             xp.arange(N, dtype=np.int32)), num_keys=1)
+        real = order != last
+        rowwise = [i for i, v in enumerate(valids) if xp.ndim(v)]
+        packs = [sum(valids[i].astype(np.int32) << b
+                     for b, i in enumerate(rowwise[at:at + 31]))[perm]
+                 for at in range(0, len(rowwise), 31)]
+        env = dict(env)
+        for j, i in enumerate(rowwise):
+            env[names[i]] = (cols[i][perm],
+                             (packs[j // 31] >> (j % 31)) & 1 == 1)
+        keys = _eval_keys(xp, key_fns, key_dtypes, env, (N,))
+        args = {}
+        for ai in used_args:
+            v, valid = arg_fns[ai](env)
+            args[ai] = (xp.broadcast_to(xp.asarray(v), (N,)),
+                        _as_mask(xp, valid, real))
+
+        # 2. segments of EQUAL KEYS (values and validity, exactly: two
+        # keys that share the 31 bits are two segments, or more where
+        # their rows interleave), each reduced onto its last row
+        # (masked rows sit last: a real row's left neighbour is real)
+        same = xp.concatenate([xp.zeros((1,), bool), real[1:]])
+        for kv, kvm in keys:
+            same = same & same_as_left(kv) & same_as_left(kvm)
+        start = real & ~same
+        end = real & ~xp.concatenate([same[1:], xp.zeros((1,), bool)])
+        lanes = [(real.astype(np.int32), "sum")]
+        for op in partial_ops:
             dt = np.dtype(op.dtype)
             if op.arg_index < 0:
-                outs.append(prior.at[slot].add(
-                    xp.where(placed, 1, 0).astype(np.int64)))
-                continue
-            v, valid = arg_fns[op.arg_index](env)
-            v = xp.asarray(v)
-            if v.ndim == 0:
-                v = xp.broadcast_to(v, row_mask.shape)
-            ok = placed & _as_mask(xp, valid, placed)
+                continue   # count(*) is the segment's rows
+            v, ok = args[op.arg_index]
+            ok = real & ok
             if op.kind == "count":
-                outs.append(prior.at[slot].add(
-                    xp.where(ok, 1, 0).astype(np.int64)))
+                lanes.append((ok.astype(np.int32), "sum"))
             elif op.kind == "sum":
-                outs.append(prior.at[slot].add(
-                    xp.where(ok, v, 0).astype(dt)))
+                lanes.append((xp.where(ok, v, 0).astype(dt), "sum"))
             else:
-                s_ = dt.type(_sentinel(op.kind, dt))
-                upd = xp.where(ok, v, s_).astype(dt)
-                outs.append(prior.at[slot].min(upd) if op.kind == "min"
-                            else prior.at[slot].max(upd))
-        rows = rows.at[slot].add(xp.where(placed, 1, 0).astype(np.int64))
-        return (tuple(key_tables), tuple(outs), rows), spill
+                lanes.append((xp.where(
+                    ok, v, _sentinel(op.kind, dt)).astype(dt), op.kind))
+        seg_rows, *seg = _segment_scan(xp, start, lanes)
+        seg = iter(seg)
+        reduced = [seg_rows if op.arg_index < 0 else next(seg)
+                   for op in partial_ops]
+
+        # 3. the D segment ends to the front, in order
+        C = min(ENTRY_CHUNK, N)
+        n_pad = -(-N // C) * C
+        pos = xp.arange(N, dtype=np.int32)
+        ends = lax.sort(xp.where(end, pos, pos + np.int32(N)))
+        ends = xp.concatenate([ends, xp.zeros((n_pad - N,), np.int32)])
+        D = end.sum(dtype=np.int32)
+
+        # 4. offer them to the table a chunk at a time: the trip count,
+        # and so the cost of the scatters, follows D
+        def offer(c, carry):
+            state, n_spilled, spill, okeys, oparts, orows = carry
+            at = c * C
+            e = lax.dynamic_slice(ends, (at,), (C,))
+            live = at + xp.arange(C, dtype=np.int32) < D
+            e = xp.where(live, e, 0)
+            ekeys = [(kv[e], kvm[e]) for kv, kvm in keys]
+            eparts = [p[e].astype(t.dtype)
+                      for p, t in zip(reduced, state[1])]
+            erows = xp.where(live, seg_rows[e], 0).astype(np.int64)
+            state, placed = _merge_entries(
+                xp, partial_ops, state, ekeys, live, eparts, erows)
+            lost = live & ~placed
+            put = lambda buf, x: lax.dynamic_update_slice(buf, x, (at,))
+            return (state, n_spilled + lost.sum(dtype=np.int32),
+                    put(spill, lost),
+                    [(put(bv, kv), put(bf, kvm.astype(np.int8) + 1))
+                     for (bv, bf), (kv, kvm) in zip(okeys, ekeys)],
+                    [put(b, p) for b, p in zip(oparts, eparts)],
+                    put(orows, erows))
+
+        _, partials, _ = table_state
+        empty = lambda dt: xp.zeros((n_pad,), dt)
+        state, n_spilled, spill, okeys, oparts, orows = lax.fori_loop(
+            0, (D + C - 1) // C, offer,
+            (table_state, np.int32(0), empty(bool),
+             [(empty(kdt), empty(np.int8)) for kdt in key_dtypes],
+             [empty(p.dtype) for p in partials], empty(np.int64)))
+        return state, (D, n_spilled, spill, tuple(okeys), tuple(oparts),
+                       orows)
     return hash_fused
 
 
@@ -246,40 +433,22 @@ def build_fused_entry_merge(plan: PhysicalPlan, xp,
     Entries are occupied slots of a peer's table — ``key_entries`` as
     [(values[M], flags[M] int8)], ``partial_entries`` the stored partial
     states, ``row_entries`` the per-entry row counts (0 = empty, skip).
-    Same two-probe match-or-claim as the streaming insert, but partial
-    states MERGE (count/sum add their accumulators, min/max keep
-    extrema) and rows adds the entry counts.  ``table_state`` is donated
-    exactly like the streaming kernel's."""
+    A peer's entries are distinct keys already, so they go straight
+    through the streaming kernel's ``_merge_entries``.  ``table_state``
+    is donated exactly like the streaming kernel's."""
     partial_ops = plan.partial_ops
     key_dtypes = tuple(np.dtype(d) for d in key_dtypes)
 
     def merge(table_state, key_entries, partial_entries, row_entries):
-        key_tables, partials, rows = table_state
-        key_tables = list(key_tables)
         row_entries = xp.asarray(row_entries)
         mask = row_entries > 0
-        keys = [(xp.asarray(kv).astype(kdt), xp.asarray(kf) == 2)
-                for (kv, kf), kdt in zip(key_entries, key_dtypes)]
-        keys = _canon_keys(xp, keys)
-        h = _fingerprint(xp, keys, row_entries.shape)
-        slot, placed, key_tables, _ = _insert_keys(
-            xp, keys, mask, h, key_tables, rows > 0)
-        spill = mask & ~placed
-        outs = []
-        for op, prior, p in zip(partial_ops, partials, partial_entries):
-            dt = np.dtype(prior.dtype)
-            p = xp.asarray(p)
-            if op.kind in ("sum", "count"):
-                outs.append(prior.at[slot].add(
-                    xp.where(placed, p, dt.type(0)).astype(dt)))
-            else:
-                s_ = dt.type(_sentinel(op.kind, dt))
-                upd = xp.where(placed, p, s_).astype(dt)
-                outs.append(prior.at[slot].min(upd) if op.kind == "min"
-                            else prior.at[slot].max(upd))
-        rows = rows.at[slot].add(
-            xp.where(placed, row_entries, 0).astype(np.int64))
-        return (tuple(key_tables), tuple(outs), rows), spill
+        keys = _canon_keys(xp, [
+            (xp.asarray(kv).astype(kdt), xp.asarray(kf) == 2)
+            for (kv, kf), kdt in zip(key_entries, key_dtypes)])
+        state, placed = _merge_entries(
+            xp, partial_ops, table_state, keys, mask,
+            [xp.asarray(p) for p in partial_entries], row_entries)
+        return state, mask & ~placed
     return merge
 
 

@@ -171,14 +171,17 @@ class StatCounters:
         "fused_rows_skipped",
         # streaming fused hash aggregation (executor/executor.py,
         # executor/megabatch.py, ops/hash_agg.py): fused hash-table
-        # kernel rounds (1 per batch, table donated in), rows that lost
-        # a fingerprint-collision probe and drained into the exact host
-        # accumulator, remote hash-table partials merged back through
+        # kernel rounds (1 per batch, table donated in), rows of the
+        # entries that lost both probes and drained into the exact host
+        # accumulator, entries offered to the table (a batch's distinct
+        # keys, after sort and segment reduce: what the kernel's
+        # scatters cost follows), remote hash-table partials merged back through
         # the device merge door (executor/pipeline.py push path), groups
         # after the host merge of table and spills (before HAVING), and
         # bytes of device hash table fetched to the host
         "hash_fused_dispatches",
         "hash_spill_rows",
+        "hash_table_updates",
         "hash_partials_pushed",
         "hash_groups_out",
         "hash_table_bytes_fetched",

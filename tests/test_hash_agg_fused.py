@@ -12,6 +12,12 @@ Covers the PR's acceptance surface:
   cache capped out of the way and depth 1;
 - `citus.hash_agg_slots = auto` sizes from catalog row stats and the
   EXPLAIN ANALYZE `Hash:` line reports slots / occupancy / spill;
+- the kernel's sort -> segment reduce -> chunked insert equals the cpu
+  oracle for every shape of a batch (one to thousands of rows a key,
+  all masked, NULL / float / two-column keys, count, decimal sum, min,
+  max and float sum together), keeps groups apart under a forced
+  fingerprint collision, and `hash_table_updates` counts the batches'
+  distinct keys, not their rows;
 - 2-host push: hash-table partials ship as TASK_VERSION 3 "hash"
   tasks (`hash_partials_pushed` rises, zero fallbacks, zero placement
   sync) byte-identical to the pull path, and a TASK_VERSION-2 peer
@@ -263,6 +269,162 @@ def test_hash_groupby_rides_megabatch(cl, one_device):
         assert "batched:jit_hash_fused" in {k[1] for k in GLOBAL_KERNELS._e}
     finally:
         cl.execute("SET citus.megabatch_window_ms = 0")
+
+
+# ------------------------------- sort -> segment reduce -> chunked insert
+
+
+def _keyed_table(cl, shape, n=6000):
+    """A table ``s`` whose GROUP BY exercises one shape of the kernel's
+    sort / segment-reduce / chunked-insert path -> its GROUP BY sql."""
+    rng = np.random.default_rng(len(shape))
+    k = np.arange(n, dtype=np.int64)
+    g = rng.integers(0, 10**12, n)
+    f = rng.integers(-50, 50, n) / 4.0
+    v = rng.integers(-500, 500, n)
+    x = rng.random(n) * 100 - 50
+    cols, where = "g", ""
+    if shape == "one_row_a_key":
+        pass                                  # g: n distinct keys
+    elif shape == "seven_rows_a_key":
+        g = np.repeat(g[:-(-n // 7)], 7)[:n]
+        rng.shuffle(g)
+    elif shape == "all_distinct_sorted":
+        g = np.sort(g)
+    elif shape == "one_key_whole_batch":
+        cols, f = "f", np.full(n, 2.5)        # a float key is never direct
+    elif shape == "all_masked":
+        where = "WHERE v * 0 = 1"             # no statistic refutes it
+    elif shape == "null_keys":
+        g = np.where(rng.random(n) < 0.3, None,
+                     g[rng.integers(0, 40, n)]).tolist()
+    elif shape == "float_keys_zero_nan":
+        cols = "f"
+        f = np.array([0.0, -0.0, float("nan"), 1.5, -1.5, float("inf"),
+                      float("-inf")])[rng.integers(0, 7, n)]
+    elif shape == "two_key_columns":
+        cols = "g, f"
+        g = g[rng.integers(0, 60, n)]
+    else:
+        raise AssertionError(shape)
+    cl.execute("CREATE TABLE s (k bigint NOT NULL, g bigint, f double, "
+               "v bigint, d decimal(12,2), x double)")
+    cl.execute("SELECT create_distributed_table('s', 'k', 2)")
+    cl.copy_from("s", columns={"k": k, "g": g, "f": f, "v": v,
+                               "d": v / 100.0, "x": x})
+    return (f"SELECT {cols}, count(*), sum(d), min(v), max(v), count(x), "
+            f"sum(x), min(x) FROM s {where} GROUP BY {cols}")
+
+
+def _rows_equal(a, b):
+    """Rows equal, a float sum within a few ulps (the device adds a
+    segment's floats as a tree, the host in row order)."""
+    assert len(a) == len(b)
+    for ra, rb in zip(sorted(a, key=repr), sorted(b, key=repr)):
+        for va, vb in zip(ra, rb):
+            if isinstance(va, float) and isinstance(vb, float):
+                assert (va == vb or (math.isnan(va) and math.isnan(vb))
+                        or math.isclose(va, vb, rel_tol=1e-9, abs_tol=1e-9)), (ra, rb)
+            else:
+                assert va == vb, (ra, rb)
+
+
+@pytest.mark.parametrize("slots", [0, 64])
+@pytest.mark.parametrize("shape", [
+    "one_row_a_key", "seven_rows_a_key", "all_distinct_sorted",
+    "one_key_whole_batch", "all_masked", "null_keys",
+    "float_keys_zero_nan", "two_key_columns"])
+def test_sorted_segments_match_cpu_oracle(cl, one_device, shape, slots):
+    """Every shape of a batch — one to thousands of rows a key, nothing
+    but masked rows, NULL and float keys, two key columns — with count,
+    decimal sum, min, max and a float sum beside each other, in a table
+    that holds the keys (auto) and in one that spills most (64 slots)."""
+    sql = _keyed_table(cl, shape)
+    _assert_hash_mode(cl, sql)
+    cl.execute(f"SET citus.hash_agg_slots = {slots}")
+    c0 = cl.counters.snapshot()
+    fused = cl.execute(sql).rows
+    c1 = cl.counters.snapshot()
+    assert _delta(c0, c1, "hash_fused_dispatches") >= 2
+    spilled = _delta(c0, c1, "hash_spill_rows")
+    if slots and shape in ("one_row_a_key", "seven_rows_a_key"):
+        assert spilled > 3000
+    cl.execute("SET citus.task_executor_backend = 'cpu'")
+    cpu = cl.execute(sql).rows
+    cl.execute("SET citus.task_executor_backend = 'tpu'")
+    _rows_equal(fused, cpu)
+    if shape == "all_masked":
+        assert fused == []
+
+
+def test_fingerprint_collision_keeps_groups_apart(cl, one_device,
+                                                  monkeypatch):
+    """Every key given ONE fingerprint: the sort brings nothing together
+    and all entries contend for the same two slots, yet segments are cut
+    on the key values and a slot takes only its stored key, so no two
+    groups merge — the rest spill and stay exact."""
+    import collections
+    from citus_tpu.ops import hash_agg
+    g, v = _fill_groups(cl, 6000, 300, shards=2)
+    monkeypatch.setattr(
+        hash_agg, "_fingerprint",
+        lambda xp, keys, shape: xp.full(shape, np.uint64(12345), np.uint64))
+    GLOBAL_KERNELS.clear()
+    try:
+        c0 = cl.counters.snapshot()
+        got = sorted(cl.execute(
+            "SELECT g, count(*), sum(v), min(v) FROM t GROUP BY g").rows)
+        c1 = cl.counters.snapshot()
+    finally:
+        GLOBAL_KERNELS.clear()   # no other test gets the patched kernel
+    truth = collections.defaultdict(lambda: [0, 0, 10**9])
+    for gi, vi in zip(g.tolist(), v.tolist()):
+        t = truth[gi]
+        t[0] += 1
+        t[1] += vi
+        t[2] = min(t[2], vi)
+    assert got == sorted((gi, *t) for gi, t in truth.items())
+    assert len(got) == 300
+    # one key holds each of the two slots; every other row spilled
+    assert _delta(c0, c1, "hash_spill_rows") > 5000
+
+
+@pytest.mark.parametrize("by,shards", [("g", 4), ("k", 1)])
+def test_table_updates_count_distinct_keys_of_each_batch(
+        cl, one_device, by, shards):
+    """``hash_table_updates`` is the sum over batches of the batch's
+    distinct keys — with every group in one shard (distributed by the
+    group key, as Q18's block is) or one batch, the groups — not its
+    rows, and still one dispatch a batch."""
+    groups, n = 1500, 20_000
+    cl.execute("CREATE TABLE t (k bigint NOT NULL, g bigint NOT NULL, "
+               "v bigint)")
+    cl.execute(f"SELECT create_distributed_table('t', '{by}', {shards})")
+    rng = np.random.default_rng(9)
+    g = rng.integers(0, 10**12, groups)[rng.integers(0, groups, n)]
+    cl.copy_from("t", columns={"k": np.arange(n, dtype=np.int64), "g": g,
+                               "v": rng.integers(0, 1000, n)})
+    distinct = len(set(g.tolist()))
+    sql = "SELECT g, count(*), sum(v) FROM t GROUP BY g"
+    _assert_hash_mode(cl, sql)
+    c0 = cl.counters.snapshot()
+    r = cl.execute(sql)
+    c1 = cl.counters.snapshot()
+    batches = len(r.explain["tasks"])
+    assert batches == shards
+    assert len(r.rows) == distinct
+    assert _delta(c0, c1, "hash_fused_dispatches") == batches
+    assert _delta(c0, c1, "hash_table_updates") == distinct
+    assert r.explain["pipeline"]["hash_table_updates"] == distinct
+    text = "\n".join(l for (l,) in cl.execute(f"EXPLAIN ANALYZE {sql}").rows)
+    assert f"table updates {distinct} ({n} rows)" in text, text
+    # the benchmark's layer metric reads this counter by name
+    import json
+    import os
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "layer_metrics",
+                           "hash_table_updates_per_query.json")) as fh:
+        assert json.load(fh)["reader"]["counters"] == ["hash_table_updates"]
 
 
 # ------------------------------------------------------- 2-host push
