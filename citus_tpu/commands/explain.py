@@ -165,8 +165,15 @@ def _execute_explain(cl, stmt: A.Explain) -> Result:
                      ", ".join(sorted({c.column for c in plan.intervals})))
     if bound.has_aggs:
         mode = plan.group_mode
+        # the group reduction follows from the group count and the arm
+        # (ops/scan_agg.py): named here so the plan says which one runs
+        from citus_tpu.ops.scan_agg import direct_reduction
+        reduce_ = direct_reduction(
+            mode.n_groups,
+            cl.settings.executor.task_executor_backend == "cpu")
         desc = {"scalar": "Global Aggregate",
-                "direct": f"Direct GroupBy (groups: {mode.n_groups}, combine: psum)",
+                "direct": f"Direct GroupBy (groups: {mode.n_groups}, "
+                          f"reduce: {reduce_}, combine: psum)",
                 "hash_host": "Hash GroupBy (host combine)"}[mode.kind]
         lines.append(f"  Partial Aggregate per shard -> {desc}")
         lines.append(f"    Partials: " + ", ".join(
@@ -274,6 +281,9 @@ def _run_analyze(cl, stmt: A.Explain) -> list[str]:
             line += (f", stream window peak "
                      f"{pl['stream_window_peak_bytes']} bytes")
         lines.append(line)
+        if "direct_groups" in pl:
+            lines.append(f"    Direct: group slots {pl['direct_groups']}, "
+                         f"groups {pl['direct_groups_out']}")
         if "hash_slots" in pl:
             # each batch is sorted by key and segment-reduced on the
             # device, then offered to the table in chunks: U is the sum

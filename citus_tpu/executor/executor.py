@@ -381,11 +381,18 @@ def _run_agg(cat: Catalog, plan: PhysicalPlan, settings: Settings,
             else:
                 *parts, rows = partials
                 keys, occupied = _decode_direct_keys(plan, rows)
-                if occupied.size == 0:
-                    return []
-                sel_parts = tuple(np.asarray(p)[occupied] for p in parts)
-                out = finalize_groups(plan, cat, keys, sel_parts,
-                                      params_env=penv)
+                out = []
+                if occupied.size:
+                    sel_parts = tuple(np.asarray(p)[occupied] for p in parts)
+                    out = finalize_groups(plan, cat, keys, sel_parts,
+                                          params_env=penv)
+                # the slots the group reduction was sized and chosen by,
+                # and the groups that came out of them (after HAVING)
+                pl = plan.runtime_cache.setdefault("pipeline", {})
+                pl["direct_groups"] = plan.group_mode.n_groups
+                pl["direct_groups_out"] = len(out)
+                GLOBAL_COUNTERS.bump("direct_groups", plan.group_mode.n_groups)
+                GLOBAL_COUNTERS.bump("direct_groups_out", len(out))
             if sp.recording:
                 sp.set(groups=len(out))
             return out

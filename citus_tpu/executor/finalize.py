@@ -52,15 +52,7 @@ def extract_aggs(plan: PhysicalPlan, partials: tuple,
             else:
                 # exact decimal average: sum is scaled by arg scale; output
                 # scale is arg scale + 6 -> multiply by 10^6 then divide
-                vals = np.zeros(s.shape, np.int64)
-                flat_s, flat_c = s.reshape(-1), c.reshape(-1)
-                flat_o = vals.reshape(-1)
-                for i in range(flat_s.shape[0]):
-                    if flat_c[i] > 0:
-                        q = (decimal.Decimal(int(flat_s[i])) * 1_000_000 /
-                             decimal.Decimal(int(flat_c[i])))
-                        flat_o[i] = int(q.to_integral_value(rounding=decimal.ROUND_HALF_UP))
-                out.append((vals, valid))
+                out.append((_avg_scaled(s, c), valid))
         elif ex.kind in ("min", "max"):
             v = np.asarray(partials[ex.slots[0]])
             c = np.asarray(partials[ex.slots[1]])
@@ -72,6 +64,28 @@ def extract_aggs(plan: PhysicalPlan, partials: tuple,
                 raise AssertionError(ex.kind)
             out.append(fin(ex, partials, cat))
     return out
+
+
+def _avg_scaled(s: np.ndarray, c: np.ndarray) -> np.ndarray:
+    """sum * 10**6 / count per group, rounded half up (away from zero,
+    as Decimal's ROUND_HALF_UP), exact: in int64 for every group at once
+    where the scaled sums fit, group by group in Python integers where
+    one does not (thousands of groups made the loop a visible share of
+    ``finalize_groups``)."""
+    s = np.asarray(s, np.int64)
+    c = np.asarray(c, np.int64)
+    if s.size and np.abs(s).max() < (1 << 62) // 1_000_000:
+        n = np.where(c > 0, c, 1)
+        q, r = np.divmod(np.abs(s) * 1_000_000, n)
+        return np.where(c > 0, np.sign(s) * (q + (2 * r >= n)), 0)
+    vals = np.zeros(s.shape, np.int64)
+    flat_s, flat_c, flat_o = s.reshape(-1), c.reshape(-1), vals.reshape(-1)
+    for i in range(flat_s.shape[0]):
+        if flat_c[i] > 0:
+            q, r = divmod(abs(int(flat_s[i])) * 1_000_000, int(flat_c[i]))
+            q += 2 * r >= int(flat_c[i])
+            flat_o[i] = q if flat_s[i] >= 0 else -q
+    return vals
 
 
 #: |shadow float sum| at or beyond this proves the exact int64 sum
@@ -246,15 +260,24 @@ def finalize_groups(
             valid = np.broadcast_to(np.asarray(valid), (n_groups,))
         out_cols.append((v, valid, e.type))
 
-    rows = []
-    for gi in range(n_groups):
-        if not keep[gi]:
-            continue
-        row = []
-        for (v, valid, t), src in zip(out_cols, text_cols):
-            row.append(decode_qualified(cat, t, src, v[gi], bool(valid[gi])))
-        rows.append(tuple(row))
-    return rows
+    kept = np.nonzero(keep)[0]
+    cols = [_decode_column(cat, t, src, v[kept], valid[kept])
+            for (v, valid, t), src in zip(out_cols, text_cols)]
+    return list(zip(*cols)) if cols else [()] * kept.size
+
+
+def _decode_column(cat: Catalog, expr_type: T.ColumnType, source, v, valid
+                   ) -> list:
+    """One output column of the groups, physical -> Python values.  A
+    plain numeric / temporal column converts in bulk (one ``tolist`` and
+    one ``from_physical`` a value): at thousands of groups the per-cell
+    route through ``decode_qualified`` was most of ``finalize_groups``."""
+    if expr_type.is_text or v.dtype == object or v.ndim != 1:
+        return [decode_qualified(cat, expr_type, source, x, bool(ok))
+                for x, ok in zip(v, valid)]
+    render = expr_type.from_physical
+    return [render(x) if ok else None
+            for x, ok in zip(v.tolist(), valid.tolist())]
 
 
 def project_rows(plan: PhysicalPlan, cat: Catalog, env_batches: list[dict],

@@ -97,6 +97,8 @@ METRIC_HELP = {
     "bytes_scanned": "columnar bytes staged for device scans",
     "batch_rows_real": "table rows in the padded scan batches made",
     "batch_rows_padded": "rows of those batches' power-of-two buckets",
+    "direct_groups": "slots of the group domains of direct-group-id aggregations",
+    "direct_groups_out": "groups those aggregations returned",
     "hash_groups_out": "groups of hash aggregations after the host merge",
     "hash_table_updates": "entries (a batch's distinct keys) offered to device hash tables",
     "hash_table_bytes_fetched": "bytes of device hash tables fetched",

@@ -40,6 +40,192 @@ def _sentinel(kind: str, dtype: np.dtype):
     return 0
 
 
+#: group tables up to this many slots reduce by the masked one-hot on the
+#: VPU; larger ones by the factored one-hot product on the MXU.  Read on
+#: the chip (PERF.md section 6, PR 29): the one-hot costs G x N selects a
+#: partial, the product a few hundred selects a row whatever G is
+ONEHOT_MAX_GROUPS = 64
+
+#: rows of one exact float32 accumulation of the product: 8-bit limbs
+#: (at most 255 each) summed in float32 stay integers below 2**24
+_MM_CHUNK = 32768
+
+
+def direct_reduction(n_groups: int, numpy_arm: bool) -> str:
+    """Which group reduction the direct mode runs, from the plan's group
+    count alone: ``scatter`` (numpy), ``onehot`` (small tables: G x N
+    selects a partial on the VPU) or ``matmul`` (the middle: counts and
+    int64 sums as one factored one-hot product on the MXU; float sums,
+    min and max keep the one-hot up to 8,192 slots and scatter above)."""
+    if numpy_arm:
+        return "scatter"
+    return "onehot" if n_groups <= ONEHOT_MAX_GROUPS else "matmul"
+
+
+def _shadow_sources(plan: PhysicalPlan) -> dict:
+    """{partial index: (arg index of the int64 sum it guards, divisor)}
+    for the float64 shadow sums ``lower_aggregates`` puts beside every
+    int64 sum: sum(CAST(x AS float8)) where sum(x) is accumulated in
+    int64.  The cast of a decimal yields the logical value, x / 10**scale."""
+    from citus_tpu.planner.bound import BCast
+    int_sums = {op.arg_index for op in plan.partial_ops
+                if op.kind == "sum" and op.dtype == "int64"}
+    out = {}
+    for i, op in enumerate(plan.partial_ops):
+        if op.kind != "sum" or op.dtype != "float64":
+            continue
+        arg = plan.agg_args[op.arg_index]
+        if not (isinstance(arg, BCast) and arg.type.is_float):
+            continue
+        src = arg.operand.type
+        if not (src.is_decimal or src.is_integer):
+            continue
+        for j in int_sums:
+            if plan.agg_args[j] == arg.operand:
+                out[i] = (j, 10.0 ** src.scale if src.is_decimal else 1.0)
+    return out
+
+
+class _Pending:
+    """A slot of the worker's output that the batch's one product fills."""
+
+    def __init__(self, fill):
+        self.fill = fill
+
+
+def _product_split(n_groups: int) -> tuple[int, int]:
+    """(H, L) of the group id's split ``hi * L + lo``.  H rows of the
+    left operand a plane, L rows of the right one: their sum is the
+    selects a row costs, and H a multiple of bfloat16's 16-row tile makes
+    the planes' rows one array without a copy.  Read on the chip (PERF.md
+    section 6, PR 29; ms a 4,194,304-row batch of 19 planes): 4,345 slots
+    8.9 at (16, 272), 11.8 at (17, 256), 10.0 at (9, 512); 8,193 slots
+    12.9 at (16, 520), 19.8 at (33, 256); 65,536 slots 59.0 at (32, 2048),
+    86.7 at (128, 512); 1,024 slots 3.9 at (8, 128), 5.5 at (4, 256)."""
+    if n_groups <= 256:
+        H = 1
+    elif n_groups <= 2048:
+        H = 8
+    else:
+        H = 16 * -(-n_groups // (16 * 2048))
+    return H, -(-n_groups // (8 * H)) * 8
+
+
+class _MatmulGroupSums:
+    """Counts and int64 sums of one batch by group id, exact, as ONE
+    matrix product on the MXU, for group tables in the thousands.
+
+    A group id splits into ``hi * L + lo``.  Every value is cut into
+    eight 8-bit limbs (the top one signed, so that the limbs of an int64
+    add up to it modulo 2**64, which is int64's own arithmetic); a count
+    is one plane of 0/1.  With ``A[p, h, n] = plane_p[n] where hi[n] == h
+    else 0`` and ``B[l, n] = (lo[n] == l)``, both exact in bfloat16, the
+    product ``A @ B.T`` over the rows is the table of per-group plane
+    sums: P*H + L selects a row instead of G a partial, and 2*G*P
+    multiply-adds a row on the MXU.  The rows are contracted in chunks of
+    32,768, inside which a float32 accumulator holds integers below
+    2**24 exactly; the chunks add up in integers.  The loop over the
+    chunks reads the 32-bit words and the masks; the limbs are cut
+    inside it, so they never travel through HBM."""
+
+    def __init__(self, xp, gid, n_groups: int):
+        self.xp, self.gid, self.G = xp, gid, n_groups
+        self.sources: list = []     # [n] int32 words and bool masks
+        self.planes: list = []      # (source, byte | None, signed)
+        self._count_plane: dict = {}
+        self.sums: dict = {}        # arg index -> its first limb plane
+
+    def count(self, ok) -> _Pending:
+        at = self._count_plane.get(id(ok))
+        if at is None:
+            at = self._count_plane[id(ok)] = len(self.planes)
+            # the source list keeps ``ok`` alive: its id is not reused
+            self.planes.append((len(self.sources), None, False))
+            self.sources.append(ok)
+        return _Pending(lambda table: table[at])
+
+    def sum_int64(self, arg_index: int, v, ok) -> _Pending:
+        xp = self.xp
+        v = xp.where(ok, v, 0).astype(np.int64)
+        at = self.sums[arg_index] = len(self.planes)
+        for word in (v.astype(np.uint32).astype(np.int32),
+                     (v >> 32).astype(np.int32)):
+            for b in range(4):
+                # the top byte of the value keeps its sign
+                self.planes.append((len(self.sources), b,
+                                    len(self.planes) == at + 7))
+            self.sources.append(word)
+        return _Pending(lambda table: sum(
+            table[at + b] << (8 * b) for b in range(8)))
+
+    def shadow(self, arg_index: int, divisor: float) -> _Pending:
+        """float64 value of the batch's TRUE per-group sum (not wrapped),
+        in the cast's logical units: what the overflow guard sums."""
+        at = self.sums[arg_index]
+        return _Pending(lambda table: sum(
+            table[at + b].astype(np.float64) * float(1 << (8 * b))
+            for b in range(8)) / divisor)
+
+    def _table(self):
+        """-> int64 [P, G]: every plane's sum in every group."""
+        import jax
+        import jax.numpy as jnp
+        G, P = self.G, len(self.planes)
+        H, L = _product_split(G)
+        n = self.gid.shape[-1]
+        chunk = min(_MM_CHUNK, n)
+        pad = -n % chunk
+        K = (n + pad) // chunk
+
+        def chunks(a):
+            return (jnp.pad(a, (0, pad)) if pad else a).reshape(K, chunk)
+
+        # a plane's sum over a whole batch passes int32 from 2**23 rows on
+        wide = np.int64 if (n + pad) * 255 >= 2 ** 31 else np.int32
+        slots_lo = jnp.arange(L, dtype=np.int32)[:, None]
+        slots_hi = jnp.arange(H, dtype=np.int32)[None, :, None]
+        specs = self.planes
+
+        def fold(table, xs):
+            g, sources = xs                         # [chunk] each
+            planes = []
+            for at, byte, signed in specs:
+                src = sources[at]
+                if byte is not None:
+                    src = src >> (8 * byte)         # arithmetic: sign kept
+                    src = src if signed else src & 0xFF
+                planes.append(src.astype(jnp.bfloat16))
+            onehot_lo = (g % L == slots_lo).astype(jnp.bfloat16)   # [L, chunk]
+            lhs = jnp.where(g // L == slots_hi, jnp.stack(planes)[:, None, :],
+                            jnp.bfloat16(0)).reshape(P * H, chunk)
+            part = jnp.einsum("cn,ln->cl", lhs, onehot_lo,
+                              preferred_element_type=jnp.float32)
+            return table + part.astype(wide), None
+
+        table, _ = jax.lax.scan(
+            fold, jnp.zeros((P * H, L), wide),
+            (chunks(self.gid), tuple(chunks(a) for a in self.sources)))
+        return table.astype(np.int64).reshape(P, H * L)[:, :G]
+
+    def resolve(self, outs: list) -> list:
+        table = self._table()
+        return [o.fill(table) if isinstance(o, _Pending) else o for o in outs]
+
+
+def _floor_div_small_quotient(xp, d, step: int, q_max: int):
+    """``d // step`` for int64 ``d``, exact wherever the quotient lies in
+    ``[0, q_max]``, without the 64-step shift-and-subtract loop that a
+    64-bit division is on a TPU (it was a quarter of the hourly rollup's
+    kernel).  A float32 estimate of a quotient below 2**17 is off by at
+    most one; one multiply and two compares in int64 settle it.  Other
+    rows (padding, NULL keys: the caller masks them) get any value in
+    ``[-1, q_max + 1]``."""
+    q = xp.floor(d.astype(np.float32) / np.float32(step))
+    q = xp.clip(q, -1, q_max + 1).astype(np.int32)
+    r = d - q.astype(np.int64) * np.int64(step)
+    return q - (r < 0) + (r >= step)
+
+
 def build_worker_fn(plan: PhysicalPlan, xp) -> Callable:
     """Build the per-shard worker function (pure, jittable when xp=jnp)."""
     filter_fn = compile_expr(plan.bound.filter, xp) if plan.bound.filter is not None else None
@@ -155,14 +341,17 @@ def build_worker_fn(plan: PhysicalPlan, xp) -> Callable:
     if mode.kind == "direct":
         los = [d.lo for d in mode.domains]
         steps = [d.step for d in mode.domains]
+        sizes = [d.size for d in mode.domains]
         strides = mode.strides
         G = mode.n_groups
+        reduction = direct_reduction(G, xp.__name__ == "numpy")
         # XLA lowers scatter with colliding indices to a serial loop on
-        # TPU; for small-to-medium group tables a masked one-hot reduction
-        # keeps the whole aggregation on the VPU (measured ~400x faster at
-        # G<=64; the [G, N] product is tiled by XLA, never materialized).
-        # Above the threshold, fall back to scatter.
+        # TPU; for small group tables a masked one-hot reduction keeps
+        # the whole aggregation on the VPU (the [G, N] product is tiled
+        # by XLA, never materialized), at G x N compares and selects a
+        # partial.  Above the threshold, fall back to scatter.
         use_onehot = xp.__name__ != "numpy" and G <= 8192
+        shadow_of = _shadow_sources(plan) if reduction == "matmul" else {}
 
         def seg_sum(gid, upd, dt):
             if use_onehot:
@@ -188,10 +377,16 @@ def build_worker_fn(plan: PhysicalPlan, xp) -> Callable:
             env = make_env(cols, valids)
             mask = eval_mask(env, row_mask)
             gid = None
-            for kf, lo, step, stride in zip(key_fns, los, steps, strides):
+            for kf, lo, step, size, stride in zip(key_fns, los, steps, sizes,
+                                                  strides):
                 kv, kvalid = kf(env)
                 kvm = _as_mask(xp, kvalid, kv)
-                code = xp.where(kvm, (kv.astype(np.int64) - lo) // step + 1, 0)
+                d = kv.astype(np.int64) - lo
+                if step != 1 and xp.__name__ != "numpy" and size < 1 << 17:
+                    code = _floor_div_small_quotient(xp, d, step, size)
+                else:
+                    code = d // step
+                code = xp.where(kvm, code + 1, 0)
                 # clamp padding rows into range; they are masked out anyway
                 code = xp.clip(code, 0, None)
                 part = code * stride
@@ -201,24 +396,44 @@ def build_worker_fn(plan: PhysicalPlan, xp) -> Callable:
             # unclamped indexes would be silently dropped by XLA scatter but
             # error under numpy)
             gid = xp.clip(xp.where(mask, gid, 0), 0, G - 1).astype(np.int32)
+            # the middle reduction takes every count and int64 sum of the
+            # batch in ONE product: they queue here and fill their slots
+            # after the loop; float sums, min and max reduce as above
+            mm = _MatmulGroupSums(xp, gid, G) if reduction == "matmul" else None
             outs = []
-            for op in partial_ops:
+
+            def count_of(ok):
+                if mm is not None:
+                    return mm.count(ok)
+                return seg_sum(gid, xp.where(ok, 1, 0).astype(np.int64),
+                               np.dtype(np.int64))
+
+            for i, op in enumerate(partial_ops):
                 dt = np.dtype(op.dtype)
                 if op.arg_index < 0:
-                    outs.append(seg_sum(gid, xp.where(mask, 1, 0).astype(np.int64), np.dtype(np.int64)))
+                    outs.append(count_of(mask))
+                    continue
+                if i in shadow_of and shadow_of[i][0] in mm.sums:
+                    # the float64 overflow guard of an int64 sum that the
+                    # product already holds limb by limb: read from those
+                    outs.append(mm.shadow(*shadow_of[i]))
                     continue
                 v, valid = arg_fns[op.arg_index](env)
                 ok = mask & _as_mask(xp, valid, mask)
                 if op.kind == "count":
-                    outs.append(seg_sum(gid, xp.where(ok, 1, 0).astype(np.int64), np.dtype(np.int64)))
+                    outs.append(count_of(ok))
+                elif op.kind == "sum" and mm is not None and dt == np.int64:
+                    outs.append(mm.sum_int64(op.arg_index, v, ok))
                 elif op.kind == "sum":
                     outs.append(seg_sum(gid, xp.where(ok, v, 0).astype(dt), dt))
                 else:
                     sent = dt.type(_sentinel(op.kind, dt))
                     upd = xp.where(ok, v, sent).astype(dt)
                     outs.append(seg_minmax(gid, upd, dt, op.kind))
-            rows = seg_sum(gid, xp.where(mask, 1, 0).astype(np.int64), np.dtype(np.int64))
-            return tuple(outs) + (rows,)
+            outs.append(count_of(mask))
+            if mm is not None:
+                outs = mm.resolve(outs)
+            return tuple(outs)
         return worker_direct
 
     # hash_host: device evaluates filter, keys and agg inputs; host groups

@@ -185,6 +185,11 @@ class StatCounters:
         "hash_partials_pushed",
         "hash_groups_out",
         "hash_table_bytes_fetched",
+        # direct-group-id aggregation (executor.py _run_agg): slots of
+        # the plan's group domain per query (what ops/scan_agg.py sizes
+        # and chooses its reduction by) and groups returned from them
+        "direct_groups",
+        "direct_groups_out",
         # pull-path placement syncs skipped because the control plane's
         # data-invalidation epoch proved the local mirror current
         # (net/data_plane.py sync_placement fast path)
