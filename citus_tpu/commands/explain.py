@@ -288,7 +288,7 @@ def _run_analyze(cl, stmt: A.Explain) -> list[str]:
             # each batch is sorted by key and segment-reduced on the
             # device, then offered to the table in chunks: U is the sum
             # of the batches' distinct keys, R the rows they held
-            lines.append(
+            line = (
                 f"    Hash: hash slots {pl['hash_slots']}, "
                 f"occupancy {pl.get('hash_occupancy_pct', 0):g}%, "
                 f"spilled {pl.get('hash_spilled_rows', 0)} rows, "
@@ -296,6 +296,13 @@ def _run_analyze(cl, stmt: A.Explain) -> list[str]:
                 f"fetched {pl.get('hash_table_bytes_fetched', 0)} bytes, "
                 f"table updates {pl.get('hash_table_updates', 0)} "
                 f"({pl.get('hash_rows_in', 0)} rows)")
+            if pl.get("hash_having_on_device"):
+                # the chip decided HAVING on the table: the survivors'
+                # blocks and the spilled keys' entries came home
+                line += (f", having on device: "
+                         f"{pl.get('hash_entries_fetched', 0)} of "
+                         f"{pl['hash_slots']} entries fetched")
+            lines.append(line)
         if "remote_wait_ms" in pl:
             wire = f", wire {pl['wire_format']}" \
                 if pl.get("wire_format") else ""

@@ -44,6 +44,10 @@ from citus_tpu.ops.scan_agg import (
 )
 from citus_tpu.planner.auto_param import PHYSICAL_SRC, substitute_params
 from citus_tpu.planner.bind import BoundSelect
+from citus_tpu.planner.bound import (
+    BAggRef, BBinOp, BCase, BCast, BIsNull, BKeyRef, BLiteral, BParam,
+    BScale, BUnOp, walk,
+)
 from citus_tpu.planner.physical import (
     PhysicalPlan, _index_eq, extract_intervals, plan_select, prune_shards,
 )
@@ -427,6 +431,12 @@ HASH_STATE_MEMORY_SHARE = 0.25
 _UNREPORTED_FREE_BYTES = 4 << 30
 
 
+def _pow2_at_least(n: int, floor: int) -> int:
+    """The power of two at or above ``n``, at least ``floor``: the
+    counts the hash path pads to, so few shapes ever compile."""
+    return max(floor, 1 << (max(1, n) - 1).bit_length())
+
+
 def _hash_slots(cat: Catalog, plan: PhysicalPlan, settings: Settings,
                 key_dtypes: tuple, tables: int = 1) -> int:
     """Slots of a query's device hash table.  ``citus.hash_agg_slots =
@@ -446,7 +456,7 @@ def _hash_slots(cat: Catalog, plan: PhysicalPlan, settings: Settings,
         n = table_row_count(cat, cat.table(plan.bound.table.name))
     except Exception:
         n = 0
-    want = max(1024, 1 << (max(1, int(n)) - 1).bit_length())
+    want = _pow2_at_least(int(n), 1024)
     stats = executor_devices()[0].memory_stats()
     free = (stats["bytes_limit"] - stats["bytes_in_use"] if stats
             else _UNREPORTED_FREE_BYTES)
@@ -512,7 +522,7 @@ class _SpillDrain:
                     # the marked entries are gathered on the device, in
                     # a power-of-two count so few shapes ever compile:
                     # some tens of KB come back, not the batch's lanes
-                    fill = max(1024, 1 << (at.size - 1).bit_length())
+                    fill = _pow2_at_least(at.size, 1024)
                     idx = jax.device_put(np.concatenate(
                         [at, np.full(fill - at.size, at[0])]))
                     keys, parts, rows = jax.device_get(jax.tree_util.tree_map(
@@ -537,7 +547,9 @@ def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     remote-only shards ship as hash tasks first and their returned table
     partials re-insert through the fused device merge door
     (``jit_hash_merge``); push fallbacks re-stream locally.  Returns the
-    fetched (key_tables, partials, rows) host arrays."""
+    table state, still on the device and ready: how it comes home is the
+    caller's choice (``_fetch_hash_table``: all of it;
+    ``_fetch_hash_survivors``: what HAVING leaves)."""
     import jax
     import jax.numpy as jnp
     from citus_tpu.executor.pipeline import PipelineStats
@@ -620,21 +632,154 @@ def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
                 GLOBAL_COUNTERS.bump("hash_partials_pushed")
     t_dev = clock()
     _block_ready(state)
-    with _trace.span("fetch"):
-        fetched = jax.device_get(state)
     pstats.device_s += clock() - t_dev
-    h_keys = [(np.asarray(kv), np.asarray(kf)) for kv, kf in fetched[0]]
-    h_partials = tuple(np.asarray(p) for p in fetched[1])
-    h_rows = np.asarray(fetched[2])
     pstats.publish(plan)
     placement.publish(plan)
     pl = plan.runtime_cache.setdefault("pipeline", {})
     pl["hash_slots"] = S
-    pl["hash_occupancy_pct"] = round(100.0 * int((h_rows > 0).sum()) / S, 1)
     pl["hash_spilled_rows"] = drain.rows
     pl["hash_table_updates"] = drain.updates
     pl["hash_rows_in"] = sum(n for _, n, _ in placement.task_times)
+    return state
+
+
+def _fetch_hash_table(plan: PhysicalPlan, state):
+    """The whole-table ending of a hash scan: every slot comes home, as
+    (key_tables, partials, rows) host arrays."""
+    import jax
+    with _trace.span("fetch"):
+        fetched = jax.device_get(state)
+    h_keys = [(np.asarray(kv), np.asarray(kf)) for kv, kf in fetched[0]]
+    h_partials = tuple(np.asarray(p) for p in fetched[1])
+    h_rows = np.asarray(fetched[2])
+    pl = plan.runtime_cache.setdefault("pipeline", {})
+    pl["hash_occupancy_pct"] = round(
+        100.0 * int((h_rows > 0).sum()) / h_rows.shape[0], 1)
     return h_keys, h_partials, h_rows
+
+
+#: node kinds and value kinds of a HAVING the chip decides as the host
+#: does: integer arithmetic, comparisons and three-valued logic
+_HAVING_NODES = (BBinOp, BUnOp, BScale, BCast, BIsNull, BCase, BLiteral,
+                 BParam, BAggRef, BKeyRef)
+
+
+def _device_having(plan: PhysicalPlan):
+    """The plan's HAVING where the chip can decide it EXACTLY on a hash
+    table's entries, as ``(generic_having, specs, values)`` with its
+    comparison literals hoisted (one compiled filter per statement
+    family), or None: no HAVING, a node ``compile_expr`` computes in
+    floats or through a dictionary, or an aggregate whose extraction is
+    not plain integer arithmetic (``avg`` and its division, ``count
+    (DISTINCT)``, sketches, float states on the TPU's float32 pairs).
+    Read from the plan alone."""
+    from citus_tpu.executor.finalize import PLAIN_AGGS
+    from citus_tpu.planner.auto_param import hoist_literals
+    having = plan.bound.having
+    if having is None:
+        return None
+    for n in walk(having):
+        t = n.type
+        if not (isinstance(n, _HAVING_NODES)
+                and (t.is_integer or t.is_decimal or t.kind == T.BOOL)):
+            return None
+        if isinstance(n, BAggRef):
+            ex = plan.agg_extract[n.index]
+            if ex.kind not in PLAIN_AGGS or not all(
+                    np.issubdtype(np.dtype(plan.partial_ops[i].dtype),
+                                  np.integer) for i in ex.slots[:2]):
+                return None
+    return hoist_literals(having, len(plan.bound.param_specs))
+
+
+def _fetch_hash_survivors(plan: PhysicalPlan, state, acc, params, having):
+    """The filtered ending of the coordinator's hash scan: HAVING is
+    decided on the table on the chip (kernel slot ``jit_hash_having``)
+    and what can still matter comes home — the blocks that hold a
+    survivor and the entries of the keys ``acc`` holds a part of (their
+    final state is entry + host part: the host decides those).  Returns
+    ``(table, entry_mask, groups)``, the layout ``_finish_hash_agg``
+    merges, with ``groups`` the aggregation's groups before HAVING; or
+    None where those would pass half the table and the whole of it may
+    as well come (a HAVING that keeps most groups, a table so small
+    that most keys spilled)."""
+    import jax
+    import jax.numpy as jnp
+    from citus_tpu.executor.finalize import (
+        raise_sum_overflow, sum_overflow_mask,
+    )
+    from citus_tpu.ops.hash_agg import (
+        FILTER_BLOCK, build_hash_having, hash_take,
+    )
+    from citus_tpu.planner.bound import param_env_names
+
+    # the keys the accumulator holds go up in a power-of-two count and
+    # the survivors' blocks are gathered in one of at least 1/256 of
+    # the table, so few shapes ever compile
+    S = int(state[2].shape[0])
+    n_host = acc.n_groups
+    M = _pow2_at_least(n_host, 1024)
+    least_blocks = max(8, -(-S // FILTER_BLOCK) >> 8)
+    if least_blocks * FILTER_BLOCK + M > S // 2:
+        return None
+    generic, specs, values = having
+    key_dtypes = tuple(a.dtype for a, _ in state[0])
+    names = tuple(param_env_names(list(plan.bound.param_specs) + specs))
+    kernel = get_kernel(
+        plan, "jit_hash_having",
+        lambda: jit_compile(build_hash_having(plan, jnp, generic, names)),
+        extra=(repr(generic), repr(plan.agg_extract)))
+    pcols, pvalids = params
+    hoisted = tuple(np.asarray(v, t.device_dtype)
+                    for (t, _), v in zip(specs, values))
+    with _trace.span("hash_filter") as sp:
+        # the host's keys go up once; their entries come back with the
+        # marks, in ONE device_get
+        host_keys = tuple(
+            (np.concatenate([kv, np.zeros(M - n_host, kv.dtype)]),
+             np.concatenate([kvm, np.zeros(M - n_host, bool)]))
+            for kv, kvm in acc.key_arrays(key_dtypes))
+        keep, home = kernel(
+            state, pcols + hoisted,
+            pvalids + (np.ones((), bool),) * len(hoisted),
+            host_keys, np.int32(n_host))
+        marks, occupied, overflows, host_slots, entries = \
+            jax.device_get(home)
+        found = host_slots < S
+        # the chip's verdicts count for the keys the host holds no part
+        # of: an overflow among those raises as finalize_groups would,
+        # kept or not; the host's keys are checked after their merge
+        of_host_keys = [int((bad & found).sum()) for bad in (
+            sum_overflow_mask(np, ex, entries[1])
+            for ex in plan.agg_extract) if bad is not None]
+        if any(n > m for n, m in zip(overflows, of_host_keys)):
+            raise_sum_overflow()
+        blocks = np.flatnonzero(marks)
+        if sp.recording:
+            sp.set(slots=S, host_keys=n_host, blocks=blocks.size,
+                   host_keys_in_table=int(found.sum()))
+    pl = plan.runtime_cache.setdefault("pipeline", {})
+    pl["hash_occupancy_pct"] = round(100.0 * int(occupied) / S, 1)
+    n_blocks = _pow2_at_least(blocks.size, least_blocks)
+    if n_blocks * FILTER_BLOCK + M > S // 2:
+        return None
+    with _trace.span("fetch"):
+        # a block past the last marked one repeats slot 0, masked; the
+        # table's last block may run past its end: clipped and masked
+        at = (np.concatenate([blocks, np.full(n_blocks - blocks.size,
+                                              marks.size)])[:, None]
+              * FILTER_BLOCK + np.arange(FILTER_BLOCK)).reshape(-1)
+        take = get_kernel(plan, "jit_hash_take",
+                          lambda: jit_compile(hash_take))
+        survivors, kept = jax.device_get(take(
+            (state, keep), np.where(at < S, at, 0).astype(np.int32)))
+    entries = jax.tree_util.tree_map(
+        lambda a, b: np.concatenate([a, b]), entries, survivors)
+    # no entry merges twice: a host key's entry came with the first fetch
+    kept = kept & (at < S) & ~np.isin(at, host_slots[found])
+    pl["hash_having_on_device"] = True
+    return (entries, np.concatenate([found, kept]),
+            int(occupied) + n_host - int(found.sum()))
 
 
 def _run_hash_partial_state(cat: Catalog, plan: PhysicalPlan,
@@ -650,8 +795,10 @@ def _run_hash_partial_state(cat: Catalog, plan: PhysicalPlan,
     penv = _params_env(plan, params)
     table = None
     if settings.executor.task_executor_backend != "cpu":
-        table = _run_hash_device(cat, plan, settings, params, acc, penv,
-                                 push_remote=False)
+        # a worker ships its whole table: HAVING on a worker is sound
+        # only where the group key holds the distribution column
+        table = _fetch_hash_table(plan, _run_hash_device(
+            cat, plan, settings, params, acc, penv, push_remote=False))
     else:
         pcols, pvalids = params
         worker = build_worker_fn(plan, np)
@@ -683,26 +830,33 @@ def _run_hash_partial_state(cat: Catalog, plan: PhysicalPlan,
 
 
 def _finish_hash_agg(cat: Catalog, plan: PhysicalPlan, acc, table,
-                     penv: dict) -> list[tuple]:
+                     penv: dict, entry_mask=None, groups=None) -> list[tuple]:
     """The exact tail of a device hash aggregation, on the caller's
-    thread: the fetched ``table`` (key tables, partials, rows) merges
-    into ``acc``, which holds the spilled rows' groups already; then the
-    accumulator's arrays, HAVING and the rendering of the kept groups."""
+    thread: the fetched ``table`` (key tables, partials, rows; of them
+    the entries ``entry_mask`` marks, all where it is None) merges into
+    ``acc``, which holds the spilled rows' groups already; then the
+    accumulator's arrays, HAVING and the rendering of the kept groups.
+    ``groups`` are the aggregation's groups before HAVING, where the
+    filtered ending left some of them on the chip."""
     from citus_tpu.ops.hash_agg import (
         hash_state_bytes, merge_hash_tables_into,
     )
     fetched = hash_state_bytes(table)
+    entries = int(table[2].shape[0])
     pl = plan.runtime_cache.setdefault("pipeline", {})
     with _trace.span("hash_merge"):
-        merge_hash_tables_into(acc, plan, *table)
+        merge_hash_tables_into(acc, plan, *table, entry_mask=entry_mask)
     with _trace.span("hash_finalize") as sp:
         key_arrays, parts = acc.finalize(
             [k.type for k in plan.bound.group_keys],
             scalar=not plan.bound.group_keys)
-        GLOBAL_COUNTERS.bump("hash_groups_out", acc.n_groups)
+        groups = acc.n_groups if groups is None else groups
+        GLOBAL_COUNTERS.bump("hash_groups_out", groups)
         GLOBAL_COUNTERS.bump("hash_table_bytes_fetched", fetched)
-        pl["hash_groups_out"] = acc.n_groups
+        GLOBAL_COUNTERS.bump("hash_entries_fetched", entries)
+        pl["hash_groups_out"] = groups
         pl["hash_table_bytes_fetched"] = fetched
+        pl["hash_entries_fetched"] = entries
         out = [] if parts is None else finalize_groups(
             plan, cat, key_arrays, parts, params_env=penv)
         if sp.recording:
@@ -729,9 +883,20 @@ def _run_agg_hash_host(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     penv = _params_env(plan, params)
 
     if backend != "cpu" and not _hash_has_exact(plan):
-        table = _run_hash_device(cat, plan, settings, params, acc, penv,
+        state = _run_hash_device(cat, plan, settings, params, acc, penv,
                                  push_remote=True)
-        return _finish_hash_agg(cat, plan, acc, table, penv)
+        # this table is the only one of the query and every part of a
+        # group that is not in it is in ``acc`` already: where the chip
+        # can decide HAVING, only what can still matter comes home
+        having = _device_having(plan)
+        home = having and _fetch_hash_survivors(plan, state, acc, params,
+                                                having)
+        if home:
+            table, entry_mask, groups = home
+            return _finish_hash_agg(cat, plan, acc, table, penv,
+                                    entry_mask, groups)
+        return _finish_hash_agg(cat, plan, acc,
+                                _fetch_hash_table(plan, state), penv)
 
     # exact value-set partials (or the cpu oracle backend) stay host-only
     # and are not elementwise-combinable — remote-only shards pull

@@ -25,6 +25,23 @@ from citus_tpu.planner.bound import (
 from citus_tpu.planner.physical import AggExtract, PhysicalPlan
 
 
+#: aggregates whose value IS a partial state and whose validity is its
+#: count: over integer and decimal states their extraction is the same
+#: array arithmetic on the host and on the chip (``plain_agg``)
+PLAIN_AGGS = ("count", "count_star", "sum", "min", "max")
+
+
+def plain_agg(xp, ex: AggExtract, partials):
+    """(values, valid) of a ``PLAIN_AGGS`` aggregate from its partial
+    states, on ``xp``: a count is always valid, a sum / min / max where
+    its argument was not NULL in some row of the group."""
+    v = xp.asarray(partials[ex.slots[0]])
+    if ex.kind in ("count", "count_star"):
+        v = v.astype(np.int64)
+        return v, xp.ones(v.shape, bool)
+    return v, xp.asarray(partials[ex.slots[1]]) > 0
+
+
 def extract_aggs(plan: PhysicalPlan, partials: tuple,
                  cat: Optional[Catalog] = None) -> list[tuple[np.ndarray, np.ndarray]]:
     """Partial-op arrays -> per-SQL-aggregate (values, valid) arrays."""
@@ -33,18 +50,14 @@ def extract_aggs(plan: PhysicalPlan, partials: tuple,
         if ex.kind == "count_distinct":
             v = np.asarray(partials[ex.slots[0]], dtype=np.int64)
             out.append((v, np.ones(v.shape, bool)))
-        elif ex.kind in ("count", "count_star"):
-            v = np.asarray(partials[ex.slots[0]], dtype=np.int64)
-            out.append((v, np.ones(v.shape, bool)))
-        elif ex.kind == "sum":
-            s = np.asarray(partials[ex.slots[0]])
-            c = np.asarray(partials[ex.slots[1]])
-            _check_sum_overflow(ex, partials, c)
-            out.append((s, c > 0))
+        elif ex.kind in PLAIN_AGGS:
+            if ex.kind == "sum":
+                _check_sum_overflow(ex, partials)
+            out.append(plain_agg(np, ex, partials))
         elif ex.kind == "avg":
             s = np.asarray(partials[ex.slots[0]])
             c = np.asarray(partials[ex.slots[1]])
-            _check_sum_overflow(ex, partials, c)
+            _check_sum_overflow(ex, partials)
             valid = c > 0
             if ex.out_type.is_float:
                 v = np.divide(s, np.where(valid, c, 1))
@@ -53,10 +66,6 @@ def extract_aggs(plan: PhysicalPlan, partials: tuple,
                 # exact decimal average: sum is scaled by arg scale; output
                 # scale is arg scale + 6 -> multiply by 10^6 then divide
                 out.append((_avg_scaled(s, c), valid))
-        elif ex.kind in ("min", "max"):
-            v = np.asarray(partials[ex.slots[0]])
-            c = np.asarray(partials[ex.slots[1]])
-            out.append((v, c > 0))
         else:
             from citus_tpu.planner.aggregates import finalize_kind
             fin = finalize_kind(ex.kind)
@@ -93,15 +102,14 @@ def _avg_scaled(s: np.ndarray, c: np.ndarray) -> np.ndarray:
 _SUM_OVERFLOW_LIMIT = float(1 << 62)
 
 
-def _check_sum_overflow(ex: AggExtract, partials: tuple, counts) -> None:
-    """sum/avg over int64-accumulated numerics carry a float64 shadow
-    sum in slot 2 (planner/physical.py lower_aggregates); reject results
-    whose true sum provably left int64 range rather than returning the
-    silently wrapped value.  The reference's NUMERIC is arbitrary-
-    precision and never overflows — erroring is the honest analog."""
-    if len(ex.slots) < 3:
-        return
-    shadow = np.asarray(partials[ex.slots[2]], np.float64)
+def sum_overflow_mask(xp, ex: AggExtract, partials):
+    """Groups whose exact int64 sum provably left its range, on ``xp``,
+    or None where ``ex`` carries no shadow.  sum/avg over
+    int64-accumulated numerics carry a float64 shadow sum in slot 2
+    (planner/physical.py lower_aggregates)."""
+    if ex.kind not in ("sum", "avg") or len(ex.slots) < 3:
+        return None
+    shadow = xp.asarray(partials[ex.slots[2]]).astype(np.float64)
     # the float cast of a decimal yields the LOGICAL value; the exact
     # accumulator holds integers at the ARGUMENT's scale — compare in
     # that space.  For sum, out scale == arg scale; avg's output gains
@@ -112,12 +120,24 @@ def _check_sum_overflow(ex: AggExtract, partials: tuple, counts) -> None:
     if ex.kind == "avg":
         scale = max(0, scale - 6)
     limit = _SUM_OVERFLOW_LIMIT / (10.0 ** scale)
-    bad = (np.abs(shadow) >= limit) & (np.asarray(counts) > 0)
-    if bad.any():
-        from citus_tpu.errors import ExecutionError
-        raise ExecutionError(
-            "numeric value out of range: sum() exceeds the exact 64-bit "
-            "accumulator (reduce the aggregate's scale or range)")
+    return (xp.abs(shadow) >= limit) & (xp.asarray(partials[ex.slots[1]]) > 0)
+
+
+def raise_sum_overflow():
+    from citus_tpu.errors import ExecutionError
+    raise ExecutionError(
+        "numeric value out of range: sum() exceeds the exact 64-bit "
+        "accumulator (reduce the aggregate's scale or range)")
+
+
+def _check_sum_overflow(ex: AggExtract, partials: tuple) -> None:
+    """Reject results whose true sum provably left int64 range rather
+    than returning the silently wrapped value.  The reference's NUMERIC
+    is arbitrary-precision and never overflows — erroring is the honest
+    analog."""
+    bad = sum_overflow_mask(np, ex, partials)
+    if bad is not None and bad.any():
+        raise_sum_overflow()
 
 
 def decode_qualified(cat: Catalog, expr_type: T.ColumnType,

@@ -300,6 +300,12 @@ class HostGroupAccumulator:
                 else:
                     self._accs[gi][pi] = max(self._accs[gi][pi], val)
 
+    def key_arrays(self, dtypes: list) -> list:
+        """[(values, valid)] of the groups' keys, in group order."""
+        return [(np.array([kvs[ki][0] for kvs in self._key_vals], dtype=dt),
+                 np.array([kvs[ki][1] for kvs in self._key_vals], dtype=bool))
+                for ki, dt in enumerate(dtypes)]
+
     def finalize(self, key_types: list, scalar: bool = False):
         """-> (key_arrays [(values, valid)], partials tuple).  ``scalar``
         forces one group even with zero input rows (global aggregates)."""
@@ -309,12 +315,7 @@ class HostGroupAccumulator:
                 return [], None
             self._new_group([])
             G = 1
-        key_arrays = []
-        for ki, kt in enumerate(key_types):
-            dt = kt.device_dtype
-            vals = np.array([kvs[ki][0] for kvs in self._key_vals], dtype=dt)
-            valid = np.array([kvs[ki][1] for kvs in self._key_vals], dtype=bool)
-            key_arrays.append((vals, valid))
+        key_arrays = self.key_arrays([kt.device_dtype for kt in key_types])
         partials = []
         for pi, op in enumerate(self.partial_ops):
             if op.kind in ("collect", "collect_set"):

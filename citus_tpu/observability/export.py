@@ -99,9 +99,10 @@ METRIC_HELP = {
     "batch_rows_padded": "rows of those batches' power-of-two buckets",
     "direct_groups": "slots of the group domains of direct-group-id aggregations",
     "direct_groups_out": "groups those aggregations returned",
-    "hash_groups_out": "groups of hash aggregations after the host merge",
+    "hash_groups_out": "groups of hash aggregations, before HAVING",
     "hash_table_updates": "entries (a batch's distinct keys) offered to device hash tables",
     "hash_table_bytes_fetched": "bytes of device hash tables fetched",
+    "hash_entries_fetched": "entries (slots) of device hash tables fetched",
     "wait_remote_rpc_ms": "ms blocked on remote RPC round trips",
     "wait_lock_ms": "ms blocked acquiring advisory locks",
     "wait_prefetch_stall_ms": "ms the device starved for host decode",

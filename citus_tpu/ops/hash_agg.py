@@ -162,6 +162,15 @@ def _set_distinct(xp, table, at, values):
     return (hi.astype(np.int64) << 32) | lo.astype(np.int64)
 
 
+def _stores(xp, keys, key_tables, slot, shape):
+    """Slot ``slot`` stores exactly each entry's keys (values and
+    validity): the test a match-or-claim and a read-only probe share."""
+    eq = xp.ones(shape, bool)
+    for (kv, kvm), (kvt, kft) in zip(keys, key_tables):
+        eq = eq & _stored_eq(xp, kvt, kft, slot, kv, kvm)
+    return eq
+
+
 def _insert_keys(xp, keys, mask, h, key_tables, occ):
     """Two-probe match-or-claim of entries into a RUNNING table, each
     placed entry in a slot of its own.
@@ -183,10 +192,7 @@ def _insert_keys(xp, keys, mask, h, key_tables, occ):
     ids = xp.arange(mask.shape[0], dtype=np.int32)
 
     def stores(tables, slot):
-        eq = xp.ones(mask.shape, bool)
-        for (kv, kvm), (kvt, kft) in zip(keys, tables):
-            eq = eq & _stored_eq(xp, kvt, kft, slot, kv, kvm)
-        return eq
+        return _stores(xp, keys, tables, slot, mask.shape)
 
     fslot = xp.zeros(mask.shape, np.int32)
     placed = repeated = xp.zeros(mask.shape, bool)
@@ -450,6 +456,94 @@ def build_fused_entry_merge(plan: PhysicalPlan, xp,
             [xp.asarray(p) for p in partial_entries], row_entries)
         return state, mask & ~placed
     return merge
+
+
+def _probe_slots(xp, keys, mask, key_tables, occ):
+    """Read-only side of ``_insert_keys``: the slot that stores each
+    key (canonical), looked for in its two candidate slots, or the slot
+    count where neither does (or ``mask`` is off).  Nothing is claimed."""
+    S = occ.shape[0]
+    h = _fingerprint(xp, keys, mask.shape)
+    slot = xp.full(mask.shape, S, np.int32)
+    for hp in (h, _mix(xp, h, _GOLD)):
+        cand = (hp % np.uint64(S)).astype(np.int32)
+        hit = mask & occ[cand] & _stores(xp, keys, key_tables, cand,
+                                         mask.shape)
+        slot = xp.where(hit & (slot == S), cand, slot)
+    return slot
+
+
+#: slots per block of the filtered ending: the host reads one bit a
+#: block and gathers the blocks that hold a survivor
+FILTER_BLOCK = 512
+
+
+def build_hash_having(plan: PhysicalPlan, xp, having,
+                      param_names: tuple) -> Callable:
+    """HAVING decided on the table: (table_state, pcols, pvalids,
+    host_keys, n_host_keys) -> (keep[S], (block_marks, occupied,
+    overflows, host_slots, host_entries)).
+
+    ``having`` is the plan's HAVING with its literals hoisted
+    (``param_names`` names the plan's parameters and then the hoisted
+    ones, the layout of ``pcols`` / ``pvalids``), so one program serves
+    the statement's family.  The aggregates of every occupied entry are
+    extracted as ``finalize.extract_aggs`` does and ``keep`` = HAVING
+    passes; ``block_marks`` is ``keep`` reduced to one bit per
+    ``FILTER_BLOCK`` slots (no scan over the slots: XLA for TPU compiles
+    a ``cumsum`` over a million rows in a minute), ``occupied`` counts
+    the occupied slots and ``overflows`` the entries that fail
+    ``finalize.sum_overflow_mask``, per aggregate that carries a shadow.
+
+    That verdict is final only for a key the host holds no part of.
+    ``host_keys`` ([(values[M], valid[M])], the first ``n_host_keys``
+    real, in the key tables' dtypes) are the keys the host accumulator
+    holds: the read-only probe
+    finds their slots (``host_slots``, the slot count = not in the
+    table) and their entries come back whatever HAVING says of them
+    (``host_entries``, a table state of M entries), so the host can set
+    the chip's verdict on them aside — by slot, not by a scatter over
+    the table, which XLA for TPU takes 7 s to compile.  The table state
+    is read, not donated."""
+    from citus_tpu.executor.finalize import plain_agg, sum_overflow_mask
+    from citus_tpu.planner.bound import BAggRef, walk
+
+    having_fn = compile_expr(having, xp)
+    read = {n.index for n in walk(having) if isinstance(n, BAggRef)}
+    extracts = plan.agg_extract
+
+    # named for its kernel slot, like hash_fused: the XLA module in a
+    # device trace is jit_hash_having
+    def hash_having(table_state, pcols, pvalids, host_keys, n_host_keys):
+        key_tables, partials, rows = table_state
+        S = rows.shape[0]
+        occ = rows > 0
+        env = {n: (c, v) for n, c, v in zip(param_names, pcols, pvalids)}
+        env["__keys__"] = [(kvt, kft == 2) for kvt, kft in key_tables]
+        env["__aggs__"] = [plain_agg(xp, ex, partials) if i in read else None
+                           for i, ex in enumerate(extracts)]
+        keep = occ & predicate_mask(xp, having_fn, env, rows)
+        B = FILTER_BLOCK
+        marks = xp.concatenate(
+            [keep, xp.zeros((-S % B,), bool)]).reshape(-1, B).any(axis=1)
+        bad = [sum_overflow_mask(xp, ex, partials) for ex in extracts]
+        overflows = [(b & occ).sum(dtype=np.int32)
+                     for b in bad if b is not None]
+        live = xp.arange(host_keys[0][0].shape[0],
+                         dtype=np.int32) < n_host_keys
+        slot = _probe_slots(xp, _canon_keys(xp, host_keys), live,
+                            key_tables, occ)
+        return keep, (marks, occ.sum(dtype=np.int32), overflows, slot,
+                      hash_take(table_state, xp.minimum(slot, S - 1)))
+    return hash_having
+
+
+def hash_take(tree, at):
+    """The entries ``at`` of every array of ``tree`` (a table state, a
+    mask over its slots): the gather of the filtered ending, one
+    dispatch (kernel slot ``jit_hash_take``) for the whole tree."""
+    import jax
+    return jax.tree_util.tree_map(lambda a: a[at], tree)
 
 
 def empty_hash_state(plan: PhysicalPlan, slots: int, key_dtypes: tuple):

@@ -47,17 +47,13 @@ _LOGIC_OPS = ("and", "or")
 _HOIST_OPS = ("=", "<>", "<", "<=", ">", ">=", "+", "-", "*", "/", "%")
 
 
-def auto_parameterize(bound) -> Optional[tuple]:
-    """Hoist filter literals into synthetic trailing params.
-
-    Returns ``(generic_bound, values)`` where ``values`` are the
-    bound-level physical literal values (positionally matching the new
-    specs), or ``None`` when the filter holds nothing hoistable — the
-    custom plan is already as generic as it gets.
-    """
-    if bound.filter is None:
-        return None
-    start = len(bound.param_specs)
+def hoist_literals(expr: BExpr, start: int) -> tuple:
+    """``expr`` with the literal operands of its comparisons and
+    arithmetic replaced by ``BParam``s numbered from ``start``:
+    ``(generic_expr, specs, values)``, the specs ``(type,
+    PHYSICAL_SRC)`` and the values bound-level physical, positionally
+    matching.  Two expressions that differ in those literals alone give
+    one ``generic_expr``."""
     specs: list = []
     values: list = []
 
@@ -93,7 +89,21 @@ def auto_parameterize(bound) -> Optional[tuple]:
             return e if op is e.operand else dataclasses.replace(e, operand=op)
         return e
 
-    new_filter = rewrite(bound.filter, False)
+    return rewrite(expr, False), specs, values
+
+
+def auto_parameterize(bound) -> Optional[tuple]:
+    """Hoist filter literals into synthetic trailing params.
+
+    Returns ``(generic_bound, values)`` where ``values`` are the
+    bound-level physical literal values (positionally matching the new
+    specs), or ``None`` when the filter holds nothing hoistable — the
+    custom plan is already as generic as it gets.
+    """
+    if bound.filter is None:
+        return None
+    new_filter, specs, values = hoist_literals(
+        bound.filter, len(bound.param_specs))
     if not values:
         return None
     generic = dataclasses.replace(

@@ -177,14 +177,17 @@ class StatCounters:
         # keys, after sort and segment reduce: what the kernel's
         # scatters cost follows), remote hash-table partials merged back through
         # the device merge door (executor/pipeline.py push path), groups
-        # after the host merge of table and spills (before HAVING), and
-        # bytes of device hash table fetched to the host
+        # of table and spills together (before HAVING), and the bytes
+        # and entries (slots) of device hash table fetched to the host
+        # at the end of a scan: the whole table, or what a HAVING
+        # decided on the chip leaves
         "hash_fused_dispatches",
         "hash_spill_rows",
         "hash_table_updates",
         "hash_partials_pushed",
         "hash_groups_out",
         "hash_table_bytes_fetched",
+        "hash_entries_fetched",
         # direct-group-id aggregation (executor.py _run_agg): slots of
         # the plan's group domain per query (what ops/scan_agg.py sizes
         # and chooses its reduction by) and groups returned from them

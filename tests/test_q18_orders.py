@@ -77,12 +77,19 @@ def cluster(tmp_path, table, limit_devices, request):
 
 
 def _hash_line(cl, sql):
+    """EXPLAIN ANALYZE's ``Hash:`` line -> [slots, occupancy, spilled
+    rows, groups, bytes fetched, entries fetched with HAVING decided on
+    the device (None where the whole table came home)]."""
     text = "\n".join(l for (l,) in cl.execute(f"EXPLAIN ANALYZE {sql}").rows)
     m = re.search(r"Hash: hash slots (\d+), occupancy ([\d.]+)%, spilled "
                   r"(\d+) rows, groups (\d+), fetched (\d+) bytes", text)
     assert m, text
-    return [int(m.group(1)), float(m.group(2))] + [int(g) for g in
-                                                   m.group(3, 4, 5)]
+    on_device = re.search(r"having on device: (\d+) of (\d+) entries "
+                          r"fetched", text)
+    assert on_device is None or on_device.group(2) == m.group(1)
+    return [int(m.group(1)), float(m.group(2))] \
+        + [int(g) for g in m.group(3, 4, 5)] \
+        + [int(on_device.group(1)) if on_device else None]
 
 
 @pytest.mark.parametrize("quantity", QUANTITIES)
@@ -97,7 +104,7 @@ def test_engine_equals_the_plain_reference(cluster, table, quantity):
     if quantity == 250:
         assert len(want) >= 5   # the comparison is not of two empty lists
     rows, orders = int(stats["rows"]), int(stats["orders"])
-    slots, _occupancy, spilled, groups, fetched = _hash_line(cl, sql)
+    slots, _occupancy, spilled, groups, fetched, entries = _hash_line(cl, sql)
     assert groups == orders == 4_000
     if mode == "forced_spill":
         assert slots == 1024 and spilled > rows // 2
@@ -106,7 +113,56 @@ def test_engine_equals_the_plain_reference(cluster, table, quantity):
         assert slots == 1 << (rows - 1).bit_length() == 16_384
         assert spilled < 0.05 * rows
     # one int64 key + its int8 flag, sum / count / float64 shadow, rows
-    assert fetched == slots * 41
+    if mode != "forced_spill" and quantity >= 312:
+        # the cell's four QUANTITY values: HAVING is decided on the
+        # table on the chip; the spilled keys' entries (1,024, their
+        # power of two) and the survivors' blocks (8 of 512 slots, the
+        # least) come home, not the 16,384 slots
+        assert entries == 1024 + 8 * 512
+        assert fetched == entries * 41
+    elif entries is None:
+        assert fetched == slots * 41
+    else:
+        assert fetched == entries * 41 <= slots * 41 // 2
+
+
+# what jit_hash_fused lowers to at PR 29 (780903a), for the statement's
+# plan on a 1,024-slot state and a 2,048-row batch: the kernel's time
+# and the bytes hash_kernel_hbm_roofline reckons are this module's
+PARENT_HASH_FUSED_SHA1 = "27c07ec0b1b2462547fa7ffafb6fdfe98d9af9ac"
+
+
+@pytest.mark.parametrize("cluster", ["defaults"], indirect=True)
+@pytest.mark.parametrize("quantity", (312, 313, 314, 315))
+def test_hash_kernel_is_the_parents_module(cluster, quantity):
+    """The filtered ending is its own kernel: ``jit_hash_fused`` lowers
+    to the text it had before, byte for byte, whatever QUANTITY."""
+    import hashlib
+
+    import jax.numpy as jnp
+    from citus_tpu.executor.executor import _hash_key_dtypes
+    from citus_tpu.executor.kernel_cache import jit_compile
+    from citus_tpu.ops.hash_agg import (
+        build_fused_hash_worker, empty_hash_state,
+    )
+    from citus_tpu.planner import parse_sql
+    from citus_tpu.planner.bind import bind_select
+    from citus_tpu.planner.physical import plan_select
+    cl, _ = cluster
+    sql = QUERY["sql"].format(QUANTITY=quantity)
+    plan = plan_select(cl.catalog, bind_select(cl.catalog, parse_sql(sql)[0]))
+    assert plan.group_mode.kind == "hash_host"
+    key_dtypes = _hash_key_dtypes(plan, {})
+    kernel = jit_compile(build_fused_hash_worker(plan, jnp, key_dtypes),
+                         donate_argnums=0)
+    schema = plan.bound.table.schema
+    n = 2048
+    cols = tuple(np.zeros(n, schema.scan_dtype(c, device=True))
+                 for c in plan.scan_columns)
+    valids = tuple(np.ones(n, bool) for _ in plan.scan_columns)
+    text = kernel.lower(empty_hash_state(plan, 1024, key_dtypes), cols,
+                        valids, np.ones(n, bool)).as_text()
+    assert hashlib.sha1(text.encode()).hexdigest() == PARENT_HASH_FUSED_SHA1
 
 
 @pytest.mark.parametrize("cluster", MODES, indirect=True)
