@@ -98,9 +98,19 @@ Q_DIRECT = """SELECT l_shipdate, count(*) AS n, sum(l_quantity) AS sum_qty,
   max(l_extendedprice) AS max_price
 FROM lineitem GROUP BY l_shipdate"""
 
+# 2,527 x 12 x 10 = 303,240 slots of the keys' provable domain.  With a
+# min among the partials the plan cannot ride the group product: the
+# device hash table, its slots from the key domain
 Q_HASH = """SELECT l_shipdate, l_discount, l_tax, count(*) AS n,
+  sum(l_quantity) AS sum_qty, min(l_quantity) AS min_qty
+FROM lineitem GROUP BY l_shipdate, l_discount, l_tax"""
+# ... and without it, 10 planes: the direct table past 65,536 slots
+# where the rows outnumber the slots twice (planner/physical.py
+# _product_reaches), the hash table under that
+Q_PRODUCT = """SELECT l_shipdate, l_discount, l_tax, count(*) AS n,
   sum(l_quantity) AS sum_qty
 FROM lineitem GROUP BY l_shipdate, l_discount, l_tax"""
+HASH_DOMAIN_SLOTS = (SHIP_DAYS + 1) * 12 * 10
 
 Q_ROUTER = """SELECT l_orderkey, l_quantity, l_extendedprice, l_shipdate,
   l_returnflag
@@ -182,6 +192,7 @@ class Reference:
         self.d_max = np.zeros(SHIP_DAYS, np.int64)
         self.h_n = np.zeros(SHIP_DAYS * 99, np.int64)
         self.h_qty = np.zeros(SHIP_DAYS * 99, np.int64)
+        self.h_min = np.full(SHIP_DAYS * 99, 1 << 62, np.int64)
         self.router_rows = []
         self.join = [0, 0, 0]             # count, sum qty, sum totalprice
         self.sd = [[0, 0, 0] for _ in range(3)]  # per flag: n, sum, sumsq
@@ -226,6 +237,7 @@ class Reference:
         self.h_n += np.bincount(g, minlength=SHIP_DAYS * 99)
         self.h_qty += np.bincount(g, weights=qty, minlength=SHIP_DAYS * 99
                                   ).astype(np.int64)
+        np.minimum.at(self.h_min, g, qty)
         # router key
         for i in np.nonzero(okey == self.router_key)[0]:
             self.router_rows.append((
@@ -261,14 +273,16 @@ class Reference:
                  dec(self.d_max[i], 2))
                 for i in np.nonzero(self.d_n)[0]}
 
-    def hash_rows(self):
+    def hash_rows(self, with_min):
         out = {}
         for g in np.nonzero(self.h_n)[0]:
             d, rest = divmod(int(g), 99)
             disc, tax = divmod(rest, 9)
+            row = (int(self.h_n[g]), dec(self.h_qty[g], 2))
+            if with_min:
+                row += (dec(self.h_min[g], 2),)
             out[(EPOCH + datetime.timedelta(days=SHIP_LO + d),
-                 dec(disc, 2), dec(tax, 2))] = (int(self.h_n[g]),
-                                                dec(self.h_qty[g], 2))
+                 dec(disc, 2), dec(tax, 2))] = row
         return out
 
     def stddev_rows(self):
@@ -513,7 +527,7 @@ def memory_growth(devices):
     out["per_device"] = [p - b for p, b in zip(peak, base)]
 
 
-def leg_hash(run, ref, devices):
+def leg_hash(run, ref, devices, rows):
     cl = run.cl
     cl.execute("SET citus.hash_agg_slots = auto")
     with memory_growth(devices) as grew:
@@ -522,16 +536,65 @@ def leg_hash(run, ref, devices):
     check("jit_hash_fused" in kernel_slots(ev),
           f"hash leg: slots {kernel_slots(ev)}")
     check(d.get("hash_fused_dispatches", 0) > 0, f"hash leg: no dispatch {d}")
-    want = ref.hash_rows()
+    want = ref.hash_rows(with_min=True)
     check_groups("hash leg", {tuple(row[:3]): tuple(row[3:]) for row in r.rows},
                  want, len(r.rows))
     pl = r.explain["pipeline"]
-    run.record("5 GROUP BY l_shipdate, l_discount, l_tax (hash)",
+    # the groups cannot outnumber the rows nor the keys' domain: the
+    # table takes the smaller bound (executor.py _hash_slots)
+    by_rows = max(1024, 1 << (rows - 1).bit_length())
+    by_domain = 1 << (2 * HASH_DOMAIN_SLOTS - 1).bit_length()
+    bound = ((by_domain, "key domain") if by_domain < by_rows
+             else (by_rows, "row count"))
+    check((pl.get("hash_slots"), pl.get("hash_slots_from")) == bound,
+          f"hash leg: slots {pl.get('hash_slots')} from "
+          f"{pl.get('hash_slots_from')}, want {bound}")
+    run.record("5 GROUP BY l_shipdate, l_discount, l_tax with a min (hash)",
                "jit_hash_fused", el, d, groups=len(want),
                hash_slots=pl.get("hash_slots"),
+               hash_slots_from=pl.get("hash_slots_from"),
                hash_occupancy_pct=pl.get("hash_occupancy_pct"),
                hash_spill_rows=d.get("hash_spill_rows", 0),
                bytes_in_use_growth_per_device=grew["per_device"])
+
+
+def leg_product(run, ref, n_dev, rows):
+    """The same keys over a count and an int64 sum: the route follows
+    from the row count, and ``citus.direct_gid_limit`` bounds it.  Both
+    routes answer the statement, each a second time on warm kernels, so
+    the report holds the product beside the hash kernel at these slots."""
+    from citus_tpu.planner.physical import DENSE_ROWS_PER_SLOT
+    cl = run.cl
+    want = ref.hash_rows(with_min=False)
+    for leg, limit, name in (("5d", "auto", "the plan's route"),
+                             ("5e", "65536", "direct_gid_limit 65536")):
+        cl.execute(f"SET citus.direct_gid_limit = {limit}")
+        try:
+            _, cold, _, ev = run.run(Q_PRODUCT)     # builds the kernel
+            r, d, el, _ = run.run(Q_PRODUCT)
+        finally:
+            cl.execute("SET citus.direct_gid_limit = auto")
+        pl = r.explain["pipeline"]
+        direct = (limit == "auto"
+                  and rows >= DENSE_ROWS_PER_SLOT * HASH_DOMAIN_SLOTS)
+        slot = scan_slot(n_dev) if direct else "jit_hash_fused"
+        check(r.explain["strategy"] == ("direct" if direct else "hash_host"),
+              f"product leg, {name}: {r.explain}")
+        check(slot in kernel_slots(ev),
+              f"product leg, {name}: slots {kernel_slots(ev)}")
+        if direct:
+            check(pl.get("direct_groups") == HASH_DOMAIN_SLOTS,
+                  f"product leg: {pl}")
+        check_groups(f"product leg, {name}",
+                     {tuple(row[:3]): tuple(row[3:]) for row in r.rows},
+                     want, len(r.rows))
+        run.record(f"{leg} the same keys, count and sum, warm "
+                   f"({'direct, product' if direct else 'hash'}; {name})",
+                   slot, el, dict(d, kernel_compile_ms=cold.get(
+                       "kernel_compile_ms", 0)), groups=len(want),
+                   direct_groups=pl.get("direct_groups") if direct else None,
+                   hash_slots=None if direct else pl.get("hash_slots"),
+                   group_rows_in=pl.get("group_rows_in"))
 
 
 def leg_float_lanes(run, rng, shards, n_dev, rows):
@@ -759,7 +822,8 @@ def main() -> int:
         leg_q6(run, ref, n_dev)
         leg_q6_coalesced(run, ref)
         leg_direct(run, ref, n_dev)
-        leg_hash(run, ref, devices)
+        leg_hash(run, ref, devices, args.rows)
+        leg_product(run, ref, n_dev, args.rows)
         leg_float_lanes(run, rng, shards, n_dev,
                         min(MEASURES_ROWS, args.rows))
         leg_router(run, ref)

@@ -60,10 +60,12 @@ def _window_ms(v) -> float:
     return float(v)
 
 
-def _hash_slots(v) -> int:
-    """citus.hash_agg_slots = <slots> | auto (the default, stored as 0:
-    sized at execution from catalog row-count stats and the device's
-    free memory)."""
+def _slots_or_auto(v) -> int:
+    """citus.hash_agg_slots / citus.direct_gid_limit = <slots> | auto
+    (the default, stored as 0: the hash table is sized at execution from
+    the plan's key domain, catalog row-count stats and the device's free
+    memory; the direct table's bound is left to the plan,
+    planner/physical.py choose_group_mode)."""
     if str(v).lower() == "auto":
         return 0
     n = int(v)
@@ -204,8 +206,8 @@ _GUCS = {
     # budget, repartition-join fanout, and the maintenance/authority
     # daemon knobs
     "citus.executor_min_batch_rows": ("executor", "min_batch_rows", int),
-    "citus.direct_gid_limit": ("planner", "direct_gid_limit", int),
-    "citus.hash_agg_slots": ("planner", "hash_agg_slots", _hash_slots),
+    "citus.direct_gid_limit": ("planner", "direct_gid_limit", _slots_or_auto),
+    "citus.hash_agg_slots": ("planner", "hash_agg_slots", _slots_or_auto),
     "citus.repartition_bucket_count_per_device": ("planner", "repartition_bucket_count_per_device", int),
     "citus.start_maintenance_daemon": (None, "start_maintenance_daemon", "bool"),
     "citus.authority_watch_interval": (None, "authority_watch_interval_s", float),

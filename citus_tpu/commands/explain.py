@@ -283,7 +283,10 @@ def _run_analyze(cl, stmt: A.Explain) -> list[str]:
         lines.append(line)
         if "direct_groups" in pl:
             lines.append(f"    Direct: group slots {pl['direct_groups']}, "
-                         f"groups {pl['direct_groups_out']}")
+                         f"groups {pl['direct_groups_out']}, "
+                         f"rows in {pl.get('group_rows_in', 0)} "
+                         f"(kept {pl.get('group_rows_kept', 0)}), "
+                         f"fetched {pl.get('direct_bytes_fetched', 0)} bytes")
         if "hash_slots" in pl:
             # each batch is sorted by key and segment-reduced on the
             # device, then offered to the table in chunks: U is the sum
@@ -295,7 +298,13 @@ def _run_analyze(cl, stmt: A.Explain) -> list[str]:
                 f"groups {pl.get('hash_groups_out', 0)}, "
                 f"fetched {pl.get('hash_table_bytes_fetched', 0)} bytes, "
                 f"table updates {pl.get('hash_table_updates', 0)} "
-                f"({pl.get('hash_rows_in', 0)} rows)")
+                f"({pl.get('hash_rows_in', 0)} rows), "
+                f"rows in {pl.get('group_rows_in', 0)}")
+            if "group_rows_kept" in pl:
+                line += f" (kept {pl['group_rows_kept']})"
+            if "hash_slots_from" in pl:
+                # what bounded the derived table (executor.py _hash_slots)
+                line += f", slots from {pl['hash_slots_from']}"
             if pl.get("hash_having_on_device"):
                 # the chip decided HAVING on the table: the survivors'
                 # blocks and the spilled keys' entries came home

@@ -41,13 +41,18 @@ class ColumnarSettings:
 @dataclass
 class PlannerSettings:
     # GROUP BY strategy thresholds.
-    # Direct-gid when the composite key domain is provably <= this bound
-    # (exact, collision-free scatter-add).
-    direct_gid_limit: int = 65536
+    # Bound on the slots of the direct (collision-free) group table, over
+    # a provably bounded composite key domain; 0 = auto, the default
+    # (SET citus.direct_gid_limit = auto): 65,536 slots, and past them
+    # where every partial rides the MXU product and the rows outnumber
+    # the slots (planner/physical.py choose_group_mode).  A positive
+    # value fixes the bound: a wider domain takes the hash table.
+    direct_gid_limit: int = 0
     # Slot count of the device hash-aggregate table; 0 = auto, the
     # default (SET citus.hash_agg_slots = auto): the next power of two at
-    # or above the catalog's row count, at least 1024, capped by what a
-    # stated share of the device's free memory holds
+    # or above the catalog's row count or, where less, twice the group
+    # keys' provable domain; at least 1024, capped by what a stated
+    # share of the device's free memory holds
     # (executor/executor.py _hash_slots).  A positive value fixes it.
     hash_agg_slots: int = 0
     # Enable repartition (all_to_all) joins; reference GUC

@@ -308,6 +308,13 @@ def _run_partials_jax(cat: Catalog, plan: PhysicalPlan, settings: Settings,
                     cached=cached, stream=stream, cache_key=key,
                     cache_tenant=tenant_key(plan.router_key))
     placement.publish(plan)
+    if plan.group_mode.kind == "direct":
+        # padded rows the group reduction ran over (counted from this
+        # scan's own placement: the plan's pipeline dict is shared by
+        # every caller of a cached plan)
+        plan.runtime_cache.setdefault("pipeline", {})["group_rows_in"] = \
+            placement.rows_padded
+        GLOBAL_COUNTERS.bump("group_rows_in", placement.rows_padded)
     t_dev = clock()
     partials = _fetch_acc(acc_dev)
     if cached is None:
@@ -392,11 +399,17 @@ def _run_agg(cat: Catalog, plan: PhysicalPlan, settings: Settings,
                                           params_env=penv)
                 # the slots the group reduction was sized and chosen by,
                 # and the groups that came out of them (after HAVING)
+                # ... the padded rows the reduction ran over beside the
+                # rows the WHERE kept, and the bytes of state fetched
                 pl = plan.runtime_cache.setdefault("pipeline", {})
                 pl["direct_groups"] = plan.group_mode.n_groups
                 pl["direct_groups_out"] = len(out)
+                kept = pl["group_rows_kept"] = int(np.asarray(rows).sum())
+                fetched = pl["direct_bytes_fetched"] = _nbytes(partials)
                 GLOBAL_COUNTERS.bump("direct_groups", plan.group_mode.n_groups)
                 GLOBAL_COUNTERS.bump("direct_groups_out", len(out))
+                GLOBAL_COUNTERS.bump("direct_bytes_fetched", fetched)
+                GLOBAL_COUNTERS.bump("group_rows_kept", kept)
             if sp.recording:
                 sp.set(groups=len(out))
             return out
@@ -438,17 +451,23 @@ def _pow2_at_least(n: int, floor: int) -> int:
 
 
 def _hash_slots(cat: Catalog, plan: PhysicalPlan, settings: Settings,
-                key_dtypes: tuple, tables: int = 1) -> int:
-    """Slots of a query's device hash table.  ``citus.hash_agg_slots =
-    n`` fixes them; ``auto`` (0, the default) derives them: the next
-    power of two at or above the catalog's row count (every row may be
-    a group), at least 1024, and at most what ``HASH_STATE_MEMORY_SHARE``
-    of the device's free memory holds of ``tables`` such tables at this
-    plan's bytes per slot.  Groups beyond the table spill to the host
+                key_dtypes: tuple, tables: int = 1) -> tuple[int, str]:
+    """Slots of a query's device hash table, and where the number came
+    from (EXPLAIN ANALYZE's ``Hash:`` line says it).  ``citus.hash_agg_slots
+    = n`` fixes them (``"setting"``); ``auto`` (0, the default) derives
+    them from what bounds the groups: the catalog's row count (every row
+    may be a group: the next power of two at or above it, ``"row
+    count"``) or, where the plan proves a domain for every group key and
+    that is the smaller, the power of two at or above TWICE the domains'
+    product (``"key domain"``: such a domain is often full, and a
+    two-probe table at half load spills little) -- at least 1024, and at
+    most what ``HASH_STATE_MEMORY_SHARE`` of the device's free memory
+    holds of ``tables`` such tables at this plan's bytes per slot
+    (``"free memory"``).  Groups beyond the table spill to the host
     accumulator, exactly."""
     S = settings.planner.hash_agg_slots
     if S > 0:
-        return S
+        return S, "setting"
     from citus_tpu.catalog.stats import table_row_count
     from citus_tpu.ops.hash_agg import empty_hash_state, hash_state_bytes
     from citus_tpu.parallel.mesh import executor_devices
@@ -456,14 +475,19 @@ def _hash_slots(cat: Catalog, plan: PhysicalPlan, settings: Settings,
         n = table_row_count(cat, cat.table(plan.bound.table.name))
     except Exception:
         n = 0
-    want = _pow2_at_least(int(n), 1024)
+    want, origin = _pow2_at_least(int(n), 1024), "row count"
+    domain = plan.group_mode.domain_slots
+    if domain is not None:
+        by_domain = _pow2_at_least(2 * domain, 1024)
+        if by_domain < want:
+            want, origin = by_domain, "key domain"
     stats = executor_devices()[0].memory_stats()
     free = (stats["bytes_limit"] - stats["bytes_in_use"] if stats
             else _UNREPORTED_FREE_BYTES)
     slot_bytes = hash_state_bytes(empty_hash_state(plan, 1, key_dtypes))
     fit = int(free * HASH_STATE_MEMORY_SHARE) // (slot_bytes * tables)
     cap = 1 << max(10, fit.bit_length() - 1)    # power of two at or under
-    return min(want, cap)
+    return (want, origin) if want <= cap else (cap, "free memory")
 
 
 def _hash_key_dtypes(plan: PhysicalPlan, penv: dict) -> tuple:
@@ -572,10 +596,10 @@ def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     placement.bind(params)
     drain = _SpillDrain(plan, [acc])
     with _trace.span("hash_init") as sp:
-        S = _hash_slots(cat, plan, settings, key_dtypes)
+        S, slots_from = _hash_slots(cat, plan, settings, key_dtypes)
         state = jax.device_put(empty_hash_state(plan, S, key_dtypes))
         if sp.recording:
-            sp.set(slots=S)
+            sp.set(slots=S, slots_from=slots_from)
 
     def scan(shard_plan, state):
         # never cached (no key): the window bounds the un-synced H2D
@@ -637,9 +661,13 @@ def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     placement.publish(plan)
     pl = plan.runtime_cache.setdefault("pipeline", {})
     pl["hash_slots"] = S
+    pl["hash_slots_from"] = slots_from
+    GLOBAL_COUNTERS.bump("hash_slots", S)
     pl["hash_spilled_rows"] = drain.rows
     pl["hash_table_updates"] = drain.updates
     pl["hash_rows_in"] = sum(n for _, n, _ in placement.task_times)
+    pl["group_rows_in"] = placement.rows_padded
+    GLOBAL_COUNTERS.bump("group_rows_in", placement.rows_padded)
     return state
 
 
@@ -655,6 +683,10 @@ def _fetch_hash_table(plan: PhysicalPlan, state):
     pl = plan.runtime_cache.setdefault("pipeline", {})
     pl["hash_occupancy_pct"] = round(
         100.0 * int((h_rows > 0).sum()) / h_rows.shape[0], 1)
+    # every kept row is in an entry's count or among the spilled
+    kept = int(h_rows.sum()) + pl.get("hash_spilled_rows", 0)
+    pl["group_rows_kept"] = kept
+    GLOBAL_COUNTERS.bump("group_rows_kept", kept)
     return h_keys, h_partials, h_rows
 
 

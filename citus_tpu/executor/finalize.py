@@ -290,14 +290,26 @@ def _decode_column(cat: Catalog, expr_type: T.ColumnType, source, v, valid
                    ) -> list:
     """One output column of the groups, physical -> Python values.  A
     plain numeric / temporal column converts in bulk (one ``tolist`` and
-    one ``from_physical`` a value): at thousands of groups the per-cell
-    route through ``decode_qualified`` was most of ``finalize_groups``."""
+    one conversion a value): at thousands of groups the per-cell route
+    through ``decode_qualified`` was most of ``finalize_groups``; at a
+    hundred thousand, ``from_physical``'s own dispatch is, so integers
+    (``tolist`` made them) and decimals convert in place."""
     if expr_type.is_text or v.dtype == object or v.ndim != 1:
         return [decode_qualified(cat, expr_type, source, x, bool(ok))
                 for x, ok in zip(v, valid)]
-    render = expr_type.from_physical
-    return [render(x) if ok else None
-            for x, ok in zip(v.tolist(), valid.tolist())]
+    values = v.tolist()
+    if expr_type.is_integer and v.dtype.kind == "i":
+        out = values
+    elif expr_type.is_decimal and v.dtype.kind == "i":
+        make, exponent = decimal.Decimal, -expr_type.scale
+        out = [make(x).scaleb(exponent) for x in values]
+    else:
+        render = expr_type.from_physical
+        return [render(x) if ok else None
+                for x, ok in zip(values, valid.tolist())]
+    if valid.all():
+        return out
+    return [x if ok else None for x, ok in zip(out, valid.tolist())]
 
 
 def project_rows(plan: PhysicalPlan, cat: Catalog, env_batches: list[dict],

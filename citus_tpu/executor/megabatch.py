@@ -402,7 +402,7 @@ def _batched_hash_agg(cat, plan, settings, group: list[_Waiter]) -> list:
     n_params = len(param_env_names(plan.bound.param_specs))
     axes = (None,) * n_cols + (0,) * n_params
     key_dtypes = _hash_key_dtypes(plan, penvs[0])
-    S = _hash_slots(cat, plan, settings, key_dtypes, tables=qp)
+    S, _ = _hash_slots(cat, plan, settings, key_dtypes, tables=qp)
 
     def _build():
         # table state maps over the query axis (donated, stays

@@ -83,6 +83,7 @@ class OneDevice:
     def __init__(self) -> None:
         self.task_times: list = []
         self.task_bytes: list = []
+        self.rows_padded = 0    # rows of every round, padding included
 
     def bind(self, params) -> None:
         self.pcols, self.pvalids = params
@@ -110,6 +111,7 @@ class OneDevice:
     def book(self, members, b: ShardBatch, nbytes: int, round_s: float,
              dispatch_s: float) -> None:
         self.task_times.append((b.shard_index, b.n_rows, dispatch_s))
+        self.rows_padded += b.padded_rows
         if members is not None:
             self.task_bytes.append((b.shard_index, nbytes))
 
@@ -148,6 +150,7 @@ class MeshPlacement:
         self.sharding = NamedSharding(mesh, PartitionSpec("shard"))
         self.task_bytes: list = []
         self.mesh_task_times: list = []
+        self.rows_padded = 0    # rows of every round, padding included
 
     def bind(self, params) -> None:
         import jax
@@ -197,6 +200,7 @@ class MeshPlacement:
         belongs to the shards that forced the round).  The byte
         remainder lands on the first member so the ledger total stays
         exactly equal to the bytes_scanned counter bump."""
+        self.rows_padded += inputs[2].size
         if members is None:
             return
         share, rem = divmod(int(nbytes), len(members))

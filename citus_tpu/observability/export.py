@@ -99,6 +99,10 @@ METRIC_HELP = {
     "batch_rows_padded": "rows of those batches' power-of-two buckets",
     "direct_groups": "slots of the group domains of direct-group-id aggregations",
     "direct_groups_out": "groups those aggregations returned",
+    "direct_bytes_fetched": "bytes of their partial states fetched",
+    "hash_slots": "slots of the device hash tables made",
+    "group_rows_in": "padded rows the grouping stages ran over",
+    "group_rows_kept": "rows of grouped scans that passed the WHERE",
     "hash_groups_out": "groups of hash aggregations, before HAVING",
     "hash_table_updates": "entries (a batch's distinct keys) offered to device hash tables",
     "hash_table_bytes_fetched": "bytes of device hash tables fetched",

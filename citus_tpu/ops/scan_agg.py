@@ -29,7 +29,9 @@ from typing import Callable
 import numpy as np
 
 from citus_tpu.planner.bound import compile_expr, param_env_names, predicate_mask
-from citus_tpu.planner.physical import PhysicalPlan
+from citus_tpu.planner.physical import (
+    PhysicalPlan, product_planes, shadow_sources,
+)
 
 
 def _sentinel(kind: str, dtype: np.dtype):
@@ -60,30 +62,6 @@ def direct_reduction(n_groups: int, numpy_arm: bool) -> str:
     if numpy_arm:
         return "scatter"
     return "onehot" if n_groups <= ONEHOT_MAX_GROUPS else "matmul"
-
-
-def _shadow_sources(plan: PhysicalPlan) -> dict:
-    """{partial index: (arg index of the int64 sum it guards, divisor)}
-    for the float64 shadow sums ``lower_aggregates`` puts beside every
-    int64 sum: sum(CAST(x AS float8)) where sum(x) is accumulated in
-    int64.  The cast of a decimal yields the logical value, x / 10**scale."""
-    from citus_tpu.planner.bound import BCast
-    int_sums = {op.arg_index for op in plan.partial_ops
-                if op.kind == "sum" and op.dtype == "int64"}
-    out = {}
-    for i, op in enumerate(plan.partial_ops):
-        if op.kind != "sum" or op.dtype != "float64":
-            continue
-        arg = plan.agg_args[op.arg_index]
-        if not (isinstance(arg, BCast) and arg.type.is_float):
-            continue
-        src = arg.operand.type
-        if not (src.is_decimal or src.is_integer):
-            continue
-        for j in int_sums:
-            if plan.agg_args[j] == arg.operand:
-                out[i] = (j, 10.0 ** src.scale if src.is_decimal else 1.0)
-    return out
 
 
 class _Pending:
@@ -351,7 +329,11 @@ def build_worker_fn(plan: PhysicalPlan, xp) -> Callable:
         # by XLA, never materialized), at G x N compares and selects a
         # partial.  Above the threshold, fall back to scatter.
         use_onehot = xp.__name__ != "numpy" and G <= 8192
-        shadow_of = _shadow_sources(plan) if reduction == "matmul" else {}
+        shadow_of = (shadow_sources(plan.partial_ops, plan.agg_args)
+                     if reduction == "matmul" else {})
+        # the planes the planner chose this route by (None: some partial
+        # reduces beside the product)
+        planned = product_planes(plan.partial_ops, plan.agg_args)
 
         def seg_sum(gid, upd, dt):
             if use_onehot:
@@ -432,6 +414,8 @@ def build_worker_fn(plan: PhysicalPlan, xp) -> Callable:
                     outs.append(seg_minmax(gid, upd, dt, op.kind))
             outs.append(count_of(mask))
             if mm is not None:
+                assert planned in (None, len(mm.planes)), \
+                    f"product of {len(mm.planes)} planes, planned {planned}"
                 outs = mm.resolve(outs)
             return tuple(outs)
         return worker_direct

@@ -73,6 +73,11 @@ class GroupMode:
     domains: list[KeyDomain] = field(default_factory=list)
     strides: list[int] = field(default_factory=list)
     n_groups: int = 1
+    # hash_host only: the product of the keys' provable domains where
+    # every key has one (the groups cannot outnumber it: the executor
+    # bounds the device table's slots by it), else None.  Not in the
+    # repr: the kernels' fingerprint holds what the kernels are built from
+    domain_slots: Optional[int] = field(default=None, repr=False)
 
 
 @dataclass
@@ -246,7 +251,89 @@ def _key_domain(cat: Catalog, table: TableMeta, key: BExpr,
     return None
 
 
-def choose_group_mode(cat: Catalog, bound: BoundSelect, direct_limit: int) -> GroupMode:
+def shadow_sources(partial_ops, agg_args) -> dict:
+    """{partial index: (arg index of the int64 sum it guards, divisor)}
+    for the float64 shadow sums ``lower_aggregates`` puts beside every
+    int64 sum: sum(CAST(x AS float8)) where sum(x) is accumulated in
+    int64.  The cast of a decimal yields the logical value, x / 10**scale."""
+    int_sums = {op.arg_index for op in partial_ops
+                if op.kind == "sum" and op.dtype == "int64"}
+    out = {}
+    for i, op in enumerate(partial_ops):
+        if op.kind != "sum" or op.dtype != "float64":
+            continue
+        arg = agg_args[op.arg_index]
+        if not (isinstance(arg, BCast) and arg.type.is_float):
+            continue
+        src = arg.operand.type
+        if not (src.is_decimal or src.is_integer):
+            continue
+        for j in int_sums:
+            if agg_args[j] == arg.operand:
+                out[i] = (j, 10.0 ** src.scale if src.is_decimal else 1.0)
+    return out
+
+
+def product_planes(partial_ops, agg_args) -> Optional[int]:
+    """Planes of the factored one-hot product (ops/scan_agg.py
+    ``_MatmulGroupSums``) that hold a plan's whole partial state, or
+    None where some partial cannot ride it: a 0/1 plane for the group
+    rows and one a count, eight 8-bit limbs an int64 sum; the float64
+    shadow of such a sum is read from its limbs."""
+    shadows = shadow_sources(partial_ops, agg_args)
+    planes = 1
+    for i, op in enumerate(partial_ops):
+        if op.kind == "count":
+            planes += op.arg_index >= 0
+        elif op.kind == "sum" and op.dtype == "int64":
+            planes += 8
+        elif i not in shadows:
+            return None
+    return planes
+
+
+#: slots up to which a provable key domain takes the direct table
+#: whatever its partials are (the one-hot reduction to 64 slots, the
+#: product for counts and int64 sums, XLA's scatter for the rest)
+DIRECT_MAX_SLOTS = 65536
+#: slots x planes up to which the product reduces a direct table past
+#: ``DIRECT_MAX_SLOTS``: it costs 2 x slots x planes operations a row on
+#: the MXU.  Read on a v5e (PERF.md section 6, PR 31): 11.7 ns a padded
+#: row at 100,001 slots x 10 planes (77 % of the bf16 peak) where the
+#: hash kernel took 206 ns a padded row of the same statement; at
+#: 303,240 slots x 10 planes (chip_smoke.py's legs 5d and 5e, 67.1 M
+#: padded rows) a warm query took 3.08 s on the product and 14.16 s on
+#: the hash table, so the bound is on the safe side of the crossover
+PRODUCT_MAX_WORK = 1 << 22
+#: ... and rows a slot from which the dense table is worth its slots:
+#: every one of them is initialised, reduced, fetched and searched on
+#: every query whatever the rows hold; below this the table would
+#: mostly be empty, and the hash table holds the keys that occur.
+#: Provisional: no reading sits near this crossover (PERF.md section 7)
+DENSE_ROWS_PER_SLOT = 2
+
+
+def _product_reaches(cat: Catalog, bound: BoundSelect, slots: int,
+                     planes: Optional[int]) -> bool:
+    """Whether a key domain past ``DIRECT_MAX_SLOTS`` still takes the
+    direct table: every partial rides the MXU product, the product's
+    work is bounded, and the catalog's row count says the rows meet
+    each slot more than once (groups met again in every batch and on
+    every shard: matches, where a hash table would take them one by
+    one)."""
+    if planes is None or slots * planes > PRODUCT_MAX_WORK:
+        return False
+    from citus_tpu.catalog.stats import table_row_count
+    return table_row_count(cat, bound.table) >= DENSE_ROWS_PER_SLOT * slots
+
+
+def choose_group_mode(cat: Catalog, bound: BoundSelect, direct_limit: int = 0,
+                      planes: Optional[int] = None) -> GroupMode:
+    """``direct_limit``: ``citus.direct_gid_limit``; 0 (auto, the
+    default) leaves the bound on the direct table's slots to the plan:
+    ``DIRECT_MAX_SLOTS``, and past it what ``_product_reaches``.  A
+    positive value is the operator's bound, and nothing passes it.
+    ``planes``: ``product_planes`` of the plan's partials."""
     # distinct and collect-based aggregates need exact value multisets:
     # only the host grouping path carries them (reference:
     # worker_partial_agg cannot combine DISTINCT either and falls back to
@@ -273,8 +360,13 @@ def choose_group_mode(cat: Catalog, bound: BoundSelect, direct_limit: int) -> Gr
     total = 1
     for d in domains:
         total *= d.size
-        if total > direct_limit:
-            return GroupMode(kind="hash_host")
+    if direct_limit:
+        fits = total <= direct_limit
+    else:
+        fits = (total <= DIRECT_MAX_SLOTS
+                or _product_reaches(cat, bound, total, planes))
+    if not fits:
+        return GroupMode(kind="hash_host", domain_slots=total)
     strides = []
     acc = 1
     for d in reversed(domains):
@@ -394,11 +486,12 @@ def _index_eq(table: TableMeta, filter_: Optional[BExpr]):
     return None
 
 
-def plan_select(cat: Catalog, bound: BoundSelect, *, direct_limit: int = 65536) -> PhysicalPlan:
+def plan_select(cat: Catalog, bound: BoundSelect, *, direct_limit: int = 0) -> PhysicalPlan:
     intervals = extract_intervals(bound.filter)
     shard_indexes, router_key = prune_shards(bound.table, bound.filter, return_key=True)
-    group_mode = choose_group_mode(cat, bound, direct_limit)
     agg_args, partial_ops, agg_extract = lower_aggregates(bound.aggs)
+    group_mode = choose_group_mode(cat, bound, direct_limit,
+                                   product_planes(partial_ops, agg_args))
     return PhysicalPlan(
         bound=bound,
         scan_columns=bound.scan_columns,

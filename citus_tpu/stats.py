@@ -193,6 +193,13 @@ class StatCounters:
         # and chooses its reduction by) and groups returned from them
         "direct_groups",
         "direct_groups_out",
+        "direct_bytes_fetched",
+        # grouped scans, direct or hashed: slots of the device hash
+        # table (EXPLAIN ANALYZE says what bounded them), padded rows
+        # the grouping stage ran over and rows the WHERE kept
+        "hash_slots",
+        "group_rows_in",
+        "group_rows_kept",
         # pull-path placement syncs skipped because the control plane's
         # data-invalidation epoch proved the local mirror current
         # (net/data_plane.py sync_placement fast path)

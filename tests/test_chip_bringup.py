@@ -180,13 +180,23 @@ def test_smoke_rehearsal_passes_every_leg(tmp_path, n_dev):
     report = json.loads((tmp_path / "out" / "report.json").read_text())
     assert report["rehearsal"] is True and report["platform"] == "cpu"
     legs = {leg["leg"].split()[0]: leg for leg in report["legs"]}
-    single = {"1", "2", "3", "3b", "4", "5", "5b", "5c", "6"}
+    single = {"1", "2", "3", "3b", "4", "5", "5b", "5c", "5d", "5e", "6"}
     assert set(legs) == (single | {"7a", "7b", "7c", "7d"} if n_dev > 1
                          else single)
     assert all(leg["ok"] for leg in legs.values())
     scan_slot = "mesh_run" if n_dev > 1 else "jit_fused"
     assert legs["1"]["kernel_slot"] == scan_slot
+    # leg 5 keeps the hash table by its partials (a min), sized here by
+    # the 12,000 rows; the same keys over a count and a sum follow the
+    # rule (fewer rows than twice the 303,240 slots: the hash table) and,
+    # in 5e, the operator's bound
     assert legs["5"]["kernel_slot"] == "jit_hash_fused"
+    assert (legs["5"]["hash_slots"], legs["5"]["hash_slots_from"]) \
+        == (16_384, "row count")
+    assert legs["5d"]["kernel_slot"] == legs["5e"]["kernel_slot"] \
+        == "jit_hash_fused"
+    assert "the plan's route" in legs["5d"]["leg"] \
+        and "direct_gid_limit 65536" in legs["5e"]["leg"]
     assert legs["6"]["kernel_slot"] == "jit_filter"
     if n_dev > 1:
         assert "devjoin" in legs["7a"]["shuffle"]

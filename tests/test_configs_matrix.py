@@ -57,7 +57,7 @@ def run_battery(tmp_path, cfg):
         planner=PlannerSettings(
             enable_repartition_joins=cfg.get("repartition", True),
             hash_agg_slots=cfg.get("hash_slots", 0),   # 0: derived
-            direct_gid_limit=cfg.get("direct_limit", 65536)),
+            direct_gid_limit=cfg.get("direct_limit", 0)),   # 0: auto
     )
     tag = "_".join(str(v) for v in cfg.values())
     cl = ct.Cluster(str(tmp_path / f"db_{tag}"), n_nodes=2, settings=st)
@@ -72,7 +72,12 @@ def run_battery(tmp_path, cfg):
         "s": np.array(["x", "y", "z"])[rng.integers(0, 3, n)].tolist()})
     out = []
     for sql in BATTERY:
-        out.append(sorted(cl.execute(sql).rows, key=repr))
+        r = cl.execute(sql)
+        if sql == BATTERY[1]:
+            # 11 slots: direct unless the setting bounds it under them
+            want = "hash_host" if "direct_limit" in cfg else "direct"
+            assert r.explain["strategy"] == want, (cfg, r.explain)
+        out.append(sorted(r.rows, key=repr))
     cl.close()
     return out
 

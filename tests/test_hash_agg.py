@@ -45,7 +45,13 @@ def test_hash_agg_with_tiny_slot_table_spills_exactly(tmp_path):
     v = rng.integers(0, 100, n)
     cl.copy_from("t", columns={"k": np.arange(n, dtype=np.int64), "g": g, "v": v})
     sql = "SELECT g, count(*), sum(v) FROM t GROUP BY g"
-    got = sorted(cl.execute(sql).rows)
+    r = cl.execute(sql)
+    got = sorted(r.rows)
+    # the setting is a bound nothing passes: 2,001 slots of a count and
+    # an int64 sum would ride the direct table's product otherwise
+    assert r.explain["strategy"] == "hash_host"
+    assert r.explain["pipeline"]["hash_slots"] == 64
+    assert r.explain["pipeline"]["hash_spilled_rows"] > 0
     # numpy truth
     import collections
     truth = collections.defaultdict(lambda: [0, 0])
@@ -61,8 +67,9 @@ def test_null_keys_in_hash_mode(tmp_path):
     cl = ct.Cluster(str(tmp_path / "db"), n_nodes=1, settings=st)
     cl.execute("CREATE TABLE t (g bigint, v bigint)")
     cl.execute("INSERT INTO t VALUES (1, 10), (NULL, 20), (1, 30), (NULL, 40), (2, 5)")
-    rows = sorted(cl.execute("SELECT g, count(*), sum(v) FROM t GROUP BY g").rows,
-                  key=repr)
+    r = cl.execute("SELECT g, count(*), sum(v) FROM t GROUP BY g")
+    assert r.explain["strategy"] == "hash_host"
+    rows = sorted(r.rows, key=repr)
     assert sorted(rows, key=repr) == sorted(
         [(1, 2, 40), (2, 1, 5), (None, 2, 60)], key=repr)
 
