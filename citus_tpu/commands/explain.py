@@ -274,6 +274,13 @@ def _run_analyze(cl, stmt: A.Explain) -> list[str]:
             # rows shipped and scanned per table row (1.0 = no padding)
             line += (", pad_share "
                      f"{pl['batch_rows_padded'] / pl['batch_rows_real']:.3f}")
+        decoded = pl.get("decode_bytes_in_place", 0) \
+            + pl.get("decode_bytes_copied", 0)
+        if decoded:
+            # share of the scan columns' bytes that the batch's one
+            # native call decoded where the kernel reads them
+            line += (", decoded in place "
+                     f"{pl['decode_bytes_in_place'] / decoded:.3f}")
         if "fused_dispatches" in pl:
             # the 1-dispatch-per-batch claim, visible per statement
             line += f", fused dispatches {pl['fused_dispatches']}"

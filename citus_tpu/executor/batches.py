@@ -1,12 +1,19 @@
 """Shard data -> padded device batches.
 
-The host-side half of the scan: read pruned chunks (decompressed on the
-host), cut the stream at exactly ``max_batch_rows`` rows, and assemble
-each cut once into a buffer padded to a power-of-two row bucket, so XLA
-sees a small, stable set of shapes (the recompile-pressure discipline
-the reference gets from prepared-statement plan caching).  A full batch
-is its bucket (no padding); only a shard's last batch is padded.
-Padding rows carry ``row_mask=False`` and zeroed values.
+The host-side half of the scan: cut a shard's pruned chunks at exactly
+``max_batch_rows`` rows and land each cut once in arrays padded to a
+power-of-two row bucket, so XLA sees a small, stable set of shapes (the
+recompile-pressure discipline the reference gets from prepared-statement
+plan caching).  A full batch is its bucket (no padding); only a shard's
+last batch is padded.  Padding rows carry ``row_mask=False`` and zeroed
+values.
+
+A device scan is cut from the footers, BEFORE anything is decompressed:
+the batch's arrays are allocated first and ONE native call decodes every
+value stream of the batch where the kernel will read it.  What cannot
+arrive that way (see ``ShardReader.in_place_columns``, and a column
+whose stored dtype is not its device dtype) is read stripe by stripe
+into fresh arrays and copied to the same place.
 """
 
 from __future__ import annotations
@@ -17,10 +24,11 @@ from typing import Iterator, NamedTuple, Optional
 import numpy as np
 
 from citus_tpu.catalog import Catalog, TableMeta
+from citus_tpu.errors import StorageError
 from citus_tpu.observability import trace as _trace
 from citus_tpu.planner.physical import PhysicalPlan
 from citus_tpu.storage import ShardReader
-from citus_tpu.storage.writer import _load_meta
+from citus_tpu.storage.reader import BatchDecode, StripeScan
 import os
 
 
@@ -32,6 +40,10 @@ class ShardBatch:
     n_rows: int                      # real rows
     padded_rows: int
     shard_index: int
+    # stored bytes of the real rows' values: decoded where they lie /
+    # copied there from a decoded chunk
+    bytes_in_place: int = 0
+    bytes_copied: int = 0
 
     @property
     def nbytes(self) -> int:
@@ -42,8 +54,9 @@ class ShardBatch:
 
 
 class _Piece(NamedTuple):
-    """A chunk, or the slice of one that a batch cut fell into (views,
-    no copy)."""
+    """A decoded chunk (of all scan columns, or of those a batch could
+    not take in place), or the slice of one that a batch cut fell into
+    (views, no copy)."""
     values: dict[str, np.ndarray]
     validity: dict[str, Optional[np.ndarray]]   # None = all valid
     rows: int
@@ -53,6 +66,30 @@ class _Piece(NamedTuple):
             {c: v[lo:hi] for c, v in self.values.items()},
             {c: None if m is None else m[lo:hi]
              for c, m in self.validity.items()}, hi - lo)
+
+
+class _ChunkRef(NamedTuple):
+    """A selected chunk still on disk: its rows are known from the
+    footer (less the stripe's deletes), none of its bytes are read."""
+    reader: ShardReader
+    columns: list[str]
+    st: StripeScan
+    ci: int
+    rows: int
+
+    def cut(self, lo: int, hi: int) -> _Piece:
+        """A batch cut falls inside this chunk (at most one a cut):
+        decode it alone; both batches copy their part."""
+        (b,) = self.reader.stripe_chunks(self.st, self.columns, [self.ci])
+        return _decoded(b, self.rows).cut(lo, hi)
+
+
+def _decoded(b, rows: int) -> _Piece:
+    if b.row_count != rows:
+        raise StorageError(
+            f"{b.stripe_file}: chunk {b.chunk_index} decoded to "
+            f"{b.row_count} rows, planned {rows}")
+    return _Piece(b.values, b.validity, rows)
 
 
 def bucket_rows(n: int, min_rows: int, max_rows: int) -> int:
@@ -79,8 +116,11 @@ def _pull_placement_fallback(cat: Catalog, table: TableMeta, shard,
 
 
 def _shard_chunks(cat: Catalog, plan: PhysicalPlan, shard_index: int,
-                  node_override: Optional[int], prefer_secondary: bool):
-    """Yield the pruned chunks of one shard placement, in order."""
+                  node_override: Optional[int], prefer_secondary: bool,
+                  decoded: bool):
+    """Yield the pruned chunks of one shard placement, in order: as
+    decoded ``_Piece``s, or (``decoded`` False, and no index to look a
+    key up in) as ``_ChunkRef``s for a batch to be laid out from."""
     table = plan.bound.table
     shard = table.shards[shard_index]
     from citus_tpu.testing.faults import FAULTS
@@ -133,56 +173,68 @@ def _shard_chunks(cat: Catalog, plan: PhysicalPlan, shard_index: int,
     cols = plan.scan_columns
     if plan.index_eq is not None:
         col, value, _name = plan.index_eq
-        yield from reader.lookup_eq(cols, col, value, plan.intervals)
+        chunks = reader.lookup_eq(cols, col, value, plan.intervals)
+    elif decoded:
+        chunks = reader.scan(cols, plan.intervals)
     else:
-        yield from reader.scan(cols, plan.intervals)
+        for st in reader.scan_stripes(cols, plan.intervals):
+            for ci in st.chunks:
+                yield _ChunkRef(reader, cols, st, ci, st.live_rows(ci))
+        return
+    for b in chunks:
+        yield _Piece(b.values, b.validity, b.row_count)
 
 
 def _cut_batches(cat: Catalog, plan: PhysicalPlan, shard_index: int,
                  max_batch_rows: int, node_override: Optional[int],
-                 prefer_secondary: bool):
-    """Yield (pieces, n_rows) with 0 < n_rows <= max_batch_rows: the
+                 prefer_secondary: bool, land=None):
+    """Yield (parts, n_rows) with 0 < n_rows <= max_batch_rows: the
     shard's rows in order, cut at exactly max_batch_rows.  Chunk sizes
     are arbitrary (deletes shorten them), so a cut may fall anywhere in
-    a chunk, and more than once in a large one."""
+    a chunk, and more than once in a large one.  Without ``land`` the
+    parts are decoded ``_Piece``s; with it the cut is planned from the
+    footers and ``land(parts, n_rows)`` — whose result is yielded in
+    their place — brings the bytes in."""
     from citus_tpu.testing.faults import FAULTS
     table = plan.bound.table
     fault_key = f"{table.name}:{table.shards[shard_index].shard_id}"
     chunks = _shard_chunks(cat, plan, shard_index, node_override,
-                           prefer_secondary)
+                           prefer_secondary, decoded=land is None)
     # NOTE: under the pipelined executor this generator runs on the
     # host decode thread (executor/pipeline.py HostPrefetcher), so the
     # decode_batch fault point below fires there — delays injected on
     # it model slow host-side decompression overlapping device compute.
-    # Spans: one stripe_read per batch (file read + decompress of every
-    # chunk it pulls), never one per chunk, and none held across the
-    # yield.
+    # Spans: one stripe_read per batch (footers, file read + decompress
+    # of every chunk it takes), never one per chunk, and none held
+    # across the yield.
     rest = None            # what the last cut left of its chunk
     exhausted = False
     while not exhausted:
-        pieces, rows = [], 0
+        parts, rows = [], 0
         with _trace.span("stripe_read") as sp:
             while rows < max_batch_rows:
-                piece = rest
+                part = rest
                 rest = None
-                if piece is None:
-                    b = next(chunks, None)
-                    if b is None:
+                if part is None:
+                    part = next(chunks, None)
+                    if part is None:
                         exhausted = True
                         break
-                    piece = _Piece(b.values, b.validity, b.row_count)
                 room = max_batch_rows - rows
-                if piece.rows > room:
-                    rest = piece.cut(room, piece.rows)
-                    piece = piece.cut(0, room)
-                if piece.rows:
-                    pieces.append(piece)
-                    rows += piece.rows
+                if part.rows > room:
+                    # a chunk still on disk is decoded here, once
+                    whole = part.cut(0, part.rows)
+                    rest, part = whole.cut(room, whole.rows), whole.cut(0, room)
+                if part.rows:
+                    parts.append(part)
+                    rows += part.rows
             if sp.recording:
-                sp.set(chunks=len(pieces), rows=rows)
+                sp.set(chunks=len(parts), rows=rows)
+            if rows and land is not None:
+                parts = land(parts, rows)
         if rows:
             FAULTS.hit("decode_batch", fault_key)
-            yield pieces, rows
+            yield parts, rows
 
 
 def load_shard_batches(
@@ -220,54 +272,107 @@ def load_padded_batches(
     prefer_secondary: bool = False,
 ) -> Iterator[ShardBatch]:
     """Yield one shard placement's rows as ShardBatches of at most
-    max_batch_rows rows, each assembled ONCE: per scan column one array
-    of padded_rows elements in the device dtype that the chunk slices
-    are written into (the cast happens in that write).  Full batches
-    have n_rows == padded_rows; the shard's last batch is padded to its
-    bucket with zeroed values, validity True and row_mask False."""
-    for pieces, n in _cut_batches(cat, plan, shard_index, max_batch_rows,
-                                  None, prefer_secondary):
-        yield _assemble(plan, pieces, n,
-                        bucket_rows(n, min_batch_rows, max_batch_rows),
-                        shard_index)
+    max_batch_rows rows, each landed ONCE: per scan column one array of
+    padded_rows elements in the device dtype, which the batch's chunks
+    are decoded into, or copied into where they cannot be (the cast
+    happens in that copy).  Full batches have n_rows == padded_rows; the
+    shard's last batch is padded to its bucket with zeroed values,
+    validity True and row_mask False."""
+    def land(parts, n):
+        return _Landing(plan, parts, n,
+                        bucket_rows(n, min_batch_rows, max_batch_rows))
+
+    for landing, _n in _cut_batches(cat, plan, shard_index, max_batch_rows,
+                                    None, prefer_secondary, land):
+        yield landing.finish(shard_index)
 
 
-def _assemble(plan: PhysicalPlan, pieces: list, n_rows: int,
-              padded_rows: int, shard_index: int) -> ShardBatch:
-    schema = plan.bound.table.schema
-    cols_out, valids_out = [], []
-    with _trace.span("pad") as sp:
-        for c in plan.scan_columns:
-            dt = schema.scan_dtype(c, device=True)
-            if len(pieces) == 1 and padded_rows == n_rows:
-                # one chunk that fills its bucket: no copy unless it casts
-                m = pieces[0].validity[c]
-                cols_out.append(pieces[0].values[c].astype(dt, copy=False))
-                valids_out.append(np.ones(n_rows, bool) if m is None else m)
-                continue
-            v = np.empty(padded_rows, dt)
-            m = np.empty(padded_rows, bool)
-            at = 0
-            for p in pieces:
-                v[at:at + p.rows] = p.values[c]
-                m[at:at + p.rows] = \
-                    True if p.validity[c] is None else p.validity[c]
-                at += p.rows
-            v[n_rows:] = 0
-            m[n_rows:] = True
-            cols_out.append(v)
-            valids_out.append(m)
-        row_mask = np.ones(padded_rows, bool)
-        row_mask[n_rows:] = False
-        out = ShardBatch(tuple(cols_out), tuple(valids_out), row_mask,
-                         n_rows, padded_rows, shard_index)
-        if sp.recording:
-            sp.set(bytes_in=int(sum(
-                p.values[c].nbytes
-                + (0 if p.validity[c] is None else p.validity[c].nbytes)
-                for p in pieces for c in plan.scan_columns)),
-                   bytes_out=out.nbytes)
-    return out
+class _Landing:
+    """One batch's arrays, from the planned cut to the ShardBatch.
+    Making it (under the caller's stripe_read span) allocates them and
+    brings every byte in: the chunks still on disk by one native call
+    straight into place where the reader allows it and the dtypes
+    agree, the others stripe by stripe into fresh arrays.  ``finish``
+    (the pad span) copies what did not land in place and fills the
+    validity, the row mask and the tail."""
+
+    def __init__(self, plan: PhysicalPlan, parts: list, n_rows: int,
+                 padded_rows: int):
+        schema = plan.bound.table.schema
+        self.columns = plan.scan_columns
+        self._index = {c: k for k, c in enumerate(self.columns)}
+        self.n_rows, self.padded_rows = n_rows, padded_rows
+        self.values = [np.empty(padded_rows, schema.scan_dtype(c, device=True))
+                       for c in self.columns]
+        # only a column stored as the device reads it can land in place
+        self._same_dtype = [c for c, v in zip(self.columns, self.values)
+                            if schema.scan_dtype(c) == v.dtype]
+        self.copies: list[tuple[int, _Piece]] = []   # (first row, piece)
+        self.bytes_in_place = 0
+        groups = []        # (first row, the refs of consecutive chunks of ONE stripe)
+        at = 0
+        for p in parts:
+            if isinstance(p, _Piece):
+                self.copies.append((at, p))
+            elif groups and groups[-1][1][-1].st is p.st:
+                groups[-1][1].append(p)
+            else:
+                groups.append((at, [p]))
+            at += p.rows
+        if groups and not self._read(groups, in_place=True):
+            # a stream failed: the stripe readers name the fault, or
+            # fall back to the Python codecs, as they always did
+            self._read(groups, in_place=False)
+
+    def _read(self, groups: list, in_place: bool) -> bool:
+        decode = BatchDecode(self.values)
+        copies = []
+        for at, refs in groups:
+            reader, st = refs[0].reader, refs[0].st
+            chunks = [r.ci for r in refs]
+            rows = np.array([r.rows for r in refs], np.int64)
+            starts = at + np.cumsum(rows) - rows
+            direct = reader.in_place_columns(st, chunks, self._same_dtype) \
+                if in_place else []
+            for c in direct:
+                k = self._index[c]
+                stats = st.footer.columns[reader.schema.scan_storage_name(c)]
+                decode.add(st, [stats[ci] for ci in chunks], k,
+                           starts * self.values[k].itemsize)
+            rest = [c for c in self.columns if c not in direct]
+            if rest:
+                for s, r, b in zip(starts, refs,
+                                   reader.stripe_chunks(st, rest, chunks)):
+                    copies.append((int(s), _decoded(b, r.rows)))
+        if not decode.run():
+            return False
+        self.bytes_in_place = decode.bytes
+        self.copies += copies
+        return True
+
+    def finish(self, shard_index: int) -> ShardBatch:
+        n_rows, padded_rows = self.n_rows, self.padded_rows
+        with _trace.span("pad") as sp:
+            valids = [np.ones(padded_rows, bool) for _ in self.columns]
+            copied = 0
+            for at, p in self.copies:
+                for c, v in p.values.items():
+                    k = self._index[c]
+                    self.values[k][at:at + p.rows] = v
+                    if p.validity[c] is not None:
+                        valids[k][at:at + p.rows] = p.validity[c]
+                    copied += v.nbytes
+            for v in self.values:
+                v[n_rows:] = 0
+            row_mask = np.ones(padded_rows, bool)
+            row_mask[n_rows:] = False
+            out = ShardBatch(tuple(self.values), tuple(valids), row_mask,
+                             n_rows, padded_rows, shard_index,
+                             self.bytes_in_place, copied)
+            if sp.recording:
+                sp.set(bytes_in=copied, bytes_in_place=self.bytes_in_place,
+                       bytes_out=out.nbytes)
+        return out
 
 
 def empty_batch(table: TableMeta, plan: PhysicalPlan, padded_rows: int,

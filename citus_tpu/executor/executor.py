@@ -204,7 +204,9 @@ def _iter_padded_batches(cat: Catalog, plan: PhysicalPlan, settings: Settings):
     columnar_reader.c:323).  A full batch is exactly its bucket; only a
     shard's last batch is padded, to its own power-of-two bucket, so
     the per-shape jit cache stays small.  Books real against padded
-    rows: process counters, and the statement's EXPLAIN pad_share."""
+    rows, and the bytes decoded in place against those copied there:
+    process counters, and the statement's EXPLAIN pad_share and
+    decoded-in-place share."""
     from citus_tpu.testing.faults import FAULTS
 
     def shard_batches():
@@ -236,10 +238,12 @@ def _iter_padded_batches(cat: Catalog, plan: PhysicalPlan, settings: Settings):
                                rows=int(hb.n_rows), bytes=hb.nbytes)
             if hb is None:
                 return
-            for name, rows in (("batch_rows_real", hb.n_rows),
-                               ("batch_rows_padded", hb.padded_rows)):
-                GLOBAL_COUNTERS.bump(name, rows)
-                pl[name] = pl.get(name, 0) + rows
+            for name, by in (("batch_rows_real", hb.n_rows),
+                             ("batch_rows_padded", hb.padded_rows),
+                             ("decode_bytes_in_place", hb.bytes_in_place),
+                             ("decode_bytes_copied", hb.bytes_copied)):
+                GLOBAL_COUNTERS.bump(name, by)
+                pl[name] = pl.get(name, 0) + by
             yield hb
     finally:
         batches.close()

@@ -51,6 +51,11 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.ct_read_streams_mt.restype = i64
     lib.ct_read_streams_mt.argtypes = [ctypes.c_char_p, i32, i64, i64p, i64p,
                                        i64p, i64p, u8p, i64, i32]
+    i32p = ctypes.POINTER(i32)
+    lib.ct_decode_batch.restype = i64
+    lib.ct_decode_batch.argtypes = [
+        i32, ctypes.POINTER(ctypes.c_char_p), i32p, i64, i32p, i64p, i64p,
+        i64p, i32p, i64p, i32, ctypes.POINTER(ctypes.c_void_p), i64p, i32]
     lib.ct_unpack_bits.restype = None
     lib.ct_unpack_bits.argtypes = [u8p, i64, u8p]
     lib.ct_version.restype = i32

@@ -15,6 +15,8 @@
 #include <thread>
 #include <vector>
 
+#include <fcntl.h>
+#include <unistd.h>
 #include <zstd.h>
 #include <zlib.h>
 
@@ -176,6 +178,98 @@ int64_t ct_read_streams_mt(const char* path, int32_t codec, int64_t n,
     std::vector<std::thread> threads;
     for (int t = 0; t < nt; t++) threads.emplace_back(worker);
     for (auto& t : threads) t.join();
+    return err.load();
+}
+
+// ---- one call per scan batch -------------------------------------------
+// The value streams of a whole batch — about 2,000 streams out of some 32
+// stripe files for 4 M rows — each decoded straight to its place in the
+// batch's column arrays: stream i lies in file file_idx[i] and lands at
+// byte dst_offs[i] of buffer dst_col[i] (dst_ptrs / dst_caps describe
+// the buffers, so every write is bounds-checked here).  Files are opened
+// once and read with pread, which threads may share; each worker owns
+// its scratch and its zstd context.  A codec "none" stream is read where
+// it belongs, with no scratch at all.
+// returns 0 on success, -(1+i) identifying the failing stream,
+// -1000000 - f for a file that does not open.
+
+int64_t ct_decode_batch(int32_t n_files, const char* const* paths,
+                        const int32_t* file_codecs, int64_t n,
+                        const int32_t* file_idx, const int64_t* offsets,
+                        const int64_t* comp_lens, const int64_t* raw_lens,
+                        const int32_t* dst_col, const int64_t* dst_offs,
+                        int32_t n_dst, uint8_t* const* dst_ptrs,
+                        const int64_t* dst_caps, int32_t n_threads) {
+    std::vector<int> fds((size_t)(n_files > 0 ? n_files : 0), -1);
+    auto close_all = [&]() {
+        for (int fd : fds) if (fd >= 0) close(fd);
+    };
+    for (int32_t f = 0; f < n_files; f++) {
+        fds[f] = open(paths[f], O_RDONLY | O_CLOEXEC);
+        if (fds[f] < 0) { close_all(); return -1000000 - f; }
+    }
+    std::atomic<int64_t> err{0};
+    std::atomic<int64_t> next{0};
+    auto read_all = [](int fd, uint8_t* buf, int64_t len, int64_t off) {
+        while (len > 0) {
+            ssize_t got = pread(fd, buf, (size_t)len, (off_t)off);
+            if (got <= 0) return false;
+            buf += got; off += got; len -= got;
+        }
+        return true;
+    };
+    auto worker = [&]() {
+        std::vector<uint8_t> scratch;
+        ZSTD_DCtx* dctx = nullptr;
+        while (err.load(std::memory_order_relaxed) == 0) {
+            int64_t i = next.fetch_add(1);
+            if (i >= n) break;
+            int32_t f = file_idx[i], c = dst_col[i];
+            bool ok = f >= 0 && f < n_files && c >= 0 && c < n_dst &&
+                      comp_lens[i] >= 0 && raw_lens[i] >= 0 &&
+                      dst_offs[i] >= 0 &&
+                      dst_offs[i] + raw_lens[i] <= dst_caps[c];
+            if (ok) {
+                uint8_t* dst = dst_ptrs[c] + dst_offs[i];
+                int32_t codec = file_codecs[f];
+                if (codec == CODEC_NONE) {
+                    ok = comp_lens[i] == raw_lens[i] &&
+                         read_all(fds[f], dst, raw_lens[i], offsets[i]);
+                } else {
+                    if ((int64_t)scratch.size() < comp_lens[i])
+                        scratch.resize((size_t)comp_lens[i]);
+                    ok = read_all(fds[f], scratch.data(), comp_lens[i],
+                                  offsets[i]);
+                    if (ok && codec == CODEC_ZSTD) {
+                        if (!dctx) dctx = ZSTD_createDCtx();
+                        size_t got = dctx ? ZSTD_decompressDCtx(
+                            dctx, dst, (size_t)raw_lens[i], scratch.data(),
+                            (size_t)comp_lens[i]) : (size_t)-1;
+                        ok = !ZSTD_isError(got) && (int64_t)got == raw_lens[i];
+                    } else if (ok) {
+                        ok = ct_decompress(codec, scratch.data(), comp_lens[i],
+                                           dst, raw_lens[i]) == raw_lens[i];
+                    }
+                }
+            }
+            if (!ok) {
+                int64_t expect = 0;
+                err.compare_exchange_strong(expect, -(1 + i));
+                break;
+            }
+        }
+        if (dctx) ZSTD_freeDCtx(dctx);
+    };
+    int nt = n_threads < 1 ? 1 : (n_threads > 16 ? 16 : n_threads);
+    if ((int64_t)nt > n) nt = (int)n;
+    if (nt <= 1) {
+        worker();
+    } else {
+        std::vector<std::thread> threads;
+        for (int t = 0; t < nt; t++) threads.emplace_back(worker);
+        for (auto& t : threads) t.join();
+    }
+    close_all();
     return err.load();
 }
 

@@ -97,6 +97,8 @@ METRIC_HELP = {
     "bytes_scanned": "columnar bytes staged for device scans",
     "batch_rows_real": "table rows in the padded scan batches made",
     "batch_rows_padded": "rows of those batches' power-of-two buckets",
+    "decode_bytes_in_place": "value bytes of those batches decoded straight into the batch's arrays",
+    "decode_bytes_copied": "value bytes of those batches decoded apart and copied in",
     "direct_groups": "slots of the group domains of direct-group-id aggregations",
     "direct_groups_out": "groups those aggregations returned",
     "direct_bytes_fetched": "bytes of their partial states fetched",

@@ -40,6 +40,11 @@ class StatCounters:
         # real rows against the rows of their power-of-two buckets
         "batch_rows_real",
         "batch_rows_padded",
+        # stored value bytes of those batches' real rows: decoded by the
+        # batch's one native call where the kernel reads them / copied
+        # there from a chunk decoded apart (deletes, NULLs, a cast, ...)
+        "decode_bytes_in_place",
+        "decode_bytes_copied",
         "plan_cache_hits",
         "plan_cache_misses",
         "connection_failovers",

@@ -19,7 +19,9 @@ import numpy as np
 
 from citus_tpu.errors import AnalysisError, StorageError
 from citus_tpu.schema import Schema
-from citus_tpu.storage.format import read_stripe_footer, read_chunk
+from citus_tpu.storage.format import (
+    StripeFooter, read_chunk, read_stripe_footer,
+)
 from citus_tpu.storage.writer import _load_meta
 
 
@@ -84,6 +86,27 @@ class ChunkBatch:
     chunk_row_offset: int = 0
 
 
+@dataclass
+class StripeScan:
+    """What a scan takes from one stripe, known from its footer and its
+    deletion bitmap before any stream byte is read."""
+
+    file: str
+    path: str
+    footer: StripeFooter
+    chunks: list[int]                  # the selected chunk groups
+    offsets: np.ndarray                # first row of every chunk group
+    del_mask: Optional[np.ndarray]     # deleted rows of the stripe, if any
+
+    def live_rows(self, ci: int) -> int:
+        """Rows chunk group ``ci`` yields once deletes are subtracted."""
+        n = self.footer.chunk_row_counts[ci]
+        if self.del_mask is None:
+            return n
+        at = int(self.offsets[ci])
+        return n - int(self.del_mask[at:at + n].sum())
+
+
 class ShardReader:
     """Reads one shard directory written by ShardWriter."""
 
@@ -113,6 +136,20 @@ class ShardReader:
         subtracting deletion bitmaps (unless ``apply_deletes=False``,
         used by DML that needs original row positions).  ``only_stripes``
         restricts to a stripe-file subset (index-lookup fallback)."""
+        for st in self.scan_stripes(columns, constraints, apply_deletes,
+                                    only_stripes):
+            yield from self.stripe_chunks(st, columns, st.chunks)
+
+    def scan_stripes(
+        self,
+        columns: list[str],
+        constraints: Optional[list[Interval]] = None,
+        apply_deletes: bool = True,
+        only_stripes: Optional[set] = None,
+    ) -> Iterator[StripeScan]:
+        """The first half of a scan, per stripe with a selected chunk:
+        footer, chunk pruning, deletion bitmap — everything known before
+        a stream byte is read, so a caller can lay a batch out first."""
         from citus_tpu.storage.deletes import deleted_mask
         from citus_tpu.storage.overlay import visible_deletes
         constraints = constraints or []
@@ -145,34 +182,43 @@ class ShardReader:
             if apply_deletes and stripe["file"] in delete_cache:
                 del_mask = deleted_mask(self.directory, stripe["file"],
                                         footer.row_count, delete_cache)
-            sel_idx = [int(i) for i in np.nonzero(selected)[0]]
-            native = self._scan_stripe_native(path, footer, columns, sel_idx)
-            if native is not None:
-                for b in native:
-                    b.chunk_row_offset = int(offsets[b.chunk_index])
-                    yield self._subtract_deletes(b, del_mask)
-                continue
-            with open(path, "rb") as fh:
-                for ci in sel_idx:
-                    vals, valid = {}, {}
-                    for col in columns:
-                        c = self.schema.scan_column(col)
-                        stream = footer.columns.get(
-                            self.schema.scan_storage_name(col))
-                        if stream is None:
-                            # column added after this stripe: all NULL
-                            n_ = footer.chunk_row_counts[ci]
-                            vals[col] = np.zeros(n_, c.type.storage_dtype)
-                            valid[col] = np.zeros(n_, bool)
-                            continue
-                        v, m = read_chunk(fh, footer, stream[ci], c.type.storage_dtype)
-                        vals[col], valid[col] = v, m
-                    b = ChunkBatch(
-                        values=vals, validity=valid,
-                        row_count=footer.chunk_row_counts[ci],
-                        stripe_file=stripe["file"], chunk_index=ci,
-                        chunk_row_offset=int(offsets[ci]))
-                    yield self._subtract_deletes(b, del_mask)
+            yield StripeScan(stripe["file"], path, footer,
+                             [int(i) for i in np.nonzero(selected)[0]],
+                             offsets, del_mask)
+
+    def stripe_chunks(self, st: StripeScan, columns: list[str],
+                      chunks: list[int]) -> Iterator[ChunkBatch]:
+        """The second half: read and decompress ``chunks`` of one
+        stripe into fresh arrays (one native call a stripe, else the
+        Python reader) and subtract the stripe's deletes."""
+        footer = st.footer
+        native = self._scan_stripe_native(st.path, footer, columns, chunks)
+        if native is not None:
+            for b in native:
+                b.chunk_row_offset = int(st.offsets[b.chunk_index])
+                yield self._subtract_deletes(b, st.del_mask)
+            return
+        with open(st.path, "rb") as fh:
+            for ci in chunks:
+                vals, valid = {}, {}
+                for col in columns:
+                    c = self.schema.scan_column(col)
+                    stream = footer.columns.get(
+                        self.schema.scan_storage_name(col))
+                    if stream is None:
+                        # column added after this stripe: all NULL
+                        n_ = footer.chunk_row_counts[ci]
+                        vals[col] = np.zeros(n_, c.type.storage_dtype)
+                        valid[col] = np.zeros(n_, bool)
+                        continue
+                    v, m = read_chunk(fh, footer, stream[ci], c.type.storage_dtype)
+                    vals[col], valid[col] = v, m
+                b = ChunkBatch(
+                    values=vals, validity=valid,
+                    row_count=footer.chunk_row_counts[ci],
+                    stripe_file=st.file, chunk_index=ci,
+                    chunk_row_offset=int(st.offsets[ci]))
+                yield self._subtract_deletes(b, st.del_mask)
 
     def lookup_eq(
         self,
@@ -345,6 +391,32 @@ class ShardReader:
                 stripe_file=os.path.basename(path), chunk_index=ci))
         return out_batches
 
+    def in_place_columns(self, st: StripeScan, chunks: list[int],
+                         columns: list[str]) -> list[str]:
+        """Of ``columns``, those whose value streams of ``chunks`` may be
+        decoded where a caller wants them (``BatchDecode``), decided
+        from what the footer shows: the native library has the stripe's
+        codec, no row of the stripe is deleted, the column was there
+        when the stripe was written, and every stream is NULL-free (a
+        validity bitmap needs unpacking) and exactly its rows long.
+        Whatever is left out arrives through ``stripe_chunks``."""
+        from citus_tpu.native import CODEC_IDS, get_lib
+        if st.del_mask is not None or get_lib() is None \
+                or st.footer.codec not in CODEC_IDS:
+            return []
+        out = []
+        for col in columns:
+            stats = st.footer.columns.get(self.schema.scan_storage_name(col))
+            if stats is None:
+                continue
+            width = self.schema.scan_dtype(col).itemsize
+            if all(not stats[ci].has_nulls
+                   and stats[ci].row_count == st.footer.chunk_row_counts[ci]
+                   and stats[ci].value_raw_length == stats[ci].row_count * width
+                   for ci in chunks):
+                out.append(col)
+        return out
+
     def chunk_counts(self, constraints: Optional[list[Interval]] = None) -> tuple[int, int]:
         """(selected_chunks, total_chunks) — for EXPLAIN/statistics."""
         sel = tot = 0
@@ -377,3 +449,70 @@ class ShardReader:
                 if not c.admits(stats.minimum, stats.maximum):
                     mask[ci] = False
         return mask
+
+
+class BatchDecode:
+    """The value streams of one scan batch, gathered stripe by stripe,
+    then read and decompressed by ONE native call on the pool
+    (``decode_thread_count()`` threads over every stream of the batch),
+    each stream straight to its place in the caller's arrays."""
+
+    def __init__(self, dst: list[np.ndarray]):
+        self._dst = dst
+        self._paths: list[bytes] = []
+        self._codecs: list[int] = []
+        self._file: list[int] = []
+        self._col: list[int] = []
+        self._stats: list = []
+        self._at: list[np.ndarray] = []
+        self.bytes = 0                    # decompressed bytes gathered
+
+    def add(self, st: StripeScan, stats: list, col: int,
+            byte_offsets: np.ndarray) -> None:
+        """Queue ``stats`` (a column's ChunkStats of consecutive chunks
+        of ``st``) for ``dst[col]``, stream i at ``byte_offsets[i]``."""
+        from citus_tpu.native import CODEC_IDS
+        path = st.path.encode()
+        if not self._paths or self._paths[-1] != path:
+            self._paths.append(path)
+            self._codecs.append(CODEC_IDS[st.footer.codec])
+        self._file += [len(self._paths) - 1] * len(stats)
+        self._col += [col] * len(stats)
+        self._stats += stats
+        self._at.append(byte_offsets)
+
+    def run(self) -> bool:
+        """Decode everything queued; False = a stream failed (the caller
+        reads those chunks the slow way, which names the fault)."""
+        if not self._stats:
+            return True
+        import ctypes
+        from citus_tpu.native import get_lib
+        i32p = ctypes.POINTER(ctypes.c_int32)
+        i64p = ctypes.POINTER(ctypes.c_int64)
+
+        def arr(values, dt, ptr):
+            a = np.ascontiguousarray(values, dt)
+            return a, a.ctypes.data_as(ptr)
+
+        for d in self._dst:
+            if not (d.flags.c_contiguous and d.flags.writeable):
+                return False
+        codecs, codecs_p = arr(self._codecs, np.int32, i32p)
+        files, files_p = arr(self._file, np.int32, i32p)
+        cols, cols_p = arr(self._col, np.int32, i32p)
+        offs, offs_p = arr([s.value_offset for s in self._stats], np.int64, i64p)
+        clens, clens_p = arr([s.value_length for s in self._stats], np.int64, i64p)
+        rlens, rlens_p = arr([s.value_raw_length for s in self._stats],
+                             np.int64, i64p)
+        at, at_p = arr(np.concatenate(self._at), np.int64, i64p)
+        caps, caps_p = arr([d.nbytes for d in self._dst], np.int64, i64p)
+        paths = (ctypes.c_char_p * len(self._paths))(*self._paths)
+        ptrs = (ctypes.c_void_p * len(self._dst))(
+            *[d.ctypes.data for d in self._dst])
+        rc = get_lib().ct_decode_batch(
+            len(self._paths), paths, codecs_p, len(self._stats), files_p,
+            offs_p, clens_p, rlens_p, cols_p, at_p, len(self._dst), ptrs,
+            caps_p, decode_thread_count())
+        self.bytes = int(rlens.sum())
+        return rc == 0

@@ -157,9 +157,10 @@ def test_raw_batches_hold_at_most_max_batch_rows(shard):
         assert np.array_equal(got[valid], vals[valid]), c
 
 
-def test_a_chunk_that_fills_its_bucket_is_not_copied(tmp_path):
-    """One chunk, no padding, no cast: the batch's column IS the chunk
-    the reader returned (bigint and decimal are int64 on both sides)."""
+def test_a_chunk_is_decoded_where_the_kernel_reads_it_unless_it_casts(tmp_path):
+    """No deletes, no NULLs: ``k`` (bigint, int64 on both sides) is
+    decompressed straight into the batch's own array; ``q`` (int,
+    stored int32, device int64) is decoded apart and cast in the copy."""
     cl = ct.Cluster(str(tmp_path / "db"), settings=Settings(
         columnar=ColumnarSettings(chunk_group_row_limit=64,
                                   stripe_row_limit=64)))
@@ -174,9 +175,11 @@ def test_a_chunk_that_fills_its_bucket_is_not_copied(tmp_path):
             load_shard_batches(cl.catalog, plan, 0, max_batch_rows=64)):
         assert n == hb.n_rows == hb.padded_rows == 64
         k, q = (hb.cols[plan.scan_columns.index(c)] for c in ("k", "q"))
-        assert k.base is not None and k.dtype == values["k"].dtype   # a view
-        assert q.base is None and q.dtype == np.int64               # cast: a copy
+        assert k.base is None and k.dtype == values["k"].dtype
+        assert q.base is None and q.dtype == np.int64
         assert np.array_equal(k, values["k"]) and np.array_equal(q, values["q"])
+        assert hb.bytes_in_place == values["k"].nbytes == 64 * 8
+        assert hb.bytes_copied == values["q"].nbytes == 64 * 4
     cl.close()
 
 
