@@ -281,6 +281,8 @@ def _run_analyze(cl, stmt: A.Explain) -> list[str]:
             # native call decoded where the kernel reads them
             line += (", decoded in place "
                      f"{pl['decode_bytes_in_place'] / decoded:.3f}")
+        if tr.find("stripe_read") is not None:
+            line += ", " + _decode_split(tr)
         if "fused_dispatches" in pl:
             # the 1-dispatch-per-batch claim, visible per statement
             line += f", fused dispatches {pl['fused_dispatches']}"
@@ -327,6 +329,29 @@ def _run_analyze(cl, stmt: A.Explain) -> list[str]:
                 f"(overlapped {pl['remote_overlapped_ms']:.2f} ms, "
                 f"peak in-flight {pl['remote_inflight_peak']}{wire})")
     return lines
+
+
+def _decode_split(tr) -> str:
+    """Where the batches' decode went, from the statement's own spans
+    (the children of stripe_read and the producer's wait): the shards'
+    metadata and the footers, the batch layout, the ONE native call a
+    batch — with the share of that call's threads x time its pool spent
+    reading and decompressing — the stripe reader, and the time
+    backpressure held the decode."""
+    def ms(*names):
+        return sum(s.duration_ms for n in names for s in tr.find_all(n))
+
+    native = tr.find_all("native_decode")
+    offered = sum(s.attrs.get("threads", 0) * s.duration_ms for s in native)
+    worked = sum(s.attrs.get("read_ms", 0.0) + s.attrs.get("decompress_ms", 0.0)
+                 for s in native)
+    busy = f" (pool {100 * worked / offered:.0f} % busy)" if offered else ""
+    return (f"decode: footers {ms('shard_open', 'footer_read'):.2f} ms, "
+            f"layout {ms('batch_layout'):.2f} ms, "
+            f"native {ms('native_decode'):.2f} ms{busy}, "
+            f"fallback {ms('stripe_fallback', 'chunk_read'):.2f} ms, "
+            f"blocked {ms('wait:prefetch_full'):.2f} ms")
+
 
 def _explain_join(cl, stmt: A.Explain) -> Result:
     from citus_tpu.executor.join_executor import execute_join_select

@@ -11,13 +11,14 @@ values.
 A device scan is cut from the footers, BEFORE anything is decompressed:
 the batch's arrays are allocated first and ONE native call decodes every
 value stream of the batch where the kernel will read it.  What cannot
-arrive that way (see ``ShardReader.in_place_columns``, and a column
+arrive that way (see ``ShardReader.not_in_place``, and a column
 whose stored dtype is not its device dtype) is read stripe by stripe
 into fresh arrays and copied to the same place.
 """
 
 from __future__ import annotations
 
+import os
 from dataclasses import dataclass
 from typing import Iterator, NamedTuple, Optional
 
@@ -29,7 +30,6 @@ from citus_tpu.observability import trace as _trace
 from citus_tpu.planner.physical import PhysicalPlan
 from citus_tpu.storage import ShardReader
 from citus_tpu.storage.reader import BatchDecode, StripeScan
-import os
 
 
 @dataclass
@@ -80,7 +80,11 @@ class _ChunkRef(NamedTuple):
     def cut(self, lo: int, hi: int) -> _Piece:
         """A batch cut falls inside this chunk (at most one a cut):
         decode it alone; both batches copy their part."""
-        (b,) = self.reader.stripe_chunks(self.st, self.columns, [self.ci])
+        with _trace.span("stripe_fallback") as sp:
+            (b,) = self.reader.stripe_chunks(self.st, self.columns, [self.ci])
+            if sp.recording:
+                sp.set(chunks=1, reason="cut",
+                       bytes=int(sum(v.nbytes for v in b.values.values())))
         return _decoded(b, self.rows).cut(lo, hi)
 
 
@@ -123,7 +127,6 @@ def _shard_chunks(cat: Catalog, plan: PhysicalPlan, shard_index: int,
     key up in) as ``_ChunkRef``s for a batch to be laid out from."""
     table = plan.bound.table
     shard = table.shards[shard_index]
-    from citus_tpu.testing.faults import FAULTS
     if node_override is not None:
         nodes = [node_override]
     else:
@@ -141,33 +144,13 @@ def _shard_chunks(cat: Catalog, plan: PhysicalPlan, shard_index: int,
     # PlacementExecutionDone failover (adaptive_executor.c:96-100).  A
     # MISSING placement directory is a failed placement, not an empty
     # shard — only when no placement exists at all is the shard empty.
-    reader = None
-    for attempt, node in enumerate(nodes):
-        d = cat.shard_dir(table.name, shard.shard_id, node)
-        try:
-            FAULTS.hit("read_placement", f"{table.name}:{shard.shard_id}:{node}")
-            if not os.path.isdir(d) and cat.is_remote_node(node) \
-                    and cat.remote_data is not None:
-                rd = _pull_placement_fallback(cat, table, shard, node)
-                if rd is not None:
-                    d = rd
-            if not os.path.isdir(d):
-                if attempt + 1 < len(nodes):
-                    from citus_tpu.executor.executor import GLOBAL_COUNTERS
-                    GLOBAL_COUNTERS.bump("connection_failovers")
-                    continue
-                return  # never written on any placement: empty shard
-            from citus_tpu.storage.overlay import visible_meta
-            if visible_meta(d)["row_count"] == 0:
-                return  # authoritative: the shard is empty
-            reader = ShardReader(d, table.schema)
-            break
-        except Exception:
-            if attempt + 1 < len(nodes):
-                from citus_tpu.executor.executor import GLOBAL_COUNTERS
-                GLOBAL_COUNTERS.bump("connection_failovers")
-                continue
-            raise
+    # One shard_open span: the placement's directory, its visible
+    # metadata and the reader, before the first footer.
+    with _trace.span("shard_open") as sp:
+        reader = _open_placement(cat, table, shard, nodes)
+        if sp.recording:
+            sp.set(shard_index=int(shard_index),
+                   stripes=0 if reader is None else len(reader.stripe_files))
     if reader is None:
         return
     cols = plan.scan_columns
@@ -183,6 +166,39 @@ def _shard_chunks(cat: Catalog, plan: PhysicalPlan, shard_index: int,
         return
     for b in chunks:
         yield _Piece(b.values, b.validity, b.row_count)
+
+
+def _open_placement(cat: Catalog, table: TableMeta, shard,
+                    nodes: list) -> Optional[ShardReader]:
+    """The reader of the first placement of ``nodes`` that can be read;
+    None for a shard that is empty (never written, or no visible row)."""
+    from citus_tpu.testing.faults import FAULTS
+    for attempt, node in enumerate(nodes):
+        d = cat.shard_dir(table.name, shard.shard_id, node)
+        try:
+            FAULTS.hit("read_placement", f"{table.name}:{shard.shard_id}:{node}")
+            if not os.path.isdir(d) and cat.is_remote_node(node) \
+                    and cat.remote_data is not None:
+                rd = _pull_placement_fallback(cat, table, shard, node)
+                if rd is not None:
+                    d = rd
+            if not os.path.isdir(d):
+                if attempt + 1 < len(nodes):
+                    from citus_tpu.executor.executor import GLOBAL_COUNTERS
+                    GLOBAL_COUNTERS.bump("connection_failovers")
+                    continue
+                return None  # never written on any placement: empty shard
+            from citus_tpu.storage.overlay import visible_meta
+            if visible_meta(d)["row_count"] == 0:
+                return None  # authoritative: the shard is empty
+            return ShardReader(d, table.schema)
+        except Exception:
+            if attempt + 1 < len(nodes):
+                from citus_tpu.executor.executor import GLOBAL_COUNTERS
+                GLOBAL_COUNTERS.bump("connection_failovers")
+                continue
+            raise
+    return None
 
 
 def _cut_batches(cat: Catalog, plan: PhysicalPlan, shard_index: int,
@@ -206,7 +222,10 @@ def _cut_batches(cat: Catalog, plan: PhysicalPlan, shard_index: int,
     # it model slow host-side decompression overlapping device compute.
     # Spans: one stripe_read per batch (footers, file read + decompress
     # of every chunk it takes), never one per chunk, and none held
-    # across the yield.
+    # across the yield.  Its children name the work from inside:
+    # footer_read per stripe (the reader's generators close it before
+    # they yield), batch_layout / native_decode / stripe_fallback per
+    # batch (_Landing), stripe_fallback(reason=cut) for a cut chunk.
     rest = None            # what the last cut left of its chunk
     exhausted = False
     while not exhausted:
@@ -294,7 +313,12 @@ class _Landing:
     straight into place where the reader allows it and the dtypes
     agree, the others stripe by stripe into fresh arrays.  ``finish``
     (the pad span) copies what did not land in place and fills the
-    validity, the row mask and the tail."""
+    validity, the row mask and the tail.
+
+    Three spans say where the making's time goes: ``batch_layout``
+    (allocation, what lands how, the native call's arguments),
+    ``native_decode`` (``BatchDecode.run``: the thread asleep on its
+    pool) and ``stripe_fallback`` (the stripe reader, with why)."""
 
     def __init__(self, plan: PhysicalPlan, parts: list, n_rows: int,
                  padded_rows: int):
@@ -302,53 +326,85 @@ class _Landing:
         self.columns = plan.scan_columns
         self._index = {c: k for k, c in enumerate(self.columns)}
         self.n_rows, self.padded_rows = n_rows, padded_rows
-        self.values = [np.empty(padded_rows, schema.scan_dtype(c, device=True))
-                       for c in self.columns]
-        # only a column stored as the device reads it can land in place
-        self._same_dtype = [c for c, v in zip(self.columns, self.values)
-                            if schema.scan_dtype(c) == v.dtype]
         self.copies: list[tuple[int, _Piece]] = []   # (first row, piece)
         self.bytes_in_place = 0
-        groups = []        # (first row, the refs of consecutive chunks of ONE stripe)
-        at = 0
-        for p in parts:
-            if isinstance(p, _Piece):
-                self.copies.append((at, p))
-            elif groups and groups[-1][1][-1].st is p.st:
-                groups[-1][1].append(p)
-            else:
-                groups.append((at, [p]))
-            at += p.rows
-        if groups and not self._read(groups, in_place=True):
+        with _trace.span("batch_layout") as sp:
+            self.values = [
+                np.empty(padded_rows, schema.scan_dtype(c, device=True))
+                for c in self.columns]
+            # only a column stored as the device reads it can land in place
+            self._same_dtype = [c for c, v in zip(self.columns, self.values)
+                                if schema.scan_dtype(c) == v.dtype]
+            groups = []    # (first row, the refs of consecutive chunks of ONE stripe)
+            at = 0
+            for p in parts:
+                if isinstance(p, _Piece):
+                    self.copies.append((at, p))
+                elif groups and groups[-1][1][-1].st is p.st:
+                    groups[-1][1].append(p)
+                else:
+                    groups.append((at, [p]))
+                at += p.rows
+            decode, slow = self._lay_out(groups, in_place=True)
+            decode.prepare()
+            if sp.recording:
+                sp.set(streams=decode.streams, files=decode.files,
+                       bytes_alloc=int(sum(v.nbytes for v in self.values)))
+        if decode.run():
+            self.bytes_in_place = decode.bytes
+        else:
             # a stream failed: the stripe readers name the fault, or
             # fall back to the Python codecs, as they always did
-            self._read(groups, in_place=False)
+            _, slow = self._lay_out(groups, in_place=False)
+        self._fall_back(slow)
 
-    def _read(self, groups: list, in_place: bool) -> bool:
+    def _lay_out(self, groups: list, in_place: bool):
+        """-> (the BatchDecode of every stream that lands in place, and
+        what does not: ``[(reader, stripe, columns, refs, first rows,
+        why)]`` for the stripe reader)."""
         decode = BatchDecode(self.values)
-        copies = []
+        slow = []
         for at, refs in groups:
             reader, st = refs[0].reader, refs[0].st
             chunks = [r.ci for r in refs]
             rows = np.array([r.rows for r in refs], np.int64)
             starts = at + np.cumsum(rows) - rows
-            direct = reader.in_place_columns(st, chunks, self._same_dtype) \
-                if in_place else []
-            for c in direct:
+            if in_place:
+                why = {c: "cast" for c in self.columns
+                       if c not in self._same_dtype}
+                why.update(reader.not_in_place(st, chunks, self._same_dtype))
+            else:
+                why = dict.fromkeys(self.columns, "codec")
+            for c in self.columns:
+                if c in why:
+                    continue
                 k = self._index[c]
                 stats = st.footer.columns[reader.schema.scan_storage_name(c)]
                 decode.add(st, [stats[ci] for ci in chunks], k,
                            starts * self.values[k].itemsize)
-            rest = [c for c in self.columns if c not in direct]
-            if rest:
-                for s, r, b in zip(starts, refs,
-                                   reader.stripe_chunks(st, rest, chunks)):
-                    copies.append((int(s), _decoded(b, r.rows)))
-        if not decode.run():
-            return False
-        self.bytes_in_place = decode.bytes
-        self.copies += copies
-        return True
+            if why:
+                slow.append((reader, st, [c for c in self.columns if c in why],
+                             refs, starts, sorted(set(why.values()))))
+        return decode, slow
+
+    def _fall_back(self, slow: list) -> None:
+        """Read what could not land in place stripe by stripe; ``pad``
+        copies it in."""
+        if not slow:
+            return
+        with _trace.span("stripe_fallback") as sp:
+            chunks = nbytes = 0
+            reasons = set()
+            for reader, st, columns, refs, starts, why in slow:
+                reasons.update(why)
+                for s, r, b in zip(starts, refs, reader.stripe_chunks(
+                        st, columns, [r.ci for r in refs])):
+                    self.copies.append((int(s), _decoded(b, r.rows)))
+                    chunks += 1
+                    nbytes += sum(v.nbytes for v in b.values.values())
+            if sp.recording:
+                sp.set(chunks=chunks, bytes=int(nbytes),
+                       reason=",".join(sorted(reasons)))
 
     def finish(self, shard_index: int) -> ShardBatch:
         n_rows, padded_rows = self.n_rows, self.padded_rows

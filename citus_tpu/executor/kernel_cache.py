@@ -98,6 +98,7 @@ class _TimedJit:
                     t1 = clock()
                     _counters().bump("kernel_compile_ms",
                                      max(1, int((t1 - t0) * 1000)))
+                    _counters().bump("kernel_compiles")
                     # compiles are detected after the fact (the trace
                     # cache grew across the call) — record retroactively
                     ctx = _trace.current()

@@ -149,17 +149,27 @@ class HostPrefetcher:
 
     # ---- producer (decode thread) ----
     def _put(self, item) -> bool:
+        try:
+            self._q.put_nowait(item)
+            return True
+        except queue.Full:
+            pass
+        # device behind: backpressure holds the decode thread, which
+        # says so (wait:prefetch_full) from this first Full on
+        wtok = begin_wait("prefetch_full")
         stalled = False
-        while not self._cancel.is_set():
-            try:
-                self._q.put(item, timeout=0.05)
-                return True
-            except queue.Full:
-                if not stalled and self._stats is not None:
-                    # device behind: backpressure holds the decode
-                    self._stats.device_stalls += 1
-                    stalled = True
-        return False
+        try:
+            while not self._cancel.is_set():
+                try:
+                    self._q.put(item, timeout=0.05)
+                    return True
+                except queue.Full:
+                    if not stalled and self._stats is not None:
+                        self._stats.device_stalls += 1
+                        stalled = True
+            return False
+        finally:
+            end_wait(wtok)
 
     def _produce(self) -> None:
         from citus_tpu.storage.overlay import transaction_overlay

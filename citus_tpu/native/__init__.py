@@ -22,6 +22,10 @@ LIB = None
 
 CODEC_IDS = {"none": 0, "zstd": 1, "lz4": 2, "zlib": 3}
 
+#: what ``ct_decode_batch`` reports about its pool through its nullable
+#: ``stats`` out-array, in the array's order (columnar_native.cpp)
+DECODE_STATS = ("read_ms", "decompress_ms", "busy_max_ms", "threads")
+
 
 def _try_build() -> bool:
     src = os.path.join(_HERE, "columnar_native.cpp")
@@ -55,7 +59,8 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.ct_decode_batch.restype = i64
     lib.ct_decode_batch.argtypes = [
         i32, ctypes.POINTER(ctypes.c_char_p), i32p, i64, i32p, i64p, i64p,
-        i64p, i32p, i64p, i32, ctypes.POINTER(ctypes.c_void_p), i64p, i32]
+        i64p, i32p, i64p, i32, ctypes.POINTER(ctypes.c_void_p), i64p, i32,
+        ctypes.POINTER(ctypes.c_double)]
     lib.ct_unpack_bits.restype = None
     lib.ct_unpack_bits.argtypes = [u8p, i64, u8p]
     lib.ct_version.restype = i32

@@ -9,6 +9,7 @@
 // via ctypes (no pybind11 dependency).
 
 #include <atomic>
+#include <chrono>
 #include <cstdint>
 #include <cstdio>
 #include <cstring>
@@ -192,6 +193,14 @@ int64_t ct_read_streams_mt(const char* path, int32_t codec, int64_t n,
 // it belongs, with no scratch at all.
 // returns 0 on success, -(1+i) identifying the failing stream,
 // -1000000 - f for a file that does not open.
+//
+// ``stats`` (nullable, DECODE_STATS doubles) is what a tracing caller
+// asks the pool about itself: [0] read_ms and [1] decompress_ms, thread
+// time summed over the workers (a codec "none" stream is all read);
+// [2] busy_max_ms, the slowest worker's time from its first claim to
+// its exit; [3] the workers that ran.  With NULL no clock is read.
+
+enum { DECODE_STATS = 4 };
 
 int64_t ct_decode_batch(int32_t n_files, const char* const* paths,
                         const int32_t* file_codecs, int64_t n,
@@ -199,7 +208,14 @@ int64_t ct_decode_batch(int32_t n_files, const char* const* paths,
                         const int64_t* comp_lens, const int64_t* raw_lens,
                         const int32_t* dst_col, const int64_t* dst_offs,
                         int32_t n_dst, uint8_t* const* dst_ptrs,
-                        const int64_t* dst_caps, int32_t n_threads) {
+                        const int64_t* dst_caps, int32_t n_threads,
+                        double* stats) {
+    using clk = std::chrono::steady_clock;
+    const bool timed = stats != nullptr;
+    struct WorkerStats { double read_ms = 0, decompress_ms = 0, busy_ms = 0; };
+    auto ms_since = [](clk::time_point t0) {
+        return std::chrono::duration<double, std::milli>(clk::now() - t0).count();
+    };
     std::vector<int> fds((size_t)(n_files > 0 ? n_files : 0), -1);
     auto close_all = [&]() {
         for (int fd : fds) if (fd >= 0) close(fd);
@@ -218,9 +234,11 @@ int64_t ct_decode_batch(int32_t n_files, const char* const* paths,
         }
         return true;
     };
-    auto worker = [&]() {
+    auto worker = [&](WorkerStats* ws) {
         std::vector<uint8_t> scratch;
         ZSTD_DCtx* dctx = nullptr;
+        clk::time_point w0, t0;
+        if (timed) w0 = clk::now();
         while (err.load(std::memory_order_relaxed) == 0) {
             int64_t i = next.fetch_add(1);
             if (i >= n) break;
@@ -232,14 +250,17 @@ int64_t ct_decode_batch(int32_t n_files, const char* const* paths,
             if (ok) {
                 uint8_t* dst = dst_ptrs[c] + dst_offs[i];
                 int32_t codec = file_codecs[f];
+                if (timed) t0 = clk::now();
                 if (codec == CODEC_NONE) {
                     ok = comp_lens[i] == raw_lens[i] &&
                          read_all(fds[f], dst, raw_lens[i], offsets[i]);
+                    if (timed) ws->read_ms += ms_since(t0);
                 } else {
                     if ((int64_t)scratch.size() < comp_lens[i])
                         scratch.resize((size_t)comp_lens[i]);
                     ok = read_all(fds[f], scratch.data(), comp_lens[i],
                                   offsets[i]);
+                    if (timed) { ws->read_ms += ms_since(t0); t0 = clk::now(); }
                     if (ok && codec == CODEC_ZSTD) {
                         if (!dctx) dctx = ZSTD_createDCtx();
                         size_t got = dctx ? ZSTD_decompressDCtx(
@@ -250,6 +271,7 @@ int64_t ct_decode_batch(int32_t n_files, const char* const* paths,
                         ok = ct_decompress(codec, scratch.data(), comp_lens[i],
                                            dst, raw_lens[i]) == raw_lens[i];
                     }
+                    if (timed) ws->decompress_ms += ms_since(t0);
                 }
             }
             if (!ok) {
@@ -259,17 +281,29 @@ int64_t ct_decode_batch(int32_t n_files, const char* const* paths,
             }
         }
         if (dctx) ZSTD_freeDCtx(dctx);
+        if (timed) ws->busy_ms = ms_since(w0);
     };
     int nt = n_threads < 1 ? 1 : (n_threads > 16 ? 16 : n_threads);
     if ((int64_t)nt > n) nt = (int)n;
+    if (nt < 1) nt = 1;
+    std::vector<WorkerStats> per_worker((size_t)nt);
     if (nt <= 1) {
-        worker();
+        worker(&per_worker[0]);
     } else {
         std::vector<std::thread> threads;
-        for (int t = 0; t < nt; t++) threads.emplace_back(worker);
+        for (int t = 0; t < nt; t++) threads.emplace_back(worker, &per_worker[t]);
         for (auto& t : threads) t.join();
     }
     close_all();
+    if (timed) {
+        for (int k = 0; k < DECODE_STATS; k++) stats[k] = 0.0;
+        for (const WorkerStats& ws : per_worker) {
+            stats[0] += ws.read_ms;
+            stats[1] += ws.decompress_ms;
+            if (ws.busy_ms > stats[2]) stats[2] = ws.busy_ms;
+        }
+        stats[3] = (double)nt;
+    }
     return err.load();
 }
 

@@ -112,6 +112,8 @@ METRIC_HELP = {
     "wait_remote_rpc_ms": "ms blocked on remote RPC round trips",
     "wait_lock_ms": "ms blocked acquiring advisory locks",
     "wait_prefetch_stall_ms": "ms the device starved for host decode",
+    "wait_prefetch_full_ms": "ms the decode thread held a batch the device was not ready for",
+    "kernel_compiles": "jitted calls that traced and compiled (cache misses and retraces for a new shape)",
     "wait_device_round_ms": "ms blocked on device round backpressure",
     "wait_2pc_decision_ms": "ms blocked on 2PC decision round trips",
     "stat_fanout_probes": "get_node_stats probes issued by this node",

@@ -73,6 +73,9 @@ class StatCounters:
         "kernel_cache_hits",
         "kernel_cache_misses",
         "kernel_compile_ms",
+        # every trace+compile a jitted call paid, a retrace of a cached
+        # kernel for a new batch shape included (no cache miss)
+        "kernel_compiles",
         # HBM-resident batch cache (executor/device_cache.py)
         "device_cache_hits",
         "device_cache_misses",
@@ -102,6 +105,7 @@ class StatCounters:
         "wait_remote_rpc_ms",
         "wait_lock_ms",
         "wait_prefetch_stall_ms",
+        "wait_prefetch_full_ms",
         "wait_device_round_ms",
         "wait_2pc_decision_ms",
         "wait_megabatch_ms",
@@ -286,6 +290,10 @@ WAIT_COUNTERS = {
     "remote_rpc": "wait_remote_rpc_ms",
     "lock": "wait_lock_ms",
     "prefetch_stall": "wait_prefetch_stall_ms",
+    # the other side of that queue: the decode thread holding a batch
+    # the consumer is ``depth`` batches away from taking
+    # (executor/pipeline.py HostPrefetcher._put)
+    "prefetch_full": "wait_prefetch_full_ms",
     "device_round": "wait_device_round_ms",
     "2pc_decision": "wait_2pc_decision_ms",
     # parked in a coalescing window (executor/megabatch.py) — a

@@ -18,6 +18,7 @@ from typing import Iterator, Optional
 import numpy as np
 
 from citus_tpu.errors import AnalysisError, StorageError
+from citus_tpu.observability import trace as _trace
 from citus_tpu.schema import Schema
 from citus_tpu.storage.format import (
     StripeFooter, read_chunk, read_stripe_footer,
@@ -138,7 +139,21 @@ class ShardReader:
         restricts to a stripe-file subset (index-lookup fallback)."""
         for st in self.scan_stripes(columns, constraints, apply_deletes,
                                     only_stripes):
-            yield from self.stripe_chunks(st, columns, st.chunks)
+            # as lazy as ``yield from``, and one span a stripe, never one
+            # a chunk: chunk_read is the reader's first step, under which
+            # the native reader brings the stripe's selected chunks in
+            # (the Python reader its first; its later chunks are the
+            # caller's own time), closed before a batch is handed out
+            batches = self.stripe_chunks(st, columns, st.chunks)
+            with _trace.span("chunk_read") as sp:
+                first = next(batches, None)
+                if sp.recording:
+                    sp.set(chunks=len(st.chunks),
+                           rows=sum(st.footer.chunk_row_counts[ci]
+                                    for ci in st.chunks))
+            if first is not None:
+                yield first
+                yield from batches
 
     def scan_stripes(
         self,
@@ -155,36 +170,49 @@ class ShardReader:
         constraints = constraints or []
         for col in columns:
             self.schema.scan_column(col)  # validate projection
-        delete_cache = visible_deletes(self.directory) if apply_deletes else {}
+        delete_cache = None if apply_deletes else {}
         for stripe in self.meta["stripes"]:
             if only_stripes is not None and stripe["file"] not in only_stripes:
                 continue
             path = os.path.join(self.directory, stripe["file"])
-            footer = read_stripe_footer(path)
-            selected = self._selected_chunks(footer, constraints)
-            try:
-                from citus_tpu.executor.executor import GLOBAL_COUNTERS
-                GLOBAL_COUNTERS.bump("chunks_total", footer.chunk_count)
-                GLOBAL_COUNTERS.bump("chunks_selected", int(selected.sum()))
-                # rows refuted by footer min/max BEFORE any stream bytes
-                # of theirs are read or decompressed — the fused hot
-                # loop's admission win
-                skipped = int(np.asarray(
-                    footer.chunk_row_counts)[~selected].sum())
-                if skipped:
-                    GLOBAL_COUNTERS.bump("fused_rows_skipped", skipped)
-            except ImportError:
-                pass
-            if not selected.any():
-                continue
-            offsets = np.concatenate([[0], np.cumsum(footer.chunk_row_counts)[:-1]])
-            del_mask = None
-            if apply_deletes and stripe["file"] in delete_cache:
-                del_mask = deleted_mask(self.directory, stripe["file"],
-                                        footer.row_count, delete_cache)
-            yield StripeScan(stripe["file"], path, footer,
-                             [int(i) for i in np.nonzero(selected)[0]],
-                             offsets, del_mask)
+            # one footer_read per stripe, closed before the yield: the
+            # footer's JSON, the chunk pruning and the deletion bitmap
+            # (the shard's map of deletes is read under the first one)
+            with _trace.span("footer_read") as sp:
+                if delete_cache is None:
+                    delete_cache = visible_deletes(self.directory)
+                footer = read_stripe_footer(path)
+                selected = self._selected_chunks(footer, constraints)
+                try:
+                    from citus_tpu.executor.executor import GLOBAL_COUNTERS
+                    GLOBAL_COUNTERS.bump("chunks_total", footer.chunk_count)
+                    GLOBAL_COUNTERS.bump("chunks_selected", int(selected.sum()))
+                    # rows refuted by footer min/max BEFORE any stream bytes
+                    # of theirs are read or decompressed — the fused hot
+                    # loop's admission win
+                    skipped = int(np.asarray(
+                        footer.chunk_row_counts)[~selected].sum())
+                    if skipped:
+                        GLOBAL_COUNTERS.bump("fused_rows_skipped", skipped)
+                except ImportError:
+                    pass
+                st = None
+                if selected.any():
+                    offsets = np.concatenate(
+                        [[0], np.cumsum(footer.chunk_row_counts)[:-1]])
+                    del_mask = None
+                    if apply_deletes and stripe["file"] in delete_cache:
+                        del_mask = deleted_mask(self.directory, stripe["file"],
+                                                footer.row_count, delete_cache)
+                    st = StripeScan(stripe["file"], path, footer,
+                                    [int(i) for i in np.nonzero(selected)[0]],
+                                    offsets, del_mask)
+                if sp.recording:
+                    sp.set(chunks=int(footer.chunk_count),
+                           selected=int(selected.sum()),
+                           deletes=st is not None and st.del_mask is not None)
+            if st is not None:
+                yield st
 
     def stripe_chunks(self, st: StripeScan, columns: list[str],
                       chunks: list[int]) -> Iterator[ChunkBatch]:
@@ -241,31 +269,47 @@ class ShardReader:
             GLOBAL_COUNTERS = None
         delete_cache = visible_deletes(self.directory)
         fallback: set = set()
+        # per stripe: index_probe, footer_read and (where a row is
+        # left) chunk_read, each closed before the stripe's rows are
+        # yielded
         for stripe in self.meta["stripes"]:
-            pos = positions_eq(self.directory, stripe["file"], column, value)
+            with _trace.span("index_probe") as sp:
+                pos = positions_eq(self.directory, stripe["file"], column,
+                                   value)
+                if sp.recording:
+                    sp.set(positions=-1 if pos is None else int(pos.size))
             if pos is None:
                 fallback.add(stripe["file"])
                 continue
             path = os.path.join(self.directory, stripe["file"])
-            footer = read_stripe_footer(path)
-            if GLOBAL_COUNTERS is not None:
-                GLOBAL_COUNTERS.bump("index_lookups")
-                GLOBAL_COUNTERS.bump("chunks_total", footer.chunk_count)
-            if pos.size == 0:
+            with _trace.span("footer_read") as sp:
+                footer = read_stripe_footer(path)
+                if GLOBAL_COUNTERS is not None:
+                    GLOBAL_COUNTERS.bump("index_lookups")
+                    GLOBAL_COUNTERS.bump("chunks_total", footer.chunk_count)
+                deletes = False
+                if pos.size and stripe["file"] in delete_cache:
+                    dm = deleted_mask(self.directory, stripe["file"],
+                                      footer.row_count, delete_cache)
+                    if dm is not None:
+                        deletes = True
+                        pos = pos[~dm[pos]]
+                needed = ()
+                if pos.size:
+                    bounds = np.concatenate(
+                        [[0], np.cumsum(footer.chunk_row_counts)])
+                    chunk_of = np.searchsorted(bounds, pos, "right") - 1
+                    needed = np.unique(chunk_of)
+                    if GLOBAL_COUNTERS is not None:
+                        GLOBAL_COUNTERS.bump("chunks_selected",
+                                             int(needed.size))
+                if sp.recording:
+                    sp.set(chunks=int(footer.chunk_count),
+                           selected=len(needed), deletes=deletes)
+            if not len(needed):
                 continue
-            if stripe["file"] in delete_cache:
-                dm = deleted_mask(self.directory, stripe["file"],
-                                  footer.row_count, delete_cache)
-                if dm is not None:
-                    pos = pos[~dm[pos]]
-                    if pos.size == 0:
-                        continue
-            bounds = np.concatenate([[0], np.cumsum(footer.chunk_row_counts)])
-            chunk_of = np.searchsorted(bounds, pos, "right") - 1
-            needed = np.unique(chunk_of)
-            if GLOBAL_COUNTERS is not None:
-                GLOBAL_COUNTERS.bump("chunks_selected", int(needed.size))
-            with open(path, "rb") as fh:
+            found = []
+            with _trace.span("chunk_read") as sp, open(path, "rb") as fh:
                 for ci in needed:
                     local = np.sort(pos[chunk_of == ci]) - bounds[ci]
                     vals, valid = {}, {}
@@ -282,10 +326,14 @@ class ShardReader:
                                           c.type.storage_dtype)
                         vals[col] = v[local]
                         valid[col] = None if m is None else m[local]
-                    yield ChunkBatch(values=vals, validity=valid,
-                                     row_count=int(local.size),
-                                     stripe_file=stripe["file"],
-                                     chunk_index=int(ci))
+                    found.append(ChunkBatch(values=vals, validity=valid,
+                                            row_count=int(local.size),
+                                            stripe_file=stripe["file"],
+                                            chunk_index=int(ci)))
+                if sp.recording:
+                    sp.set(chunks=len(found),
+                           rows=sum(b.row_count for b in found))
+            yield from found
         if fallback:
             yield from self.scan(columns, constraints,
                                  only_stripes=fallback)
@@ -391,30 +439,36 @@ class ShardReader:
                 stripe_file=os.path.basename(path), chunk_index=ci))
         return out_batches
 
-    def in_place_columns(self, st: StripeScan, chunks: list[int],
-                         columns: list[str]) -> list[str]:
-        """Of ``columns``, those whose value streams of ``chunks`` may be
-        decoded where a caller wants them (``BatchDecode``), decided
-        from what the footer shows: the native library has the stripe's
-        codec, no row of the stripe is deleted, the column was there
-        when the stripe was written, and every stream is NULL-free (a
-        validity bitmap needs unpacking) and exactly its rows long.
-        Whatever is left out arrives through ``stripe_chunks``."""
+    def not_in_place(self, st: StripeScan, chunks: list[int],
+                     columns: list[str]) -> dict[str, str]:
+        """``{column: why}`` for the ``columns`` whose value streams of
+        ``chunks`` cannot be decoded where a caller wants them
+        (``BatchDecode``) and arrive through ``stripe_chunks``, decided
+        from what the footer shows: ``codec`` (the native library lacks
+        the stripe's codec or is missing, or a stream is not exactly its
+        rows long), ``deletes`` (a row of the stripe is deleted),
+        ``late_column`` (the column was added after the stripe was
+        written), ``nulls`` (a stream has a validity bitmap, which needs
+        unpacking)."""
         from citus_tpu.native import CODEC_IDS, get_lib
-        if st.del_mask is not None or get_lib() is None \
-                or st.footer.codec not in CODEC_IDS:
-            return []
-        out = []
+        if get_lib() is None or st.footer.codec not in CODEC_IDS:
+            return dict.fromkeys(columns, "codec")
+        if st.del_mask is not None:
+            return dict.fromkeys(columns, "deletes")
+        out = {}
         for col in columns:
             stats = st.footer.columns.get(self.schema.scan_storage_name(col))
             if stats is None:
+                out[col] = "late_column"
                 continue
             width = self.schema.scan_dtype(col).itemsize
-            if all(not stats[ci].has_nulls
-                   and stats[ci].row_count == st.footer.chunk_row_counts[ci]
-                   and stats[ci].value_raw_length == stats[ci].row_count * width
-                   for ci in chunks):
-                out.append(col)
+            if any(stats[ci].has_nulls for ci in chunks):
+                out[col] = "nulls"
+            elif not all(
+                    stats[ci].row_count == st.footer.chunk_row_counts[ci]
+                    and stats[ci].value_raw_length == stats[ci].row_count * width
+                    for ci in chunks):
+                out[col] = "codec"
         return out
 
     def chunk_counts(self, constraints: Optional[list[Interval]] = None) -> tuple[int, int]:
@@ -465,7 +519,17 @@ class BatchDecode:
         self._col: list[int] = []
         self._stats: list = []
         self._at: list[np.ndarray] = []
+        self._args = None                 # prepare()'s, and the arrays
+        self._keep: list = []             # their pointers borrow
         self.bytes = 0                    # decompressed bytes gathered
+
+    @property
+    def streams(self) -> int:
+        return len(self._stats)
+
+    @property
+    def files(self) -> int:
+        return len(self._paths)
 
     def add(self, st: StripeScan, stats: list, col: int,
             byte_offsets: np.ndarray) -> None:
@@ -481,38 +545,58 @@ class BatchDecode:
         self._stats += stats
         self._at.append(byte_offsets)
 
-    def run(self) -> bool:
-        """Decode everything queued; False = a stream failed (the caller
-        reads those chunks the slow way, which names the fault)."""
-        if not self._stats:
-            return True
+    def prepare(self) -> None:
+        """Marshal what was queued into the native call's argument
+        arrays (once): the last of the batch's layout, apart from
+        ``run`` so that a tracing caller can time it there."""
+        if self._args is not None or not self._stats:
+            return
         import ctypes
-        from citus_tpu.native import get_lib
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
 
         def arr(values, dt, ptr):
             a = np.ascontiguousarray(values, dt)
-            return a, a.ctypes.data_as(ptr)
+            self._keep.append(a)
+            return a.ctypes.data_as(ptr)
 
-        for d in self._dst:
-            if not (d.flags.c_contiguous and d.flags.writeable):
-                return False
-        codecs, codecs_p = arr(self._codecs, np.int32, i32p)
-        files, files_p = arr(self._file, np.int32, i32p)
-        cols, cols_p = arr(self._col, np.int32, i32p)
-        offs, offs_p = arr([s.value_offset for s in self._stats], np.int64, i64p)
-        clens, clens_p = arr([s.value_length for s in self._stats], np.int64, i64p)
-        rlens, rlens_p = arr([s.value_raw_length for s in self._stats],
-                             np.int64, i64p)
-        at, at_p = arr(np.concatenate(self._at), np.int64, i64p)
-        caps, caps_p = arr([d.nbytes for d in self._dst], np.int64, i64p)
+        rlens = np.array([s.value_raw_length for s in self._stats], np.int64)
+        self.bytes = int(rlens.sum())
         paths = (ctypes.c_char_p * len(self._paths))(*self._paths)
         ptrs = (ctypes.c_void_p * len(self._dst))(
             *[d.ctypes.data for d in self._dst])
-        rc = get_lib().ct_decode_batch(
-            len(self._paths), paths, codecs_p, len(self._stats), files_p,
-            offs_p, clens_p, rlens_p, cols_p, at_p, len(self._dst), ptrs,
-            caps_p, decode_thread_count())
-        self.bytes = int(rlens.sum())
+        self._args = (
+            len(self._paths), paths, arr(self._codecs, np.int32, i32p),
+            len(self._stats), arr(self._file, np.int32, i32p),
+            arr([s.value_offset for s in self._stats], np.int64, i64p),
+            arr([s.value_length for s in self._stats], np.int64, i64p),
+            arr(rlens, np.int64, i64p), arr(self._col, np.int32, i32p),
+            arr(np.concatenate(self._at), np.int64, i64p), len(self._dst),
+            ptrs, arr([d.nbytes for d in self._dst], np.int64, i64p),
+            decode_thread_count())
+
+    def run(self) -> bool:
+        """Decode everything queued; False = a stream failed (the caller
+        reads those chunks the slow way, which names the fault).  The
+        native call alone is the ``native_decode`` span; only while that
+        span records is the pool asked to time itself."""
+        if not self._stats:
+            return True
+        if not all(d.flags.c_contiguous and d.flags.writeable
+                   for d in self._dst):
+            return False
+        self.prepare()
+        import ctypes
+        from citus_tpu.native import DECODE_STATS, get_lib
+        with _trace.span("native_decode") as sp:
+            pool = (ctypes.c_double * len(DECODE_STATS))() \
+                if sp.recording else None
+            rc = get_lib().ct_decode_batch(*self._args, pool)
+            if pool is not None:
+                sp.set(streams=self.streams, files=self.files,
+                       bytes_comp=int(sum(s.value_length
+                                          for s in self._stats)),
+                       bytes_raw=self.bytes,
+                       **{k: int(v) if k == "threads" else round(v, 3)
+                          for k, v in zip(DECODE_STATS, pool)})
         return rc == 0

@@ -8,6 +8,7 @@ this mechanism does not touch) cast and padded by hand.  Whatever the
 geometry and whatever a stripe holds, the two must agree byte for byte.
 """
 
+import contextlib
 import decimal
 import os
 import threading
@@ -331,14 +332,14 @@ def test_a_footer_that_lies_about_a_length_is_not_decoded_in_place(tmp_path):
         r = rd.ShardReader(cl.catalog.shard_dir(
             "t", shard.shard_id, shard.placements[0]), plan.bound.table.schema)
         st = next(r.scan_stripes(["k", "v"]))
-        assert r.in_place_columns(st, st.chunks, ["k", "v"]) == ["k", "v"]
+        assert list(r.not_in_place(st, st.chunks, ["k", "v"])) == []
         st.footer.columns["v"][1].value_raw_length -= 8
-        assert r.in_place_columns(st, st.chunks, ["k", "v"]) == ["k"]
-        assert r.in_place_columns(st, [0, 2], ["k", "v"]) == ["k", "v"]
+        assert list(r.not_in_place(st, st.chunks, ["k", "v"])) == ["v"]
+        assert list(r.not_in_place(st, [0, 2], ["k", "v"])) == []
         st.footer.columns["k"][0].has_nulls = True
-        assert r.in_place_columns(st, st.chunks, ["k", "v"]) == []
+        assert list(r.not_in_place(st, st.chunks, ["k", "v"])) == ["k", "v"]
         st.del_mask = np.zeros(st.footer.row_count, bool)
-        assert r.in_place_columns(st, [2], ["k", "v"]) == []
+        assert list(r.not_in_place(st, [2], ["k", "v"])) == ["k", "v"]
     finally:
         cl.close()
 
@@ -463,3 +464,154 @@ def test_the_counters_are_exported(tmp_path):
     from citus_tpu.stats import StatCounters
     for name in ("decode_bytes_in_place", "decode_bytes_copied"):
         assert name in StatCounters.COUNTERS and name in METRIC_HELP
+
+
+# ------------------------------------------- what the pool says of itself
+
+
+def _under_a_trace(cl, work):
+    """Run ``work()`` under a forced trace on this thread -> (its result,
+    the trace)."""
+    from citus_tpu.observability import trace as T
+    qt = T.begin_query("test", cl.settings.observability, force=True)
+    try:
+        out = work()
+    finally:
+        qt.finish()
+    return out, qt.trace
+
+
+@pytest.mark.parametrize("codec,threads", [("zstd", 1), ("zstd", 8),
+                                           ("none", 4)])
+def test_the_stats_array_changes_no_decoded_byte(tmp_path, monkeypatch,
+                                                 codec, threads):
+    """The native call with its stats array (a recording native_decode
+    span) lands the same bytes as without, and what it reports adds up:
+    bytes_raw is BatchDecode.bytes, the pool's read + decompress time is
+    positive, its workers are at most the threads asked for."""
+    monkeypatch.setattr(rd, "_DECODE_THREADS", threads)
+    cl = _cluster(tmp_path, 64, 256, codec)
+    try:
+        cl.execute("CREATE TABLE t (k bigint NOT NULL, v bigint)")
+        cl.execute("SELECT create_distributed_table('t', 'k', 1)")
+        rng = np.random.default_rng(11)
+        cl.copy_from("t", columns={"k": np.arange(3000),
+                                   "v": rng.integers(0, 2**40, 3000)})
+        plan = _plan(cl, "SELECT k, v FROM t")
+
+        def load():
+            return list(load_padded_batches(
+                cl.catalog, plan, 0, min_batch_rows=MIN_ROWS,
+                max_batch_rows=1024))
+
+        plain = load()
+        traced, tr = _under_a_trace(cl, load)
+        assert len(plain) == len(traced) == 3
+        for a, b in zip(plain, traced):
+            assert a.bytes_in_place == b.bytes_in_place > 0
+            for x, y in zip(a.cols + a.valids + (a.row_mask,),
+                            b.cols + b.valids + (b.row_mask,)):
+                assert x.tobytes() == y.tobytes()
+        calls = tr.find_all("native_decode")
+        assert len(calls) == 3
+        assert [s.attrs["bytes_raw"] for s in calls] == \
+            [b.bytes_in_place for b in traced]
+        for s, b in zip(calls, traced):
+            assert s.attrs["read_ms"] + s.attrs["decompress_ms"] > 0
+            assert (s.attrs["decompress_ms"] == 0) == (codec == "none")
+            assert 1 <= s.attrs["threads"] <= threads
+            assert s.attrs["busy_max_ms"] <= s.duration_ms
+            assert s.attrs["streams"] == 2 * -(-b.n_rows // 64)
+            assert s.attrs["files"] == -(-b.n_rows // 256)
+            assert s.attrs["bytes_comp"] > 0
+    finally:
+        cl.close()
+
+
+def test_batchdecode_reports_bytes_raw_as_its_own_count(tmp_path):
+    """The hand-made streams of ``_decode``, under a trace: bytes_raw of
+    the span is ``BatchDecode.bytes``; a refused stream still closes the
+    span and reports nothing decoded in place."""
+    path = str(tmp_path / "f")
+    payload = np.arange(64, dtype=np.int64)
+    with open(path, "wb") as fh:
+        fh.write(payload.tobytes())
+    cl = _cluster(tmp_path, 64, 256)
+    try:
+        a = np.zeros(64, np.int64)
+        (ok, d), tr = _under_a_trace(cl, lambda: _decode(
+            [a], [(0, 256, 256, 0, 256), (256, 256, 256, 0, 0)], path))
+        assert ok and np.array_equal(a, np.r_[payload[32:], payload[:32]])
+        (span,) = tr.find_all("native_decode")
+        assert span.attrs["bytes_raw"] == d.bytes == 512
+        assert span.attrs["streams"] == 2 and span.attrs["files"] == 1
+        assert span.attrs["read_ms"] > 0 and span.attrs["decompress_ms"] == 0
+        (ok, _d), tr = _under_a_trace(cl, lambda: _decode(
+            [a], [(0, 256, 256, 0, 512)], path))
+        assert not ok and tr.find("native_decode").t1 is not None
+    finally:
+        cl.close()
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_the_raw_scan_reads_only_as_far_as_it_is_asked(tmp_path, monkeypatch,
+                                                       traced):
+    """``ShardReader.scan`` is as lazy traced as untraced: a caller that
+    takes one chunk of the Python reader pays for one, and the stripe's
+    chunk_read span is closed by the time the chunk is handed out."""
+    from citus_tpu.observability import trace as T
+    cl = _cluster(tmp_path, 64, 256)
+    try:
+        cl.execute("CREATE TABLE t (k bigint NOT NULL, v bigint)")
+        cl.execute("SELECT create_distributed_table('t', 'k', 1)")
+        cl.copy_from("t", columns={"k": np.arange(600), "v": np.arange(600)})
+        plan = _plan(cl, "SELECT k, v FROM t")
+        shard = cl.catalog.table("t").shards[0]
+        r = rd.ShardReader(cl.catalog.shard_dir(
+            "t", shard.shard_id, shard.placements[0]), plan.bound.table.schema)
+        read = []
+        monkeypatch.setattr(r, "_scan_stripe_native", lambda *a: None)
+        monkeypatch.setattr(rd, "read_chunk", lambda *a, _f=rd.read_chunk:
+                            read.append(1) or _f(*a))
+        tr = T.Trace()
+        root = tr.open_span("query", None, {})
+        with T.activate(tr, root) if traced else contextlib.nullcontext():
+            rows = r.scan(["k", "v"])
+            first = next(rows)
+            assert first.row_count == 64 and len(read) == 2
+            if traced:
+                (span,) = tr.find_all("chunk_read")
+                assert span.t1 is not None and span.attrs == {
+                    "chunks": 4, "rows": 256}
+            assert sum(b.row_count for b in rows) == 600 - 64
+        assert len(tr.find_all("chunk_read")) == (3 if traced else 0)
+    finally:
+        cl.close()
+
+
+def test_not_in_place_names_the_reason(tmp_path):
+    """Each column that cannot land in place comes with why."""
+    cl = _cluster(tmp_path, 64, 256)
+    try:
+        cl.execute("CREATE TABLE t (k bigint NOT NULL, v bigint)")
+        cl.execute("SELECT create_distributed_table('t', 'k', 1)")
+        cl.copy_from("t", columns={"k": np.arange(600), "v": np.arange(600)})
+        cl.execute("ALTER TABLE t ADD COLUMN late bigint")
+        plan = _plan(cl, "SELECT k, v, late FROM t")
+        shard = cl.catalog.table("t").shards[0]
+        r = rd.ShardReader(cl.catalog.shard_dir(
+            "t", shard.shard_id, shard.placements[0]), plan.bound.table.schema)
+        cols = ["k", "v", "late"]
+        st = next(r.scan_stripes(cols))
+        assert r.not_in_place(st, st.chunks, cols) == {"late": "late_column"}
+        st.footer.columns["v"][1].value_raw_length -= 8
+        assert r.not_in_place(st, st.chunks, ["k", "v"]) == {"v": "codec"}
+        st.footer.columns["k"][0].has_nulls = True
+        assert r.not_in_place(st, st.chunks, ["k", "v"]) == \
+            {"k": "nulls", "v": "codec"}
+        st.del_mask = np.zeros(st.footer.row_count, bool)
+        assert r.not_in_place(st, [2], cols) == dict.fromkeys(cols, "deletes")
+        st.footer.codec = "brotli"
+        assert r.not_in_place(st, [2], cols) == dict.fromkeys(cols, "codec")
+    finally:
+        cl.close()

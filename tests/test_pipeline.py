@@ -417,3 +417,261 @@ def test_inline_decode_has_the_same_spans_on_the_callers_thread(
     assert len(batches) == 8
     assert {s.tid for s in tr.spans} == {tr.root().tid}
     assert not tr.find_all("wait:prefetch_stall")
+
+
+# --------------------------------- the decode thread's work, named from inside
+#
+# stripe_read's children (shard_open, footer_read, batch_layout,
+# native_decode, stripe_fallback) and the producer's own wait
+# (wait:prefetch_full).
+
+STRIPE_READ_CHILDREN = {"shard_open", "footer_read", "batch_layout",
+                        "native_decode", "stripe_fallback"}
+
+
+def _mixed_table(cl, name, n=20000, shards=8):
+    """k and v land in place; w (int, read as int64 on the device) casts
+    and so takes the stripe reader in every batch."""
+    cl.execute(f"CREATE TABLE {name} (k bigint NOT NULL, v bigint, w int)")
+    cl.execute(f"SELECT create_distributed_table('{name}', 'k', {shards})")
+    cl.copy_from(name, columns={"k": np.arange(n), "v": np.arange(n) * 3,
+                                "w": np.arange(n) % 7})
+    return [(n, n * (n - 1) // 2, 3 * n * (n - 1) // 2,
+             int((np.arange(n) % 7).sum()))]
+
+
+def _traced_stream(cl, q, exp):
+    """Run ``q`` streaming under a sampled trace -> the trace."""
+    from citus_tpu.observability import trace as T
+    assert cl.execute(q).rows == exp                 # compile
+    cl.execute("SET citus.trace_sample_rate = 1.0")
+    GLOBAL_CACHE.clear()
+    assert cl.execute(q).rows == exp
+    return T.last_trace()
+
+
+def test_stripe_read_names_its_parts_on_the_decode_thread(tmp_cluster,
+                                                          limit_devices):
+    limit_devices(1)
+    cl = tmp_cluster
+    exp = _mixed_table(cl, "nm")
+    tr = _traced_stream(cl, "SELECT count(*), sum(k), sum(v), sum(w) FROM nm",
+                        exp)
+    by_id = {s.span_id: s for s in tr.spans}
+    reads = tr.find_all("stripe_read")
+    assert len(reads) == 8        # one batch a shard
+    decode_tids = {s.tid for s in reads}
+    assert len(decode_tids) == 1 and decode_tids != {tr.root().tid}
+    for read in reads:
+        assert by_id[read.parent_id].name == "decode_batch"
+        kids = [s for s in tr.spans if s.parent_id == read.span_id]
+        assert [k.name for k in kids] == [
+            "shard_open", "footer_read", "batch_layout", "native_decode",
+            "stripe_fallback"]
+        for k in kids:
+            # on the decode thread, closed, inside the parent: nothing
+            # was held open across the generators' yields
+            assert k.tid == read.tid and k.t1 is not None
+            assert read.t0 <= k.t0 <= k.t1 <= read.t1
+            assert not [s for s in tr.spans if s.parent_id == k.span_id]
+        assert sum(k.duration_ms for k in kids) <= read.duration_ms
+        opened, foot, lay, nat, slow = kids
+        assert opened.attrs["stripes"] == 1
+        assert foot.attrs == {"chunks": foot.attrs["chunks"], "deletes": False,
+                              "selected": foot.attrs["chunks"]}
+        assert lay.attrs["streams"] == nat.attrs["streams"] == \
+            2 * foot.attrs["chunks"]
+        assert lay.attrs["files"] == nat.attrs["files"] == 1
+        assert lay.attrs["bytes_alloc"] >= 3 * 8 * read.attrs["rows"]
+        assert nat.attrs["bytes_raw"] == 16 * read.attrs["rows"]
+        assert 0 < nat.attrs["bytes_comp"]
+        assert 1 <= nat.attrs["threads"] <= 16
+        assert nat.attrs["read_ms"] + nat.attrs["decompress_ms"] > 0
+        assert nat.attrs["busy_max_ms"] > 0
+        assert slow.attrs == {"chunks": foot.attrs["chunks"], "reason": "cast",
+                              "bytes": 4 * read.attrs["rows"]}
+    pads = tr.find_all("pad")
+    assert len(pads) == 8
+    assert {s.name for s in tr.spans
+            if by_id.get(s.parent_id) in reads} == STRIPE_READ_CHILDREN
+
+
+# id, the table's DDL tail / what is done to it, the statement, the reason
+FALLBACKS = [
+    ("deletes", "v bigint", "DELETE FROM {t} WHERE k % 1000 = 3", "deletes"),
+    ("nulls", "v bigint", None, "nulls"),
+    ("cast", "v int", None, "cast"),
+    ("late_column", "v bigint", "ALTER", "late_column"),
+]
+
+
+@pytest.mark.parametrize("case", FALLBACKS, ids=[c[0] for c in FALLBACKS])
+def test_stripe_fallback_says_why(tmp_cluster, limit_devices, case):
+    """What cannot land in place takes the stripe reader under a
+    stripe_fallback span that names the reason; a clean column of the
+    same batch still goes through native_decode."""
+    name, column, action, reason = case
+    limit_devices(1)
+    cl = tmp_cluster
+    n, t = 8000, "fb_" + name
+    cl.execute(f"CREATE TABLE {t} (k bigint NOT NULL, {column})")
+    cl.execute(f"SELECT create_distributed_table('{t}', 'k', 2)")
+    v = np.arange(n) * 3
+    keep = np.ones(n, bool)
+    if name == "nulls":
+        cl.copy_from(t, rows=[(int(k), None if k % 50 == 0 else int(k) * 3)
+                              for k in range(n)])
+        v = np.where(np.arange(n) % 50 == 0, 0, v)
+    else:
+        cl.copy_from(t, columns={"k": np.arange(n), "v": v})
+    extra, none = "", ()
+    if action == "ALTER":
+        cl.execute(f"ALTER TABLE {t} ADD COLUMN x bigint")
+        extra, none = ", count(x)", (0,)
+    elif action:
+        cl.execute(action.format(t=t))
+        keep = np.arange(n) % 1000 != 3
+    q = f"SELECT count(*), sum(k), sum(v){extra} FROM {t}"
+    exp = [(int(keep.sum()), int(np.arange(n)[keep].sum()),
+            int(v[keep].sum())) + none]
+    tr = _traced_stream(cl, q, exp)
+    slow = tr.find_all("stripe_fallback")
+    assert slow and {s.attrs["reason"] for s in slow} == {reason}
+    assert all(s.attrs["chunks"] > 0 and s.attrs["bytes"] > 0 for s in slow)
+    foots = tr.find_all("footer_read")
+    assert {f.attrs["deletes"] for f in foots} == {name == "deletes"}
+    if name == "deletes":
+        assert not tr.find_all("native_decode")     # the whole stripe copies
+    else:
+        assert len(tr.find_all("native_decode")) == len(slow)
+
+
+def test_a_cut_chunk_is_a_stripe_fallback_of_its_own(tmp_path, monkeypatch,
+                                                     limit_devices):
+    """The chunk a batch cut falls in is decoded alone, under
+    stripe_fallback(reason=cut), inside the stripe_read that met it."""
+    import functools
+    from citus_tpu.config import ColumnarSettings, Settings
+    from citus_tpu.executor import executor as ex
+    from citus_tpu.executor.batches import load_padded_batches
+    limit_devices(1)
+    cl = ct.Cluster(str(tmp_path / "db"), settings=Settings(
+        columnar=ColumnarSettings(chunk_group_row_limit=128,
+                                  stripe_row_limit=256)))
+    try:
+        cl.execute("CREATE TABLE cu (k bigint NOT NULL, v bigint)")
+        cl.execute("SELECT create_distributed_table('cu', 'k', 1)")
+        n = 900
+        cl.copy_from("cu", columns={"k": np.arange(n), "v": np.arange(n)})
+        monkeypatch.setattr(ex, "load_padded_batches", functools.partial(
+            load_padded_batches, max_batch_rows=200))
+        cl.execute("SET citus.executor_min_batch_rows = 16")
+        tr = _traced_stream(cl, "SELECT count(*), sum(v) FROM cu",
+                            [(n, n * (n - 1) // 2)])
+        cuts = [s for s in tr.find_all("stripe_fallback")
+                if s.attrs["reason"] == "cut"]
+        assert cuts and len(cuts) == len(tr.find_all("stripe_fallback"))
+        by_id = {s.span_id: s for s in tr.spans}
+        for s in cuts:
+            assert by_id[s.parent_id].name == "stripe_read"
+            assert s.attrs["chunks"] == 1 and s.attrs["bytes"] > 0
+    finally:
+        GLOBAL_CACHE.clear()
+        cl.close()
+
+
+def test_a_slow_consumer_shows_as_prefetch_full_on_the_decode_thread(
+        tmp_cluster, limit_devices):
+    """Backpressure: the device is behind, the decode thread holds a
+    batch — a wait:prefetch_full span from the seam, on ITS thread,
+    beside its decode_batch spans, booked into wait_prefetch_full_ms."""
+    from citus_tpu.observability import trace as T
+    limit_devices(1)
+    cl = tmp_cluster
+    exp = _streaming_table(cl, "pf")
+    q = "SELECT count(*), sum(v) FROM pf"
+    assert cl.execute(q).rows == exp
+    cl.execute("SET citus.executor_prefetch_depth = 1")
+    cl.execute("SET citus.trace_sample_rate = 1.0")
+    GLOBAL_CACHE.clear()
+    before = cl.counters.snapshot().get("wait_prefetch_full_ms", 0)
+    FAULTS.arm("device_round", delay_s=0.05, match="pf")
+    try:
+        assert cl.execute(q).rows == exp
+    finally:
+        FAULTS.disarm()
+    tr = T.last_trace()
+    ex = tr.find("execute")
+    full = tr.find_all("wait:prefetch_full")
+    batch_tids = {s.tid for s in tr.find_all("decode_batch")}
+    assert full and len(batch_tids) == 1
+    assert all(s.parent_id == ex.span_id and s.tid in batch_tids
+               and s.t1 is not None for s in full)
+    assert sum(s.duration_ms for s in full) >= 100     # of 8 rounds x 50 ms
+    assert cl.counters.snapshot()["wait_prefetch_full_ms"] - before >= 100
+    # it waits between batches, never inside one
+    batches = tr.find_all("decode_batch")
+    assert not [(w, b) for w in full for b in batches
+                if w.t0 < b.t1 and b.t0 < w.t1]
+
+
+def test_an_unsampled_stream_asks_the_pool_nothing(tmp_cluster, limit_devices,
+                                                   monkeypatch):
+    """Tracing off: no Span is made and the native call gets NULL for
+    its stats array (it then reads no clock); tracing on: it gets one."""
+    import citus_tpu.native as nat
+    from citus_tpu.observability import trace as T
+    limit_devices(1)
+    cl = tmp_cluster
+    exp = _streaming_table(cl, "us")
+    q = "SELECT count(*), sum(v) FROM us"
+    assert cl.execute(q).rows == exp
+    lib, asked = nat.get_lib(), []
+
+    class Spy:
+        def __getattr__(self, name):
+            return getattr(lib, name)
+
+        def ct_decode_batch(self, *args):
+            asked.append(args[-1])
+            return lib.ct_decode_batch(*args)
+
+    spy = Spy()
+    monkeypatch.setattr(nat, "get_lib", lambda: spy)
+    cl.execute("SET citus.trace_sample_rate = 0")
+    GLOBAL_CACHE.clear()
+    before = T.span_allocations()
+    assert cl.execute(q).rows == exp
+    assert T.span_allocations() == before
+    assert len(asked) == 8 and all(a is None for a in asked)
+    del asked[:]
+    cl.execute("SET citus.trace_sample_rate = 1.0")
+    GLOBAL_CACHE.clear()
+    assert cl.execute(q).rows == exp
+    assert len(asked) == 8 and all(a is not None for a in asked)
+
+
+def test_explain_analyze_splits_the_decode(tmp_cluster, limit_devices):
+    """The pipeline line reads the same spans: footers, layout, the
+    native call with its pool's busy share, the fallback, the time the
+    decode thread was blocked."""
+    import re
+    limit_devices(1)
+    cl = tmp_cluster
+    exp = _mixed_table(cl, "ea")
+    q = "SELECT count(*), sum(k), sum(v), sum(w) FROM ea"
+    assert cl.execute(q).rows == exp
+    GLOBAL_CACHE.clear()
+    text = "\n".join(r[0] for r in cl.execute("EXPLAIN ANALYZE " + q).rows)
+    (line,) = [ln for ln in text.splitlines() if "Pipeline:" in ln]
+    m = re.search(
+        r"decoded in place 0\.800, decode: footers (\d+\.\d\d) ms, "
+        r"layout (\d+\.\d\d) ms, native (\d+\.\d\d) ms \(pool (\d+) % busy\), "
+        r"fallback (\d+\.\d\d) ms, blocked (\d+\.\d\d) ms, fused dispatches 8",
+        line)
+    assert m, line
+    footers, layout, native, pool, fallback, _blocked = map(float, m.groups())
+    assert min(footers, layout, native, fallback) > 0 and 0 < pool <= 100
+    # a resident scan decodes nothing: nothing to split
+    again = "\n".join(r[0] for r in cl.execute("EXPLAIN ANALYZE " + q).rows)
+    assert "decode:" not in again

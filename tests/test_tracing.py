@@ -518,3 +518,75 @@ def test_hash_counters_for_a_known_table(clh, limit_devices, slots):
     # shadow sum, int64 rows
     fetched = c1["hash_table_bytes_fetched"] - c0["hash_table_bytes_fetched"]
     assert fetched == pl["hash_table_bytes_fetched"] == pl["hash_slots"] * 41
+
+
+# ------------------------------------- the raw reader and the index, named
+
+
+def _spans_under(tr, parent):
+    return [s for s in tr.spans if s.parent_id == parent.span_id]
+
+
+def test_a_routed_lookup_names_footers_probes_and_chunk_reads(cl):
+    """A point lookup's stripe_read holds the shard_open, one footer_read
+    a stripe and a chunk_read for the stripe whose chunk is selected; with an index,
+    one index_probe a stripe before them.  All closed before the rows
+    are handed out, all on the caller's thread."""
+    cl.execute("SET citus.trace_sample_rate = 1.0")
+    q = "SELECT k, v FROM t WHERE k = 777"
+    cl.execute(q)
+    want = cl.execute(q).rows
+    tr = T.last_trace()
+    (read,) = tr.find_all("stripe_read")
+    kids = _spans_under(tr, read)
+    assert [k.name for k in kids][0] == "shard_open"
+    assert {k.name for k in kids[1:]} == {"footer_read", "chunk_read"}
+    foots = [k for k in kids if k.name == "footer_read"]
+    assert sum(f.attrs["selected"] for f in foots) == 1
+    (chunk,) = [k for k in kids if k.name == "chunk_read"]
+    assert chunk.attrs["chunks"] == 1 and chunk.attrs["rows"] > 0
+    assert all(k.tid == read.tid and read.t0 <= k.t0 <= k.t1 <= read.t1
+               for k in kids)
+    assert sum(k.duration_ms for k in kids) <= read.duration_ms
+    cl.execute("CREATE INDEX t_k ON t (k)")
+    assert cl.execute(q).rows == want
+    tr = T.last_trace()
+    (read,) = tr.find_all("stripe_read")
+    names = [k.name for k in _spans_under(tr, read)]
+    assert names.count("index_probe") == names.count("footer_read") >= 1
+    assert names.count("chunk_read") == 1
+    probes = tr.find_all("index_probe")
+    assert sum(p.attrs["positions"] for p in probes) == len(want)
+
+
+def test_kernel_compiles_counts_what_the_miss_counter_cannot(cl):
+    """A kernel retraced for a new batch shape is no cache miss, but it
+    is a compile: kernel_compiles moves with the kernel_compile spans."""
+    def delta(c0, c1, name):
+        return c1.get(name, 0) - c0.get(name, 0)
+
+    from citus_tpu.executor.kernel_cache import GLOBAL_KERNELS
+    GLOBAL_KERNELS.clear()       # shared by the process: start from a miss
+    cl.execute("SET citus.trace_sample_rate = 1.0")
+    q = "SELECT k, v FROM t WHERE k = 777"
+    c0 = cl.counters.snapshot()
+    cl.execute(q)
+    c1 = cl.counters.snapshot()
+    first = len(T.last_trace().find_all("kernel_compile"))
+    assert delta(c0, c1, "kernel_cache_misses") >= 1
+    assert delta(c0, c1, "kernel_compiles") == first >= 1
+    cl.execute(q)
+    c2 = cl.counters.snapshot()
+    assert delta(c1, c2, "kernel_compiles") == 0
+    # an index shrinks the batch handed to the same kernel: a new shape
+    cl.execute("CREATE INDEX t_k2 ON t (k)")
+    c3 = cl.counters.snapshot()
+    cl.execute(q)
+    c4 = cl.counters.snapshot()
+    again = len(T.last_trace().find_all("kernel_compile"))
+    assert delta(c3, c4, "kernel_compiles") == again >= 1
+    assert delta(c3, c4, "kernel_cache_misses") == 0
+    from citus_tpu.observability.export import METRIC_HELP
+    from citus_tpu.stats import StatCounters
+    for name in ("kernel_compiles", "wait_prefetch_full_ms"):
+        assert name in StatCounters.COUNTERS and name in METRIC_HELP
