@@ -334,7 +334,8 @@ def _run_analyze(cl, stmt: A.Explain) -> list[str]:
 def _decode_split(tr) -> str:
     """Where the batches' decode went, from the statement's own spans
     (the children of stripe_read and the producer's wait): the shards'
-    metadata and the footers, the batch layout, the ONE native call a
+    metadata and the footers (and how many of them the footer cache
+    served decoded), the batch layout, the ONE native call a
     batch — with the share of that call's threads x time its pool spent
     reading and decompressing — the stripe reader, and the time
     backpressure held the decode."""
@@ -346,7 +347,10 @@ def _decode_split(tr) -> str:
     worked = sum(s.attrs.get("read_ms", 0.0) + s.attrs.get("decompress_ms", 0.0)
                  for s in native)
     busy = f" (pool {100 * worked / offered:.0f} % busy)" if offered else ""
-    return (f"decode: footers {ms('shard_open', 'footer_read'):.2f} ms, "
+    footers = tr.find_all("footer_read")
+    cached = sum(bool(s.attrs.get("cached")) for s in footers)
+    return (f"decode: footers {ms('shard_open', 'footer_read'):.2f} ms "
+            f"({cached} of {len(footers)} cached), "
             f"layout {ms('batch_layout'):.2f} ms, "
             f"native {ms('native_decode'):.2f} ms{busy}, "
             f"fallback {ms('stripe_fallback', 'chunk_read'):.2f} ms, "

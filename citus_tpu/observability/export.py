@@ -99,6 +99,9 @@ METRIC_HELP = {
     "batch_rows_padded": "rows of those batches' power-of-two buckets",
     "decode_bytes_in_place": "value bytes of those batches decoded straight into the batch's arrays",
     "decode_bytes_copied": "value bytes of those batches decoded apart and copied in",
+    "footer_cache_hits": "stripe footers served decoded, the file's identity unchanged",
+    "footer_parses": "stripe files opened and their footers parsed",
+    "footer_cache_evictions": "decoded footers the cache's bound pushed out",
     "direct_groups": "slots of the group domains of direct-group-id aggregations",
     "direct_groups_out": "groups those aggregations returned",
     "direct_bytes_fetched": "bytes of their partial states fetched",

@@ -45,6 +45,12 @@ class StatCounters:
         # there from a chunk decoded apart (deletes, NULLs, a cast, ...)
         "decode_bytes_in_place",
         "decode_bytes_copied",
+        # decoded stripe footers kept by file identity
+        # (storage/format.py read_stripe_footer): footers served from
+        # the cache, files opened and parsed, entries the bound pushed out
+        "footer_cache_hits",
+        "footer_parses",
+        "footer_cache_evictions",
         "plan_cache_hits",
         "plan_cache_misses",
         "connection_failovers",

@@ -26,8 +26,11 @@ from __future__ import annotations
 import json
 import os
 import struct
+import threading
+from collections import OrderedDict
 from dataclasses import dataclass, field
-from typing import BinaryIO, Optional
+from functools import cached_property
+from typing import BinaryIO, NamedTuple, Optional
 
 import numpy as np
 
@@ -74,6 +77,11 @@ class ChunkStats:
 
 @dataclass
 class StripeFooter:
+    """One stripe's skip list.  A footer that ``read_stripe_footer``
+    hands out is shared by every reader of the file (the footer cache
+    below) and is never mutated: the only writer of ``columns`` is
+    ``write_stripe_file``, on the footer it is still building."""
+
     row_count: int
     chunk_row_limit: int
     chunk_row_counts: list[int]
@@ -84,6 +92,16 @@ class StripeFooter:
     @property
     def chunk_count(self) -> int:
         return len(self.chunk_row_counts)
+
+    @cached_property
+    def chunk_bounds(self) -> np.ndarray:
+        """First row of every chunk group, then the stripe's row count
+        (``chunk_count + 1`` int64s, read-only): derived once a footer,
+        since a scan of a cached footer would derive it every time."""
+        bounds = np.zeros(self.chunk_count + 1, np.int64)
+        np.cumsum(self.chunk_row_counts, out=bounds[1:])
+        bounds.setflags(write=False)
+        return bounds
 
     def to_json(self) -> dict:
         return {
@@ -186,10 +204,120 @@ def write_stripe_file(
     return footer
 
 
-def read_stripe_footer(path: str) -> StripeFooter:
+# Decoded footers are kept, by path.  A stripe file is written under a
+# temp name, fsynced and renamed into place, and never written again, so
+# what was parsed from a file stays true of that FILE; names are reused
+# (VACUUM, TRUNCATE + COPY, DROP + CREATE, a shard move), so an entry is
+# served only while one os.stat says the path still names the file it
+# was parsed from.  Bounded by the footers' JSON bytes (an SF10 lineitem
+# is 480 stripes and 8-15 MB of them; decoded, about three times that):
+# some tens of such tables, least recently used first out.
+FOOTER_CACHE_JSON_BYTES = 256 << 20
+
+
+class _CachedFooter(NamedTuple):
+    identity: tuple          # of the file it was parsed from
+    footer: StripeFooter
+    json_bytes: int
+
+
+class _FooterCache:
+    """path -> _CachedFooter, least recently used first."""
+
+    def __init__(self):
+        self._mu = threading.Lock()
+        self._entries: "OrderedDict[str, _CachedFooter]" = OrderedDict()
+        self._bytes = 0
+
+    def get(self, path: str, identity: tuple) -> Optional[StripeFooter]:
+        with self._mu:
+            entry = self._entries.get(path)
+            if entry is None or entry.identity != identity:
+                return None
+            self._entries.move_to_end(path)
+            return entry.footer
+
+    def drop(self, path: str) -> None:
+        with self._mu:
+            self._drop_locked(path)
+
+    def put(self, path: str, entry: _CachedFooter) -> int:
+        """File ``entry`` under ``path``; -> entries the bound pushed out
+        (never the one just filed)."""
+        evicted = 0
+        with self._mu:
+            self._drop_locked(path)
+            self._entries[path] = entry
+            self._bytes += entry.json_bytes
+            while (self._bytes > FOOTER_CACHE_JSON_BYTES
+                   and len(self._entries) > 1):
+                self._drop_locked(next(iter(self._entries)))
+                evicted += 1
+        return evicted
+
+    def _drop_locked(self, path: str) -> None:
+        gone = self._entries.pop(path, None)
+        if gone is not None:
+            self._bytes -= gone.json_bytes
+
+
+_FOOTERS = _FooterCache()
+
+
+def _file_identity(st: os.stat_result) -> tuple:
+    return (st.st_dev, st.st_ino, st.st_size, st.st_mtime_ns, st.st_ctime_ns)
+
+
+def _bump(name: str, by: int = 1) -> None:
+    try:
+        from citus_tpu.executor.executor import GLOBAL_COUNTERS
+    except ImportError:
+        return
+    GLOBAL_COUNTERS.bump(name, by)
+
+
+def read_stripe_footer(path: str, span=None,
+                       dir_fd: Optional[int] = None) -> StripeFooter:
+    """The footer of the stripe file at ``path`` — the one way to get
+    one.  Served from the process-wide cache when ONE ``os.stat`` of the
+    file returns the identity (device, inode, size, mtime, ctime) of the
+    file the entry was parsed from; else the file is opened and parsed
+    and the entry replaced.  The footer is shared: do not mutate it.  A
+    recording ``span`` gets the attribute ``cached``.  ``dir_fd``, the
+    caller's open descriptor of ``path``'s directory, lets the stat name
+    the file from there instead of walking the whole path (one component
+    against a dozen: the walk is most of a hit where a path component
+    costs what a syscall does)."""
+    try:
+        if dir_fd is None:
+            st = os.stat(path)
+        else:
+            st = os.stat(os.path.basename(path), dir_fd=dir_fd)
+    except OSError:
+        _FOOTERS.drop(path)
+        raise
+    footer = _FOOTERS.get(path, _file_identity(st))
+    if span is not None and span.recording:
+        span.set(cached=footer is not None)
+    if footer is not None:
+        _bump("footer_cache_hits")
+        return footer
+    # parsed outside the lock; the identity is the opened file's own,
+    # so a file swapped in since the stat above is filed as itself
+    entry = _parse_stripe_footer(path)
+    _bump("footer_parses")
+    evicted = _FOOTERS.put(path, entry)
+    if evicted:
+        _bump("footer_cache_evictions", evicted)
+    return entry.footer
+
+
+def _parse_stripe_footer(path: str) -> _CachedFooter:
+    """Open and parse: the opened file's identity, its footer, the
+    footer's JSON bytes."""
     with open(path, "rb") as fh:
-        fh.seek(0, os.SEEK_END)
-        size = fh.tell()
+        st = os.fstat(fh.fileno())
+        size = st.st_size
         if size < len(MAGIC) * 2 + 8:
             raise StorageError(f"stripe file too small: {path}")
         fh.seek(size - len(MAGIC) - 8)
@@ -202,7 +330,9 @@ def read_stripe_footer(path: str) -> StripeFooter:
         fh.seek(0)
         if fh.read(len(MAGIC)) != MAGIC:
             raise StorageError(f"bad leading magic in {path}")
-        return StripeFooter.from_json(json.loads(fj.decode()))
+        return _CachedFooter(
+            _file_identity(st),
+            StripeFooter.from_json(json.loads(fj.decode())), len(fj))
 
 
 def read_chunk(

@@ -171,48 +171,61 @@ class ShardReader:
         for col in columns:
             self.schema.scan_column(col)  # validate projection
         delete_cache = None if apply_deletes else {}
-        for stripe in self.meta["stripes"]:
-            if only_stripes is not None and stripe["file"] not in only_stripes:
-                continue
-            path = os.path.join(self.directory, stripe["file"])
-            # one footer_read per stripe, closed before the yield: the
-            # footer's JSON, the chunk pruning and the deletion bitmap
-            # (the shard's map of deletes is read under the first one)
-            with _trace.span("footer_read") as sp:
-                if delete_cache is None:
-                    delete_cache = visible_deletes(self.directory)
-                footer = read_stripe_footer(path)
-                selected = self._selected_chunks(footer, constraints)
-                try:
-                    from citus_tpu.executor.executor import GLOBAL_COUNTERS
-                    GLOBAL_COUNTERS.bump("chunks_total", footer.chunk_count)
-                    GLOBAL_COUNTERS.bump("chunks_selected", int(selected.sum()))
-                    # rows refuted by footer min/max BEFORE any stream bytes
-                    # of theirs are read or decompressed — the fused hot
-                    # loop's admission win
-                    skipped = int(np.asarray(
-                        footer.chunk_row_counts)[~selected].sum())
-                    if skipped:
-                        GLOBAL_COUNTERS.bump("fused_rows_skipped", skipped)
-                except ImportError:
-                    pass
-                st = None
-                if selected.any():
-                    offsets = np.concatenate(
-                        [[0], np.cumsum(footer.chunk_row_counts)[:-1]])
-                    del_mask = None
-                    if apply_deletes and stripe["file"] in delete_cache:
-                        del_mask = deleted_mask(self.directory, stripe["file"],
-                                                footer.row_count, delete_cache)
-                    st = StripeScan(stripe["file"], path, footer,
-                                    [int(i) for i in np.nonzero(selected)[0]],
-                                    offsets, del_mask)
-                if sp.recording:
-                    sp.set(chunks=int(footer.chunk_count),
-                           selected=int(selected.sum()),
-                           deletes=st is not None and st.del_mask is not None)
-            if st is not None:
-                yield st
+        try:
+            from citus_tpu.executor.executor import GLOBAL_COUNTERS
+        except ImportError:
+            GLOBAL_COUNTERS = None
+        # the footers' files are named from the directory as this scan
+        # found it, like the stripe list its reader holds
+        dir_fd = None
+        try:
+            for stripe in self.meta["stripes"]:
+                if only_stripes is not None and stripe["file"] not in only_stripes:
+                    continue
+                path = os.path.join(self.directory, stripe["file"])
+                # one footer_read per stripe, closed before the yield: the
+                # footer (decoded once a file: attr cached), the chunk pruning
+                # and the deletion bitmap (the shard's map of deletes and
+                # the directory are opened under the first one)
+                with _trace.span("footer_read") as sp:
+                    if delete_cache is None:
+                        delete_cache = visible_deletes(self.directory)
+                    if dir_fd is None:
+                        dir_fd = os.open(self.directory,
+                                         os.O_RDONLY | os.O_DIRECTORY)
+                    footer = read_stripe_footer(path, sp, dir_fd)
+                    selected = self._selected_chunks(footer, constraints)
+                    n_selected = int(np.count_nonzero(selected))
+                    if GLOBAL_COUNTERS is not None:
+                        GLOBAL_COUNTERS.bump("chunks_total", footer.chunk_count)
+                        GLOBAL_COUNTERS.bump("chunks_selected", n_selected)
+                        # rows refuted by footer min/max BEFORE any stream bytes
+                        # of theirs are read or decompressed — the fused hot
+                        # loop's admission win
+                        if n_selected < footer.chunk_count:
+                            skipped = int(np.diff(
+                                footer.chunk_bounds)[~selected].sum())
+                            if skipped:
+                                GLOBAL_COUNTERS.bump("fused_rows_skipped", skipped)
+                    st = None
+                    if n_selected:
+                        offsets = footer.chunk_bounds[:-1]
+                        del_mask = None
+                        if apply_deletes and stripe["file"] in delete_cache:
+                            del_mask = deleted_mask(self.directory, stripe["file"],
+                                                    footer.row_count, delete_cache)
+                        st = StripeScan(stripe["file"], path, footer,
+                                        [int(i) for i in np.nonzero(selected)[0]],
+                                        offsets, del_mask)
+                    if sp.recording:
+                        sp.set(chunks=int(footer.chunk_count),
+                               selected=n_selected,
+                               deletes=st is not None and st.del_mask is not None)
+                if st is not None:
+                    yield st
+        finally:
+            if dir_fd is not None:
+                os.close(dir_fd)
 
     def stripe_chunks(self, st: StripeScan, columns: list[str],
                       chunks: list[int]) -> Iterator[ChunkBatch]:
@@ -283,7 +296,7 @@ class ShardReader:
                 continue
             path = os.path.join(self.directory, stripe["file"])
             with _trace.span("footer_read") as sp:
-                footer = read_stripe_footer(path)
+                footer = read_stripe_footer(path, sp)
                 if GLOBAL_COUNTERS is not None:
                     GLOBAL_COUNTERS.bump("index_lookups")
                     GLOBAL_COUNTERS.bump("chunks_total", footer.chunk_count)
@@ -296,8 +309,7 @@ class ShardReader:
                         pos = pos[~dm[pos]]
                 needed = ()
                 if pos.size:
-                    bounds = np.concatenate(
-                        [[0], np.cumsum(footer.chunk_row_counts)])
+                    bounds = footer.chunk_bounds
                     chunk_of = np.searchsorted(bounds, pos, "right") - 1
                     needed = np.unique(chunk_of)
                     if GLOBAL_COUNTERS is not None:
@@ -482,7 +494,7 @@ class ShardReader:
         return sel, tot
 
     def _selected_chunks(self, footer, constraints: list[Interval]) -> np.ndarray:
-        mask = np.ones(footer.chunk_count, dtype=bool)
+        keep = [True] * footer.chunk_count
         for c in constraints:
             try:
                 sname = self.schema.scan_storage_name(c.column)
@@ -492,17 +504,13 @@ class ShardReader:
             if chunks is None:
                 # column added after this stripe: every row is NULL there,
                 # so no range constraint can match
-                mask[:] = False
-                return mask
+                return np.zeros(footer.chunk_count, dtype=bool)
             for ci, stats in enumerate(chunks):
-                if not mask[ci]:
-                    continue
-                if stats.row_count == stats.null_count:
-                    mask[ci] = False  # all null: no row can match a range
-                    continue
-                if not c.admits(stats.minimum, stats.maximum):
-                    mask[ci] = False
-        return mask
+                # all null: no row can match a range
+                if keep[ci] and (stats.row_count == stats.null_count or
+                                 not c.admits(stats.minimum, stats.maximum)):
+                    keep[ci] = False
+        return np.array(keep, dtype=bool)
 
 
 class BatchDecode:

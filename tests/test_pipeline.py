@@ -477,8 +477,10 @@ def test_stripe_read_names_its_parts_on_the_decode_thread(tmp_cluster,
         assert sum(k.duration_ms for k in kids) <= read.duration_ms
         opened, foot, lay, nat, slow = kids
         assert opened.attrs["stripes"] == 1
+        # the compile run before the trace parsed every footer
         assert foot.attrs == {"chunks": foot.attrs["chunks"], "deletes": False,
-                              "selected": foot.attrs["chunks"]}
+                              "selected": foot.attrs["chunks"],
+                              "cached": True}
         assert lay.attrs["streams"] == nat.attrs["streams"] == \
             2 * foot.attrs["chunks"]
         assert lay.attrs["files"] == nat.attrs["files"] == 1
@@ -652,9 +654,10 @@ def test_an_unsampled_stream_asks_the_pool_nothing(tmp_cluster, limit_devices,
 
 
 def test_explain_analyze_splits_the_decode(tmp_cluster, limit_devices):
-    """The pipeline line reads the same spans: footers, layout, the
-    native call with its pool's busy share, the fallback, the time the
-    decode thread was blocked."""
+    """The pipeline line reads the same spans: footers (all of them
+    served by the footer cache: the statement ran once before), layout,
+    the native call with its pool's busy share, the fallback, the time
+    the decode thread was blocked."""
     import re
     limit_devices(1)
     cl = tmp_cluster
@@ -665,13 +668,16 @@ def test_explain_analyze_splits_the_decode(tmp_cluster, limit_devices):
     text = "\n".join(r[0] for r in cl.execute("EXPLAIN ANALYZE " + q).rows)
     (line,) = [ln for ln in text.splitlines() if "Pipeline:" in ln]
     m = re.search(
-        r"decoded in place 0\.800, decode: footers (\d+\.\d\d) ms, "
+        r"decoded in place 0\.800, decode: footers (\d+\.\d\d) ms "
+        r"\((\d+) of (\d+) cached\), "
         r"layout (\d+\.\d\d) ms, native (\d+\.\d\d) ms \(pool (\d+) % busy\), "
         r"fallback (\d+\.\d\d) ms, blocked (\d+\.\d\d) ms, fused dispatches 8",
         line)
     assert m, line
-    footers, layout, native, pool, fallback, _blocked = map(float, m.groups())
+    (footers, hits, stripes, layout, native, pool, fallback,
+     _blocked) = map(float, m.groups())
     assert min(footers, layout, native, fallback) > 0 and 0 < pool <= 100
+    assert hits == stripes > 0
     # a resident scan decodes nothing: nothing to split
     again = "\n".join(r[0] for r in cl.execute("EXPLAIN ANALYZE " + q).rows)
     assert "decode:" not in again
