@@ -111,6 +111,13 @@ Q_PRODUCT = """SELECT l_shipdate, l_discount, l_tax, count(*) AS n,
   sum(l_quantity) AS sum_qty
 FROM lineitem GROUP BY l_shipdate, l_discount, l_tax"""
 HASH_DOMAIN_SLOTS = (SHIP_DAYS + 1) * 12 * 10
+# Q18's block (TPC-H 2.4.18): one group per order on the distribution
+# column, so a host of several devices builds one hash table a device,
+# each over its own shards; the threshold is taken from the reference
+Q_ORDERS = """SELECT l_orderkey, sum(l_quantity) AS sum_qty FROM lineitem
+GROUP BY l_orderkey HAVING sum(l_quantity) > {threshold}"""
+#: orders the block's HAVING is set to keep
+ORDERS_KEPT = 20
 
 Q_ROUTER = """SELECT l_orderkey, l_quantity, l_extendedprice, l_shipdate,
   l_returnflag
@@ -182,7 +189,8 @@ class Reference:
     """Plain numpy answers, accumulated chunk by chunk at ingest.  All
     money columns are integer cents, so every sum here is exact."""
 
-    def __init__(self):
+    def __init__(self, n_orders):
+        self.o_qty = np.zeros(n_orders, np.int64)   # per order, in cents
         self.router_key = None            # the first row's l_orderkey
         self.q1 = {}                      # (rf, ls) -> running sums
         self.q6 = [0] * len(Q6_VARIANTS)
@@ -204,6 +212,9 @@ class Reference:
                                    c["ship"])
         if self.router_key is None:
             self.router_key = int(okey[0])
+        # Q18's block: a chunk's sums are integers below 2**53
+        self.o_qty += np.bincount(okey, weights=qty,
+                                  minlength=self.o_qty.size).astype(np.int64)
         # Q1
         keep = ship <= Q1_SHIP_HI
         disc_price = price * (100 - disc)
@@ -284,6 +295,14 @@ class Reference:
             out[(EPOCH + datetime.timedelta(days=SHIP_LO + d),
                  dec(disc, 2), dec(tax, 2))] = row
         return out
+
+    def order_rows(self):
+        """-> (threshold in cents, {order key: sum}) of the
+        ``ORDERS_KEPT`` largest quantity sums (ties at the threshold
+        left out, as HAVING ... > threshold leaves them)."""
+        threshold = int(np.sort(self.o_qty)[-ORDERS_KEPT - 1])
+        return threshold, {int(k): dec(self.o_qty[k], 2)
+                           for k in np.nonzero(self.o_qty > threshold)[0]}
 
     def stddev_rows(self):
         out = []
@@ -542,10 +561,15 @@ def leg_hash(run, ref, devices, rows):
     pl = r.explain["pipeline"]
     # the groups cannot outnumber the rows nor the keys' domain: the
     # table takes the smaller bound (executor.py _hash_slots)
+    # -- a table a device, each bounded by its fullest device's rows
+    tables = pl.get("hash_tables", 1)
+    rows = pl.get("hash_rows_in_max_device", rows)
     by_rows = max(1024, 1 << (rows - 1).bit_length())
     by_domain = 1 << (2 * HASH_DOMAIN_SLOTS - 1).bit_length()
-    bound = ((by_domain, "key domain") if by_domain < by_rows
-             else (by_rows, "row count"))
+    bound = ((tables * by_domain, "key domain") if by_domain < by_rows
+             else (tables * by_rows, "row count"))
+    check(tables == len(devices), f"hash leg: {tables} tables on "
+          f"{len(devices)} devices")
     check((pl.get("hash_slots"), pl.get("hash_slots_from")) == bound,
           f"hash leg: slots {pl.get('hash_slots')} from "
           f"{pl.get('hash_slots_from')}, want {bound}")
@@ -554,6 +578,50 @@ def leg_hash(run, ref, devices, rows):
                hash_slots=pl.get("hash_slots"),
                hash_slots_from=pl.get("hash_slots_from"),
                hash_occupancy_pct=pl.get("hash_occupancy_pct"),
+               hash_spill_rows=d.get("hash_spill_rows", 0),
+               bytes_in_use_growth_per_device=grew["per_device"])
+
+
+def leg_hash_every_device(run, ref, devices):
+    """Q18's block on every visible device: one hash table a device
+    (the counter says so, and so does each device's memory), apart on
+    the distribution column, every kept order's sum equal."""
+    cl = run.cl
+    threshold, want = ref.order_rows()
+    # the smoke's order keys are dense: keep the statement off the
+    # direct table, as the sparse keys of a real lineitem do
+    cl.execute("SET citus.direct_gid_limit = 16")
+    try:
+        with memory_growth(devices) as grew:
+            r, d, el, ev = run.run(Q_ORDERS.format(
+                threshold=dec(threshold, 2)))
+    finally:
+        cl.execute("SET citus.direct_gid_limit = auto")
+    pl = r.explain["pipeline"]
+    n_dev = len(devices)
+    check(r.explain["strategy"] == "hash_host", f"orders leg: {r.explain}")
+    check("jit_hash_fused" in kernel_slots(ev),
+          f"orders leg: slots {kernel_slots(ev)}")
+    check(d.get("hash_tables") == pl.get("hash_tables") == n_dev,
+          f"orders leg: {d.get('hash_tables')} tables on {n_dev} devices")
+    check(d.get("hash_tables_merged", 0) == 0 and (
+        n_dev == 1 or pl.get("hash_disjoint_on") == "l_orderkey"),
+        f"orders leg: tables not apart: {pl}")
+    if isinstance(grew["per_device"], list):
+        check(all(g > 0 for g in grew["per_device"]),
+              f"orders leg: bytes_in_use did not grow on every device: "
+              f"{grew['per_device']}")
+    got = {row[0]: row[1] for row in r.rows}
+    check_groups("orders leg", got, want, len(r.rows))
+    run.record("5f Q18's block, a hash table on every device",
+               "jit_hash_fused", el, d, groups=len(want),
+               hash_tables=pl.get("hash_tables"),
+               hash_slots=pl.get("hash_slots"),
+               hash_slots_from=pl.get("hash_slots_from"),
+               hash_disjoint_on=pl.get("hash_disjoint_on"),
+               hash_rows_in_max_device=pl.get("hash_rows_in_max_device"),
+               hash_having_on_device=bool(pl.get("hash_having_on_device")),
+               hash_entries_fetched=d.get("hash_entries_fetched", 0),
                hash_spill_rows=d.get("hash_spill_rows", 0),
                bytes_in_use_growth_per_device=grew["per_device"])
 
@@ -801,7 +869,7 @@ def main() -> int:
         cl.execute("SELECT create_distributed_table('lineitem', "
                    f"'l_orderkey', {shards})")
         rng = np.random.default_rng(args.seed)
-        ref = Reference()
+        ref = Reference(n_orders)
         t0 = time.perf_counter()
         load_lineitem(cl, ref, rng, args.rows, n_orders)
         if n_dev > 1:
@@ -824,6 +892,7 @@ def main() -> int:
         leg_direct(run, ref, n_dev)
         leg_hash(run, ref, devices, args.rows)
         leg_product(run, ref, n_dev, args.rows)
+        leg_hash_every_device(run, ref, devices)
         leg_float_lanes(run, rng, shards, n_dev,
                         min(MEASURES_ROWS, args.rows))
         leg_router(run, ref)

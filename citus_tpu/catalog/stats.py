@@ -23,14 +23,19 @@ from citus_tpu.storage.writer import _load_meta
 _CACHE: dict[tuple, dict[str, tuple]] = {}
 
 
-def table_row_count(cat: Catalog, table: TableMeta) -> int:
-    total = 0
+def shard_row_counts(cat: Catalog, table: TableMeta) -> list[int]:
+    """Rows of each shard, in the order of ``table.shards`` (0 for a
+    shard with no local directory)."""
+    counts = []
     for shard in table.shards:
         node = shard.placements[0]
         d = cat.shard_dir(table.name, shard.shard_id, node)
-        if os.path.isdir(d):
-            total += _load_meta(d)["row_count"]
-    return total
+        counts.append(_load_meta(d)["row_count"] if os.path.isdir(d) else 0)
+    return counts
+
+
+def table_row_count(cat: Catalog, table: TableMeta) -> int:
+    return sum(shard_row_counts(cat, table))
 
 
 def column_bounds(cat: Catalog, table: TableMeta) -> dict[str, tuple]:

@@ -314,6 +314,14 @@ def _run_analyze(cl, stmt: A.Explain) -> list[str]:
             if "hash_slots_from" in pl:
                 # what bounded the derived table (executor.py _hash_slots)
                 line += f", slots from {pl['hash_slots_from']}"
+            if pl.get("hash_tables", 1) > 1:
+                # one table a device, each fed its own shards: apart
+                # where the keys hold the distribution column, else
+                # fetched whole and merged
+                line += (f", tables {pl['hash_tables']} x "
+                         f"{pl['hash_slots'] // pl['hash_tables']} slots, "
+                         + (f"disjoint on {pl['hash_disjoint_on']}"
+                            if pl.get("hash_disjoint_on") else "merged"))
             if pl.get("hash_having_on_device"):
                 # the chip decided HAVING on the table: the survivors'
                 # blocks and the spilled keys' entries came home

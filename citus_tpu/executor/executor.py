@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import threading
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -33,8 +33,8 @@ from citus_tpu.executor.batches import (
 from citus_tpu.executor.finalize import finalize_groups, order_and_limit, project_rows
 from citus_tpu.executor.kernel_cache import get_kernel, jit_compile
 from citus_tpu.executor.scan_loop import (
-    OneDevice, Step, _block_ready, _nbytes, _prefetch_depth,
-    choose_placement, drive,
+    Step, _block_ready, _nbytes, _prefetch_depth,
+    choose_affine_placement, choose_placement, drive,
 )
 from citus_tpu.observability import trace as _trace
 from citus_tpu.observability.trace import clock
@@ -455,10 +455,12 @@ def _pow2_at_least(n: int, floor: int) -> int:
 
 
 def _hash_slots(cat: Catalog, plan: PhysicalPlan, settings: Settings,
-                key_dtypes: tuple, tables: int = 1) -> tuple[int, str]:
-    """Slots of a query's device hash table, and where the number came
-    from (EXPLAIN ANALYZE's ``Hash:`` line says it).  ``citus.hash_agg_slots
-    = n`` fixes them (``"setting"``); ``auto`` (0, the default) derives
+                key_dtypes: tuple, tables: int = 1,
+                placement=None) -> tuple[int, str]:
+    """Slots of ONE of a query's device hash tables, and where the number
+    came from (EXPLAIN ANALYZE's ``Hash:`` line says it).
+    ``citus.hash_agg_slots = n`` fixes them (``"setting"``); ``auto``
+    (0, the default) derives
     them from what bounds the groups: the catalog's row count (every row
     may be a group: the next power of two at or above it, ``"row
     count"``) or, where the plan proves a domain for every group key and
@@ -467,27 +469,38 @@ def _hash_slots(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     two-probe table at half load spills little) -- at least 1024, and at
     most what ``HASH_STATE_MEMORY_SHARE`` of the device's free memory
     holds of ``tables`` such tables at this plan's bytes per slot
-    (``"free memory"``).  Groups beyond the table spill to the host
-    accumulator, exactly."""
+    (``"free memory"``).  With a ``placement`` of one table a DEVICE
+    (``AffineMeshPlacement``) the row count is that of the fullest
+    device's own shards and the free memory that of the device with the
+    least: the tables share one shape.  Groups beyond a table spill to
+    the host accumulator, exactly."""
     S = settings.planner.hash_agg_slots
     if S > 0:
         return S, "setting"
-    from citus_tpu.catalog.stats import table_row_count
+    from citus_tpu.catalog.stats import shard_row_counts
     from citus_tpu.ops.hash_agg import empty_hash_state, hash_state_bytes
     from citus_tpu.parallel.mesh import executor_devices
     try:
-        n = table_row_count(cat, cat.table(plan.bound.table.name))
+        counts = shard_row_counts(cat, cat.table(plan.bound.table.name))
     except Exception:
-        n = 0
+        counts = []
+    devices = executor_devices()[:1]
+    n = sum(counts)
+    if placement is not None and placement.mesh is not None:
+        devices = list(placement.mesh.devices.flat)
+        per_device = [0] * len(devices)
+        for si, rows in enumerate(counts):
+            per_device[placement.owner(si)] += rows
+        n = max(per_device)
     want, origin = _pow2_at_least(int(n), 1024), "row count"
     domain = plan.group_mode.domain_slots
     if domain is not None:
         by_domain = _pow2_at_least(2 * domain, 1024)
         if by_domain < want:
             want, origin = by_domain, "key domain"
-    stats = executor_devices()[0].memory_stats()
-    free = (stats["bytes_limit"] - stats["bytes_in_use"] if stats
-            else _UNREPORTED_FREE_BYTES)
+    free = min((st["bytes_limit"] - st["bytes_in_use"]
+                for st in (d.memory_stats() for d in devices) if st),
+               default=_UNREPORTED_FREE_BYTES)
     slot_bytes = hash_state_bytes(empty_hash_state(plan, 1, key_dtypes))
     fit = int(free * HASH_STATE_MEMORY_SHARE) // (slot_bytes * tables)
     cap = 1 << max(10, fit.bit_length() - 1)    # power of two at or under
@@ -511,19 +524,30 @@ def _hash_key_dtypes(plan: PhysicalPlan, penv: dict) -> tuple:
     return tuple(dts)
 
 
+def _device_rows(a) -> list:
+    """The rows of an array whose leading axis is sharded one row a
+    device, each as that device's own array (no program runs, and none
+    that spans the mesh)."""
+    rows = [None] * a.shape[0]
+    for sh in a.addressable_shards:
+        rows[sh.index[0].start or 0] = sh.data
+    return rows
+
+
 class _SpillDrain:
     """The hash scan's sync hook: at the points where the loop waits
     for the device anyway (one per prefetch window, not per batch) the
     window's spills come back — per batch two scalars (entries offered
     to the table, entries that lost both probes) and, only where some
     did, the spill mask and the entries it marks — and those merge into
-    the host accumulator, exactly: rider by rider (one accumulator
-    each; the serial scan's outputs are ``[n]``, a megabatched group's
-    ``[qp, n]``).  ``rows`` counts the rows of the spilled entries,
-    ``updates`` the entries offered."""
+    the host accumulator, exactly.  The serial scan's outputs are
+    ``[n]``; a megabatched group's ``[qp, n]``, rider by rider (one
+    accumulator each); the per-device tables' ``[n_dev, n]`` (``devices``
+    of them), every device's into the ONE accumulator.  ``rows`` counts
+    the rows of the spilled entries, ``updates`` the entries offered."""
 
-    def __init__(self, plan: PhysicalPlan, accs: list):
-        self.plan, self.accs = plan, accs
+    def __init__(self, plan: PhysicalPlan, accs: list, devices: int = 0):
+        self.plan, self.accs, self.devices = plan, accs, devices
         self.rows = self.updates = 0
 
     def __call__(self, pending: list) -> None:
@@ -533,9 +557,10 @@ class _SpillDrain:
             return
         import jax
         from citus_tpu.ops.hash_agg import merge_hash_tables_into
-        q = len(self.accs)
+        q = self.devices or len(self.accs)
         with _trace.span("spill_drain") as dsp:
             n_rows = n_updates = 0
+            spilling = set()
             for _, (offered, n_spilled, lost, *entries) in pending:
                 offered = np.asarray(offered)
                 serial = offered.ndim == 0
@@ -543,39 +568,112 @@ class _SpillDrain:
                 if not np.atleast_1d(np.asarray(n_spilled))[:q].any():
                     continue
                 lost = np.atleast_2d(np.asarray(lost))
-                for qi, acc in enumerate(self.accs):
+                entries = tuple(entries)
+                if self.devices:
+                    # leaves become lists: a device's row apiece
+                    entries = jax.tree_util.tree_map(_device_rows, entries)
+                for qi in range(q):
                     at = np.flatnonzero(lost[qi])
                     if not at.size:
                         continue
+                    spilling.add(qi)
                     # the marked entries are gathered on the device, in
                     # a power-of-two count so few shapes ever compile:
                     # some tens of KB come back, not the batch's lanes
                     fill = _pow2_at_least(at.size, 1024)
-                    idx = jax.device_put(np.concatenate(
-                        [at, np.full(fill - at.size, at[0])]))
+                    idx = np.concatenate(
+                        [at, np.full(fill - at.size, at[0])])
+                    if self.devices:
+                        # the indexes go to the device whose row it is
+                        take = lambda rows: rows[qi][0, idx]
+                        is_leaf = lambda x: isinstance(x, list)
+                    else:
+                        idx = jax.device_put(idx)
+                        take = lambda a: (a if serial else a[qi])[idx]
+                        is_leaf = None
                     keys, parts, rows = jax.device_get(jax.tree_util.tree_map(
-                        lambda a: (a if serial else a[qi])[idx], entries))
+                        take, entries, is_leaf=is_leaf))
                     real = np.arange(fill) < at.size
                     n_rows += int(rows[real].sum())
-                    merge_hash_tables_into(acc, self.plan, keys, parts,
-                                           rows, entry_mask=real)
+                    merge_hash_tables_into(
+                        self.accs[0 if self.devices else qi], self.plan,
+                        keys, parts, rows, entry_mask=real)
             GLOBAL_COUNTERS.bump("hash_spill_rows", n_rows)
             GLOBAL_COUNTERS.bump("hash_table_updates", n_updates)
             self.rows += n_rows
             self.updates += n_updates
             if dsp.recording:
-                dsp.set(batches=len(pending), rows=n_rows)
+                dsp.set(batches=len(pending), rows=n_rows,
+                        devices=len(spilling) if self.devices
+                        else int(bool(spilling)))
+
+
+def _table_kernel(plan: PhysicalPlan, mesh, slot: str, build, *,
+                  extra: tuple = (), replicated: tuple = (), **jit_kwargs):
+    """A hash slot's kernel for where the tables live: ``build()``'s
+    function jitted as it is (one table, ``mesh`` None) or run by each
+    device of the mesh on its own table (``per_device``; the arguments
+    at ``replicated`` go to every device whole)."""
+    if mesh is None:
+        return get_kernel(plan, slot,
+                          lambda: jit_compile(build(), **jit_kwargs),
+                          extra=extra)
+    from citus_tpu.parallel.mesh import per_device, shard_axis_size
+    return get_kernel(
+        plan, slot,
+        lambda: jit_compile(per_device(build(), mesh, replicated),
+                            **jit_kwargs),
+        extra=extra + ("mesh", shard_axis_size(mesh), "affine"))
+
+
+class _HashTables(NamedTuple):
+    """A hash scan's device state and what the endings need to know of
+    it: ``state`` is one table (arrays ``[S]``, on the default device)
+    or one a device (``[n_dev, S]``, a row a chip; ``mesh`` then says
+    where), ``disjoint`` names the distribution column where no group
+    can sit in two of the tables."""
+    state: tuple
+    mesh: object = None
+    disjoint: Optional[str] = None
+
+    @property
+    def tables(self) -> int:
+        return 1 if self.mesh is None else int(self.state[2].shape[0])
+
+    @property
+    def slots(self) -> int:
+        return int(self.state[2].shape[-1])
+
+
+def _disjoint_on(plan: PhysicalPlan) -> Optional[str]:
+    """The distribution column, where the plan's group keys hold it as
+    it is: every group then lives in ONE shard of a hash-distributed
+    table, and per-device tables fed shard-affinely share no group."""
+    from citus_tpu.catalog.catalog import DistributionMethod
+    from citus_tpu.planner.bound import BColumn
+    table = plan.bound.table
+    if table.method != DistributionMethod.HASH or table.dist_column is None:
+        return None
+    held = any(isinstance(k, BColumn) and k.name == table.dist_column
+               for k in plan.bound.group_keys)
+    return table.dist_column if held else None
 
 
 def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
-                     params, acc, penv, push_remote: bool):
-    """Device half of a hash_host plan: stream every local batch into ONE
-    donated HBM-resident hash table (kernel slot ``jit_hash_fused``),
-    draining spills into ``acc`` exactly.  With ``push_remote``,
+                     params, acc, penv, push_remote: bool) -> _HashTables:
+    """Device half of a hash_host plan: stream every local batch into
+    donated HBM-resident hash tables (kernel slot ``jit_hash_fused``),
+    draining spills into ``acc`` exactly.  ONE table on a host of one
+    device, or where the stream is a single batch; else one table a
+    DEVICE, each fed its own shards' batches (``AffineMeshPlacement``)
+    by the one-device kernel's body under ``shard_map``, no collective.
+    With ``push_remote``,
     remote-only shards ship as hash tasks first and their returned table
     partials re-insert through the fused device merge door
-    (``jit_hash_merge``); push fallbacks re-stream locally.  Returns the
-    table state, still on the device and ready: how it comes home is the
+    (``jit_hash_merge``; across the devices' tables where there are
+    several, so a group may then sit in more than one); push fallbacks
+    re-stream locally.  Returns the
+    tables, still on the device(s) and ready: how they come home is the
     caller's choice (``_fetch_hash_table``: all of it;
     ``_fetch_hash_survivors``: what HAVING leaves)."""
     import jax
@@ -589,30 +687,6 @@ def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     pstats = PipelineStats()
     _trace.set_phase("device")
     key_dtypes = _hash_key_dtypes(plan, penv)
-    step = Step(get_kernel(
-        plan, "jit_hash_fused",
-        lambda: jit_compile(build_fused_hash_worker(plan, jnp, key_dtypes),
-                            donate_argnums=0)),
-        "jit_hash_fused", "hash_fused_dispatches")
-    # one table on the default device, also on a multi-chip host; both
-    # passes (local, then push fallbacks) book into one placement
-    placement = OneDevice()
-    placement.bind(params)
-    drain = _SpillDrain(plan, [acc])
-    with _trace.span("hash_init") as sp:
-        S, slots_from = _hash_slots(cat, plan, settings, key_dtypes)
-        state = jax.device_put(empty_hash_state(plan, S, key_dtypes))
-        if sp.recording:
-            sp.set(slots=S, slots_from=slots_from)
-
-    def scan(shard_plan, state):
-        # never cached (no key): the window bounds the un-synced H2D
-        # bytes from the first round on, so peak device footprint stays
-        # O(slots) + depth x batch bytes
-        return drive(shard_plan, settings, placement, step, state, pstats,
-                     stream=_iter_padded_batches(cat, shard_plan, settings),
-                     on_sync=drain)
-
     dispatch = None
     run_plan = plan
     if push_remote:
@@ -621,34 +695,89 @@ def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
         if local != plan.shard_indexes:
             import dataclasses
             run_plan = dataclasses.replace(plan, shard_indexes=local)
+
+    def open_stream(shard_indexes):
+        import dataclasses
+        return _iter_padded_batches(
+            cat, dataclasses.replace(plan, shard_indexes=shard_indexes),
+            settings)
+
     try:
-        state = scan(run_plan, state)
+        # both passes (local, then push fallbacks) book into the one
+        # placement the first pass chose
+        placement, stream = choose_affine_placement(run_plan, open_stream,
+                                                    pstats)
+        mesh = placement.mesh
+        n_dev = 0 if mesh is None else placement.round_size
+
+        step = Step(_table_kernel(
+            plan, mesh, "jit_hash_fused",
+            lambda: build_fused_hash_worker(plan, jnp, key_dtypes),
+            donate_argnums=0), "jit_hash_fused", "hash_fused_dispatches")
+        placement.bind(params)
+        drain = _SpillDrain(plan, [acc], devices=n_dev)
+        with _trace.span("hash_init") as sp:
+            S, slots_from = _hash_slots(cat, plan, settings, key_dtypes,
+                                        placement=placement)
+            if mesh is None:
+                state = jax.device_put(empty_hash_state(plan, S, key_dtypes))
+            else:
+                # filled on the chips, each its own table: one small
+                # dispatch a query, nothing put from the host
+                from jax.sharding import NamedSharding, PartitionSpec
+
+                def hash_zero(slots):
+                    return empty_hash_state(plan, slots, key_dtypes, jnp,
+                                            tables=n_dev)
+                state = get_kernel(
+                    plan, "mesh_hash_zero",
+                    lambda: jit_compile(
+                        hash_zero, static_argnums=0,
+                        out_shardings=NamedSharding(
+                            mesh, PartitionSpec("shard"))),
+                    extra=placement.key_suffix)(S)
+            if sp.recording:
+                sp.set(slots=S, slots_from=slots_from, devices=n_dev or 1)
+
+        def scan(state, stream):
+            # never cached (no key): the window bounds the un-synced H2D
+            # bytes from the first round on, so peak device footprint stays
+            # O(slots) + depth x batch bytes
+            return drive(plan, settings, placement, step, state, pstats,
+                         stream=stream, on_sync=drain)
+
+        state = scan(state, stream)
     except BaseException:
         if dispatch is not None:
             dispatch.abort()  # no RPC thread outlives the attempt
         raise
+    disjoint = _disjoint_on(plan) if mesh is not None else None
     if dispatch is not None:
         fallback, remote = dispatch.collect()
+        if fallback or remote:
+            # the plan proves the tables apart for its own local pass
+            # alone: whatever else met them, they come home whole
+            disjoint = None
         if fallback:
-            import dataclasses
-            fb_plan = dataclasses.replace(plan, shard_indexes=fallback)
-            state = scan(fb_plan, state)
+            state = scan(state, open_stream(fallback) if mesh is None
+                         else placement.affine(fallback, open_stream))
         if remote:
-            merge_jit = get_kernel(
-                plan, "jit_hash_merge",
-                lambda: jit_compile(
-                    build_fused_entry_merge(plan, jnp, key_dtypes),
-                    donate_argnums=0))
+            merge_jit = _table_kernel(
+                plan, mesh, "jit_hash_merge",
+                lambda: build_fused_entry_merge(plan, jnp, key_dtypes),
+                donate_argnums=0)
             for table, spilled in remote:
                 if table is not None:
                     key_e, part_e, row_e = table
+                    # a peer's entries go to the tables as they come, a
+                    # slice a device: nothing says which holds a group
+                    deal = (lambda a: jnp.asarray(a)) if mesh is None \
+                        else placement.deal
                     state, espill = merge_jit(
                         state,
-                        tuple((jnp.asarray(kv), jnp.asarray(kf))
-                              for kv, kf in key_e),
-                        tuple(jnp.asarray(p) for p in part_e),
-                        jnp.asarray(row_e))
-                    espill = np.asarray(espill)
+                        tuple((deal(kv), deal(kf)) for kv, kf in key_e),
+                        tuple(deal(p) for p in part_e), deal(row_e))
+                    espill = np.asarray(espill).reshape(-1)[:len(row_e)]
                     if espill.any():
                         # fingerprint-collision losers among remote
                         # entries: merge exactly on the host
@@ -663,27 +792,42 @@ def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     pstats.device_s += clock() - t_dev
     pstats.publish(plan)
     placement.publish(plan)
+    tables = n_dev or 1
+    # table rows the scan took, and the fullest device's share of them
+    per_device = placement.device_rows if mesh is not None \
+        else [sum(n for _, n, _ in placement.task_times)]
     pl = plan.runtime_cache.setdefault("pipeline", {})
-    pl["hash_slots"] = S
+    pl["hash_tables"] = tables
+    pl["hash_slots"] = tables * S
     pl["hash_slots_from"] = slots_from
-    GLOBAL_COUNTERS.bump("hash_slots", S)
+    pl["hash_disjoint_on"] = disjoint
+    GLOBAL_COUNTERS.bump("hash_tables", tables)
+    GLOBAL_COUNTERS.bump("hash_slots", tables * S)
     pl["hash_spilled_rows"] = drain.rows
     pl["hash_table_updates"] = drain.updates
-    pl["hash_rows_in"] = sum(n for _, n, _ in placement.task_times)
+    pl["hash_rows_in"] = sum(per_device)
+    pl["hash_rows_in_max_device"] = max(per_device)
+    GLOBAL_COUNTERS.bump("hash_rows_in", sum(per_device))
+    GLOBAL_COUNTERS.bump("hash_rows_in_max_device", max(per_device))
     pl["group_rows_in"] = placement.rows_padded
     GLOBAL_COUNTERS.bump("group_rows_in", placement.rows_padded)
-    return state
+    return _HashTables(state, mesh, disjoint)
 
 
-def _fetch_hash_table(plan: PhysicalPlan, state):
-    """The whole-table ending of a hash scan: every slot comes home, as
-    (key_tables, partials, rows) host arrays."""
+def _fetch_hash_table(plan: PhysicalPlan, tables: _HashTables):
+    """The whole-table ending of a hash scan: every slot of every table
+    comes home, as (key_tables, partials, rows) host arrays, table
+    after table."""
     import jax
-    with _trace.span("fetch"):
-        fetched = jax.device_get(state)
-    h_keys = [(np.asarray(kv), np.asarray(kf)) for kv, kf in fetched[0]]
-    h_partials = tuple(np.asarray(p) for p in fetched[1])
-    h_rows = np.asarray(fetched[2])
+    with _trace.span("fetch") as sp:
+        fetched = jax.tree_util.tree_map(
+            lambda a: np.asarray(a).reshape(-1),
+            jax.device_get(tables.state))
+        if sp.recording:
+            sp.set(tables=tables.tables, entries=int(fetched[2].shape[0]))
+    h_keys = [(kv, kf) for kv, kf in fetched[0]]
+    h_partials = tuple(fetched[1])
+    h_rows = fetched[2]
     pl = plan.runtime_cache.setdefault("pipeline", {})
     pl["hash_occupancy_pct"] = round(
         100.0 * int((h_rows > 0).sum()) / h_rows.shape[0], 1)
@@ -728,15 +872,18 @@ def _device_having(plan: PhysicalPlan):
     return hoist_literals(having, len(plan.bound.param_specs))
 
 
-def _fetch_hash_survivors(plan: PhysicalPlan, state, acc, params, having):
+def _fetch_hash_survivors(plan: PhysicalPlan, tables: _HashTables, acc,
+                          params, having):
     """The filtered ending of the coordinator's hash scan: HAVING is
-    decided on the table on the chip (kernel slot ``jit_hash_having``)
+    decided on each table on its chip (kernel slot ``jit_hash_having``)
     and what can still matter comes home — the blocks that hold a
     survivor and the entries of the keys ``acc`` holds a part of (their
-    final state is entry + host part: the host decides those).  Returns
+    final state is entry + host part: the host decides those; every
+    table is probed for them).  Sound for one table, and for several
+    that share no group.  Returns
     ``(table, entry_mask, groups)``, the layout ``_finish_hash_agg``
     merges, with ``groups`` the aggregation's groups before HAVING; or
-    None where those would pass half the table and the whole of it may
+    None where those would pass half a table and the whole of it may
     as well come (a HAVING that keeps most groups, a table so small
     that most keys spilled)."""
     import jax
@@ -752,7 +899,8 @@ def _fetch_hash_survivors(plan: PhysicalPlan, state, acc, params, having):
     # the keys the accumulator holds go up in a power-of-two count and
     # the survivors' blocks are gathered in one of at least 1/256 of
     # the table, so few shapes ever compile
-    S = int(state[2].shape[0])
+    state, mesh = tables.state, tables.mesh
+    S = tables.slots
     n_host = acc.n_groups
     M = _pow2_at_least(n_host, 1024)
     least_blocks = max(8, -(-S // FILTER_BLOCK) >> 8)
@@ -761,13 +909,17 @@ def _fetch_hash_survivors(plan: PhysicalPlan, state, acc, params, having):
     generic, specs, values = having
     key_dtypes = tuple(a.dtype for a, _ in state[0])
     names = tuple(param_env_names(list(plan.bound.param_specs) + specs))
-    kernel = get_kernel(
-        plan, "jit_hash_having",
-        lambda: jit_compile(build_hash_having(plan, jnp, generic, names)),
-        extra=(repr(generic), repr(plan.agg_extract)))
+    kernel = _table_kernel(
+        plan, mesh, "jit_hash_having",
+        lambda: build_hash_having(plan, jnp, generic, names),
+        extra=(repr(generic), repr(plan.agg_extract)),
+        replicated=(1, 2, 3, 4))
     pcols, pvalids = params
     hoisted = tuple(np.asarray(v, t.device_dtype)
                     for (t, _), v in zip(specs, values))
+    # one table: its results as the first of one
+    lead = (lambda tree: tree) if mesh is not None else (
+        lambda tree: jax.tree_util.tree_map(lambda a: a[None], tree))
     with _trace.span("hash_filter") as sp:
         # the host's keys go up once; their entries come back with the
         # marks, in ONE device_get
@@ -780,7 +932,7 @@ def _fetch_hash_survivors(plan: PhysicalPlan, state, acc, params, having):
             pvalids + (np.ones((), bool),) * len(hoisted),
             host_keys, np.int32(n_host))
         marks, occupied, overflows, host_slots, entries = \
-            jax.device_get(home)
+            lead(jax.device_get(home))
         found = host_slots < S
         # the chip's verdicts count for the keys the host holds no part
         # of: an overflow among those raises as finalize_groups would,
@@ -788,34 +940,41 @@ def _fetch_hash_survivors(plan: PhysicalPlan, state, acc, params, having):
         of_host_keys = [int((bad & found).sum()) for bad in (
             sum_overflow_mask(np, ex, entries[1])
             for ex in plan.agg_extract) if bad is not None]
-        if any(n > m for n, m in zip(overflows, of_host_keys)):
+        if any(int(n.sum()) > m for n, m in zip(overflows, of_host_keys)):
             raise_sum_overflow()
-        blocks = np.flatnonzero(marks)
+        blocks = [np.flatnonzero(m) for m in marks]
         if sp.recording:
-            sp.set(slots=S, host_keys=n_host, blocks=blocks.size,
+            sp.set(slots=S, host_keys=n_host, tables=tables.tables,
+                   blocks=sum(b.size for b in blocks),
                    host_keys_in_table=int(found.sum()))
     pl = plan.runtime_cache.setdefault("pipeline", {})
-    pl["hash_occupancy_pct"] = round(100.0 * int(occupied) / S, 1)
-    n_blocks = _pow2_at_least(blocks.size, least_blocks)
+    occupied = int(occupied.sum())
+    pl["hash_occupancy_pct"] = round(100.0 * occupied / (S * len(marks)), 1)
+    n_blocks = _pow2_at_least(max(b.size for b in blocks), least_blocks)
     if n_blocks * FILTER_BLOCK + M > S // 2:
         return None
-    with _trace.span("fetch"):
+    with _trace.span("fetch") as sp:
         # a block past the last marked one repeats slot 0, masked; the
         # table's last block may run past its end: clipped and masked
-        at = (np.concatenate([blocks, np.full(n_blocks - blocks.size,
-                                              marks.size)])[:, None]
-              * FILTER_BLOCK + np.arange(FILTER_BLOCK)).reshape(-1)
-        take = get_kernel(plan, "jit_hash_take",
-                          lambda: jit_compile(hash_take))
-        survivors, kept = jax.device_get(take(
-            (state, keep), np.where(at < S, at, 0).astype(np.int32)))
+        at = np.stack([
+            (np.concatenate([b, np.full(n_blocks - b.size, marks.shape[1])]
+                            )[:, None] * FILTER_BLOCK
+             + np.arange(FILTER_BLOCK)).reshape(-1) for b in blocks])
+        take = _table_kernel(plan, mesh, "jit_hash_take", lambda: hash_take)
+        at_dev = np.where(at < S, at, 0).astype(np.int32)
+        survivors, kept = lead(jax.device_get(take(
+            (state, keep), at_dev if mesh is not None else at_dev[0])))
+        if sp.recording:
+            sp.set(tables=tables.tables, entries=int(at.size + found.size))
     entries = jax.tree_util.tree_map(
-        lambda a, b: np.concatenate([a, b]), entries, survivors)
+        lambda a, b: np.concatenate([a.reshape(-1), b.reshape(-1)]),
+        entries, survivors)
     # no entry merges twice: a host key's entry came with the first fetch
-    kept = kept & (at < S) & ~np.isin(at, host_slots[found])
+    kept = kept & (at < S) & ~np.stack([
+        np.isin(a, slots[f]) for a, slots, f in zip(at, host_slots, found)])
     pl["hash_having_on_device"] = True
-    return (entries, np.concatenate([found, kept]),
-            int(occupied) + n_host - int(found.sum()))
+    return (entries, np.concatenate([found.reshape(-1), kept.reshape(-1)]),
+            occupied + n_host - int(found.sum()))
 
 
 def _run_hash_partial_state(cat: Catalog, plan: PhysicalPlan,
@@ -866,22 +1025,35 @@ def _run_hash_partial_state(cat: Catalog, plan: PhysicalPlan,
 
 
 def _finish_hash_agg(cat: Catalog, plan: PhysicalPlan, acc, table,
-                     penv: dict, entry_mask=None, groups=None) -> list[tuple]:
+                     penv: dict, entry_mask=None, groups=None,
+                     pieces: int = 1) -> list[tuple]:
     """The exact tail of a device hash aggregation, on the caller's
     thread: the fetched ``table`` (key tables, partials, rows; of them
     the entries ``entry_mask`` marks, all where it is None) merges into
     ``acc``, which holds the spilled rows' groups already; then the
     accumulator's arrays, HAVING and the rendering of the kept groups.
     ``groups`` are the aggregation's groups before HAVING, where the
-    filtered ending left some of them on the chip."""
+    filtered ending left some of them on the chip.  ``pieces`` > 1:
+    ``table`` is that many tables end to end which may hold a group
+    more than once between them; they merge one after another, each a
+    table of distinct keys."""
     from citus_tpu.ops.hash_agg import (
         hash_state_bytes, merge_hash_tables_into,
     )
     fetched = hash_state_bytes(table)
     entries = int(table[2].shape[0])
     pl = plan.runtime_cache.setdefault("pipeline", {})
-    with _trace.span("hash_merge"):
-        merge_hash_tables_into(acc, plan, *table, entry_mask=entry_mask)
+    with _trace.span("hash_merge") as sp:
+        if pieces > 1:
+            for i in range(pieces):
+                cut = slice(i * entries // pieces, (i + 1) * entries // pieces)
+                merge_hash_tables_into(
+                    acc, plan, [(kv[cut], kf[cut]) for kv, kf in table[0]],
+                    [p[cut] for p in table[1]], table[2][cut])
+        else:
+            merge_hash_tables_into(acc, plan, *table, entry_mask=entry_mask)
+        if sp.recording:
+            sp.set(tables=pieces, entries=entries)
     with _trace.span("hash_finalize") as sp:
         key_arrays, parts = acc.finalize(
             [k.type for k in plan.bound.group_keys],
@@ -906,7 +1078,8 @@ def _run_agg_hash_host(cat: Catalog, plan: PhysicalPlan, settings: Settings,
 
     tpu backend: streaming fused device hash aggregation
     (ops/hash_agg.py build_fused_hash_worker) — one donated HBM-resident
-    table, one dispatch per batch, exact host merge of the final table
+    table (one a device where the host has several), one dispatch per
+    batch (per round), exact host merge of what comes home
     and of spilled entries; remote-only shards push hash tasks and ship
     table partials back over CTFR frames.  cpu backend (and exact
     value-set partials): full host grouping over the pull path."""
@@ -919,20 +1092,30 @@ def _run_agg_hash_host(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     penv = _params_env(plan, params)
 
     if backend != "cpu" and not _hash_has_exact(plan):
-        state = _run_hash_device(cat, plan, settings, params, acc, penv,
-                                 push_remote=True)
-        # this table is the only one of the query and every part of a
-        # group that is not in it is in ``acc`` already: where the chip
-        # can decide HAVING, only what can still matter comes home
-        having = _device_having(plan)
-        home = having and _fetch_hash_survivors(plan, state, acc, params,
+        tables = _run_hash_device(cat, plan, settings, params, acc, penv,
+                                  push_remote=True)
+        # the ending adapts to what the plan proves.  One table, or one
+        # a device that share no group (the keys hold the distribution
+        # column and each device took its own shards): every part of a
+        # group that is not in its table is in ``acc`` already, so where
+        # the chip can decide HAVING, only what can still matter comes
+        # home.  Tables that may hold a group several times come home
+        # whole and merge exactly, HAVING after the merge.
+        apart = tables.tables == 1 or tables.disjoint is not None
+        merged = 0 if apart else tables.tables
+        plan.runtime_cache.setdefault("pipeline", {})[
+            "hash_tables_merged"] = merged
+        GLOBAL_COUNTERS.bump("hash_tables_merged", merged)
+        having = apart and _device_having(plan)
+        home = having and _fetch_hash_survivors(plan, tables, acc, params,
                                                 having)
         if home:
             table, entry_mask, groups = home
             return _finish_hash_agg(cat, plan, acc, table, penv,
                                     entry_mask, groups)
         return _finish_hash_agg(cat, plan, acc,
-                                _fetch_hash_table(plan, state), penv)
+                                _fetch_hash_table(plan, tables), penv,
+                                pieces=max(1, merged))
 
     # exact value-set partials (or the cpu oracle backend) stay host-only
     # and are not elementwise-combinable — remote-only shards pull

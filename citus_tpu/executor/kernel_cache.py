@@ -199,10 +199,14 @@ def get_kernel(plan, slot: str, build: Callable[[], object],
     counter traffic — same plan object re-executing), then the global
     LRU by fingerprint, building and publishing on a true miss."""
     rc = plan.runtime_cache
+    extra = tuple(extra)
     k = rc.get(slot)
-    if k is not None:
+    # the mirror holds one kernel a slot: it serves only what it was
+    # built for (a slot's kernel differs by ``extra``: where its state
+    # lives, the HAVING it compiled)
+    if k is not None and rc.get(slot + "#extra", ()) == extra:
         return k
-    key = (plan_fingerprint(plan), slot) + tuple(extra)
+    key = (plan_fingerprint(plan), slot) + extra
     k = GLOBAL_KERNELS.get(key)
     if k is None:
         _counters().bump("kernel_cache_misses")
@@ -215,6 +219,7 @@ def get_kernel(plan, slot: str, build: Callable[[], object],
         with _trace.span("kernel", slot=slot, cache="hit"):
             pass
     rc[slot] = k
+    rc[slot + "#extra"] = extra
     return k
 
 

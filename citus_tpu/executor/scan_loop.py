@@ -9,7 +9,9 @@ and the only one.  What differs between the scans is data:
 - a **placement** says where a round's inputs live: how many batches
   make a round, how they are put, how parameters ride along, the cache
   key's suffix, how a round's bytes and seconds are attributed.
-  ``OneDevice`` and ``MeshPlacement``; ``choose_placement`` picks;
+  ``OneDevice``, ``MeshPlacement`` and its shard-affine sibling
+  ``AffineMeshPlacement`` (per-device state: a shard always meets the
+  same device); ``choose_placement`` / ``choose_affine_placement`` pick;
 - a **step** is the compiled kernel, ``state' = fn(state, cols, valids,
   row_mask)`` — or ``(state', aux)`` beside a sync hook — with the slot
   and counter names its rounds are booked under;
@@ -133,8 +135,9 @@ def _repad_batch(b: ShardBatch, bucket: int) -> ShardBatch:
 
 
 class MeshPlacement:
-    """A round is up to ``n_dev`` host batches, re-padded to the round's
-    largest bucket, filled up with empty batches and stacked along the
+    """A round is up to ``n_dev`` host batches (a member may be None: a
+    device with nothing this round), re-padded to the round's largest
+    bucket, filled up with empty batches and stacked along the
     shard axis: one ``(cols, valids, row_mask)`` triple of device-sharded
     stacks, a different structure than the one-device ShardBatch list, so
     its cache entries key apart.  Parameters replicate across the shard
@@ -165,10 +168,11 @@ class MeshPlacement:
         import jax
         n_cols = range(len(plan.scan_columns))
         with _trace.span("stack") as sp:
-            bucket = max(b.padded_rows for b in members)
-            buf = [_repad_batch(b, bucket) for b in members]
-            buf += [empty_batch(plan.bound.table, plan, bucket, -1)
-                    ] * (self.round_size - len(buf))
+            bucket = max(b.padded_rows for b in members if b is not None)
+            filler = empty_batch(plan.bound.table, plan, bucket, -1)
+            buf = [filler if b is None else _repad_batch(b, bucket)
+                   for b in members]
+            buf += [filler] * (self.round_size - len(buf))
             cols = tuple(np.stack([b.cols[i] for b in buf]) for i in n_cols)
             valids = tuple(np.stack([b.valids[i] for b in buf])
                            for i in n_cols)
@@ -218,6 +222,72 @@ class MeshPlacement:
         plan.runtime_cache["mesh_task_times"] = self.mesh_task_times
 
 
+class AffineMeshPlacement(MeshPlacement):
+    """The mesh placement for a step whose state is PER DEVICE (one
+    hash table a chip): its rounds are shard-affine.  Member ``i`` of
+    every round is a batch of a shard that device ``i`` owns, by a
+    fixed map from the shard's index in its table to a device -- the
+    contiguous ``n_shards / n_dev`` a device -- or None where that
+    device has run dry.  So whatever a shard holds meets one device
+    alone, scan after scan: tables of groups that live in one shard
+    (a key that holds the distribution column) stay disjoint."""
+
+    def __init__(self, mesh, n_shards: int) -> None:
+        super().__init__(mesh)
+        self.n_shards = max(1, n_shards)
+        self.key_suffix = ("mesh", self.round_size, "affine")
+        self.device_rows = [0] * self.round_size    # table rows a device took
+
+    def owner(self, shard_index: int) -> int:
+        return shard_index * self.round_size // self.n_shards
+
+    def affine(self, shard_indexes: list, open_stream: Callable) -> Iterator:
+        """The batches of ``shard_indexes`` in round order: each round
+        is ``round_size`` items, item ``i`` the next batch of device
+        ``i``'s own shards (``open_stream(its shards)``: one stream a
+        device, all pulled by whoever pulls this one) or None.  Ends
+        with the last round that holds a batch."""
+        owned = [[si for si in shard_indexes if self.owner(si) == d]
+                 for d in range(self.round_size)]
+        streams = [open_stream(mine) if mine else iter(()) for mine in owned]
+        try:
+            while True:
+                members = [next(s, None) for s in streams]
+                if all(m is None for m in members):
+                    return
+                yield from members
+        finally:
+            for s in streams:
+                close = getattr(s, "close", None)
+                if close is not None:
+                    close()
+
+    def deal(self, a):
+        """A host array of entries, any of which may go to any device:
+        padded with zeros to a multiple of the devices and put on the
+        mesh a contiguous slice a device, ``[n_dev, len / n_dev]``."""
+        import jax
+        a = np.asarray(a)
+        n = self.round_size
+        a = np.concatenate([a, np.zeros(-len(a) % n, a.dtype)])
+        return jax.device_put(a.reshape(n, -1), self.sharding)
+
+    @staticmethod
+    def _real(members):
+        return members and [m for m in members if m is not None]
+
+    def describe(self, members, inputs: tuple) -> dict:
+        return super().describe(self._real(members), inputs)
+
+    def book(self, members, inputs, nbytes: int, round_s: float,
+             dispatch_s: float) -> None:
+        for d, m in enumerate(members or ()):
+            if m is not None:
+                self.device_rows[d] += m.n_rows
+        super().book(self._real(members), inputs, nbytes, round_s,
+                     dispatch_s)
+
+
 def _lookup(make_key: Callable[[], Optional[tuple]], mesh: bool):
     """-> (cache key | None, the device inputs cached under it | None)."""
     with _trace.span("cache_lookup") as sp:
@@ -260,6 +330,37 @@ def choose_placement(plan, data_dir: str, use_cache: bool,
     if len(head) < 2:
         return OneDevice(), key, None, iter(head)   # 0 or 1 batch
     return placement, mkey, None, itertools.chain(head, stream)
+
+
+def choose_affine_placement(plan, open_stream: Callable[[list], Iterator],
+                            pstats: PipelineStats):
+    """Where the rounds of a scan with per-device state live, from what
+    the code observes, as ``choose_placement`` does: one device, or a
+    stream of a single batch -> ``OneDevice`` (and the stream as it
+    always was); several devices -> ``AffineMeshPlacement`` and the
+    stream in its round order.  ``open_stream(shard_indexes)`` opens
+    the host batches of those shards.  -> (placement, host stream)."""
+    from citus_tpu.parallel.mesh import default_mesh, executor_devices
+    with _trace.span("scan_setup"):
+        devices = executor_devices()
+        if len(devices) == 1:
+            return OneDevice(), open_stream(plan.shard_indexes)
+        placement = AffineMeshPlacement(default_mesh(),
+                                        plan.bound.table.shard_count)
+    stream = placement.affine(plan.shard_indexes, open_stream)
+    t_peek = clock()
+    head: list = []
+    while sum(m is not None for m in head) < 2:
+        try:
+            head.append(next(stream))
+        except StopIteration:
+            break
+    pstats.host_decode_s += clock() - t_peek
+    real = [m for m in head if m is not None]
+    if len(real) < 2:
+        stream.close()
+        return OneDevice(), iter(real)      # 0 or 1 batch
+    return placement, itertools.chain(head, stream)
 
 
 # ---------------------------------------------------------------- driver

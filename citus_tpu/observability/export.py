@@ -106,6 +106,10 @@ METRIC_HELP = {
     "direct_groups_out": "groups those aggregations returned",
     "direct_bytes_fetched": "bytes of their partial states fetched",
     "hash_slots": "slots of the device hash tables made",
+    "hash_tables": "device hash tables made (one a device where a host has several)",
+    "hash_tables_merged": "per-device hash tables fetched whole and merged (a group may sit in several)",
+    "hash_rows_in": "table rows the hash scans took",
+    "hash_rows_in_max_device": "of those, the rows of each scan's fullest device",
     "group_rows_in": "padded rows the grouping stages ran over",
     "group_rows_kept": "rows of grouped scans that passed the WHERE",
     "hash_groups_out": "groups of hash aggregations, before HAVING",

@@ -1,8 +1,10 @@
 """Device-side hash aggregation for unbounded GROUP BY cardinality.
 
 When the key domain can't be proven small (no direct-gid mode), the
-executor aggregates on device into ONE fixed-size open-addressed hash
-table that lives in HBM for the whole scan: ``build_fused_hash_worker``
+executor aggregates on device into a fixed-size open-addressed hash
+table that lives in HBM for the whole scan (one a query, or one a
+device of a multi-chip host, each over its own shards: the executor's
+``_run_hash_device``): ``build_fused_hash_worker``
 composes the whole of a batch's work in a single traced body, so the
 executor jits it with ``donate_argnums=0`` (kernel-cache slot
 ``jit_hash_fused``) and XLA reuses the table buffers in place — one
@@ -546,27 +548,31 @@ def hash_take(tree, at):
     return jax.tree_util.tree_map(lambda a: a[at], tree)
 
 
-def empty_hash_state(plan: PhysicalPlan, slots: int, key_dtypes: tuple):
-    """Host-built empty table state for the fused kernels: key value
+def empty_hash_state(plan: PhysicalPlan, slots: int, key_dtypes: tuple,
+                     xp=np, tables: int = 0):
+    """Empty table state for the fused kernels: key value
     tables filled with their dtype minimum (neutral under ``.at[].max``
     claims), int8 flag tables at 0 (1 = stored null, 2 = stored valid),
-    partial tables at their op's identity/sentinel, rows at 0."""
-    S = int(slots)
+    partial tables at their op's identity/sentinel, rows at 0.  Built
+    on the host by default; with ``xp`` = jax.numpy inside a jitted
+    function it is filled where its output lives.  ``tables`` > 0 gives
+    every array a leading axis of that many tables (one a device)."""
+    shape = (int(tables), int(slots)) if tables else (int(slots),)
     key_tables = []
     for kdt in key_dtypes:
         kdt = np.dtype(kdt)
-        key_tables.append((np.full((S,), _key_sentinel(kdt), kdt),
-                           np.zeros((S,), np.int8)))
+        key_tables.append((xp.full(shape, _key_sentinel(kdt), kdt),
+                           xp.zeros(shape, np.int8)))
     partials = []
     for op in plan.partial_ops:
         dt = np.dtype(op.dtype)
         if op.kind == "count" or op.arg_index < 0:
-            partials.append(np.zeros((S,), np.int64))
+            partials.append(xp.zeros(shape, np.int64))
         elif op.kind == "sum":
-            partials.append(np.zeros((S,), dt))
+            partials.append(xp.zeros(shape, dt))
         else:
-            partials.append(np.full((S,), dt.type(_sentinel(op.kind, dt)), dt))
-    return tuple(key_tables), tuple(partials), np.zeros((S,), np.int64)
+            partials.append(xp.full(shape, dt.type(_sentinel(op.kind, dt)), dt))
+    return tuple(key_tables), tuple(partials), xp.zeros(shape, np.int64)
 
 
 def hash_state_bytes(state) -> int:

@@ -127,3 +127,32 @@ def zero_partials(empty_partials: Callable[[], tuple], mesh: Mesh) -> Callable:
         return empty_partials()
 
     return jit_compile(zero_acc, out_shardings=NamedSharding(mesh, P()))
+
+
+def per_device(fn: Callable, mesh: Mesh, replicated: tuple = ()) -> Callable:
+    """``fn`` run by every chip of the mesh on its own slice, with no
+    collective: each argument and each result is a pytree of arrays
+    with a leading device axis, sharded over ``shard`` (a chip sees its
+    slice without that axis and returns its results without it) --
+    except the arguments at the positions ``replicated``, which every
+    chip gets whole.  The per-device hash tables' steps: what one chip
+    does to its table is the one-device kernel's body, to the letter.
+    The wrapper keeps ``fn``'s name, so the XLA module of the jitted
+    wrapper is named as the one-device kernel's is."""
+    import functools
+
+    def body(*args):
+        args = [a if i in replicated
+                else jax.tree_util.tree_map(lambda x: x[0], a)
+                for i, a in enumerate(args)]
+        return jax.tree_util.tree_map(lambda x: jnp.asarray(x)[None],
+                                      fn(*args))
+
+    @functools.wraps(fn)
+    def on_each(*args):
+        specs = tuple(P() if i in replicated else P(SHARD_AXIS)
+                      for i in range(len(args)))
+        return jax.shard_map(body, mesh=mesh, in_specs=specs,
+                             out_specs=P(SHARD_AXIS), check_vma=False)(*args)
+
+    return on_each

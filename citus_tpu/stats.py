@@ -215,6 +215,16 @@ class StatCounters:
         "hash_slots",
         "group_rows_in",
         "group_rows_kept",
+        # device hash tables a query built (one, or one a device of a
+        # multi-chip host) and, of those, the tables that came home
+        # whole to be merged because a group may sit in several (0
+        # where they are disjoint); table rows the hash scans took and
+        # the rows of each scan's fullest device (the balance of the
+        # shard-to-device map)
+        "hash_tables",
+        "hash_tables_merged",
+        "hash_rows_in",
+        "hash_rows_in_max_device",
         # pull-path placement syncs skipped because the control plane's
         # data-invalidation epoch proved the local mirror current
         # (net/data_plane.py sync_placement fast path)

@@ -180,7 +180,8 @@ def test_smoke_rehearsal_passes_every_leg(tmp_path, n_dev):
     report = json.loads((tmp_path / "out" / "report.json").read_text())
     assert report["rehearsal"] is True and report["platform"] == "cpu"
     legs = {leg["leg"].split()[0]: leg for leg in report["legs"]}
-    single = {"1", "2", "3", "3b", "4", "5", "5b", "5c", "5d", "5e", "6"}
+    single = {"1", "2", "3", "3b", "4", "5", "5b", "5c", "5d", "5e", "5f",
+              "6"}
     assert set(legs) == (single | {"7a", "7b", "7c", "7d"} if n_dev > 1
                          else single)
     assert all(leg["ok"] for leg in legs.values())
@@ -197,6 +198,12 @@ def test_smoke_rehearsal_passes_every_leg(tmp_path, n_dev):
         == "jit_hash_fused"
     assert "the plan's route" in legs["5d"]["leg"] \
         and "direct_gid_limit 65536" in legs["5e"]["leg"]
+    # Q18's block: a hash table on every device, apart on the
+    # distribution column
+    assert legs["5f"]["kernel_slot"] == "jit_hash_fused"
+    assert legs["5f"]["hash_tables"] == n_dev and legs["5f"]["groups"] >= 1
+    assert legs["5f"]["hash_disjoint_on"] == \
+        ("l_orderkey" if n_dev > 1 else None)
     assert legs["6"]["kernel_slot"] == "jit_filter"
     if n_dev > 1:
         assert "devjoin" in legs["7a"]["shuffle"]

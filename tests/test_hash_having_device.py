@@ -320,11 +320,15 @@ def test_sum_overflow_still_raises_for_a_group_having_drops(tmp_path,
 # ------------------------------------------------------- 2-host push
 
 
-def test_pushed_partials_with_having_equal_the_pull_path(pair):
+def test_pushed_partials_with_having_equal_the_pull_path(pair,
+                                                         limit_devices):
     """The coordinator decides HAVING on its one table after the remote
     partials merged into it; the worker ships its whole table (a worker
-    plan has no HAVING)."""
+    plan has no HAVING).  One device: across several, a peer's entries
+    are dealt to the tables as they come and the tables merge on the
+    host (tests/test_hash_agg_mesh.py)."""
     from citus_tpu.executor import executor
+    limit_devices(1)
     a, b, na, nb = pair
     a.execute("CREATE TABLE t (k bigint NOT NULL, g bigint, v bigint)")
     a.execute("SELECT create_distributed_table('t', 'k', 4)")

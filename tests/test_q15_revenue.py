@@ -366,10 +366,12 @@ def test_view_on_the_hash_table_with_null_discounts(sparse, date):
     assert rows_in >= 60_000 > 20 * kept and spilled < kept
 
 
-def test_hash_slots_bounded_by_the_key_domain(tmp_path):
+def test_hash_slots_bounded_by_the_key_domain(tmp_path, limit_devices):
     """A key domain past ``DIRECT_MAX_SLOTS`` under many rows whose
     partial (a max) cannot ride the product: the table is sized by the
-    domain, not by the catalog's row count."""
+    domain, not by the catalog's row count.  (One device, one table:
+    tests/test_hash_agg_mesh.py sizes the per-device tables.)"""
+    limit_devices(1)
     cl = new_cluster(tmp_path / "db", shards=4)
     n, keys = 200_000, 70_000
     rows = make_rows(n, keys, 17)
@@ -404,11 +406,13 @@ def test_forced_spill_stays_exact(sparse):
     want, kept = reference(rows, "1995-02-01")
     cl.execute("SET citus.hash_agg_slots = 64")
     try:
-        assert sorted(cl.execute(view("1995-02-01")).rows,
-                      key=lambda t: t[0]) == want
+        r = cl.execute(view("1995-02-01"))
+        assert sorted(r.rows, key=lambda t: t[0]) == want
         slots, spilled, groups, *_rest, origin = \
             hash_line(analyze(cl, view("1995-02-01")))
-        assert (slots, origin) == (64, "setting")
+        # the setting is a table's slots, and a device has a table
+        tables = r.explain["pipeline"]["hash_tables"]
+        assert (slots, origin) == (64 * tables, "setting")
         assert spilled > kept // 2 and groups == len(want)
     finally:
         cl.execute("SET citus.hash_agg_slots = auto")

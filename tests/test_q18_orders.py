@@ -110,10 +110,15 @@ def test_engine_equals_the_plain_reference(cluster, table, quantity):
         assert slots == 1024 and spilled > rows // 2
     else:
         # derived: the next power of two at or above the catalog's rows
+        # (four devices: a table a device, each sized by the rows of the
+        # fullest device's own shards, 4,096 slots, 16,384 in all)
         assert slots == 1 << (rows - 1).bit_length() == 16_384
         assert spilled < 0.05 * rows
+    text = "\n".join(l for (l,) in cl.execute(f"EXPLAIN ANALYZE {sql}").rows)
+    assert ("tables 4 x 4096 slots, disjoint on l_orderkey" in text) \
+        == (mode == "four_devices")
     # one int64 key + its int8 flag, sum / count / float64 shadow, rows
-    if mode != "forced_spill" and quantity >= 312:
+    if mode == "defaults" and quantity >= 312:
         # the cell's four QUANTITY values: HAVING is decided on the
         # table on the chip; the spilled keys' entries (1,024, their
         # power of two) and the survivors' blocks (8 of 512 slots, the
@@ -121,6 +126,8 @@ def test_engine_equals_the_plain_reference(cluster, table, quantity):
         assert entries == 1024 + 8 * 512
         assert fetched == entries * 41
     elif entries is None:
+        # also four tables of 4,096 slots: the least that comes home
+        # filtered (8 blocks and 1,024 keys) is more than half a table
         assert fetched == slots * 41
     else:
         assert fetched == entries * 41 <= slots * 41 // 2

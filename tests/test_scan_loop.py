@@ -202,7 +202,8 @@ def test_the_hash_step_drains_at_every_window_and_once_at_the_end(
         np.add.at(sums, k % 1500, k % 7)
         assert sorted(r.rows) == sorted(zip(g.tolist(), sums.tolist(),
                                             [4] * 1500))
-        (_keys, _partials, h_rows), = tables
+        (_keys, _partials, h_rows) = tables[0].state
+        assert len(tables) == 1 and tables[0].tables == 1
         spilled = c1["hash_spill_rows"] - c0["hash_spill_rows"]
         assert spilled == ROWS - int(h_rows.sum()) > ROWS // 2
         assert r.explain["pipeline"]["hash_spilled_rows"] == spilled

@@ -128,9 +128,22 @@ def test_remote_spans_share_trace_id_and_nest(pair, tmp_path):
     a.execute("SELECT create_distributed_table('t', 'k', 4)")
     a.copy_from("t", columns={"k": np.arange(n), "v": np.arange(n)})
     export = tmp_path / "traces"
+    sql = "SELECT count(*), sum(v) FROM t"
+    # A worker's first task compiles its kernels inside the RPC, and on
+    # a loaded machine (the suite under several workers, cold compile
+    # caches) that can outlast the RPC's time limit: the push then
+    # falls back to a local scan -- the right answer, and no worker
+    # span to graft.  What the program guarantees, and what is asserted
+    # below, is the tree of a push that SUCCEEDED: warm the statement's
+    # kernels on both hosts until a run pushes without a fallback.
+    for _ in range(6):
+        before = a.counters.snapshot()["remote_task_fallbacks"]
+        assert a.execute(sql).rows == [(n, n * (n - 1) // 2)]
+        if a.counters.snapshot()["remote_task_fallbacks"] == before:
+            break
     a.execute("SET citus.trace_sample_rate = 1.0")
     a.execute(f"SET citus.trace_export_dir = '{export}'")
-    r = a.execute("SELECT count(*), sum(v) FROM t")
+    r = a.execute(sql)
     assert r.rows == [(n, n * (n - 1) // 2)]
     tr = T.last_trace()
     root = tr.root()
@@ -166,6 +179,38 @@ def test_remote_spans_share_trace_id_and_nest(pair, tmp_path):
     # coordinator's
     pids = {e["pid"] for e in evts if e["name"] == "execute_task"}
     assert pids and 1 not in pids
+
+
+def test_a_push_that_fails_falls_back_and_grafts_nothing(pair):
+    """The other half of what the program guarantees: a pushed task
+    that fails (here: the worker raises; on a loaded machine: the RPC
+    outlasts its time limit while the worker compiles) rescans locally,
+    the answer is right, its remote_task span says ok=False and no
+    worker span hangs under it; the next push grafts again."""
+    from citus_tpu.testing.faults import FAULTS
+    a, b = pair
+    n = 4000
+    a.execute("CREATE TABLE t (k bigint NOT NULL, v bigint)")
+    a.execute("SELECT create_distributed_table('t', 'k', 4)")
+    a.copy_from("t", columns={"k": np.arange(n), "v": np.arange(n)})
+    a.execute("SET citus.trace_sample_rate = 1.0")
+    sql = "SELECT count(*), sum(v) FROM t"
+    FAULTS.arm("execute_task", error=RuntimeError("worker down"))
+    try:
+        before = a.counters.snapshot()["remote_task_fallbacks"]
+        assert a.execute(sql).rows == [(n, n * (n - 1) // 2)]
+        tr = T.last_trace()
+    finally:
+        FAULTS.disarm()
+    rtasks = tr.find_all("remote_task")
+    assert rtasks and not any(s.attrs["ok"] for s in rtasks)
+    assert a.counters.snapshot()["remote_task_fallbacks"] - before \
+        == len(rtasks)
+    assert tr.find_all("execute_task") == []
+    assert a.execute(sql).rows == [(n, n * (n - 1) // 2)]
+    tr = T.last_trace()
+    assert all(s.attrs["ok"] for s in tr.find_all("remote_task"))
+    assert len(tr.find_all("execute_task")) == len(rtasks)
 
 
 def test_explain_analyze_renders_from_span_tree(pair):
