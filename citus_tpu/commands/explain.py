@@ -221,6 +221,11 @@ def _run_analyze(cl, stmt: A.Explain) -> list[str]:
                          for s in tr.find_all("kernel_compile")))
     lines.append(f"  Plan Cache: {'hit' if hit else 'miss'}  "
                  f"fingerprint {fp}  compile {compile_ms} ms")
+    pa = r.explain.get("partials")
+    if pa:
+        g, n = pa["overflow_guards_proved_away"], pa["null_counts_proved_away"]
+        lines.append(f"  Partials: {pa['computed']} computed, {g + n} proved "
+                     f"away: {g} overflow guards, {n} null counts")
     dh = c1.get("device_cache_hits", 0) - c0.get("device_cache_hits", 0)
     dm = c1.get("device_cache_misses", 0) - c0.get("device_cache_misses", 0)
     lines.append(f"  Device Cache: {dh} hit(s), {dm} miss(es)")

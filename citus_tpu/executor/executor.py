@@ -50,6 +50,7 @@ from citus_tpu.planner.bound import (
 )
 from citus_tpu.planner.physical import (
     PhysicalPlan, _index_eq, extract_intervals, plan_select, prune_shards,
+    sees_staged_rows,
 )
 from citus_tpu.stats import StatCounters
 
@@ -1279,6 +1280,12 @@ def execute_select(cat: Catalog, bound: BoundSelect, settings: Settings,
                    param_values: Optional[list] = None) -> Result:
     t0 = clock()
     _guard_remote_written(cat, [bound.table.name])
+    if plan is not None and any(plan.proved_away) \
+            and sees_staged_rows(bound.table):
+        # a cached plan dropped partial states on the strength of the
+        # table's statistics, and this scan sees rows they do not cover
+        # (the transaction's own staged writes): plan with every guard
+        plan = None
     if plan is None:
         with _trace.span("plan_physical"):
             plan = plan_select(
@@ -1416,6 +1423,15 @@ def _finish_select(bound: BoundSelect, plan: PhysicalPlan, rows: list[tuple],
     }
     if megabatch:
         explain["megabatch"] = dict(megabatch)
+    if bound.has_aggs:
+        # the states this plan's kernels compute, and the overflow
+        # guards and NULL counts the table's statistics proved away
+        guards, counts = plan.proved_away
+        explain["partials"] = {"computed": len(plan.partial_ops),
+                               "overflow_guards_proved_away": guards,
+                               "null_counts_proved_away": counts}
+        GLOBAL_COUNTERS.bump("agg_partials", len(plan.partial_ops))
+        GLOBAL_COUNTERS.bump("agg_partials_proved_away", guards + counts)
     return Result(
         columns=visible,
         rows=rows,

@@ -105,6 +105,8 @@ METRIC_HELP = {
     "direct_groups": "slots of the group domains of direct-group-id aggregations",
     "direct_groups_out": "groups those aggregations returned",
     "direct_bytes_fetched": "bytes of their partial states fetched",
+    "agg_partials": "partial states the plans of aggregate queries computed",
+    "agg_partials_proved_away": "overflow guards and null counts the table statistics proved redundant",
     "hash_slots": "slots of the device hash tables made",
     "hash_tables": "device hash tables made (one a device where a host has several)",
     "hash_tables_merged": "per-device hash tables fetched whole and merged (a group may sit in several)",

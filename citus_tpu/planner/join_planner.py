@@ -269,7 +269,7 @@ def bind_join_select(catalog: Catalog, stmt: A.Select) -> BoundJoinSelect:
         order_by[oi_pos] = (len(final_exprs) - 1, asc, nf)
         hidden += 1
 
-    agg_args, partial_ops, agg_extract = lower_aggregates(aggs)
+    agg_args, partial_ops, agg_extract, _ = lower_aggregates(aggs)
 
     # ---- column requirements per relation ------------------------------
     def note_columns(e: Optional[BExpr]):

@@ -32,10 +32,10 @@ import numpy as np
 from citus_tpu import types as T
 from citus_tpu.catalog import Catalog, TableMeta
 from citus_tpu.catalog.hashing import hash_int64_scalar
-from citus_tpu.catalog.stats import column_bounds
+from citus_tpu.catalog.stats import TableFacts, column_bounds, table_facts
 from citus_tpu.planner.bind import AggSpec, BoundSelect
 from citus_tpu.planner.bound import (
-    BBinOp, BCast, BColumn, BDateTrunc, BExpr, BLiteral, BScale, BUnOp,
+    BBinOp, BCase, BCast, BColumn, BDateTrunc, BExpr, BLiteral, BScale, BUnOp,
 )
 from citus_tpu.storage.reader import Interval
 
@@ -90,6 +90,9 @@ class PhysicalPlan:
     agg_args: list[BExpr]           # deduped aggregate input expressions
     partial_ops: list[PartialOp]
     agg_extract: list[AggExtract]
+    # partial states ``lower_aggregates`` proved redundant from the
+    # table's statistics and did not emit: (overflow guards, null counts)
+    proved_away: tuple = (0, 0)
     # executor-populated cache of jitted kernels; lives with the plan so a
     # plan cache hit skips XLA recompilation (the analog of the reference's
     # prepared-statement local plan cache, local_plan_cache.c)
@@ -251,6 +254,71 @@ def _key_domain(cat: Catalog, table: TableMeta, key: BExpr,
     return None
 
 
+_INT64_MAX = (1 << 63) - 1
+
+
+def arg_facts(facts: Optional[TableFacts], table: TableMeta,
+              e: BExpr) -> Optional[tuple[int, int, bool]]:
+    """``(lo, hi, nullable)`` of an aggregate argument over every row a
+    scan of ``table`` can return, proved from the footers' ``facts``:
+    exact Python integers over PHYSICAL (scaled) values, ``nullable``
+    False only where no column read has a NULL and no node makes one.
+    None = nothing proved: no facts, a column without bounds, a
+    parameter, a division, a function, a float — or an intermediate
+    that may leave int64, where the kernel's arithmetic would wrap and
+    the interval no longer holds."""
+    if facts is None or not (e.type.is_integer or e.type.is_decimal):
+        return None
+
+    def sub(x):
+        return arg_facts(facts, table, x)
+
+    out = None
+    if isinstance(e, BColumn):
+        if table.schema.has(e.name):
+            b = facts.columns.get(table.schema.column(e.name).storage_name)
+            out = None if b is None else (int(b[0]), int(b[1]), bool(b[2]))
+    elif isinstance(e, BLiteral):
+        if e.value is not None:
+            out = (int(e.value), int(e.value), False)
+    elif isinstance(e, BUnOp) and e.op == "-":
+        a = sub(e.operand)
+        out = a and (-a[1], -a[0], a[2])
+    elif isinstance(e, BScale) and e.power >= 0:
+        a = sub(e.operand)
+        out = a and (a[0] * 10 ** e.power, a[1] * 10 ** e.power, a[2])
+    elif isinstance(e, BCast):
+        # the casts that only rescale upward (or widen an integer)
+        src, up = e.operand.type, None
+        if src.is_decimal and e.type.is_decimal:
+            up = e.type.scale - src.scale
+        elif src.is_integer:
+            up = e.type.scale if e.type.is_decimal else 0
+        a = sub(e.operand) if up is not None and up >= 0 else None
+        out = a and (a[0] * 10 ** up, a[1] * 10 ** up, a[2])
+    elif isinstance(e, BBinOp) and e.op in ("+", "-", "*"):
+        a, b = sub(e.left), sub(e.right)
+        if a and b:
+            if e.op == "+":
+                lo, hi = a[0] + b[0], a[1] + b[1]
+            elif e.op == "-":
+                lo, hi = a[0] - b[1], a[1] - b[0]
+            else:
+                corners = [x * y for x in a[:2] for y in b[:2]]
+                lo, hi = min(corners), max(corners)
+            out = (lo, hi, a[2] or b[2])
+    elif isinstance(e, BCase):
+        # the union of its arms; no arm taken is a NULL
+        arms = [sub(v) for _, v in e.whens]
+        arms += [sub(e.else_)] if e.else_ is not None else []
+        if all(arms):
+            out = (min(a[0] for a in arms), max(a[1] for a in arms),
+                   e.else_ is None or any(a[2] for a in arms))
+    if out is None or max(abs(out[0]), abs(out[1])) > _INT64_MAX:
+        return None
+    return out
+
+
 def shadow_sources(partial_ops, agg_args) -> dict:
     """{partial index: (arg index of the int64 sum it guards, divisor)}
     for the float64 shadow sums ``lower_aggregates`` puts beside every
@@ -379,12 +447,24 @@ def choose_group_mode(cat: Catalog, bound: BoundSelect, direct_limit: int = 0,
 # ------------------------------------------------------ aggregate split
 
 
-def lower_aggregates(aggs: list[AggSpec]) -> tuple[list[BExpr], list[PartialOp], list[AggExtract]]:
-    """SQL aggregates -> deduped partial ops (the worker half) and
-    extraction recipes (the combine/final half)."""
+def lower_aggregates(aggs: list[AggSpec], prove=None, rows: int = 0
+                     ) -> tuple[list[BExpr], list[PartialOp],
+                                list[AggExtract], tuple[int, int]]:
+    """SQL aggregates -> deduped partial ops (the worker half),
+    extraction recipes (the combine/final half) and the partial states
+    proved away: ``(overflow guards, null counts)`` not emitted.
+
+    ``prove(arg)`` is ``arg_facts`` over the scanned table's statistics
+    and ``rows`` their bound on the rows a scan returns (``plan_select``;
+    a join, whose rows multiply, passes none).  A partial state they
+    prove redundant is not emitted: the NULL count of an argument that
+    has no NULL is ``count(*)``, and an int64 sum that ``rows`` times the
+    argument's largest magnitude cannot push out of int64 — exact
+    integer arithmetic, no margin — carries no float64 shadow."""
     agg_args: list[BExpr] = []
     partials: list[PartialOp] = []
     extracts: list[AggExtract] = []
+    gone: list[tuple] = []   # (guard | count, argument), each once
 
     def arg_slot(e: BExpr) -> int:
         for i, a in enumerate(agg_args):
@@ -402,22 +482,32 @@ def lower_aggregates(aggs: list[AggSpec]) -> tuple[list[BExpr], list[PartialOp],
         partials.append(op)
         return len(partials) - 1
 
+    def proved(what: str, arg: BExpr) -> None:
+        if (what, arg) not in gone:
+            gone.append((what, arg))
+
+    def null_count(arg: BExpr, facts) -> int:
+        if facts is not None and not facts[2]:
+            proved("count", arg)
+            return partial_slot("count", -1, "int64")
+        return partial_slot("count", arg_slot(arg), "int64")
+
     for spec in aggs:
         if spec.kind == "count_star":
             s = partial_slot("count", -1, "int64")
             extracts.append(AggExtract("count_star", [s], spec.out_type))
             continue
-        ai = arg_slot(spec.arg)
         acc_dtype = "float64" if spec.arg.type.is_float else "int64"
+        facts = prove(spec.arg) if prove else None
         if spec.kind == "count" and spec.distinct:
-            s = partial_slot("distinct", ai, "int64")
+            s = partial_slot("distinct", arg_slot(spec.arg), "int64")
             extracts.append(AggExtract("count_distinct", [s], spec.out_type))
         elif spec.kind == "count":
-            s = partial_slot("count", ai, "int64")
+            s = null_count(spec.arg, facts)
             extracts.append(AggExtract("count", [s], spec.out_type))
         elif spec.kind in ("sum", "avg"):
-            s = partial_slot("sum", ai, acc_dtype)
-            c = partial_slot("count", ai, "int64")
+            s = partial_slot("sum", arg_slot(spec.arg), acc_dtype)
+            c = null_count(spec.arg, facts)
             slots = [s, c]
             if acc_dtype == "int64" and spec.arg.type.is_numeric:
                 # overflow guard (round-4 weak #7): an int64 partial sum
@@ -427,23 +517,30 @@ def lower_aggregates(aggs: list[AggSpec]) -> tuple[list[BExpr], list[PartialOp],
                 # fits, and |shadow| >= 2^62 proves it cannot (float
                 # error is relative, far below the 2x margin).  The
                 # reference's NUMERIC never overflows; we error instead
-                # of silently wrapping.
-                from citus_tpu.planner.bound import BCast
-                fa = arg_slot(BCast(spec.arg, T.FLOAT64_T))
-                slots.append(partial_slot("sum", fa, "float64"))
+                # of silently wrapping.  No shadow where the statistics
+                # prove that the sum fits.
+                if facts is not None and \
+                        rows * max(abs(facts[0]), abs(facts[1])) <= _INT64_MAX:
+                    proved("guard", spec.arg)
+                else:
+                    fa = arg_slot(BCast(spec.arg, T.FLOAT64_T))
+                    slots.append(partial_slot("sum", fa, "float64"))
             extracts.append(AggExtract(spec.kind, slots, spec.out_type))
         elif spec.kind in ("min", "max"):
             dt = str(spec.arg.type.device_dtype)
-            s = partial_slot(spec.kind, ai, dt)
-            c = partial_slot("count", ai, "int64")
+            s = partial_slot(spec.kind, arg_slot(spec.arg), dt)
+            c = null_count(spec.arg, facts)
             extracts.append(AggExtract(spec.kind, [s, c], spec.out_type))
         else:
             from citus_tpu.planner.aggregates import AGG_REGISTRY
             defn = AGG_REGISTRY.get(spec.kind)
             if defn is None:
                 raise AssertionError(spec.kind)
+            arg_slot(spec.arg)
             extracts.append(defn.lower(spec, arg_slot, partial_slot))
-    return agg_args, partials, extracts
+    away = (sum(w == "guard" for w, _ in gone),
+            sum(w == "count" for w, _ in gone))
+    return agg_args, partials, extracts, away
 
 
 # ------------------------------------------------------------ entry
@@ -486,10 +583,23 @@ def _index_eq(table: TableMeta, filter_: Optional[BExpr]):
     return None
 
 
+def sees_staged_rows(table: TableMeta) -> bool:
+    """Whether this thread's scans of ``table`` return rows no live
+    footer covers: the staged writes of its own open transaction, which
+    bump no version.  No statistic is a fact about such a scan."""
+    from citus_tpu.storage.overlay import current_overlay
+    txn = current_overlay()
+    return txn is not None and table.name in txn.tables
+
+
 def plan_select(cat: Catalog, bound: BoundSelect, *, direct_limit: int = 0) -> PhysicalPlan:
     intervals = extract_intervals(bound.filter)
     shard_indexes, router_key = prune_shards(bound.table, bound.filter, return_key=True)
-    agg_args, partial_ops, agg_extract = lower_aggregates(bound.aggs)
+    facts = (table_facts(cat, bound.table)
+             if bound.aggs and not sees_staged_rows(bound.table) else None)
+    agg_args, partial_ops, agg_extract, proved_away = lower_aggregates(
+        bound.aggs, lambda e: arg_facts(facts, bound.table, e),
+        facts.rows if facts else 0)
     group_mode = choose_group_mode(cat, bound, direct_limit,
                                    product_planes(partial_ops, agg_args))
     return PhysicalPlan(
@@ -501,6 +611,7 @@ def plan_select(cat: Catalog, bound: BoundSelect, *, direct_limit: int = 0) -> P
         agg_args=agg_args,
         partial_ops=partial_ops,
         agg_extract=agg_extract,
+        proved_away=proved_away,
         router_key=router_key,
         router_param=_deferred_router_param(bound.table, bound.filter),
         index_eq=_index_eq(bound.table, bound.filter),

@@ -209,6 +209,12 @@ class StatCounters:
         "direct_groups",
         "direct_groups_out",
         "direct_bytes_fetched",
+        # aggregate queries: partial states their plans compute, and
+        # the overflow guards and per-argument NULL counts that
+        # planner/physical.py lower_aggregates proved away from the
+        # table's statistics and did not emit
+        "agg_partials",
+        "agg_partials_proved_away",
         # grouped scans, direct or hashed: slots of the device hash
         # table (EXPLAIN ANALYZE says what bounded them), padded rows
         # the grouping stage ran over and rows the WHERE kept

@@ -75,12 +75,28 @@ def _reference(grouped, lim):
     return rows
 
 
-@pytest.mark.parametrize("mode", ["resident", "streaming"])
-@pytest.mark.parametrize("with_params", [False, True], ids=["literal", "dollar"])
-@pytest.mark.parametrize("grouped", [False, True], ids=["scalar", "direct"])
+# every (grouped, with_params, mode) on the table whose statistics prove
+# every overflow guard and NULL count away, and the grouped dollar case
+# of each mode on a table they cannot: one more row whose ``v`` 6,002
+# rows of would leave int64 (sum(v) keeps its float64 shadow) and one
+# whose ``v`` is NULL (count(v) is not count(*)); ``v < 300`` keeps
+# neither, so the answers stand
+_FOLD_CASES = [(g, p, m, True) for g in (False, True) for p in (False, True)
+               for m in ("resident", "streaming")] \
+    + [(True, True, m, False) for m in ("resident", "streaming")]
+
+
+@pytest.mark.parametrize(
+    "grouped, with_params, mode, provable", _FOLD_CASES,
+    ids=["-".join(("direct" if g else "scalar", "dollar" if p else "literal",
+                   m) + (() if ok else ("unprovable",)))
+         for g, p, m, ok in _FOLD_CASES])
 def test_mesh_rounds_fold_on_the_devices(cl, mesh_calls, monkeypatch, mode,
-                                         with_params, grouped):
+                                         with_params, grouped, provable):
     lim = 300
+    if not provable:
+        cl.execute(f"INSERT INTO m VALUES (6000, {1 << 61}, 0, 1.00), "
+                   "(6001, NULL, 0, 1.00)")
     sql = ("SELECT {k}count(*), sum(v), min(v), max(v), sum(d), min(d), avg(v) "
            "FROM m WHERE v < {lim}{g}").format(
         k="g, " if grouped else "", lim="$1" if with_params else lim,
@@ -107,6 +123,11 @@ def test_mesh_rounds_fold_on_the_devices(cl, mesh_calls, monkeypatch, mode,
     assert "mesh_run" in plan.runtime_cache
     oracle = X._run_partials_cpu(cat, plan, settings, prm)
     assert len(got) == len(oracle) == len(X.combine_kinds(plan))
+    # count(*), sum / min / max of v, sum / min of d (+ the group rows):
+    # no other state on the provable table; count(v), the shadow of
+    # sum(v) and count(d)'s stand-in stay where nothing is proved of v
+    assert plan.proved_away == ((2, 2) if provable else (1, 1))
+    assert sum(a.dtype.kind == "f" for a in got) == (0 if provable else 1)
     for a, b in zip(got, oracle):
         assert isinstance(a, np.ndarray) and a.dtype == np.asarray(b).dtype
         if a.dtype.kind == "f":

@@ -42,7 +42,12 @@ DATA_SEED = 22
 # at 4,000 orders few or no orders pass those, so 250 and 275 (the
 # reference keeps every order from 250.00) make the answer non-empty
 QUANTITIES = (250, 275, 300, 312, 313, 314, 315)
-MODES = ("defaults", "forced_spill", "four_devices")
+# "no_statistics": the planner is told nothing about the table, so the
+# plan keeps every guard -- sum, count(l_quantity) and the float64
+# overflow shadow, 41 bytes an entry, the layout before PR 36.  The
+# other modes plan from the footers' statistics, which prove the sum
+# fits and the quantity is never NULL: sum and count(*), 33 bytes
+MODES = ("defaults", "forced_spill", "four_devices", "no_statistics")
 
 with open(os.path.join(ROOT, "benchmarks", "queries", "q18_orders.json")) as fh:
     QUERY = json.load(fh)
@@ -61,9 +66,12 @@ def table():
 
 
 @pytest.fixture()
-def cluster(tmp_path, table, limit_devices, request):
+def cluster(tmp_path, table, limit_devices, request, monkeypatch):
     mode = request.param
     limit_devices(4 if mode == "four_devices" else 1)
+    if mode == "no_statistics":
+        from citus_tpu.planner import physical
+        monkeypatch.setattr(physical, "table_facts", lambda cat, t: None)
     cl = ct.Cluster(str(tmp_path / "db"))
     cl.execute(CONFIG["ddl"])
     cl.execute(f"SELECT create_distributed_table('{CONFIG['table']}', "
@@ -117,33 +125,46 @@ def test_engine_equals_the_plain_reference(cluster, table, quantity):
     text = "\n".join(l for (l,) in cl.execute(f"EXPLAIN ANALYZE {sql}").rows)
     assert ("tables 4 x 4096 slots, disjoint on l_orderkey" in text) \
         == (mode == "four_devices")
-    # one int64 key + its int8 flag, sum / count / float64 shadow, rows
-    if mode == "defaults" and quantity >= 312:
+    # one int64 key + its int8 flag, sum / count, rows -- and the
+    # float64 shadow where the statistics could not prove it away
+    entry = 41 if mode == "no_statistics" else 33
+    pa = cl.execute(sql).explain["partials"]
+    assert (pa["computed"], pa["overflow_guards_proved_away"],
+            pa["null_counts_proved_away"]) \
+        == ((3, 0, 0) if mode == "no_statistics" else (2, 1, 1))
+    if mode in ("defaults", "no_statistics") and quantity >= 312:
         # the cell's four QUANTITY values: HAVING is decided on the
         # table on the chip; the spilled keys' entries (1,024, their
         # power of two) and the survivors' blocks (8 of 512 slots, the
         # least) come home, not the 16,384 slots
         assert entries == 1024 + 8 * 512
-        assert fetched == entries * 41
+        assert fetched == entries * entry
     elif entries is None:
         # also four tables of 4,096 slots: the least that comes home
         # filtered (8 blocks and 1,024 keys) is more than half a table
-        assert fetched == slots * 41
+        assert fetched == slots * entry
     else:
-        assert fetched == entries * 41 <= slots * 41 // 2
+        assert fetched == entries * entry <= slots * entry // 2
 
 
 # what jit_hash_fused lowers to at PR 29 (780903a), for the statement's
 # plan on a 1,024-slot state and a 2,048-row batch: the kernel's time
 # and the bytes hash_kernel_hbm_roofline reckons are this module's
 PARENT_HASH_FUSED_SHA1 = "27c07ec0b1b2462547fa7ffafb6fdfe98d9af9ac"
+# ... and since PR 36 for the plan the table's statistics leave: the
+# same kernel builder over two partials (sum, count(*)), no float64 lane
+PROVED_HASH_FUSED_SHA1 = "a57a19761dbe5e6a16c4325200f96d2c87f36064"
 
 
-@pytest.mark.parametrize("cluster", ["defaults"], indirect=True)
+@pytest.mark.parametrize("cluster", ["defaults", "no_statistics"],
+                         indirect=True)
 @pytest.mark.parametrize("quantity", (312, 313, 314, 315))
 def test_hash_kernel_is_the_parents_module(cluster, quantity):
     """The filtered ending is its own kernel: ``jit_hash_fused`` lowers
-    to the text it had before, byte for byte, whatever QUANTITY."""
+    to the text it had before, byte for byte, whatever QUANTITY -- for
+    the plan with every guard; the plan the statistics slim lowers to
+    one pinned text of its own (``ops/hash_agg.py`` is not edited: the
+    text differs by the partials alone)."""
     import hashlib
 
     import jax.numpy as jnp
@@ -155,10 +176,15 @@ def test_hash_kernel_is_the_parents_module(cluster, quantity):
     from citus_tpu.planner import parse_sql
     from citus_tpu.planner.bind import bind_select
     from citus_tpu.planner.physical import plan_select
-    cl, _ = cluster
+    cl, mode = cluster
     sql = QUERY["sql"].format(QUANTITY=quantity)
     plan = plan_select(cl.catalog, bind_select(cl.catalog, parse_sql(sql)[0]))
     assert plan.group_mode.kind == "hash_host"
+    guarded = mode == "no_statistics"
+    assert [(op.kind, op.dtype) for op in plan.partial_ops] == (
+        [("sum", "int64"), ("count", "int64"), ("sum", "float64")] if guarded
+        else [("sum", "int64"), ("count", "int64")])
+    assert plan.partial_ops[1].arg_index == (0 if guarded else -1)
     key_dtypes = _hash_key_dtypes(plan, {})
     kernel = jit_compile(build_fused_hash_worker(plan, jnp, key_dtypes),
                          donate_argnums=0)
@@ -169,7 +195,8 @@ def test_hash_kernel_is_the_parents_module(cluster, quantity):
     valids = tuple(np.ones(n, bool) for _ in plan.scan_columns)
     text = kernel.lower(empty_hash_state(plan, 1024, key_dtypes), cols,
                         valids, np.ones(n, bool)).as_text()
-    assert hashlib.sha1(text.encode()).hexdigest() == PARENT_HASH_FUSED_SHA1
+    assert hashlib.sha1(text.encode()).hexdigest() == (
+        PARENT_HASH_FUSED_SHA1 if guarded else PROVED_HASH_FUSED_SHA1)
 
 
 @pytest.mark.parametrize("cluster", MODES, indirect=True)

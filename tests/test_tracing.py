@@ -542,9 +542,17 @@ def test_unsampled_hash_query_allocates_no_span(clh, limit_devices):
     assert T.span_allocations() == before
 
 
-@pytest.mark.parametrize("slots", [None, 1024, 64])
-def test_hash_counters_for_a_known_table(clh, limit_devices, slots):
+@pytest.mark.parametrize("slots, provable", [
+    (None, True), (1024, True), (64, True), (None, False)])
+def test_hash_counters_for_a_known_table(clh, limit_devices, slots, provable):
     limit_devices(1)
+    if not provable:
+        # two rows of a group that is there: a NULL, so count(v) is not
+        # count(*), and a value 6,002 rows of which would leave int64,
+        # so the sum keeps its float64 overflow shadow
+        g0 = clh.execute("SELECT g FROM t WHERE k = 0").rows[0][0]
+        clh.execute(f"INSERT INTO t VALUES (6000, {g0}, NULL), "
+                    f"(6001, {g0}, {1 << 61})")
     if slots is not None:
         clh.execute(f"SET citus.hash_agg_slots = {slots}")
     c0 = clh.counters.snapshot()
@@ -559,10 +567,19 @@ def test_hash_counters_for_a_known_table(clh, limit_devices, slots):
     assert (c1["hash_spill_rows"] - c0["hash_spill_rows"]
             == pl["hash_spilled_rows"])
     assert (pl["hash_spilled_rows"] > 3000) == (slots == 64)
-    # a slot: int64 key + int8 flag, int64 sum + int64 count + float64
-    # shadow sum, int64 rows
+    # a slot: int64 key + int8 flag, int64 sum + int64 count, int64 rows
+    # -- and the float64 shadow sum where the table's statistics cannot
+    # prove that the sum fits (planner/physical.py lower_aggregates)
     fetched = c1["hash_table_bytes_fetched"] - c0["hash_table_bytes_fetched"]
-    assert fetched == pl["hash_table_bytes_fetched"] == pl["hash_slots"] * 41
+    assert fetched == pl["hash_table_bytes_fetched"] \
+        == pl["hash_slots"] * (33 if provable else 41)
+    assert r.explain["partials"] == {
+        "computed": 2 if provable else 3,
+        "overflow_guards_proved_away": int(provable),
+        "null_counts_proved_away": int(provable)}
+    assert (c1["agg_partials"] - c0["agg_partials"],
+            c1["agg_partials_proved_away"] - c0["agg_partials_proved_away"]) \
+        == ((2, 2) if provable else (3, 0))
 
 
 # ------------------------------------- the raw reader and the index, named
