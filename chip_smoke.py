@@ -128,6 +128,23 @@ Q_JOIN = f"""SELECT count(*), sum(l.l_quantity), sum(o.o_totalprice)
 FROM lineitem l JOIN orders o ON l.l_orderkey = o.o_orderkey
 WHERE l.l_shipdate < date '{EPOCH + datetime.timedelta(days=JOIN_SHIP_HI)}'"""
 
+# the colocated join on the device (ops/join.py): lineitem and an
+# ``orders`` table distributed on the ORDER key are colocated, customer
+# is a reference table -- TPC-H Q3's join, in the published comma form
+ORDERS_K_DDL = """CREATE TABLE orders_k (
+    o_orderkey bigint NOT NULL, o_custkey bigint NOT NULL,
+    o_totalprice decimal(12,2))"""
+CUSTOMER_DDL = """CREATE TABLE customer (
+    c_custkey bigint NOT NULL, c_mktsegment text)"""
+SEGMENTS = ["AUTOMOBILE", "BUILDING", "FURNITURE", "HOUSEHOLD", "MACHINERY"]
+Q_JOIN_COLOCATED = f"""SELECT c_mktsegment, count(*), sum(l_quantity),
+  sum(o_totalprice)
+FROM customer, orders_k, lineitem
+WHERE c_custkey = o_custkey AND l_orderkey = o_orderkey
+  AND l_shipdate < date '{EPOCH + datetime.timedelta(days=JOIN_SHIP_HI)}'
+  AND c_mktsegment <> 'MACHINERY'
+GROUP BY c_mktsegment ORDER BY c_mktsegment"""
+
 # float64 lanes: the TPU holds them as float32 pairs, so the hash
 # fingerprint and the HLL hash of a float value take a path of their own
 MEASURES_DDL = "CREATE TABLE measures (m_key bigint NOT NULL, x double precision)"
@@ -176,6 +193,12 @@ def avg_dec(total: int, n: int, scale: int) -> decimal.Decimal:
     return dec(q, scale + 6)
 
 
+def order_custkey(orderkey, n_orders):
+    """o_custkey as a function of the key (and c_mktsegment of the
+    customer's key: ``SEGMENTS[key % 5]``)."""
+    return (orderkey * 31 + 7) % (n_orders // 10 + 1)
+
+
 def order_totalprice_cents(orderkey):
     """o_totalprice as a function of the key, so the join reference
     needs no lookup table."""
@@ -203,6 +226,8 @@ class Reference:
         self.h_min = np.full(SHIP_DAYS * 99, 1 << 62, np.int64)
         self.router_rows = []
         self.join = [0, 0, 0]             # count, sum qty, sum totalprice
+        self.n_orders = n_orders
+        self.join_seg = np.zeros((5, 3), np.int64)    # the same, a segment
         self.sd = [[0, 0, 0] for _ in range(3)]  # per flag: n, sum, sumsq
         self.qty_hist = np.zeros(5100, np.int64)
 
@@ -259,7 +284,12 @@ class Reference:
         m = ship < JOIN_SHIP_HI
         self.join[0] += int(m.sum())
         self.join[1] += int(qty[m].sum())
-        self.join[2] += int(order_totalprice_cents(okey[m]).sum())
+        total = order_totalprice_cents(okey[m])
+        self.join[2] += int(total.sum())
+        seg = order_custkey(okey[m], self.n_orders) % 5
+        for k, w in enumerate((np.ones(int(m.sum()), np.int64), qty[m],
+                               total)):
+            np.add.at(self.join_seg[:, k], seg, w)
         # stddev(l_quantity) per returnflag, median of l_quantity
         for r in range(3):
             q = qty[rf == r]
@@ -351,15 +381,22 @@ def load_lineitem(cl, ref, rng, rows, n_orders):
         done += n
 
 
-def load_orders(cl, n_orders):
+def load_orders(cl, n_orders, table="orders"):
     for start in range(0, n_orders, CHUNK_ROWS):
         key = np.arange(start, min(start + CHUNK_ROWS, n_orders),
                         dtype=np.int64)
-        cl.copy_from("orders", columns={
+        cl.copy_from(table, columns={
             "o_orderkey": key,
-            "o_custkey": (key * 31 + 7) % (n_orders // 10 + 1),
+            "o_custkey": order_custkey(key, n_orders),
             "o_totalprice": order_totalprice_cents(key) / 100.0,
         })
+
+
+def load_customer(cl, n_orders):
+    key = np.arange(n_orders // 10 + 1, dtype=np.int64)
+    cl.copy_from("customer", columns={
+        "c_custkey": key,
+        "c_mktsegment": [SEGMENTS[k % 5] for k in key.tolist()]})
 
 
 # ------------------------------------------------------------------- legs
@@ -725,6 +762,32 @@ def close(got, want, rtol):
     return got is not None and abs(float(got) - want) <= rtol * abs(want)
 
 
+def leg_join_colocated(run, ref):
+    """The colocated many-to-one join on the device, on one chip and on
+    several alike: customer built once, orders once a shard pair, every
+    lineitem batch probed, the survivors aggregated in the device hash
+    table; no statement falls back to the host path."""
+    r, d, el, ev = run.run(Q_JOIN_COLOCATED)
+    check(r.explain["strategy"] == "join:colocated"
+          and r.explain.get("join", {}).get("on") == "device",
+          f"colocated join: {r.explain}")
+    check(d.get("join_host_fallbacks", 0) == 0
+          and d.get("join_rows_probed", 0) > 0, f"colocated join: {d}")
+    check({"jit_join_probe", "jit_hash_fused"} <= set(kernel_slots(ev)),
+          f"colocated join: slots {kernel_slots(ev)}")
+    want = [(SEGMENTS[s], int(n), dec(q, 2), dec(t, 2))
+            for s, (n, q, t) in enumerate(ref.join_seg.tolist())
+            if SEGMENTS[s] != "MACHINERY" and n]
+    check(r.rows == want, f"colocated join answer {r.rows} want {want}")
+    j = r.explain["join"]
+    run.record("7c colocated join on the device (customer, orders_k, "
+               "lineitem: build, probe, packed aggregate)",
+               "jit_join_probe", el, d, rows_probed=j["rows_probed"],
+               rows_matched=j["rows_matched"], rows_out=j["rows_out"],
+               rows_built=j["rows_built"], table_bytes=j["table_bytes"],
+               overflow_rounds=j["overflow_rounds"])
+
+
 def leg_mesh(run, ref, devices, q1_h2d_bytes):
     """More than one device: where the cached Q1 stack lives, then the
     repartition join and the mesh aggregates the single-device legs do
@@ -765,7 +828,7 @@ def leg_mesh(run, ref, devices, q1_h2d_bytes):
     check(close(r.rows[0][0], want, SKETCH_RTOL),
           f"approx_percentile {r.rows} want {want} within {SKETCH_RTOL}")
     check("mesh_run" in kernel_slots(ev), f"sketch: {kernel_slots(ev)}")
-    run.record("7c approx_percentile (DDSketch buckets over psum)",
+    run.record("7d approx_percentile (DDSketch buckets over psum)",
                "mesh_run", el, d, rtol=SKETCH_RTOL, got=float(r.rows[0][0]),
                want=want)
 
@@ -774,7 +837,7 @@ def leg_mesh(run, ref, devices, q1_h2d_bytes):
           and r.rows == [(len(ref.router_rows),)],
           f"routed count {r.rows} {r.explain}")
     check("jit_fused" in kernel_slots(ev), f"routed count: {kernel_slots(ev)}")
-    run.record("7d routed count(*) WHERE l_orderkey = $1", "jit_fused", el, d)
+    run.record("7e routed count(*) WHERE l_orderkey = $1", "jit_fused", el, d)
     return report
 
 
@@ -858,7 +921,8 @@ def main() -> int:
                 "lineitem carries 8 of TPC-H's 16 columns",
                 "keys and values are uniform draws (numpy default_rng), "
                 "not dbgen's distributions",
-                "orders (4-chip join leg) carries 3 columns",
+                "orders (4-chip join leg) and orders_k (the colocated "
+                "join's) carry 3 columns, customer 2",
             ],
             "reduced": ([] if args.rows == SF10_LINEITEM_ROWS else
                         [f"rows cut from {SF10_LINEITEM_ROWS} to {args.rows}"]),
@@ -877,6 +941,13 @@ def main() -> int:
             cl.execute("SELECT create_distributed_table('orders', "
                        f"'o_custkey', {shards})")
             load_orders(cl, n_orders)
+        cl.execute(ORDERS_K_DDL)
+        cl.execute("SELECT create_distributed_table('orders_k', "
+                   f"'o_orderkey', {shards})")
+        load_orders(cl, n_orders, "orders_k")
+        cl.execute(CUSTOMER_DDL)
+        cl.execute("SELECT create_reference_table('customer')")
+        load_customer(cl, n_orders)
         load_s = time.perf_counter() - t0
         print(f"setup: generated, referenced and ingested {args.rows} rows "
               f"in {load_s:.1f} s (set-up, not a metric)", flush=True)
@@ -896,6 +967,7 @@ def main() -> int:
         leg_float_lanes(run, rng, shards, n_dev,
                         min(MEASURES_ROWS, args.rows))
         leg_router(run, ref)
+        leg_join_colocated(run, ref)
 
         memory = []
         for dv in devices:

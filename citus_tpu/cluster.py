@@ -2719,7 +2719,9 @@ class Cluster:
         if isinstance(stmt, A.Select) and isinstance(stmt.from_, A.Join):
             from citus_tpu.executor.join_executor import execute_join_select
             from citus_tpu.planner.join_planner import bind_join_select
-            bj = bind_join_select(self.catalog, stmt)
+            with _trace.span("plan", cache_hit=False):
+                with _trace.span("bind"):
+                    bj = bind_join_select(self.catalog, stmt)
             return execute_join_select(self.catalog, bj, self.settings)
         if isinstance(stmt, A.Select):
             if self.catalog.rollups:

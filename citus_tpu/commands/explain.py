@@ -390,4 +390,17 @@ def _explain_join(cl, stmt: A.Explain) -> Result:
         r = execute_join_select(cl.catalog, bj, cl.settings)
         lines.append(f"  Rows: {r.rowcount}  Tasks: {r.explain['tasks']}  "
                      f"Elapsed: {r.explain['elapsed_s']*1000:.2f} ms")
+        j = r.explain.get("join")
+        if j and j["on"] == "device":
+            tables = ", ".join(
+                f"{a} {t['table']} {t['slots']} slots a {t['built_per']}"
+                for a, t in j["tables"].items())
+            lines.append(
+                f"  Join: on device, probe {j['probe']}; tables: {tables} "
+                f"({j['table_bytes']} bytes); rows built {j['rows_built']}, "
+                f"probed {j['rows_probed']}, matched {j['rows_matched']}, "
+                f"out {j['rows_out']}; overflow rounds "
+                f"{j['overflow_rounds']}; groups {j['groups']}")
+        elif j:
+            lines.append(f"  Join: on host ({j['why']})")
     return Result(columns=["QUERY PLAN"], rows=[(l,) for l in lines])

@@ -44,6 +44,9 @@ class ShardBatch:
     # copied there from a decoded chunk
     bytes_in_place: int = 0
     bytes_copied: int = 0
+    # what a stream of several relations' batches says of this one
+    # (executor/join_device.py: relation, shard, first / last of its scan)
+    tag: object = None
 
     @property
     def nbytes(self) -> int:

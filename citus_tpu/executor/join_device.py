@@ -1,0 +1,549 @@
+"""The device path of a colocated many-to-one join (``ops/join.py``).
+
+``run_device_join`` answers a ``join:colocated`` statement whose steps
+``planner/join_planner.py`` ``plan_device_join`` takes, or says why the
+host path (``executor/join_executor.py``, the oracle) has to.
+
+One stream, one ``scan_loop.drive``, one decode thread a query: the
+batches of every relation in the order their tables are needed -- the
+reference and local relations first, built once a query and kept on the
+device for every shard pair (span ``join_broadcast``); then per shard
+index the build-side relations' shards (span ``join_build``, once a
+shard and relation) and the probe relation's shard, each of whose
+batches runs one step: ``jit_join_probe`` (probe, filters, the
+survivors packed into a block) and the aggregate's update over that
+block (``jit_hash_fused``, the device hash table every GROUP BY of
+unbounded cardinality uses).  A block that overflows takes further
+rounds (``join_overflow_round``), found where the loop waits for the
+device anyway.  The statement's literals reach the kernels as
+parameters (``auto_param.hoist_literals``): a new SEGMENT or DATE
+compiles nothing.  One fetch brings home the aggregate's table (at
+most ``AGG_SLOTS[1]`` entries; the groups it cannot hold spill to the
+host accumulator, exactly).
+"""
+
+from __future__ import annotations
+
+import hashlib
+from contextlib import nullcontext
+from types import SimpleNamespace
+from typing import Optional
+
+import numpy as np
+
+from citus_tpu import types as T
+from citus_tpu.catalog import Catalog
+from citus_tpu.config import Settings
+from citus_tpu.executor.batches import empty_batch
+from citus_tpu.executor.executor import (
+    _UNREPORTED_FREE_BYTES, _pow2_at_least,
+)
+from citus_tpu.executor.finalize import finalize_groups
+from citus_tpu.executor.kernel_cache import get_kernel, jit_compile
+from citus_tpu.executor.scan_loop import OneDevice, Step, drive
+from citus_tpu.observability import trace as _trace
+from citus_tpu.ops import join as J
+from citus_tpu.planner.auto_param import hoist_literals
+from citus_tpu.planner.bound import (
+    BColumn, compile_expr, param_env_names, walk,
+)
+from citus_tpu.planner.join_planner import (
+    BoundJoinSelect, DeviceJoinTree, plan_device_join,
+)
+
+#: slots of the aggregate's table: the probe relation's rows over this,
+#: between these bounds (what it cannot hold spills to the host, exactly)
+AGG_ROWS_PER_SLOT = 8
+AGG_SLOTS = (1 << 10, 1 << 20)
+#: share of the device's free memory a direct-address table's index may
+#: take
+DIRECT_MEMORY_SHARE = 0.25
+
+
+class _HostFallback(Exception):
+    """What the build found out on the device: the host path answers."""
+
+
+class _Placement(OneDevice):
+    """One device; remembers which relation's batch the round holds."""
+
+    def put(self, plan, members: list):
+        self.host = members[0]
+        return super().put(plan, members)
+
+
+def _names_in(exprs) -> list:
+    seen: list = []
+    for e in exprs:
+        if e is None:
+            continue
+        for n in walk(e):
+            if isinstance(n, BColumn) and n.name not in seen:
+                seen.append(n.name)
+    return seen
+
+
+def run_device_join(cat: Catalog, bj: BoundJoinSelect, settings: Settings,
+                    t0: float):
+    """-> the statement's Result, or the reason (a string) the host
+    path answers it."""
+    from citus_tpu.catalog.stats import shard_row_counts
+    from citus_tpu.executor.executor import _hash_has_exact
+    shard_rows = {}
+    with _trace.span("scan_setup"):
+        for alias, t in bj.rels:
+            try:
+                shard_rows[alias] = shard_row_counts(cat, t)
+            except Exception:
+                shard_rows[alias] = [0] * max(1, t.shard_count)
+    with _trace.span("plan_physical"):
+        tree = plan_device_join(
+            bj, {a: sum(c) for a, c in shard_rows.items()})
+        if isinstance(tree, str):
+            return tree
+        if _hash_has_exact(bj):
+            return "exact value-set partials"
+        join = _DeviceJoin(cat, bj, settings, tree, shard_rows)
+    try:
+        return join.run(t0)
+    except _HostFallback as e:
+        return str(e)
+
+
+class _DeviceJoin:
+    #: the block's capacity: None = from the batch's bucket
+    block_rows: Optional[int] = None
+
+    def __init__(self, cat: Catalog, bj: BoundJoinSelect, settings: Settings,
+                 tree: DeviceJoinTree, shard_rows: dict):
+        self.cat, self.bj, self.settings, self.tree = cat, bj, settings, tree
+        self.shard_rows = shard_rows
+        self.tables_of = dict(bj.rels)
+        root = tree.root
+
+        # the statement's literals, hoisted: one generic tree a family
+        specs: list = []
+        values: list = []
+
+        def hoist(e):
+            if e is None:
+                return None
+            g, sp, vals = hoist_literals(e, len(specs))
+            specs.extend(sp)
+            values.extend(vals)
+            return g
+
+        filters = {a: hoist(bj.rel_plans[a].filter) for a, _ in bj.rels}
+        post = hoist(bj.post_filter)
+        self.specs = specs
+        self.param_names = tuple(param_env_names(specs))
+        self.params = (
+            tuple(np.asarray(v, t.device_dtype)
+                  for (t, _), v in zip(specs, values)),
+            (np.ones((), bool),) * len(specs))
+
+        def dtype_of(name: str) -> str:
+            alias, col = name.split(".", 1)
+            return str(self.tables_of[alias].schema.scan_dtype(
+                col, device=True))
+
+        out = _names_in(list(bj.group_keys) + list(bj.agg_args))
+        at_root = _names_in([post]) + out
+        payload = {}
+        for a in tree.builds:
+            under = set(tree.subtree(a))
+            payload[a] = tuple(sorted(
+                {(n, dtype_of(n)) for n in at_root
+                 if n.split(".", 1)[0] in under}))
+
+        self.kind = {a: "hash" for a in tree.builds}
+        self.spans: dict = {}
+        for a in tree.builds:
+            span = self._direct_span(a)
+            if span is not None:
+                self.kind[a], self.spans[a] = "direct", span
+
+        def node(a: str) -> J.JoinNode:
+            rp = bj.rel_plans[a]
+            return J.JoinNode(
+                alias=a,
+                names=tuple(f"{a}.{c}" for c in rp.columns),
+                filter=filters[a],
+                children=tuple(
+                    J.ChildProbe(c, tuple(tree.edge[c][1]), payload[c],
+                                 self.kind[c])
+                    for c in tree.children(a)),
+                key=() if a == root else tuple(tree.edge[a][0]),
+                payload=() if a == root else payload[a],
+                post_filter=post if a == root else None,
+                out=tuple(out) if a == root else (),
+                kind=self.kind.get(a, "hash"))
+
+        self.nodes = {a: node(a) for a in tree.builds + [root]}
+        self.out_dtypes = tuple(dtype_of(n) for n in out)
+        # a build node is rebuilt per shard where it, or a relation
+        # under it, is distributed; else once a query
+        self.per_shard = {
+            a: any(self.tables_of[b].is_distributed for b in tree.subtree(a))
+            for a in tree.builds}
+        scans = [(a, [(c, dtype_of(f"{a}.{c}"))
+                      for c in bj.rel_plans[a].columns])
+                 for a in self.nodes]
+        fp = hashlib.sha256(repr((
+            sorted(self.nodes.items()), scans, bj.group_keys, bj.agg_args,
+            bj.partial_ops, len(specs))).encode()).hexdigest()
+        self.holder = SimpleNamespace(
+            bound=SimpleNamespace(table=self.tables_of[root]),
+            runtime_cache={"_fingerprint": fp})
+        # the aggregate over the block, as the hash kernel reads a plan
+        self.agg = SimpleNamespace(
+            bound=SimpleNamespace(filter=None, group_keys=list(bj.group_keys),
+                                  param_specs=specs),
+            agg_args=bj.agg_args, scan_columns=list(out),
+            partial_ops=bj.partial_ops, agg_extract=bj.agg_extract,
+            runtime_cache=self.holder.runtime_cache)
+
+        self.n_shards = max(
+            [t.shard_count for _, t in bj.rels if t.is_distributed] or [0])
+        self.tables: dict = {}
+        self.slots: dict = {}
+        self.lane_rows: dict = {}
+        self.rows_in: dict = {}
+        self.bytes_in: dict = {}
+        self.built = {a: 0 for a in tree.builds}
+        self.totals = np.zeros(3, np.int64)
+        self.probed = self.overflow_rounds = self.later_level = 0
+
+    # ------------------------------------------------------------ scans
+
+    def _scan(self, alias: str, shard_indexes: list):
+        rp = self.bj.rel_plans[alias]
+        return SimpleNamespace(
+            bound=SimpleNamespace(table=rp.table), scan_columns=list(rp.columns),
+            intervals=rp.intervals, index_eq=None,
+            shard_indexes=shard_indexes,
+            runtime_cache=self.holder.runtime_cache)
+
+    def _tagged(self, alias: str, shard_indexes: list, si: int):
+        """The host batches of a relation's shards, each tagged
+        ``(alias, shard index, first, last)``; a build relation with no
+        batch yields one of padding alone, so that its table is made."""
+        from citus_tpu.executor.executor import _iter_padded_batches
+        plan = self._scan(alias, shard_indexes)
+        batches = _iter_padded_batches(self.cat, plan, self.settings)
+        held, first = next(batches, None), True
+        if held is None:
+            if alias != self.tree.root:
+                held = empty_batch(
+                    plan.bound.table, plan,
+                    max(1, self.settings.executor.min_batch_rows), si)
+            else:
+                return
+        while held is not None:
+            nxt = next(batches, None)
+            held.tag = (alias, si, first, nxt is None)
+            yield held
+            held, first = nxt, False
+
+    def _stream(self):
+        tree = self.tree
+        all_of = lambda a: list(range(self.tables_of[a].shard_count))
+        for a in tree.builds:
+            if not self.per_shard[a]:
+                yield from self._tagged(a, all_of(a), -1)
+        if not self.n_shards:
+            yield from self._tagged(tree.root, all_of(tree.root), -1)
+            return
+        for si in range(self.n_shards):
+            one = lambda a: [si] if self.tables_of[a].is_distributed \
+                else all_of(a)
+            for a in tree.builds:
+                if self.per_shard[a]:
+                    yield from self._tagged(a, one(a), si)
+            yield from self._tagged(tree.root, one(tree.root), si)
+
+    # ---------------------------------------------------------- kernels
+
+    def _kernel(self, slot: str, build, extra: tuple = (), **jit_kwargs):
+        return get_kernel(self.holder, slot,
+                          lambda: jit_compile(build(), **jit_kwargs),
+                          extra=extra)
+
+    def _direct_span(self, alias: str):
+        """-> (lowest key, slots) of a direct-address table for build
+        node ``alias``, or None: its ONE key lane is a plain integer or
+        date column whose every value the footers bound
+        (``catalog/stats.py`` ``table_facts``; no transaction of this
+        thread has staged rows into the table), and an int32 a key of
+        that span fits ``DIRECT_MEMORY_SHARE`` of the device's free
+        memory.  From that proof alone -- the way ``choose_group_mode``
+        picks the direct group table; no setting."""
+        from citus_tpu.catalog.stats import table_facts
+        from citus_tpu.parallel.mesh import executor_devices
+        from citus_tpu.planner.physical import sees_staged_rows
+        key = self.tree.edge[alias][0]
+        table = self.tables_of[alias]
+        if len(key) != 1 or not isinstance(key[0], BColumn) \
+                or not (key[0].type.is_integer or key[0].type.kind == T.DATE) \
+                or sees_staged_rows(table):
+            return None
+        facts = table_facts(self.cat, table)
+        bounds = facts and facts.columns.get(key[0].name.split(".", 1)[1])
+        if not bounds or bounds[0] is None or bounds[1] is None:
+            return None
+        lo, hi = int(bounds[0]), int(bounds[1])
+        slots = _pow2_at_least(hi - lo + 1, 1024)
+        st = executor_devices()[0].memory_stats()
+        free = st["bytes_limit"] - st["bytes_in_use"] if st \
+            else _UNREPORTED_FREE_BYTES
+        if slots > 1 << 30 or 4 * slots > DIRECT_MEMORY_SHARE * free:
+            return None
+        return lo, slots
+
+    def _rows_of(self, alias: str) -> int:
+        """Rows one build of ``alias`` can take: a shard's, or all."""
+        rows = self.shard_rows[alias]
+        return max(rows, default=0) if (
+            self.per_shard[alias] and self.tables_of[alias].is_distributed
+        ) else sum(rows)
+
+    def _slots_of(self, alias: str) -> int:
+        if self.kind[alias] == "direct":
+            return self.spans[alias][1]
+        return _pow2_at_least(J.SLOTS_PER_ROW * self._rows_of(alias), 1024)
+
+    def _zero(self, alias: str):
+        import jax.numpy as jnp
+        from citus_tpu.ops.hash_agg import ENTRY_CHUNK
+        node = self.nodes[alias]
+        S = self.slots.setdefault(alias, self._slots_of(alias))
+        zero = self._kernel(
+            f"jit_join_zero:{alias}",
+            lambda: lambda slots, rows, lo: J.empty_join_table(
+                node, slots, jnp, rows, lo),
+            static_argnums=(0, 1))
+        # a direct table's lanes: every row of the build, and a chunk
+        self.lane_rows[alias] = _pow2_at_least(self._rows_of(alias), 1024)
+        return zero(S, self.lane_rows[alias] + ENTRY_CHUNK,
+                    np.int64(self.spans.get(alias, (0, 0))[0]))
+
+    # ------------------------------------------------------------- step
+
+    def _step(self, _token, cols, valids, row_mask):
+        import jax.numpy as jnp
+        hb = self.placement.host
+        alias, si, first, last = hb.tag
+        node = self.nodes[alias]
+        children = tuple(self.tables[c.alias] for c in node.children)
+        if alias != self.tree.root:
+            if first:
+                self.tables[alias] = self._zero(alias)
+                self.rows_in[alias] = self.bytes_in[alias] = 0
+            build = self._kernel(
+                f"jit_join_build:{alias}",
+                lambda: J.build_join_build(node, self.param_names, jnp),
+                donate_argnums=0)
+            self.rows_in[alias] += hb.n_rows
+            self.bytes_in[alias] += hb.nbytes
+            # the span of a build: its last dispatch and the wait for
+            # its counts
+            name = "join_build" if self.per_shard[alias] else "join_broadcast"
+            with (_trace.span(name) if last else nullcontext()) as sp:
+                table = self.tables[alias] = build(
+                    self.tables[alias], children, cols, valids, row_mask)
+                built = self._verdict(alias, table) if last else 0
+                if last and sp.recording:
+                    if self.per_shard[alias]:
+                        sp.set(shard_index=int(si), relation=alias,
+                               rows_in=self.rows_in[alias], rows_built=built,
+                               slots=self.slots[alias],
+                               table=self.kind[alias])
+                    else:
+                        sp.set(relation=alias, rows=self.rows_in[alias],
+                               rows_kept=built, bytes=self.bytes_in[alias])
+            return (table[1],), None
+        probe = self._probe_kernel()
+        bcols, bvalids, bmask, counts = probe(
+            children, cols, valids, row_mask, np.int32(0))
+        spill = self._aggregate(bcols, bvalids, bmask)
+        self.probed += int(row_mask.shape[0])
+        return (counts,), (counts, children, (cols, valids, row_mask), spill)
+
+    def _probe_kernel(self):
+        import jax.numpy as jnp
+        root = self.nodes[self.tree.root]
+        return self._kernel(
+            "jit_join_probe",
+            lambda: J.build_join_probe(root, self.param_names, jnp,
+                                       self.block_rows),
+            extra=(self.block_rows,))
+
+    def _aggregate(self, bcols, bvalids, bmask):
+        pcols, pvalids = self.params
+        self.agg_state, spill = self.agg_kernel(
+            self.agg_state, bcols + pcols, bvalids + pvalids, bmask)
+        return spill
+
+    def _verdict(self, alias: str, table) -> int:
+        """The build's counts, fetched (the one wait a build costs): a
+        key that came twice or an entry no pair of slots would take
+        sends the statement to the host path."""
+        import jax
+        import jax.numpy as jnp
+        verdict = self._kernel(
+            "jit_join_verdict",
+            lambda: lambda t: J.join_table_verdict(jnp, t))
+        v = np.asarray(jax.device_get(verdict(table)))
+        if v[J.REPEATED] or v[J.COUNTS]:
+            raise _HostFallback(f"build key of {alias} is not unique")
+        # a direct table's lanes hold the rows the catalog counted
+        if v[J.UNPLACED] or (self.kind[alias] == "direct"
+                             and v[J.PACKED_ROWS] > self.lane_rows[alias]):
+            raise _HostFallback(f"build table of {alias} is full")
+        self.built[alias] += int(v[J.BUILT])
+        self.later_level += int(v[J.LATER_LEVEL])
+        return int(v[J.BUILT])
+
+    def _sync(self, pending: list) -> None:
+        """Where the loop waits for the device anyway: the rounds'
+        counts come home, a block that overflowed takes its further
+        rounds, the aggregate's spilled entries drain to the host."""
+        import jax
+        rounds = [aux for _, aux in pending if aux is not None]
+        if not rounds:
+            return
+        probe = self._probe_kernel()
+        spills = []
+        with _trace.span("join_counts", rounds=len(rounds)):
+            counts = jax.device_get([r[0] for r in rounds])
+        for (_, children, inputs, spill), c in zip(rounds, counts):
+            c = np.asarray(c, np.int64)
+            spills.append((None, spill))
+            C = J.block_capacity(int(inputs[2].shape[0]), self.block_rows)
+            for r in range(1, -(-int(c[J.PACKED]) // C)):
+                with _trace.span("join_overflow_round", round=r):
+                    bcols, bvalids, bmask, cr = probe(
+                        children, *inputs, np.int32(r))
+                    spills.append(
+                        (None, self._aggregate(bcols, bvalids, bmask)))
+                    c[J.OUT] += int(cr[J.OUT])
+                self.overflow_rounds += 1
+                self.probed += int(inputs[2].shape[0])
+            self.totals += c
+        self.drain(spills)
+
+    # -------------------------------------------------------------- run
+
+    def run(self, t0: float):
+        import jax
+        import jax.numpy as jnp
+        from citus_tpu.executor.executor import (
+            GLOBAL_COUNTERS, _HashTables, _SpillDrain, _fetch_hash_table,
+        )
+        from citus_tpu.executor.host_agg import HostGroupAccumulator
+        from citus_tpu.executor.join_executor import (
+            _JoinPlanView, _join_text_src, finish_join,
+        )
+        from citus_tpu.executor.pipeline import PipelineStats
+        from citus_tpu.ops.hash_agg import (
+            build_fused_hash_worker, empty_hash_state, hash_state_bytes,
+            merge_hash_tables_into,
+        )
+        bj, tree = self.bj, self.tree
+        pstats = PipelineStats()
+        _trace.set_phase("device")
+        self.placement = _Placement()
+        self.placement.bind(self.params)
+
+        # the aggregate's table: key dtypes by evaluating the keys on a
+        # block of no rows
+        env = {n: (np.zeros(0, dt), np.zeros(0, bool))
+               for n, dt in zip(self.nodes[tree.root].out, self.out_dtypes)}
+        key_dtypes = tuple(
+            np.asarray(compile_expr(k, np)(env)[0]).dtype
+            for k in bj.group_keys)
+        self.agg_kernel = self._kernel(
+            "jit_hash_fused",
+            lambda: build_fused_hash_worker(self.agg, jnp, key_dtypes),
+            donate_argnums=0)
+        acc = HostGroupAccumulator(len(bj.group_keys), bj.partial_ops)
+        self.drain = _SpillDrain(self.agg, [acc])
+        with _trace.span("hash_init") as sp:
+            S = min(AGG_SLOTS[1], _pow2_at_least(
+                sum(self.shard_rows[tree.root]) // AGG_ROWS_PER_SLOT,
+                AGG_SLOTS[0]))
+            agg = self.agg      # the cached kernel must not hold ``self``
+            zero = self._kernel(
+                "jit_join_agg_zero",
+                lambda: lambda slots: empty_hash_state(
+                    agg, slots, key_dtypes, jnp),
+                static_argnums=0)
+            self.agg_state = zero(S)
+            if sp.recording:
+                sp.set(slots=S, devices=1)
+
+        token = (jnp.zeros((), np.int32),)
+        drive(self.holder, self.settings, self.placement,
+              Step(self._step, "jit_join_probe", "join_dispatches"), token,
+              pstats, stream=self._stream(), on_sync=self._sync)
+        self.placement.publish(self.holder)
+        pstats.publish(self.holder)
+
+        # one fetch: the table comes home whole (at most AGG_SLOTS[1]
+        # entries; what it could not hold is in ``acc`` already)
+        h_keys, h_parts, h_rows = _fetch_hash_table(
+            self.agg, _HashTables(self.agg_state))
+        fetched = hash_state_bytes((h_keys, h_parts, h_rows))
+        occupied = h_rows > 0
+        n = int(occupied.sum())
+        view = _JoinPlanView(bj)
+        with _trace.span("finalize_groups") as sp:
+            if acc.n_groups == 0 and n > 0:
+                # nothing spilled: the entries are the groups
+                key_arrays = [(kv[occupied], kf[occupied] == 2)
+                              for kv, kf in h_keys]
+                partials = tuple(p[occupied] for p in h_parts)
+                groups = n
+            else:
+                merge_hash_tables_into(acc, self.agg, h_keys, h_parts, h_rows)
+                key_arrays, partials = acc.finalize(
+                    [g.type for g in bj.group_keys],
+                    scalar=not bj.group_keys)
+                groups = acc.n_groups
+            rows = [] if partials is None else finalize_groups(
+                view, self.cat, key_arrays, partials,
+                text_src=_join_text_src(bj))
+            if sp.recording:
+                sp.set(groups=groups, rows=len(rows))
+
+        table_bytes = sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(
+            [t[0] for t in self.tables.values()]))
+        join = {
+            "on": "device", "probe": tree.root,
+            "tables": {a: {"table": self.kind[a],
+                           "slots": self.slots.get(a, 0),
+                           "built_per": "shard" if self.per_shard[a]
+                           else "query"} for a in tree.builds},
+            "rows_built": sum(self.built.values()),
+            "later_level_rows": self.later_level,
+            "rows_probed": self.probed,
+            "rows_matched": int(self.totals[J.MATCHED]),
+            "rows_out": int(self.totals[J.OUT]),
+            "overflow_rounds": self.overflow_rounds,
+            "table_bytes": table_bytes,
+            "agg_slots": S, "groups": groups,
+            "spilled_rows": self.drain.rows,
+        }
+        GLOBAL_COUNTERS.bump("join_rows_built", join["rows_built"])
+        GLOBAL_COUNTERS.bump("join_rows_probed", join["rows_probed"])
+        GLOBAL_COUNTERS.bump("join_rows_matched", join["rows_matched"])
+        GLOBAL_COUNTERS.bump("join_rows_out", join["rows_out"])
+        GLOBAL_COUNTERS.bump("join_overflow_rounds", join["overflow_rounds"])
+        GLOBAL_COUNTERS.bump("join_table_bytes", join["table_bytes"])
+        GLOBAL_COUNTERS.bump("hash_table_bytes_fetched", fetched)
+        GLOBAL_COUNTERS.bump("hash_entries_fetched", S)
+        GLOBAL_COUNTERS.bump("hash_groups_out", join["groups"])
+        return finish_join(
+            bj, view, rows, "colocated", max(1, self.n_shards), t0,
+            {"join": join,
+             "pipeline": self.holder.runtime_cache.get("pipeline", {})})

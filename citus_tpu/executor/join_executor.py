@@ -1,20 +1,44 @@
 """Join execution.
 
-Physical execution of BoundJoinSelect plans:
+Which join runs where:
 
-- *colocated* strategy: one task per shard index of the colocation
-  group; each task joins the colocated shard of every distributed
-  relation plus the (replicated) reference/local relations — the direct
-  analog of the reference's per-shard-group pushdown joins.
-- *pull* strategy: relations are scanned (with filter/chunk pruning
-  pushed down) and joined on the coordinator — the reference's
-  pull-to-coordinator degradation path.  A device-resident repartition
-  (all_to_all) path replaces this for large inputs in a later milestone.
+- **On the device** (``executor/join_device.py``, ``ops/join.py``): a
+  ``colocated`` join whose steps are all inner equi-joins without a
+  residual, many-to-one toward the relation that is streamed, with an
+  aggregate above it -- TPC-H Q3 as published.  Reference and local
+  relations are scanned, filtered and built into device lookup tables
+  once a query; per colocated shard pair the build-side relations'
+  shards stream through the scan loop every scan uses
+  (``load_padded_batches`` -> ``HostPrefetcher`` -> ``scan_loop.drive``
+  / ``OneDevice``) into their tables, and the probe side's batches then
+  run one step each: probe, filters, the rows that survive packed into
+  a block, the aggregate's update over that block (the device hash
+  table of ``ops/hash_agg.py``).  No relation is ever whole in host
+  memory and one fetch a query brings home groups.  Several devices: the
+  path runs on the first (the mesh is the repartition join's).
+- **On the host** (everything else below; also the oracle of the device
+  path and the whole of the ``numpy`` arm, ``task_executor_backend =
+  'cpu'``): outer and cross steps, residual ON conditions, builds whose
+  keys are not unique, joins without an aggregate, float / text / uuid
+  key lanes, and the ``repartition`` / ``pull`` strategies.  Counter
+  ``join_host_fallbacks`` counts the statements the device backend
+  answered here.
 
-The join algorithm is an exact hash join over int64-encoded key bit
-patterns (nulls never match, matching SQL semantics); inner/left/right/
-full/cross kinds are supported.  Aggregation over joined rows reuses
-HostGroupAccumulator + the standard finalize pipeline.
+  - *colocated* strategy: one task per shard index of the colocation
+    group; each task joins the colocated shard of every distributed
+    relation plus the (replicated) reference/local relations -- the
+    direct analog of the reference's per-shard-group pushdown joins.
+  - *repartition*: both sides re-hashed on the join key (host buckets,
+    or ``all_to_all`` over a mesh with a per-device sort join,
+    ``parallel/shuffle.py``).
+  - *pull* strategy: relations are scanned (with filter/chunk pruning
+    pushed down) and joined on the coordinator -- the reference's
+    pull-to-coordinator degradation path.
+
+  The host join is an exact hash join over int64-encoded key bit
+  patterns (nulls never match, matching SQL semantics); inner/left/
+  right/full/cross kinds are supported.  Aggregation over joined rows
+  reuses HostGroupAccumulator + the standard finalize pipeline.
 """
 
 from __future__ import annotations
@@ -580,19 +604,30 @@ def execute_join_select(cat: Catalog, bj: BoundJoinSelect, settings: Settings) -
     from citus_tpu.transaction.snapshot import snapshot_read_multi
 
     _guard_remote_written(cat, [t_.name for _, t_ in bj.rels])
-    # snapshot read across every base relation: the multi-shard frame
-    # loads below must observe a consistent flip generation per
-    # colocation group — validated, non-blocking (transaction/snapshot.py)
-    return snapshot_read_multi(
-        cat.data_dir, [t_ for _, t_ in bj.rels],
-        lambda: _execute_join_select(cat, bj, settings),
-        timeout=settings.executor.lock_timeout_s)
+    # snapshot read across every base relation: the scans below must
+    # observe a consistent flip generation per colocation group --
+    # validated, non-blocking (transaction/snapshot.py)
+    with _trace.span("execute") as sp:
+        r = snapshot_read_multi(
+            cat.data_dir, [t_ for _, t_ in bj.rels],
+            lambda: _execute_join_select(cat, bj, settings),
+            timeout=settings.executor.lock_timeout_s)
+        if sp.recording:
+            sp.set(strategy=r.explain["strategy"], rows=len(r.rows))
+        return r
 
 
 def _execute_join_select(cat: Catalog, bj: BoundJoinSelect, settings: Settings) -> Result:
     from citus_tpu.executor.executor import GLOBAL_COUNTERS
     GLOBAL_COUNTERS.bump("join_queries")
     t0 = clock()
+    host_reason = None
+    if settings.executor.task_executor_backend != "cpu":
+        from citus_tpu.executor.join_device import run_device_join
+        host_reason = run_device_join(cat, bj, settings, t0)
+        if not isinstance(host_reason, str):
+            return host_reason
+        GLOBAL_COUNTERS.bump("join_host_fallbacks")
     strategy = bj.strategy
     if strategy == "repartition" and not settings.planner.enable_repartition_joins:
         strategy = "pull"
@@ -657,22 +692,29 @@ def _execute_join_select(cat: Catalog, bj: BoundJoinSelect, settings: Settings) 
             env_batches.append((frame, mask))
         rows = project_rows(view, cat, env_batches, text_src=text_src)
 
-    rows = order_and_limit(view, rows)
+    explain = {}
+    if shuffle_mode is not None:
+        explain["shuffle"] = shuffle_mode
+    if host_reason is not None:
+        explain["join"] = {"on": "host", "why": host_reason}
+    return finish_join(bj, view, rows, strategy, len(tasks), t0, explain)
+
+
+def finish_join(bj: BoundJoinSelect, view, rows: list, strategy: str,
+                tasks: int, t0: float, explain: dict) -> Result:
+    """The tail the host and the device path share: ORDER BY / LIMIT,
+    the hidden outputs' trim, the Result."""
+    with _trace.span("order_and_limit"):
+        rows = order_and_limit(view, rows)
     visible = list(bj.output_names)
     if bj.hidden_outputs:
         keep = len(visible) - bj.hidden_outputs
         visible = visible[:keep]
         rows = [r[:keep] for r in rows]
-    explain = {
-        "strategy": f"join:{strategy}",
-        "tasks": len(tasks),
-        "elapsed_s": clock() - t0,
-    }
-    if shuffle_mode is not None:
-        explain["shuffle"] = shuffle_mode
     return Result(
         columns=visible,
         rows=rows,
         types=[e.type for e in bj.final_exprs][:len(visible)],
-        explain=explain,
+        explain={"strategy": f"join:{strategy}", "tasks": tasks,
+                 "elapsed_s": clock() - t0, **explain},
     )

@@ -219,14 +219,17 @@ def _insert_keys(xp, keys, mask, h, key_tables, occ):
 
 
 def _merge_entries(xp, partial_ops, table_state, keys, mask,
-                   partial_entries, row_entries):
+                   partial_entries, row_entries, h=None):
     """Match-or-claim ``keys`` (canonical) into the table and MERGE the
     placed entries' partial states into their slots (count/sum add their
     accumulators, min/max keep extrema, rows adds the entry counts):
     read the slot, combine, write it back, since ``_insert_keys`` gives
-    each placed entry a slot of its own.  -> (table_state', placed)."""
+    each placed entry a slot of its own.  ``h`` are the entries' hashes
+    where they are not the keys' fingerprints (a join table's later
+    probe levels, ops/join.py).  -> (table_state', placed)."""
     key_tables, partials, rows = table_state
-    h = _fingerprint(xp, keys, mask.shape)
+    if h is None:
+        h = _fingerprint(xp, keys, mask.shape)
     slot, placed, key_tables = _insert_keys(
         xp, keys, mask, h, list(key_tables), rows > 0)
     at = xp.where(placed, slot, rows.shape[0])
@@ -460,19 +463,29 @@ def build_fused_entry_merge(plan: PhysicalPlan, xp,
     return merge
 
 
-def _probe_slots(xp, keys, mask, key_tables, occ):
+def _probe_slots(xp, keys, mask, key_tables, occ=None, h=None,
+                 crowded=False):
     """Read-only side of ``_insert_keys``: the slot that stores each
     key (canonical), looked for in its two candidate slots, or the slot
-    count where neither does (or ``mask`` is off).  Nothing is claimed."""
-    S = occ.shape[0]
-    h = _fingerprint(xp, keys, mask.shape)
+    count where neither does (or ``mask`` is off).  Nothing is claimed.
+    ``h`` as in ``_merge_entries``.  Without ``occ`` a slot is occupied
+    where its first key lane's flag says so (the gather the comparison
+    makes anyway: a probe of every row of a join's batch pays for no
+    other).  With ``crowded`` -> ``(slot, crowded)``: the keys found in
+    neither slot whose two slots are BOTH taken -- where alone an
+    insert can have gone on to other slots."""
+    S = key_tables[0][1].shape[0] if occ is None else occ.shape[0]
+    if h is None:
+        h = _fingerprint(xp, keys, mask.shape)
     slot = xp.full(mask.shape, S, np.int32)
+    taken = mask
     for hp in (h, _mix(xp, h, _GOLD)):
         cand = (hp % np.uint64(S)).astype(np.int32)
-        hit = mask & occ[cand] & _stores(xp, keys, key_tables, cand,
-                                         mask.shape)
+        full = key_tables[0][1][cand] != 0 if occ is None else occ[cand]
+        hit = mask & full & _stores(xp, keys, key_tables, cand, mask.shape)
         slot = xp.where(hit & (slot == S), cand, slot)
-    return slot
+        taken = taken & full
+    return (slot, taken & (slot == S)) if crowded else slot
 
 
 #: slots per block of the filtered ending: the host reads one bit a

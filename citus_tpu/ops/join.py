@@ -1,0 +1,447 @@
+"""Device-side many-to-one equi-join: build once, probe every batch.
+
+A join the device runs is a TREE of relations rooted at the one that is
+streamed and probed (``planner/join_planner.py`` ``plan_device_join``:
+the largest distributed relation).  Every other relation is a *build*
+node: its rows that pass its own filter and find a partner in each of
+its own children go into a device-resident lookup table keyed by the
+edge to its parent, carrying the payload columns the root needs of it
+and of what lies below it.  The root's batches then probe its
+children's tables, and the rows that matched and passed every filter
+are PACKED into a fixed-capacity block -- the only rows the aggregate
+kernel ever sees.
+
+The table is the aggregation's (``ops/hash_agg.py``): the state layout
+``(key_tables, lane tables, rows)`` of ``empty_hash_state``, filled
+through ``_merge_entries`` / ``_insert_keys`` and read through
+``_probe_slots`` / ``_stores`` -- open addressing on the key lanes
+(int64 each: integers, dates, decimals' scaled integers), exact, never
+probabilistic.  A join cannot spill an entry to the host as a GROUP BY
+can, so an entry that loses both slots of its pair goes on to the
+next of ``JOIN_LEVELS`` pairs (the hash remixed); a probe looks into
+the first pair for every row of a batch -- two gathers a lane -- and
+into the later pairs only for the few rows whose first pair is taken
+twice over by other keys, after packing.  The join is many-to-one: a
+slot's ``rows`` counts the build rows that claimed it, and the build
+counts keys that came twice and entries no pair would take
+(``COUNTS``); either sends the statement to the host path.  NULL keys
+never match: they are masked out on both sides.
+
+Packing is one sort of the row positions (a scatter is a serial loop
+on a TPU and ``cumsum`` over a million rows takes XLA for TPU a minute
+to compile: PERF.md section 6).
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Callable, NamedTuple, Optional
+
+import numpy as np
+
+from citus_tpu.ops.hash_agg import (
+    ENTRY_CHUNK, _fingerprint, _merge_entries, _mix, _probe_slots,
+    empty_hash_state,
+)
+from citus_tpu.planner.bound import (
+    BExpr, _as_mask, compile_expr, predicate_mask,
+)
+
+#: pairs of candidate slots a build may try for an entry
+JOIN_LEVELS = 5
+_LEVEL_SALT = (None, np.uint64(0xD6E8FEB86659FD93),
+               np.uint64(0xA0761D6478BD642F), np.uint64(0xE7037ED1A0B428DB),
+               np.uint64(0x8EBC6AF09C88C6E3))
+#: slots a build row of a table: at a load of an eighth or less an entry
+#: finds all ``2 x JOIN_LEVELS`` of its slots taken once in 10^9
+SLOTS_PER_ROW = 8
+
+#: what a build counts, in the int32 vector that rides with its table
+BUILT, LATER_LEVEL, REPEATED, UNPLACED, PACKED_ROWS = range(5)
+COUNTS = 5
+
+#: what a probe round counts: rows packed (candidates for the block,
+#: over all rounds), rows with a build index, rows handed on
+PACKED, MATCHED, OUT = range(3)
+
+
+class _Lane(NamedTuple):
+    """A payload lane of a join table, in the terms of a partial state:
+    a unique key's lane is 0 + its value."""
+    dtype: str
+    kind: str = "sum"
+    arg_index: int = 0
+
+
+class _Lanes(NamedTuple):
+    partial_ops: tuple
+
+
+@dataclass(frozen=True)
+class ChildProbe:
+    """How a node's rows meet one of its children's tables."""
+    alias: str
+    keys: tuple            # BExpr over the node's own columns, a lane each
+    payload: tuple         # ((env name, dtype), ...) the child's table carries
+    kind: str = "hash"     # the child's table: "hash" | "direct"
+
+
+@dataclass(frozen=True)
+class JoinNode:
+    """One relation of the join tree, as its kernel sees it."""
+    alias: str
+    names: tuple                   # env names of its scan columns
+    filter: Optional[BExpr]        # its own conjuncts, literals hoisted
+    children: tuple                # ChildProbe, ...
+    key: tuple = ()                # the edge to its parent (() = the root)
+    payload: tuple = ()            # ((env name, dtype), ...) it hands up
+    post_filter: Optional[BExpr] = None    # the root's: cross-relation conjuncts
+    out: tuple = ()                # the root's: env names of its block
+    kind: str = "hash"             # a build node's own table
+
+
+def lane_dtype(dt) -> np.dtype:
+    dt = np.dtype(dt)
+    return np.dtype(np.int8) if dt == np.dtype(bool) else dt
+
+
+def payload_lanes(payload: tuple) -> _Lanes:
+    """A value lane and a validity lane (int8) per payload column."""
+    ops = []
+    for _, dt in payload:
+        ops += [_Lane(str(lane_dtype(dt))), _Lane("int8")]
+    return _Lanes(tuple(ops))
+
+
+def empty_join_table(node: JoinNode, slots: int, xp=np, rows: int = 0,
+                     lo=0):
+    """An empty table for build node ``node`` and the zeroed counts that
+    ride with it.  ``hash``: ``slots`` slots of the aggregation's state
+    layout.  ``direct``: ``(index, lanes, lo)`` -- ``index[key - lo]``
+    is 1 + the build row's place in the payload lanes (0: no such key),
+    ``slots`` the keys' span, ``rows`` the lanes' capacity.  Either
+    way ``table[0][1]`` are the payload lanes and a row's *slot* is its
+    place in them, the table's ``_span`` where it has none."""
+    if node.kind == "direct":
+        lanes = tuple(xp.zeros((rows,), np.dtype(op.dtype))
+                      for op in payload_lanes(node.payload).partial_ops)
+        state = (xp.zeros((slots,), np.int32), lanes,
+                 xp.asarray(lo, np.int64))
+    else:
+        state = empty_hash_state(payload_lanes(node.payload), slots,
+                                 (np.int64,) * len(node.key), xp)
+    return state, xp.zeros((COUNTS,), np.int32)
+
+
+def _span(kind: str, state) -> int:
+    """What a row's slot reads where it found no partner."""
+    return state[0].shape[0] if kind == "direct" else state[2].shape[0]
+
+
+def _direct_at(xp, state, keys, mask):
+    """-> (place in ``index`` of each key, the keys inside its span)."""
+    index, _, lo = state
+    (kv, _), = keys
+    k = kv - lo
+    ok = mask & (k >= 0) & (k < index.shape[0])
+    return xp.where(ok, k, 0).astype(np.int32), ok
+
+
+def block_capacity(n: int, block_rows: Optional[int] = None) -> int:
+    """Rows of the block a probe round of a batch of ``n`` rows hands
+    on: ``block_rows`` where given, else 1/64 of the bucket (a join
+    that keeps more of its probe rows takes further rounds)."""
+    return block_rows or min(n, max(1024, n // 64))
+
+
+def _level_hash(xp, h, level):
+    """The hash an entry's pair of slots at ``level`` >= 1 comes from
+    (``level`` may be traced: the later levels are one loop)."""
+    return _mix(xp, h, xp.asarray(np.array(_LEVEL_SALT[1:], np.uint64))[
+        level - 1])
+
+
+def _key_lanes(xp, key_fns, env, shape):
+    """-> ([(int64 values, all-true validity)], every lane valid)."""
+    ok = xp.ones(shape, bool)
+    vals = []
+    for fn in key_fns:
+        v, valid = fn(env)
+        v = xp.broadcast_to(xp.asarray(v).astype(np.int64), shape)
+        ok = ok & xp.broadcast_to(_as_mask(xp, valid, v), shape)
+        vals.append(v)
+    ones = xp.ones(shape, bool)
+    return [(xp.where(ok, v, 0), ones) for v in vals], ok
+
+
+def _pack(xp, mask, width: int):
+    """The positions where ``mask`` is on, in order, then the others:
+    padded to a whole number of ``width``; and their count."""
+    from jax import lax
+    n = mask.shape[0]
+    pos = xp.arange(n, dtype=np.int32)
+    order = lax.sort(xp.where(mask, pos, pos + np.int32(n)))
+    order = xp.where(order >= n, 0, order)
+    pad = -n % width
+    if pad:
+        order = xp.concatenate([order, xp.zeros((pad,), np.int32)])
+    return order, mask.sum(dtype=np.int32)
+
+
+class _Prefix:
+    """What the build and the probe kernels share: a batch's env, the
+    node's own filter, and its rows' way through the children's tables
+    -- the first pair of slots for every row, the later pairs for the
+    packed rows that may need them."""
+
+    def __init__(self, node: JoinNode, param_names: tuple, xp):
+        self.node, self.xp = node, xp
+        self.names = node.names + tuple(param_names)
+        self.filter_fn = compile_expr(node.filter, xp) \
+            if node.filter is not None else None
+        self.child_key_fns = [[compile_expr(k, xp) for k in ch.keys]
+                              for ch in node.children]
+
+    def env(self, cols, valids):
+        return {n: (c, v) for n, c, v in zip(self.names, cols, valids)}
+
+    def own_filter(self, env, row_mask):
+        if self.filter_fn is None:
+            return row_mask
+        return row_mask & predicate_mask(self.xp, self.filter_fn, env,
+                                         row_mask)
+
+    def first_pair(self, env, mask, child_tables):
+        """-> (rows that found a partner in every child or may yet,
+        rows that found one in the first pair, per child (slot, keys))."""
+        xp = self.xp
+        found_all = mask
+        through = mask
+        probes = []
+        for ch, fns, (state, counts) in zip(
+                self.node.children, self.child_key_fns, child_tables):
+            keys, ok = _key_lanes(xp, fns, env, mask.shape)
+            if ch.kind == "direct":
+                # one gather a row: the key is the address
+                at, ok = _direct_at(xp, state, keys, mask & ok)
+                place = state[0][at]
+                found = ok & (place > 0)
+                slot = xp.where(found, place - 1, _span(ch.kind, state))
+                may = found
+            else:
+                slot, crowded = _probe_slots(xp, keys, mask & ok, state[0],
+                                             crowded=True)
+                found = slot < _span(ch.kind, state)
+                may = found | (crowded & (counts[LATER_LEVEL] > 0))
+            found_all = found_all & found
+            through = through & may
+            probes.append((slot, keys))
+        return through, found_all, probes
+
+    def later_pairs(self, at, live, probes, child_tables):
+        """For the packed rows ``at``: the slot of each child's partner
+        (looked for in the later pairs where the first held none) and
+        the rows that have one in every child."""
+        from jax import lax
+        xp = self.xp
+        slots = []
+        for ch, (slot, keys), (state, _) in zip(
+                self.node.children, probes, child_tables):
+            S = _span(ch.kind, state)
+            slot = slot[at]
+            if ch.kind == "direct":
+                live = live & (slot < S)
+                slots.append(slot)
+                continue
+            keys = [(kv[at], kvm[at]) for kv, kvm in keys]
+            h = _fingerprint(xp, keys, live.shape)
+
+            def look(level, slot, keys=keys, h=h, state=state, S=S):
+                need = live & (slot == S)
+                return lax.cond(
+                    need.any(),
+                    lambda slot: xp.minimum(slot, _probe_slots(
+                        xp, keys, need, state[0],
+                        h=_level_hash(xp, h, level))),
+                    lambda slot: slot, slot)
+
+            slot = lax.fori_loop(1, JOIN_LEVELS, look, slot)
+            live = live & (slot < S)
+            slots.append(xp.minimum(slot, S - 1))
+        return live, slots
+
+    def child_payloads(self, env, slots, child_tables):
+        """The children's payload columns of the packed rows, into
+        ``env`` under their names."""
+        for ch, slot, (state, _) in zip(self.node.children, slots,
+                                        child_tables):
+            lanes = state[1]
+            for i, (name, dt) in enumerate(ch.payload):
+                v = lanes[2 * i][slot]
+                if np.dtype(dt) == np.dtype(bool):
+                    v = v != 0
+                env[name] = (v, lanes[2 * i + 1][slot] != 0)
+        return env
+
+
+def build_join_build(node: JoinNode, param_names: tuple, xp) -> Callable:
+    """The build step of node ``node``: (table, child_tables, cols,
+    valids, row_mask) -> table', ``table`` DONATED.  The batch's rows
+    that pass the node's filter, hold no NULL key and have a partner in
+    every child are packed to the front and offered to the table a
+    chunk at a time (the trip count follows their number), each with
+    its payload lanes: the node's own columns and its children's."""
+    from jax import lax
+
+    pre = _Prefix(node, param_names, xp)
+    key_fns = [compile_expr(k, xp) for k in node.key]
+    lanes = payload_lanes(node.payload)
+    own = set(node.names)
+
+    # named for its kernel slot: the XLA module in a device trace is
+    # jit_join_build
+    def join_build(table, child_tables, cols, valids, row_mask):
+        N = row_mask.shape[0]
+        env = pre.env(cols, valids)
+        mask = pre.own_filter(env, row_mask)
+        keys, ok = _key_lanes(xp, key_fns, env, (N,))
+        through, _, probes = pre.first_pair(env, mask & ok, child_tables)
+        C = min(ENTRY_CHUNK, N)
+        order, D = _pack(xp, through, C)
+
+        def offer(c, carry):
+            state, counts = carry
+            S = _span(node.kind, state)
+            at = lax.dynamic_slice(order, (c * C,), (C,))
+            live = c * C + xp.arange(C, dtype=np.int32) < D
+            live, slots = pre.later_pairs(at, live, probes, child_tables)
+            take = lambda a: a[at] if xp.ndim(a) else a
+            packed = {n: (take(env[n][0]), take(env[n][1]))
+                      for n, _ in node.payload if n in own}
+            packed = pre.child_payloads(packed, slots, child_tables)
+            entries = []
+            for name, dt in node.payload:
+                v, m = packed[name]
+                v = xp.broadcast_to(xp.asarray(v), (C,))
+                m = xp.broadcast_to(_as_mask(xp, m, v), (C,))
+                entries += [xp.where(m, v, 0).astype(lane_dtype(dt)),
+                            m.astype(np.int8)]
+            ekeys = [(kv[at], kvm[at]) for kv, kvm in keys]
+            if node.kind == "direct":
+                return direct(c, state, counts, ekeys, live, entries)
+            h = _fingerprint(xp, ekeys, (C,))
+            one = xp.ones((C,), np.int64)
+
+            def place(state, lost, hl):
+                state, placed = _merge_entries(
+                    xp, lanes.partial_ops, state, ekeys, lost, entries, one,
+                    h=hl)
+                lost = lost & ~placed
+                # a key met twice among these entries: the loser finds
+                # its own key stored
+                again = lost & (_probe_slots(
+                    xp, ekeys, lost, state[0], h=hl) < S)
+                return state, lost & ~again, again.sum(dtype=np.int32)
+
+            def later(level, carry):
+                state, lost, repeated = carry
+                state, lost, again = lax.cond(
+                    lost.any(),
+                    lambda a: place(*a, _level_hash(xp, h, level)),
+                    lambda a: (*a, np.int32(0)), (state, lost))
+                return state, lost, repeated + again
+
+            tally = [live.sum(dtype=np.int32)] + [np.int32(0)] * (COUNTS - 1)
+            state, lost, repeated = place(state, live, h)
+            tally[LATER_LEVEL] = lost.sum(dtype=np.int32)
+            state, lost, tally[REPEATED] = lax.fori_loop(
+                1, JOIN_LEVELS, later, (state, lost, repeated))
+            tally[UNPLACED] = lost.sum(dtype=np.int32)
+            return state, counts + xp.stack(tally)
+
+        def direct(c, state, counts, ekeys, live, entries):
+            """A chunk into a direct-address table: the rows' payload
+            goes to the lanes where the chunk stands among the packed
+            rows (contiguous: no scatter), their places to ``index`` at
+            their keys; a key that is there already, or that another
+            row of the chunk took, is a key met twice, and one outside
+            the span the statistics promised is refused."""
+            index, lane_tables, lo = state
+            at_k, ok = _direct_at(xp, state, ekeys, live)
+            first = counts[PACKED_ROWS] + c * C
+            place = first + xp.arange(C, dtype=np.int32) + 1
+            again = ok & (index[at_k] != 0)
+            new = ok & ~again
+            index = index.at[xp.where(new, at_k, index.shape[0])].set(
+                place, mode="drop")
+            lost = new & (index[at_k] != place)
+            lane_tables = tuple(
+                lax.dynamic_update_slice(t, e.astype(t.dtype), (first,))
+                for t, e in zip(lane_tables, entries))
+            tally = [np.int32(0)] * COUNTS
+            tally[BUILT] = (new & ~lost).sum(dtype=np.int32)
+            tally[REPEATED] = (again | lost).sum(dtype=np.int32)
+            tally[UNPLACED] = (live & ~ok).sum(dtype=np.int32)
+            return (index, lane_tables, lo), counts + xp.stack(tally)
+
+        state, counts = lax.fori_loop(0, (D + C - 1) // C, offer, table)
+        return state, counts.at[PACKED_ROWS].add(D)
+    return join_build
+
+
+def join_table_verdict(xp, table):
+    """-> int32 [COUNTS + 1]: a build's counts and the slots more than
+    one build row claimed (a key met twice across chunks or batches)."""
+    state, counts = table
+    twice = (state[2] > 1).sum(dtype=np.int32) if len(state[2].shape) \
+        else xp.zeros((), np.int32)      # a direct table counts as it builds
+    return xp.concatenate([counts, twice[None]])
+
+
+def build_join_probe(node: JoinNode, param_names: tuple, xp,
+                     block_rows: Optional[int] = None) -> Callable:
+    """The probe step of the root ``node``: (child_tables, cols, valids,
+    row_mask, round) -> (block cols, block valids, block mask, counts).
+    Every row of the batch looks into its children's tables; the rows
+    that matched and passed the node's filter are packed, and round
+    ``r`` hands on the ``r``-th ``block_rows`` of them (a power of two
+    from the batch's bucket where not given) with the columns
+    ``node.out`` names -- the node's own and the payload gathered from
+    the tables -- and the cross-relation conjuncts decided.  ``counts``
+    (``PACKED`` / ``MATCHED`` / ``OUT``) say whether another round is
+    due: ``PACKED`` > (r + 1) x ``block_rows``."""
+    from jax import lax
+
+    pre = _Prefix(node, param_names, xp)
+    post_fn = compile_expr(node.post_filter, xp) \
+        if node.post_filter is not None else None
+    params = tuple(param_names)
+
+    # named for its kernel slot: the XLA module in a device trace is
+    # jit_join_probe
+    def join_probe(child_tables, cols, valids, row_mask, rnd):
+        N = row_mask.shape[0]
+        C = block_capacity(N, block_rows)
+        env = pre.env(cols, valids)
+        through, found, probes = pre.first_pair(env, row_mask, child_tables)
+        order, D = _pack(xp, pre.own_filter(env, through), C)
+        at = lax.dynamic_slice(order, (rnd * C,), (C,))
+        live = rnd * C + xp.arange(C, dtype=np.int32) < D
+        live, slots = pre.later_pairs(at, live, probes, child_tables)
+        block = {n: (v[at] if xp.ndim(v) else v, m[at] if xp.ndim(m) else m)
+                 for n, (v, m) in env.items() if n not in params}
+        block = pre.child_payloads(block, slots, child_tables)
+        if post_fn is not None:
+            penv = dict(block)
+            penv.update({n: env[n] for n in params})
+            live = live & predicate_mask(xp, post_fn, penv, live)
+        out_cols, out_valids = [], []
+        for name in node.out:
+            v, m = block[name]
+            v = xp.broadcast_to(xp.asarray(v), (C,))
+            out_cols.append(v)
+            out_valids.append(xp.broadcast_to(_as_mask(xp, m, v), (C,)))
+        counts = xp.stack([D, found.sum(dtype=np.int32),
+                           live.sum(dtype=np.int32)])
+        return tuple(out_cols), tuple(out_valids), live, counts
+    return join_probe

@@ -182,14 +182,16 @@ def bind_join_select(catalog: Catalog, stmt: A.Select) -> BoundJoinSelect:
     for s in steps:
         if s.kind in ("right", "full"):
             left_of_right_join.update(a for a in joined if a != s.right_alias)
+    below_outer = outer_right | left_of_right_join
     for c in _conjuncts(where):
         alias = rel_alias_of_col(c)
         # pushing a filter below an outer join's null-supplying side would
         # change semantics; keep those conjuncts post-join
-        if alias is not None and alias not in outer_right and alias not in left_of_right_join:
+        if alias is not None and alias not in below_outer:
             rp = rel_plans[alias]
             rp.filter = c if rp.filter is None else BBinOp("and", rp.filter, c, T.BOOL_T)
-        else:
+        elif not _promote_equi_key(c, joined, steps, below_outer,
+                                   rel_alias_of_col):
             cross_conjuncts.append(c)
     post_filter = _and_all(cross_conjuncts)
     for rp in rel_plans.values():
@@ -315,6 +317,32 @@ def bind_join_select(catalog: Catalog, stmt: A.Select) -> BoundJoinSelect:
     )
     bj.strategy = _choose_strategy(bj)
     return bj
+
+
+def _promote_equi_key(c: BExpr, joined: list[str], steps: list[JoinStep],
+                      below_outer: set, rel_of) -> bool:
+    """A WHERE conjunct ``a = b`` whose sides each name one relation is
+    a join key of the first step at which both relations are present --
+    the published comma form of a join (``from customer, orders where
+    c_custkey = o_custkey``) then plans as its ``JOIN ... ON`` form
+    does.  For ``cross`` and ``inner`` steps only, and never below an
+    outer join's null-supplying side: there it stays a filter.  A
+    ``cross`` step that gains a key becomes ``inner``."""
+    if not (isinstance(c, BBinOp) and c.op == "="):
+        return False
+    la, ra = rel_of(c.left), rel_of(c.right)
+    if la is None or ra is None or la == ra \
+            or la in below_outer or ra in below_outer:
+        return False
+    li, ri = joined.index(la), joined.index(ra)
+    step = steps[max(li, ri) - 1]
+    if step.kind not in ("cross", "inner"):
+        return False
+    earlier, later = (c.left, c.right) if li < ri else (c.right, c.left)
+    step.left_keys.append(earlier)
+    step.right_keys.append(later)
+    step.kind = "inner"
+    return True
 
 
 def _resolve_order(e: A.Expr, items, names, binder, final_exprs, key_map, aggs) -> int:
@@ -443,3 +471,82 @@ def _repartition_spec(bj: BoundJoinSelect) -> Optional[tuple]:
     s, lks, rks = connecting
     left_alias = _rel_of(lks[0], qualified)
     return (left_alias, s.right_alias, lks, rks)
+
+
+# ------------------------------------------------------ the device join
+
+
+@dataclass
+class DeviceJoinTree:
+    """A colocated inner equi-join as the device runs it
+    (``ops/join.py``): the relations as a tree rooted at the one that
+    is streamed and probed; every other relation is built into a lookup
+    table keyed by the edge to its parent."""
+    root: str
+    parent: dict        # alias -> its parent's alias
+    edge: dict          # alias -> (its own key exprs, its parent's)
+    builds: list        # the build nodes, children before parents
+
+    def children(self, alias: str) -> list:
+        return [a for a in self.builds if self.parent[a] == alias]
+
+    def subtree(self, alias: str) -> list:
+        out = [alias]
+        for c in self.children(alias):
+            out += self.subtree(c)
+        return out
+
+
+def _device_key_type(t: T.ColumnType) -> bool:
+    return t.is_integer or t.is_decimal or t.kind in (
+        T.DATE, T.BOOL, T.TIMESTAMP, T.TIMESTAMPTZ, T.TIME)
+
+
+def plan_device_join(bj: BoundJoinSelect, rel_rows: dict):
+    """-> the ``DeviceJoinTree`` of a join the device can run, or the
+    reason (a string) it goes to the host path: every step an inner
+    equi-join without a residual whose keys tie its relation to ONE
+    earlier relation, key lanes the device holds as integers, an
+    aggregate above.  The root is the distributed relation with the
+    most rows (``rel_rows``, from the catalog; the later in FROM on a
+    tie) -- the many side of a many-to-one join, which the build then
+    checks row by row; without a distributed relation, the largest."""
+    qualified = bj.binder.qualified
+    if bj.strategy != "colocated":
+        return f"strategy {bj.strategy}"
+    if not bj.has_aggs:
+        return "no aggregate above the join"
+    if not bj.steps:
+        return "no join step"
+    links: dict = {}
+    for s in bj.steps:
+        if s.kind != "inner" or not s.left_keys:
+            return f"{s.kind} step"
+        if s.residual is not None:
+            return "residual ON condition"
+        others = {_rel_of(lk, qualified) for lk in s.left_keys}
+        mine = {_rel_of(rk, qualified) for rk in s.right_keys}
+        if mine != {s.right_alias} or len(others) != 1 or None in others:
+            return "a step's keys name more than two relations"
+        if not all(_device_key_type(k.type)
+                   for k in s.left_keys + s.right_keys):
+            return "a key lane the device does not hold as an integer"
+        links[s.right_alias] = (others.pop(), list(s.right_keys),
+                                list(s.left_keys))
+    order = [a for a, _ in bj.rels]
+    dist = [a for a, t in bj.rels if t.is_distributed]
+    root = max(dist or order,
+               key=lambda a: (rel_rows.get(a, 0), order.index(a)))
+    # the steps' tree (each relation hangs on an earlier one), re-rooted
+    near: dict = {a: [] for a in order}
+    for a, (b, mine, theirs) in links.items():
+        near[a].append((b, mine, theirs))
+        near[b].append((a, theirs, mine))
+    parent, edge, walk_order = {}, {}, [root]
+    for a in walk_order:
+        for b, mine, theirs in near[a]:
+            if b != root and b not in parent:
+                parent[b] = a
+                edge[b] = (theirs, mine)
+                walk_order.append(b)
+    return DeviceJoinTree(root, parent, edge, walk_order[:0:-1])

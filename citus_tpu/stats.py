@@ -30,6 +30,21 @@ class StatCounters:
         "router_queries",
         "multi_shard_queries",
         "join_queries",
+        # the device join (executor/join_device.py, per statement): the
+        # rounds of its scan loop, rows its builds put into lookup
+        # tables, padded rows of every probe round, probe rows that
+        # found a build row (before the probe side's own filter), rows
+        # handed to the aggregate, further rounds of blocks the
+        # survivors overflowed, bytes of the lookup tables resident at
+        # once; and the joins the device backend answered on the host
+        "join_dispatches",
+        "join_rows_built",
+        "join_rows_probed",
+        "join_rows_matched",
+        "join_rows_out",
+        "join_overflow_rounds",
+        "join_table_bytes",
+        "join_host_fallbacks",
         "tasks_dispatched",
         "rows_ingested",
         "rows_returned",

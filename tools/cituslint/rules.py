@@ -261,9 +261,11 @@ CONFINED_CALLS = {
     # same discipline for the streaming fused hash-table builder: only
     # the executor's jit_hash_fused / batched:jit_hash_fused slots may
     # enter it (the slot count / donated-state contract with
-    # empty_hash_state)
+    # empty_hash_state); the device join aggregates its packed
+    # survivors through the same slot
     "citus_tpu.ops.hash_agg.build_fused_hash_worker":
-        ("executor/executor.py", "executor/megabatch.py"),
+        ("executor/executor.py", "executor/megabatch.py",
+         "executor/join_device.py"),
     # hash-partial frames are wire format: encoded only by the task
     # codec halves, never ad-hoc
     "citus_tpu.net.data_plane.encode_hash_partials":
