@@ -300,7 +300,10 @@ def _run_analyze(cl, stmt: A.Explain) -> list[str]:
                          f"groups {pl['direct_groups_out']}, "
                          f"rows in {pl.get('group_rows_in', 0)} "
                          f"(kept {pl.get('group_rows_kept', 0)}), "
-                         f"fetched {pl.get('direct_bytes_fetched', 0)} bytes")
+                         f"fetched {pl.get('direct_bytes_fetched', 0)} bytes, "
+                         f"id lanes: {pl.get('direct_gid_keys_narrow', 0)} of "
+                         f"{pl.get('direct_gid_keys', 0)} keys 32-bit, "
+                         f"{pl.get('direct_gid_divisions', 0)} divisions")
         if "hash_slots" in pl:
             # each batch is sorted by key and segment-reduced on the
             # device, then offered to the table in chunks: U is the sum

@@ -40,7 +40,7 @@ from citus_tpu.observability import trace as _trace
 from citus_tpu.observability.trace import clock
 from citus_tpu.ops.scan_agg import (
     build_fused_worker_fn, build_worker_fn, combine_kinds,
-    combine_partials_host,
+    combine_partials_host, direct_id_lanes,
 )
 from citus_tpu.planner.auto_param import PHYSICAL_SRC, substitute_params
 from citus_tpu.planner.bind import BoundSelect
@@ -413,6 +413,17 @@ def _run_agg(cat: Catalog, plan: PhysicalPlan, settings: Settings,
                 fetched = pl["direct_bytes_fetched"] = _nbytes(partials)
                 GLOBAL_COUNTERS.bump("direct_groups", plan.group_mode.n_groups)
                 GLOBAL_COUNTERS.bump("direct_groups_out", len(out))
+                # how the kernel made the group id: keys whose code took
+                # no 64-bit division and rode 32-bit lanes, and the keys
+                # that divide at all (a date_trunc unit)
+                lanes = direct_id_lanes(plan)
+                pl["direct_gid_keys"] = len(lanes)
+                pl["direct_gid_keys_narrow"] = sum(l.narrow for l in lanes)
+                pl["direct_gid_divisions"] = sum(
+                    l.divide != "none" for l in lanes)
+                GLOBAL_COUNTERS.bump("direct_gid_keys", len(lanes))
+                GLOBAL_COUNTERS.bump("direct_gid_keys_narrow",
+                                     pl["direct_gid_keys_narrow"])
                 GLOBAL_COUNTERS.bump("direct_bytes_fetched", fetched)
                 GLOBAL_COUNTERS.bump("group_rows_kept", kept)
             if sp.recording:
@@ -1280,11 +1291,13 @@ def execute_select(cat: Catalog, bound: BoundSelect, settings: Settings,
                    param_values: Optional[list] = None) -> Result:
     t0 = clock()
     _guard_remote_written(cat, [bound.table.name])
-    if plan is not None and any(plan.proved_away) \
+    if plan is not None and (any(plan.proved_away)
+                             or plan.group_mode.kind == "direct") \
             and sees_staged_rows(bound.table):
-        # a cached plan dropped partial states on the strength of the
-        # table's statistics, and this scan sees rows they do not cover
-        # (the transaction's own staged writes): plan with every guard
+        # a cached plan dropped partial states, or sized its group
+        # table, on the strength of the table's statistics, and this
+        # scan sees rows they do not cover (the transaction's own staged
+        # writes): plan with every guard and no bound taken from them
         plan = None
     if plan is None:
         with _trace.span("plan_physical"):

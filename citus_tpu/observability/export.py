@@ -105,6 +105,8 @@ METRIC_HELP = {
     "direct_groups": "slots of the group domains of direct-group-id aggregations",
     "direct_groups_out": "groups those aggregations returned",
     "direct_bytes_fetched": "bytes of their partial states fetched",
+    "direct_gid_keys": "group keys of the direct-group-id aggregations that ran",
+    "direct_gid_keys_narrow": "those keys whose group code took no 64-bit division and rode 32-bit lanes",
     "agg_partials": "partial states the plans of aggregate queries computed",
     "agg_partials_proved_away": "overflow guards and null counts the table statistics proved redundant",
     "hash_slots": "slots of the device hash tables made",

@@ -24,11 +24,14 @@ Output convention:
 
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import Callable
 
 import numpy as np
 
-from citus_tpu.planner.bound import compile_expr, param_env_names, predicate_mask
+from citus_tpu.planner.bound import (
+    _as_mask, compile_expr, param_env_names, predicate_mask,
+)
 from citus_tpu.planner.physical import (
     PhysicalPlan, product_planes, shadow_sources,
 )
@@ -191,23 +194,109 @@ class _MatmulGroupSums:
 
 
 def _floor_div_small_quotient(xp, d, step: int, q_max: int):
-    """``d // step`` for int64 ``d``, exact wherever the quotient lies in
-    ``[0, q_max]``, without the 64-step shift-and-subtract loop that a
+    """``d // step`` for integer ``d``, exact wherever the quotient lies
+    in ``[0, q_max]``, without the 64-step shift-and-subtract loop that a
     64-bit division is on a TPU (it was a quarter of the hourly rollup's
     kernel).  A float32 estimate of a quotient below 2**17 is off by at
-    most one; one multiply and two compares in int64 settle it.  Other
-    rows (padding, NULL keys: the caller masks them) get any value in
-    ``[-1, q_max + 1]``."""
+    most one; one multiply and two compares in ``d``'s own width settle
+    it.  Other rows (padding, NULL keys: the caller masks them) get any
+    value in ``[-1, q_max + 1]``."""
     q = xp.floor(d.astype(np.float32) / np.float32(step))
     q = xp.clip(q, -1, q_max + 1).astype(np.int32)
-    r = d - q.astype(np.int64) * np.int64(step)
+    r = d - q.astype(d.dtype) * d.dtype.type(step)
     return q - (r < 0) + (r >= step)
+
+
+_INT32 = np.dtype(np.int32)
+
+
+@dataclass(frozen=True)
+class IdLane:
+    """How the direct mode makes one key's group code: the width the
+    key is subtracted in, and which division (if any) follows."""
+    sub: np.dtype    # lanes of ``kv - lo``
+    divide: str      # none | estimate | floor_div
+
+    @property
+    def narrow(self) -> bool:
+        """No 64-bit division, and 32-bit lanes from the code on."""
+        return self.divide != "floor_div" or self.sub == _INT32
+
+
+def direct_id_lanes(plan: PhysicalPlan) -> list[IdLane]:
+    """One ``IdLane`` a group key of a direct plan, from what the plan
+    proves: every row that counts has ``0 <= kv - lo <= step * (size -
+    2)``, its code lies below ``size`` and the id below ``n_groups``.
+
+    ``step == 1`` (a plain column, dictionary or boolean key): the code
+    IS the difference, below 2**31, so the low 32 bits of ``kv`` and of
+    ``lo`` give it exactly whatever the key's width, and nothing
+    divides (for a constant divisor of 1 XLA folds the quotient and
+    keeps the 64-bit remainder: two thirds of Q1's kernel).  Another
+    ``step`` (``date_trunc``): the difference keeps the key's own width
+    where 32 bits hold it and the estimate's correction, else int64;
+    the quotient is the float32 estimate (``size`` below 2**17) or a
+    floor division, narrowed right after."""
+    mode = plan.group_mode
+    assert mode.n_groups < 1 << 31, mode.n_groups
+    lanes = []
+    for key, d in zip(plan.bound.group_keys, mode.domains):
+        if d.step == 1:
+            lanes.append(IdLane(_INT32, "none"))
+            continue
+        # the difference and the estimate's ``q * step`` stay in int32
+        fits32 = (key.type.device_dtype.itemsize <= 4
+                  and -(1 << 31) <= d.lo < 1 << 31
+                  and d.step * (d.size + 1) < 1 << 31)
+        lanes.append(IdLane(_INT32 if fits32 else np.dtype(np.int64),
+                            "estimate" if d.size < 1 << 17 else "floor_div"))
+    return lanes
+
+
+def _low32(v: int) -> np.int32:
+    """The low 32 bits of ``v`` as an int32 (two's complement)."""
+    return np.int32((v + (1 << 31)) % (1 << 32) - (1 << 31))
+
+
+def direct_group_id_fn(plan: PhysicalPlan, xp) -> Callable:
+    """``group_id(env, mask) -> int32 [N]`` of a direct plan: the slot of
+    every row in ``[0, n_groups - 1]``, made as ``direct_id_lanes`` says.
+    A row inside its keys' domains gets ``sum((code + 1) * stride)``, a
+    NULL key code 0; a masked or padding row computes a wild (wrapped)
+    id from its zeroed values and gets slot 0, where its updates are
+    neutral.  The numpy and the jax arm take the same branches."""
+    mode = plan.group_mode
+    key_fns = [compile_expr(k, xp) for k in plan.bound.group_keys]
+    lanes = direct_id_lanes(plan)
+    G = mode.n_groups
+
+    def group_id(env, mask):
+        gid = None
+        for kf, lane, d, stride in zip(key_fns, lanes, mode.domains,
+                                       mode.strides):
+            kv, kvalid = kf(env)
+            if lane.divide == "none":
+                code = kv.astype(np.int32) - _low32(d.lo)
+            else:
+                diff = kv.astype(lane.sub) - lane.sub.type(d.lo)
+                if lane.divide == "estimate":
+                    code = _floor_div_small_quotient(xp, diff, d.step, d.size)
+                else:
+                    code = (diff // lane.sub.type(d.step)).astype(np.int32)
+            code = xp.where(_as_mask(xp, kvalid, kv), code + np.int32(1),
+                            np.int32(0))
+            part = code if stride == 1 else code * np.int32(stride)
+            gid = part if gid is None else gid + part
+        # the one clip keeps every index in the table whatever a row
+        # holds (XLA's scatter would drop it silently, numpy's raises)
+        return xp.clip(xp.where(mask, gid, np.int32(0)), np.int32(0),
+                       np.int32(G - 1))
+    return group_id
 
 
 def build_worker_fn(plan: PhysicalPlan, xp) -> Callable:
     """Build the per-shard worker function (pure, jittable when xp=jnp)."""
     filter_fn = compile_expr(plan.bound.filter, xp) if plan.bound.filter is not None else None
-    key_fns = [compile_expr(k, xp) for k in plan.bound.group_keys]
     arg_fns = [compile_expr(a, xp) for a in plan.agg_args]
     arg_types = [a.type for a in plan.agg_args]
     mode = plan.group_mode
@@ -234,7 +323,6 @@ def build_worker_fn(plan: PhysicalPlan, xp) -> Callable:
                     outs.append(xp.sum(mask, dtype=np.int64))
                     continue
                 v, valid = arg_fns[op.arg_index](env)
-                from citus_tpu.planner.bound import _as_mask
                 ok = mask & _as_mask(xp, valid, mask)
                 dt = np.dtype(op.dtype)
                 if op.kind == "count":
@@ -317,11 +405,8 @@ def build_worker_fn(plan: PhysicalPlan, xp) -> Callable:
         return worker_scalar
 
     if mode.kind == "direct":
-        los = [d.lo for d in mode.domains]
-        steps = [d.step for d in mode.domains]
-        sizes = [d.size for d in mode.domains]
-        strides = mode.strides
         G = mode.n_groups
+        group_id = direct_group_id_fn(plan, xp)
         reduction = direct_reduction(G, xp.__name__ == "numpy")
         # XLA lowers scatter with colliding indices to a serial loop on
         # TPU; for small group tables a masked one-hot reduction keeps
@@ -355,29 +440,9 @@ def build_worker_fn(plan: PhysicalPlan, xp) -> Callable:
             return (_np_scatter_min if kind == "min" else _np_scatter_max)(acc, gid, upd)
 
         def worker_direct(cols, valids, row_mask):
-            from citus_tpu.planner.bound import _as_mask
             env = make_env(cols, valids)
             mask = eval_mask(env, row_mask)
-            gid = None
-            for kf, lo, step, size, stride in zip(key_fns, los, steps, sizes,
-                                                  strides):
-                kv, kvalid = kf(env)
-                kvm = _as_mask(xp, kvalid, kv)
-                d = kv.astype(np.int64) - lo
-                if step != 1 and xp.__name__ != "numpy" and size < 1 << 17:
-                    code = _floor_div_small_quotient(xp, d, step, size)
-                else:
-                    code = d // step
-                code = xp.where(kvm, code + 1, 0)
-                # clamp padding rows into range; they are masked out anyway
-                code = xp.clip(code, 0, None)
-                part = code * stride
-                gid = part if gid is None else gid + part
-            # masked/padding rows may compute wild codes from zeroed values;
-            # clamp into table range (their updates are neutral anyway, and
-            # unclamped indexes would be silently dropped by XLA scatter but
-            # error under numpy)
-            gid = xp.clip(xp.where(mask, gid, 0), 0, G - 1).astype(np.int32)
+            gid = group_id(env, mask)
             # the middle reduction takes every count and int64 sum of the
             # batch in ONE product: they queue here and fill their slots
             # after the loop; float sums, min and max reduce as above
@@ -421,8 +486,9 @@ def build_worker_fn(plan: PhysicalPlan, xp) -> Callable:
         return worker_direct
 
     # hash_host: device evaluates filter, keys and agg inputs; host groups
+    key_fns = [compile_expr(k, xp) for k in plan.bound.group_keys]
+
     def worker_hash(cols, valids, row_mask):
-        from citus_tpu.planner.bound import _as_mask
         env = make_env(cols, valids)
         mask = eval_mask(env, row_mask)
         keys = []

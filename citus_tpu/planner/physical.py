@@ -214,8 +214,10 @@ def prune_shards(table: TableMeta, filter_: Optional[BExpr],
 
 
 def _key_domain(cat: Catalog, table: TableMeta, key: BExpr,
-                bounds: dict[str, tuple]) -> Optional[KeyDomain]:
-    """Provable physical domain of a group key, or None."""
+                bounds: Optional[dict[str, tuple]]) -> Optional[KeyDomain]:
+    """Provable physical domain of a group key, or None.  ``bounds`` is
+    None where the scan sees rows no statistic covers: only a domain the
+    key's type proves (a dictionary, a boolean) stands then."""
     if isinstance(key, BColumn):
         if key.type.kind == T.UUID or T.is_uuid_lane(key.name):
             # 128-bit lane pairs have no enumerable domain
@@ -229,6 +231,8 @@ def _key_domain(cat: Catalog, table: TableMeta, key: BExpr,
             # never direct-encode floats: -0.0/0.0 and NaN payloads
             # need the hash path's canonical equality, and NaN poisons
             # min/max stats (which would masquerade as "all null" here)
+            return None
+        if bounds is None:
             return None
         b = bounds.get(key.name)
         if b is None:
@@ -418,7 +422,8 @@ def choose_group_mode(cat: Catalog, bound: BoundSelect, direct_limit: int = 0,
     if any(a.kind in AGG_REGISTRY and AGG_REGISTRY[a.kind].host_grouped
            for a in bound.aggs):
         return GroupMode(kind="hash_host")
-    bounds = column_bounds(cat, bound.table)
+    bounds = (None if sees_staged_rows(bound.table)
+              else column_bounds(cat, bound.table))
     domains: list[KeyDomain] = []
     for key in bound.group_keys:
         d = _key_domain(cat, bound.table, key, bounds)

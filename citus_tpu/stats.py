@@ -224,6 +224,11 @@ class StatCounters:
         "direct_groups",
         "direct_groups_out",
         "direct_bytes_fetched",
+        # group keys of the direct plans that ran, and those whose code
+        # ops/scan_agg.py direct_id_lanes made without a 64-bit division
+        # and carried in 32-bit lanes
+        "direct_gid_keys",
+        "direct_gid_keys_narrow",
         # aggregate queries: partial states their plans compute, and
         # the overflow guards and per-argument NULL counts that
         # planner/physical.py lower_aggregates proved away from the
