@@ -294,6 +294,11 @@ def _run_analyze(cl, stmt: A.Explain) -> list[str]:
         if "stream_window_peak_bytes" in pl:
             line += (f", stream window peak "
                      f"{pl['stream_window_peak_bytes']} bytes")
+        if "scan_lanes" in pl:
+            # int64 scan columns the table's statistics bound inside
+            # int32: the device holds them, and reads them, at 32 bits
+            line += (f", lanes: {pl.get('scan_lanes_narrow', 0)} of "
+                     f"{pl['scan_lanes']} 64-bit columns at 32 bits")
         lines.append(line)
         if "direct_groups" in pl:
             lines.append(f"    Direct: group slots {pl['direct_groups']}, "

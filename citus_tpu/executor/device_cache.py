@@ -4,10 +4,11 @@ The reference keeps hot table blocks in PostgreSQL shared buffers; the
 TPU-native analog is keeping decompressed, padded column batches resident
 in device HBM across queries.  Entries are keyed by
 (table, table.version, snapshot flip generation, shard, projected
-columns, pruning signature, bucket) — any ingest/DDL bumps the version
-and naturally invalidates, and the generation keys out the two windows
-version alone misses (the version is committed before the stripe flip,
-and a torn scan's put must not satisfy the seqlock retry after it).
+columns, pruning signature, the lane each column rides at) — any
+ingest/DDL bumps the version and naturally invalidates, and the
+generation keys out the two windows version alone misses (the version
+is committed before the stripe flip, and a torn scan's put must not
+satisfy the seqlock retry after it).
 
 A simple byte-bounded LRU keeps us inside HBM (the capacity is a
 constant, not yet read off the device: chip_smoke.py prints the chip's
@@ -153,5 +154,7 @@ def plan_cache_key(plan, data_dir: str) -> tuple:
     # validation, i.e. the cached scan was consistent.
     from citus_tpu.transaction.snapshot import read_generation
     gen, _busy = read_generation(data_dir, t)
+    # ... and the width each column rides the device at: an entry is
+    # read only by a plan that expects its widths
     return (data_dir, t.name, t.version, gen, tuple(plan.scan_columns),
-            shard_ids, intervals)
+            shard_ids, intervals, plan.lanes)

@@ -33,7 +33,7 @@ from citus_tpu.executor.batches import (
 from citus_tpu.executor.finalize import finalize_groups, order_and_limit, project_rows
 from citus_tpu.executor.kernel_cache import get_kernel, jit_compile
 from citus_tpu.executor.scan_loop import (
-    Step, _block_ready, _nbytes, _prefetch_depth,
+    ScanLanesBelied, Step, _block_ready, _nbytes, _prefetch_depth,
     choose_affine_placement, choose_placement, drive,
 )
 from citus_tpu.observability import trace as _trace
@@ -1292,12 +1292,14 @@ def execute_select(cat: Catalog, bound: BoundSelect, settings: Settings,
     t0 = clock()
     _guard_remote_written(cat, [bound.table.name])
     if plan is not None and (any(plan.proved_away)
-                             or plan.group_mode.kind == "direct") \
+                             or plan.group_mode.kind == "direct"
+                             or plan.narrow_lanes) \
             and sees_staged_rows(bound.table):
-        # a cached plan dropped partial states, or sized its group
-        # table, on the strength of the table's statistics, and this
-        # scan sees rows they do not cover (the transaction's own staged
-        # writes): plan with every guard and no bound taken from them
+        # a cached plan dropped partial states, sized its group table
+        # or narrowed a scan column's lane on the strength of the
+        # table's statistics, and this scan sees rows they do not cover
+        # (the transaction's own staged writes): plan with every guard,
+        # no bound taken from them and every column at its full width
         plan = None
     if plan is None:
         with _trace.span("plan_physical"):
@@ -1355,6 +1357,14 @@ def _execute_select_traced(cat: Catalog, bound: BoundSelect,
         # never-block property the reference inherits from PostgreSQL)
         run_plan = plan
 
+        def _replan(trust_stats: bool = True) -> PhysicalPlan:
+            fresh = plan_select(
+                cat, bound, direct_limit=settings.planner.direct_gid_limit,
+                trust_stats=trust_stats)
+            if bound.param_specs:
+                fresh = _bind_time_prune(fresh, params)
+            return fresh
+
         def _attempt():
             nonlocal run_plan
             if run_plan.table_shard_count not in (-1,
@@ -1363,14 +1373,18 @@ def _execute_select_traced(cat: Catalog, bound: BoundSelect,
                 # built (a split's catalog flip racing the scan):
                 # planned shard indexes would resolve against the NEW
                 # shard list — re-plan before (re)trying
-                run_plan = plan_select(
-                    cat, bound,
-                    direct_limit=settings.planner.direct_gid_limit)
-                if bound.param_specs:
-                    run_plan = _bind_time_prune(run_plan, params)
-            if bound.has_aggs:
+                run_plan = _replan()
+            if not bound.has_aggs:
+                return _run_projection(cat, run_plan, settings, params)
+            try:
                 return _run_agg(cat, run_plan, settings, params)
-            return _run_projection(cat, run_plan, settings, params)
+            except ScanLanesBelied:
+                # a batch held a value the table's statistics rule out
+                # (nothing was cached, no state fetched): what else the
+                # plan took from them stands no better, so the statement
+                # runs once more on a plan that takes nothing from them
+                run_plan = _replan(trust_stats=False)
+                return _run_agg(cat, run_plan, settings, params)
         rows = snapshot_read(cat.data_dir, bound.table, _attempt,
                              timeout=settings.executor.lock_timeout_s)
         plan = run_plan

@@ -192,13 +192,16 @@ class _DeviceJoin:
         fp = hashlib.sha256(repr((
             sorted(self.nodes.items()), scans, bj.group_keys, bj.agg_args,
             bj.partial_ops, len(specs))).encode()).hexdigest()
+        # every relation's columns ride at their logical widths: the
+        # join's lanes are not narrowed from the statistics (yet)
         self.holder = SimpleNamespace(
             bound=SimpleNamespace(table=self.tables_of[root]),
+            narrow_lanes=(), wide_lanes=0,
             runtime_cache={"_fingerprint": fp})
         # the aggregate over the block, as the hash kernel reads a plan
         self.agg = SimpleNamespace(
             bound=SimpleNamespace(filter=None, group_keys=list(bj.group_keys),
-                                  param_specs=specs),
+                                  param_specs=specs, table=None),
             agg_args=bj.agg_args, scan_columns=list(out),
             partial_ops=bj.partial_ops, agg_extract=bj.agg_extract,
             runtime_cache=self.holder.runtime_cache)

@@ -206,7 +206,19 @@ def get_kernel(plan, slot: str, build: Callable[[], object],
     # lives, the HAVING it compiled)
     if k is not None and rc.get(slot + "#extra", ()) == extra:
         return k
-    key = (plan_fingerprint(plan), slot) + extra
+    k = shared_kernel(slot, build, extra, plan_fingerprint(plan))
+    rc[slot] = k
+    rc[slot + "#extra"] = extra
+    return k
+
+
+def shared_kernel(slot: str, build: Callable[[], object], extra: tuple = (),
+                  family: str = ""):
+    """The global LRU's kernel for (family, slot) + extra, built and
+    published on a true miss.  ``family`` is a plan's fingerprint
+    (``get_kernel``); a slot that no plan shapes -- the scan loop's
+    lane convert -- keeps one kernel a process under the empty family."""
+    key = (family, slot) + tuple(extra)
     k = GLOBAL_KERNELS.get(key)
     if k is None:
         _counters().bump("kernel_cache_misses")
@@ -218,8 +230,6 @@ def get_kernel(plan, slot: str, build: Callable[[], object],
         _counters().bump("kernel_cache_hits")
         with _trace.span("kernel", slot=slot, cache="hit"):
             pass
-    rc[slot] = k
-    rc[slot + "#extra"] = extra
     return k
 
 

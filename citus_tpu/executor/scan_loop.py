@@ -16,10 +16,21 @@ and the only one.  What differs between the scans is data:
   row_mask)`` — or ``(state', aux)`` beside a sync hook — with the slot
   and counter names its rounds are booked under;
 - the first state and the tail (``_fetch_acc``) are the caller's.
+
+**Lanes.**  The device form of a scan column is as wide as the table's
+statistics prove it has to be (``PhysicalPlan.scan_lanes``): an int64
+column they bound inside int32 is narrowed ON THE DEVICE right after
+its put, by one ``jit_narrow`` dispatch a round that also reduces
+"some valid row's value did not survive" into one flag the placement
+carries from round to round.  The kernels widen where they read
+(``ops/scan_agg.py`` ``scan_env_fn``); ``drive`` looks at the flag where
+it blocks anyway and raises ``ScanLanesBelied`` before anything enters
+the batch cache or a result leaves.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import itertools
 from typing import Callable, Iterator, NamedTuple, Optional
 
@@ -64,6 +75,78 @@ def _prefetch_depth(settings) -> int:
                settings.executor.max_tasks_in_flight)
 
 
+class ScanLanesBelied(Exception):
+    """A batch held a value its table's statistics rule out: the scan's
+    narrowed lanes lost it.  Nothing was cached and no result returned;
+    the statement runs again on a plan that takes nothing from the
+    statistics (``executor.py`` ``_execute_select_traced``)."""
+
+
+def _narrow_kernel(suffix: tuple, sharding):
+    """The lane convert of a placement, one a process and placement
+    kind: no plan shapes it (jit keys the column count and the bucket
+    itself).  Module ``jit_narrow`` in a device trace."""
+    import jax.numpy as jnp
+    from citus_tpu.executor.kernel_cache import jit_compile, shared_kernel
+
+    def narrow(wide, valids, row_mask, belied):
+        out = tuple(c.astype(np.int32) for c in wide)
+        for c, o, v in zip(wide, out, valids):
+            # a NULL slot or a padding row may hold anything: the
+            # validity bit and the row mask decide, not the value
+            lost = (o.astype(c.dtype) != c) & v & row_mask
+            belied = belied | jnp.any(lost, axis=-1)
+        return out, belied
+    # the wide arrays are donated: nothing reads them again
+    return shared_kernel(
+        "jit_narrow",
+        lambda: jit_compile(narrow, donate_argnums=0, out_shardings=sharding),
+        extra=suffix)
+
+
+class Lanes:
+    """What both placements do about a plan's narrow lanes: convert the
+    marked columns of a round that is already on the device, carry the
+    flag from round to round, say at the end what it reads."""
+
+    sharding = None    # of a round's arrays (None: the default device)
+    belied = None      # device flag(s): a scalar, or one a mesh device
+    _marked: tuple = ()
+
+    def prepare(self, plan) -> None:
+        """Before a streamed scan's first round: the convert, where the
+        plan has narrow lanes, and the flag it starts from."""
+        self._marked = plan.narrow_lanes
+        if self._marked and self.belied is None:
+            import jax
+            self._convert = _narrow_kernel(self.key_suffix, self.sharding)
+            self.belied = jax.device_put(
+                np.zeros(() if self.sharding is None else (self.round_size,),
+                         bool), self.sharding)
+
+    def _narrow(self, cols: tuple, valids: tuple, row_mask) -> tuple:
+        """-> ``cols`` with the plan's narrow lanes as int32."""
+        marked = self._marked
+        with _trace.span("narrow") as sp:
+            out, self.belied = self._convert(
+                tuple(cols[i] for i in marked),
+                tuple(valids[i] for i in marked), row_mask, self.belied)
+            if sp.recording:
+                sp.set(columns=len(marked))
+        cols = list(cols)
+        for i, c in zip(marked, out):
+            cols[i] = c
+        return tuple(cols)
+
+    def lanes_belied(self) -> bool:
+        """Whether any round's convert lost a valid value.  Waits for
+        the last convert (the fetch of one flag a scan)."""
+        if self.belied is None:
+            return False
+        import jax
+        return bool(np.any(jax.device_get(self.belied)))
+
+
 class Step(NamedTuple):
     fn: Callable
     slot: str       # on the round's ``dispatch`` span
@@ -73,10 +156,11 @@ class Step(NamedTuple):
 # ------------------------------------------------------------ placements
 
 
-class OneDevice:
+class OneDevice(Lanes):
     """A round is one host batch, put on the default device as a
-    ShardBatch of device arrays (which is also its cache entry);
-    parameters ride along as the host arrays they are."""
+    ShardBatch of device arrays (which is also its cache entry: the
+    plan's narrow lanes as int32); parameters ride along as the host
+    arrays they are."""
 
     round_size = 1
     key_suffix: tuple = ()
@@ -101,10 +185,18 @@ class OneDevice:
                             hb.padded_rows, hb.shard_index)
             if sp.recording:
                 sp.set(bytes=nbytes)
+        if self._marked:
+            db = dataclasses.replace(
+                db, cols=self._narrow(db.cols, db.valids, db.row_mask))
         return db, nbytes
 
     def args(self, b: ShardBatch) -> tuple:
         return b.cols + self.pcols, b.valids + self.pvalids, b.row_mask
+
+    @staticmethod
+    def held_bytes(b: ShardBatch) -> int:
+        """Device bytes of a round's inputs: what a cache entry holds."""
+        return b.nbytes
 
     def describe(self, members, b: ShardBatch) -> dict:
         return {"shard_index": int(b.shard_index), "rows": int(b.n_rows),
@@ -134,7 +226,7 @@ def _repad_batch(b: ShardBatch, bucket: int) -> ShardBatch:
     return ShardBatch(cols, valids, mask, b.n_rows, bucket, b.shard_index)
 
 
-class MeshPlacement:
+class MeshPlacement(Lanes):
     """A round is up to ``n_dev`` host batches (a member may be None: a
     device with nothing this round), re-padded to the round's largest
     bucket, filled up with empty batches and stacked along the
@@ -142,7 +234,9 @@ class MeshPlacement:
     stacks, a different structure than the one-device ShardBatch list, so
     its cache entries key apart.  Parameters replicate across the shard
     axis as ``[n_dev]`` stacks, put on the mesh once a query (never
-    cached: they change per execution)."""
+    cached: they change per execution).  The plan's narrow lanes are
+    converted on the chips after the put, each device its own row of
+    the stack and its own flag: no collective."""
 
     def __init__(self, mesh) -> None:
         from jax.sharding import NamedSharding, PartitionSpec
@@ -181,21 +275,27 @@ class MeshPlacement:
             if sp.recording:
                 sp.set(bytes=nbytes)
         with _trace.span("h2d") as sp:
-            inputs = (tuple(jax.device_put(c, self.sharding) for c in cols),
-                      tuple(jax.device_put(v, self.sharding) for v in valids),
-                      jax.device_put(mask, self.sharding))
+            dcols = tuple(jax.device_put(c, self.sharding) for c in cols)
+            dvalids = tuple(jax.device_put(v, self.sharding) for v in valids)
+            dmask = jax.device_put(mask, self.sharding)
             if sp.recording:
                 sp.set(bytes=nbytes)
-        return inputs, nbytes
+        if self._marked:
+            dcols = self._narrow(dcols, dvalids, dmask)
+        return (dcols, dvalids, dmask), nbytes
 
     def args(self, inputs: tuple) -> tuple:
         dcols, dvalids, dmask = inputs
         return dcols + self.pcols, dvalids + self.pvalids, dmask
 
-    def describe(self, members, inputs: tuple) -> dict:
+    @staticmethod
+    def held_bytes(inputs: tuple) -> int:
         dcols, dvalids, dmask = inputs
+        return _nbytes(dcols) + _nbytes(dvalids) + dmask.nbytes
+
+    def describe(self, members, inputs: tuple) -> dict:
         return {"batches": len(members) if members else self.round_size,
-                "bytes": _nbytes(dcols) + _nbytes(dvalids) + dmask.nbytes}
+                "bytes": self.held_bytes(inputs)}
 
     def book(self, members, inputs, nbytes: int, round_s: float,
              dispatch_s: float) -> None:
@@ -385,12 +485,13 @@ def drive(plan, settings, placement, step: Step, state, pstats: PipelineStats,
     The rounds are ``cached`` (device inputs a previous scan kept:
     replayed as they are) or come from ``stream`` (host batches, pulled
     through the decode thread and grouped by the placement's round
-    size).  A streamed scan keeps its device inputs and puts them in
-    the HBM batch cache under ``cache_key`` when the whole working set
-    fits it; past the capacity (or with no key) it streams: at most
-    ``_prefetch_depth`` rounds are un-synced, and since the donated
-    state chain orders the rounds, a wait for one output of the newest
-    state retires every round admitted before it.
+    size).  A streamed scan keeps its device inputs (the plan's narrow
+    lanes as int32: what they hold is what the cache books) and puts
+    them in the HBM batch cache under ``cache_key`` when the whole
+    working set fits it; past the capacity (or with no key) it streams:
+    at most ``_prefetch_depth`` rounds are un-synced, and since the
+    donated state chain orders the rounds, a wait for one output of the
+    newest state retires every round admitted before it.
 
     With ``on_sync`` the step returns ``(state', aux)``; the hook gets
     the ``[(host members, aux)]`` of the rounds since the last sync at
@@ -402,6 +503,7 @@ def drive(plan, settings, placement, step: Step, state, pstats: PipelineStats,
 
     streamed = cached is None
     if streamed:
+        placement.prepare(plan)
         # host/device overlap: the decode thread prepares the next
         # rounds while the device executes the current one
         source = prefetch_batches(
@@ -413,7 +515,7 @@ def drive(plan, settings, placement, step: Step, state, pstats: PipelineStats,
     depth = _prefetch_depth(settings)
     table = plan.bound.table.name
     pending: list = []
-    rounds = nbytes = since_sync = window_bytes = 0
+    rounds = nbytes = held = since_sync = window_bytes = 0
     try:
         for members, inputs in todo:
             synced = False
@@ -440,7 +542,8 @@ def drive(plan, settings, placement, step: Step, state, pstats: PipelineStats,
                 placement.book(members, inputs, nb, t1 - t_dev, t1 - t0)
                 if collect is not None:
                     collect.append(inputs)
-                    if nbytes > GLOBAL_CACHE.capacity:
+                    held += placement.held_bytes(inputs)
+                    if held > GLOBAL_CACHE.capacity:
                         collect = None  # working set exceeds the HBM cache
                 if streamed and collect is None:
                     window_bytes += nb
@@ -461,16 +564,28 @@ def drive(plan, settings, placement, step: Step, state, pstats: PipelineStats,
     finally:
         if streamed:
             source.close()
+    if streamed and placement.lanes_belied():
+        # the footers' bounds did not hold for a batch this scan put:
+        # its narrowed values are not the table's.  Found where the
+        # loop blocks anyway: before the cache's wait for the inputs,
+        # and just ahead of the caller's fetch of the state
+        GLOBAL_COUNTERS.bump("scan_lanes_belied")
+        raise ScanLanesBelied(table)
     if on_sync is not None:
         on_sync(pending)
     if collect:
         _block_ready([placement.args(i)[0] for i in collect])
         with _trace.span("cache_put"):
-            GLOBAL_CACHE.put(cache_key, collect, nbytes, tenant=cache_tenant)
+            GLOBAL_CACHE.put(cache_key, collect, held, tenant=cache_tenant)
     pstats.rounds += rounds
     GLOBAL_COUNTERS.bump(step.counter, rounds)
     pl = plan.runtime_cache.setdefault("pipeline", {})
     pl["fused_dispatches"] = pstats.rounds
+    # the int64 scan columns of this scan, and those that rode at 32 bits
+    pl["scan_lanes"] = plan.wide_lanes
+    pl["scan_lanes_narrow"] = len(plan.narrow_lanes)
+    GLOBAL_COUNTERS.bump("scan_lanes", pl["scan_lanes"])
+    GLOBAL_COUNTERS.bump("scan_lanes_narrow", pl["scan_lanes_narrow"])
     if streamed:
         pstats.h2d_bytes += nbytes
         GLOBAL_COUNTERS.bump("bytes_scanned", nbytes)

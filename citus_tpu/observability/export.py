@@ -107,6 +107,9 @@ METRIC_HELP = {
     "direct_bytes_fetched": "bytes of their partial states fetched",
     "direct_gid_keys": "group keys of the direct-group-id aggregations that ran",
     "direct_gid_keys_narrow": "those keys whose group code took no 64-bit division and rode 32-bit lanes",
+    "scan_lanes": "int64 scan columns of the device scans that ran",
+    "scan_lanes_narrow": "those the table statistics bound inside int32 and the device held at 32 bits",
+    "scan_lanes_belied": "device scans a batch of which held a value outside those bounds (re-run at full width)",
     "agg_partials": "partial states the plans of aggregate queries computed",
     "agg_partials_proved_away": "overflow guards and null counts the table statistics proved redundant",
     "hash_slots": "slots of the device hash tables made",

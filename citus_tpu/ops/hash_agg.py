@@ -66,10 +66,10 @@ from typing import Callable
 
 import numpy as np
 
-from citus_tpu.planner.bound import _as_mask, compile_expr, param_env_names, predicate_mask
+from citus_tpu.planner.bound import _as_mask, compile_expr, predicate_mask
 from citus_tpu.planner.aggregates import float_bits
 from citus_tpu.planner.physical import PhysicalPlan
-from citus_tpu.ops.scan_agg import _sentinel
+from citus_tpu.ops.scan_agg import _sentinel, scan_env_fn
 
 _FNV = np.uint64(0xCBF29CE484222325)
 _C1 = np.uint64(0xBF58476D1CE4E5B9)
@@ -314,7 +314,7 @@ def build_fused_hash_worker(plan: PhysicalPlan, xp,
         if plan.bound.filter is not None else None
     key_fns = [compile_expr(k, xp) for k in plan.bound.group_keys]
     arg_fns = [compile_expr(a, xp) for a in plan.agg_args]
-    names = plan.scan_columns + param_env_names(plan.bound.param_specs)
+    make_env = scan_env_fn(plan)
     partial_ops = plan.partial_ops
     key_dtypes = tuple(np.dtype(d) for d in key_dtypes)
     used_args = sorted({op.arg_index for op in partial_ops
@@ -330,7 +330,7 @@ def build_fused_hash_worker(plan: PhysicalPlan, xp,
     # jit_hash_fused, apart from the scan kernel's jit_fused
     def hash_fused(table_state, cols, valids, row_mask):
         N = row_mask.shape[0]
-        env = {n: (c, v) for n, c, v in zip(names, cols, valids)}
+        env = make_env(cols, valids)
         mask = row_mask
         if filter_fn is not None:
             mask = mask & predicate_mask(xp, filter_fn, env, row_mask)
@@ -339,7 +339,9 @@ def build_fused_hash_worker(plan: PhysicalPlan, xp,
         # and the masked-out rows last.  One uint32 key and the row
         # index: XLA for TPU compiles a sort in 10-15 s PER 32-bit lane
         # (five lanes: 78 s), so the rows follow by gather — the scan
-        # columns a key or an argument reads (XLA drops the others),
+        # columns a key or an argument reads (XLA drops the others), at
+        # the width they arrived (an int64 column that rides as int32
+        # is ONE gather lane: ``make_env`` widens after the gather),
         # their validity bits packed 31 to a lane — and keys and
         # arguments are evaluated on the rows in that order.
         h = _fingerprint(
@@ -353,10 +355,11 @@ def build_fused_hash_worker(plan: PhysicalPlan, xp,
         packs = [sum(valids[i].astype(np.int32) << b
                      for b, i in enumerate(rowwise[at:at + 31]))[perm]
                  for at in range(0, len(rowwise), 31)]
-        env = dict(env)
+        cols, valids = list(cols), list(valids)
         for j, i in enumerate(rowwise):
-            env[names[i]] = (cols[i][perm],
-                             (packs[j // 31] >> (j % 31)) & 1 == 1)
+            cols[i] = cols[i][perm]
+            valids[i] = (packs[j // 31] >> (j % 31)) & 1 == 1
+        env = make_env(cols, valids)
         keys = _eval_keys(xp, key_fns, key_dtypes, env, (N,))
         args = {}
         for ai in used_args:

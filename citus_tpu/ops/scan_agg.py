@@ -294,6 +294,36 @@ def direct_group_id_fn(plan: PhysicalPlan, xp) -> Callable:
     return group_id
 
 
+def scan_env_fn(plan: PhysicalPlan) -> Callable:
+    """The ONE maker of a scan kernel's ``env``: ``make_env(cols,
+    valids) -> {name: (values, valid)}`` over the scan columns and the
+    trailing parameter "columns".  A scan column whose
+    array arrives narrower than its logical device dtype (an int64
+    column the table's statistics bound inside int32 rides the device
+    as int32: ``PhysicalPlan.scan_lanes``) is widened to it HERE, inside
+    the traced body, so every expression, filter, key and aggregate
+    computes on the values and at the dtype it always did, and on a
+    TPU no 64-bit parameter has to be cut into halves by a pass of its
+    own.  A kernel that moves rows first (the hash kernel's gather
+    into sorted order) moves the arrays as they arrived and makes its
+    env of the moved ones: a narrowed column is one 32-bit lane there."""
+    names = plan.scan_columns + param_env_names(plan.bound.param_specs)
+    # (the join's block of payload lanes is no table's: nothing to widen)
+    table = plan.bound.table
+    logical = [] if table is None else [
+        np.dtype(table.schema.scan_dtype(c, device=True))
+        for c in plan.scan_columns]
+
+    def make_env(cols, valids):
+        env = {}
+        for i, (n, c, v) in enumerate(zip(names, cols, valids)):
+            if i < len(logical) and c.dtype != logical[i]:
+                c = c.astype(logical[i])
+            env[n] = (c, v)
+        return env
+    return make_env
+
+
 def build_worker_fn(plan: PhysicalPlan, xp) -> Callable:
     """Build the per-shard worker function (pure, jittable when xp=jnp)."""
     filter_fn = compile_expr(plan.bound.filter, xp) if plan.bound.filter is not None else None
@@ -302,16 +332,13 @@ def build_worker_fn(plan: PhysicalPlan, xp) -> Callable:
     mode = plan.group_mode
     # $N parameters ride as trailing 0-d "columns": the jitted kernel
     # treats them as traced inputs, so one compile serves every value
-    names = plan.scan_columns + param_env_names(plan.bound.param_specs)
+    make_env = scan_env_fn(plan)
     partial_ops = plan.partial_ops
 
     def eval_mask(env, row_mask):
         if filter_fn is None:
             return row_mask
         return row_mask & predicate_mask(xp, filter_fn, env, row_mask)
-
-    def make_env(cols, valids):
-        return {n: (c, v) for n, c, v in zip(names, cols, valids)}
 
     if mode.kind == "scalar":
         def worker_scalar(cols, valids, row_mask):

@@ -25,6 +25,7 @@ From a BoundSelect this derives everything the executor needs:
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from functools import cached_property
 from typing import Optional
 
 import numpy as np
@@ -93,6 +94,10 @@ class PhysicalPlan:
     # partial states ``lower_aggregates`` proved redundant from the
     # table's statistics and did not emit: (overflow guards, null counts)
     proved_away: tuple = (0, 0)
+    # the width each scan column rides the device at, one entry a
+    # ``scan_columns`` entry (``scan_lanes_of``); empty = every column
+    # at its logical device dtype (a plan not made by ``plan_select``)
+    scan_lanes: tuple = ()
     # executor-populated cache of jitted kernels; lives with the plan so a
     # plan cache hit skips XLA recompilation (the analog of the reference's
     # prepared-statement local plan cache, local_plan_cache.c)
@@ -116,6 +121,31 @@ class PhysicalPlan:
     @property
     def is_router(self) -> bool:
         return len(self.shard_indexes) == 1 and self.bound.table.is_distributed
+
+    @cached_property
+    def lanes(self) -> tuple:
+        """``scan_lanes``, the logical device dtypes where it is empty."""
+        if self.scan_lanes:
+            return self.scan_lanes
+        schema = self.bound.table.schema
+        return tuple(np.dtype(schema.scan_dtype(c, device=True))
+                     for c in self.scan_columns)
+
+    @cached_property
+    def narrow_lanes(self) -> tuple:
+        """Indexes into ``scan_columns`` of the int64 columns that ride
+        the device as int32."""
+        schema = self.bound.table.schema
+        return tuple(i for i, (c, lane) in enumerate(
+            zip(self.scan_columns, self.scan_lanes))
+            if lane != schema.scan_dtype(c, device=True))
+
+    @cached_property
+    def wide_lanes(self) -> int:
+        """Scan columns whose logical device dtype is int64."""
+        schema = self.bound.table.schema
+        return sum(schema.scan_dtype(c, device=True) == np.int64
+                   for c in self.scan_columns)
 
     def resolve_shards(self, param_values: Optional[list]) -> list[int]:
         """Shard indexes for one execution; applies deferred pruning."""
@@ -400,12 +430,15 @@ def _product_reaches(cat: Catalog, bound: BoundSelect, slots: int,
 
 
 def choose_group_mode(cat: Catalog, bound: BoundSelect, direct_limit: int = 0,
-                      planes: Optional[int] = None) -> GroupMode:
+                      planes: Optional[int] = None,
+                      trust_stats: bool = True) -> GroupMode:
     """``direct_limit``: ``citus.direct_gid_limit``; 0 (auto, the
     default) leaves the bound on the direct table's slots to the plan:
     ``DIRECT_MAX_SLOTS``, and past it what ``_product_reaches``.  A
     positive value is the operator's bound, and nothing passes it.
-    ``planes``: ``product_planes`` of the plan's partials."""
+    ``planes``: ``product_planes`` of the plan's partials.  Without
+    ``trust_stats`` (``plan_select``: the scan sees staged rows, or a
+    batch belied them) no key's domain is bounded by the statistics."""
     # distinct and collect-based aggregates need exact value multisets:
     # only the host grouping path carries them (reference:
     # worker_partial_agg cannot combine DISTINCT either and falls back to
@@ -422,8 +455,7 @@ def choose_group_mode(cat: Catalog, bound: BoundSelect, direct_limit: int = 0,
     if any(a.kind in AGG_REGISTRY and AGG_REGISTRY[a.kind].host_grouped
            for a in bound.aggs):
         return GroupMode(kind="hash_host")
-    bounds = (None if sees_staged_rows(bound.table)
-              else column_bounds(cat, bound.table))
+    bounds = column_bounds(cat, bound.table) if trust_stats else None
     domains: list[KeyDomain] = []
     for key in bound.group_keys:
         d = _key_domain(cat, bound.table, key, bounds)
@@ -548,6 +580,36 @@ def lower_aggregates(aggs: list[AggSpec], prove=None, rows: int = 0
     return agg_args, partials, extracts, away
 
 
+# ------------------------------------------------------------ lanes
+
+
+_INT32 = np.dtype(np.int32)
+_INT32_MIN, _INT32_MAX = -(1 << 31), (1 << 31) - 1
+
+
+def scan_lanes_of(facts: Optional[TableFacts], table: TableMeta,
+                  scan_columns: list[str]) -> tuple:
+    """The device form of each scan column: as wide as the table's
+    statistics prove it has to be.  The column's logical device dtype,
+    except int32 where that is int64 and the footers bound every stored
+    value inside int32 (``facts.columns[storage name]``: the physical,
+    scaled values of every row of one ``table.version``).  Whether the
+    column has NULLs does not matter: the validity bit decides what a
+    NULL slot means, not what it holds.  No facts, a column absent from
+    them, a float, a narrower integer: the logical dtype."""
+    schema = table.schema
+    lanes = []
+    for c in scan_columns:
+        lane = np.dtype(schema.scan_dtype(c, device=True))
+        if facts is not None and lane == np.int64 and schema.has(c):
+            b = facts.columns.get(schema.column(c).storage_name)
+            if b is not None and _INT32_MIN <= int(b[0]) \
+                    and int(b[1]) <= _INT32_MAX:
+                lane = _INT32
+        lanes.append(lane)
+    return tuple(lanes)
+
+
 # ------------------------------------------------------------ entry
 
 
@@ -597,16 +659,23 @@ def sees_staged_rows(table: TableMeta) -> bool:
     return txn is not None and table.name in txn.tables
 
 
-def plan_select(cat: Catalog, bound: BoundSelect, *, direct_limit: int = 0) -> PhysicalPlan:
+def plan_select(cat: Catalog, bound: BoundSelect, *, direct_limit: int = 0,
+                trust_stats: bool = True) -> PhysicalPlan:
+    """``trust_stats`` False: a plan that takes nothing from the table's
+    statistics -- every guard, no bounded group domain, every scan
+    column at its logical width -- as for a scan that sees staged rows:
+    what a statement runs on once a batch belied them."""
     intervals = extract_intervals(bound.filter)
     shard_indexes, router_key = prune_shards(bound.table, bound.filter, return_key=True)
+    trust_stats = trust_stats and not sees_staged_rows(bound.table)
     facts = (table_facts(cat, bound.table)
-             if bound.aggs and not sees_staged_rows(bound.table) else None)
+             if bound.aggs and trust_stats else None)
     agg_args, partial_ops, agg_extract, proved_away = lower_aggregates(
         bound.aggs, lambda e: arg_facts(facts, bound.table, e),
         facts.rows if facts else 0)
     group_mode = choose_group_mode(cat, bound, direct_limit,
-                                   product_planes(partial_ops, agg_args))
+                                   product_planes(partial_ops, agg_args),
+                                   trust_stats)
     return PhysicalPlan(
         bound=bound,
         scan_columns=bound.scan_columns,
@@ -617,6 +686,7 @@ def plan_select(cat: Catalog, bound: BoundSelect, *, direct_limit: int = 0) -> P
         partial_ops=partial_ops,
         agg_extract=agg_extract,
         proved_away=proved_away,
+        scan_lanes=scan_lanes_of(facts, bound.table, bound.scan_columns),
         router_key=router_key,
         router_param=_deferred_router_param(bound.table, bound.filter),
         index_eq=_index_eq(bound.table, bound.filter),

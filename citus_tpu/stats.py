@@ -229,6 +229,13 @@ class StatCounters:
         # and carried in 32-bit lanes
         "direct_gid_keys",
         "direct_gid_keys_narrow",
+        # device scans (executor/scan_loop.py drive): their int64 scan
+        # columns, those the table's statistics bound inside int32 and
+        # the placement put at 32 bits, and the scans a batch of which
+        # belied those statistics (re-run at full width; must stay 0)
+        "scan_lanes",
+        "scan_lanes_narrow",
+        "scan_lanes_belied",
         # aggregate queries: partial states their plans compute, and
         # the overflow guards and per-argument NULL counts that
         # planner/physical.py lower_aggregates proved away from the

@@ -317,7 +317,8 @@ def test_streaming_scan_spans_cover_both_threads(tmp_cluster, limit_devices,
     """A streaming scan's trace: decode_batch spans from the decode
     thread hang under the query's execute, each with one stripe_read
     and one pad (the batch is assembled once: no concat); every
-    device_round holds h2d + dispatch; the stall
+    device_round holds h2d + narrow (v fits 32 bits: the lane convert of
+    tests/test_scan_lanes.py) + dispatch; the stall
     the consumer sat in is a wait:prefetch_stall span from the seam."""
     from citus_tpu.observability import trace as T
     limit_devices(n_dev)
@@ -359,8 +360,9 @@ def test_streaming_scan_spans_cover_both_threads(tmp_cluster, limit_devices,
     assert len(rounds) == (8 if n_dev == 1 else 2)
     for r in rounds:
         assert r.parent_id == ex.span_id and r.attrs["resident"] is False
-        assert kids[r.span_id][:3] == (["h2d", "dispatch"] if n_dev == 1 else
-                                       ["stack", "h2d", "dispatch"])[:3]
+        assert kids[r.span_id][:4] == (
+            ["h2d", "narrow", "dispatch"] if n_dev == 1 else
+            ["stack", "h2d", "narrow", "dispatch"])[:4]
     stalls = tr.find_all("wait:prefetch_stall")
     assert stalls and all(s.parent_id == ex.span_id and s.tid == root.tid
                           for s in stalls)

@@ -139,6 +139,10 @@ def test_one_loop_for_every_placement_and_source(
         rounds = tr.find_all("device_round")
         for i, s in enumerate(rounds, 1):
             want = [] if resident else (["stack"] if on_mesh else []) + ["h2d"]
+            if want and plan.narrow_lanes:
+                # v and d ride at 32 bits: the lane convert follows the
+                # put (tests/test_scan_lanes.py)
+                want.append("narrow")
             want.append("dispatch")
             if source == "past_capacity" and i % depth == 0:
                 want.append("wait:device_round")    # the window's sync
@@ -311,7 +315,7 @@ def test_the_driver_takes_any_placement_step_and_state():
     from citus_tpu.config import Settings
     from citus_tpu.executor.pipeline import PipelineStats
 
-    class Plain:
+    class Plain(L.Lanes):       # (what a placement does about narrow lanes)
         round_size = 2
 
         def __init__(self):
@@ -329,7 +333,8 @@ def test_the_driver_takes_any_placement_step_and_state():
         def book(self, members, inputs, nbytes, round_s, dispatch_s):
             self.booked.append((members, nbytes))
 
-    plan = type("P", (), {"runtime_cache": {}, "bound": type("B", (), {
+    plan = type("P", (), {"runtime_cache": {}, "narrow_lanes": (),
+                          "wide_lanes": 0, "bound": type("B", (), {
         "table": type("Tb", (), {"name": "plain"})})})()
     seen = []
     step = L.Step(lambda s, cols, valids, mask: (s + cols[0].sum(),
