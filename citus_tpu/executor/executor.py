@@ -18,6 +18,7 @@ coordinator.
 from __future__ import annotations
 
 import threading
+import dataclasses
 from dataclasses import dataclass, field
 from typing import NamedTuple, Optional
 
@@ -32,6 +33,7 @@ from citus_tpu.executor.batches import (
 )
 from citus_tpu.executor.finalize import finalize_groups, order_and_limit, project_rows
 from citus_tpu.executor.kernel_cache import get_kernel, jit_compile
+from citus_tpu.executor.pipeline import PipelineStats, dispatch_remote_tasks
 from citus_tpu.executor.scan_loop import (
     ScanLanesBelied, Step, _block_ready, _nbytes, _prefetch_depth,
     choose_affine_placement, choose_placement, drive,
@@ -152,7 +154,8 @@ def encode_params(cat: Catalog, bound, values: Optional[list]):
 
 
 def _run_partials_cpu(cat: Catalog, plan: PhysicalPlan, settings: Settings,
-                      params=((), ())):
+                      params=((), ()), record=None):
+    """The numpy arm of ``_run_partials_jax``; books nothing."""
     worker = build_worker_fn(plan, np)
     pcols, pvalids = params
     shard_results = []
@@ -198,7 +201,8 @@ def _empty_partials(plan: PhysicalPlan, xp):
     return tuple(outs)
 
 
-def _iter_padded_batches(cat: Catalog, plan: PhysicalPlan, settings: Settings):
+def _iter_padded_batches(cat: Catalog, plan: PhysicalPlan, settings: Settings,
+                         record: PipelineStats):
     """Lazily yield host ShardBatches of at most 1 << 22 rows, nothing
     materialized up front — the streaming scan path's host half
     (reference analog: ColumnarReadNextRow never materializes a stripe,
@@ -225,7 +229,6 @@ def _iter_padded_batches(cat: Catalog, plan: PhysicalPlan, settings: Settings):
     # span stack while the consumer runs.  The pull that finds the
     # stream exhausted is a last span without a batch (eof).
     batches = shard_batches()
-    pl = plan.runtime_cache.setdefault("pipeline", {})
     try:
         while True:
             with _trace.span("decode_batch") as sp:
@@ -239,12 +242,10 @@ def _iter_padded_batches(cat: Catalog, plan: PhysicalPlan, settings: Settings):
                                rows=int(hb.n_rows), bytes=hb.nbytes)
             if hb is None:
                 return
-            for name, by in (("batch_rows_real", hb.n_rows),
-                             ("batch_rows_padded", hb.padded_rows),
-                             ("decode_bytes_in_place", hb.bytes_in_place),
-                             ("decode_bytes_copied", hb.bytes_copied)):
-                GLOBAL_COUNTERS.bump(name, by)
-                pl[name] = pl.get(name, 0) + by
+            record.tally("batch_rows_real", hb.n_rows, add=True)
+            record.tally("batch_rows_padded", hb.padded_rows, add=True)
+            record.tally("decode_bytes_in_place", hb.bytes_in_place, add=True)
+            record.tally("decode_bytes_copied", hb.bytes_copied, add=True)
             yield hb
     finally:
         batches.close()
@@ -285,13 +286,11 @@ def _agg_step(plan: PhysicalPlan, placement):
 
 
 def _run_partials_jax(cat: Catalog, plan: PhysicalPlan, settings: Settings,
-                      params=((), ())):
-    from citus_tpu.executor.pipeline import PipelineStats
+                      params, record: PipelineStats):
     from citus_tpu.storage.overlay import current_overlay
     from citus_tpu.workload import tenant_key
 
     with _trace.span("scan_setup"):
-        pstats = PipelineStats()
         _trace.set_phase("device")
         # an open transaction's staged writes change what a scan sees
         # without bumping table.version — bypass the HBM cache for
@@ -300,7 +299,7 @@ def _run_partials_jax(cat: Catalog, plan: PhysicalPlan, settings: Settings,
         overlaid = txn is not None and plan.bound.table.name in txn.tables
     placement, key, cached, stream = choose_placement(
         plan, cat.data_dir, not overlaid,
-        lambda: _iter_padded_batches(cat, plan, settings), pstats)
+        lambda: _iter_padded_batches(cat, plan, settings, record), record)
     step, first_state = _agg_step(plan, placement)
     placement.bind(params)
     with _trace.span("init_acc") as sp:
@@ -309,22 +308,17 @@ def _run_partials_jax(cat: Catalog, plan: PhysicalPlan, settings: Settings,
             sp.set(arrays=len(acc_dev), bytes=_nbytes(acc_dev))
     # HBM attribution: resident entries are charged to the tenant whose
     # query pinned them (the shared bucket for non-router scans)
-    acc_dev = drive(plan, settings, placement, step, acc_dev, pstats,
+    acc_dev = drive(plan, settings, placement, step, acc_dev, record,
                     cached=cached, stream=stream, cache_key=key,
                     cache_tenant=tenant_key(plan.router_key))
-    placement.publish(plan)
     if plan.group_mode.kind == "direct":
-        # padded rows the group reduction ran over (counted from this
-        # scan's own placement: the plan's pipeline dict is shared by
-        # every caller of a cached plan)
-        plan.runtime_cache.setdefault("pipeline", {})["group_rows_in"] = \
-            placement.rows_padded
-        GLOBAL_COUNTERS.bump("group_rows_in", placement.rows_padded)
+        # padded rows the group reduction ran over
+        record.tally("group_rows_in", placement.rows_padded)
     t_dev = clock()
     partials = _fetch_acc(acc_dev)
     if cached is None:
-        pstats.device_s += clock() - t_dev
-        pstats.publish(plan)
+        record.device_s += clock() - t_dev
+        record.book_timings()
     return partials
 
 
@@ -341,7 +335,7 @@ def _decode_direct_keys(plan: PhysicalPlan, rows: np.ndarray):
 
 
 def _run_agg(cat: Catalog, plan: PhysicalPlan, settings: Settings,
-             params=((), ())) -> list[tuple]:
+             params, record: PipelineStats) -> list[tuple]:
     backend = settings.executor.task_executor_backend
     mode = plan.group_mode.kind
     penv = _params_env(plan, params)
@@ -352,19 +346,17 @@ def _run_agg(cat: Catalog, plan: PhysicalPlan, settings: Settings,
         # first, scan while the RPCs fly, collect as they complete.
         # Push fallbacks scan locally in a second pass; combine is
         # associative, so the split changes nothing in the result.
-        from citus_tpu.executor.pipeline import dispatch_remote_tasks
         run = _run_partials_cpu if backend == "cpu" else _run_partials_jax
         with _trace.span("remote_dispatch") as sp:
             local, dispatch = dispatch_remote_tasks(cat, plan, settings,
-                                                    params)
+                                                    params, record)
             if sp.recording:
                 sp.set(tasks=len(plan.shard_indexes) - len(local))
         run_plan = plan
         if local != plan.shard_indexes:
-            import dataclasses
             run_plan = dataclasses.replace(plan, shard_indexes=local)
         try:
-            partials = run(cat, run_plan, settings, params)
+            partials = run(cat, run_plan, settings, params, record)
         except BaseException:
             dispatch.abort()  # no RPC thread outlives the attempt
             raise
@@ -373,16 +365,9 @@ def _run_agg(cat: Catalog, plan: PhysicalPlan, settings: Settings,
             if sp.recording:
                 sp.set(tasks=len(remote_partials), fallback=len(fallback))
         if fallback:
-            import dataclasses
-            tt = list(plan.runtime_cache.get("task_times", []))
-            tb = list(plan.runtime_cache.get("task_bytes", []))
             fb_plan = dataclasses.replace(plan, shard_indexes=fallback)
             remote_partials = [*remote_partials,
-                               run(cat, fb_plan, settings, params)]
-            plan.runtime_cache["task_times"] = (
-                tt + list(plan.runtime_cache.get("task_times", [])))
-            plan.runtime_cache["task_bytes"] = (
-                tb + list(plan.runtime_cache.get("task_bytes", [])))
+                               run(cat, fb_plan, settings, params, record)]
         if remote_partials:
             partials = _combine(plan, [partials, *remote_partials])
         with _trace.span("finalize_groups") as sp:
@@ -404,35 +389,28 @@ def _run_agg(cat: Catalog, plan: PhysicalPlan, settings: Settings,
                                           params_env=penv)
                 # the slots the group reduction was sized and chosen by,
                 # and the groups that came out of them (after HAVING)
-                # ... the padded rows the reduction ran over beside the
-                # rows the WHERE kept, and the bytes of state fetched
-                pl = plan.runtime_cache.setdefault("pipeline", {})
-                pl["direct_groups"] = plan.group_mode.n_groups
-                pl["direct_groups_out"] = len(out)
-                kept = pl["group_rows_kept"] = int(np.asarray(rows).sum())
-                fetched = pl["direct_bytes_fetched"] = _nbytes(partials)
-                GLOBAL_COUNTERS.bump("direct_groups", plan.group_mode.n_groups)
-                GLOBAL_COUNTERS.bump("direct_groups_out", len(out))
+                record.tally("direct_groups", plan.group_mode.n_groups)
+                record.tally("direct_groups_out", len(out))
+                # the rows the WHERE kept (beside group_rows_in, the
+                # padded rows the reduction ran over), the state fetched
+                record.tally("group_rows_kept", int(np.asarray(rows).sum()))
+                record.tally("direct_bytes_fetched", _nbytes(partials))
                 # how the kernel made the group id: keys whose code took
                 # no 64-bit division and rode 32-bit lanes, and the keys
                 # that divide at all (a date_trunc unit)
                 lanes = direct_id_lanes(plan)
-                pl["direct_gid_keys"] = len(lanes)
-                pl["direct_gid_keys_narrow"] = sum(l.narrow for l in lanes)
-                pl["direct_gid_divisions"] = sum(
+                record.tally("direct_gid_keys", len(lanes))
+                record.tally("direct_gid_keys_narrow",
+                             sum(l.narrow for l in lanes))
+                record.figures["direct_gid_divisions"] = sum(
                     l.divide != "none" for l in lanes)
-                GLOBAL_COUNTERS.bump("direct_gid_keys", len(lanes))
-                GLOBAL_COUNTERS.bump("direct_gid_keys_narrow",
-                                     pl["direct_gid_keys_narrow"])
-                GLOBAL_COUNTERS.bump("direct_bytes_fetched", fetched)
-                GLOBAL_COUNTERS.bump("group_rows_kept", kept)
             if sp.recording:
                 sp.set(groups=len(out))
             return out
     # unbounded-cardinality GROUP BY: per-shard hash tables merge on the
     # host, so the whole strategy renders as one host_agg span
     with _trace.span("host_agg", shards=len(plan.shard_indexes)):
-        return _run_agg_hash_host(cat, plan, settings, params)
+        return _run_agg_hash_host(cat, plan, settings, params, record)
 
 
 def _params_env(plan, params) -> dict:
@@ -672,7 +650,8 @@ def _disjoint_on(plan: PhysicalPlan) -> Optional[str]:
 
 
 def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
-                     params, acc, penv, push_remote: bool) -> _HashTables:
+                     params, acc, penv, push_remote: bool,
+                     record: PipelineStats) -> _HashTables:
     """Device half of a hash_host plan: stream every local batch into
     donated HBM-resident hash tables (kernel slot ``jit_hash_fused``),
     draining spills into ``acc`` exactly.  ONE table on a host of one
@@ -690,35 +669,31 @@ def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     ``_fetch_hash_survivors``: what HAVING leaves)."""
     import jax
     import jax.numpy as jnp
-    from citus_tpu.executor.pipeline import PipelineStats
     from citus_tpu.ops.hash_agg import (
         build_fused_hash_worker, build_fused_entry_merge, empty_hash_state,
         merge_hash_tables_into,
     )
 
-    pstats = PipelineStats()
     _trace.set_phase("device")
     key_dtypes = _hash_key_dtypes(plan, penv)
     dispatch = None
     run_plan = plan
     if push_remote:
-        from citus_tpu.executor.pipeline import dispatch_remote_tasks
-        local, dispatch = dispatch_remote_tasks(cat, plan, settings, params)
+        local, dispatch = dispatch_remote_tasks(cat, plan, settings, params,
+                                                record)
         if local != plan.shard_indexes:
-            import dataclasses
             run_plan = dataclasses.replace(plan, shard_indexes=local)
 
     def open_stream(shard_indexes):
-        import dataclasses
         return _iter_padded_batches(
             cat, dataclasses.replace(plan, shard_indexes=shard_indexes),
-            settings)
+            settings, record)
 
     try:
         # both passes (local, then push fallbacks) book into the one
         # placement the first pass chose
         placement, stream = choose_affine_placement(run_plan, open_stream,
-                                                    pstats)
+                                                    record)
         mesh = placement.mesh
         n_dev = 0 if mesh is None else placement.round_size
 
@@ -755,7 +730,7 @@ def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
             # never cached (no key): the window bounds the un-synced H2D
             # bytes from the first round on, so peak device footprint stays
             # O(slots) + depth x batch bytes
-            return drive(plan, settings, placement, step, state, pstats,
+            return drive(plan, settings, placement, step, state, record,
                          stream=stream, on_sync=drain)
 
         state = scan(state, stream)
@@ -801,32 +776,26 @@ def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
                 GLOBAL_COUNTERS.bump("hash_partials_pushed")
     t_dev = clock()
     _block_ready(state)
-    pstats.device_s += clock() - t_dev
-    pstats.publish(plan)
-    placement.publish(plan)
+    record.device_s += clock() - t_dev
+    record.book_timings()
     tables = n_dev or 1
+    record.tally("hash_tables", tables)
+    record.tally("hash_slots", tables * S)
+    record.figures["hash_slots_from"] = slots_from
+    record.figures["hash_disjoint_on"] = disjoint
+    # the drain bumped its counters window by window
+    record.figures["hash_spilled_rows"] = drain.rows
+    record.figures["hash_table_updates"] = drain.updates
     # table rows the scan took, and the fullest device's share of them
     per_device = placement.device_rows if mesh is not None \
-        else [sum(n for _, n, _ in placement.task_times)]
-    pl = plan.runtime_cache.setdefault("pipeline", {})
-    pl["hash_tables"] = tables
-    pl["hash_slots"] = tables * S
-    pl["hash_slots_from"] = slots_from
-    pl["hash_disjoint_on"] = disjoint
-    GLOBAL_COUNTERS.bump("hash_tables", tables)
-    GLOBAL_COUNTERS.bump("hash_slots", tables * S)
-    pl["hash_spilled_rows"] = drain.rows
-    pl["hash_table_updates"] = drain.updates
-    pl["hash_rows_in"] = sum(per_device)
-    pl["hash_rows_in_max_device"] = max(per_device)
-    GLOBAL_COUNTERS.bump("hash_rows_in", sum(per_device))
-    GLOBAL_COUNTERS.bump("hash_rows_in_max_device", max(per_device))
-    pl["group_rows_in"] = placement.rows_padded
-    GLOBAL_COUNTERS.bump("group_rows_in", placement.rows_padded)
+        else [sum(n for _, n, _ in record.task_times)]
+    record.tally("hash_rows_in", sum(per_device))
+    record.tally("hash_rows_in_max_device", max(per_device))
+    record.tally("group_rows_in", placement.rows_padded)
     return _HashTables(state, mesh, disjoint)
 
 
-def _fetch_hash_table(plan: PhysicalPlan, tables: _HashTables):
+def _fetch_hash_table(tables: _HashTables, record: PipelineStats):
     """The whole-table ending of a hash scan: every slot of every table
     comes home, as (key_tables, partials, rows) host arrays, table
     after table."""
@@ -840,13 +809,12 @@ def _fetch_hash_table(plan: PhysicalPlan, tables: _HashTables):
     h_keys = [(kv, kf) for kv, kf in fetched[0]]
     h_partials = tuple(fetched[1])
     h_rows = fetched[2]
-    pl = plan.runtime_cache.setdefault("pipeline", {})
+    pl = record.figures
     pl["hash_occupancy_pct"] = round(
         100.0 * int((h_rows > 0).sum()) / h_rows.shape[0], 1)
     # every kept row is in an entry's count or among the spilled
-    kept = int(h_rows.sum()) + pl.get("hash_spilled_rows", 0)
-    pl["group_rows_kept"] = kept
-    GLOBAL_COUNTERS.bump("group_rows_kept", kept)
+    record.tally("group_rows_kept",
+                 int(h_rows.sum()) + pl.get("hash_spilled_rows", 0))
     return h_keys, h_partials, h_rows
 
 
@@ -885,7 +853,7 @@ def _device_having(plan: PhysicalPlan):
 
 
 def _fetch_hash_survivors(plan: PhysicalPlan, tables: _HashTables, acc,
-                          params, having):
+                          params, having, record: PipelineStats):
     """The filtered ending of the coordinator's hash scan: HAVING is
     decided on each table on its chip (kernel slot ``jit_hash_having``)
     and what can still matter comes home — the blocks that hold a
@@ -959,7 +927,7 @@ def _fetch_hash_survivors(plan: PhysicalPlan, tables: _HashTables, acc,
             sp.set(slots=S, host_keys=n_host, tables=tables.tables,
                    blocks=sum(b.size for b in blocks),
                    host_keys_in_table=int(found.sum()))
-    pl = plan.runtime_cache.setdefault("pipeline", {})
+    pl = record.figures
     occupied = int(occupied.sum())
     pl["hash_occupancy_pct"] = round(100.0 * occupied / (S * len(marks)), 1)
     n_blocks = _pow2_at_least(max(b.size for b in blocks), least_blocks)
@@ -990,7 +958,8 @@ def _fetch_hash_survivors(plan: PhysicalPlan, tables: _HashTables, acc,
 
 
 def _run_hash_partial_state(cat: Catalog, plan: PhysicalPlan,
-                            settings: Settings, params=((), ())):
+                            settings: Settings, params,
+                            record: PipelineStats):
     """Worker half of a pushed hash task: -> (table | None, spilled |
     None) where ``table`` is the merged device hash table's host arrays
     and ``spilled`` re-renders the host accumulator's exact groups as
@@ -1004,8 +973,9 @@ def _run_hash_partial_state(cat: Catalog, plan: PhysicalPlan,
     if settings.executor.task_executor_backend != "cpu":
         # a worker ships its whole table: HAVING on a worker is sound
         # only where the group key holds the distribution column
-        table = _fetch_hash_table(plan, _run_hash_device(
-            cat, plan, settings, params, acc, penv, push_remote=False))
+        table = _fetch_hash_table(_run_hash_device(
+            cat, plan, settings, params, acc, penv, push_remote=False,
+            record=record), record)
     else:
         pcols, pvalids = params
         worker = build_worker_fn(plan, np)
@@ -1037,8 +1007,8 @@ def _run_hash_partial_state(cat: Catalog, plan: PhysicalPlan,
 
 
 def _finish_hash_agg(cat: Catalog, plan: PhysicalPlan, acc, table,
-                     penv: dict, entry_mask=None, groups=None,
-                     pieces: int = 1) -> list[tuple]:
+                     penv: dict, record: PipelineStats, entry_mask=None,
+                     groups=None, pieces: int = 1) -> list[tuple]:
     """The exact tail of a device hash aggregation, on the caller's
     thread: the fetched ``table`` (key tables, partials, rows; of them
     the entries ``entry_mask`` marks, all where it is None) merges into
@@ -1054,7 +1024,6 @@ def _finish_hash_agg(cat: Catalog, plan: PhysicalPlan, acc, table,
     )
     fetched = hash_state_bytes(table)
     entries = int(table[2].shape[0])
-    pl = plan.runtime_cache.setdefault("pipeline", {})
     with _trace.span("hash_merge") as sp:
         if pieces > 1:
             for i in range(pieces):
@@ -1070,13 +1039,10 @@ def _finish_hash_agg(cat: Catalog, plan: PhysicalPlan, acc, table,
         key_arrays, parts = acc.finalize(
             [k.type for k in plan.bound.group_keys],
             scalar=not plan.bound.group_keys)
-        groups = acc.n_groups if groups is None else groups
-        GLOBAL_COUNTERS.bump("hash_groups_out", groups)
-        GLOBAL_COUNTERS.bump("hash_table_bytes_fetched", fetched)
-        GLOBAL_COUNTERS.bump("hash_entries_fetched", entries)
-        pl["hash_groups_out"] = groups
-        pl["hash_table_bytes_fetched"] = fetched
-        pl["hash_entries_fetched"] = entries
+        record.tally("hash_groups_out",
+                     acc.n_groups if groups is None else groups)
+        record.tally("hash_table_bytes_fetched", fetched)
+        record.tally("hash_entries_fetched", entries)
         out = [] if parts is None else finalize_groups(
             plan, cat, key_arrays, parts, params_env=penv)
         if sp.recording:
@@ -1085,7 +1051,7 @@ def _finish_hash_agg(cat: Catalog, plan: PhysicalPlan, acc, table,
 
 
 def _run_agg_hash_host(cat: Catalog, plan: PhysicalPlan, settings: Settings,
-                       params=((), ())) -> list[tuple]:
+                       params, record: PipelineStats) -> list[tuple]:
     """Unbounded GROUP BY cardinality.
 
     tpu backend: streaming fused device hash aggregation
@@ -1105,7 +1071,7 @@ def _run_agg_hash_host(cat: Catalog, plan: PhysicalPlan, settings: Settings,
 
     if backend != "cpu" and not _hash_has_exact(plan):
         tables = _run_hash_device(cat, plan, settings, params, acc, penv,
-                                  push_remote=True)
+                                  push_remote=True, record=record)
         # the ending adapts to what the plan proves.  One table, or one
         # a device that share no group (the keys hold the distribution
         # column and each device took its own shards): every part of a
@@ -1114,20 +1080,18 @@ def _run_agg_hash_host(cat: Catalog, plan: PhysicalPlan, settings: Settings,
         # home.  Tables that may hold a group several times come home
         # whole and merge exactly, HAVING after the merge.
         apart = tables.tables == 1 or tables.disjoint is not None
-        merged = 0 if apart else tables.tables
-        plan.runtime_cache.setdefault("pipeline", {})[
-            "hash_tables_merged"] = merged
-        GLOBAL_COUNTERS.bump("hash_tables_merged", merged)
+        merged = record.tally("hash_tables_merged",
+                              0 if apart else tables.tables)
         having = apart and _device_having(plan)
         home = having and _fetch_hash_survivors(plan, tables, acc, params,
-                                                having)
+                                                having, record)
         if home:
             table, entry_mask, groups = home
-            return _finish_hash_agg(cat, plan, acc, table, penv,
+            return _finish_hash_agg(cat, plan, acc, table, penv, record,
                                     entry_mask, groups)
         return _finish_hash_agg(cat, plan, acc,
-                                _fetch_hash_table(plan, tables), penv,
-                                pieces=max(1, merged))
+                                _fetch_hash_table(tables, record), penv,
+                                record, pieces=max(1, merged))
 
     # exact value-set partials (or the cpu oracle backend) stay host-only
     # and are not elementwise-combinable — remote-only shards pull
@@ -1156,7 +1120,7 @@ def _run_agg_hash_host(cat: Catalog, plan: PhysicalPlan, settings: Settings,
 
 
 def _run_projection(cat: Catalog, plan: PhysicalPlan, settings: Settings,
-                    params=((), ())) -> list[tuple]:
+                    params, record: PipelineStats) -> list[tuple]:
     backend = settings.executor.task_executor_backend
     use_jax = backend != "cpu"
     pcols, pvalids = params
@@ -1207,11 +1171,10 @@ def _run_projection(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     # and return already-compacted rows; local shards stream HERE while
     # the remote RPCs are in flight (the adaptive executor's overlap of
     # worker waits with the coordinator's own placements)
-    from citus_tpu.executor.pipeline import dispatch_remote_tasks
-    local, dispatch = dispatch_remote_tasks(cat, plan, settings, params)
+    local, dispatch = dispatch_remote_tasks(cat, plan, settings, params,
+                                            record)
     run_plan = plan
     if local != plan.shard_indexes:
-        import dataclasses
         run_plan = dataclasses.replace(plan, shard_indexes=local)
     local_batches: list = []
     try:
@@ -1235,7 +1198,6 @@ def _run_projection(cat: Catalog, plan: PhysicalPlan, settings: Settings,
         env_batches.append((env, np.ones(n, bool)))
     env_batches.extend(local_batches)
     if fallback:
-        import dataclasses
         _scan_shards(dataclasses.replace(plan, shard_indexes=fallback),
                      env_batches)
     return project_rows(plan, cat, env_batches)
@@ -1270,7 +1232,9 @@ def _bind_time_prune(plan: PhysicalPlan, params) -> PhysicalPlan:
     fast-path are re-derived — a cached generic plan prunes exactly like
     a freshly-planned literal query (reference: deferred pruning on
     Job->deferredPruning).  The shared runtime_cache dict rides along,
-    so jitted kernels are reused across parameter values."""
+    so what was compiled from the plan (kernels, the numpy arm's
+    closures, the fingerprint) is reused across parameter values; what
+    an execution counts goes to its own ``PipelineStats``."""
     bound = plan.bound
     pcols, pvalids = params
     phys = [pcols[i].item() if bool(pvalids[i]) else None
@@ -1279,7 +1243,6 @@ def _bind_time_prune(plan: PhysicalPlan, params) -> PhysicalPlan:
     shard_indexes, router_key = prune_shards(bound.table, sub, return_key=True)
     if plan.router_param is not None and phys[plan.router_param] is None:
         shard_indexes = []  # dist = NULL matches nothing
-    import dataclasses
     return dataclasses.replace(
         plan, shard_indexes=shard_indexes, router_key=router_key,
         intervals=extract_intervals(sub),
@@ -1365,6 +1328,12 @@ def _execute_select_traced(cat: Catalog, bound: BoundSelect,
                 fresh = _bind_time_prune(fresh, params)
             return fresh
 
+        def _run(run):
+            # the record is of the attempt that answers: one that is
+            # run again (a flip, belied lanes) leaves nothing in it
+            record = PipelineStats()
+            return run(cat, run_plan, settings, params, record), record
+
         def _attempt():
             nonlocal run_plan
             if run_plan.table_shard_count not in (-1,
@@ -1375,30 +1344,32 @@ def _execute_select_traced(cat: Catalog, bound: BoundSelect,
                 # shard list — re-plan before (re)trying
                 run_plan = _replan()
             if not bound.has_aggs:
-                return _run_projection(cat, run_plan, settings, params)
+                return _run(_run_projection)
             try:
-                return _run_agg(cat, run_plan, settings, params)
+                return _run(_run_agg)
             except ScanLanesBelied:
                 # a batch held a value the table's statistics rule out
                 # (nothing was cached, no state fetched): what else the
                 # plan took from them stands no better, so the statement
                 # runs once more on a plan that takes nothing from them
                 run_plan = _replan(trust_stats=False)
-                return _run_agg(cat, run_plan, settings, params)
-        rows = snapshot_read(cat.data_dir, bound.table, _attempt,
-                             timeout=settings.executor.lock_timeout_s)
+                return _run(_run_agg)
+        rows, record = snapshot_read(
+            cat.data_dir, bound.table, _attempt,
+            timeout=settings.executor.lock_timeout_s)
         plan = run_plan
-    return _finish_select(bound, plan, rows, t0, exec_span)
+    return _finish_select(bound, plan, rows, t0, exec_span, record)
 
 
 def _finish_select(bound: BoundSelect, plan: PhysicalPlan, rows: list[tuple],
-                   t0: float, exec_span, megabatch: Optional[dict] = None
-                   ) -> Result:
+                   t0: float, exec_span, record: PipelineStats,
+                   megabatch: Optional[dict] = None) -> Result:
     """Shared tail of the serial and megabatched paths: ORDER/LIMIT +
     hidden-output trim, result-shape counters, span attrs and the
-    explain dict.  Runs on the issuing caller's own thread either way,
-    so per-query spans and stat attribution are identical under
-    coalescing (``megabatch`` adds the occupancy attrs)."""
+    explain dict, read from the execution's ``record``.  Runs on the
+    issuing caller's own thread either way, so per-query spans and stat
+    attribution are identical under coalescing (``megabatch`` adds the
+    occupancy attrs)."""
     _trace.set_phase("finalize")
     with _trace.span("finalize"):
         rows = order_and_limit(plan, rows)
@@ -1412,30 +1383,22 @@ def _finish_select(bound: BoundSelect, plan: PhysicalPlan, rows: list[tuple],
             strategy=plan.group_mode.kind if bound.has_aggs else "projection",
             shards=len(plan.shard_indexes), router=bool(plan.is_router),
             rows=len(rows))
-        pipe = plan.runtime_cache.get("pipeline") or {}
-        if pipe:
+        if record.figures:
             # the full pipeline-overlap dict rides the span so EXPLAIN
             # ANALYZE and the Chrome export render from one source
-            exec_span.attrs["pipeline"] = dict(pipe)
+            exec_span.attrs["pipeline"] = dict(record.figures)
         if megabatch:
             exec_span.attrs["megabatch"] = dict(megabatch)
     visible = list(bound.output_names)
     if bound.hidden_outputs:
         visible = visible[:len(visible) - bound.hidden_outputs]
-    # attribution booking consumes the per-execution task logs exactly
-    # once (pop, not get): a later execution of this cached plan that
-    # serves entirely from HBM re-books nothing stale
-    task_times = plan.runtime_cache.pop("task_times", [])
-    task_bytes = plan.runtime_cache.pop("task_bytes", [])
-    remote_tasks = plan.runtime_cache.pop("remote_tasks", [])
-    mesh_times = plan.runtime_cache.pop("mesh_task_times", [])
     from citus_tpu.observability.load_attribution import GLOBAL_ATTRIBUTION
     from citus_tpu.workload import tenant_key
     with _trace.span("book_stats"):
         GLOBAL_ATTRIBUTION.book_query(
             bound.table, tenant_key(plan.router_key),
-            task_times + mesh_times, task_bytes,
-            len(rows), remote_tasks,
+            record.task_times + record.mesh_task_times, record.task_bytes,
+            len(rows), record.remote_tasks,
             head_si=plan.shard_indexes[0] if plan.shard_indexes else None)
     explain = {
         "strategy": plan.group_mode.kind if bound.has_aggs else "projection",
@@ -1443,9 +1406,9 @@ def _finish_select(bound: BoundSelect, plan: PhysicalPlan, rows: list[tuple],
         "router": plan.is_router,
         "intervals": [c.column for c in plan.intervals],
         "elapsed_s": elapsed,
-        "tasks": task_times,
-        "remote_tasks": remote_tasks,
-        "pipeline": plan.runtime_cache.get("pipeline", {}),
+        "tasks": record.task_times,
+        "remote_tasks": record.remote_tasks,
+        "pipeline": record.figures,
         "router_key": plan.router_key,
     }
     if megabatch:

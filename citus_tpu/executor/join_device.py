@@ -193,7 +193,9 @@ class _DeviceJoin:
             sorted(self.nodes.items()), scans, bj.group_keys, bj.agg_args,
             bj.partial_ops, len(specs))).encode()).hexdigest()
         # every relation's columns ride at their logical widths: the
-        # join's lanes are not narrowed from the statistics (yet)
+        # join's lanes are not narrowed from the statistics (yet).
+        # This view, the aggregate's and the scans' share one
+        # runtime_cache, which holds their kernels and nothing else
         self.holder = SimpleNamespace(
             bound=SimpleNamespace(table=self.tables_of[root]),
             narrow_lanes=(), wide_lanes=0,
@@ -233,7 +235,8 @@ class _DeviceJoin:
         batch yields one of padding alone, so that its table is made."""
         from citus_tpu.executor.executor import _iter_padded_batches
         plan = self._scan(alias, shard_indexes)
-        batches = _iter_padded_batches(self.cat, plan, self.settings)
+        batches = _iter_padded_batches(self.cat, plan, self.settings,
+                                       self.record)
         held, first = next(batches, None), True
         if held is None:
             if alias != self.tree.root:
@@ -453,9 +456,9 @@ class _DeviceJoin:
             merge_hash_tables_into,
         )
         bj, tree = self.bj, self.tree
-        pstats = PipelineStats()
+        record = self.record = PipelineStats()
         _trace.set_phase("device")
-        self.placement = _Placement()
+        self.placement = _Placement(record)
         self.placement.bind(self.params)
 
         # the aggregate's table: key dtypes by evaluating the keys on a
@@ -488,14 +491,13 @@ class _DeviceJoin:
         token = (jnp.zeros((), np.int32),)
         drive(self.holder, self.settings, self.placement,
               Step(self._step, "jit_join_probe", "join_dispatches"), token,
-              pstats, stream=self._stream(), on_sync=self._sync)
-        self.placement.publish(self.holder)
-        pstats.publish(self.holder)
+              record, stream=self._stream(), on_sync=self._sync)
+        record.book_timings()
 
         # one fetch: the table comes home whole (at most AGG_SLOTS[1]
         # entries; what it could not hold is in ``acc`` already)
         h_keys, h_parts, h_rows = _fetch_hash_table(
-            self.agg, _HashTables(self.agg_state))
+            _HashTables(self.agg_state), record)
         fetched = hash_state_bytes((h_keys, h_parts, h_rows))
         occupied = h_rows > 0
         n = int(occupied.sum())
@@ -548,5 +550,4 @@ class _DeviceJoin:
         GLOBAL_COUNTERS.bump("hash_groups_out", join["groups"])
         return finish_join(
             bj, view, rows, "colocated", max(1, self.n_shards), t0,
-            {"join": join,
-             "pipeline": self.holder.runtime_cache.get("pipeline", {})})
+            {"join": join, "pipeline": record.figures})

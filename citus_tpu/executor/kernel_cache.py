@@ -23,6 +23,9 @@ program — this module makes that sharing explicit and process-wide:
   in front of a global LRU (``citus.kernel_cache_size`` entries), so a
   plan-cache hit costs a dict lookup and a plan-cache miss that lands on
   a known fingerprint skips XLA entirely (kernel_cache_hits counter).
+  The mirror is shared by every caller of a cached plan and holds what
+  is compiled from it alone: kernels by slot, ``_fingerprint``, the
+  numpy arm's ``np_filter`` / ``np_final_fns`` closures.
 - ``jit_compile(fn)`` — the ONLY ``jax.jit`` call site in the package
   (CI-enforced, tests/test_ci_invariants.py); asks the no-silent-CPU
   device guard, then wraps the jitted callable to attribute

@@ -68,7 +68,7 @@ class _Waiter:
     plus the scatter slots the leader fills."""
 
     __slots__ = ("cat", "bound", "settings", "plan", "params", "done",
-                 "payload", "serial", "occupancy", "t_enq")
+                 "payload", "record", "serial", "occupancy", "t_enq")
 
     def __init__(self, cat, bound, settings, plan, params):
         self.cat = cat
@@ -79,6 +79,8 @@ class _Waiter:
         self.done = threading.Event()
         # ("agg", [per-batch partial tuples]) or ("proj", env_batches)
         self.payload = None
+        # the group's ONE device run as this rider's execution record
+        self.record = None
         self.serial = False
         self.occupancy = 0
         self.t_enq = clock()
@@ -219,6 +221,7 @@ class MegabatchDispatcher:
                 raise
 
     def _run_group(self, group: list[_Waiter]) -> None:
+        from citus_tpu.executor.pipeline import PipelineStats
         from citus_tpu.transaction.snapshot import snapshot_read
         from citus_tpu.workload import GLOBAL_SCHEDULER, tenant_key
         w0 = group[0]
@@ -244,14 +247,17 @@ class MegabatchDispatcher:
                 [tenant_key(x.plan.router_key) for x in group[1:]])
 
             def _attempt():
-                if bound.has_aggs:
-                    if plan.group_mode.kind == "hash_host":
-                        return _batched_hash_agg(cat, scan_plan, settings,
-                                                 group)
-                    return _batched_agg(cat, scan_plan, settings, group)
-                return _batched_projection(cat, scan_plan, settings, group)
-            payloads = snapshot_read(cat.data_dir, bound.table, _attempt,
-                                     timeout=settings.executor.lock_timeout_s)
+                record = PipelineStats()    # of the attempt that answers
+                if not bound.has_aggs:
+                    # a host loop: no device scan, nothing to record
+                    return _batched_projection(cat, scan_plan, settings,
+                                               group), record
+                run = _batched_hash_agg \
+                    if plan.group_mode.kind == "hash_host" else _batched_agg
+                return run(cat, scan_plan, settings, group, record), record
+            payloads, record = snapshot_read(
+                cat.data_dir, bound.table, _attempt,
+                timeout=settings.executor.lock_timeout_s)
         c = _counters()
         c.bump("megabatch_batches")
         c.bump("megabatch_queries", occ)
@@ -262,6 +268,9 @@ class MegabatchDispatcher:
         for w, payload in zip(group, payloads):
             w.occupancy = occ
             w.payload = payload
+            # the device run's task log goes to the first rider alone:
+            # the load ledger books the group's device work once
+            w.record = record if w is w0 else record.rider()
 
     # ------------------------------------------------------- stats
 
@@ -315,7 +324,7 @@ def _q_pad(q: int) -> int:
     return 1 << max(0, q - 1).bit_length()
 
 
-def _batched_agg(cat, plan, settings, group: list[_Waiter]) -> list:
+def _batched_agg(cat, plan, settings, group: list[_Waiter], record) -> list:
     """Scan the group's shards ONCE, run the vmap-lifted worker over
     the query axis, and slice per-query partial states back out.
     Returns one ("agg", [per-batch partial tuples]) payload per
@@ -328,7 +337,6 @@ def _batched_agg(cat, plan, settings, group: list[_Waiter]) -> list:
         _empty_partials, _fetch_acc, _iter_padded_batches,
     )
     from citus_tpu.executor.kernel_cache import get_kernel, jit_compile
-    from citus_tpu.executor.pipeline import PipelineStats
     from citus_tpu.executor.scan_loop import OneDevice, Step, drive
     from citus_tpu.ops.scan_agg import build_fused_worker_fn
 
@@ -354,7 +362,7 @@ def _batched_agg(cat, plan, settings, group: list[_Waiter]) -> list:
 
     _trace.set_phase("device")
     # the [qp] parameter stacks ride along as one device's parameters do
-    placement = OneDevice()
+    placement = OneDevice(record)
     placement.bind(_stacked_params(group, qp))
     # interval-free scan: the device-cache entry is the family-wide
     # full-shard batch set, shared by every literal variant and
@@ -365,15 +373,16 @@ def _batched_agg(cat, plan, settings, group: list[_Waiter]) -> list:
     # slots replay rider 0's params; their results are sliced off)
     acc = tuple(jax.device_put(np.stack([p] * qp))
                 for p in _empty_partials(plan, np))
-    acc = drive(plan, settings, placement, step, acc, PipelineStats(),
+    acc = drive(plan, settings, placement, step, acc, record,
                 cached=cached, cache_key=key,
                 stream=None if cached is not None
-                else _iter_padded_batches(cat, plan, settings))
+                else _iter_padded_batches(cat, plan, settings, record))
     host = _fetch_acc(acc)
     return [("agg", [tuple(o[qi] for o in host)]) for qi in range(q)]
 
 
-def _batched_hash_agg(cat, plan, settings, group: list[_Waiter]) -> list:
+def _batched_hash_agg(cat, plan, settings, group: list[_Waiter],
+                      record) -> list:
     """Shared scan + ONE vmap-lifted fused hash dispatch per batch over
     [qp]-stacked donated hash tables (kernel slot
     ``batched:jit_hash_fused``).  Spill masks drain per prefetch window
@@ -389,7 +398,6 @@ def _batched_hash_agg(cat, plan, settings, group: list[_Waiter]) -> list:
     )
     from citus_tpu.executor.host_agg import HostGroupAccumulator
     from citus_tpu.executor.kernel_cache import get_kernel, jit_compile
-    from citus_tpu.executor.pipeline import PipelineStats
     from citus_tpu.executor.scan_loop import OneDevice, Step, drive
     from citus_tpu.ops.hash_agg import build_fused_hash_worker, \
         empty_hash_state
@@ -419,12 +427,12 @@ def _batched_hash_agg(cat, plan, settings, group: list[_Waiter]) -> list:
                                  plan.partial_ops) for _ in group]
 
     _trace.set_phase("device")
-    placement = OneDevice()
+    placement = OneDevice(record)
     placement.bind(_stacked_params(group, qp))
     state = jax.device_put(jax.tree_util.tree_map(
         lambda a: np.stack([a] * qp), empty_hash_state(plan, S, key_dtypes)))
-    state = drive(plan, settings, placement, step, state, PipelineStats(),
-                  stream=_iter_padded_batches(cat, plan, settings),
+    state = drive(plan, settings, placement, step, state, record,
+                  stream=_iter_padded_batches(cat, plan, settings, record),
                   on_sync=_SpillDrain(plan, accs))
     host = jax.device_get(state)
     return [("hash_agg",
@@ -548,13 +556,14 @@ def _finalize_agg(cat, plan, batch_partials, params) -> list[tuple]:
     return finalize_groups(plan, cat, keys, sel, params_env=penv)
 
 
-def _finalize_hash_agg(cat, plan, data, params) -> list[tuple]:
+def _finalize_hash_agg(cat, plan, data, params, record) -> list[tuple]:
     """Per-query exact merge + finalize of a hash_host rider's table
     slice — the exact tail of the serial _run_agg_hash_host, run on the
     caller's own thread."""
     from citus_tpu.executor.executor import _finish_hash_agg, _params_env
     table, acc = data
-    return _finish_hash_agg(cat, plan, acc, table, _params_env(plan, params))
+    return _finish_hash_agg(cat, plan, acc, table, _params_env(plan, params),
+                            record)
 
 
 def maybe_megabatch(cat, bound, settings, plan, params, t0, exec_span):
@@ -591,7 +600,7 @@ def maybe_megabatch(cat, bound, settings, plan, params, t0, exec_span):
     if kind == "agg":
         rows = _finalize_agg(cat, plan, data, params)
     elif kind == "hash_agg":
-        rows = _finalize_hash_agg(cat, plan, data, params)
+        rows = _finalize_hash_agg(cat, plan, data, params, w.record)
     else:
         rows = project_rows(plan, cat, data)
     wait_ms = (clock() - w.t_enq) * 1000.0
@@ -603,4 +612,5 @@ def maybe_megabatch(cat, bound, settings, plan, params, t0, exec_span):
         tr, parent = ctx
         tr.add_closed("megabatch", parent.span_id, w.t_enq, clock(),
                       dict(info))
-    return _finish_select(bound, plan, rows, t0, exec_span, megabatch=info)
+    return _finish_select(bound, plan, rows, t0, exec_span, w.record,
+                          megabatch=info)

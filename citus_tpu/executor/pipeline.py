@@ -47,9 +47,12 @@ from citus_tpu.stats import begin_wait, end_wait
 
 
 class PipelineStats:
-    """Per-query pipeline accounting.  The decode thread owns
-    host_decode_s/device_stalls, the consumer owns the rest — disjoint
-    writers, read only after the pipeline is joined."""
+    """One execution's record: everything ONE run of a statement counted
+    and logged, made where the run starts and handed down by argument
+    (the cached plan is shared by every caller of its family and keeps
+    compiled kernels alone).  The decode thread owns host_decode_s /
+    device_stalls and the four per-batch figures, the consumer owns the
+    rest -- disjoint writers, read only after the pipeline is joined."""
 
     def __init__(self) -> None:
         self.host_decode_s = 0.0   # time inside the host decode iterator
@@ -59,26 +62,51 @@ class PipelineStats:
         self.device_stalls = 0     # producer found the queue full
         self.rounds = 0            # device rounds dispatched
         self.window_peak_bytes = 0  # most un-synced streamed bytes on device
+        # what becomes Result.explain["pipeline"], EXPLAIN ANALYZE's
+        # Pipeline: / Direct: / Hash: lines and the execute span's attr
+        self.figures: dict = {}
+        # the load ledger's input (_finish_select, run_worker_task)
+        self.task_times: list = []       # (shard index, rows, dispatch s)
+        self.task_bytes: list = []       # (shard index, H2D bytes)
+        self.mesh_task_times: list = []  # a mesh round's s, split by member
+        self.remote_tasks: list = []     # (si, node, bytes, rpc s, decode s)
 
-    def as_dict(self) -> dict:
-        return {
-            "host_decode_ms": round(self.host_decode_s * 1000, 3),
-            "device_ms": round(self.device_s * 1000, 3),
-            "h2d_bytes": int(self.h2d_bytes),
-            "host_stalls": int(self.host_stalls),
-            "device_stalls": int(self.device_stalls),
-        }
-
-    def publish(self, plan) -> None:
-        """Merge into the plan's EXPLAIN surface and the global
-        counters (the citus_stat_counters analog)."""
+    def tally(self, name: str, amount, add: bool = False):
+        """A figure that is also a process counter, booked once: the
+        statement's ``figures[name]`` becomes ``amount`` (with ``add``,
+        grows by it) and the counter ``name`` is bumped by the same."""
         from citus_tpu.executor.executor import GLOBAL_COUNTERS
-        plan.runtime_cache.setdefault("pipeline", {}).update(self.as_dict())
-        if self.host_stalls:
-            GLOBAL_COUNTERS.bump("pipeline_host_stalls", self.host_stalls)
-        if self.device_stalls:
-            GLOBAL_COUNTERS.bump("pipeline_device_stalls",
-                                 self.device_stalls)
+        self.figures[name] = amount + (self.figures.get(name, 0) if add
+                                       else 0)
+        GLOBAL_COUNTERS.bump(name, amount)
+        return amount
+
+    def book_timings(self) -> None:
+        """The timings of a streamed scan into the figures, once its
+        pipeline is joined, and its stalls into the process counters
+        (what an earlier scan of this execution booked is in the
+        figures already)."""
+        from citus_tpu.executor.executor import GLOBAL_COUNTERS
+        pl = self.figures
+        GLOBAL_COUNTERS.bump("pipeline_host_stalls",
+                             self.host_stalls - pl.get("host_stalls", 0))
+        GLOBAL_COUNTERS.bump("pipeline_device_stalls",
+                             self.device_stalls - pl.get("device_stalls", 0))
+        pl.update(
+            host_decode_ms=round(self.host_decode_s * 1000, 3),
+            device_ms=round(self.device_s * 1000, 3),
+            h2d_bytes=int(self.h2d_bytes),
+            host_stalls=int(self.host_stalls),
+            device_stalls=int(self.device_stalls))
+
+    def rider(self) -> "PipelineStats":
+        """A megabatched group's record as a rider other than the first
+        takes it: the ONE device run's figures, its own copy (each rider
+        adds its own ending's); no task log -- the group's device work
+        is booked to the load ledger once, by the first."""
+        r = PipelineStats()
+        r.figures = dict(self.figures)
+        return r
 
 
 def read_ahead_depth(settings) -> int:
@@ -282,9 +310,10 @@ class RemoteTaskDispatch:
     ``abort()`` (error path) drops undispatched tasks and waits out the
     in-flight ones so no thread outlives the query attempt."""
 
-    def __init__(self, cat, plan, settings, tasks, payload_kind: str):
+    def __init__(self, cat, record: PipelineStats, settings, tasks,
+                 payload_kind: str):
         self.cat = cat
-        self.plan = plan
+        self.record = record
         self.cap = max(1, settings.executor.max_adaptive_pool_size)
         self.shared_limit = settings.executor.max_shared_pool_size
         self.wire = settings.executor.wire_format
@@ -440,9 +469,10 @@ class RemoteTaskDispatch:
     # ---- caller side ----
     def collect(self) -> tuple[list[int], list]:
         """Wait for every in-flight task; returns (fallback shard
-        indexes, successful results in shard-index order) and publishes
-        the overlap/peak stats.  Decode runs here, on the caller — the
-        event loop only moves bytes."""
+        indexes, successful results in shard-index order) and books the
+        task log and the overlap/peak figures to the execution's record.
+        Decode runs here, on the caller — the event loop only moves
+        bytes."""
         from citus_tpu.executor.executor import GLOBAL_COUNTERS
         from citus_tpu.net.data_plane import (decode_batch,
                                               decode_hash_partials,
@@ -494,9 +524,9 @@ class RemoteTaskDispatch:
         # the stretch of remote in-flight time the caller spent doing
         # local work instead of blocking — the overlap win itself
         overlapped_s = max(0.0, min(t_enter, t_last) - self._t_start)
-        self.plan.runtime_cache["remote_tasks"] = tlog
+        self.record.remote_tasks.extend(tlog)
         if self._total:
-            pl = self.plan.runtime_cache.setdefault("pipeline", {})
+            pl = self.record.figures
             pl["remote_wait_ms"] = round(wait_s * 1000, 3)
             pl["remote_overlapped_ms"] = round(overlapped_s * 1000, 3)
             pl["remote_inflight_peak"] = peak
@@ -518,7 +548,7 @@ class RemoteTaskDispatch:
                 self._cv.wait(0.5)
 
 
-def dispatch_remote_tasks(cat, plan, settings, params=((), ())
+def dispatch_remote_tasks(cat, plan, settings, params, record: PipelineStats
                           ) -> tuple[list[int], RemoteTaskDispatch]:
     """Start the remote fan-out for every remote-only placement of
     ``plan`` and return immediately: ``(local_shard_indexes,
@@ -527,11 +557,9 @@ def dispatch_remote_tasks(cat, plan, settings, params=((), ())
     policy "pull") push nothing — everything stays local."""
     from citus_tpu.executor.executor import GLOBAL_COUNTERS
     from citus_tpu.executor.worker_tasks import encode_task, split_pushable
-    plan.runtime_cache["pipeline"] = {}
     local, remote = split_pushable(cat, plan, settings)
     if not remote:
-        plan.runtime_cache["remote_tasks"] = []
-        return list(local), RemoteTaskDispatch(cat, plan, settings, [], "")
+        return list(local), RemoteTaskDispatch(cat, record, settings, [], "")
     template = encode_task(plan, params)
     if template is not None:
         # the coordinator's citus.wire_format decides how the WORKER
@@ -541,13 +569,12 @@ def dispatch_remote_tasks(cat, plan, settings, params=((), ())
         template = dict(template, wire=settings.executor.wire_format)
     if template is None:
         GLOBAL_COUNTERS.bump("remote_task_fallbacks", len(remote))
-        plan.runtime_cache["remote_tasks"] = []
         return (sorted(local + [si for si, _, _ in remote]),
-                RemoteTaskDispatch(cat, plan, settings, [], ""))
+                RemoteTaskDispatch(cat, record, settings, [], ""))
     tasks = [(si, node,
               ep, dict(template,
                        shard_id=plan.bound.table.shards[si].shard_id,
                        node=node))
              for si, node, ep in remote]
     return list(local), RemoteTaskDispatch(
-        cat, plan, settings, tasks, template["kind"])
+        cat, record, settings, tasks, template["kind"])

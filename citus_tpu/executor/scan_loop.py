@@ -8,7 +8,9 @@ and the only one.  What differs between the scans is data:
 
 - a **placement** says where a round's inputs live: how many batches
   make a round, how they are put, how parameters ride along, the cache
-  key's suffix, how a round's bytes and seconds are attributed.
+  key's suffix, how a round's bytes and seconds are attributed (into
+  the execution's record, ``pipeline.PipelineStats``, which it is made
+  with).
   ``OneDevice``, ``MeshPlacement`` and its shard-affine sibling
   ``AffineMeshPlacement`` (per-device state: a shard always meets the
   same device); ``choose_placement`` / ``choose_affine_placement`` pick;
@@ -166,9 +168,8 @@ class OneDevice(Lanes):
     key_suffix: tuple = ()
     mesh = None
 
-    def __init__(self) -> None:
-        self.task_times: list = []
-        self.task_bytes: list = []
+    def __init__(self, record: PipelineStats) -> None:
+        self.record = record
         self.rows_padded = 0    # rows of every round, padding included
 
     def bind(self, params) -> None:
@@ -204,14 +205,10 @@ class OneDevice(Lanes):
 
     def book(self, members, b: ShardBatch, nbytes: int, round_s: float,
              dispatch_s: float) -> None:
-        self.task_times.append((b.shard_index, b.n_rows, dispatch_s))
+        self.record.task_times.append((b.shard_index, b.n_rows, dispatch_s))
         self.rows_padded += b.padded_rows
         if members is not None:
-            self.task_bytes.append((b.shard_index, nbytes))
-
-    def publish(self, plan) -> None:
-        plan.runtime_cache["task_times"] = self.task_times
-        plan.runtime_cache["task_bytes"] = self.task_bytes
+            self.record.task_bytes.append((b.shard_index, nbytes))
 
 
 def _repad_batch(b: ShardBatch, bucket: int) -> ShardBatch:
@@ -238,15 +235,14 @@ class MeshPlacement(Lanes):
     converted on the chips after the put, each device its own row of
     the stack and its own flag: no collective."""
 
-    def __init__(self, mesh) -> None:
+    def __init__(self, mesh, record: PipelineStats) -> None:
         from jax.sharding import NamedSharding, PartitionSpec
         from citus_tpu.parallel.mesh import shard_axis_size
         self.mesh = mesh
+        self.record = record
         self.round_size = shard_axis_size(mesh)
         self.key_suffix = ("mesh", self.round_size)
         self.sharding = NamedSharding(mesh, PartitionSpec("shard"))
-        self.task_bytes: list = []
-        self.mesh_task_times: list = []
         self.rows_padded = 0    # rows of every round, padding included
 
     def bind(self, params) -> None:
@@ -303,23 +299,18 @@ class MeshPlacement(Lanes):
         shard members for attribution (the filler batches' padding
         belongs to the shards that forced the round).  The byte
         remainder lands on the first member so the ledger total stays
-        exactly equal to the bytes_scanned counter bump."""
+        exactly equal to the bytes_scanned counter bump.  The times are
+        attribution-only (``mesh_task_times``, not the EXPLAIN Tasks
+        section, which renders single-device dispatches)."""
         self.rows_padded += inputs[2].size
         if members is None:
             return
         share, rem = divmod(int(nbytes), len(members))
         for i, mb in enumerate(members):
-            self.task_bytes.append(
+            self.record.task_bytes.append(
                 (mb.shard_index, share + (rem if i == 0 else 0)))
-            self.mesh_task_times.append(
+            self.record.mesh_task_times.append(
                 (mb.shard_index, mb.n_rows, round_s / len(members)))
-
-    def publish(self, plan) -> None:
-        plan.runtime_cache["task_bytes"] = self.task_bytes
-        # attribution-only (not the EXPLAIN Tasks section, which renders
-        # single-device dispatches): per-round device time split across
-        # the round's shard members
-        plan.runtime_cache["mesh_task_times"] = self.mesh_task_times
 
 
 class AffineMeshPlacement(MeshPlacement):
@@ -332,8 +323,8 @@ class AffineMeshPlacement(MeshPlacement):
     alone, scan after scan: tables of groups that live in one shard
     (a key that holds the distribution column) stay disjoint."""
 
-    def __init__(self, mesh, n_shards: int) -> None:
-        super().__init__(mesh)
+    def __init__(self, mesh, n_shards: int, record: PipelineStats) -> None:
+        super().__init__(mesh, record)
         self.n_shards = max(1, n_shards)
         self.key_suffix = ("mesh", self.round_size, "affine")
         self.device_rows = [0] * self.round_size    # table rows a device took
@@ -400,7 +391,7 @@ def _lookup(make_key: Callable[[], Optional[tuple]], mesh: bool):
 
 def choose_placement(plan, data_dir: str, use_cache: bool,
                      open_stream: Callable[[], Iterator],
-                     pstats: PipelineStats):
+                     record: PipelineStats):
     """Where this scan's rounds live, from what the code observes: the
     device count, a hit under the one-device key, a stream of a single
     batch.  -> (placement, cache key | None, cached device inputs |
@@ -415,10 +406,10 @@ def choose_placement(plan, data_dir: str, use_cache: bool,
     # there without touching disk: the mesh is entered only when no such
     # entry exists
     if len(devices) == 1 or cached is not None:
-        return (OneDevice(), key, cached,
+        return (OneDevice(record), key, cached,
                 None if cached is not None else open_stream())
     with _trace.span("scan_setup"):
-        placement = MeshPlacement(default_mesh())
+        placement = MeshPlacement(default_mesh(), record)
     # the key holds the snapshot generation (three file reads): made once
     mkey, mcached = _lookup(lambda: key and key + placement.key_suffix, True)
     if mcached is not None:
@@ -426,14 +417,14 @@ def choose_placement(plan, data_dir: str, use_cache: bool,
     stream = open_stream()
     t_peek = clock()
     head = list(itertools.islice(stream, 2))
-    pstats.host_decode_s += clock() - t_peek
+    record.host_decode_s += clock() - t_peek
     if len(head) < 2:
-        return OneDevice(), key, None, iter(head)   # 0 or 1 batch
+        return OneDevice(record), key, None, iter(head)   # 0 or 1 batch
     return placement, mkey, None, itertools.chain(head, stream)
 
 
 def choose_affine_placement(plan, open_stream: Callable[[list], Iterator],
-                            pstats: PipelineStats):
+                            record: PipelineStats):
     """Where the rounds of a scan with per-device state live, from what
     the code observes, as ``choose_placement`` does: one device, or a
     stream of a single batch -> ``OneDevice`` (and the stream as it
@@ -444,9 +435,9 @@ def choose_affine_placement(plan, open_stream: Callable[[list], Iterator],
     with _trace.span("scan_setup"):
         devices = executor_devices()
         if len(devices) == 1:
-            return OneDevice(), open_stream(plan.shard_indexes)
+            return OneDevice(record), open_stream(plan.shard_indexes)
         placement = AffineMeshPlacement(default_mesh(),
-                                        plan.bound.table.shard_count)
+                                        plan.bound.table.shard_count, record)
     stream = placement.affine(plan.shard_indexes, open_stream)
     t_peek = clock()
     head: list = []
@@ -455,11 +446,11 @@ def choose_affine_placement(plan, open_stream: Callable[[list], Iterator],
             head.append(next(stream))
         except StopIteration:
             break
-    pstats.host_decode_s += clock() - t_peek
+    record.host_decode_s += clock() - t_peek
     real = [m for m in head if m is not None]
     if len(real) < 2:
         stream.close()
-        return OneDevice(), iter(real)      # 0 or 1 batch
+        return OneDevice(record), iter(real)      # 0 or 1 batch
     return placement, itertools.chain(head, stream)
 
 
@@ -476,7 +467,7 @@ def _rounds(batches: Iterator, n: int) -> Iterator[list]:
         yield members
 
 
-def drive(plan, settings, placement, step: Step, state, pstats: PipelineStats,
+def drive(plan, settings, placement, step: Step, state, record: PipelineStats,
           *, cached: Optional[list] = None, stream: Optional[Iterator] = None,
           cache_key: Optional[tuple] = None, cache_tenant: Optional[str] = None,
           on_sync: Optional[Callable[[list], None]] = None):
@@ -507,7 +498,7 @@ def drive(plan, settings, placement, step: Step, state, pstats: PipelineStats,
         # host/device overlap: the decode thread prepares the next
         # rounds while the device executes the current one
         source = prefetch_batches(
-            stream, read_ahead_depth(settings) * placement.round_size, pstats)
+            stream, read_ahead_depth(settings) * placement.round_size, record)
         todo = ((m, None) for m in _rounds(source, placement.round_size))
     else:
         todo = ((None, inputs) for inputs in cached)
@@ -547,14 +538,14 @@ def drive(plan, settings, placement, step: Step, state, pstats: PipelineStats,
                         collect = None  # working set exceeds the HBM cache
                 if streamed and collect is None:
                     window_bytes += nb
-                    pstats.window_peak_bytes = max(pstats.window_peak_bytes,
+                    record.window_peak_bytes = max(record.window_peak_bytes,
                                                    window_bytes)
                     since_sync += 1
                     if since_sync >= depth:
                         _block_ready(jax.tree_util.tree_leaves(state)[-1:])
                         since_sync = window_bytes = 0
                         synced = True
-                pstats.device_s += clock() - t_dev
+                record.device_s += clock() - t_dev
                 if rsp.recording:
                     rsp.set(resident=not streamed,
                             **placement.describe(members, inputs))
@@ -577,18 +568,15 @@ def drive(plan, settings, placement, step: Step, state, pstats: PipelineStats,
         _block_ready([placement.args(i)[0] for i in collect])
         with _trace.span("cache_put"):
             GLOBAL_CACHE.put(cache_key, collect, held, tenant=cache_tenant)
-    pstats.rounds += rounds
+    record.rounds += rounds
     GLOBAL_COUNTERS.bump(step.counter, rounds)
-    pl = plan.runtime_cache.setdefault("pipeline", {})
-    pl["fused_dispatches"] = pstats.rounds
+    record.figures["fused_dispatches"] = record.rounds
     # the int64 scan columns of this scan, and those that rode at 32 bits
-    pl["scan_lanes"] = plan.wide_lanes
-    pl["scan_lanes_narrow"] = len(plan.narrow_lanes)
-    GLOBAL_COUNTERS.bump("scan_lanes", pl["scan_lanes"])
-    GLOBAL_COUNTERS.bump("scan_lanes_narrow", pl["scan_lanes_narrow"])
+    record.tally("scan_lanes", plan.wide_lanes)
+    record.tally("scan_lanes_narrow", len(plan.narrow_lanes))
     if streamed:
-        pstats.h2d_bytes += nbytes
+        record.h2d_bytes += nbytes
         GLOBAL_COUNTERS.bump("bytes_scanned", nbytes)
         GLOBAL_COUNTERS.bump("device_hbm_touched_bytes", nbytes)
-        pl["stream_window_peak_bytes"] = pstats.window_peak_bytes
+        record.figures["stream_window_peak_bytes"] = record.window_peak_bytes
     return state

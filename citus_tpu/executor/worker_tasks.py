@@ -349,7 +349,7 @@ def split_pushable(cat, plan: PhysicalPlan, settings):
     return local, remote
 
 
-def push_remote_tasks(cat, plan: PhysicalPlan, settings, params=((), ())):
+def push_remote_tasks(cat, plan: PhysicalPlan, settings, params, record):
     """Push the worker task to every remote-only placement; returns
     (local_shard_indexes, remote_results).  Agg results are partial
     tuples ready for combine_partials_host; projection results are
@@ -364,7 +364,8 @@ def push_remote_tasks(cat, plan: PhysicalPlan, settings, params=((), ())):
     (local scan while RPCs fly) call dispatch_remote_tasks directly
     and collect() after their local work."""
     from citus_tpu.executor.pipeline import dispatch_remote_tasks
-    local, dispatch = dispatch_remote_tasks(cat, plan, settings, params)
+    local, dispatch = dispatch_remote_tasks(cat, plan, settings, params,
+                                            record)
     fallback, results = dispatch.collect()
     return sorted(local + fallback), results
 
@@ -378,8 +379,6 @@ def note_inexpressible(cat, plan: PhysicalPlan, settings) -> None:
     _, remote = split_pushable(cat, plan, settings)
     if remote:
         GLOBAL_COUNTERS.bump("remote_task_fallbacks", len(remote))
-    plan.runtime_cache["remote_tasks"] = []
-    plan.runtime_cache["pipeline"] = {}
 
 
 # ------------------------------------------------------ worker side
@@ -496,7 +495,9 @@ def run_worker_task(cluster, p: dict) -> tuple[dict, bytes]:
     from citus_tpu.executor.executor import (
         _run_partials_cpu, _run_partials_jax,
     )
+    from citus_tpu.executor.pipeline import PipelineStats
     t0 = clock()
+    record = PipelineStats()    # a task's: of the attempt that answers
     if int(p.get("v", -1)) != TASK_VERSION:
         raise ExecutionError(
             f"task version {p.get('v')!r} != {TASK_VERSION}")
@@ -533,9 +534,10 @@ def run_worker_task(cluster, p: dict) -> tuple[dict, bytes]:
         run = _run_partials_cpu if backend == "cpu" else _run_partials_jax
 
         def _attempt():
-            return run(cat, plan, settings, params)
+            record = PipelineStats()
+            return run(cat, plan, settings, params, record), record
         with _trace.span("worker_scan", shard_id=shard_id, kind="agg"):
-            partials = snapshot_read(
+            partials, record = snapshot_read(
                 cat.data_dir, t, _attempt,
                 timeout=settings.executor.lock_timeout_s)
         with _trace.span("worker_encode"):
@@ -545,9 +547,11 @@ def run_worker_task(cluster, p: dict) -> tuple[dict, bytes]:
         from citus_tpu.net.data_plane import encode_hash_partials
 
         def _attempt():
-            return _run_hash_partial_state(cat, plan, settings, params)
+            record = PipelineStats()
+            return _run_hash_partial_state(cat, plan, settings, params,
+                                           record), record
         with _trace.span("worker_scan", shard_id=shard_id, kind="hash"):
-            table, spilled = snapshot_read(
+            (table, spilled), record = snapshot_read(
                 cat.data_dir, t, _attempt,
                 timeout=settings.executor.lock_timeout_s)
         with _trace.span("worker_encode"):
@@ -570,13 +574,12 @@ def run_worker_task(cluster, p: dict) -> tuple[dict, bytes]:
             if os.path.isfile(fp):
                 stripe_bytes += os.path.getsize(fp)
     # pushed-execution attribution: the placement's own host books the
-    # device work its scan did (popped from the inner run's task logs,
-    # so the worker-local ledger stays balanced against the worker's
-    # own bytes_scanned counter); query/row counts stay with the
-    # pushing coordinator — they are booked once at its _finish_select
+    # device work its scan did (from the task's own record, so the
+    # worker-local ledger stays balanced against the worker's own
+    # bytes_scanned counter); query/row counts stay with the pushing
+    # coordinator — they are booked once at its _finish_select
     from citus_tpu.observability.load_attribution import GLOBAL_ATTRIBUTION
-    att_times = plan.runtime_cache.pop("task_times", [])
-    att_bytes = plan.runtime_cache.pop("task_bytes", [])
+    att_times, att_bytes = record.task_times, record.task_bytes
     dev_ms = sum(s for _si, _n, s in att_times) * 1000.0
     if not att_times:
         dev_ms = (clock() - t0) * 1000.0  # host-only task: wall fallback

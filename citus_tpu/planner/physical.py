@@ -98,9 +98,12 @@ class PhysicalPlan:
     # ``scan_columns`` entry (``scan_lanes_of``); empty = every column
     # at its logical device dtype (a plan not made by ``plan_select``)
     scan_lanes: tuple = ()
-    # executor-populated cache of jitted kernels; lives with the plan so a
-    # plan cache hit skips XLA recompilation (the analog of the reference's
-    # prepared-statement local plan cache, local_plan_cache.c)
+    # what the executor compiled from this plan and nothing else: jitted
+    # kernels by slot, the numpy arm's closures (np_filter,
+    # np_final_fns), _fingerprint.  Lives with the plan so a plan cache
+    # hit skips XLA recompilation (the analog of the reference's
+    # prepared-statement local plan cache, local_plan_cache.c); what one
+    # execution counted is in its own executor/pipeline.py PipelineStats
     runtime_cache: dict = field(default_factory=dict)
     # distribution-key literal when the router path was chosen (tenant id)
     router_key: Optional[object] = None

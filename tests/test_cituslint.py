@@ -261,6 +261,30 @@ def test_declared_and_used_counters_quiet(tmp_path):
     assert run_lint(pkg, select={"CNT01", "CNT02"}) == []
 
 
+def test_undeclared_record_tally_fires(tmp_path):
+    """``record.tally("name", n)`` (executor/pipeline.py PipelineStats)
+    books a statement's figure and bumps the counter of that name: an
+    undeclared name fires as a bump of it would."""
+    pkg = make_pkg(tmp_path, {
+        "stats.py": STATS_FIXTURE,
+        "m.py": ("def f(record):\n"
+                 "    record.tally('queries_executed', 1)\n"
+                 "    record.tally('made_up_figure', 3, add=True)\n"),
+    })
+    diags = run_lint(pkg, select={"CNT01"})
+    assert len(diags) == 1 and "made_up_figure" in diags[0].message
+
+
+def test_counter_booked_through_a_record_alone_is_not_dead(tmp_path):
+    pkg = make_pkg(tmp_path, {
+        "stats.py": STATS_FIXTURE,
+        "m.py": ("def f(record):\n"
+                 "    record.tally('queries_executed', 1)\n"
+                 "    record.tally('errors_seen', 2, add=True)\n"),
+    })
+    assert run_lint(pkg, select={"CNT01", "CNT02"}) == []
+
+
 # --------------------------------------------------------------- CNT03
 
 WAITS_FIXTURE = """

@@ -50,8 +50,8 @@ def mesh_calls(monkeypatch):
     calls = []
     real = X._run_partials_jax
 
-    def spy(cat, plan, settings, params=((), ())):
-        out = real(cat, plan, settings, params)
+    def spy(cat, plan, settings, params, record):
+        out = real(cat, plan, settings, params, record)
         calls.append((cat, plan, settings, params, out))
         return out
 

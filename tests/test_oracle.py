@@ -124,7 +124,9 @@ def test_mesh_path_is_used(loaded):
     bound = bind_select(cl.catalog, parse_sql("SELECT kind, count(*) FROM events GROUP BY kind")[0])
     plan = plan_select(cl.catalog, bound)
     from citus_tpu.executor.executor import _iter_padded_batches
-    batches = list(_iter_padded_batches(cl.catalog, plan, cl.settings))
+    from citus_tpu.executor.pipeline import PipelineStats
+    batches = list(_iter_padded_batches(cl.catalog, plan, cl.settings,
+                                        PipelineStats()))
     assert len(batches) > 1  # multi-batch -> shard_map + psum path
 
 

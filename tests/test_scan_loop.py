@@ -49,8 +49,8 @@ def scans(monkeypatch):
     calls = []
     real = X._run_partials_jax
 
-    def spy(cat, plan, settings, params=((), ())):
-        out = real(cat, plan, settings, params)
+    def spy(cat, plan, settings, params, record):
+        out = real(cat, plan, settings, params, record)
         calls.append((cat, plan, settings, params, out))
         return out
 
@@ -333,8 +333,8 @@ def test_the_driver_takes_any_placement_step_and_state():
         def book(self, members, inputs, nbytes, round_s, dispatch_s):
             self.booked.append((members, nbytes))
 
-    plan = type("P", (), {"runtime_cache": {}, "narrow_lanes": (),
-                          "wide_lanes": 0, "bound": type("B", (), {
+    plan = type("P", (), {"narrow_lanes": (), "wide_lanes": 0,
+                          "bound": type("B", (), {
         "table": type("Tb", (), {"name": "plain"})})})()
     seen = []
     step = L.Step(lambda s, cols, valids, mask: (s + cols[0].sum(),
@@ -349,7 +349,7 @@ def test_the_driver_takes_any_placement_step_and_state():
     assert seen == [([1, 2], 2), ([3, 4], 4), ([5], 5)]
     assert placement.booked == [([1, 2], 16), ([3, 4], 16), ([5], 8)]
     assert pstats.h2d_bytes == 40
-    assert plan.runtime_cache["pipeline"]["fused_dispatches"] == 3
+    assert pstats.figures["fused_dispatches"] == 3
     names = set(inspect.signature(L.drive).parameters)
     assert not [n for n in names if re.search("mesh|hash|mega", n)]
 

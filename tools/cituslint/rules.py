@@ -13,8 +13,8 @@ THR01     ``threading.Thread(...)`` must pass an explicit ``daemon=``
 THR02     a created thread needs a reachable join()/cancel path
 SWL01     silent swallow: ``except Exception: pass`` / bare ``except:``
           with an empty body (no bump, no log, no re-raise)
-CNT01     ``bump("name")`` / span-fold strings must name a counter
-          declared in ``StatCounters.COUNTERS``
+CNT01     ``bump("name")`` / ``tally("name")`` / span-fold strings must
+          name a counter declared in ``StatCounters.COUNTERS``
 CNT02     every declared counter must have a bump site (dead counters
           lie in every dashboard)
 CNT03     ``begin_wait("event")`` names must be registered in
@@ -524,10 +524,12 @@ def _counters_decl(pkg: PackageIndex):
 
 
 class CounterNameRule(Rule):
-    """Every ``bump("name")``/``bump_max("name")`` literal and every
-    value of a ``_SPAN_MS``-style span-fold dict must be declared in
-    ``StatCounters.COUNTERS`` — a typo'd bump silently counts into the
-    void."""
+    """Every ``bump("name")``/``bump_max("name")`` literal, every
+    ``tally("name")`` of an execution's record (``executor/pipeline.py``
+    ``PipelineStats``: the statement's figure and the counter's bump in
+    one call) and every value of a ``_SPAN_MS``-style span-fold dict
+    must be declared in ``StatCounters.COUNTERS`` — a typo'd bump
+    silently counts into the void."""
 
     id = "CNT01"
     name = "counter names declared"
@@ -539,7 +541,7 @@ class CounterNameRule(Rule):
         for node in ast.walk(mod.tree):
             if isinstance(node, ast.Call) \
                     and isinstance(node.func, ast.Attribute) \
-                    and node.func.attr in ("bump", "bump_max") \
+                    and node.func.attr in ("bump", "bump_max", "tally") \
                     and node.args \
                     and isinstance(node.args[0], ast.Constant) \
                     and isinstance(node.args[0].value, str) \
@@ -566,7 +568,8 @@ class CounterNameRule(Rule):
 
 class DeadCounterRule(Rule):
     """Inverse of CNT01: every declared counter needs at least one
-    bump site (a string-literal use outside the declaration)."""
+    bump site (a string-literal use outside the declaration: a
+    ``tally("name")`` is one as a ``bump("name")`` is)."""
 
     id = "CNT02"
     name = "no dead counters"
