@@ -788,10 +788,67 @@ def leg_join_colocated(run, ref):
                overflow_rounds=j["overflow_rounds"])
 
 
+def leg_join_repartition(run, ref, devices):
+    """The single-hash repartition join on the device, on every device
+    the machine has (TPC-H Q12's shape: ``orders`` is distributed on the
+    customer key, the join is on the order key, ``lineitem``'s filter
+    keeps a few rows): every order is exchanged to the device that owns
+    its key's ``lineitem`` shard (one ``all_to_all`` a lane; skipped on
+    one device) and built into that device's one lookup table, then each
+    device probes its own ``lineitem`` shards.  The answer is the
+    reference's and the host path's."""
+    n_dev = len(devices)
+    with memory_growth(devices) as grew:
+        r, d, el, ev = run.run(Q_JOIN)
+    j = r.explain.get("join", {})
+    check(r.explain["strategy"] == "join:repartition"
+          and j.get("on") == "device", f"repartition join: {r.explain}")
+    check(r.explain.get("shuffle") == (
+        "all_to_all:device" if n_dev > 1 else "local"),
+        f"repartition join: shuffle {r.explain.get('shuffle')}")
+    check(d.get("join_host_fallbacks", 0) == 0, f"repartition join: {d}")
+    built = j["rows_built"]
+    check(built == ref.n_orders, f"repartition join: built {built} of "
+                                 f"{ref.n_orders} orders")
+    check(d.get("join_rows_exchanged", 0) == (built if n_dev > 1 else 0),
+          f"repartition join: exchanged {d.get('join_rows_exchanged')} "
+          f"of {built} build rows")
+    slots = set(kernel_slots(ev))
+    check({"jit_join_probe", "jit_hash_fused"} <= slots and (
+        n_dev == 1 or "jit_join_exchange" in slots),
+        f"repartition join: slots {sorted(slots)}")
+    if isinstance(grew["per_device"], list):
+        check(all(g > 0 for g in grew["per_device"]),
+              f"repartition join: bytes_in_use did not grow on every "
+              f"device: {grew['per_device']}")
+    want = [(ref.join[0], dec(ref.join[1], 2), dec(ref.join[2], 2))]
+    check(r.rows == want, f"repartition join answer {r.rows} want {want}")
+    run.cl.execute("SET citus.task_executor_backend = 'cpu'")
+    try:
+        host = run.cl.execute(Q_JOIN)
+    finally:
+        run.cl.execute("SET citus.task_executor_backend = 'tpu'")
+    check(host.rows == r.rows, f"repartition join: host path {host.rows}")
+    line = next(l for (l,) in run.cl.execute(
+        "EXPLAIN ANALYZE " + Q_JOIN).rows if l.lstrip().startswith("Join:"))
+    print("explain " + line.strip(), flush=True)
+    x = j["exchange"]
+    run.record("7a single-hash repartition join on the device (orders "
+               "exchanged on o_orderkey, build and probe per device)",
+               "jit_join_exchange" if n_dev > 1 else "jit_join_probe", el, d,
+               shuffle=r.explain["shuffle"], pairs=ref.join[0],
+               rows_exchanged=x["rows"], bytes_exchanged=x["bytes"],
+               rows_received_max_device=x["rows_received_max_device"],
+               exchange_overflow_rounds=x["overflow_rounds"],
+               rows_built=built, rows_probed=j["rows_probed"],
+               rows_looked_up=j["rows_looked_up"], rows_out=j["rows_out"],
+               table_bytes=j["table_bytes"],
+               bytes_in_use_growth_per_device=grew["per_device"])
+
+
 def leg_mesh(run, ref, devices, q1_h2d_bytes):
     """More than one device: where the cached Q1 stack lives, then the
-    repartition join and the mesh aggregates the single-device legs do
-    not reach."""
+    mesh aggregates the single-device legs do not reach."""
     report = {}
     stats = [dv.memory_stats() for dv in devices]
     if all(s is not None for s in stats):
@@ -803,16 +860,6 @@ def leg_mesh(run, ref, devices, q1_h2d_bytes):
         report["bytes_in_use_per_device"] = in_use
     else:
         report["bytes_in_use_per_device"] = "memory_stats unavailable"
-
-    r, d, el, _ = run.run(Q_JOIN)
-    check(r.explain["strategy"] == "join:repartition", f"join: {r.explain}")
-    check("devjoin" in r.explain.get("shuffle", ""),
-          f"join did not run on the device: {r.explain}")
-    want = [(ref.join[0], dec(ref.join[1], 2), dec(ref.join[2], 2))]
-    check(r.rows == want, f"join answer {r.rows} want {want}")
-    run.record("7a repartition join (all_to_all + device sort join)",
-               "build_repartition_join", el, d, shuffle=r.explain["shuffle"],
-               pairs=ref.join[0])
 
     r, d, el, ev = run.run(Q_STDDEV)
     want = ref.stddev_rows()
@@ -921,8 +968,9 @@ def main() -> int:
                 "lineitem carries 8 of TPC-H's 16 columns",
                 "keys and values are uniform draws (numpy default_rng), "
                 "not dbgen's distributions",
-                "orders (4-chip join leg) and orders_k (the colocated "
-                "join's) carry 3 columns, customer 2",
+                "orders (the repartition join's, distributed on the "
+                "customer key) and orders_k (the colocated join's) carry "
+                "3 columns, customer 2",
             ],
             "reduced": ([] if args.rows == SF10_LINEITEM_ROWS else
                         [f"rows cut from {SF10_LINEITEM_ROWS} to {args.rows}"]),
@@ -936,11 +984,10 @@ def main() -> int:
         ref = Reference(n_orders)
         t0 = time.perf_counter()
         load_lineitem(cl, ref, rng, args.rows, n_orders)
-        if n_dev > 1:
-            cl.execute(ORDERS_DDL)
-            cl.execute("SELECT create_distributed_table('orders', "
-                       f"'o_custkey', {shards})")
-            load_orders(cl, n_orders)
+        cl.execute(ORDERS_DDL)
+        cl.execute("SELECT create_distributed_table('orders', "
+                   f"'o_custkey', {shards})")
+        load_orders(cl, n_orders)
         cl.execute(ORDERS_K_DDL)
         cl.execute("SELECT create_distributed_table('orders_k', "
                    f"'o_orderkey', {shards})")
@@ -968,6 +1015,7 @@ def main() -> int:
                         min(MEASURES_ROWS, args.rows))
         leg_router(run, ref)
         leg_join_colocated(run, ref)
+        leg_join_repartition(run, ref, devices)
 
         memory = []
         for dv in devices:

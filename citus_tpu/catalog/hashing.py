@@ -21,16 +21,18 @@ INT32_MIN = -(2**31)
 INT32_MAX = 2**31 - 1
 
 
-def hash_int64(values: np.ndarray) -> np.ndarray:
-    """Vectorized splitmix64 finalizer -> signed int32 hash values."""
+def hash_int64(values, xp=np):
+    """Vectorized splitmix64 finalizer -> signed int32 hash values.
+    ``xp`` is numpy (ingest, host pruning) or ``jax.numpy`` (the
+    device's repartition exchange): the same bits either way."""
     with np.errstate(over="ignore"):
-        x = values.astype(np.int64).view(np.uint64) + _GOLDEN
-        x ^= x >> np.uint64(30)
-        x *= _C1
-        x ^= x >> np.uint64(27)
-        x *= _C2
-        x ^= x >> np.uint64(31)
-    return (x >> np.uint64(32)).astype(np.uint32).view(np.int32)
+        x = values.astype(np.int64).astype(np.uint64) + _GOLDEN
+        x = x ^ (x >> np.uint64(30))
+        x = x * _C1
+        x = x ^ (x >> np.uint64(27))
+        x = x * _C2
+        x = x ^ (x >> np.uint64(31))
+    return (x >> np.uint64(32)).astype(np.uint32).astype(np.int32)
 
 
 def hash_int64_scalar(value: int) -> int:

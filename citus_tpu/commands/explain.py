@@ -409,6 +409,13 @@ def _explain_join(cl, stmt: A.Explain) -> Result:
                 f"probed {j['rows_probed']}, matched {j['rows_matched']}, "
                 f"out {j['rows_out']}; overflow rounds "
                 f"{j['overflow_rounds']}; groups {j['groups']}")
+            x = j.get("exchange")
+            if x:
+                lines[-1] += (
+                    f"; exchange: {x['relation']} on {x['key']}, "
+                    f"{x['rows']} rows, {x['bytes']} bytes over "
+                    f"{x['devices']} devices, fullest device "
+                    f"{x['rows_received_max_device']}")
         elif j:
             lines.append(f"  Join: on host ({j['why']})")
     return Result(columns=["QUERY PLAN"], rows=[(l,) for l in lines])

@@ -1,8 +1,11 @@
-"""The device path of a colocated many-to-one join (``ops/join.py``).
+"""The device path of a many-to-one join (``ops/join.py``): colocated,
+or a single-hash repartition.
 
 ``run_device_join`` answers a ``join:colocated`` statement whose steps
-``planner/join_planner.py`` ``plan_device_join`` takes, or says why the
-host path (``executor/join_executor.py``, the oracle) has to.
+``planner/join_planner.py`` ``plan_device_join`` takes -- and a
+``join:repartition`` one whose probe relation is distributed on the
+join key -- or says why the host path (``executor/join_executor.py``,
+the oracle) has to.
 
 One stream, one ``scan_loop.drive``, one decode thread a query: the
 batches of every relation in the order their tables are needed -- the
@@ -20,6 +23,26 @@ parameters (``auto_param.hoist_literals``): a new SEGMENT or DATE
 compiles nothing.  One fetch brings home the aggregate's table (at
 most ``AGG_SLOTS[1]`` entries; the groups it cannot hold spill to the
 host accumulator, exactly).
+
+**The single-hash repartition** (``DeviceJoinTree.exchanged``: the
+reference's ``MapMergeJob`` with partition type ``SINGLE_HASH``).  The
+probe relation stays where its shards lie; the other distributed
+relation is built ONCE a query.  On several devices the stream's rounds
+are shard-affine (``_MeshRounds`` over ``AffineMeshPlacement``: a
+round's members are batches of one relation, member ``i`` of a shard
+device ``i`` owns) and every kernel runs per device under
+``shard_map``: first the rounds of the exchanged relation, each ONE
+dispatch of ``jit_join_exchange`` -- a row's target is the device that
+owns the probe relation's shard its key hashes to by the catalog's map,
+one ``all_to_all`` a lane, and what a device receives goes into its ONE
+lookup table by the build's own step (span ``join_exchange``; a block
+that could not hold its rows takes further rounds, found where the loop
+waits anyway and always before the first probe) -- then the rounds of
+the probe relation's own shards, each ``jit_join_probe`` and the
+aggregate's update per device, no collective; the per-device group
+tables come home in one fetch and merge exactly.  On one device the
+same tree and kernels with the exchange skipped: every shard of the
+relation builds the one table.
 """
 
 from __future__ import annotations
@@ -36,11 +59,13 @@ from citus_tpu.catalog import Catalog
 from citus_tpu.config import Settings
 from citus_tpu.executor.batches import empty_batch
 from citus_tpu.executor.executor import (
-    _UNREPORTED_FREE_BYTES, _pow2_at_least,
+    _UNREPORTED_FREE_BYTES, _pow2_at_least, _table_kernel,
 )
 from citus_tpu.executor.finalize import finalize_groups
 from citus_tpu.executor.kernel_cache import get_kernel, jit_compile
-from citus_tpu.executor.scan_loop import OneDevice, Step, drive
+from citus_tpu.executor.scan_loop import (
+    AffineMeshPlacement, OneDevice, Step, _rounds, drive,
+)
 from citus_tpu.observability import trace as _trace
 from citus_tpu.ops import join as J
 from citus_tpu.planner.auto_param import hoist_literals
@@ -69,7 +94,25 @@ class _Placement(OneDevice):
 
     def put(self, plan, members: list):
         self.host = members[0]
+        self.rows_in = self.host.n_rows
         return super().put(plan, members)
+
+
+class _MeshRounds(AffineMeshPlacement):
+    """The devices of the mesh, a table each: a round's members are
+    batches of ONE relation (``scans``: how each relation's batches are
+    shaped), member ``i`` a batch of a shard device ``i`` owns -- or,
+    of a replicated relation, the same batch for every device."""
+
+    def __init__(self, mesh, record, scans: dict) -> None:
+        super().__init__(mesh, 1, record)
+        self.scans = scans
+
+    def put(self, plan, members: list):
+        real = {id(m): m for m in members if m is not None}
+        self.host = next(iter(real.values()))
+        self.rows_in = sum(m.n_rows for m in real.values())
+        return super().put(self.scans[self.host.tag[0]], members)
 
 
 def _names_in(exprs) -> list:
@@ -89,6 +132,9 @@ def run_device_join(cat: Catalog, bj: BoundJoinSelect, settings: Settings,
     path answers it."""
     from citus_tpu.catalog.stats import shard_row_counts
     from citus_tpu.executor.executor import _hash_has_exact
+    if bj.strategy == "repartition" \
+            and not settings.planner.enable_repartition_joins:
+        return "repartition joins are disabled"
     shard_rows = {}
     with _trace.span("scan_setup"):
         for alias, t in bj.rels:
@@ -113,6 +159,12 @@ def run_device_join(cat: Catalog, bj: BoundJoinSelect, settings: Settings,
 class _DeviceJoin:
     #: the block's capacity: None = from the batch's bucket
     block_rows: Optional[int] = None
+    #: rows of a (source, destination) block of an exchange round:
+    #: None = ``ops/join.py`` ``exchange_capacity`` of the batch's bucket
+    exchange_rows: Optional[int] = None
+    #: where the probe's lookup stands: None = from what each batch
+    #: shows; ``lookup`` / ``filter`` pin it (a measurement's)
+    probe_order: Optional[str] = None
 
     def __init__(self, cat: Catalog, bj: BoundJoinSelect, settings: Settings,
                  tree: DeviceJoinTree, shard_rows: dict):
@@ -128,7 +180,7 @@ class _DeviceJoin:
         def hoist(e):
             if e is None:
                 return None
-            g, sp, vals = hoist_literals(e, len(specs))
+            g, sp, vals = hoist_literals(e, len(specs), in_lists=True)
             specs.extend(sp)
             values.extend(vals)
             return g
@@ -156,6 +208,19 @@ class _DeviceJoin:
                 {(n, dtype_of(n)) for n in at_root
                  if n.split(".", 1)[0] in under}))
 
+        # a single-hash repartition runs over every device, a table
+        # each; whatever else the device joins, on the first
+        from citus_tpu.parallel.mesh import default_mesh, executor_devices
+        self.exchanged = tree.exchanged[0] if tree.exchanged else None
+        # the key lane the exchange hashes, as its column is named
+        self.exchange_key = self.exchanged and getattr(
+            tree.edge[self.exchanged][0][tree.exchanged[1]], "name", "expr")
+        self.mesh, self.n_dev = None, 1
+        if self.exchanged and 1 < len(executor_devices()) <= \
+                self.tables_of[root].shard_count:
+            self.mesh = default_mesh()
+            self.n_dev = len(executor_devices())
+
         self.kind = {a: "hash" for a in tree.builds}
         self.spans: dict = {}
         for a in tree.builds:
@@ -182,16 +247,18 @@ class _DeviceJoin:
         self.nodes = {a: node(a) for a in tree.builds + [root]}
         self.out_dtypes = tuple(dtype_of(n) for n in out)
         # a build node is rebuilt per shard where it, or a relation
-        # under it, is distributed; else once a query
+        # under it, is distributed; else once a query -- as every one
+        # is where the distributed build is exchanged
         self.per_shard = {
-            a: any(self.tables_of[b].is_distributed for b in tree.subtree(a))
+            a: not self.exchanged and any(
+                self.tables_of[b].is_distributed for b in tree.subtree(a))
             for a in tree.builds}
         scans = [(a, [(c, dtype_of(f"{a}.{c}"))
                       for c in bj.rel_plans[a].columns])
                  for a in self.nodes]
         fp = hashlib.sha256(repr((
             sorted(self.nodes.items()), scans, bj.group_keys, bj.agg_args,
-            bj.partial_ops, len(specs))).encode()).hexdigest()
+            bj.partial_ops, len(specs), tree.exchanged)).encode()).hexdigest()
         # every relation's columns ride at their logical widths: the
         # join's lanes are not narrowed from the statistics (yet).
         # This view, the aggregate's and the scans' share one
@@ -208,7 +275,9 @@ class _DeviceJoin:
             partial_ops=bj.partial_ops, agg_extract=bj.agg_extract,
             runtime_cache=self.holder.runtime_cache)
 
-        self.n_shards = max(
+        # shard pairs the stream goes through one after another: none
+        # where nothing is built per shard
+        self.n_shards = 0 if self.exchanged else max(
             [t.shard_count for _, t in bj.rels if t.is_distributed] or [0])
         self.tables: dict = {}
         self.slots: dict = {}
@@ -216,8 +285,14 @@ class _DeviceJoin:
         self.rows_in: dict = {}
         self.bytes_in: dict = {}
         self.built = {a: 0 for a in tree.builds}
-        self.totals = np.zeros(3, np.int64)
+        self.totals = np.zeros(4, np.int64)
         self.probed = self.overflow_rounds = self.later_level = 0
+        # the exchange: rounds whose counts are not home yet, and what
+        # the ones that are have counted
+        self.exchange_pending: list = []
+        self.sent = self.exchange_overflow_rounds = 0
+        self.spanned = (0, 0)    # of those, what a span has reported
+        self.received = np.zeros(self.n_dev, np.int64)
 
     # ------------------------------------------------------------ scans
 
@@ -229,51 +304,95 @@ class _DeviceJoin:
             shard_indexes=shard_indexes,
             runtime_cache=self.holder.runtime_cache)
 
-    def _tagged(self, alias: str, shard_indexes: list, si: int):
-        """The host batches of a relation's shards, each tagged
-        ``(alias, shard index, first, last)``; a build relation with no
-        batch yields one of padding alone, so that its table is made."""
+    def _batches(self, alias: str, shard_indexes: list):
         from citus_tpu.executor.executor import _iter_padded_batches
-        plan = self._scan(alias, shard_indexes)
-        batches = _iter_padded_batches(self.cat, plan, self.settings,
-                                       self.record)
-        held, first = next(batches, None), True
+        return _iter_padded_batches(
+            self.cat, self._scan(alias, shard_indexes), self.settings,
+            self.record)
+
+    def _filler(self, alias: str, si: int):
+        """A batch of padding alone: a build relation with no batch
+        still makes its table."""
+        plan = self._scan(alias, [])
+        return empty_batch(plan.bound.table, plan,
+                           max(1, self.settings.executor.min_batch_rows), si)
+
+    def _tagged(self, alias: str, rounds, si: int, filler):
+        """``rounds`` (lists of host batches, None where a device has
+        none) flat, every batch tagged ``(alias, shard index, first,
+        last)`` by its round; a build relation with no round yields
+        ``filler()``, so that its table is made."""
+        held, first = next(rounds, None), True
         if held is None:
-            if alias != self.tree.root:
-                held = empty_batch(
-                    plan.bound.table, plan,
-                    max(1, self.settings.executor.min_batch_rows), si)
-            else:
+            if alias == self.tree.root:
                 return
+            held = filler()
         while held is not None:
-            nxt = next(batches, None)
-            held.tag = (alias, si, first, nxt is None)
-            yield held
+            nxt = next(rounds, None)
+            for m in held:
+                if m is not None:
+                    m.tag = (alias, si, first, nxt is None)
+            yield from held
             held, first = nxt, False
+
+    def _one_device(self, alias: str, shard_indexes: list, si: int):
+        return self._tagged(
+            alias, ([b] for b in self._batches(alias, shard_indexes)), si,
+            lambda: [self._filler(alias, si)])
+
+    def _rounds_of(self, alias: str):
+        """A relation's batches in the mesh's rounds: shard-affine by
+        its own table's map, or every batch to every device."""
+        table, n = self.tables_of[alias], self.n_dev
+        every = list(range(table.shard_count))
+        if table.is_distributed:
+            rounds = _rounds(self.placement.affine(
+                every, lambda mine: self._batches(alias, mine),
+                n_shards=table.shard_count), n)
+        else:
+            rounds = ([b] * n for b in self._batches(alias, every))
+        return self._tagged(alias, rounds, -1,
+                            lambda: [self._filler(alias, -1)] * n)
 
     def _stream(self):
         tree = self.tree
         all_of = lambda a: list(range(self.tables_of[a].shard_count))
+        if self.mesh is not None:
+            for a in tree.builds + [tree.root]:
+                yield from self._rounds_of(a)
+            return
         for a in tree.builds:
             if not self.per_shard[a]:
-                yield from self._tagged(a, all_of(a), -1)
+                yield from self._one_device(a, all_of(a), -1)
         if not self.n_shards:
-            yield from self._tagged(tree.root, all_of(tree.root), -1)
+            yield from self._one_device(tree.root, all_of(tree.root), -1)
             return
         for si in range(self.n_shards):
             one = lambda a: [si] if self.tables_of[a].is_distributed \
                 else all_of(a)
             for a in tree.builds:
                 if self.per_shard[a]:
-                    yield from self._tagged(a, one(a), si)
-            yield from self._tagged(tree.root, one(tree.root), si)
+                    yield from self._one_device(a, one(a), si)
+            yield from self._one_device(tree.root, one(tree.root), si)
 
     # ---------------------------------------------------------- kernels
 
-    def _kernel(self, slot: str, build, extra: tuple = (), **jit_kwargs):
-        return get_kernel(self.holder, slot,
-                          lambda: jit_compile(build(), **jit_kwargs),
-                          extra=extra)
+    def _kernel(self, slot: str, build, extra: tuple = (),
+                replicated: tuple = (), **jit_kwargs):
+        """``build()``'s function jitted as it is, or run by each device
+        of the mesh on its own tables (the arguments at ``replicated``
+        go to every device whole)."""
+        return _table_kernel(self.holder, self.mesh, slot, build,
+                             extra=extra, replicated=replicated, **jit_kwargs)
+
+    def _filled_on_devices(self) -> dict:
+        """jit arguments of a kernel that fills a state where it lives:
+        on the mesh a row a device."""
+        if self.mesh is None:
+            return {}
+        from jax.sharding import NamedSharding, PartitionSpec
+        return {"out_shardings": NamedSharding(self.mesh,
+                                               PartitionSpec("shard"))}
 
     def _direct_span(self, alias: str):
         """-> (lowest key, slots) of a direct-address table for build
@@ -307,11 +426,16 @@ class _DeviceJoin:
         return lo, slots
 
     def _rows_of(self, alias: str) -> int:
-        """Rows one build of ``alias`` can take: a shard's, or all."""
+        """Rows one build of ``alias`` can take: a shard's, all -- or,
+        of the exchanged relation on the mesh, a device's even share of
+        all and the exchange's margin of it."""
         rows = self.shard_rows[alias]
-        return max(rows, default=0) if (
-            self.per_shard[alias] and self.tables_of[alias].is_distributed
-        ) else sum(rows)
+        if self.per_shard[alias] and self.tables_of[alias].is_distributed:
+            return max(rows, default=0)
+        if alias == self.exchanged and self.mesh is not None:
+            num, den = J.EXCHANGE_MARGIN
+            return -(-sum(rows) * num // (den * self.n_dev))
+        return sum(rows)
 
     def _slots_of(self, alias: str) -> int:
         if self.kind[alias] == "direct":
@@ -319,19 +443,50 @@ class _DeviceJoin:
         return _pow2_at_least(J.SLOTS_PER_ROW * self._rows_of(alias), 1024)
 
     def _zero(self, alias: str):
+        import jax
         import jax.numpy as jnp
         from citus_tpu.ops.hash_agg import ENTRY_CHUNK
         node = self.nodes[alias]
         S = self.slots.setdefault(alias, self._slots_of(alias))
-        zero = self._kernel(
-            f"jit_join_zero:{alias}",
-            lambda: lambda slots, rows, lo: J.empty_join_table(
-                node, slots, jnp, rows, lo),
-            static_argnums=(0, 1))
+        n = self.n_dev if self.mesh is not None else 0
+
+        def make(slots, rows, lo):
+            table = J.empty_join_table(node, slots, jnp, rows, lo)
+            # on the mesh the same table a device
+            return jax.tree_util.tree_map(
+                lambda x: jnp.broadcast_to(x, (n,) + x.shape), table) \
+                if n else table
+        zero = get_kernel(
+            self.holder, f"jit_join_zero:{alias}",
+            lambda: jit_compile(make, static_argnums=(0, 1),
+                                **self._filled_on_devices()),
+            extra=(n,))
         # a direct table's lanes: every row of the build, and a chunk
         self.lane_rows[alias] = _pow2_at_least(self._rows_of(alias), 1024)
         return zero(S, self.lane_rows[alias] + ENTRY_CHUNK,
                     np.int64(self.spans.get(alias, (0, 0))[0]))
+
+    def _exchange_kernel(self):
+        import jax.numpy as jnp
+        node = self.nodes[self.exchanged]
+        lane = self.tree.exchanged[1]
+        n_dev, rows = self.n_dev, self.exchange_rows
+        return self._kernel(
+            "jit_join_exchange",
+            lambda: J.build_join_exchange(node, self.param_names, jnp, n_dev,
+                                          lane, rows),
+            extra=(rows,), replicated=(2, 6), donate_argnums=0)
+
+    def _bounds(self) -> np.ndarray:
+        """The least hash of each device's shards of the probe
+        relation: ``TableMeta.route_hashes``' ranges over the
+        placement's shard-to-device map."""
+        table = self.tables_of[self.tree.root]
+        first = {}
+        for si, shard in enumerate(table.shards):
+            first.setdefault(
+                self.placement.owner(si, table.shard_count), shard.hash_min)
+        return np.array([first[d] for d in range(self.n_dev)], np.int32)
 
     # ------------------------------------------------------------- step
 
@@ -341,39 +496,108 @@ class _DeviceJoin:
         alias, si, first, last = hb.tag
         node = self.nodes[alias]
         children = tuple(self.tables[c.alias] for c in node.children)
-        if alias != self.tree.root:
-            if first:
-                self.tables[alias] = self._zero(alias)
-                self.rows_in[alias] = self.bytes_in[alias] = 0
-            build = self._kernel(
-                f"jit_join_build:{alias}",
-                lambda: J.build_join_build(node, self.param_names, jnp),
-                donate_argnums=0)
-            self.rows_in[alias] += hb.n_rows
-            self.bytes_in[alias] += hb.nbytes
-            # the span of a build: its last dispatch and the wait for
-            # its counts
-            name = "join_build" if self.per_shard[alias] else "join_broadcast"
-            with (_trace.span(name) if last else nullcontext()) as sp:
-                table = self.tables[alias] = build(
-                    self.tables[alias], children, cols, valids, row_mask)
-                built = self._verdict(alias, table) if last else 0
-                if last and sp.recording:
-                    if self.per_shard[alias]:
-                        sp.set(shard_index=int(si), relation=alias,
-                               rows_in=self.rows_in[alias], rows_built=built,
-                               slots=self.slots[alias],
-                               table=self.kind[alias])
-                    else:
-                        sp.set(relation=alias, rows=self.rows_in[alias],
-                               rows_kept=built, bytes=self.bytes_in[alias])
-            return (table[1],), None
-        probe = self._probe_kernel()
-        bcols, bvalids, bmask, counts = probe(
-            children, cols, valids, row_mask, np.int32(0))
-        spill = self._aggregate(bcols, bvalids, bmask)
-        self.probed += int(row_mask.shape[0])
-        return (counts,), (counts, children, (cols, valids, row_mask), spill)
+        if alias == self.tree.root:
+            probe = self._probe_kernel()
+            bcols, bvalids, bmask, counts = probe(
+                children, cols, valids, row_mask, np.int32(0))
+            spill = self._aggregate(bcols, bvalids, bmask)
+            self.probed += int(row_mask.size)
+            return (counts,), (counts, children, (cols, valids, row_mask),
+                               spill)
+        if first:
+            self.tables[alias] = self._zero(alias)
+            self.rows_in[alias] = self.bytes_in[alias] = 0
+        self.rows_in[alias] += self.placement.rows_in
+        self.bytes_in[alias] += hb.nbytes
+        if alias == self.exchanged and self.mesh is not None:
+            return self._exchange_round(children, cols, valids, row_mask,
+                                        last), None
+        build = self._kernel(
+            f"jit_join_build:{alias}",
+            lambda: J.build_join_build(node, self.param_names, jnp),
+            donate_argnums=0)
+        # the span of a build: its last dispatch and the wait for
+        # its counts
+        name = "join_build" if self.per_shard[alias] or \
+            self.tables_of[alias].is_distributed else "join_broadcast"
+        with (_trace.span(name) if last else nullcontext()) as sp:
+            table = self.tables[alias] = build(
+                self.tables[alias], children, cols, valids, row_mask)
+            built = self._verdict(alias, table) if last else 0
+            if last and sp.recording:
+                if name == "join_build":
+                    sp.set(shard_index=int(si), relation=alias,
+                           rows_in=self.rows_in[alias], rows_built=built,
+                           slots=self.slots[alias],
+                           table=self.kind[alias])
+                else:
+                    sp.set(relation=alias, rows=self.rows_in[alias],
+                           rows_kept=built, bytes=self.bytes_in[alias])
+        return (table[1],), None
+
+    def _exchange_round(self, children, cols, valids, row_mask, last: bool):
+        """One round of the exchanged relation: the dispatch and, on
+        the relation's last round, the wait for every round's counts
+        (further rounds for the rows a block left behind) and the
+        build's verdict -- before any probe."""
+        alias = self.exchanged
+        exchange = self._exchange_kernel()
+        with _trace.span("join_exchange") as sp:
+            table, counts = exchange(
+                self.tables[alias], children, self.bounds, cols, valids,
+                row_mask, np.int32(0))
+            self.tables[alias] = table
+            self.exchange_pending.append(
+                (counts, children, (cols, valids, row_mask)))
+            if last:
+                self._settle_exchange()
+                with _trace.span("join_build") as bsp:
+                    built = self._verdict(alias, self.tables[alias])
+                    if bsp.recording:
+                        bsp.set(shard_index=-1, relation=alias,
+                                rows_in=self.rows_in[alias],
+                                rows_built=built, slots=self.slots[alias],
+                                table=self.kind[alias])
+            if sp.recording:
+                # what has come home since the last round's span
+                sent, further = self.sent, self.exchange_overflow_rounds
+                sp.set(relation=alias, key=self.exchange_key,
+                       rows_in=self.placement.rows_in,
+                       rows_sent=sent - self.spanned[0],
+                       capacity=J.exchange_capacity(
+                           int(row_mask.shape[-1]), self.n_dev,
+                           self.exchange_rows),
+                       overflow=further - self.spanned[1],
+                       devices=self.n_dev)
+                self.spanned = (sent, further)
+        return (self.tables[alias][1],)
+
+    def _settle_exchange(self) -> None:
+        """The counts of the exchange rounds dispatched since the last
+        time come home; a round that left rows behind takes further
+        rounds of them, exactly."""
+        import jax
+        pending, self.exchange_pending = self.exchange_pending, []
+        if not pending:
+            return
+        alias = self.exchanged
+        exchange = self._exchange_kernel()
+        homes = jax.device_get([p[0] for p in pending])
+        for c, (_, children, inputs) in zip(homes, pending):
+            rnd = 0
+            while True:
+                c = np.asarray(c, np.int64).reshape(self.n_dev, 3)
+                self.sent += int(c[:, J.SENT].sum())
+                self.received += c[:, J.RECEIVED]
+                if not c[:, J.LEFT].any():
+                    break
+                rnd += 1
+                self.exchange_overflow_rounds += 1
+                with _trace.span("join_exchange_overflow_round", round=rnd):
+                    self.tables[alias], c = exchange(
+                        self.tables[alias], children, self.bounds, *inputs,
+                        np.int32(rnd))
+                    c = jax.device_get(c)
 
     def _probe_kernel(self):
         import jax.numpy as jnp
@@ -381,11 +605,11 @@ class _DeviceJoin:
         return self._kernel(
             "jit_join_probe",
             lambda: J.build_join_probe(root, self.param_names, jnp,
-                                       self.block_rows),
-            extra=(self.block_rows,))
+                                       self.block_rows, self.probe_order),
+            extra=(self.block_rows, self.probe_order), replicated=(4,))
 
     def _aggregate(self, bcols, bvalids, bmask):
-        pcols, pvalids = self.params
+        pcols, pvalids = self.placement.pcols, self.placement.pvalids
         self.agg_state, spill = self.agg_kernel(
             self.agg_state, bcols + pcols, bvalids + pvalids, bmask)
         return spill
@@ -399,22 +623,25 @@ class _DeviceJoin:
         verdict = self._kernel(
             "jit_join_verdict",
             lambda: lambda t: J.join_table_verdict(jnp, t))
-        v = np.asarray(jax.device_get(verdict(table)))
-        if v[J.REPEATED] or v[J.COUNTS]:
+        v = np.asarray(jax.device_get(verdict(table))).reshape(
+            -1, J.COUNTS + 1)
+        if v[:, J.REPEATED].any() or v[:, J.COUNTS].any():
             raise _HostFallback(f"build key of {alias} is not unique")
         # a direct table's lanes hold the rows the catalog counted
-        if v[J.UNPLACED] or (self.kind[alias] == "direct"
-                             and v[J.PACKED_ROWS] > self.lane_rows[alias]):
+        if v[:, J.UNPLACED].any() or (
+                self.kind[alias] == "direct"
+                and v[:, J.PACKED_ROWS].max() > self.lane_rows[alias]):
             raise _HostFallback(f"build table of {alias} is full")
-        self.built[alias] += int(v[J.BUILT])
-        self.later_level += int(v[J.LATER_LEVEL])
-        return int(v[J.BUILT])
+        self.built[alias] += int(v[:, J.BUILT].sum())
+        self.later_level += int(v[:, J.LATER_LEVEL].sum())
+        return int(v[:, J.BUILT].sum())
 
     def _sync(self, pending: list) -> None:
         """Where the loop waits for the device anyway: the rounds'
         counts come home, a block that overflowed takes its further
         rounds, the aggregate's spilled entries drain to the host."""
         import jax
+        self._settle_exchange()
         rounds = [aux for _, aux in pending if aux is not None]
         if not rounds:
             return
@@ -423,22 +650,48 @@ class _DeviceJoin:
         with _trace.span("join_counts", rounds=len(rounds)):
             counts = jax.device_get([r[0] for r in rounds])
         for (_, children, inputs, spill), c in zip(rounds, counts):
-            c = np.asarray(c, np.int64)
+            c = np.asarray(c, np.int64).reshape(self.n_dev, -1)
             spills.append((None, spill))
-            C = J.block_capacity(int(inputs[2].shape[0]), self.block_rows)
-            for r in range(1, -(-int(c[J.PACKED]) // C)):
+            C = J.block_capacity(int(inputs[2].shape[-1]), self.block_rows)
+            for r in range(1, -(-int(c[:, J.PACKED].max()) // C)):
                 with _trace.span("join_overflow_round", round=r):
                     bcols, bvalids, bmask, cr = probe(
                         children, *inputs, np.int32(r))
                     spills.append(
                         (None, self._aggregate(bcols, bvalids, bmask)))
-                    c[J.OUT] += int(cr[J.OUT])
+                    c[:, 1:] += np.asarray(cr, np.int64).reshape(
+                        self.n_dev, -1)[:, 1:]
                 self.overflow_rounds += 1
-                self.probed += int(inputs[2].shape[0])
-            self.totals += c
+                self.probed += int(inputs[2].size)
+            self.totals += c.sum(axis=0)
         self.drain(spills)
 
     # -------------------------------------------------------------- run
+
+    def _agg_slots(self, key_dtypes: tuple) -> tuple:
+        """Slots of a device's group table and where the number came
+        from: the probe relation's rows over ``AGG_ROWS_PER_SLOT`` (a
+        device's share of them) between ``AGG_SLOTS``, or, where every
+        group key is a column whose type proves a domain (a dictionary,
+        a boolean: ``planner/physical.py`` ``_key_domain``) and that is
+        the smaller, twice the domains' product."""
+        from citus_tpu.planner.physical import _key_domain
+        rows = sum(self.shard_rows[self.tree.root]) // self.n_dev
+        S = min(AGG_SLOTS[1], _pow2_at_least(rows // AGG_ROWS_PER_SLOT,
+                                             AGG_SLOTS[0]))
+        domain = 1
+        for k in self.bj.group_keys:
+            d = None
+            if isinstance(k, BColumn) and "." in k.name:
+                alias, col = k.name.split(".", 1)
+                d = _key_domain(self.cat, self.tables_of[alias],
+                                BColumn(col, k.type), None)
+            if d is None:
+                return S, "row count"
+            domain *= d.size
+        by_domain = _pow2_at_least(2 * domain, AGG_SLOTS[0])
+        return (by_domain, "key domain") if by_domain < S \
+            else (S, "row count")
 
     def run(self, t0: float):
         import jax
@@ -458,7 +711,12 @@ class _DeviceJoin:
         bj, tree = self.bj, self.tree
         record = self.record = PipelineStats()
         _trace.set_phase("device")
-        self.placement = _Placement(record)
+        if self.mesh is None:
+            self.placement = _Placement(record)
+        else:
+            self.placement = _MeshRounds(
+                self.mesh, record, {a: self._scan(a, []) for a in self.nodes})
+            self.bounds = self._bounds()
         self.placement.bind(self.params)
 
         # the aggregate's table: key dtypes by evaluating the keys on a
@@ -473,20 +731,22 @@ class _DeviceJoin:
             lambda: build_fused_hash_worker(self.agg, jnp, key_dtypes),
             donate_argnums=0)
         acc = HostGroupAccumulator(len(bj.group_keys), bj.partial_ops)
-        self.drain = _SpillDrain(self.agg, [acc])
+        self.drain = _SpillDrain(self.agg, [acc],
+                                 devices=self.n_dev if self.mesh else 0)
         with _trace.span("hash_init") as sp:
-            S = min(AGG_SLOTS[1], _pow2_at_least(
-                sum(self.shard_rows[tree.root]) // AGG_ROWS_PER_SLOT,
-                AGG_SLOTS[0]))
+            S, slots_from = self._agg_slots(key_dtypes)
             agg = self.agg      # the cached kernel must not hold ``self``
-            zero = self._kernel(
-                "jit_join_agg_zero",
-                lambda: lambda slots: empty_hash_state(
-                    agg, slots, key_dtypes, jnp),
-                static_argnums=0)
+            n_tables = self.n_dev if self.mesh is not None else 0
+            zero = get_kernel(
+                self.holder, "jit_join_agg_zero",
+                lambda: jit_compile(
+                    lambda slots: empty_hash_state(
+                        agg, slots, key_dtypes, jnp, tables=n_tables),
+                    static_argnums=0, **self._filled_on_devices()),
+                extra=(n_tables,))
             self.agg_state = zero(S)
             if sp.recording:
-                sp.set(slots=S, devices=1)
+                sp.set(slots=S, slots_from=slots_from, devices=self.n_dev)
 
         token = (jnp.zeros((), np.int32),)
         drive(self.holder, self.settings, self.placement,
@@ -494,22 +754,24 @@ class _DeviceJoin:
               record, stream=self._stream(), on_sync=self._sync)
         record.book_timings()
 
-        # one fetch: the table comes home whole (at most AGG_SLOTS[1]
-        # entries; what it could not hold is in ``acc`` already)
+        # one fetch: the tables come home whole (at most AGG_SLOTS[1]
+        # entries each; what they could not hold is in ``acc`` already)
         h_keys, h_parts, h_rows = _fetch_hash_table(
-            _HashTables(self.agg_state), record)
+            _HashTables(self.agg_state, self.mesh), record)
         fetched = hash_state_bytes((h_keys, h_parts, h_rows))
         occupied = h_rows > 0
         n = int(occupied.sum())
         view = _JoinPlanView(bj)
         with _trace.span("finalize_groups") as sp:
-            if acc.n_groups == 0 and n > 0:
+            if acc.n_groups == 0 and n > 0 and self.mesh is None:
                 # nothing spilled: the entries are the groups
                 key_arrays = [(kv[occupied], kf[occupied] == 2)
                               for kv, kf in h_keys]
                 partials = tuple(p[occupied] for p in h_parts)
                 groups = n
             else:
+                # a group may sit in every device's table: they merge
+                # into the one accumulator, exactly
                 merge_hash_tables_into(acc, self.agg, h_keys, h_parts, h_rows)
                 key_arrays, partials = acc.finalize(
                     [g.type for g in bj.group_keys],
@@ -532,13 +794,40 @@ class _DeviceJoin:
             "rows_built": sum(self.built.values()),
             "later_level_rows": self.later_level,
             "rows_probed": self.probed,
+            "rows_looked_up": int(self.totals[J.LOOKED]),
             "rows_matched": int(self.totals[J.MATCHED]),
             "rows_out": int(self.totals[J.OUT]),
             "overflow_rounds": self.overflow_rounds,
             "table_bytes": table_bytes,
-            "agg_slots": S, "groups": groups,
+            "agg_slots": S, "agg_slots_from": slots_from, "groups": groups,
             "spilled_rows": self.drain.rows,
         }
+        explain = {"join": join, "pipeline": record.figures}
+        if self.exchanged:
+            explain["shuffle"] = "local" if self.mesh is None \
+                else "all_to_all:device"
+            node = self.nodes[self.exchanged]
+            # a row travels as its columns and a validity byte each,
+            # and a byte that says the lane holds a row
+            row_bytes = 1 + sum(
+                np.dtype(self.tables_of[self.exchanged].schema.scan_dtype(
+                    c.split(".", 1)[1], device=True)).itemsize + 1
+                for c in node.names)
+            join["exchange"] = {
+                "relation": self.exchanged,
+                "key": self.exchange_key.split(".", 1)[-1],
+                "rows": self.sent, "bytes": self.sent * row_bytes,
+                "devices": self.n_dev,
+                "rows_received_max_device": int(self.received.max()),
+                "overflow_rounds": self.exchange_overflow_rounds,
+            }
+            ex = join["exchange"]
+            GLOBAL_COUNTERS.bump("join_rows_exchanged", ex["rows"])
+            GLOBAL_COUNTERS.bump("join_bytes_exchanged", ex["bytes"])
+            GLOBAL_COUNTERS.bump("join_rows_received_max_device",
+                                 ex["rows_received_max_device"])
+            GLOBAL_COUNTERS.bump("join_exchange_overflow_rounds",
+                                 ex["overflow_rounds"])
         GLOBAL_COUNTERS.bump("join_rows_built", join["rows_built"])
         GLOBAL_COUNTERS.bump("join_rows_probed", join["rows_probed"])
         GLOBAL_COUNTERS.bump("join_rows_matched", join["rows_matched"])
@@ -546,8 +835,8 @@ class _DeviceJoin:
         GLOBAL_COUNTERS.bump("join_overflow_rounds", join["overflow_rounds"])
         GLOBAL_COUNTERS.bump("join_table_bytes", join["table_bytes"])
         GLOBAL_COUNTERS.bump("hash_table_bytes_fetched", fetched)
-        GLOBAL_COUNTERS.bump("hash_entries_fetched", S)
+        GLOBAL_COUNTERS.bump("hash_entries_fetched", S * self.n_dev)
         GLOBAL_COUNTERS.bump("hash_groups_out", join["groups"])
-        return finish_join(
-            bj, view, rows, "colocated", max(1, self.n_shards), t0,
-            {"join": join, "pipeline": record.figures})
+        tasks = self.tables_of[tree.root].shard_count if self.exchanged \
+            else max(1, self.n_shards)
+        return finish_join(bj, view, rows, bj.strategy, tasks, t0, explain)

@@ -329,16 +329,21 @@ class AffineMeshPlacement(MeshPlacement):
         self.key_suffix = ("mesh", self.round_size, "affine")
         self.device_rows = [0] * self.round_size    # table rows a device took
 
-    def owner(self, shard_index: int) -> int:
-        return shard_index * self.round_size // self.n_shards
+    def owner(self, shard_index: int, n_shards: Optional[int] = None) -> int:
+        """The device of a shard of a table of ``n_shards`` shards (the
+        placement's own table where not given)."""
+        return shard_index * self.round_size // (n_shards or self.n_shards)
 
-    def affine(self, shard_indexes: list, open_stream: Callable) -> Iterator:
+    def affine(self, shard_indexes: list, open_stream: Callable,
+               n_shards: Optional[int] = None) -> Iterator:
         """The batches of ``shard_indexes`` in round order: each round
         is ``round_size`` items, item ``i`` the next batch of device
         ``i``'s own shards (``open_stream(its shards)``: one stream a
         device, all pulled by whoever pulls this one) or None.  Ends
-        with the last round that holds a batch."""
-        owned = [[si for si in shard_indexes if self.owner(si) == d]
+        with the last round that holds a batch.  ``n_shards``: of the
+        table these shards are of, where a scan streams several."""
+        owned = [[si for si in shard_indexes
+                  if self.owner(si, n_shards) == d]
                  for d in range(self.round_size)]
         streams = [open_stream(mine) if mine else iter(()) for mine in owned]
         try:

@@ -61,8 +61,18 @@ BUILT, LATER_LEVEL, REPEATED, UNPLACED, PACKED_ROWS = range(5)
 COUNTS = 5
 
 #: what a probe round counts: rows packed (candidates for the block,
-#: over all rounds), rows with a build index, rows handed on
-PACKED, MATCHED, OUT = range(3)
+#: over all rounds), rows with a build index (a later round of the
+#: lookup-first order counts none again), rows handed on, rows looked up
+PACKED, MATCHED, OUT, LOOKED = range(4)
+
+#: what an exchange round counts: rows this device sent, rows it
+#: received, rows of its batch that no round has taken yet
+SENT, RECEIVED, LEFT = range(3)
+#: room a (source, destination) block of an exchange has over the even
+#: share of its batch, as a fraction: the catalog's hash spreads a
+#: batch's keys evenly, and what a block cannot hold takes a further
+#: round
+EXCHANGE_MARGIN = (5, 4)
 
 
 class _Lane(NamedTuple):
@@ -152,6 +162,19 @@ def block_capacity(n: int, block_rows: Optional[int] = None) -> int:
     on: ``block_rows`` where given, else 1/64 of the bucket (a join
     that keeps more of its probe rows takes further rounds)."""
     return block_rows or min(n, max(1024, n // 64))
+
+
+def exchange_capacity(n: int, n_dev: int,
+                      block_rows: Optional[int] = None) -> int:
+    """Rows of one (source, destination) block of an exchange round of
+    a batch of ``n`` rows between ``n_dev`` devices: ``block_rows``
+    where given, else the even share and ``EXCHANGE_MARGIN`` of it, to
+    the next 1,024."""
+    if block_rows:
+        return block_rows
+    num, den = EXCHANGE_MARGIN
+    share = -(-n * num // (den * n_dev))
+    return min(n, -(-share // 1024) * 1024)
 
 
 def _level_hash(xp, h, level):
@@ -398,24 +421,87 @@ def join_table_verdict(xp, table):
     return xp.concatenate([counts, twice[None]])
 
 
+def build_join_exchange(node: JoinNode, param_names: tuple, xp, n_dev: int,
+                        hash_lane: int,
+                        block_rows: Optional[int] = None) -> Callable:
+    """The exchange step of build node ``node``, one device's body under
+    ``shard_map``: (table, child_tables, bounds, cols, valids, row_mask,
+    round) -> (table', counts), ``table`` DONATED.  The rows of the
+    device's batch that pass the node's filter and hold no NULL key go
+    to the device that owns the shard of the PROBE relation their key
+    hashes to, by the catalog's own map -- ``catalog/hashing.py``
+    ``hash_int64`` of key lane ``hash_lane``, and ``bounds``: the least
+    hash of each device's shards (``TableMeta.route_hashes`` over the
+    shard-to-device map of the placement) -- through
+    ``parallel/shuffle.py`` ``exchange_rows``: one ``all_to_all`` a
+    lane, ``exchange_capacity`` rows a (source, destination) block,
+    round ``round`` of them.  What the device receives is a batch
+    whose row mask is the received validity, and goes into its table by
+    the build's own step (``build_join_build``, not a copy of it).
+    ``counts``: ``SENT`` / ``RECEIVED`` / ``LEFT`` -- ``LEFT`` > 0 on
+    any device asks for another round."""
+    from citus_tpu.catalog.hashing import hash_int64
+    from citus_tpu.parallel.shuffle import exchange_rows
+
+    pre = _Prefix(node, param_names, xp)
+    key_fns = [compile_expr(k, xp) for k in node.key]
+    build = build_join_build(node, param_names, xp)
+    n_own = len(node.names)
+
+    # named for its kernel slot: the XLA module in a device trace is
+    # jit_join_exchange
+    def join_exchange(table, child_tables, bounds, cols, valids, row_mask,
+                      rnd):
+        N = row_mask.shape[0]
+        env = pre.env(cols, valids)
+        mask = pre.own_filter(env, row_mask)
+        keys, ok = _key_lanes(xp, key_fns, env, (N,))
+        h = hash_int64(keys[hash_lane][0], xp)
+        target = (h[:, None] >= bounds[None, 1:]).sum(axis=1, dtype=np.int32)
+        whole = lambda a, dt: xp.broadcast_to(xp.asarray(a, dt), (N,))
+        lanes = tuple(whole(c, c.dtype) for c in cols[:n_own]) \
+            + tuple(whole(v, bool) for v in valids[:n_own])
+        received, rvalid, counts = exchange_rows(
+            lanes, target, mask & ok, n_dev,
+            exchange_capacity(N, n_dev, block_rows), rnd)
+        table = build(table, child_tables,
+                      received[:n_own] + tuple(cols[n_own:]),
+                      received[n_own:] + tuple(valids[n_own:]), rvalid)
+        return table, counts
+    return join_exchange
+
+
 def build_join_probe(node: JoinNode, param_names: tuple, xp,
-                     block_rows: Optional[int] = None) -> Callable:
+                     block_rows: Optional[int] = None,
+                     order: Optional[str] = None) -> Callable:
     """The probe step of the root ``node``: (child_tables, cols, valids,
     row_mask, round) -> (block cols, block valids, block mask, counts).
-    Every row of the batch looks into its children's tables; the rows
-    that matched and passed the node's filter are packed, and round
-    ``r`` hands on the ``r``-th ``block_rows`` of them (a power of two
-    from the batch's bucket where not given) with the columns
-    ``node.out`` names -- the node's own and the payload gathered from
-    the tables -- and the cross-relation conjuncts decided.  ``counts``
-    (``PACKED`` / ``MATCHED`` / ``OUT``) say whether another round is
-    due: ``PACKED`` > (r + 1) x ``block_rows``."""
+    The rows that pass the node's filter and have a partner in every
+    child are packed, and round ``r`` hands on the ``r``-th
+    ``block_rows`` of them (a power of two from the batch's bucket
+    where not given) with the columns ``node.out`` names -- the node's
+    own and the payload gathered from the tables -- and the
+    cross-relation conjuncts decided.  ``counts`` (``PACKED`` /
+    ``MATCHED`` / ``OUT`` / ``LOOKED``) say whether another round is
+    due: ``PACKED`` > (r + 1) x ``block_rows``.
+
+    Where the lookup stands follows from what the batch shows
+    (``order`` None; a name pins it, for a measurement): ``lookup``
+    first looks every row of the batch up and packs the rows that
+    matched and passed the filter -- one gather a row and child, few
+    rounds however little the filter keeps back; ``filter`` first packs
+    the rows the node's own filter keeps and looks only the block's
+    rows up.  The kernel holds both and takes the second for a batch
+    whose filtered rows fit ONE block (a gather a block row instead of
+    a batch row: TPC-H Q12 keeps a row in 190, Q3 one in two)."""
     from jax import lax
 
     pre = _Prefix(node, param_names, xp)
     post_fn = compile_expr(node.post_filter, xp) \
         if node.post_filter is not None else None
     params = tuple(param_names)
+    if pre.filter_fn is None or not node.children:
+        order = "lookup"
 
     # named for its kernel slot: the XLA module in a device trace is
     # jit_join_probe
@@ -423,12 +509,39 @@ def build_join_probe(node: JoinNode, param_names: tuple, xp,
         N = row_mask.shape[0]
         C = block_capacity(N, block_rows)
         env = pre.env(cols, valids)
-        through, found, probes = pre.first_pair(env, row_mask, child_tables)
-        order, D = _pack(xp, pre.own_filter(env, through), C)
-        at = lax.dynamic_slice(order, (rnd * C,), (C,))
-        live = rnd * C + xp.arange(C, dtype=np.int32) < D
-        live, slots = pre.later_pairs(at, live, probes, child_tables)
-        block = {n: (v[at] if xp.ndim(v) else v, m[at] if xp.ndim(m) else m)
+        own = pre.own_filter(env, row_mask)
+        lane = rnd * C + xp.arange(C, dtype=np.int32)
+        take = lambda a, at: a[at] if xp.ndim(a) else a
+
+        def lookup_first(_):
+            through, found, probes = pre.first_pair(env, row_mask,
+                                                    child_tables)
+            packed, D = _pack(xp, own & through, C)
+            at = lax.dynamic_slice(packed, (rnd * C,), (C,))
+            live, slots = pre.later_pairs(at, lane < D, probes, child_tables)
+            matched = xp.where(rnd == 0, found.sum(dtype=np.int32), 0)
+            return at, live, tuple(slots), D, matched, np.int32(N)
+
+        def filter_first(_):
+            packed, D = _pack(xp, own, C)
+            at = lax.dynamic_slice(packed, (rnd * C,), (C,))
+            kept = lane < D
+            penv = {n: (take(v, at), take(m, at)) for n, (v, m) in env.items()}
+            through, found, probes = pre.first_pair(penv, kept, child_tables)
+            live, slots = pre.later_pairs(xp.arange(C, dtype=np.int32),
+                                          through, probes, child_tables)
+            return (at, live, tuple(slots), D, found.sum(dtype=np.int32),
+                    kept.sum(dtype=np.int32))
+
+        if order == "lookup":
+            picked = lookup_first(None)
+        elif order == "filter":
+            picked = filter_first(None)
+        else:
+            picked = lax.cond(own.sum(dtype=np.int32) <= C, filter_first,
+                              lookup_first, None)
+        at, live, slots, D, matched, looked = picked
+        block = {n: (take(v, at), take(m, at))
                  for n, (v, m) in env.items() if n not in params}
         block = pre.child_payloads(block, slots, child_tables)
         if post_fn is not None:
@@ -441,7 +554,6 @@ def build_join_probe(node: JoinNode, param_names: tuple, xp,
             v = xp.broadcast_to(xp.asarray(v), (C,))
             out_cols.append(v)
             out_valids.append(xp.broadcast_to(_as_mask(xp, m, v), (C,)))
-        counts = xp.stack([D, found.sum(dtype=np.int32),
-                           live.sum(dtype=np.int32)])
+        counts = xp.stack([D, matched, live.sum(dtype=np.int32), looked])
         return tuple(out_cols), tuple(out_valids), live, counts
     return join_probe

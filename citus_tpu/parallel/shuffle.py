@@ -30,33 +30,64 @@ from citus_tpu.parallel.mesh import SHARD_AXIS
 
 
 def _pack_blocks(values: tuple, target: jnp.ndarray, mask: jnp.ndarray,
-                 n_dev: int, capacity: int):
+                 n_dev: int, capacity: int, rnd=0):
     """Arrange one device's rows into [n_dev, C] send blocks by target.
 
-    Returns (packed values tuple, packed validity, per-dest counts).
-    Rows beyond capacity for their destination are dropped and counted
-    in the overflow total (caller checks).
+    Returns (packed values tuple, packed validity, overflow): block
+    ``d`` holds the ``rnd``-th ``capacity`` rows addressed to device
+    ``d``, in their order; ``overflow`` counts the rows that no round up
+    to this one has taken (the caller answers with a further round, or
+    with a larger capacity).  One sort of the row positions and a
+    contiguous slice a destination: a scatter is a serial loop on a TPU.
     """
     n = target.shape[0]
-    tgt = jnp.where(mask, target, n_dev)  # invalid rows -> virtual bucket
-    order = jnp.argsort(tgt, stable=True)
-    sorted_tgt = tgt[order]
-    # rank of each sorted row within its destination segment
-    start = jnp.searchsorted(sorted_tgt, jnp.arange(n_dev + 1))
-    counts = start[1:n_dev + 1] - start[:n_dev]
-    rank = jnp.arange(n) - start[sorted_tgt.clip(0, n_dev - 1)]
-    dest_ok = (sorted_tgt < n_dev) & (rank < capacity)
-    slot = sorted_tgt.clip(0, n_dev - 1) * capacity + rank.clip(0, capacity - 1)
-    total = n_dev * capacity
-    packed_valid = jnp.zeros(total, bool).at[slot].set(dest_ok, mode="drop")
-    packed = []
-    for v in values:
-        sv = v[order]
-        buf = jnp.zeros(total, v.dtype).at[slot].set(
-            jnp.where(dest_ok, sv, jnp.zeros((), v.dtype)), mode="drop")
-        packed.append(buf.reshape(n_dev, capacity))
-    overflow = jnp.sum(jnp.maximum(counts - capacity, 0))
-    return tuple(packed), packed_valid.reshape(n_dev, capacity), overflow
+    bits = max(1, (n - 1).bit_length())
+    pos = jnp.arange(n, dtype=np.int32)
+    tgt = jnp.where(mask, target, n_dev).astype(np.int32)  # invalid -> virtual bucket
+    if (n_dev + 1) << bits < 2 ** 31:
+        # destination above position in one int32 lane: one operand
+        order = jax.lax.sort((tgt << bits) | pos) & np.int32((1 << bits) - 1)
+    else:
+        order = jnp.argsort(tgt, stable=True).astype(np.int32)
+    # where each destination's rows start among the sorted ones
+    counts = jnp.stack([jnp.sum(tgt == d, dtype=np.int32)
+                        for d in range(n_dev)])
+    start = jnp.cumsum(counts) - counts
+    # room for a whole block after the last row: a slice never clamps
+    order = jnp.concatenate([order, jnp.zeros((capacity,), np.int32)])
+    first = rnd * capacity
+    at = jnp.stack([jax.lax.dynamic_slice(
+        order, (jnp.minimum(start[d] + first, n),), (capacity,))
+        for d in range(n_dev)])
+    lane = first + jnp.arange(capacity, dtype=np.int32)
+    packed_valid = lane[None, :] < counts[:, None]
+    packed = tuple(jnp.where(packed_valid, v[at], jnp.zeros((), v.dtype))
+                   for v in values)
+    overflow = jnp.sum(jnp.maximum(counts - (first + capacity), 0))
+    return packed, packed_valid, overflow
+
+
+def exchange_rows(values: tuple, target, mask, n_dev: int, capacity: int,
+                  rnd=0):
+    """One device's half of an ``all_to_all`` exchange, for a body that
+    runs under ``shard_map`` over ``SHARD_AXIS``: its rows ``values``
+    (arrays ``[N]``) that ``mask`` keeps go to the devices ``target``
+    names, ``capacity`` rows a (source, destination) block, round
+    ``rnd`` of them.  -> (the received lanes ``[n_dev * capacity]``,
+    their validity, int32 ``[3]``: rows sent this round, rows this
+    device received, rows no round has taken yet).  One ``all_to_all`` a
+    lane; nothing is dropped: what a block cannot hold waits for the
+    next round."""
+    packed, pvalid, overflow = _pack_blocks(values, target, mask, n_dev,
+                                            capacity, rnd)
+    swap = lambda v: jax.lax.all_to_all(
+        v, SHARD_AXIS, split_axis=0, concat_axis=0).reshape(-1)
+    received = tuple(swap(v) for v in packed)
+    rvalid = swap(pvalid)
+    counts = jnp.stack([pvalid.sum(dtype=np.int32),
+                        rvalid.sum(dtype=np.int32),
+                        overflow.astype(np.int32)])
+    return received, rvalid, counts
 
 
 def build_repartition(mesh: Mesh, n_cols: int, capacity: int):

@@ -32,8 +32,9 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+from citus_tpu import types as T
 from citus_tpu.planner.bound import (
-    BBinOp, BCast, BExpr, BLiteral, BParam, BScale, BUnOp,
+    BBinOp, BCast, BColumn, BDictMask, BExpr, BLiteral, BParam, BScale, BUnOp,
 )
 
 #: param_specs source marker: the stored value is already physical
@@ -47,13 +48,21 @@ _LOGIC_OPS = ("and", "or")
 _HOIST_OPS = ("=", "<>", "<", "<=", ">", ">=", "+", "-", "*", "/", "%")
 
 
-def hoist_literals(expr: BExpr, start: int) -> tuple:
+#: words up to which a text IN list is hoisted as equalities
+IN_LIST_MAX = 8
+
+
+def hoist_literals(expr: BExpr, start: int, in_lists: bool = False) -> tuple:
     """``expr`` with the literal operands of its comparisons and
     arithmetic replaced by ``BParam``s numbered from ``start``:
     ``(generic_expr, specs, values)``, the specs ``(type,
     PHYSICAL_SRC)`` and the values bound-level physical, positionally
     matching.  Two expressions that differ in those literals alone give
-    one ``generic_expr``."""
+    one ``generic_expr``.  With ``in_lists`` a text column's ``IN`` of
+    up to ``IN_LIST_MAX`` words of its dictionary (``BDictMask``, whose
+    mask is part of the kernel) becomes the OR of as many equalities on
+    the column's codes, each code hoisted: two lists of equally many
+    words give one ``generic_expr``."""
     specs: list = []
     values: list = []
 
@@ -87,6 +96,18 @@ def hoist_literals(expr: BExpr, start: int) -> tuple:
         if isinstance(e, (BScale, BCast)):
             op = rewrite(e.operand, hoistable)
             return e if op is e.operand else dataclasses.replace(e, operand=op)
+        if in_lists and isinstance(e, BDictMask) \
+                and isinstance(e.operand, BColumn) \
+                and 0 < sum(e.mask) <= IN_LIST_MAX:
+            out = None
+            for code, on in enumerate(e.mask):
+                if on:
+                    eq = BBinOp("=", e.operand,
+                                hoist(BLiteral(code, e.operand.type)),
+                                T.BOOL_T)
+                    out = eq if out is None else BBinOp("or", out, eq,
+                                                        T.BOOL_T)
+            return out
         return e
 
     return rewrite(expr, False), specs, values

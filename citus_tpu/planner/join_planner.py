@@ -478,14 +478,22 @@ def _repartition_spec(bj: BoundJoinSelect) -> Optional[tuple]:
 
 @dataclass
 class DeviceJoinTree:
-    """A colocated inner equi-join as the device runs it
-    (``ops/join.py``): the relations as a tree rooted at the one that
-    is streamed and probed; every other relation is built into a lookup
-    table keyed by the edge to its parent."""
+    """An inner equi-join as the device runs it (``ops/join.py``): the
+    relations as a tree rooted at the one that is streamed and probed;
+    every other relation is built into a lookup table keyed by the edge
+    to its parent.  Colocated, or a single-hash repartition
+    (``exchanged``): the root stays where its shards lie and the other
+    distributed relation's rows travel to the device that owns the
+    root's shard their key hashes to."""
     root: str
     parent: dict        # alias -> its parent's alias
     edge: dict          # alias -> (its own key exprs, its parent's)
     builds: list        # the build nodes, children before parents
+    # a single-hash repartition: (the build node whose rows are
+    # EXCHANGED between the devices on its edge's key, the lane of that
+    # key the root is distributed on); None = every relation meets its
+    # partners where it lies (colocated, replicated)
+    exchanged: Optional[tuple] = None
 
     def children(self, alias: str) -> list:
         return [a for a in self.builds if self.parent[a] == alias]
@@ -502,6 +510,39 @@ def _device_key_type(t: T.ColumnType) -> bool:
         T.DATE, T.BOOL, T.TIMESTAMP, T.TIMESTAMPTZ, T.TIME)
 
 
+def _exchange_of(bj: BoundJoinSelect, rel_rows: dict):
+    """-> (root, exchanged, key lane) of a single-hash repartition the
+    device runs, or the reason (a string) it does not: the two
+    distributed relations meet in ONE inner step, one of them (the
+    root) is hash-distributed on its side of a key lane that both hold
+    as the same integer or date type -- so the catalog's hash of the
+    other's lane names the root's shard -- and the root is the larger
+    (the many side of a many-to-one join)."""
+    qualified = bj.binder.qualified
+    spec = bj.repartition_spec
+    if spec is None:
+        return "several steps between distributed relations"
+    tables = dict(bj.rels)
+    left, right, lks, rks = spec
+    sides = []
+    for mine, other, my_keys, their_keys in ((left, right, lks, rks),
+                                             (right, left, rks, lks)):
+        d = _dist_col_expr(mine, tables[mine], qualified)
+        for lane, (k, o) in enumerate(zip(my_keys, their_keys)):
+            if k == d and isinstance(o, BColumn) and o.type == k.type \
+                    and (k.type.is_integer or k.type.kind == T.DATE):
+                sides.append((mine, other, lane))
+                break
+    if not sides:
+        return "neither side is distributed on the join key " \
+               "(a dual repartition)"
+    root, exchanged, lane = max(sides, key=lambda s: rel_rows.get(s[0], 0))
+    if rel_rows.get(exchanged, 0) > rel_rows.get(root, 0):
+        return f"{exchanged}, the relation off the join key, is the " \
+               f"larger: the build side would not be unique"
+    return root, exchanged, lane
+
+
 def plan_device_join(bj: BoundJoinSelect, rel_rows: dict):
     """-> the ``DeviceJoinTree`` of a join the device can run, or the
     reason (a string) it goes to the host path: every step an inner
@@ -510,9 +551,17 @@ def plan_device_join(bj: BoundJoinSelect, rel_rows: dict):
     aggregate above.  The root is the distributed relation with the
     most rows (``rel_rows``, from the catalog; the later in FROM on a
     tie) -- the many side of a many-to-one join, which the build then
-    checks row by row; without a distributed relation, the largest."""
+    checks row by row; without a distributed relation, the largest.  Of
+    a ``repartition`` strategy the single-hash kind (``_exchange_of``):
+    the root is the relation distributed on the key, and the other
+    distributed relation a build whose edge is marked exchanged."""
     qualified = bj.binder.qualified
-    if bj.strategy != "colocated":
+    exchange = None
+    if bj.strategy == "repartition":
+        exchange = _exchange_of(bj, rel_rows)
+        if isinstance(exchange, str):
+            return exchange
+    elif bj.strategy != "colocated":
         return f"strategy {bj.strategy}"
     if not bj.has_aggs:
         return "no aggregate above the join"
@@ -535,8 +584,8 @@ def plan_device_join(bj: BoundJoinSelect, rel_rows: dict):
                                 list(s.left_keys))
     order = [a for a, _ in bj.rels]
     dist = [a for a, t in bj.rels if t.is_distributed]
-    root = max(dist or order,
-               key=lambda a: (rel_rows.get(a, 0), order.index(a)))
+    root = exchange[0] if exchange else max(
+        dist or order, key=lambda a: (rel_rows.get(a, 0), order.index(a)))
     # the steps' tree (each relation hangs on an earlier one), re-rooted
     near: dict = {a: [] for a in order}
     for a, (b, mine, theirs) in links.items():
@@ -549,4 +598,11 @@ def plan_device_join(bj: BoundJoinSelect, rel_rows: dict):
                 parent[b] = a
                 edge[b] = (theirs, mine)
                 walk_order.append(b)
-    return DeviceJoinTree(root, parent, edge, walk_order[:0:-1])
+    tree = DeviceJoinTree(root, parent, edge, walk_order[:0:-1])
+    if exchange:
+        _, exchanged, lane = exchange
+        if parent.get(exchanged) != root:
+            return "the exchanged relation does not hang on the probe's"
+        # the spec's lanes are the connecting step's, and so the edge's
+        tree.exchanged = (exchanged, lane)
+    return tree

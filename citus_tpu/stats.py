@@ -33,10 +33,19 @@ class StatCounters:
         # the device join (executor/join_device.py, per statement): the
         # rounds of its scan loop, rows its builds put into lookup
         # tables, padded rows of every probe round, probe rows that
-        # found a build row (before the probe side's own filter), rows
+        # found a build row (of the rows looked up: all of them, or where
+        # the probe side's own filter stands first the rows it kept), rows
         # handed to the aggregate, further rounds of blocks the
         # survivors overflowed, bytes of the lookup tables resident at
-        # once; and the joins the device backend answered on the host
+        # once; and the joins the device backend answered on the host;
+        # of a single-hash repartition: the build relation's rows sent
+        # through the all_to_all exchange and their bytes as they
+        # travel, the rows the fullest device received, further rounds
+        # for rows a (source, destination) block could not hold
+        "join_rows_exchanged",
+        "join_bytes_exchanged",
+        "join_rows_received_max_device",
+        "join_exchange_overflow_rounds",
         "join_dispatches",
         "join_rows_built",
         "join_rows_probed",

@@ -181,8 +181,8 @@ def test_smoke_rehearsal_passes_every_leg(tmp_path, n_dev):
     assert report["rehearsal"] is True and report["platform"] == "cpu"
     legs = {leg["leg"].split()[0]: leg for leg in report["legs"]}
     single = {"1", "2", "3", "3b", "4", "5", "5b", "5c", "5d", "5e", "5f",
-              "6", "7c"}
-    assert set(legs) == (single | {"7a", "7b", "7d", "7e"} if n_dev > 1
+              "6", "7a", "7c"}
+    assert set(legs) == (single | {"7b", "7d", "7e"} if n_dev > 1
                          else single)
     assert all(leg["ok"] for leg in legs.values())
     scan_slot = "mesh_run" if n_dev > 1 else "jit_fused"
@@ -205,8 +205,17 @@ def test_smoke_rehearsal_passes_every_leg(tmp_path, n_dev):
     assert legs["5f"]["hash_disjoint_on"] == \
         ("l_orderkey" if n_dev > 1 else None)
     assert legs["6"]["kernel_slot"] == "jit_filter"
-    if n_dev > 1:
-        assert "devjoin" in legs["7a"]["shuffle"]
+    # the single-hash repartition join runs on the device too: every
+    # order exchanged once between several devices, none on one
+    assert legs["7a"]["shuffle"] == \
+        ("all_to_all:device" if n_dev > 1 else "local")
+    assert legs["7a"]["kernel_slot"] == \
+        ("jit_join_exchange" if n_dev > 1 else "jit_join_probe")
+    assert legs["7a"]["rows_built"] == 3_000
+    assert legs["7a"]["rows_exchanged"] == (3_000 if n_dev > 1 else 0)
+    assert "explain Join: on device, probe l;" in p.stdout \
+        and f"exchange: o on o_orderkey, {3_000 if n_dev > 1 else 0} " \
+            f"rows" in p.stdout
     # the colocated join runs on the device whatever the device count
     assert legs["7c"]["kernel_slot"] == "jit_join_probe"
     assert legs["7c"]["rows_probed"] >= 12_000 > legs["7c"]["rows_out"] > 0
