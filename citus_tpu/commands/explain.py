@@ -294,6 +294,11 @@ def _run_analyze(cl, stmt: A.Explain) -> list[str]:
         if "stream_window_peak_bytes" in pl:
             line += (f", stream window peak "
                      f"{pl['stream_window_peak_bytes']} bytes")
+        if "mesh_round_bytes_copied" in pl:
+            # a mesh round's members go to their devices as they stand:
+            # what the host still copied (re-pads, fillers) of the bytes
+            line += (f", stacked: {pl['mesh_round_bytes_copied']} of "
+                     f"{pl.get('h2d_bytes', 0)} bytes copied on the host")
         if "scan_lanes" in pl:
             # int64 scan columns the table's statistics bound inside
             # int32: the device holds them, and reads them, at 32 bits

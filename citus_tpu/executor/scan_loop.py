@@ -212,8 +212,8 @@ class OneDevice(Lanes):
 
 
 def _repad_batch(b: ShardBatch, bucket: int) -> ShardBatch:
-    """Grow a padded batch to a larger bucket (mesh rounds stack, so all
-    members share one shape)."""
+    """Grow a padded batch to a larger bucket (the members of a mesh
+    round share one shape)."""
     pad = bucket - b.padded_rows
     if pad <= 0:
         return b
@@ -225,15 +225,22 @@ def _repad_batch(b: ShardBatch, bucket: int) -> ShardBatch:
 
 class MeshPlacement(Lanes):
     """A round is up to ``n_dev`` host batches (a member may be None: a
-    device with nothing this round), re-padded to the round's largest
-    bucket, filled up with empty batches and stacked along the
-    shard axis: one ``(cols, valids, row_mask)`` triple of device-sharded
-    stacks, a different structure than the one-device ShardBatch list, so
-    its cache entries key apart.  Parameters replicate across the shard
-    axis as ``[n_dev]`` stacks, put on the mesh once a query (never
-    cached: they change per execution).  The plan's narrow lanes are
-    converted on the chips after the put, each device its own row of
-    the stack and its own flag: no collective."""
+    device with nothing this round).  It is never assembled on the
+    host: member ``i``'s arrays go to the device that owns row ``i`` of
+    the round's sharding as they stand, and the ``[n_dev, bucket]``
+    arrays are made of those per-device pieces -- one ``(cols, valids,
+    row_mask)`` triple of device-sharded arrays, a different structure
+    than the one-device ShardBatch list, so its cache entries key
+    apart.  The host copies only what it must: a member cut at a
+    smaller bucket than the round's is re-padded, and a device that ran
+    dry takes a filler of padding alone, made once a scan and bucket,
+    kept here as HOST arrays and put afresh every round it is needed
+    (a device array would be donated away by the first ``jit_narrow``
+    that took it).  Parameters replicate across the shard axis as
+    ``[n_dev]`` stacks, put on the mesh once a query (never cached:
+    they change per execution).  The plan's narrow lanes are converted
+    on the chips after the put, each device its own row of the round
+    and its own flag: no collective."""
 
     def __init__(self, mesh, record: PipelineStats) -> None:
         from jax.sharding import NamedSharding, PartitionSpec
@@ -244,6 +251,7 @@ class MeshPlacement(Lanes):
         self.key_suffix = ("mesh", self.round_size)
         self.sharding = NamedSharding(mesh, PartitionSpec("shard"))
         self.rows_padded = 0    # rows of every round, padding included
+        self._fillers: dict = {}    # (table, scan columns, bucket) -> batch
 
     def bind(self, params) -> None:
         import jax
@@ -254,26 +262,52 @@ class MeshPlacement(Lanes):
                  tuple(np.stack([v] * n) for v in params[1])),
                 self.sharding)
 
-    def put(self, plan, members: list):
+    def _filler(self, plan, bucket: int) -> tuple:
+        """-> (a batch of padding alone, the host bytes making it
+        wrote: 0 where the scan has made this bucket's before)."""
+        table = plan.bound.table
+        key = (table.name, tuple(plan.scan_columns), bucket)
+        filler = self._fillers.get(key)
+        if filler is not None:
+            return filler, 0
+        filler = self._fillers[key] = empty_batch(table, plan, bucket, -1)
+        return filler, filler.nbytes
+
+    def _assemble(self, pieces: list):
+        """``pieces[i]``: a ``[1, bucket]`` host array for row ``i`` of
+        the round -> the ``[n_dev, bucket]`` device array, each piece
+        on the device the sharding gives its row (one transfer a
+        device, what ``device_put`` of the whole makes)."""
         import jax
+        return jax.make_array_from_callback(
+            (len(pieces), pieces[0].shape[1]), self.sharding,
+            lambda index: pieces[index[0].start or 0])
+
+    def put(self, plan, members: list):
         n_cols = range(len(plan.scan_columns))
         with _trace.span("stack") as sp:
             bucket = max(b.padded_rows for b in members if b is not None)
-            filler = empty_batch(plan.bound.table, plan, bucket, -1)
-            buf = [filler if b is None else _repad_batch(b, bucket)
-                   for b in members]
-            buf += [filler] * (self.round_size - len(buf))
-            cols = tuple(np.stack([b.cols[i] for b in buf]) for i in n_cols)
-            valids = tuple(np.stack([b.valids[i] for b in buf])
-                           for i in n_cols)
-            mask = np.stack([b.row_mask for b in buf])
-            nbytes = _nbytes(cols) + _nbytes(valids) + mask.nbytes
+            copied = 0
+            buf = []
+            for b in members + [None] * (self.round_size - len(members)):
+                if b is None:
+                    b, fresh = self._filler(plan, bucket)
+                    copied += fresh
+                elif b.padded_rows < bucket:
+                    b = _repad_batch(b, bucket)
+                    copied += b.nbytes
+                buf.append(b)
+            cols = [[b.cols[i][None] for b in buf] for i in n_cols]
+            valids = [[b.valids[i][None] for b in buf] for i in n_cols]
+            mask = [b.row_mask[None] for b in buf]
+            nbytes = sum(b.nbytes for b in buf)
+            self.record.tally("mesh_round_bytes_copied", copied, add=True)
             if sp.recording:
-                sp.set(bytes=nbytes)
+                sp.set(bytes=nbytes, copied=copied)
         with _trace.span("h2d") as sp:
-            dcols = tuple(jax.device_put(c, self.sharding) for c in cols)
-            dvalids = tuple(jax.device_put(v, self.sharding) for v in valids)
-            dmask = jax.device_put(mask, self.sharding)
+            dcols = tuple(self._assemble(c) for c in cols)
+            dvalids = tuple(self._assemble(v) for v in valids)
+            dmask = self._assemble(mask)
             if sp.recording:
                 sp.set(bytes=nbytes)
         if self._marked:

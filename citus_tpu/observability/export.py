@@ -110,6 +110,7 @@ METRIC_HELP = {
     "scan_lanes": "int64 scan columns of the device scans that ran",
     "scan_lanes_narrow": "those the table statistics bound inside int32 and the device held at 32 bits",
     "scan_lanes_belied": "device scans a batch of which held a value outside those bounds (re-run at full width)",
+    "mesh_round_bytes_copied": "host bytes the streamed mesh rounds copied before the put (re-padded short members, new fillers)",
     "agg_partials": "partial states the plans of aggregate queries computed",
     "agg_partials_proved_away": "overflow guards and null counts the table statistics proved redundant",
     "hash_slots": "slots of the device hash tables made",

@@ -245,6 +245,10 @@ class StatCounters:
         "scan_lanes",
         "scan_lanes_narrow",
         "scan_lanes_belied",
+        # streamed mesh rounds (scan_loop.py MeshPlacement.put): host
+        # bytes the round had to copy before its members went to their
+        # devices as they stand -- a short member's re-pad, a new filler
+        "mesh_round_bytes_copied",
         # aggregate queries: partial states their plans compute, and
         # the overflow guards and per-argument NULL counts that
         # planner/physical.py lower_aggregates proved away from the
