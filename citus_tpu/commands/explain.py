@@ -288,6 +288,11 @@ def _run_analyze(cl, stmt: A.Explain) -> list[str]:
                      f"{pl['decode_bytes_in_place'] / decoded:.3f}")
         if tr.find("stripe_read") is not None:
             line += ", " + _decode_split(tr)
+            if "decode_streams" in pl:
+                # batches of different shards (a mesh's devices) decoded
+                # side by side, and the wall time two or more were
+                line += (f", {pl['decode_streams']} streams at once, "
+                         f"{pl.get('decode_overlap_ms', 0)} ms overlapped")
         if "fused_dispatches" in pl:
             # the 1-dispatch-per-batch claim, visible per statement
             line += f", fused dispatches {pl['fused_dispatches']}"

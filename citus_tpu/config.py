@@ -97,8 +97,10 @@ class ExecutorSettings:
     # dispatching thread (no host/device overlap).
     executor_prefetch_depth: int = 2
     # Worker threads for the native stripe read+decompress pool
-    # (storage/reader.py) — citus.decode_threads.  0 = auto:
-    # min(8, cpu_count).
+    # (storage/reader.py), per native call — citus.decode_threads.
+    # 0 = auto: the cores this process may use, at most 8, divided by
+    # the threads that decode a streamed scan's batches at once
+    # (executor/pipeline.py: one a stream while every call keeps four).
     decode_threads: int = 0
     # Prefer replica (non-primary) placements for reads — the
     # citus.use_secondary_nodes='always' analog; failover to the

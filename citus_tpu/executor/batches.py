@@ -18,6 +18,7 @@ into fresh arrays and copied to the same place.
 
 from __future__ import annotations
 
+import functools
 import os
 from dataclasses import dataclass
 from typing import Iterator, NamedTuple, Optional
@@ -412,19 +413,24 @@ class _Landing:
     def finish(self, shard_index: int) -> ShardBatch:
         n_rows, padded_rows = self.n_rows, self.padded_rows
         with _trace.span("pad") as sp:
-            valids = [np.ones(padded_rows, bool) for _ in self.columns]
+            # shared and read-only until a column shows a NULL
+            valids = [_all_true(padded_rows)] * len(self.columns)
             copied = 0
             for at, p in self.copies:
                 for c, v in p.values.items():
                     k = self._index[c]
                     self.values[k][at:at + p.rows] = v
                     if p.validity[c] is not None:
+                        if not valids[k].flags.writeable:
+                            valids[k] = np.ones(padded_rows, bool)
                         valids[k][at:at + p.rows] = p.validity[c]
                     copied += v.nbytes
             for v in self.values:
                 v[n_rows:] = 0
-            row_mask = np.ones(padded_rows, bool)
-            row_mask[n_rows:] = False
+            row_mask = _all_true(padded_rows)
+            if n_rows < padded_rows:
+                row_mask = np.ones(padded_rows, bool)
+                row_mask[n_rows:] = False
             out = ShardBatch(tuple(self.values), tuple(valids), row_mask,
                              n_rows, padded_rows, shard_index,
                              self.bytes_in_place, copied)
@@ -432,6 +438,19 @@ class _Landing:
                 sp.set(bytes_in=copied, bytes_in_place=self.bytes_in_place,
                        bytes_out=out.nbytes)
         return out
+
+
+@functools.lru_cache(maxsize=32)
+def _all_true(n: int) -> np.ndarray:
+    """THE read-only array of ``n`` True: the validity of every column
+    without a NULL in the batch and the row mask of every full batch of
+    that bucket, whoever decodes it.  A bucket is a power of two up to
+    the batch limit, so a handful are ever made; a fresh one a column
+    and batch was 32 MB of new pages a batch for ``pad`` to fault in
+    (PERF.md section 7, PR 45)."""
+    a = np.ones(n, bool)
+    a.flags.writeable = False
+    return a
 
 
 def empty_batch(table: TableMeta, plan: PhysicalPlan, padded_rows: int,

@@ -33,7 +33,9 @@ from citus_tpu.executor.batches import (
 )
 from citus_tpu.executor.finalize import finalize_groups, order_and_limit, project_rows
 from citus_tpu.executor.kernel_cache import get_kernel, jit_compile
-from citus_tpu.executor.pipeline import PipelineStats, dispatch_remote_tasks
+from citus_tpu.executor.pipeline import (
+    PipelineStats, dispatch_remote_tasks, one_after_another,
+)
 from citus_tpu.executor.scan_loop import (
     ScanLanesBelied, Step, _block_ready, _nbytes, _prefetch_depth,
     choose_affine_placement, choose_placement, drive,
@@ -208,40 +210,50 @@ def _iter_padded_batches(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     (reference analog: ColumnarReadNextRow never materializes a stripe,
     columnar_reader.c:323).  A full batch is exactly its bucket; only a
     shard's last batch is padded, to its own power-of-two bucket, so
-    the per-shape jit cache stays small.  Books real against padded
+    the per-shape jit cache stays small.  Every shard is a stream of
+    its own (``pipeline.one_after_another``): the sequence is shard by
+    shard in ``plan.shard_indexes`` order, a shard's batches in file
+    order, whichever threads decode them.  Books real against padded
     rows, and the bytes decoded in place against those copied there:
     process counters, and the statement's EXPLAIN pad_share and
-    decoded-in-place share."""
+    decoded-in-place share -- as the batches pass in their order, on
+    the one thread that pulls this generator."""
     from citus_tpu.testing.faults import FAULTS
 
-    def shard_batches():
-        for si in plan.shard_indexes:
-            FAULTS.hit("dispatch_task", f"{plan.bound.table.name}:{si}")
-            GLOBAL_COUNTERS.bump("tasks_dispatched")
-            yield from load_padded_batches(
-                cat, plan, si,
-                min_batch_rows=settings.executor.min_batch_rows,
-                prefer_secondary=settings.executor.use_secondary_nodes)
+    def shard_batches(si):
+        # one decode_batch span per batch, on whichever thread pulls
+        # this shard's stream (a producer of the prefetcher, its
+        # pulling thread, the caller at depth 0), closed BEFORE the
+        # yield: a span held across a yield would stay on the puller's
+        # span stack while the consumer runs.  The pull that finds the
+        # shard exhausted is a last span without a batch (eof).
+        FAULTS.hit("dispatch_task", f"{plan.bound.table.name}:{si}")
+        GLOBAL_COUNTERS.bump("tasks_dispatched")
+        batches = load_padded_batches(
+            cat, plan, si,
+            min_batch_rows=settings.executor.min_batch_rows,
+            prefer_secondary=settings.executor.use_secondary_nodes)
+        try:
+            while True:
+                with _trace.span("decode_batch") as sp:
+                    hb = next(batches, None)
+                    if sp.recording:
+                        sp.set(thread=threading.current_thread().name)
+                        if hb is None:
+                            sp.set(eof=True)
+                        else:
+                            sp.set(shard_index=int(hb.shard_index),
+                                   rows=int(hb.n_rows), bytes=hb.nbytes)
+                if hb is None:
+                    return
+                yield hb
+        finally:
+            batches.close()
 
-    # one decode_batch span per batch, on whichever thread pulls this
-    # generator (the decode thread under the prefetcher), closed BEFORE
-    # the yield: a span held across a yield would stay on the puller's
-    # span stack while the consumer runs.  The pull that finds the
-    # stream exhausted is a last span without a batch (eof).
-    batches = shard_batches()
+    batches = one_after_another(
+        [shard_batches(si) for si in plan.shard_indexes])
     try:
-        while True:
-            with _trace.span("decode_batch") as sp:
-                hb = next(batches, None)
-                if sp.recording:
-                    sp.set(thread=threading.current_thread().name)
-                    if hb is None:
-                        sp.set(eof=True)
-                    else:
-                        sp.set(shard_index=int(hb.shard_index),
-                               rows=int(hb.n_rows), bytes=hb.nbytes)
-            if hb is None:
-                return
+        for hb in batches:
             record.tally("batch_rows_real", hb.n_rows, add=True)
             record.tally("batch_rows_padded", hb.padded_rows, add=True)
             record.tally("decode_bytes_in_place", hb.bytes_in_place, add=True)

@@ -25,16 +25,21 @@ Two pieces:
   on the collecting thread, not the loop; per-task failures fall back
   to the local pull path exactly like the serial dispatcher did.
 - ``prefetch_batches`` / ``HostPrefetcher``: a bounded read-ahead
-  queue fed by a background decode worker producing padded
-  ``ShardBatch``es (chunk decompress, null decode, pad, stack) while
-  the device executes the previous round — backpressure at
-  ``citus.executor_prefetch_depth``, errors from the decode thread
-  re-raised at the consumer, prompt cancellation when the consumer
-  dies.  Depth 0 decodes inline (the pre-pipeline serial behavior).
+  queue filled by a background thread with padded ``ShardBatch``es
+  (chunk decompress, null decode, pad) while the device executes the
+  previous round — backpressure at ``citus.executor_prefetch_depth``,
+  errors from the decode side re-raised at the consumer, prompt
+  cancellation when the consumer dies.  Depth 0 decodes inline (the
+  pre-pipeline serial behavior).  A scan's shards (a mesh's devices)
+  are independent streams (``one_after_another`` / ``in_rounds``):
+  where there are several and the cores allow it, producer threads
+  decode several at once and the background thread hands the batches
+  on in exactly the order one thread would have made them.
 """
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 from collections import deque
@@ -50,12 +55,14 @@ class PipelineStats:
     """One execution's record: everything ONE run of a statement counted
     and logged, made where the run starts and handed down by argument
     (the cached plan is shared by every caller of its family and keeps
-    compiled kernels alone).  The decode thread owns host_decode_s /
-    device_stalls and the four per-batch figures, the consumer owns the
-    rest -- disjoint writers, read only after the pipeline is joined."""
+    compiled kernels alone).  The decode side owns host_decode_s /
+    device_stalls (its pulling thread alone writes them), the consumer
+    owns the rest; ``tally`` may be called from any thread (a mesh's
+    device streams book their batches on the producers that decode
+    them).  Read only after the pipeline is joined."""
 
     def __init__(self) -> None:
-        self.host_decode_s = 0.0   # time inside the host decode iterator
+        self.host_decode_s = 0.0   # thread-time inside the host decode iterators
         self.device_s = 0.0        # H2D transfer + kernel dispatch + sync
         self.h2d_bytes = 0         # bytes shipped host -> device
         self.host_stalls = 0       # consumer found the queue empty
@@ -65,6 +72,7 @@ class PipelineStats:
         # what becomes Result.explain["pipeline"], EXPLAIN ANALYZE's
         # Pipeline: / Direct: / Hash: lines and the execute span's attr
         self.figures: dict = {}
+        self._tally_mu = threading.Lock()
         # the load ledger's input (_finish_select, run_worker_task)
         self.task_times: list = []       # (shard index, rows, dispatch s)
         self.task_bytes: list = []       # (shard index, H2D bytes)
@@ -76,8 +84,9 @@ class PipelineStats:
         statement's ``figures[name]`` becomes ``amount`` (with ``add``,
         grows by it) and the counter ``name`` is bumped by the same."""
         from citus_tpu.executor.executor import GLOBAL_COUNTERS
-        self.figures[name] = amount + (self.figures.get(name, 0) if add
-                                       else 0)
+        with self._tally_mu:
+            self.figures[name] = amount + (self.figures.get(name, 0) if add
+                                           else 0)
         GLOBAL_COUNTERS.bump(name, amount)
         return amount
 
@@ -99,6 +108,13 @@ class PipelineStats:
             host_stalls=int(self.host_stalls),
             device_stalls=int(self.device_stalls))
 
+    def book_streams(self, streams: int, overlap_s: float) -> None:
+        """A streamed scan's decode side, once it is done: the threads
+        that decoded its batches side by side (1: one thread did) and
+        the wall time two or more of them were inside a batch."""
+        self.tally("decode_streams", streams)
+        self.tally("decode_overlap_ms", int(overlap_s * 1000), add=True)
+
     def rider(self) -> "PipelineStats":
         """A megabatched group's record as a rider other than the first
         takes it: the ONE device run's figures, its own copy (each rider
@@ -118,6 +134,12 @@ def read_ahead_depth(settings) -> int:
 # ------------------------------------------------- host/device overlap
 
 
+def _close_iter(it) -> None:
+    close = getattr(it, "close", None)
+    if close is not None:
+        close()
+
+
 class _InlineHostIter:
     """Depth-0 degenerate prefetcher: decode inline on the consumer
     thread (the serial pre-pipeline behavior), still timing the host
@@ -126,6 +148,8 @@ class _InlineHostIter:
     def __init__(self, source: Iterator, stats: Optional[PipelineStats]):
         self._source = iter(source)
         self._stats = stats
+        if stats is not None:
+            stats.book_streams(1, 0.0)      # the caller's thread, alone
 
     def __iter__(self):
         return self
@@ -139,23 +163,301 @@ class _InlineHostIter:
                 self._stats.host_decode_s += _perf() - t0
 
     def close(self) -> None:
-        close = getattr(self._source, "close", None)
-        if close is not None:
-            close()
+        _close_iter(self._source)
+
+
+class _Cancelled(BaseException):
+    """The prefetcher was closed under a pull of its source: unwinds
+    the pulling thread through the generators it was in."""
+
+
+# .producers: the ``_Producers`` of the HostPrefetcher whose pulling
+# thread this is (on every other thread: nothing)
+_pulling = threading.local()
+
+_ITEM, _DONE, _ERR = 0, 1, 2
+
+
+class _Streams:
+    """Iterators that share no state -- a scan's shards, a mesh's
+    devices -- behind ``takers``, one a stream, that hand out what the
+    stream would.  Whoever sequences the takers decides the order; this
+    only needs to know which it is: stream after stream, or
+    (``rounds``) one item of each in turn.  Pulled on a prefetcher's
+    pulling thread where the cores allow it, the streams are decoded by
+    that prefetcher's producers, several at once and ahead of their
+    turn; anywhere else each ``next`` of a taker is the stream's own,
+    on the caller's thread."""
+
+    def __init__(self, streams: list, rounds: bool):
+        self.streams = [iter(s) for s in streams]
+        self.rounds = rounds
+        self.takers = [_Taker(self, i) for i in range(len(self.streams))]
+        self._by: Optional[_Producers] = None
+        n = len(self.streams)
+        # the producers' side, under their lock once adopted
+        self.ready = [deque() for _ in range(n)]
+        self.busy = [False] * n     # a producer is inside next(stream)
+        self.ended = [False] * n    # the stream has nothing more to make
+        self.made = [0] * n         # items asked of it
+
+    def take(self, i: int):
+        if self._by is None:
+            pool = getattr(_pulling, "producers", None)
+            if pool is None or not pool.adopt(self):
+                return next(self.streams[i])
+            self._by = pool
+        return self._by.take(self, i)
+
+    def close(self) -> None:
+        if self._by is not None:
+            self._by.release(self)
+        for s in self.streams:
+            _close_iter(s)
+
+
+class _Taker:
+    __slots__ = ("_of", "_i")
+
+    def __init__(self, of: _Streams, i: int):
+        self._of, self._i = of, i
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        return self._of.take(self._i)
+
+
+def one_after_another(streams: list) -> Iterator:
+    """Every item of every stream, stream after stream (a scan's shards
+    in ``plan.shard_indexes`` order, a shard's batches in file order):
+    the sequence whatever thread made the items."""
+    turns = _Streams(streams, rounds=False)
+    try:
+        for taker in turns.takers:
+            yield from taker
+    finally:
+        turns.close()
+
+
+def in_rounds(streams: list) -> Iterator[list]:
+    """Round after round of one item a stream, None for a stream that
+    has ended, until every stream has: member ``i`` of round ``r`` is
+    stream ``i``'s ``r``-th item, whatever thread made it."""
+    turns = _Streams(streams, rounds=True)
+    try:
+        while True:
+            members = [next(taker, None) for taker in turns.takers]
+            if all(m is None for m in members):
+                return
+            yield members
+    finally:
+        turns.close()
+
+
+class _Producers:
+    """The threads ``citus-host-decode-<n>`` of one prefetcher: each
+    asks the stream whose next item is due soonest, and that nobody is
+    inside, for ONE item, then looks again -- so the streams advance in
+    the order their items will be taken, whatever the ratio of streams
+    to threads.  Started when the pulling thread first meets streams
+    worth more than one thread (``decode_producers``: the streams and
+    the usable cores decide), each under the statement's transaction
+    overlay and trace context, each telling the native pool that it
+    shares the cores.
+
+    Memory: items made and not yet taken plus items in the making never
+    exceed ``2 x threads`` -- one in the making and one waiting for its
+    turn a thread; a producer that finds the budget spent waits until
+    the pulling thread takes an item (or waits for one nobody has made:
+    a source that takes its streams in another order than it said)."""
+
+    def __init__(self, txn, trace_ctx):
+        self._txn, self._trace_ctx = txn, trace_ctx
+        self._cv = threading.Condition()
+        self._threads: list[threading.Thread] = []
+        self._adopted: list[_Streams] = []
+        self._cancelled = False
+        self._alive = 0          # items in the making or waiting
+        self._wanted = None      # (streams, i) the pulling thread waits for
+        self._inside = 0         # producers inside a next(stream)
+        self._overlap_from = 0.0
+        self._unsettled_s = 0.0  # producers' thread-time less the puller's waits
+        self.overlap_s = 0.0     # wall time with two or more inside
+        self.decoded: set = set()   # producers that made a batch
+
+    # ---- the pulling thread ----
+    def adopt(self, streams: _Streams) -> bool:
+        from citus_tpu.storage.reader import decode_producers
+        want = decode_producers(len(streams.streams))
+        if want < 2:
+            return False
+        with self._cv:
+            if self._cancelled:
+                raise _Cancelled()
+            self._adopted.append(streams)
+            while len(self._threads) < want:
+                t = threading.Thread(
+                    target=self._run, daemon=True,
+                    name=f"citus-host-decode-{len(self._threads) + 1}")
+                self._threads.append(t)
+                t.start()
+            self._cv.notify_all()
+        return True
+
+    def take(self, streams: _Streams, i: int):
+        ready = streams.ready[i]
+        with self._cv:
+            if not ready:
+                t0 = _perf()
+                self._wanted = (streams, i)
+                self._cv.notify_all()
+                while not ready:
+                    if self._cancelled:
+                        raise _Cancelled()
+                    self._cv.wait(0.5)
+                self._wanted = None
+                self._unsettled_s -= _perf() - t0
+            kind, val = ready[0]
+            if kind == _DONE:       # stays: every later take ends so
+                raise StopIteration
+            ready.popleft()
+            if kind == _ERR:
+                ready.append((_DONE, None))
+                raise val
+            self._alive -= 1
+            self._cv.notify_all()
+            return val
+
+    def release(self, streams: _Streams) -> None:
+        """Forget ``streams`` (exhausted, or closed under the
+        producers): returns once no producer is inside one of them."""
+        with self._cv:
+            if streams in self._adopted:
+                self._adopted.remove(streams)
+            while any(streams.busy):
+                self._cv.wait(0.5)
+            for q in streams.ready:
+                self._alive -= sum(kind == _ITEM for kind, _ in q)
+                q.clear()
+            self._cv.notify_all()
+
+    def settle(self) -> float:
+        """Thread-time the producers spent decoding since the last call,
+        less what the pulling thread spent waiting for them."""
+        with self._cv:
+            s, self._unsettled_s = self._unsettled_s, 0.0
+        return s
+
+    def stop(self) -> None:
+        with self._cv:
+            self._cancelled = True
+            self._cv.notify_all()
+
+    def join(self) -> None:
+        for t in self._threads:
+            t.join()
+
+    # ---- a producer ----
+    def _due(self):
+        """-> (streams, i) of the item due soonest among the streams
+        nobody is inside, or None: nothing to make, or no room.  The
+        item the pulling thread waits for is always due, room or not:
+        whatever order a source takes its streams in, it goes on."""
+        if self._wanted is not None:
+            streams, i = self._wanted
+            if not (streams.ready[i] or streams.busy[i] or streams.ended[i]):
+                return self._wanted
+        if self._alive >= 2 * len(self._threads):
+            return None
+        for streams in self._adopted:
+            free = [i for i in range(len(streams.streams))
+                    if not (streams.busy[i] or streams.ended[i])]
+            if free:
+                return streams, min(free, key=lambda i: (
+                    streams.made[i] if streams.rounds else 0, i))
+        return None
+
+    def _run(self) -> None:
+        from citus_tpu.storage.overlay import transaction_overlay
+        from citus_tpu.storage.reader import decode_pool_shared
+        with contextlib.ExitStack() as ctx:
+            ctx.enter_context(transaction_overlay(self._txn))
+            if self._trace_ctx is not None:
+                ctx.enter_context(_trace.activate(*self._trace_ctx))
+            while True:
+                due = self._claim()
+                if due is None:
+                    return
+                with decode_pool_shared(len(self._threads)):
+                    self._make(*due)
+
+    def _claim(self):
+        """Wait for an item to be due and take it on -> (streams, i);
+        None once the prefetcher is closed."""
+        with self._cv:
+            while not self._cancelled:
+                due = self._due()
+                if due is not None:
+                    break
+                self._cv.wait()
+            else:
+                return None
+            streams, i = due
+            streams.busy[i] = True
+            streams.made[i] += 1
+            self._alive += 1
+            self._inside += 1
+            if self._inside == 2:
+                self._overlap_from = _perf()
+        return due
+
+    def _make(self, streams: _Streams, i: int) -> None:
+        t0 = _perf()
+        try:
+            made = (_ITEM, next(streams.streams[i]))
+        except StopIteration:
+            made = (_DONE, None)
+        except BaseException as e:  # surfaces where the item was due
+            made = (_ERR, e)
+        t1 = _perf()
+        with self._cv:
+            self._inside -= 1
+            if self._inside == 1:
+                self.overlap_s += t1 - self._overlap_from
+            self._unsettled_s += t1 - t0
+            streams.busy[i] = False
+            if made[0] == _ITEM:
+                self.decoded.add(threading.get_ident())
+            else:
+                streams.ended[i] = True
+                self._alive -= 1
+            streams.ready[i].append(made)
+            self._cv.notify_all()
 
 
 class HostPrefetcher:
-    """Bounded read-ahead over a host batch iterator, fed by one
-    background decode worker.  The queue depth IS the backpressure:
-    the decode thread blocks when the device is ``depth`` batches
-    behind, so host memory stays bounded no matter how large the scan.
+    """Bounded read-ahead over a host batch iterator, pulled by one
+    background thread (``citus-host-decode``).  The queue depth IS the
+    backpressure: the pulling thread blocks when the device is
+    ``depth`` batches behind, so host memory stays bounded no matter
+    how large the scan.
+
+    Where the source takes its items from several independent streams
+    (``one_after_another`` / ``in_rounds``) and the cores allow it, the
+    pulling thread only hands the items on in their order and
+    ``_Producers`` decode them, several streams at once; a source of
+    one stream is decoded on the pulling thread, with no other.  Host
+    batches alive at once, made or in the making: at most ``2 x
+    producers + depth`` and what the source itself holds in hand (a
+    mesh round's members).
 
     Exceptions raised by the source (fault injections included) are
-    re-raised at the consumer's next ``__next__``.  ``close()``
-    cancels the worker promptly even when it is blocked on a full
-    queue (consumer died mid-scan)."""
-
-    _ITEM, _DONE, _ERR = 0, 1, 2
+    re-raised at the consumer's next ``__next__``, after everything
+    that was due before them.  ``close()`` cancels every thread
+    promptly, also one blocked on backpressure (consumer died
+    mid-scan)."""
 
     def __init__(self, source: Iterator, depth: int,
                  stats: Optional[PipelineStats] = None):
@@ -164,26 +466,29 @@ class HostPrefetcher:
         self._stats = stats
         self._q: queue.Queue = queue.Queue(maxsize=max(1, depth))
         self._cancel = threading.Event()
-        # the transaction overlay is thread-local: the decode thread
+        # the transaction overlay is thread-local: the decode threads
         # must see the consumer's staged writes, not a bare snapshot
         self._txn = current_overlay()
-        # so is the trace context: the decode thread's spans hang under
+        # so is the trace context: the decode threads' spans hang under
         # the span the consumer had open (the query's ``execute``)
         self._trace_ctx = _trace.capture()
+        self._producers = _Producers(self._txn, self._trace_ctx)
         self._thread = threading.Thread(target=self._produce, daemon=True,
                                         name="citus-host-decode")
         self._finished = False
+        self._booked = False
         self._thread.start()
 
-    # ---- producer (decode thread) ----
+    # ---- pulling thread ----
     def _put(self, item) -> bool:
         try:
             self._q.put_nowait(item)
             return True
         except queue.Full:
             pass
-        # device behind: backpressure holds the decode thread, which
-        # says so (wait:prefetch_full) from this first Full on
+        # device behind: backpressure holds the decode side, which says
+        # so (wait:prefetch_full) from this first Full on -- ONE span
+        # an interval, however many producers it holds back in turn
         wtok = begin_wait("prefetch_full")
         stalled = False
         try:
@@ -201,12 +506,17 @@ class HostPrefetcher:
 
     def _produce(self) -> None:
         from citus_tpu.storage.overlay import transaction_overlay
-        with transaction_overlay(self._txn):
-            if self._trace_ctx is None:
-                self._produce_inner()
-            else:
-                with _trace.activate(*self._trace_ctx):
+        _pulling.producers = self._producers
+        try:
+            with transaction_overlay(self._txn):
+                if self._trace_ctx is None:
                     self._produce_inner()
+                else:
+                    with _trace.activate(*self._trace_ctx):
+                        self._produce_inner()
+        finally:
+            # nothing is left to make: no producer waits on for close()
+            self._producers.stop()
 
     def _produce_inner(self) -> None:
         try:
@@ -215,15 +525,20 @@ class HostPrefetcher:
                 try:
                     batch = next(self._source)
                 except StopIteration:
-                    self._put((self._DONE, None))
+                    self._put((_DONE, None))
                     return
                 finally:
                     if self._stats is not None:
-                        self._stats.host_decode_s += _perf() - t0
-                if not self._put((self._ITEM, batch)):
+                        # thread-time: this thread's own, and its
+                        # producers' in place of its waits for them
+                        self._stats.host_decode_s += \
+                            _perf() - t0 + self._producers.settle()
+                if not self._put((_ITEM, batch)):
                     return
+        except _Cancelled:
+            pass
         except BaseException as e:  # surfaces at the consumer
-            self._put((self._ERR, e))
+            self._put((_ERR, e))
 
     # ---- consumer ----
     def __iter__(self):
@@ -250,17 +565,19 @@ class HostPrefetcher:
                                 "host decode worker died without a result")
             finally:
                 end_wait(wtok)
-        if kind == self._ITEM:
+        if kind == _ITEM:
             return val
         self._finished = True
-        if kind == self._ERR:
+        if kind == _ERR:
             raise val
         raise StopIteration
 
     def close(self) -> None:
-        """Cancel the decode worker and drain; idempotent, safe to call
-        from a ``finally`` around the consumer loop."""
+        """Cancel the decode threads and drain; idempotent, safe to call
+        from a ``finally`` around the consumer loop.  No thread of this
+        prefetcher is alive when it returns."""
         self._cancel.set()
+        self._producers.stop()
         while self._thread.is_alive():
             try:
                 while True:
@@ -268,13 +585,17 @@ class HostPrefetcher:
             except queue.Empty:
                 pass
             self._thread.join(timeout=0.05)
-        close = getattr(self._source, "close", None)
-        if close is not None:
-            try:
-                close()
-            # lint: disable=SWL01 -- source close at shutdown is best-effort; batches already delivered
-            except Exception:
-                pass
+        self._producers.join()
+        try:
+            _close_iter(self._source)
+        # lint: disable=SWL01 -- source close at shutdown is best-effort; batches already delivered
+        except Exception:
+            pass
+        if self._stats is not None and not self._booked:
+            self._booked = True
+            self._stats.host_decode_s += self._producers.settle()
+            self._stats.book_streams(max(1, len(self._producers.decoded)),
+                                     self._producers.overlap_s)
 
 
 def prefetch_batches(source: Iterator, depth: int,

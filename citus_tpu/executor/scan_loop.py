@@ -41,7 +41,7 @@ import numpy as np
 from citus_tpu.executor.batches import ShardBatch, empty_batch
 from citus_tpu.executor.device_cache import GLOBAL_CACHE, plan_cache_key
 from citus_tpu.executor.pipeline import (
-    PipelineStats, prefetch_batches, read_ahead_depth,
+    PipelineStats, in_rounds, prefetch_batches, read_ahead_depth,
 )
 from citus_tpu.observability import trace as _trace
 from citus_tpu.observability.trace import clock
@@ -373,24 +373,20 @@ class AffineMeshPlacement(MeshPlacement):
         """The batches of ``shard_indexes`` in round order: each round
         is ``round_size`` items, item ``i`` the next batch of device
         ``i``'s own shards (``open_stream(its shards)``: one stream a
-        device, all pulled by whoever pulls this one) or None.  Ends
-        with the last round that holds a batch.  ``n_shards``: of the
+        device, decoded side by side where a prefetcher pulls this one:
+        ``pipeline.in_rounds``) or None.  Ends with the last round that
+        holds a batch.  ``n_shards``: of the
         table these shards are of, where a scan streams several."""
         owned = [[si for si in shard_indexes
                   if self.owner(si, n_shards) == d]
                  for d in range(self.round_size)]
-        streams = [open_stream(mine) if mine else iter(()) for mine in owned]
+        rounds = in_rounds([open_stream(mine) if mine else iter(())
+                            for mine in owned])
         try:
-            while True:
-                members = [next(s, None) for s in streams]
-                if all(m is None for m in members):
-                    return
+            for members in rounds:
                 yield from members
         finally:
-            for s in streams:
-                close = getattr(s, "close", None)
-                if close is not None:
-                    close()
+            rounds.close()
 
     def deal(self, a):
         """A host array of entries, any of which may go to any device:

@@ -99,6 +99,8 @@ METRIC_HELP = {
     "batch_rows_padded": "rows of those batches' power-of-two buckets",
     "decode_bytes_in_place": "value bytes of those batches decoded straight into the batch's arrays",
     "decode_bytes_copied": "value bytes of those batches decoded apart and copied in",
+    "decode_streams": "threads that decoded a streamed scan's batches side by side, summed over the scans (1 a scan that one thread decoded)",
+    "decode_overlap_ms": "wall milliseconds of those scans during which two or more batches were being decoded at once",
     "footer_cache_hits": "stripe footers served decoded, the file's identity unchanged",
     "footer_parses": "stripe files opened and their footers parsed",
     "footer_cache_evictions": "decoded footers the cache's bound pushed out",

@@ -69,6 +69,12 @@ class StatCounters:
         # there from a chunk decoded apart (deletes, NULLs, a cast, ...)
         "decode_bytes_in_place",
         "decode_bytes_copied",
+        # streamed scans (executor/pipeline.py): threads that decoded
+        # batches of different streams (shards, a mesh's devices) side
+        # by side, 1 a scan one thread decoded; wall ms during which two
+        # or more of them were inside a batch
+        "decode_streams",
+        "decode_overlap_ms",
         # decoded stripe footers kept by file identity
         # (storage/format.py read_stripe_footer): footers served from
         # the cache, files opened and parsed, entries the bound pushed out

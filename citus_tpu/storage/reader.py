@@ -11,7 +11,9 @@ SelectedChunkMask/BuildBaseConstraint in the reference.
 
 from __future__ import annotations
 
+import contextlib
 import os
+import threading
 from dataclasses import dataclass
 from typing import Iterator, Optional
 
@@ -31,22 +33,69 @@ from citus_tpu.storage.writer import _load_meta
 # ambient settings
 _DECODE_THREADS: Optional[int] = None
 
+#: threads a native call gets at least, where nobody set a number, before
+#: a scan decodes a further stream beside it (PERF.md section 6, PR 45)
+_THREADS_A_PRODUCER = 4
+
+# .ways: how many threads decode batches at once beside this one, itself
+# included (executor/pipeline.py's producers say so of themselves)
+_sharing = threading.local()
+
 
 def set_decode_threads(n: int) -> None:
     global _DECODE_THREADS
     _DECODE_THREADS = int(n)
 
 
-def decode_thread_count() -> int:
-    """Threads for the native read+decompress pool — citus.decode_threads
-    (0 = auto: min(8, cpu_count))."""
+def _configured_threads() -> int:
+    """citus.decode_threads as set: 0 = auto."""
     n = _DECODE_THREADS
     if n is None:
         from citus_tpu.config import current_settings
         n = current_settings().executor.decode_threads
+    return n
+
+
+def usable_cores() -> int:
+    """Cores this process may run on: its affinity mask where the
+    platform has one (a container's share, not the host's count)."""
+    try:
+        return len(os.sched_getaffinity(0)) or 1
+    except AttributeError:
+        return os.cpu_count() or 1
+
+
+def decode_thread_count() -> int:
+    """Threads of ONE call of the native read+decompress pool --
+    citus.decode_threads; 0 = auto: the usable cores, at most 8,
+    divided by the threads that make such calls at once (1 unless the
+    calling thread is one of ``decode_pool_shared``'s)."""
+    n = _configured_threads()
     if n > 0:
         return n
-    return min(8, os.cpu_count() or 1)
+    return max(1, min(8, usable_cores() // getattr(_sharing, "ways", 1)))
+
+
+def decode_producers(streams: int) -> int:
+    """How many of a scan's ``streams`` may be decoded at once, each by
+    a thread of its own, so that the producers' native calls together
+    stay inside the usable cores: one a stream, and no more than leave
+    every call ``citus.decode_threads`` threads (auto: four)."""
+    per_call = _configured_threads()
+    if per_call <= 0:
+        per_call = _THREADS_A_PRODUCER
+    return max(1, min(streams, usable_cores() // per_call))
+
+
+@contextlib.contextmanager
+def decode_pool_shared(ways: int):
+    """This thread is one of ``ways`` that decode batches at once: its
+    native calls take their share of the cores, not all of them."""
+    _sharing.ways = max(1, ways)
+    try:
+        yield
+    finally:
+        del _sharing.ways
 
 
 @dataclass(frozen=True)

@@ -56,6 +56,18 @@ def limit_devices(monkeypatch):
     return limit
 
 
+@pytest.fixture()
+def producers(monkeypatch):
+    """``producers(n)``: the machine has the cores for ``n`` threads
+    decoding a streamed scan's batches at once (a scan still takes no
+    more than it has streams); 1 = the one decode thread."""
+    def allow(n):
+        from citus_tpu.storage import reader
+        monkeypatch.setattr(reader, "usable_cores",
+                            lambda: n * reader._THREADS_A_PRODUCER)
+    return allow
+
+
 @pytest.fixture(scope="session", autouse=True)
 def _sanitizer_teardown_gate():
     """When the suite runs under CITUS_SANITIZE, an empty

@@ -282,3 +282,59 @@ def test_pad_share_counts_buckets_of_the_cut_not_of_the_overshoot(
     # a resident scan makes no batch: nothing to report
     assert "pad_share" not in "\n".join(row[0] for row in resident.rows)
     assert cl.counters.snapshot()["batch_rows_real"] == after["batch_rows_real"]
+
+
+# ------------------------------------- validity arrays nobody has to write
+
+
+def test_columns_without_a_null_share_one_read_only_validity_array(tmp_path):
+    """A batch's all-True validity arrays (and a full batch's row mask)
+    are ONE read-only array a bucket, shared by every column and batch;
+    a column that shows a NULL gets an array of its own, as does the row
+    mask of a padded batch -- and every one reads as it always did."""
+    from citus_tpu.executor import batches as B
+    cl = ct.Cluster(str(tmp_path / "db"), settings=Settings(
+        columnar=ColumnarSettings(chunk_group_row_limit=64,
+                                  stripe_row_limit=256)))
+    try:
+        n = 1000
+        cl.execute("CREATE TABLE sh (k bigint NOT NULL, v bigint, w bigint)")
+        cl.execute("SELECT create_distributed_table('sh', 'k', 1)")
+        cl.copy_from("sh", rows=[(i, None if i % 9 == 0 else i * 3, i * 5)
+                                 for i in range(n)])
+        bound = bind_select(cl.catalog,
+                            parse_sql("SELECT k, v, w FROM sh")[0])
+        plan = plan_select(cl.catalog, bound)
+        at = {c: i for i, c in enumerate(plan.scan_columns)}
+        batches = list(load_padded_batches(
+            cl.catalog, plan, 0, min_batch_rows=MIN_ROWS, max_batch_rows=256))
+        assert [b.n_rows for b in batches] == [256, 256, 256, 232]
+        shared = B._all_true(256)
+        assert shared.all() and not shared.flags.writeable
+        lo = 0
+        for b in batches:
+            assert b.padded_rows == 256
+            for c in ("k", "w"):                    # never a NULL
+                assert b.valids[at[c]] is shared
+            own = b.valids[at["v"]]
+            assert own is not shared and own.flags.writeable
+            want = np.ones(256, bool)
+            want[:b.n_rows] = np.arange(lo, lo + b.n_rows) % 9 != 0
+            assert (own == want).all()
+            if b.n_rows == b.padded_rows:
+                assert b.row_mask is shared
+            else:
+                assert b.row_mask is not shared
+                assert b.row_mask[:b.n_rows].all() \
+                    and not b.row_mask[b.n_rows:].any()
+            lo += b.n_rows
+        # what is shared stays what it was after the scan that read it
+        GLOBAL_CACHE.clear()
+        assert cl.execute("SELECT count(*), count(v), sum(w) FROM sh").rows \
+            == [(n, n - len(range(0, n, 9)), 5 * n * (n - 1) // 2)]
+        assert B._all_true(256) is shared and shared.all()
+        with pytest.raises(ValueError):
+            shared[0] = False
+    finally:
+        GLOBAL_CACHE.clear()
+        cl.close()

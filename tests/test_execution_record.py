@@ -30,7 +30,8 @@ ROWS, SHARDS = 4000, 4
 # what the callers that close a streamed scan's timings add
 SCAN = {"fused_dispatches", "scan_lanes", "scan_lanes_narrow"}
 STREAMED = {"batch_rows_real", "batch_rows_padded", "decode_bytes_in_place",
-            "decode_bytes_copied", "stream_window_peak_bytes"}
+            "decode_bytes_copied", "stream_window_peak_bytes",
+            "decode_streams", "decode_overlap_ms"}
 TIMINGS = {"host_decode_ms", "device_ms", "h2d_bytes", "host_stalls",
            "device_stalls"}
 DIRECT = {"group_rows_in", "direct_groups", "direct_groups_out",

@@ -311,17 +311,22 @@ def _streaming_table(cl, name, n=20000, shards=8):
     return [(n, 3 * n * (n - 1) // 2)]
 
 
+@pytest.mark.parametrize("n_producers", [1, 3])
 @pytest.mark.parametrize("n_dev", [1, 4])
 def test_streaming_scan_spans_cover_both_threads(tmp_cluster, limit_devices,
-                                                 n_dev):
+                                                 producers, n_dev,
+                                                 n_producers):
     """A streaming scan's trace: decode_batch spans from the decode
-    thread hang under the query's execute, each with one stripe_read
+    threads hang under the query's execute, each with one stripe_read
     and one pad (the batch is assembled once: no concat); every
     device_round holds h2d + narrow (v fits 32 bits: the lane convert of
     tests/test_scan_lanes.py) + dispatch; the stall
-    the consumer sat in is a wait:prefetch_stall span from the seam."""
+    the consumer sat in is a wait:prefetch_stall span from the seam.
+    One producer: the ONE thread ``citus-host-decode``; several: the
+    threads ``citus-host-decode-<n>``, each on a line of its own."""
     from citus_tpu.observability import trace as T
     limit_devices(n_dev)
+    producers(n_producers)
     cl = tmp_cluster
     exp = _streaming_table(cl, "sp")
     q = "SELECT count(*), sum(v) FROM sp"
@@ -345,17 +350,30 @@ def test_streaming_scan_spans_cover_both_threads(tmp_cluster, limit_devices,
     batches = [s for s in tr.find_all("decode_batch")
                if not s.attrs.get("eof")]
     assert len(batches) == 8                         # one per shard
-    assert len(tr.find_all("decode_batch")) == 9     # and the last pull
-    for s in batches:
+    # and every shard's last pull, which finds it exhausted
+    assert len(tr.find_all("decode_batch")) == 16
+    for s in tr.find_all("decode_batch"):
         assert s.parent_id == ex.span_id
+    for s in batches:
         assert kids[s.span_id] == ["stripe_read", "pad"]
         assert s.attrs["rows"] > 0 and s.attrs["bytes"] > 0
     other = [s for s in batches if s.tid != root.tid]
     # the mesh loop peeks two batches on its own thread before it
     # starts the decode thread; the single-device loop none
     assert len(other) == (8 if n_dev == 1 else 6)
-    assert {s.attrs["thread"] for s in other} == {"citus-host-decode"}
-    assert len({s.tid for s in other}) == 1
+    names = {s.attrs["thread"] for s in other}
+    if n_producers == 1:
+        assert names == {"citus-host-decode"}
+    else:
+        assert 2 <= len(names) <= n_producers and names <= {
+            f"citus-host-decode-{k + 1}" for k in range(n_producers)}
+        pl = ex.attrs["pipeline"]
+        assert pl["decode_streams"] == len(names)
+    # a thread, a line: every span of a batch on its batch's
+    assert len({s.tid for s in other}) == len(names)
+    for s in tr.spans:
+        if by_id.get(s.parent_id) in batches:
+            assert s.tid == by_id[s.parent_id].tid
     rounds = tr.find_all("device_round")
     assert len(rounds) == (8 if n_dev == 1 else 2)
     for r in rounds:
@@ -462,8 +480,9 @@ def test_stripe_read_names_its_parts_on_the_decode_thread(tmp_cluster,
     by_id = {s.span_id: s for s in tr.spans}
     reads = tr.find_all("stripe_read")
     assert len(reads) == 8        # one batch a shard
+    # on the decode threads (how many: the cores decide), none on the caller's
     decode_tids = {s.tid for s in reads}
-    assert len(decode_tids) == 1 and decode_tids != {tr.root().tid}
+    assert decode_tids and tr.root().tid not in decode_tids
     for read in reads:
         assert by_id[read.parent_id].name == "decode_batch"
         kids = [s for s in tr.spans if s.parent_id == read.span_id]
@@ -584,13 +603,18 @@ def test_a_cut_chunk_is_a_stripe_fallback_of_its_own(tmp_path, monkeypatch,
         cl.close()
 
 
+@pytest.mark.parametrize("n_producers", [1, 3])
 def test_a_slow_consumer_shows_as_prefetch_full_on_the_decode_thread(
-        tmp_cluster, limit_devices):
-    """Backpressure: the device is behind, the decode thread holds a
-    batch — a wait:prefetch_full span from the seam, on ITS thread,
-    beside its decode_batch spans, booked into wait_prefetch_full_ms."""
+        tmp_cluster, limit_devices, producers, n_producers):
+    """Backpressure: the device is behind, the decode side holds a
+    batch — a wait:prefetch_full span from the seam, on the thread that
+    hands the batches on (with one producer: beside its decode_batch
+    spans), booked into wait_prefetch_full_ms.  ONE span an interval
+    however many producers the consumer holds back: the intervals
+    never overlap, and their sum is wall time."""
     from citus_tpu.observability import trace as T
     limit_devices(1)
+    producers(n_producers)
     cl = tmp_cluster
     exp = _streaming_table(cl, "pf")
     q = "SELECT count(*), sum(v) FROM pf"
@@ -606,17 +630,26 @@ def test_a_slow_consumer_shows_as_prefetch_full_on_the_decode_thread(
         FAULTS.disarm()
     tr = T.last_trace()
     ex = tr.find("execute")
-    full = tr.find_all("wait:prefetch_full")
-    batch_tids = {s.tid for s in tr.find_all("decode_batch")}
-    assert full and len(batch_tids) == 1
-    assert all(s.parent_id == ex.span_id and s.tid in batch_tids
-               and s.t1 is not None for s in full)
-    assert sum(s.duration_ms for s in full) >= 100     # of 8 rounds x 50 ms
-    assert cl.counters.snapshot()["wait_prefetch_full_ms"] - before >= 100
-    # it waits between batches, never inside one
+    full = sorted(tr.find_all("wait:prefetch_full"), key=lambda s: s.t0)
     batches = tr.find_all("decode_batch")
-    assert not [(w, b) for w in full for b in batches
-                if w.t0 < b.t1 and b.t0 < w.t1]
+    batch_tids = {s.tid for s in batches}
+    assert full and len({s.tid for s in full}) == 1
+    assert all(s.parent_id == ex.span_id and s.t1 is not None for s in full)
+    assert full[0].tid != tr.root().tid
+    if n_producers == 1:
+        assert batch_tids == {full[0].tid}
+        # it waits between batches, never inside one
+        assert not [(w, b) for w in full for b in batches
+                    if w.t0 < b.t1 and b.t0 < w.t1]
+    else:
+        assert len(batch_tids) >= 2 and full[0].tid not in batch_tids
+    # one span an interval: at most one a batch handed on, none beside
+    # another
+    assert len(full) <= 8
+    assert all(a.t1 <= b.t0 for a, b in zip(full, full[1:]))
+    assert sum(s.duration_ms for s in full) >= 100     # of 8 rounds x 50 ms
+    assert sum(s.duration_ms for s in full) <= ex.duration_ms
+    assert cl.counters.snapshot()["wait_prefetch_full_ms"] - before >= 100
 
 
 def test_an_unsampled_stream_asks_the_pool_nothing(tmp_cluster, limit_devices,
@@ -655,31 +688,45 @@ def test_an_unsampled_stream_asks_the_pool_nothing(tmp_cluster, limit_devices,
     assert len(asked) == 8 and all(a is not None for a in asked)
 
 
-def test_explain_analyze_splits_the_decode(tmp_cluster, limit_devices):
+@pytest.mark.parametrize("n_producers", [1, 3])
+def test_explain_analyze_splits_the_decode(tmp_cluster, limit_devices,
+                                           producers, n_producers):
     """The pipeline line reads the same spans: footers (all of them
     served by the footer cache: the statement ran once before), layout,
     the native call with its pool's busy share, the fallback, the time
-    the decode thread was blocked."""
+    the decode side was blocked, and how many streams were decoded at
+    once for how long."""
     import re
     limit_devices(1)
+    producers(n_producers)
     cl = tmp_cluster
     exp = _mixed_table(cl, "ea")
     q = "SELECT count(*), sum(k), sum(v), sum(w) FROM ea"
     assert cl.execute(q).rows == exp
     GLOBAL_CACHE.clear()
-    text = "\n".join(r[0] for r in cl.execute("EXPLAIN ANALYZE " + q).rows)
+    FAULTS.arm("decode_batch", delay_s=0.02, match="ea")
+    try:
+        text = "\n".join(r[0] for r in cl.execute("EXPLAIN ANALYZE " + q).rows)
+    finally:
+        FAULTS.disarm()
     (line,) = [ln for ln in text.splitlines() if "Pipeline:" in ln]
     m = re.search(
         r"decoded in place 0\.800, decode: footers (\d+\.\d\d) ms "
         r"\((\d+) of (\d+) cached\), "
         r"layout (\d+\.\d\d) ms, native (\d+\.\d\d) ms \(pool (\d+) % busy\), "
-        r"fallback (\d+\.\d\d) ms, blocked (\d+\.\d\d) ms, fused dispatches 8",
+        r"fallback (\d+\.\d\d) ms, blocked (\d+\.\d\d) ms, "
+        r"(\d+) streams at once, (\d+) ms overlapped, fused dispatches 8",
         line)
     assert m, line
     (footers, hits, stripes, layout, native, pool, fallback,
-     _blocked) = map(float, m.groups())
+     _blocked, streams, overlapped) = map(float, m.groups())
     assert min(footers, layout, native, fallback) > 0 and 0 < pool <= 100
     assert hits == stripes > 0
+    if n_producers == 1:
+        assert (streams, overlapped) == (1, 0)
+    else:
+        # eight batches of 20 ms each by two or three threads
+        assert 2 <= streams <= 3 and overlapped > 0
     # a resident scan decodes nothing: nothing to split
     again = "\n".join(r[0] for r in cl.execute("EXPLAIN ANALYZE " + q).rows)
     assert "decode:" not in again

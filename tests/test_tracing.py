@@ -387,12 +387,15 @@ class AnnotationRecorder:
         self.log.append(("exit", self.name, threading.get_ident()))
 
 
+@pytest.mark.parametrize("n_producers", [1, 3])
 @pytest.mark.parametrize("n_dev", [1, 4])
 def test_every_real_span_holds_one_annotation_on_its_own_thread(
-        cl8, monkeypatch, limit_devices, tmp_path, n_dev):
+        cl8, monkeypatch, limit_devices, producers, tmp_path, n_dev,
+        n_producers):
     from collections import Counter
     from citus_tpu.executor.device_cache import GLOBAL_CACHE
     limit_devices(n_dev)
+    producers(n_producers)
     q = "SELECT count(*), sum(v) FROM t"
     cl8.execute(q)                                   # compile unsampled
     AnnotationRecorder.log = log = []
@@ -402,10 +405,11 @@ def test_every_real_span_holds_one_annotation_on_its_own_thread(
     cl8.execute("SET citus.trace_sample_rate = 1.0")
     del log[:]
     GLOBAL_CACHE.clear()                             # stream: two threads
-    cl8.execute(q)
+    cl8.execute(q)                                   # or a producer more each
     tr = T.last_trace()
     tree(tr)
-    assert len({s.tid for s in tr.spans}) == 2
+    threads = len({s.tid for s in tr.spans})
+    assert threads == 2 if n_producers == 1 else 3 <= threads <= 5
     entered = Counter((n, t) for kind, n, t in log if kind == "enter")
     left = Counter((n, t) for kind, n, t in log if kind == "exit")
     assert entered == left
@@ -442,10 +446,13 @@ def test_unsampled_resident_streaming_and_mesh_queries_allocate_no_span(
         assert T.span_allocations() == before, n_dev
 
 
+@pytest.mark.parametrize("n_producers", [1, 3])
 def test_chrome_export_puts_each_thread_on_its_own_row(cl8, limit_devices,
-                                                       tmp_path):
+                                                       producers, tmp_path,
+                                                       n_producers):
     from citus_tpu.executor.device_cache import GLOBAL_CACHE
     limit_devices(1)
+    producers(n_producers)
     export = tmp_path / "traces"
     cl8.execute("SET citus.trace_sample_rate = 1.0")
     cl8.execute(f"SET citus.trace_export_dir = '{export}'")
@@ -453,7 +460,8 @@ def test_chrome_export_puts_each_thread_on_its_own_row(cl8, limit_devices,
     cl8.execute("SELECT count(*), sum(v) FROM t")
     tr = T.last_trace()
     doc = json.load(open(export / f"trace_{tr.trace_id}.json"))
-    assert doc["otherData"]["thread_rows"] == 2
+    rows = doc["otherData"]["thread_rows"]
+    assert rows == 2 if n_producers == 1 else 3 <= rows <= 5
     evts = {e["args"]["span_id"]: e for e in doc["traceEvents"]
             if e["ph"] == "X"}
     assert len(evts) == len(tr.spans)
@@ -463,8 +471,11 @@ def test_chrome_export_puts_each_thread_on_its_own_row(cl8, limit_devices,
         e = evts[s.span_id]
         assert (e["tid"] == 1) == (s.tid == root.tid)
         assert e["args"].get("parent_id") == s.parent_id
-    assert {e["tid"] for e in evts.values()
-            if e["name"] == "decode_batch"} == {2}
+    decoders = {e["tid"] for e in evts.values() if e["name"] == "decode_batch"}
+    if n_producers == 1:
+        assert decoders == {2}
+    else:
+        assert 2 <= len(decoders) <= 3 and 1 not in decoders
 
 
 def test_profile_traces_its_statement_whatever_the_sampling_rate(
