@@ -183,14 +183,16 @@ def test_span_attr_reads_nothing_where_nothing_was_written(spans_dir,
 
 def test_every_new_metric_names_a_reader_the_harness_finds():
     from benchmarks.spec import Cell, load_json, plugin
-    for cell, expect in (("tpch_sf10_q1q6_params", 15),
+    # PR 33's and, in the first cell, PR 45's decode_streams_per_query; a
+    # later PR adds to a cell and edits no test, so these are floors
+    for cell, expect in (("tpch_sf10_q1q6_params", 16),
                          ("tpch_sf1_q18_orders_params", 15),
                          ("tpch_sf10_orderkey_lookup", 4)):
         new = [m["name"] for m in Cell(cell).per_layer
                if m["name"].startswith(("decode_", "stall_idle_", "producer_",
                                         "kernel_compiles"))
                and m["name"] not in ("decode_wall_ms", "decode_wait_ms")]
-        assert len(new) == expect, (cell, new)
+        assert len(new) >= expect, (cell, new)
         for name in new:
             reader = load_json("layer_metrics", name + ".json")["reader"]
             assert callable(plugin("sources", reader["kind"]).read)

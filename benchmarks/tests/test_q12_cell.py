@@ -96,48 +96,68 @@ def test_the_statement_is_q12_as_published_over_21_pairs_and_5_years():
     assert QUERY["ordered"] is True and QUERY["reference"] == "q12"
 
 
+#: the ten metrics PR 43 brought: four stay under the cell's prefix, six
+#: read what another cell's copy read and were folded by PR 47 into one
+#: entry with a ``workloads`` list each
+OWN = {"q12x4_exchange_kernel_ms", "q12x4_exchange_kernel_hbm_roofline",
+       "q12x4_exchange_ms", "q12x4_rows_exchanged_per_query"}
+SHARED = {"join_kernel_ms", "join_kernel_hbm_roofline",
+          "join_host_fallbacks_per_query", "mesh_collective_ms", "stack_ms",
+          "decode_wait_ms"}
+
+
 def test_the_cells_metric_set():
     b = bench_json()
-    mine = [m for m in b["per_layer"] if m.get("workloads") == [CELL]]
-    assert len(mine) == 10      # per_layer holds 128 at most: CHANGES.md
-    assert all(m["name"].startswith("q12x4_")
-               and m["moves"] == "scan_rows_per_s" for m in mine)
-    # readers the benchmark had, and one this cell brings: the trace
-    # names the exchange's collective all_to_all.N, which the accepted
-    # trace_collectives does not look for
-    readers = {"trace_module", "trace_roofline", "trace_ops",
+    by_name = {m["name"]: m for m in b["per_layer"]}
+    assert OWN | SHARED <= set(by_name)
+    assert all(by_name[n]["workloads"] == [CELL] for n in OWN)
+    assert all(CELL in by_name[n]["workloads"]
+               and len(by_name[n]["workloads"]) > 1 for n in SHARED)
+    # readers the benchmark had: since PR 47 trace_collectives finds the
+    # exchange's all_to_all.N and the cell needs no reader of its own
+    readers = {"trace_module", "trace_roofline", "trace_collectives",
                "span_self", "counter_delta"}
-    for m in mine:
-        f = spec.load_json("layer_metrics", m["name"] + ".json")
-        assert f["reader"]["kind"] in readers
+    for n in OWN | SHARED:
+        m = by_name[n]
+        f = spec.load_json("layer_metrics", n + ".json")
+        assert f["reader"]["kind"] in readers and m["moves"] == "scan_rows_per_s"
         assert (f["unit"], f["better"], f["layer"], f["source"]) == (
             m["unit"], m["better"], m["layer"], m["source"])
     everywhere = {m["name"] for m in b["per_layer"] if "workloads" not in m}
     cell = spec.Cell(CELL)
+    # PR 45 gave the cell decode_streams_per_query; a later PR may add more
     assert {m["name"] for m in cell.per_layer} \
-        == {m["name"] for m in mine} | everywhere
+        >= OWN | SHARED | everywhere | {"decode_streams_per_query"}
     assert {m["name"] for m in cell.end_to_end} \
         == {"scan_rows_per_s", "setup_s"}
-    rooflines = [m["name"] for m in mine if "roofline" in m["name"]]
-    assert rooflines == ["q12x4_exchange_kernel_hbm_roofline",
-                         "q12x4_join_kernel_hbm_roofline"]
+    rooflines = {m["name"] for m in cell.per_layer if "roofline" in m["name"]}
+    assert rooflines >= {"join_kernel_hbm_roofline",
+                         "q12x4_exchange_kernel_hbm_roofline"}
 
 
-def test_trace_ops_sums_the_ops_a_prefix_names_and_is_silent_without():
+def test_trace_collectives_sums_the_exchanges_lanes_and_is_silent_without():
+    """What ``sources/trace_ops.py`` did for this cell until PR 47: the
+    v5e trace names the exchange's ops ``all_to_all.N``."""
     import types
-    from benchmarks.sources import trace_ops
-    args = {"prefixes": ["all_to_all", "all-to-all"]}
-    ctx = types.SimpleNamespace(slice_queries=["q12", "q12"], trace={
-        "ops": {"all_to_all.27": 0.004, "all_to_all.24": 0.001,
-                "fusion.9": 0.5, "all-to-all-start": 0.001,
-                "while.20": 0.3}})
-    assert trace_ops.read(ctx, args) == pytest.approx(3.0)
-    ctx.trace = {"ops": {"fusion.9": 0.5}}
-    assert trace_ops.read(ctx, args) == 0.0         # a program without it
-    ctx.trace = {}
-    assert trace_ops.read(ctx, args) == 0.0
+    from benchmarks import trace_reduce
+    from benchmarks.sources import trace_collectives
+    from benchmarks.tests.test_trace_reduce import Line, Plane, Profile, ev
+
+    def reduced(*ops):
+        host = Plane("/host:CPU", [Line("python", [
+            ev("bench.execute.q12", 0, 10), ev("bench.execute.q12", 10, 10)])])
+        chip = Plane("/device:TPU:0", [Line("XLA Ops", list(ops))])
+        return trace_reduce.reduce_trace(Profile([host, chip]))
+
+    ctx = types.SimpleNamespace(slice_queries=["q12", "q12"], trace=reduced(
+        ev("all_to_all.27", 1, 4), ev("all_to_all.24", 5, 1),
+        ev("fusion.9", 6, 3), ev("all-to-all-start", 9, 1),
+        ev("while.20", 11, 5)))
+    assert trace_collectives.read(ctx, {}) == pytest.approx(3.0)
+    ctx.trace = reduced(ev("fusion.9", 1, 5))
+    assert trace_collectives.read(ctx, {}) == 0.0   # a program without it
     ctx.trace = None
-    assert trace_ops.read(ctx, args) is None        # a CPU rehearsal
+    assert trace_collectives.read(ctx, {}) is None  # a CPU rehearsal
 
 
 def test_the_rooflines_bytes_come_from_the_query_file():
@@ -254,15 +274,15 @@ def test_cell_traced_gives_every_program_metric_a_number(checkout):
               if m["source"] == "device_trace"}
     want = expected_metrics(checkout, CELL, "per_layer") - device \
         - {"peak_hbm_gb", "idle_unattributed_ms"}
-    mine = {n for n in want if n.startswith("q12x4_")}
-    assert len(mine) == 5 and mine <= set(out["metrics"])
+    mine = (OWN | SHARED) - device
+    assert len(mine) == 5 and mine <= want
     assert want <= set(out["metrics"])
     m = {k: v["value"] for k, v in out["metrics"].items()}
     orders = out["info"]["data"]["table_rows"]["orders"]
-    assert m["q12x4_host_fallbacks_per_query"] == 0
+    assert m["join_host_fallbacks_per_query"] == 0
     assert m["q12x4_rows_exchanged_per_query"] == orders
     assert m["compiles_in_window"] == 0
-    assert m["q12x4_exchange_ms"] > 0 and m["q12x4_stack_ms"] > 0
+    assert m["q12x4_exchange_ms"] > 0 and m["stack_ms"] > 0
     # what the cell's metric list has no room for, from the counters
     n = out["attempted"]
     per_query = {k: v / n for k, v in out["info"]["counters"].items()}

@@ -168,9 +168,9 @@ def test_q15_cell_traced_reports_the_grouping_layer(checkout):
         - {"peak_hbm_gb"}
     assert set(out["metrics"]) == want
     assert {"q15_state_slots", "q15_group_rows_in_per_query",
-            "q15_bytes_fetched_per_query", "q15_finalize_ms",
+            "q15_bytes_fetched_per_query", "group_finalize_ms",
             "q15_groups_out_per_query", "q15_state_init_ms",
-            "q15_result_fetch_ms", "q15_decode_wait_ms"} <= want
+            "result_fetch_ms", "decode_wait_ms"} <= want
     m = {k: v["value"] for k, v in out["metrics"].items()}
     assert m["compiles_in_window"] == 0
     # the direct table over the suppliers' domain, three states of it
@@ -180,7 +180,7 @@ def test_q15_cell_traced_reports_the_grouping_layer(checkout):
     assert m["q15_bytes_fetched_per_query"] == 24 * 100_001
     assert m["q15_group_rows_in_per_query"] >= out["info"]["rows"]
     assert 5_000 < m["q15_groups_out_per_query"] < 12_000
-    assert m["q15_finalize_ms"] > 0 and m["q15_state_init_ms"] > 0
+    assert m["group_finalize_ms"] > 0 and m["q15_state_init_ms"] > 0
     counters, n = out["info"]["counters"], out["attempted"]
     assert counters["direct_groups"] == 100_001 * n
     assert counters["group_rows_kept"] < counters["group_rows_in"] // 20
@@ -221,20 +221,20 @@ def test_q1_repeat_8c_cell(checkout):
     assert out["correct"] is True and out["failed"] == 0
     from_trace = {m["name"] for m in bench(checkout)["per_layer"]
                   if m["source"] == "device_trace"}
-    assert {"q1_8c_kernel_ms", "q1_8c_dispatches_per_query"} <= from_trace
+    assert {"scan_kernel_ms", "scan_dispatches_per_query"} <= from_trace
     # the CPU backend's trace has no device plane
     spans = {"q1_8c_admission_wait_ms", "q1_8c_round_wait_ms",
-             "q1_8c_fetch_wait_ms", "q1_8c_cache_lookup_ms", "q1_8c_plan_ms",
+             "result_fetch_ms", "q1_8c_cache_lookup_ms", "q1_8c_plan_ms",
              "q1_8c_dispatch_ms", "q1_8c_device_wait_ms"}
     assert set(out["metrics"]) == spans | {
         "cache_hit_share", "compiles_in_window", "untraced_host_ms",
-        "partials_proved_away_per_query"} \
+        "partials_proved_away_per_query", "kernel_compiles_in_window"} \
         == expected_metrics(checkout, CELL_8C, "per_layer") - from_trace \
         - {"peak_hbm_gb"}
     m = {k: v["value"] for k, v in out["metrics"].items()}
     assert m["cache_hit_share"] == 100
     assert all(m[k] >= 0 for k in spans)
-    assert m["q1_8c_round_wait_ms"] > 0 and m["q1_8c_fetch_wait_ms"] > 0 \
+    assert m["q1_8c_round_wait_ms"] > 0 and m["result_fetch_ms"] > 0 \
         and m["q1_8c_dispatch_ms"] > 0
 
 
@@ -271,7 +271,7 @@ def test_the_grouping_module_is_read_on_either_route():
     ctx.trace = None
     assert reader.read(ctx, ms) is None
     # the files name this reader, and the configuration both roles
-    for name in ("q15_group_kernel_ms", "q15_group_dispatches_per_query",
+    for name in ("group_kernel_ms", "q15_group_dispatches_per_query",
                  "q15_group_kernel_hbm_roofline"):
         with open(os.path.join(os.path.dirname(__file__), "..",
                                "layer_metrics", name + ".json")) as fh:

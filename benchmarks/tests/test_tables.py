@@ -5,8 +5,9 @@ reference table, a Q3-shaped ``JOIN ... GROUP BY`` and a statement over
 ``lineitem`` alone.  The fixture enters the scratch checkout as a later
 PR's cell would: files copied beside the ones that are there, two
 entries appended, nothing edited.  Beside it, the pins that hold the
-ten shipped cells where they were: the data directory's name and the
-one-table arithmetic of ``scan_rows_per_s`` and the roofline's bytes."""
+shipped cells where they were: the data directory's name and the
+one-table arithmetic of ``scan_rows_per_s`` and the roofline's bytes
+(since PR 47 also over the two configurations that list ``tables``)."""
 
 import json
 import os
@@ -178,17 +179,28 @@ QUERY_FILES = sorted(f[:-5] for f in os.listdir(
 
 @pytest.mark.parametrize("name", QUERY_FILES)
 def test_flat_columns_over_one_table_are_the_old_arithmetic(name):
+    """A query file written before PR 37 lists its columns flat; one
+    over several tables (Q3, Q12) lists them by table, and its bytes are
+    that arithmetic table by table."""
     query = load(ROOT, "benchmarks", "queries", name + ".json")
     columns = query["scanned_columns"]
-    assert all(isinstance(t, str) for t in columns.values())
+
+    def per_row(of_table):
+        return sum(roofline.TYPE_BYTES[t] + roofline.VALIDITY_BYTES
+                   for t in of_table.values())
+
     table_rows = 59_998_987
-    per_row = sum(roofline.TYPE_BYTES[t] + roofline.VALIDITY_BYTES
-                  for t in columns.values())
-    assert roofline.algorithmic_bytes(query, {"lineitem": table_rows}) \
-        == table_rows * per_row
+    if all(isinstance(t, str) for t in columns.values()):
+        assert roofline.algorithmic_bytes(query, {"lineitem": table_rows}) \
+            == table_rows * per_row(columns)
+    else:
+        assert set(columns) == set(query["tables"]) and len(columns) > 1
+        rows = {t: table_rows // (i + 1) for i, t in enumerate(columns)}
+        assert roofline.algorithmic_bytes(query, rows) \
+            == sum(rows[t] * per_row(columns[t]) for t in columns)
 
 
-# ---- the ten shipped cells stay where they were -------------------------
+# ---- the shipped cells stay where they were ------------------------------
 
 
 def shipped():
@@ -209,6 +221,8 @@ DATA_DIRS = {
     "nyctaxi_hourly_1chip": "nyctaxi_hourly_1chip-seed29-g1-orders85000000",
     "tpch_sf10_supp_1chip": "tpch_sf10_supp_1chip-seed22-g1-orders15000000",
     "tpch_sf10_orders_4chip": "tpch_sf10_orders_4chip-seed22-g1-orders15000000",
+    "tpch_sf10_q3_1chip": "tpch_sf10_q3_1chip-seed22-g1-orders15000000",
+    "tpch_sf10_q12_4chip": "tpch_sf10_q12_4chip-seed22-g1-orders15000000",
 }
 
 
@@ -216,11 +230,20 @@ DATA_DIRS = {
 def test_a_shipped_configuration_keeps_its_directory_and_its_form(
         name, config, cells):
     """The chip machines hold these directories: a character's change
-    and every one is ingested again (107-213 s)."""
+    and every one is ingested again (107-213 s; the join cells' three
+    and two tables longer).  A configuration written ``table`` / ``ddl``
+    reads as a list of one; one that lists ``tables`` as what it lists."""
     generator = plugin("generators", config["generator"]["name"])
     assert dataset.data_dir(config, generator, config["generator"]["orders"]) \
         == os.path.join(ROOT, "benchmarks", ".data", DATA_DIRS[name])
-    assert "tables" not in config
+    if "tables" in config:
+        assert not {"table", "ddl", "distribution_column"} & set(config)
+        assert tables_of(config) == config["tables"]
+        arrays = {f"rows.{t['name']}": 7 + i
+                  for i, t in enumerate(config["tables"])}
+        assert dataset.table_rows(config, arrays) == {
+            t["name"]: 7 + i for i, t in enumerate(config["tables"])}
+        return
     assert tables_of(config) == [{
         "name": config["table"], "ddl": config["ddl"],
         "distribution": {"kind": "hash",
@@ -231,20 +254,26 @@ def test_a_shipped_configuration_keeps_its_directory_and_its_form(
 @pytest.mark.parametrize("name,config,cells", SHIPPED)
 def test_over_one_table_scan_rows_per_s_is_the_old_formula(
         name, config, cells):
+    """Over one table the rate is table rows x queries / time, to the
+    digit; over several a query counts the rows of the tables it names
+    (``old`` with their sum)."""
     def old(records, t_start, table_rows):      # the parent's body
         return table_rows * len(records) / (max(r.done for r in records)
                                             - t_start)
 
-    table_rows, t_start = 59_998_987, 1234.567
+    tables = tables_of(config)
+    rows_of = {t["name"]: 59_998_987 // (i + 1) for i, t in enumerate(tables)}
+    t_start = 1234.567
     for cell in cells:
         traffic = load(ROOT, "benchmarks", "traffic", cell["traffic"] + ".json")
         queries = {s["query"]: load(ROOT, "benchmarks", "queries",
                                     s["query"] + ".json")
                    for s in traffic["statements"]}
         rows_scanned = {
-            q: sum({config["table"]: table_rows}[t]
-                   for t in query_tables(queries[q], tables_of(config)))
+            q: sum(rows_of[t] for t in query_tables(queries[q], tables))
             for q in queries}
+        if "tables" not in config:
+            assert set(rows_scanned.values()) == {59_998_987}
         records = []
         for i in range(37):
             r = Record(list(queries)[i % len(queries)], {}, None)
@@ -255,5 +284,7 @@ def test_over_one_table_scan_rows_per_s_is_the_old_formula(
         done = [r for r in records if r.error is None]
         got = metrics.end_to_end(["scan_rows_per_s", "query_p50_ms"],
                                  records, t_start, rows_scanned)
-        assert got["scan_rows_per_s"] == old(done, t_start, table_rows)
+        # every statement of a shipped cell scans the same tables
+        scanned, = set(rows_scanned.values())
+        assert got["scan_rows_per_s"] == old(done, t_start, scanned)
         assert got["query_p50_ms"] == pytest.approx(1234.5)

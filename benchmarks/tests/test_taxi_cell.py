@@ -131,7 +131,7 @@ def test_taxi_cell_traced_reports_the_groupby_layer(checkout):
     assert out["correct"] is True and out["failed"] == 0
     want = traced_metrics(checkout, TAXI)
     assert set(out["metrics"]) == want
-    assert {"groupby_init_ms", "groupby_fetch_ms", "groupby_combine_ms",
+    assert {"groupby_init_ms", "result_fetch_ms", "groupby_combine_ms",
             "groupby_groups_per_query"} <= want
     m = {k: v["value"] for k, v in out["metrics"].items()}
     assert m["compiles_in_window"] == 0 and m["cache_hit_share"] == 100
@@ -141,12 +141,18 @@ def test_taxi_cell_traced_reports_the_groupby_layer(checkout):
     assert counters["direct_groups"] == (181 * 24 + 1) * n
     assert 1500 * n < counters["direct_groups_out"] < 4000 * n
     assert counters["fused_dispatches"] == 8 * n
-    # every metric the taxi cell adds is this cell's alone
-    mine = {m["name"] for m in bench(checkout)["per_layer"]
-            if m["name"].startswith("groupby_")}
-    assert len(mine) == 8 and all(
-        m["workloads"] == [TAXI] for m in bench(checkout)["per_layer"]
-        if m["name"] in mine)
+    # of the eight metrics the taxi cell added, four are this cell's
+    # alone; four read what another cell's copy read and, since PR 47,
+    # are one entry that lists both cells
+    by_name = {m["name"]: m for m in bench(checkout)["per_layer"]}
+    mine = {"groupby_kernel_hbm_roofline", "groupby_init_ms",
+            "groupby_combine_ms", "groupby_groups_per_query"}
+    assert all(by_name[n]["workloads"] == [TAXI] for n in mine)
+    shared = {"scan_kernel_ms", "scan_dispatches_per_query",
+              "result_fetch_ms", "group_kernel_mxu_roofline"}
+    assert all(TAXI in by_name[n]["workloads"]
+               and len(by_name[n]["workloads"]) > 1 for n in shared)
+    assert m["result_fetch_ms"] > 0
 
 
 def test_lookup_cell_traced(checkout):

@@ -85,6 +85,35 @@ def test_busy_union_window_and_modules(profile):
     assert r["ops"]["all-reduce.3"] == pytest.approx(0.0005)
 
 
+@pytest.mark.parametrize("name,collective", [
+    ("all_to_all.27", True),        # a v5e trace: named after lax.all_to_all
+    ("all-to-all.3", True),
+    ("%all_to_all.27 = u32[4,1,163840]{2,1,0} all-to-all(u32[4,1,163840]{2,1,0}"
+     " %fusion.9), replica_groups={{0,1,2,3}}", True),
+    ("all-reduce-start.2", True), ("all_reduce.1", True),
+    ("all-gather.4", True), ("all_gather.4", True),
+    ("reduce-scatter.1", True), ("reduce_scatter.1", True),
+    ("collective-permute.5", True), ("collective_permute.5", True),
+    ("ppermute.2", True),
+    ("fusion.46", False), ("while.20", False), ("copy.3", False),
+])
+def test_a_collective_is_found_under_either_spelling_of_its_name(name,
+                                                                 collective):
+    """``collective_s`` feeds the ``trace_collectives`` reader and
+    nothing else: the op counts as busy time and under its own name in
+    ``ops`` whichever way this goes."""
+    host = Plane("/host:CPU", [Line("python", [ev("bench.execute.q12", 0, 10)])])
+    chips = [Plane(f"/device:TPU:{d}", [
+        Line("XLA Ops", [ev("fusion.1", 2, 2), ev(name, 4, 1)]),
+        Line("XLA Modules", [ev("jit_join_exchange(5)", 2, 3)])])
+        for d in range(4)]
+    r = trace_reduce.reduce_trace(Profile([host] + chips))
+    assert r["collective_s"] == pytest.approx(0.001 if collective else 0.0)
+    assert r["busy_s"] == pytest.approx(0.003) and r["n_devices"] == 4
+    assert r["ops"][trace_reduce.op_name(name)] == pytest.approx(0.001)
+    assert r["modules"]["jit_join_exchange"]["seconds"] == pytest.approx(0.003)
+
+
 def test_idle_gaps_say_what_the_host_was_doing(profile):
     gaps = trace_reduce.reduce_trace(profile)["gaps"]
     assert gaps == {

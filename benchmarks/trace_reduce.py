@@ -20,8 +20,12 @@ HOST_PLANE = "/host:CPU"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 SPAN_PREFIX = "bench.execute."
+#: a collective op's name starts with its HLO opcode (``all-to-all.3``)
+#: or with the JAX primitive XLA named the instruction after
+#: (``all_to_all.27`` in a v5e trace)
 COLLECTIVES = ("all-reduce", "all-gather", "all-to-all", "reduce-scatter",
-               "collective-permute")
+               "collective-permute", "all_reduce", "all_gather", "all_to_all",
+               "reduce_scatter", "collective_permute", "ppermute")
 
 
 def _intervals(line):
