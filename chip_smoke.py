@@ -145,6 +145,35 @@ WHERE c_custkey = o_custkey AND l_orderkey = o_orderkey
   AND c_mktsegment <> 'MACHINERY'
 GROUP BY c_mktsegment ORDER BY c_mktsegment"""
 
+# TPC-H Q10's shape in small tables of its own (a few hundred thousand
+# orders, so that the numpy arm can answer it too): four relations along
+# a chain, seven group keys of which six are functions of the customer
+# key, ORDER BY revenue DESC LIMIT 20
+Q10_ORDERS = 300_000
+Q10_DDL = (
+    ("q10_orders", "o_orderkey", """CREATE TABLE q10_orders (
+    o_orderkey bigint NOT NULL, o_custkey bigint, o_orderdate date)"""),
+    ("q10_lineitem", "l_orderkey", """CREATE TABLE q10_lineitem (
+    l_orderkey bigint NOT NULL, l_extendedprice decimal(12,2),
+    l_discount decimal(12,2), l_returnflag text)"""),
+    ("q10_customer", None, """CREATE TABLE q10_customer (
+    c_custkey bigint NOT NULL, c_name text, c_address text,
+    c_nationkey integer, c_phone text, c_acctbal decimal(15,2),
+    c_comment text)"""),
+    ("q10_nation", None, """CREATE TABLE q10_nation (
+    n_nationkey integer NOT NULL, n_name text)"""))
+Q10_DATE = datetime.date(1994, 1, 1)
+Q_Q10 = f"""select c_custkey, c_name,
+  sum(l_extendedprice * (1 - l_discount)) as revenue, c_acctbal, n_name,
+  c_address, c_phone, c_comment
+from q10_customer, q10_orders, q10_lineitem, q10_nation
+where c_custkey = o_custkey and l_orderkey = o_orderkey
+  and o_orderdate >= date '{Q10_DATE}'
+  and o_orderdate < date '{Q10_DATE}' + interval '3' month
+  and l_returnflag = 'R' and c_nationkey = n_nationkey
+group by c_custkey, c_name, c_acctbal, c_phone, n_name, c_address, c_comment
+order by revenue desc limit 20"""
+
 # float64 lanes: the TPU holds them as float32 pairs, so the hash
 # fingerprint and the HLL hash of a float value take a path of their own
 MEASURES_DDL = "CREATE TABLE measures (m_key bigint NOT NULL, x double precision)"
@@ -788,6 +817,94 @@ def leg_join_colocated(run, ref):
                overflow_rounds=j["overflow_rounds"])
 
 
+def leg_join_q10(run, rng, shards, n_orders):
+    """TPC-H Q10's shape on the device: nation and customer built once,
+    orders once a shard pair, the groups on the ONE key lane the join
+    proves decides them (1 of 7), ORDER BY ... LIMIT 20 cut on the chip
+    (``jit_hash_top``) and the six other keys looked up in customer's
+    resident table for the groups that come home (``jit_join_lookup``);
+    the answer is the numpy arm's and a plain numpy join's."""
+    cl = run.cl
+    orders = min(Q10_ORDERS, max(n_orders, 20_000))
+    customers = max(orders // 10, 5000)
+    for table, column, ddl in Q10_DDL:
+        cl.execute(ddl)
+        cl.execute(f"SELECT create_distributed_table('{table}', '{column}', "
+                   f"{shards})" if column
+                   else f"SELECT create_reference_table('{table}')")
+    nations = ["ALGERIA", "ARGENTINA", "BRAZIL", "CANADA", "EGYPT"]
+    c_key = np.arange(1, customers + 1)
+    c_nation = rng.integers(0, len(nations), customers)
+    c_acctbal = rng.integers(-99_999, 1_000_000, customers)
+    word = lambda k, salt: f"{salt}{(k * 2654435761) % 4294967291:010d}"
+    text = {c: [word(k, c[2]) for k in c_key.tolist()]
+            for c in ("c_address", "c_phone", "c_comment")}
+    cl.copy_from("q10_nation", columns={
+        "n_nationkey": np.arange(len(nations), dtype=np.int32),
+        "n_name": nations})
+    cl.copy_from("q10_customer", columns=dict(
+        text, c_custkey=c_key,
+        c_name=[f"Customer#{k:09d}" for k in c_key.tolist()],
+        c_nationkey=c_nation.astype(np.int32), c_acctbal=c_acctbal / 100.0))
+    o_key = np.arange(orders, dtype=np.int64) * 4 + 1
+    o_cust = rng.integers(1, customers + customers // 3, orders)
+    o_date = days(Q10_DATE) + rng.integers(-200, 300, orders)
+    cl.copy_from("q10_orders", columns={
+        "o_orderkey": o_key, "o_custkey": o_cust,
+        "o_orderdate": o_date.astype(np.int32)})
+    at = np.repeat(np.arange(orders), rng.integers(1, 8, orders))
+    price = rng.integers(100, 10_000_000, at.size)
+    disc = rng.integers(0, 11, at.size)
+    flag = rng.integers(0, 3, at.size)
+    cl.copy_from("q10_lineitem", columns={
+        "l_orderkey": o_key[at], "l_extendedprice": price / 100.0,
+        "l_discount": disc / 100.0,
+        "l_returnflag": RETURNFLAGS[flag].tolist()})
+
+    r, d, el, ev = run.run(Q_Q10)
+    j = r.explain.get("join", {})
+    check(r.explain["strategy"] == "join:colocated" and j.get("on") == "device"
+          and d.get("join_host_fallbacks", 0) == 0, f"Q10: {r.explain}")
+    check((j["group_keys"], j["group_key_lanes"],
+           j["group_keys_dependent"]) == (7, 1, 6),
+          f"Q10 groups on {j['group_key_lanes']} of {j['group_keys']} keys, "
+          f"{j['group_keys_dependent']} dependent")
+    check(isinstance(j["top"], dict) and j["top"]["rows"] == 20
+          and j["top"]["entries"] < j["agg_slots"]
+          and d.get("group_top_cuts") == 1, f"Q10 was not cut: {j['top']}")
+    check({"jit_join_probe", "jit_hash_fused", "jit_hash_top"}
+          <= set(kernel_slots(ev)), f"Q10: slots {kernel_slots(ev)}")
+    # the plain join, in numpy: the R lines of the quarter's orders of a
+    # customer that exists
+    end = days(datetime.date(Q10_DATE.year, Q10_DATE.month + 3, 1))
+    keep = (flag == 2) & (o_date[at] >= days(Q10_DATE)) \
+        & (o_date[at] < end) & (o_cust[at] <= customers)
+    revenue = np.zeros(customers + 1, np.int64)
+    np.add.at(revenue, o_cust[at][keep], (price * (100 - disc))[keep])
+    groups = np.unique(o_cust[at][keep])
+    first = groups[np.lexsort((groups, -revenue[groups]))][:21]
+    check(len(set(revenue[first].tolist())) == first.size,
+          "Q10: two of the first 21 rows tie on revenue; take another seed")
+    want = [(int(k), f"Customer#{k:09d}", dec(revenue[k], 4),
+             dec(c_acctbal[k - 1], 2), nations[c_nation[k - 1]],
+             text["c_address"][k - 1], text["c_phone"][k - 1],
+             text["c_comment"][k - 1]) for k in first[:20].tolist()]
+    check(r.rows == want, f"Q10 answer {r.rows[:2]} want {want[:2]}")
+    check(j["groups"] == groups.size, f"Q10 groups {j['groups']}")
+    cl.execute("SET citus.task_executor_backend = 'cpu'")
+    try:
+        oracle = cl.execute(Q_Q10)
+    finally:
+        cl.execute("SET citus.task_executor_backend = 'tpu'")
+    check(oracle.rows == r.rows, "Q10: the numpy arm answers otherwise")
+    run.record("7f Q10 on the device (nation, customer, orders, lineitem: "
+               "1 of 7 keys grouped, top 20 cut on the chip, 6 keys looked "
+               "up)", "jit_hash_top", el, d, groups=j["groups"],
+               agg_slots=j["agg_slots"], entries_fetched=j["top"]["entries"],
+               groups_looked_up=j["groups_looked_up"],
+               rows_probed=j["rows_probed"], rows_out=j["rows_out"])
+
+
 def leg_join_repartition(run, ref, devices):
     """The single-hash repartition join on the device, on every device
     the machine has (TPC-H Q12's shape: ``orders`` is distributed on the
@@ -1015,6 +1132,7 @@ def main() -> int:
                         min(MEASURES_ROWS, args.rows))
         leg_router(run, ref)
         leg_join_colocated(run, ref)
+        leg_join_q10(run, rng, shards, n_orders)
         leg_join_repartition(run, ref, devices)
 
         memory = []

@@ -351,6 +351,11 @@ def _run_analyze(cl, stmt: A.Explain) -> list[str]:
                 line += (f", having on device: "
                          f"{pl.get('hash_entries_fetched', 0)} of "
                          f"{pl['hash_slots']} entries fetched")
+            if pl.get("group_top_cuts"):
+                # ORDER BY ... LIMIT was cut on the table
+                line += (f", {pl['group_top']}: "
+                         f"{pl['group_top_entries']} of {pl['hash_slots']} "
+                         f"entries fetched")
             lines.append(line)
         if "remote_wait_ms" in pl:
             wire = f", wire {pl['wire_format']}" \
@@ -419,6 +424,14 @@ def _explain_join(cl, stmt: A.Explain) -> Result:
                 f"probed {j['rows_probed']}, matched {j['rows_matched']}, "
                 f"out {j['rows_out']}; overflow rounds "
                 f"{j['overflow_rounds']}; groups {j['groups']}")
+            top = j["top"]
+            lines[-1] += (
+                f"; keys: {j['group_key_lanes']} of {j['group_keys']} "
+                f"grouped, {j['group_keys_dependent']} looked up for "
+                f"{j['groups_looked_up']} groups; "
+                + (f"top {top['rows']} on device: {top['entries']} entries "
+                   f"fetched" if isinstance(top, dict)
+                   else f"top not cut on device: {top}"))
             x = j.get("exchange")
             if x:
                 lines[-1] += (

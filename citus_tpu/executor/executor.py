@@ -836,32 +836,173 @@ _HAVING_NODES = (BBinOp, BUnOp, BScale, BCast, BIsNull, BCase, BLiteral,
                  BParam, BAggRef, BKeyRef)
 
 
-def _device_having(plan: PhysicalPlan):
-    """The plan's HAVING where the chip can decide it EXACTLY on a hash
-    table's entries, as ``(generic_having, specs, values)`` with its
-    comparison literals hoisted (one compiled filter per statement
-    family), or None: no HAVING, a node ``compile_expr`` computes in
-    floats or through a dictionary, or an aggregate whose extraction is
-    not plain integer arithmetic (``avg`` and its division, ``count
-    (DISTINCT)``, sketches, float states on the TPU's float32 pairs).
-    Read from the plan alone."""
+def _table_decides(plan: PhysicalPlan, e, dates: bool = False) -> bool:
+    """``e`` is computed on a hash table's entries EXACTLY as the host
+    computes it: nodes of ``_HAVING_NODES`` over integers, decimals and
+    booleans (``dates``: and dates, which order as integers), aggregates
+    whose extraction is plain integer arithmetic -- no ``avg`` and its
+    division, no ``count(DISTINCT)``, no sketch, no float state on the
+    TPU's float32 pairs, nothing through a dictionary."""
     from citus_tpu.executor.finalize import PLAIN_AGGS
-    from citus_tpu.planner.auto_param import hoist_literals
-    having = plan.bound.having
-    if having is None:
-        return None
-    for n in walk(having):
+    for n in walk(e):
         t = n.type
         if not (isinstance(n, _HAVING_NODES)
-                and (t.is_integer or t.is_decimal or t.kind == T.BOOL)):
-            return None
+                and (t.is_integer or t.is_decimal or t.kind == T.BOOL
+                     or (dates and t.kind == T.DATE))):
+            return False
         if isinstance(n, BAggRef):
             ex = plan.agg_extract[n.index]
             if ex.kind not in PLAIN_AGGS or not all(
                     np.issubdtype(np.dtype(plan.partial_ops[i].dtype),
                                   np.integer) for i in ex.slots[:2]):
-                return None
+                return False
+    return True
+
+
+def _device_having(plan: PhysicalPlan):
+    """The plan's HAVING where the chip can decide it EXACTLY on a hash
+    table's entries (``_table_decides``), as ``(generic_having, specs,
+    values)`` with its comparison literals hoisted (one compiled filter
+    per statement family), or None.  Read from the plan alone."""
+    from citus_tpu.planner.auto_param import hoist_literals
+    having = plan.bound.having
+    if having is None or not _table_decides(plan, having):
+        return None
     return hoist_literals(having, len(plan.bound.param_specs))
+
+
+#: rows an ORDER BY ... LIMIT may ask for and still be cut on the chip
+TOP_ROWS_MAX = 1 << 16
+#: the host's keys go up to the cut in a power-of-two count of at least
+#: this many, so that a table that spills a few hundred keys more under
+#: one parameter than under another compiles nothing new
+TOP_HOST_KEYS = 2048
+
+
+def _device_top(plan: PhysicalPlan):
+    """The plan's ORDER BY ... LIMIT (+ OFFSET) where the chip can cut
+    it EXACTLY on a hash table's entries, as ``(having, order, rows)``
+    -- ``having`` as ``_device_having`` gives it (None: the statement
+    has none), ``order`` the ORDER BY's ``(final expression, ascending,
+    nulls first)``, ``rows`` LIMIT + OFFSET -- or the reason (a string)
+    every group comes home: no LIMIT, a DISTINCT above the groups, a
+    HAVING or an ORDER BY key the table does not decide (a text key:
+    dictionary ids are not ordered; a float; an ``avg``).  Read from the
+    plan alone."""
+    b = plan.bound
+    if not b.order_by or b.limit is None:
+        return "no ORDER BY ... LIMIT"
+    if b.distinct:
+        return "DISTINCT above the groups"
+    rows = int(b.limit) + int(b.offset or 0)
+    if rows > TOP_ROWS_MAX:
+        return f"LIMIT + OFFSET past {TOP_ROWS_MAX}"
+    having = None
+    if b.having is not None:
+        having = _device_having(plan)
+        if having is None:
+            return "HAVING is not decided on the chip"
+    order = []
+    for idx, asc, nulls_first in b.order_by:
+        e = b.final_exprs[idx]
+        if not _table_decides(plan, e, dates=True):
+            return (f"ORDER BY key {b.output_names[idx]} ({e.type.kind}) is "
+                    f"not ordered on the chip")
+        order.append((e, asc, nulls_first))
+    return having, order, rows
+
+
+def _ending_arguments(acc, key_dtypes: tuple, M: int, params, having):
+    """What both filtered endings hand their kernel after the table
+    state: the plan's parameters and then HAVING's hoisted literals
+    (``having``: ``_device_having``'s, or None), and the keys the host
+    accumulator holds, padded to ``M``, with their count."""
+    pcols, pvalids = params
+    _, specs, values = having or (None, [], [])
+    hoisted = tuple(np.asarray(v, t.device_dtype)
+                    for (t, _), v in zip(specs, values))
+    n_host = acc.n_groups
+    host_keys = tuple(
+        (np.concatenate([kv, np.zeros(M - n_host, kv.dtype)]),
+         np.concatenate([kvm, np.zeros(M - n_host, bool)]))
+        for kv, kvm in acc.key_arrays(key_dtypes))
+    return (pcols + hoisted, pvalids + (np.ones((), bool),) * len(hoisted),
+            host_keys, np.int32(n_host))
+
+
+def _raise_on_overflow(plan: PhysicalPlan, overflows, host_entries,
+                       found) -> None:
+    """The chip's verdicts count for the keys the host holds no part
+    of: an overflow among those (``overflows``, per aggregate that
+    carries a shadow, over every occupied entry) raises as
+    ``finalize_groups`` would, kept or not; the host's keys (their
+    entries ``host_entries``, in the table where ``found``) are checked
+    after their merge."""
+    from citus_tpu.executor.finalize import (
+        raise_sum_overflow, sum_overflow_mask,
+    )
+    of_host_keys = [int((bad & found).sum()) for bad in (
+        sum_overflow_mask(np, ex, host_entries[1])
+        for ex in plan.agg_extract) if bad is not None]
+    if any(int(np.sum(n)) > m for n, m in zip(overflows, of_host_keys)):
+        raise_sum_overflow()
+
+
+def _fetch_hash_top(plan: PhysicalPlan, state, acc, params, top,
+                    record: PipelineStats, key_lanes=None):
+    """The cut ending of a hash scan of ONE table: ORDER BY ... LIMIT is
+    cut on the table on its chip (kernel slot ``jit_hash_top``,
+    ``ops/hash_agg.py`` ``build_hash_top``) and the first ``rows``
+    candidates come home as a power-of-two block, beside the entries of
+    the keys ``acc`` holds a part of (their final state is entry + host
+    part, so they stood aside from the sort and the host decides them).
+    ``top`` is ``_device_top``'s; ``key_lanes`` as ``build_hash_top``
+    takes it.  Returns ``(table, entry_mask, groups)``, the layout
+    ``_finish_hash_agg`` merges, ``groups`` the aggregation's groups
+    before HAVING and the cut; or None where block and host keys would
+    pass half the table and the whole of it may as well come."""
+    import jax
+    import jax.numpy as jnp
+    from citus_tpu.ops.hash_agg import build_hash_top
+    from citus_tpu.planner.bound import param_env_names
+
+    having, order, rows = top
+    S = int(state[2].shape[-1])
+    n_host = acc.n_groups
+    M = _pow2_at_least(n_host, TOP_HOST_KEYS)
+    B = _pow2_at_least(rows, 32)
+    if B + M > S // 2:
+        return None
+    generic, specs, _ = having or (None, [], [])
+    key_dtypes = tuple(a.dtype for a, _ in state[0])
+    names = tuple(param_env_names(list(plan.bound.param_specs) + specs))
+    kernel = get_kernel(
+        plan, "jit_hash_top",
+        lambda: jit_compile(build_hash_top(plan, jnp, generic, order, names,
+                                           B, key_lanes)),
+        extra=(repr(generic), repr(order), B, key_lanes,
+               repr(plan.agg_extract)))
+    with _trace.span("group_top") as sp:
+        # the host's keys go up once; the winners' block and the host's
+        # keys' entries come back in ONE device_get
+        winners, candidates, occupied, overflows, host_slots, entries = \
+            jax.device_get(kernel(state, *_ending_arguments(
+                acc, key_dtypes, M, params, having)))
+        found = host_slots < S
+        _raise_on_overflow(plan, overflows, entries, found)
+        kept = min(int(candidates), B)
+        if sp.recording:
+            sp.set(slots=S, entries=B + M, kept=kept, spilled_keys=n_host,
+                   limit=rows, candidates=int(candidates))
+    record.figures["hash_occupancy_pct"] = round(
+        100.0 * int(occupied) / S, 1)
+    record.tally("group_top_cuts", 1)
+    record.tally("group_top_entries", B + M)
+    table = jax.tree_util.tree_map(
+        lambda a, b: np.concatenate([a.reshape(-1), b.reshape(-1)]),
+        entries, winners)
+    return (table, np.concatenate([found, np.arange(B) < kept]),
+            int(occupied) + n_host - int(found.sum()))
 
 
 def _fetch_hash_survivors(plan: PhysicalPlan, tables: _HashTables, acc,
@@ -880,9 +1021,6 @@ def _fetch_hash_survivors(plan: PhysicalPlan, tables: _HashTables, acc,
     that most keys spilled)."""
     import jax
     import jax.numpy as jnp
-    from citus_tpu.executor.finalize import (
-        raise_sum_overflow, sum_overflow_mask,
-    )
     from citus_tpu.ops.hash_agg import (
         FILTER_BLOCK, build_hash_having, hash_take,
     )
@@ -898,7 +1036,7 @@ def _fetch_hash_survivors(plan: PhysicalPlan, tables: _HashTables, acc,
     least_blocks = max(8, -(-S // FILTER_BLOCK) >> 8)
     if least_blocks * FILTER_BLOCK + M > S // 2:
         return None
-    generic, specs, values = having
+    generic, specs, _ = having
     key_dtypes = tuple(a.dtype for a, _ in state[0])
     names = tuple(param_env_names(list(plan.bound.param_specs) + specs))
     kernel = _table_kernel(
@@ -906,34 +1044,18 @@ def _fetch_hash_survivors(plan: PhysicalPlan, tables: _HashTables, acc,
         lambda: build_hash_having(plan, jnp, generic, names),
         extra=(repr(generic), repr(plan.agg_extract)),
         replicated=(1, 2, 3, 4))
-    pcols, pvalids = params
-    hoisted = tuple(np.asarray(v, t.device_dtype)
-                    for (t, _), v in zip(specs, values))
     # one table: its results as the first of one
     lead = (lambda tree: tree) if mesh is not None else (
         lambda tree: jax.tree_util.tree_map(lambda a: a[None], tree))
     with _trace.span("hash_filter") as sp:
         # the host's keys go up once; their entries come back with the
         # marks, in ONE device_get
-        host_keys = tuple(
-            (np.concatenate([kv, np.zeros(M - n_host, kv.dtype)]),
-             np.concatenate([kvm, np.zeros(M - n_host, bool)]))
-            for kv, kvm in acc.key_arrays(key_dtypes))
-        keep, home = kernel(
-            state, pcols + hoisted,
-            pvalids + (np.ones((), bool),) * len(hoisted),
-            host_keys, np.int32(n_host))
+        keep, home = kernel(state, *_ending_arguments(
+            acc, key_dtypes, M, params, having))
         marks, occupied, overflows, host_slots, entries = \
             lead(jax.device_get(home))
         found = host_slots < S
-        # the chip's verdicts count for the keys the host holds no part
-        # of: an overflow among those raises as finalize_groups would,
-        # kept or not; the host's keys are checked after their merge
-        of_host_keys = [int((bad & found).sum()) for bad in (
-            sum_overflow_mask(np, ex, entries[1])
-            for ex in plan.agg_extract) if bad is not None]
-        if any(int(n.sum()) > m for n, m in zip(overflows, of_host_keys)):
-            raise_sum_overflow()
+        _raise_on_overflow(plan, overflows, entries, found)
         blocks = [np.flatnonzero(m) for m in marks]
         if sp.recording:
             sp.set(slots=S, host_keys=n_host, tables=tables.tables,
@@ -1094,9 +1216,17 @@ def _run_agg_hash_host(cat: Catalog, plan: PhysicalPlan, settings: Settings,
         apart = tables.tables == 1 or tables.disjoint is not None
         merged = record.tally("hash_tables_merged",
                               0 if apart else tables.tables)
-        having = apart and _device_having(plan)
-        home = having and _fetch_hash_survivors(plan, tables, acc, params,
-                                                having, record)
+        # ORDER BY ... LIMIT over one table that the chip can cut: the
+        # winners' block comes home; else what HAVING leaves, else all
+        top = _device_top(plan) if tables.mesh is None \
+            else "one table a device"
+        record.figures["group_top"] = top if isinstance(top, str) \
+            else f"first {top[2]} on device"
+        home = isinstance(top, tuple) and _fetch_hash_top(
+            plan, tables.state, acc, params, top, record)
+        having = not home and apart and _device_having(plan)
+        home = home or (having and _fetch_hash_survivors(
+            plan, tables, acc, params, having, record))
         if home:
             table, entry_mask, groups = home
             return _finish_hash_agg(cat, plan, acc, table, penv, record,

@@ -294,6 +294,14 @@ def _decode_column(cat: Catalog, expr_type: T.ColumnType, source, v, valid
     through ``decode_qualified`` was most of ``finalize_groups``; at a
     hundred thousand, ``from_physical``'s own dispatch is, so integers
     (``tolist`` made them) and decimals convert in place."""
+    if expr_type.kind == T.TEXT and source is not None \
+            and v.dtype.kind == "i" and v.ndim == 1:
+        # dictionary ids: one lookup for the column's words, not one a
+        # cell (a NULL's lane may hold anything: it asks for word 0)
+        words = cat.decode_strings(source[0], source[1],
+                                   np.where(valid, v, 0).tolist())
+        return words if valid.all() else [
+            w if ok else None for w, ok in zip(words, valid.tolist())]
     if expr_type.is_text or v.dtype == object or v.ndim != 1:
         return [decode_qualified(cat, expr_type, source, x, bool(ok))
                 for x, ok in zip(v, valid)]

@@ -70,16 +70,20 @@ from citus_tpu.observability import trace as _trace
 from citus_tpu.ops import join as J
 from citus_tpu.planner.auto_param import hoist_literals
 from citus_tpu.planner.bound import (
-    BColumn, compile_expr, param_env_names, walk,
+    BColumn, BKeyRef, compile_expr, param_env_names, walk,
 )
 from citus_tpu.planner.join_planner import (
-    BoundJoinSelect, DeviceJoinTree, plan_device_join,
+    BoundJoinSelect, DeviceJoinTree, dependent_group_keys, plan_device_join,
 )
 
 #: slots of the aggregate's table: the probe relation's rows over this,
 #: between these bounds (what it cannot hold spills to the host, exactly)
 AGG_ROWS_PER_SLOT = 8
 AGG_SLOTS = (1 << 10, 1 << 20)
+#: rows of the block the returned groups' keys are looked up in, at
+#: least: a cut's winners and the host's groups fit it whatever the
+#: parameter, so a new draw compiles nothing
+LOOKUP_ROWS = 4096
 #: share of the device's free memory a direct-address table's index may
 #: take
 DIRECT_MEMORY_SHARE = 0.25
@@ -199,17 +203,9 @@ class _DeviceJoin:
             return str(self.tables_of[alias].schema.scan_dtype(
                 col, device=True))
 
-        out = _names_in(list(bj.group_keys) + list(bj.agg_args))
-        at_root = _names_in([post]) + out
-        payload = {}
-        for a in tree.builds:
-            under = set(tree.subtree(a))
-            payload[a] = tuple(sorted(
-                {(n, dtype_of(n)) for n in at_root
-                 if n.split(".", 1)[0] in under}))
-
         # a single-hash repartition runs over every device, a table
         # each; whatever else the device joins, on the first
+        from citus_tpu.executor.executor import _device_top
         from citus_tpu.parallel.mesh import default_mesh, executor_devices
         self.exchanged = tree.exchanged[0] if tree.exchanged else None
         # the key lane the exchange hashes, as its column is named
@@ -220,6 +216,65 @@ class _DeviceJoin:
                 self.tables_of[root].shard_count:
             self.mesh = default_mesh()
             self.n_dev = len(executor_devices())
+        # a build node is rebuilt per shard where it, or a relation
+        # under it, is distributed; else once a query -- as every one
+        # is where the distributed build is exchanged
+        self.per_shard = {
+            a: not self.exchanged and any(
+                self.tables_of[b].is_distributed for b in tree.subtree(a))
+            for a in tree.builds}
+
+        # the statement above the groups, as the table's endings read a
+        # plan: ORDER BY ... LIMIT is cut on the chip where the table
+        # decides it (one table: the tables of a mesh may share a group)
+        above = SimpleNamespace(
+            having=bj.having, order_by=bj.order_by, limit=bj.limit,
+            offset=bj.offset, distinct=bj.distinct,
+            final_exprs=bj.final_exprs, output_names=bj.output_names,
+            param_specs=specs)
+        self.top = "one table a device" if self.mesh is not None \
+            else _device_top(SimpleNamespace(
+                bound=above, agg_extract=bj.agg_extract,
+                partial_ops=bj.partial_ops))
+        # the group keys that decide the groups; the others are looked
+        # up for the groups that are returned, in the tables that still
+        # stand then (one built a shard is gone with its shard, and a
+        # mesh holds a table a device: their keys stay lanes).  A key
+        # the cut reads before any lookup stays a lane too: the table
+        # has its lane anyway, and a lookup a slot would walk the build
+        # table once more for every occupied entry
+        pinned = set()
+        if isinstance(self.top, tuple):
+            read = [e for e, _, _ in self.top[1]]
+            read += [self.top[0][0]] if self.top[0] else []
+            pinned = {n.index for e in read for n in walk(e)
+                      if isinstance(n, BKeyRef)}
+        self.keys = dependent_group_keys(
+            bj, tree, pinned,
+            resident={a for a in tree.builds if not self.per_shard[a]}
+            if self.mesh is None else set())
+        resolvers = set(self.keys.lookups)
+        held = {bj.group_keys[i].name: b
+                for i, b in self.keys.resolver.items()}
+
+        out = _names_in(list(self.keys.lanes) + list(bj.agg_args))
+        at_root = _names_in([post]) + out
+        # a build's table carries what the root needs of its subtree
+        # and, after those, the dependants that it or a build above it
+        # holds for the lookup; a parent gathers the lanes it carries on
+        # itself: all of them, or the first ones alone where the child
+        # is the build that holds the dependants
+        carried, payload = {}, {}
+        for a in tree.builds:
+            under = set(tree.subtree(a))
+            base = sorted({(n, dtype_of(n)) for n in at_root
+                           if n.split(".", 1)[0] in under})
+            extra = sorted({(n, dtype_of(n)) for n, b in held.items()
+                            if a in tree.subtree(b)
+                            and n.split(".", 1)[0] in under} - set(base))
+            carried[a] = tuple(base + extra)
+            payload[a] = tuple(base) if a in resolvers else carried[a]
+        self.carried = carried
 
         self.kind = {a: "hash" for a in tree.builds}
         self.spans: dict = {}
@@ -239,26 +294,20 @@ class _DeviceJoin:
                                  self.kind[c])
                     for c in tree.children(a)),
                 key=() if a == root else tuple(tree.edge[a][0]),
-                payload=() if a == root else payload[a],
+                payload=() if a == root else carried[a],
                 post_filter=post if a == root else None,
                 out=tuple(out) if a == root else (),
                 kind=self.kind.get(a, "hash"))
 
         self.nodes = {a: node(a) for a in tree.builds + [root]}
         self.out_dtypes = tuple(dtype_of(n) for n in out)
-        # a build node is rebuilt per shard where it, or a relation
-        # under it, is distributed; else once a query -- as every one
-        # is where the distributed build is exchanged
-        self.per_shard = {
-            a: not self.exchanged and any(
-                self.tables_of[b].is_distributed for b in tree.subtree(a))
-            for a in tree.builds}
         scans = [(a, [(c, dtype_of(f"{a}.{c}"))
                       for c in bj.rel_plans[a].columns])
                  for a in self.nodes]
         fp = hashlib.sha256(repr((
-            sorted(self.nodes.items()), scans, bj.group_keys, bj.agg_args,
-            bj.partial_ops, len(specs), tree.exchanged)).encode()).hexdigest()
+            sorted(self.nodes.items()), scans, bj.group_keys, self.keys.lanes,
+            bj.agg_args, bj.partial_ops, len(specs),
+            tree.exchanged)).encode()).hexdigest()
         # every relation's columns ride at their logical widths: the
         # join's lanes are not narrowed from the statistics (yet).
         # This view, the aggregate's and the scans' share one
@@ -269,7 +318,7 @@ class _DeviceJoin:
             runtime_cache={"_fingerprint": fp})
         # the aggregate over the block, as the hash kernel reads a plan
         self.agg = SimpleNamespace(
-            bound=SimpleNamespace(filter=None, group_keys=list(bj.group_keys),
+            bound=SimpleNamespace(filter=None, group_keys=list(self.keys.lanes),
                                   param_specs=specs, table=None),
             agg_args=bj.agg_args, scan_columns=list(out),
             partial_ops=bj.partial_ops, agg_extract=bj.agg_extract,
@@ -287,6 +336,7 @@ class _DeviceJoin:
         self.built = {a: 0 for a in tree.builds}
         self.totals = np.zeros(4, np.int64)
         self.probed = self.overflow_rounds = self.later_level = 0
+        self.looked_up = 0       # groups whose dependants were looked up
         # the exchange: rounds whose counts are not home yet, and what
         # the ones that are have counted
         self.exchange_pending: list = []
@@ -671,33 +721,105 @@ class _DeviceJoin:
     def _agg_slots(self, key_dtypes: tuple) -> tuple:
         """Slots of a device's group table and where the number came
         from: the probe relation's rows over ``AGG_ROWS_PER_SLOT`` (a
-        device's share of them) between ``AGG_SLOTS``, or, where every
-        group key is a column whose type proves a domain (a dictionary,
-        a boolean: ``planner/physical.py`` ``_key_domain``) and that is
-        the smaller, twice the domains' product."""
-        from citus_tpu.planner.physical import _key_domain
+        device's share of them) between ``AGG_SLOTS`` (``row count``)
+        -- or, where every key lane is a column whose domain is proved
+        (by its type: a dictionary, a boolean, ``planner/physical.py``
+        ``_key_domain``; or by the footers' bounds of an integer or date
+        column of a table no transaction of this thread has staged rows
+        into), twice the domains' product (``key domain``): where that
+        is fewer slots, and also where it is more than ``AGG_SLOTS[1]``
+        and no more than the rows ask for -- the groups are at most the
+        product, so such a table stays under half full and few of its
+        keys spill to the host."""
+        from citus_tpu.catalog.stats import table_facts
+        from citus_tpu.planner.physical import _key_domain, sees_staged_rows
         rows = sum(self.shard_rows[self.tree.root]) // self.n_dev
-        S = min(AGG_SLOTS[1], _pow2_at_least(rows // AGG_ROWS_PER_SLOT,
-                                             AGG_SLOTS[0]))
+        by_rows = _pow2_at_least(rows // AGG_ROWS_PER_SLOT, AGG_SLOTS[0])
+        S = min(AGG_SLOTS[1], by_rows)
         domain = 1
-        for k in self.bj.group_keys:
-            d = None
+        for k in self.keys.lanes:
+            size = None
             if isinstance(k, BColumn) and "." in k.name:
                 alias, col = k.name.split(".", 1)
-                d = _key_domain(self.cat, self.tables_of[alias],
-                                BColumn(col, k.type), None)
-            if d is None:
+                table = self.tables_of[alias]
+                d = _key_domain(self.cat, table, BColumn(col, k.type), None)
+                size = d and d.size
+                if size is None and (k.type.is_integer
+                                     or k.type.kind == T.DATE) \
+                        and not sees_staged_rows(table):
+                    facts = table_facts(self.cat, table)
+                    lo, hi, *_ = (facts and facts.columns.get(col)) \
+                        or (None, None)
+                    if lo is not None and hi is not None:
+                        size = int(hi) - int(lo) + 2
+            if size is None:
                 return S, "row count"
-            domain *= d.size
+            domain *= size
         by_domain = _pow2_at_least(2 * domain, AGG_SLOTS[0])
-        return (by_domain, "key domain") if by_domain < S \
+        return (by_domain, "key domain") \
+            if by_domain < S or S < by_domain <= by_rows \
             else (S, "row count")
+
+    def _materialize(self, key_arrays: list) -> list:
+        """The returned groups' key lanes -> a (values, valid) pair for
+        every group key of the statement: a key that is a lane as it
+        came, a dependant looked up in the table of the build that holds
+        it, still resident -- the groups' lanes that are the build's
+        edge key go through the table as ONE block, the way the probe
+        walks it (``ops/join.py`` ``build_join_lookup``), and the
+        dependants' payload lanes come home with it."""
+        import jax
+        import jax.numpy as jnp
+        from citus_tpu.errors import ExecutionError
+        keys, bj = self.keys, self.bj
+        self.looked_up = 0
+        if not keys.dependants:
+            return [key_arrays[i] for i in keys.lane_of]
+        n = int(key_arrays[0][0].shape[0])
+        found = {}
+        with _trace.span("materialize_keys") as sp:
+            P = _pow2_at_least(n, LOOKUP_ROWS)
+            pad = lambda a: np.concatenate(
+                [a, np.zeros(P - n, a.dtype)])
+            for b, lane_ids in keys.lookups.items():
+                names = tuple(f"__lane_{i}" for i in lane_ids)
+                node = J.JoinNode(
+                    alias="__groups__", names=names, filter=None,
+                    children=(J.ChildProbe(
+                        b, tuple(BColumn(nm, keys.lanes[i].type)
+                                 for nm, i in zip(names, lane_ids)),
+                        self.carried[b], self.kind[b]),))
+                lookup = self._kernel(
+                    f"jit_join_lookup:{b}",
+                    lambda node=node: J.build_join_lookup(node, jnp))
+                cols, valids, hit = jax.device_get(lookup(
+                    (self.tables[b],),
+                    tuple(pad(np.asarray(key_arrays[i][0])) for i in lane_ids),
+                    tuple(pad(np.asarray(key_arrays[i][1], bool))
+                          for i in lane_ids),
+                    np.arange(P) < n))
+                if not hit[:n].all():
+                    # an inner join's group has a row in every build
+                    raise ExecutionError(
+                        f"a group's key is not in the table of {b}")
+                for (name, _), c, v in zip(self.carried[b], cols, valids):
+                    found[name] = (c[:n], v[:n])
+            self.looked_up = n
+            if sp.recording:
+                sp.set(groups=n, keys=keys.dependants,
+                       words=n * sum(bj.group_keys[i].type.is_text
+                                     for i in keys.resolver),
+                       tables=len(keys.lookups))
+        return [key_arrays[lane] if lane is not None
+                else found[bj.group_keys[i].name]
+                for i, lane in enumerate(keys.lane_of)]
 
     def run(self, t0: float):
         import jax
         import jax.numpy as jnp
         from citus_tpu.executor.executor import (
             GLOBAL_COUNTERS, _HashTables, _SpillDrain, _fetch_hash_table,
+            _fetch_hash_top,
         )
         from citus_tpu.executor.host_agg import HostGroupAccumulator
         from citus_tpu.executor.join_executor import (
@@ -725,12 +847,12 @@ class _DeviceJoin:
                for n, dt in zip(self.nodes[tree.root].out, self.out_dtypes)}
         key_dtypes = tuple(
             np.asarray(compile_expr(k, np)(env)[0]).dtype
-            for k in bj.group_keys)
+            for k in self.keys.lanes)
         self.agg_kernel = self._kernel(
             "jit_hash_fused",
             lambda: build_fused_hash_worker(self.agg, jnp, key_dtypes),
             donate_argnums=0)
-        acc = HostGroupAccumulator(len(bj.group_keys), bj.partial_ops)
+        acc = HostGroupAccumulator(len(self.keys.lanes), bj.partial_ops)
         self.drain = _SpillDrain(self.agg, [acc],
                                  devices=self.n_dev if self.mesh else 0)
         with _trace.span("hash_init") as sp:
@@ -754,31 +876,42 @@ class _DeviceJoin:
               record, stream=self._stream(), on_sync=self._sync)
         record.book_timings()
 
-        # one fetch: the tables come home whole (at most AGG_SLOTS[1]
-        # entries each; what they could not hold is in ``acc`` already)
-        h_keys, h_parts, h_rows = _fetch_hash_table(
-            _HashTables(self.agg_state, self.mesh), record)
-        fetched = hash_state_bytes((h_keys, h_parts, h_rows))
-        occupied = h_rows > 0
-        n = int(occupied.sum())
+        # the ending: ORDER BY ... LIMIT cut on the chip where the table
+        # decides it -- the winners' block and the entries of the host's
+        # keys come home -- else one fetch of the tables whole (what
+        # they could not hold is in ``acc`` already)
+        lanes = self.keys.lanes
         view = _JoinPlanView(bj)
+        home = isinstance(self.top, tuple) and _fetch_hash_top(
+            self.agg, self.agg_state, acc, self.params, self.top, record,
+            key_lanes=tuple(self.keys.lane_of))
+        not_cut = self.top if isinstance(self.top, str) \
+            else "block and host keys past half the table"
+        (h_keys, h_parts, h_rows), entry_mask, groups = home or (
+            _fetch_hash_table(_HashTables(self.agg_state, self.mesh), record),
+            None, None)
         with _trace.span("finalize_groups") as sp:
-            if acc.n_groups == 0 and n > 0 and self.mesh is None:
-                # nothing spilled: the entries are the groups
+            fetched = hash_state_bytes((h_keys, h_parts, h_rows))
+            entries = int(h_rows.shape[0])
+            occupied = h_rows > 0
+            if not home and acc.n_groups == 0 and occupied.any() \
+                    and self.mesh is None:
+                # every entry came and nothing spilled: they are the groups
                 key_arrays = [(kv[occupied], kf[occupied] == 2)
                               for kv, kf in h_keys]
                 partials = tuple(p[occupied] for p in h_parts)
-                groups = n
+                groups = int(occupied.sum())
             else:
-                # a group may sit in every device's table: they merge
-                # into the one accumulator, exactly
-                merge_hash_tables_into(acc, self.agg, h_keys, h_parts, h_rows)
+                # the host holds a part of some groups, and a group may
+                # sit in every device's table: they merge into the one
+                # accumulator, exactly
+                merge_hash_tables_into(acc, self.agg, h_keys, h_parts, h_rows,
+                                       entry_mask=entry_mask)
                 key_arrays, partials = acc.finalize(
-                    [g.type for g in bj.group_keys],
-                    scalar=not bj.group_keys)
-                groups = acc.n_groups
+                    [g.type for g in lanes], scalar=not lanes)
+                groups = groups if home else acc.n_groups
             rows = [] if partials is None else finalize_groups(
-                view, self.cat, key_arrays, partials,
+                view, self.cat, self._materialize(key_arrays), partials,
                 text_src=_join_text_src(bj))
             if sp.recording:
                 sp.set(groups=groups, rows=len(rows))
@@ -801,7 +934,20 @@ class _DeviceJoin:
             "table_bytes": table_bytes,
             "agg_slots": S, "agg_slots_from": slots_from, "groups": groups,
             "spilled_rows": self.drain.rows,
+            "group_keys": len(bj.group_keys),
+            "group_key_lanes": len(lanes),
+            "group_keys_dependent": self.keys.dependants,
+            "groups_looked_up": self.looked_up,
+            # the rows the cut asked for and the entries that came
+            # home, or why every group did
+            "top": {"rows": self.top[2], "entries": entries} if home
+            else not_cut,
         }
+        record.figures["group_top"] = not_cut if not home \
+            else f"first {self.top[2]} on device"
+        record.tally("group_keys", join["group_keys"])
+        record.tally("group_key_lanes", join["group_key_lanes"])
+        record.tally("group_keys_dependent", join["group_keys_dependent"])
         explain = {"join": join, "pipeline": record.figures}
         if self.exchanged:
             explain["shuffle"] = "local" if self.mesh is None \
@@ -835,7 +981,7 @@ class _DeviceJoin:
         GLOBAL_COUNTERS.bump("join_overflow_rounds", join["overflow_rounds"])
         GLOBAL_COUNTERS.bump("join_table_bytes", join["table_bytes"])
         GLOBAL_COUNTERS.bump("hash_table_bytes_fetched", fetched)
-        GLOBAL_COUNTERS.bump("hash_entries_fetched", S * self.n_dev)
+        GLOBAL_COUNTERS.bump("hash_entries_fetched", entries)
         GLOBAL_COUNTERS.bump("hash_groups_out", join["groups"])
         tasks = self.tables_of[tree.root].shard_count if self.exchanged \
             else max(1, self.n_shards)

@@ -126,6 +126,11 @@ METRIC_HELP = {
     "hash_table_updates": "entries (a batch's distinct keys) offered to device hash tables",
     "hash_table_bytes_fetched": "bytes of device hash tables fetched",
     "hash_entries_fetched": "entries (slots) of device hash tables fetched",
+    "group_keys": "group keys of the device joins that ran",
+    "group_keys_dependent": "of those, keys a join proved functions of another: looked up for the returned groups",
+    "group_key_lanes": "key lanes the device joins' group tables held",
+    "group_top_cuts": "statements whose ORDER BY ... LIMIT was cut on the chip",
+    "group_top_entries": "entries of device hash tables fetched by those cuts",
     "wait_remote_rpc_ms": "ms blocked on remote RPC round trips",
     "wait_lock_ms": "ms blocked acquiring advisory locks",
     "wait_prefetch_stall_ms": "ms the device starved for host decode",

@@ -556,6 +556,91 @@ def build_hash_having(plan: PhysicalPlan, xp, having,
     return hash_having
 
 
+def build_hash_top(plan: PhysicalPlan, xp, having, order, param_names: tuple,
+                   block: int, key_lanes=None) -> Callable:
+    """ORDER BY ... LIMIT cut on the table: (table_state, pcols, pvalids,
+    host_keys, n_host_keys) -> (winners, candidates, occupied, overflows,
+    host_slots, host_entries).
+
+    ``having`` (or None) and ``param_names`` as in ``build_hash_having``;
+    ``order`` is the ORDER BY as ``[(final expression, ascending, nulls
+    first), ...]`` over the table's keys and its ``finalize.PLAIN_AGGS``
+    aggregates in integers; ``key_lanes[i]`` is the table's lane that
+    holds the statement's group key ``i`` (None: every key has its own,
+    in order; an entry None: a key the table does not hold and no
+    expression here reads).  Every occupied entry is a CANDIDATE where
+    HAVING passes -- except the entries of the keys the host accumulator
+    holds a part of (``host_keys``, probed as ``build_hash_having``
+    probes them): theirs is a partial state, which bounds nothing (a sum
+    may be negative), so they stand aside from the sort and come home
+    whatever they hold (``host_slots``, ``host_entries``) for the host
+    to complete.  The candidates are sorted by the ORDER BY's keys --
+    PostgreSQL's NULL placement, a descending key as its complement, the
+    slot last so that the order is total -- and ``winners`` are the first
+    ``block`` of them as a table state of ``block`` entries, of which
+    the first min(``candidates``, ``block``) are real.  Whatever the
+    whole table and the host's groups would have given as the first
+    ``block`` rows is among the winners and the host's groups, each
+    complete once merged.  The table state is read, not donated."""
+    from jax import lax
+
+    from citus_tpu.executor.finalize import plain_agg, sum_overflow_mask
+    from citus_tpu.planner.bound import BAggRef, walk
+
+    having_fn = compile_expr(having, xp) if having is not None else None
+    order_fns = [(compile_expr(e, xp), asc, (not asc) if nf is None else nf)
+                 for e, asc, nf in order]
+    read = {n.index for e in [having] + [e for e, _, _ in order]
+            if e is not None for n in walk(e) if isinstance(n, BAggRef)}
+    extracts = plan.agg_extract
+
+    # named for its kernel slot, like hash_fused: the XLA module in a
+    # device trace is jit_hash_top
+    def hash_top(table_state, pcols, pvalids, host_keys, n_host_keys):
+        key_tables, partials, rows = table_state
+        S = rows.shape[0]
+        occ = rows > 0
+        env = {n: (c, v) for n, c, v in zip(param_names, pcols, pvalids)}
+        lanes = [(kvt, kft == 2) for kvt, kft in key_tables]
+        env["__keys__"] = lanes if key_lanes is None else [
+            None if i is None else lanes[i] for i in key_lanes]
+        env["__aggs__"] = [plain_agg(xp, ex, partials) if i in read else None
+                           for i, ex in enumerate(extracts)]
+        keep = occ
+        if having_fn is not None:
+            keep = keep & predicate_mask(xp, having_fn, env, rows)
+        live = xp.arange(host_keys[0][0].shape[0],
+                         dtype=np.int32) < n_host_keys
+        slot = _probe_slots(xp, _canon_keys(xp, host_keys), live,
+                            key_tables, occ)
+        aside = xp.zeros((S,), bool).at[slot].set(True, mode="drop")
+        candidate = keep & ~aside
+        operands = []
+        for fn, asc, nulls_first in order_fns:
+            v, valid = fn(env)
+            # a sort lane as wide as the key: XLA for TPU compiles a
+            # sort by the 32-bit lane
+            v = xp.asarray(v)
+            v = xp.broadcast_to(v, (S,)).astype(
+                np.int64 if v.dtype.itemsize > 4 else np.int32)
+            valid = xp.broadcast_to(_as_mask(xp, valid, v), (S,))
+            rank = xp.where(valid, np.int32(nulls_first),
+                            np.int32(not nulls_first))
+            if not operands:
+                # the entries that are no candidate sort behind all
+                rank = xp.where(candidate, rank, np.int32(2))
+            operands += [rank, xp.where(valid, v if asc else ~v, 0)]
+        operands.append(xp.arange(S, dtype=np.int32))
+        at = lax.sort(tuple(operands), num_keys=len(operands))[-1][:block]
+        bad = [sum_overflow_mask(xp, ex, partials) for ex in extracts]
+        overflows = [(b & occ).sum(dtype=np.int32)
+                     for b in bad if b is not None]
+        return (hash_take(table_state, at), candidate.sum(dtype=np.int32),
+                occ.sum(dtype=np.int32), overflows, slot,
+                hash_take(table_state, xp.minimum(slot, S - 1)))
+    return hash_top
+
+
 def hash_take(tree, at):
     """The entries ``at`` of every array of ``tree`` (a table state, a
     mask over its slots): the gather of the filtered ending, one

@@ -471,6 +471,33 @@ def build_join_exchange(node: JoinNode, param_names: tuple, xp, n_dev: int,
     return join_exchange
 
 
+def build_join_lookup(node: JoinNode, xp) -> Callable:
+    """The lookup of a block of keys in build tables that stand after
+    the probe: (child_tables, cols, valids, mask) -> (payload cols,
+    payload valids, found).  ``node`` has no relation behind it: its
+    ``names`` are the lanes of the block (the key lanes a GROUP BY kept)
+    and each of its ``children`` a resident table, met by the probe's
+    own way through it -- the first pair of slots, the later pairs for
+    the rows that need them, the payload gathered by slot -- so a row
+    finds here what a probed row of the same key found.  The payload
+    comes back child after child in the order of ``ChildProbe.payload``;
+    ``found`` marks the rows with a partner in every child."""
+    pre = _Prefix(node, (), xp)
+
+    # named for its kernel slot: the XLA module in a device trace is
+    # jit_join_lookup
+    def join_lookup(child_tables, cols, valids, mask):
+        env = pre.env(cols, valids)
+        through, _, probes = pre.first_pair(env, mask, child_tables)
+        at = xp.arange(mask.shape[0], dtype=np.int32)
+        found, slots = pre.later_pairs(at, through, probes, child_tables)
+        got = pre.child_payloads({}, slots, child_tables)
+        names = [n for ch in node.children for n, _ in ch.payload]
+        return (tuple(got[n][0] for n in names),
+                tuple(got[n][1] for n in names), found)
+    return join_lookup
+
+
 def build_join_probe(node: JoinNode, param_names: tuple, xp,
                      block_rows: Optional[int] = None,
                      order: Optional[str] = None) -> Callable:

@@ -505,6 +505,101 @@ class DeviceJoinTree:
         return out
 
 
+@dataclass
+class GroupKeyPlan:
+    """Which group keys of a device join decide the groups, and which
+    are functions of those (``dependent_group_keys``)."""
+    lanes: list         # the key lanes the device groups on (BExpr)
+    lane_of: list       # per group key: its lane's index, None = dependant
+    resolver: dict      # dependant's key index -> the build that holds it
+    lookups: dict       # such a build -> the lanes that are its edge key
+
+    @property
+    def dependants(self) -> int:
+        return len(self.resolver)
+
+
+def dependent_group_keys(bj: BoundJoinSelect, tree: DeviceJoinTree,
+                         pinned=(), resident=None) -> GroupKeyPlan:
+    """From the tree alone: which group keys are functions of which.
+
+    A build node is UNIQUE on its edge key (the verdict the device join
+    takes before the first probe, else the host path answers), and so
+    is every build under it: a column of the build, or of a node under
+    it, is a function of the build's edge key on the PARENT's side.  A
+    build therefore *collapses* where every lane of its edge is among
+    the lanes the statement groups on, on either side of the equality
+    (an inner step: the two sides are equal on every joined row, so the
+    parent's side stands for the build's own): the group keys that are
+    plain columns of its subtree stop being lanes and become
+    *dependants*, looked up in the build's table for the groups that are
+    returned, and the parent's side of the edge is grouped on instead --
+    the same partition of the rows, since the old lanes determined the
+    edge and the edge determines them.  Builds are tried children before
+    parents, so the highest build that collapses holds every dependant
+    under it; a collapse that would not leave fewer lanes is not taken.
+    A key that is the build's own side of the edge is no dependant: it
+    reads the lane of the parent's side (equal on every joined row).
+
+    What stays a lane, as today: a key of the root, an expression over
+    several relations (any key that is not a plain column), a key under
+    a step that is not inner, a key in ``pinned`` (indexes: the keys an
+    ORDER BY or a HAVING decided on the chip reads before any lookup),
+    and every key under a build that is not in ``resident`` (the builds
+    whose tables still stand when the groups are returned; None = all).
+    Duplicate keys share a lane."""
+    keys = list(bj.group_keys)
+    qualified = bj.binder.qualified
+    inner = {s.right_alias: s.kind == "inner" for s in bj.steps}
+    resolver: dict = {}
+
+    def lanes_now() -> list:
+        out: list = []
+        for i, k in enumerate(keys):
+            if i not in resolver and k not in out:
+                out.append(k)
+        for b in dict.fromkeys(resolver.values()):
+            for t in tree.edge[b][1]:
+                if t not in out:
+                    out.append(t)
+        return out
+
+    for b in tree.builds:
+        under = set(tree.subtree(b))
+        # every step on the way down from ``b`` joins inner
+        if (resident is not None and b not in resident) \
+                or not all(inner.get(a, True) for a in under):
+            continue
+        own, theirs = tree.edge[b]
+        lanes = lanes_now()
+        if not all(o in lanes or t in lanes for o, t in zip(own, theirs)):
+            continue
+        mine = [i for i, k in enumerate(keys)
+                if i not in pinned and isinstance(k, BColumn)
+                and _rel_of(k, qualified) in under]
+        before = dict(resolver)
+        resolver.update({i: b for i in mine})
+        if len(lanes_now()) >= len(lanes):
+            resolver = before
+    lanes = lanes_now()
+    lane_of = [None if i in resolver else lanes.index(k)
+               for i, k in enumerate(keys)]
+    # a key that IS the build's side of its edge needs no lookup: on
+    # every joined row it equals the parent's side, which is the lane
+    for i, b in list(resolver.items()):
+        for o, t in zip(*tree.edge[b]):
+            if keys[i] == o and o.type == t.type:
+                lane_of[i] = lanes.index(t)
+                del resolver[i]
+                break
+    return GroupKeyPlan(
+        lanes=lanes,
+        lane_of=lane_of,
+        resolver=resolver,
+        lookups={b: [lanes.index(t) for t in tree.edge[b][1]]
+                 for b in dict.fromkeys(resolver.values())})
+
+
 def _device_key_type(t: T.ColumnType) -> bool:
     return t.is_integer or t.is_decimal or t.kind in (
         T.DATE, T.BOOL, T.TIMESTAMP, T.TIMESTAMPTZ, T.TIME)

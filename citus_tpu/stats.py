@@ -233,6 +233,16 @@ class StatCounters:
         "hash_groups_out",
         "hash_table_bytes_fetched",
         "hash_entries_fetched",
+        # group keys of the device joins that ran, those of them a join
+        # proved functions of another (looked up for the returned groups
+        # instead of grouped on) and the key lanes the group tables
+        # held; statements whose ORDER BY ... LIMIT was cut on the chip
+        # (jit_hash_top) and the entries that came home from the cuts
+        "group_keys",
+        "group_keys_dependent",
+        "group_key_lanes",
+        "group_top_cuts",
+        "group_top_entries",
         # direct-group-id aggregation (executor.py _run_agg): slots of
         # the plan's group domain per query (what ops/scan_agg.py sizes
         # and chooses its reduction by) and groups returned from them

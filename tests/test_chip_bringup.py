@@ -181,7 +181,7 @@ def test_smoke_rehearsal_passes_every_leg(tmp_path, n_dev):
     assert report["rehearsal"] is True and report["platform"] == "cpu"
     legs = {leg["leg"].split()[0]: leg for leg in report["legs"]}
     single = {"1", "2", "3", "3b", "4", "5", "5b", "5c", "5d", "5e", "5f",
-              "6", "7a", "7c"}
+              "6", "7a", "7c", "7f"}
     assert set(legs) == (single | {"7b", "7d", "7e"} if n_dev > 1
                          else single)
     assert all(leg["ok"] for leg in legs.values())

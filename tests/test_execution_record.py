@@ -41,7 +41,7 @@ HASH = {"hash_tables", "hash_slots", "hash_slots_from", "hash_disjoint_on",
         "hash_spilled_rows", "hash_table_updates", "hash_rows_in",
         "hash_rows_in_max_device", "group_rows_in", "hash_tables_merged",
         "hash_occupancy_pct", "group_rows_kept", "hash_groups_out",
-        "hash_table_bytes_fetched", "hash_entries_fetched"}
+        "hash_table_bytes_fetched", "hash_entries_fetched", "group_top"}
 REMOTE = {"remote_wait_ms", "remote_overlapped_ms", "remote_inflight_peak",
           "wire_format"}
 
@@ -176,7 +176,9 @@ def _device_join(cl):
             for _ in range(2)]
     assert all(on_device(r.explain) for r in runs)
     return runs, [SCAN | STREAMED | TIMINGS
-                  | {"hash_occupancy_pct", "group_rows_kept"}] * 2
+                  | {"hash_occupancy_pct", "group_rows_kept", "group_top",
+                     "group_keys", "group_key_lanes",
+                     "group_keys_dependent"}] * 2
 
 
 @pytest.mark.parametrize("route", [
