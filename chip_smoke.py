@@ -145,6 +145,10 @@ WHERE c_custkey = o_custkey AND l_orderkey = o_orderkey
   AND c_mktsegment <> 'MACHINERY'
 GROUP BY c_mktsegment ORDER BY c_mktsegment"""
 
+# no filter on the probe relation: every row of every batch is looked up
+Q_JOIN_UNFILTERED = ("SELECT count(*) FROM orders_k, lineitem "
+                     "WHERE l_orderkey = o_orderkey")
+
 # TPC-H Q10's shape in small tables of its own (a few hundred thousand
 # orders, so that the numpy arm can answer it too): four relations along
 # a chain, seven group keys of which six are functions of the customer
@@ -809,12 +813,27 @@ def leg_join_colocated(run, ref):
             if SEGMENTS[s] != "MACHINERY" and n]
     check(r.rows == want, f"colocated join answer {r.rows} want {want}")
     j = r.explain["join"]
+    # the probe looks up the rows lineitem's own filter keeps, not the
+    # batch: a chunk loop that looked everything up would read equal
+    check(0 < j["rows_looked_up"] < j["rows_probed"]
+          and j["rows_out"] <= j["rows_looked_up"],
+          f"colocated join: looked up {j['rows_looked_up']} of "
+          f"{j['rows_probed']} probed, {j['rows_out']} out")
+    # ... and the whole bucket where the probe relation has no filter
+    whole = run.cl.execute(Q_JOIN_UNFILTERED)
+    w = whole.explain.get("join", {})
+    check(w.get("on") == "device"
+          and w["rows_looked_up"] == w["rows_probed"] > 0
+          and whole.rows == [(int(ref.d_n.sum()),)],
+          f"unfiltered join: {whole.rows} {w}")
     run.record("7c colocated join on the device (customer, orders_k, "
                "lineitem: build, probe, packed aggregate)",
                "jit_join_probe", el, d, rows_probed=j["rows_probed"],
+               rows_looked_up=j["rows_looked_up"],
                rows_matched=j["rows_matched"], rows_out=j["rows_out"],
                rows_built=j["rows_built"], table_bytes=j["table_bytes"],
-               overflow_rounds=j["overflow_rounds"])
+               overflow_rounds=j["overflow_rounds"],
+               unfiltered_rows_looked_up=w["rows_looked_up"])
 
 
 def leg_join_q10(run, rng, shards, n_orders):

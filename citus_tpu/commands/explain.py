@@ -421,7 +421,8 @@ def _explain_join(cl, stmt: A.Explain) -> Result:
             lines.append(
                 f"  Join: on device, probe {j['probe']}; tables: {tables} "
                 f"({j['table_bytes']} bytes); rows built {j['rows_built']}, "
-                f"probed {j['rows_probed']}, matched {j['rows_matched']}, "
+                f"probed {j['rows_probed']}, looked up "
+                f"{j['rows_looked_up']}, matched {j['rows_matched']}, "
                 f"out {j['rows_out']}; overflow rounds "
                 f"{j['overflow_rounds']}; groups {j['groups']}")
             top = j["top"]

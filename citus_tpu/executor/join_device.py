@@ -166,9 +166,6 @@ class _DeviceJoin:
     #: rows of a (source, destination) block of an exchange round:
     #: None = ``ops/join.py`` ``exchange_capacity`` of the batch's bucket
     exchange_rows: Optional[int] = None
-    #: where the probe's lookup stands: None = from what each batch
-    #: shows; ``lookup`` / ``filter`` pin it (a measurement's)
-    probe_order: Optional[str] = None
 
     def __init__(self, cat: Catalog, bj: BoundJoinSelect, settings: Settings,
                  tree: DeviceJoinTree, shard_rows: dict):
@@ -655,8 +652,8 @@ class _DeviceJoin:
         return self._kernel(
             "jit_join_probe",
             lambda: J.build_join_probe(root, self.param_names, jnp,
-                                       self.block_rows, self.probe_order),
-            extra=(self.block_rows, self.probe_order), replicated=(4,))
+                                       self.block_rows),
+            extra=(self.block_rows,), replicated=(4,))
 
     def _aggregate(self, bcols, bvalids, bmask):
         pcols, pvalids = self.placement.pcols, self.placement.pvalids
@@ -976,6 +973,7 @@ class _DeviceJoin:
                                  ex["overflow_rounds"])
         GLOBAL_COUNTERS.bump("join_rows_built", join["rows_built"])
         GLOBAL_COUNTERS.bump("join_rows_probed", join["rows_probed"])
+        GLOBAL_COUNTERS.bump("join_rows_looked_up", join["rows_looked_up"])
         GLOBAL_COUNTERS.bump("join_rows_matched", join["rows_matched"])
         GLOBAL_COUNTERS.bump("join_rows_out", join["rows_out"])
         GLOBAL_COUNTERS.bump("join_overflow_rounds", join["overflow_rounds"])

@@ -19,17 +19,28 @@ through ``_merge_entries`` / ``_insert_keys`` and read through
 probabilistic.  A join cannot spill an entry to the host as a GROUP BY
 can, so an entry that loses both slots of its pair goes on to the
 next of ``JOIN_LEVELS`` pairs (the hash remixed); a probe looks into
-the first pair for every row of a batch -- two gathers a lane -- and
-into the later pairs only for the few rows whose first pair is taken
-twice over by other keys, after packing.  The join is many-to-one: a
-slot's ``rows`` counts the build rows that claimed it, and the build
-counts keys that came twice and entries no pair would take
-(``COUNTS``); either sends the statement to the host path.  NULL keys
-never match: they are masked out on both sides.
+the first pair for every row its lookup is handed -- two gathers a
+lane -- and into the later pairs only for the few rows whose first
+pair is taken twice over by other keys, after packing.  The join is
+many-to-one: a slot's ``rows`` counts the build rows that claimed it,
+and the build counts keys that came twice and entries no pair would
+take (``COUNTS``); either sends the statement to the host path.  NULL
+keys never match: they are masked out on both sides.
 
 Packing is one sort of the row positions (a scatter is a serial loop
 on a TPU and ``cumsum`` over a million rows takes XLA for TPU a minute
 to compile: PERF.md section 6).
+
+The probe has ONE path and its cost follows the rows the probe
+relation's own filter keeps (``build_join_probe``).  A gather on this
+chip costs by the index, whatever it hits -- 24-29 ns an element, be it
+``index[key - min]`` of a 240 MB table or a column of the batch -- so
+the only way to pay less is to gather for fewer rows: the kept rows are
+packed WITH what their lookup reads (``_Prefix.probe_lanes``, carried
+as further operands of the packing sort: 5.6 ms for a batch of 4 M
+rows where the gather is 99), and a loop looks the first K packed rows
+up, a ``lookup_chunk`` a trip.  No second order, no setting: K is what
+the kernel sees in its batch.
 """
 
 from __future__ import annotations
@@ -61,8 +72,9 @@ BUILT, LATER_LEVEL, REPEATED, UNPLACED, PACKED_ROWS = range(5)
 COUNTS = 5
 
 #: what a probe round counts: rows packed (candidates for the block,
-#: over all rounds), rows with a build index (a later round of the
-#: lookup-first order counts none again), rows handed on, rows looked up
+#: over all rounds), looked-up rows with a partner in every child's first
+#: pair (a later round counts none again), rows handed on, rows looked up
+#: (those the node's own filter kept; its bucket where it has none)
 PACKED, MATCHED, OUT, LOOKED = range(4)
 
 #: what an exchange round counts: rows this device sent, rows it
@@ -184,6 +196,12 @@ def _level_hash(xp, h, level):
         level - 1])
 
 
+def _lane_keys(xp, lane):
+    """A hash child's probe lane (validity, key lanes ...) as the key
+    pairs ``_probe_slots`` reads; a direct child's lane has none."""
+    return [(kv, xp.ones(kv.shape, bool)) for kv in lane[1:]]
+
+
 def _key_lanes(xp, key_fns, env, shape):
     """-> ([(int64 values, all-true validity)], every lane valid)."""
     ok = xp.ones(shape, bool)
@@ -197,13 +215,29 @@ def _key_lanes(xp, key_fns, env, shape):
     return [(xp.where(ok, v, 0), ones) for v in vals], ok
 
 
+def _marked(xp, mask):
+    """Each row's position where ``mask`` is on, past the batch's length
+    where it is off."""
+    n = mask.shape[0]
+    pos = xp.arange(n, dtype=np.int32)
+    return xp.where(mask, pos, pos + np.int32(n))
+
+
+def _pack_with(xp, mask, lanes=()):
+    """-> (the positions where ``mask`` is on, in order, then the others
+    past the batch's length; ``lanes`` in that order): ONE sort whose
+    key is the position and whose further operands are the lanes, so
+    what a packed row carries is not fetched by a gather a row."""
+    from jax import lax
+    order, *lanes = lax.sort((_marked(xp, mask), *lanes), num_keys=1)
+    return order, lanes
+
+
 def _pack(xp, mask, width: int):
     """The positions where ``mask`` is on, in order, then the others:
     padded to a whole number of ``width``; and their count."""
-    from jax import lax
     n = mask.shape[0]
-    pos = xp.arange(n, dtype=np.int32)
-    order = lax.sort(xp.where(mask, pos, pos + np.int32(n)))
+    order, _ = _pack_with(xp, mask)
     order = xp.where(order >= n, 0, order)
     pad = -n % width
     if pad:
@@ -214,7 +248,8 @@ def _pack(xp, mask, width: int):
 class _Prefix:
     """What the build and the probe kernels share: a batch's env, the
     node's own filter, and its rows' way through the children's tables
-    -- the first pair of slots for every row, the later pairs for the
+    -- what a row's lookup reads (``probe_lanes``), the first pair of
+    slots for the rows handed to ``look_up``, the later pairs for the
     packed rows that may need them."""
 
     def __init__(self, node: JoinNode, param_names: tuple, xp):
@@ -234,32 +269,58 @@ class _Prefix:
         return row_mask & predicate_mask(self.xp, self.filter_fn, env,
                                          row_mask)
 
-    def first_pair(self, env, mask, child_tables):
-        """-> (rows that found a partner in every child or may yet,
-        rows that found one in the first pair, per child (slot, keys))."""
+    def probe_lanes(self, env, mask, child_tables):
+        """What each child's lookup reads of a row, elementwise over the
+        batch: for a ``direct`` child ONE int32 lane, 1 + the key's
+        place in ``index`` (0 where ``mask`` is off, the key is NULL or
+        lies outside the span); for a ``hash`` child the joint validity
+        of its keys (int8) and then its int64 key lanes."""
+        xp = self.xp
+        lanes = []
+        for ch, fns, (state, _) in zip(
+                self.node.children, self.child_key_fns, child_tables):
+            keys, ok = _key_lanes(xp, fns, env, mask.shape)
+            if ch.kind == "direct":
+                at, ok = _direct_at(xp, state, keys, mask & ok)
+                lanes.append((xp.where(ok, at + np.int32(1), 0),))
+            else:
+                lanes.append(((mask & ok).astype(np.int8),)
+                             + tuple(kv for kv, _ in keys))
+        return lanes
+
+    def look_up(self, lanes, mask, child_tables):
+        """The first pair of slots for the rows whose ``probe_lanes``
+        are ``lanes`` -> (rows that found a partner in every child or
+        may yet, rows that found one in the first pair, per child
+        (slot, keys))."""
         xp = self.xp
         found_all = mask
         through = mask
         probes = []
-        for ch, fns, (state, counts) in zip(
-                self.node.children, self.child_key_fns, child_tables):
-            keys, ok = _key_lanes(xp, fns, env, mask.shape)
+        for ch, lane, (state, counts) in zip(
+                self.node.children, lanes, child_tables):
             if ch.kind == "direct":
                 # one gather a row: the key is the address
-                at, ok = _direct_at(xp, state, keys, mask & ok)
-                place = state[0][at]
-                found = ok & (place > 0)
+                at1, = lane
+                place = state[0][xp.maximum(at1 - np.int32(1), 0)]
+                found = mask & (at1 > 0) & (place > 0)
                 slot = xp.where(found, place - 1, _span(ch.kind, state))
                 may = found
             else:
-                slot, crowded = _probe_slots(xp, keys, mask & ok, state[0],
-                                             crowded=True)
+                slot, crowded = _probe_slots(
+                    xp, _lane_keys(xp, lane), mask & (lane[0] != 0),
+                    state[0], crowded=True)
                 found = slot < _span(ch.kind, state)
                 may = found | (crowded & (counts[LATER_LEVEL] > 0))
             found_all = found_all & found
             through = through & may
-            probes.append((slot, keys))
+            probes.append((slot, _lane_keys(xp, lane)))
         return through, found_all, probes
+
+    def first_pair(self, env, mask, child_tables):
+        """``look_up`` of every row of an unpacked batch."""
+        return self.look_up(self.probe_lanes(env, mask, child_tables), mask,
+                            child_tables)
 
     def later_pairs(self, at, live, probes, child_tables):
         """For the packed rows ``at``: the slot of each child's partner
@@ -498,9 +559,19 @@ def build_join_lookup(node: JoinNode, xp) -> Callable:
     return join_lookup
 
 
+def lookup_chunk(n: int) -> int:
+    """Rows a trip of the probe's lookup loop gathers for, of a batch
+    of ``n`` rows: the smallest of the bucket's halvings down to 1/64
+    that holds 1,024 rows and divides it (a small bucket is one
+    chunk)."""
+    for share in (64, 32, 16, 8, 4, 2):
+        if n % share == 0 and n // share >= 1024:
+            return n // share
+    return n
+
+
 def build_join_probe(node: JoinNode, param_names: tuple, xp,
-                     block_rows: Optional[int] = None,
-                     order: Optional[str] = None) -> Callable:
+                     block_rows: Optional[int] = None) -> Callable:
     """The probe step of the root ``node``: (child_tables, cols, valids,
     row_mask, round) -> (block cols, block valids, block mask, counts).
     The rows that pass the node's filter and have a partner in every
@@ -512,63 +583,85 @@ def build_join_probe(node: JoinNode, param_names: tuple, xp,
     ``MATCHED`` / ``OUT`` / ``LOOKED``) say whether another round is
     due: ``PACKED`` > (r + 1) x ``block_rows``.
 
-    Where the lookup stands follows from what the batch shows
-    (``order`` None; a name pins it, for a measurement): ``lookup``
-    first looks every row of the batch up and packs the rows that
-    matched and passed the filter -- one gather a row and child, few
-    rounds however little the filter keeps back; ``filter`` first packs
-    the rows the node's own filter keeps and looks only the block's
-    rows up.  The kernel holds both and takes the second for a batch
-    whose filtered rows fit ONE block (a gather a block row instead of
-    a batch row: TPC-H Q12 keeps a row in 190, Q3 one in two)."""
+    One path, whose cost follows the K rows the node's own filter
+    keeps, which the kernel sees in its batch: (1) the filter and every
+    child's ``probe_lanes`` over the whole batch, elementwise; (2) ONE
+    sort packs the kept rows' positions to the front and carries the
+    lanes as further operands, so a kept row's key is never fetched by
+    a gather of its own; (3) a loop of ceil(K / ``lookup_chunk``)
+    trips looks the first K packed rows up -- a slice of the lanes,
+    ``look_up``'s gathers, a slice of the slots written back; (4) the
+    rows that came through are packed again into the round's block
+    (where K fits the block its first rows ARE the block: no second
+    sort), the later pairs, the payload and the block's own columns
+    are gathered for the block's rows alone, by slot and by original
+    position.  A node without a filter keeps its whole bucket: the
+    batch is its own packing and nothing is sorted.  ``LOOKED`` counts
+    K a round (the bucket where nothing is filtered; the gathers issued
+    are K to the chunk), ``MATCHED`` the looked-up rows with a partner
+    in every child's first pair (round 0 alone: a later round looks the
+    same rows up again)."""
     from jax import lax
 
     pre = _Prefix(node, param_names, xp)
     post_fn = compile_expr(node.post_filter, xp) \
         if node.post_filter is not None else None
     params = tuple(param_names)
-    if pre.filter_fn is None or not node.children:
-        order = "lookup"
 
     # named for its kernel slot: the XLA module in a device trace is
     # jit_join_probe
     def join_probe(child_tables, cols, valids, row_mask, rnd):
         N = row_mask.shape[0]
         C = block_capacity(N, block_rows)
+        CH = lookup_chunk(N)
         env = pre.env(cols, valids)
         own = pre.own_filter(env, row_mask)
-        lane = rnd * C + xp.arange(C, dtype=np.int32)
-        take = lambda a, at: a[at] if xp.ndim(a) else a
-
-        def lookup_first(_):
-            through, found, probes = pre.first_pair(env, row_mask,
-                                                    child_tables)
-            packed, D = _pack(xp, own & through, C)
-            at = lax.dynamic_slice(packed, (rnd * C,), (C,))
-            live, slots = pre.later_pairs(at, lane < D, probes, child_tables)
-            matched = xp.where(rnd == 0, found.sum(dtype=np.int32), 0)
-            return at, live, tuple(slots), D, matched, np.int32(N)
-
-        def filter_first(_):
-            packed, D = _pack(xp, own, C)
-            at = lax.dynamic_slice(packed, (rnd * C,), (C,))
-            kept = lane < D
-            penv = {n: (take(v, at), take(m, at)) for n, (v, m) in env.items()}
-            through, found, probes = pre.first_pair(penv, kept, child_tables)
-            live, slots = pre.later_pairs(xp.arange(C, dtype=np.int32),
-                                          through, probes, child_tables)
-            return (at, live, tuple(slots), D, found.sum(dtype=np.int32),
-                    kept.sum(dtype=np.int32))
-
-        if order == "lookup":
-            picked = lookup_first(None)
-        elif order == "filter":
-            picked = filter_first(None)
+        lanes = pre.probe_lanes(env, own, child_tables)
+        if pre.filter_fn is not None and node.children:
+            order, flat = _pack_with(xp, own,
+                                     [a for lane in lanes for a in lane])
+            flat = iter(flat)
+            lanes = [tuple(next(flat) for _ in lane) for lane in lanes]
+            K = own.sum(dtype=np.int32)
         else:
-            picked = lax.cond(own.sum(dtype=np.int32) <= C, filter_first,
-                              lookup_first, None)
-        at, live, slots, D, matched, looked = picked
-        block = {n: (take(v, at), take(m, at))
+            # a node without a filter keeps its bucket: nothing to pack
+            order, K = _marked(xp, own), np.int32(N)
+
+        def look(c, carry):
+            slots, through, matched = carry
+            cut = lambda a: lax.dynamic_slice(a, (c * CH,), (CH,))
+            chunk = [tuple(cut(a) for a in lane) for lane in lanes]
+            went, found, probes = pre.look_up(chunk, cut(order) < N,
+                                              child_tables)
+            put = lambda whole, part: lax.dynamic_update_slice(
+                whole, part, (c * CH,))
+            return (tuple(put(s, slot) for s, (slot, _) in zip(slots, probes)),
+                    put(through, went),
+                    matched + found.sum(dtype=np.int32))
+
+        slots, through, matched = lax.fori_loop(
+            0, (K + CH - 1) // CH, look,
+            (tuple(xp.zeros((N,), np.int32) for _ in lanes),
+             xp.zeros((N,), bool), np.int32(0)))
+        probes = [(slot, _lane_keys(xp, lane))
+                  for slot, lane in zip(slots, lanes)]
+        D = through.sum(dtype=np.int32)
+        lane = rnd * C + xp.arange(C, dtype=np.int32)
+
+        def whole_block(_):
+            # the kept rows fit the block: they are its first rows
+            return xp.arange(C, dtype=np.int32), through[:C] & (rnd == 0)
+
+        def packed_block(_):
+            again, _ = _pack(xp, through, C)
+            return lax.dynamic_slice(again, (rnd * C,), (C,)), lane < D
+
+        among, live = lax.cond(K <= C, whole_block, packed_block, None)
+        live, slots = pre.later_pairs(among, live, probes, child_tables)
+        at = order[among]
+        at = xp.where(at >= N, 0, at)
+        take = lambda a: a[at] if xp.ndim(a) else a
+        block = {n: (take(v), take(m))
                  for n, (v, m) in env.items() if n not in params}
         block = pre.child_payloads(block, slots, child_tables)
         if post_fn is not None:
@@ -581,6 +674,7 @@ def build_join_probe(node: JoinNode, param_names: tuple, xp,
             v = xp.broadcast_to(xp.asarray(v), (C,))
             out_cols.append(v)
             out_valids.append(xp.broadcast_to(_as_mask(xp, m, v), (C,)))
-        counts = xp.stack([D, matched, live.sum(dtype=np.int32), looked])
+        counts = xp.stack([D, xp.where(rnd == 0, matched, 0),
+                           live.sum(dtype=np.int32), K])
         return tuple(out_cols), tuple(out_valids), live, counts
     return join_probe

@@ -32,9 +32,10 @@ class StatCounters:
         "join_queries",
         # the device join (executor/join_device.py, per statement): the
         # rounds of its scan loop, rows its builds put into lookup
-        # tables, padded rows of every probe round, probe rows that
-        # found a build row (of the rows looked up: all of them, or where
-        # the probe side's own filter stands first the rows it kept), rows
+        # tables, padded rows of every probe round, the rows of them
+        # that were looked up (those the probe side's own filter kept,
+        # a round; the bucket where it has none), the looked-up rows
+        # that found a build row, rows
         # handed to the aggregate, further rounds of blocks the
         # survivors overflowed, bytes of the lookup tables resident at
         # once; and the joins the device backend answered on the host;
@@ -49,6 +50,7 @@ class StatCounters:
         "join_dispatches",
         "join_rows_built",
         "join_rows_probed",
+        "join_rows_looked_up",
         "join_rows_matched",
         "join_rows_out",
         "join_overflow_rounds",

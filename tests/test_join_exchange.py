@@ -259,31 +259,11 @@ def test_an_exchange_block_that_overflows_takes_further_rounds(
         tables.stats.arrays(), {"SHIPMODES": "MAIL', 'SHIP", "DATE": 1994})
 
 
-@pytest.mark.parametrize("order", ["lookup", "filter"])
-def test_either_probe_order_gives_the_answer(cl, tables, limit_devices,
-                                             monkeypatch, order):
-    """The lookup before the probe relation's filter, or after it: the
-    kernel holds both and a batch picks; pinned here, and with a block
-    so small that both take overflow rounds."""
-    limit_devices(4)
-    monkeypatch.setattr(JD._DeviceJoin, "probe_order", order)
-    monkeypatch.setattr(JD._DeviceJoin, "block_rows", 8)
-    r = cl.execute(q12())
-    j = r.explain["join"]
-    assert on_device(r.explain) and j["overflow_rounds"] > 0
-    assert [tuple(row) for row in r.rows] == R.expected(
-        tables.stats.arrays(), {"SHIPMODES": "MAIL', 'SHIP", "DATE": 1994})
-    rows = int(tables.stats.rows["lineitem"])
-    if order == "filter":
-        # only block rows are looked up, and every one finds its order
-        assert j["rows_looked_up"] == j["rows_matched"] == j["rows_out"] < rows
-    else:
-        assert j["rows_looked_up"] >= j["rows_probed"] > rows
-        assert j["rows_matched"] == rows
-
-
 def test_a_batch_picks_the_filter_first_where_its_rows_fit_a_block(
         cl, limit_devices):
+    """Since PR 49 the probe has ONE order -- filter, pack, look the
+    kept rows up (``tests/test_join_probe_chunks.py``) -- and a batch
+    whose kept rows fit a block pays for a block's lookups."""
     limit_devices(4)
     r = cl.execute(q12())
     j = r.explain["join"]
