@@ -2889,14 +2889,19 @@ class Cluster:
         analog of SURVEY §5.1); view the trace with TensorBoard or
         xprof.  The statement is traced whatever the sampling rate, so
         its spans lie in the profile as ``citus.*`` annotations under
-        the device ops."""
+        the device ops; ``<trace_dir>/kernels/`` names the profile's
+        ``fusion.N`` and ``while.N`` by the kernels' steps
+        (``kernel_cache.export_kernel_scopes``)."""
+        from citus_tpu.executor.kernel_cache import export_kernel_scopes
         with jax.profiler.trace(trace_dir):
             qt = _trace.begin_query(sql, self.settings.observability,
                                     force=True)
             try:
-                return self.execute(sql)    # joins the forced trace
+                result = self.execute(sql)    # joins the forced trace
             finally:
                 self._finish_query_trace(qt, sql)
+        export_kernel_scopes(f"{trace_dir}/kernels")
+        return result
 
     def _execute_explain(self, stmt):
         from citus_tpu.commands.explain import _execute_explain

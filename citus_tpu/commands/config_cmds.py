@@ -293,6 +293,12 @@ def _execute_set(cl, stmt: A.SetConfig) -> Result:
     elif key == "citus.kernel_cache_size":
         from citus_tpu.executor.kernel_cache import GLOBAL_KERNELS
         GLOBAL_KERNELS.set_capacity(int(v))
+    elif key == "citus.trace_export_dir":
+        # the kernels' instruction -> scope maps go BESIDE the span
+        # directory: its readers list it, and who empties it removes
+        # files
+        from citus_tpu.executor.kernel_cache import follow_kernel_scopes
+        follow_kernel_scopes(v + ".kernels" if v else None)
     elif key == "citus.decode_threads":
         from citus_tpu.storage.reader import set_decode_threads
         set_decode_threads(int(v))

@@ -97,6 +97,37 @@ def bare_annotation(name: str):
             ann.__exit__(None, None, None)
 
 
+_NO_SCOPE = contextlib.nullcontext()
+
+
+def kernel_scope(xp, name: str):
+    """Names a step of a kernel's body where the work is written:
+    ``with kernel_scope(xp, "hash.sort"): ...``.  Under ``jax.numpy`` a
+    ``jax.named_scope("citus.<name>")``: the name rides the ops traced
+    under it into the compiled module as their ``op_name`` metadata
+    (fusions and ``while``s included), which is how a device trace's
+    ``fusion.26`` gets a role (``executor/kernel_cache.py``
+    ``export_kernel_scopes``).  It exists while JAX traces the body,
+    once a compile; a dispatch never meets it.  On the numpy arm a
+    no-op that imports nothing.  Scopes do not nest: a body is cut
+    into consecutive blocks.  ("Scope", not "phase": ``set_phase`` is
+    the statement's live phase.)"""
+    if xp.__name__ == "numpy":
+        return _NO_SCOPE
+    import jax
+    _tls.kernel_scopes = getattr(_tls, "kernel_scopes", 0) + 1
+    return jax.named_scope("citus." + name)
+
+
+def take_kernel_scopes() -> int:
+    """Scopes this thread has entered since it last asked: not 0 right
+    after a jitted call that compiled says the call traced a scoped
+    body."""
+    n = getattr(_tls, "kernel_scopes", 0)
+    _tls.kernel_scopes = 0
+    return n
+
+
 class Span:
     """One timed node of a trace.  Context manager: ``__enter__``
     activates it for the current thread and opens its profiler

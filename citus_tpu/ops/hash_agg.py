@@ -66,6 +66,7 @@ from typing import Callable
 
 import numpy as np
 
+from citus_tpu.observability.trace import kernel_scope
 from citus_tpu.planner.bound import _as_mask, compile_expr, predicate_mask
 from citus_tpu.planner.aggregates import float_bits
 from citus_tpu.planner.physical import PhysicalPlan
@@ -330,10 +331,13 @@ def build_fused_hash_worker(plan: PhysicalPlan, xp,
     # jit_hash_fused, apart from the scan kernel's jit_fused
     def hash_fused(table_state, cols, valids, row_mask):
         N = row_mask.shape[0]
-        env = make_env(cols, valids)
-        mask = row_mask
-        if filter_fn is not None:
-            mask = mask & predicate_mask(xp, filter_fn, env, row_mask)
+        with kernel_scope(xp, "hash.keys"):
+            env = make_env(cols, valids)
+            mask = row_mask
+            if filter_fn is not None:
+                mask = mask & predicate_mask(xp, filter_fn, env, row_mask)
+            h = _fingerprint(
+                xp, _eval_keys(xp, key_fns, key_dtypes, env, (N,)), (N,))
 
         # 1. sort: 31 bits of the fingerprint bring equal keys together
         # and the masked-out rows last.  One uint32 key and the row
@@ -344,64 +348,66 @@ def build_fused_hash_worker(plan: PhysicalPlan, xp,
         # is ONE gather lane: ``make_env`` widens after the gather),
         # their validity bits packed 31 to a lane — and keys and
         # arguments are evaluated on the rows in that order.
-        h = _fingerprint(
-            xp, _eval_keys(xp, key_fns, key_dtypes, env, (N,)), (N,))
-        last = np.uint32(1 << 31)
-        order, perm = lax.sort(
-            (xp.where(mask, (h >> np.uint64(33)).astype(np.uint32), last),
-             xp.arange(N, dtype=np.int32)), num_keys=1)
-        real = order != last
-        rowwise = [i for i, v in enumerate(valids) if xp.ndim(v)]
-        packs = [sum(valids[i].astype(np.int32) << b
-                     for b, i in enumerate(rowwise[at:at + 31]))[perm]
-                 for at in range(0, len(rowwise), 31)]
-        cols, valids = list(cols), list(valids)
-        for j, i in enumerate(rowwise):
-            cols[i] = cols[i][perm]
-            valids[i] = (packs[j // 31] >> (j % 31)) & 1 == 1
-        env = make_env(cols, valids)
-        keys = _eval_keys(xp, key_fns, key_dtypes, env, (N,))
-        args = {}
-        for ai in used_args:
-            v, valid = arg_fns[ai](env)
-            args[ai] = (xp.broadcast_to(xp.asarray(v), (N,)),
-                        _as_mask(xp, valid, real))
+        with kernel_scope(xp, "hash.sort"):
+            last = np.uint32(1 << 31)
+            order, perm = lax.sort(
+                (xp.where(mask, (h >> np.uint64(33)).astype(np.uint32), last),
+                 xp.arange(N, dtype=np.int32)), num_keys=1)
+            real = order != last
+        with kernel_scope(xp, "hash.gather"):
+            rowwise = [i for i, v in enumerate(valids) if xp.ndim(v)]
+            packs = [sum(valids[i].astype(np.int32) << b
+                         for b, i in enumerate(rowwise[at:at + 31]))[perm]
+                     for at in range(0, len(rowwise), 31)]
+            cols, valids = list(cols), list(valids)
+            for j, i in enumerate(rowwise):
+                cols[i] = cols[i][perm]
+                valids[i] = (packs[j // 31] >> (j % 31)) & 1 == 1
+            env = make_env(cols, valids)
+            keys = _eval_keys(xp, key_fns, key_dtypes, env, (N,))
+            args = {}
+            for ai in used_args:
+                v, valid = arg_fns[ai](env)
+                args[ai] = (xp.broadcast_to(xp.asarray(v), (N,)),
+                            _as_mask(xp, valid, real))
 
         # 2. segments of EQUAL KEYS (values and validity, exactly: two
         # keys that share the 31 bits are two segments, or more where
         # their rows interleave), each reduced onto its last row
         # (masked rows sit last: a real row's left neighbour is real)
-        same = xp.concatenate([xp.zeros((1,), bool), real[1:]])
-        for kv, kvm in keys:
-            same = same & same_as_left(kv) & same_as_left(kvm)
-        start = real & ~same
-        end = real & ~xp.concatenate([same[1:], xp.zeros((1,), bool)])
-        lanes = [(real.astype(np.int32), "sum")]
-        for op in partial_ops:
-            dt = np.dtype(op.dtype)
-            if op.arg_index < 0:
-                continue   # count(*) is the segment's rows
-            v, ok = args[op.arg_index]
-            ok = real & ok
-            if op.kind == "count":
-                lanes.append((ok.astype(np.int32), "sum"))
-            elif op.kind == "sum":
-                lanes.append((xp.where(ok, v, 0).astype(dt), "sum"))
-            else:
-                lanes.append((xp.where(
-                    ok, v, _sentinel(op.kind, dt)).astype(dt), op.kind))
-        seg_rows, *seg = _segment_scan(xp, start, lanes)
-        seg = iter(seg)
-        reduced = [seg_rows if op.arg_index < 0 else next(seg)
-                   for op in partial_ops]
+        with kernel_scope(xp, "hash.segments"):
+            same = xp.concatenate([xp.zeros((1,), bool), real[1:]])
+            for kv, kvm in keys:
+                same = same & same_as_left(kv) & same_as_left(kvm)
+            start = real & ~same
+            end = real & ~xp.concatenate([same[1:], xp.zeros((1,), bool)])
+            lanes = [(real.astype(np.int32), "sum")]
+            for op in partial_ops:
+                dt = np.dtype(op.dtype)
+                if op.arg_index < 0:
+                    continue   # count(*) is the segment's rows
+                v, ok = args[op.arg_index]
+                ok = real & ok
+                if op.kind == "count":
+                    lanes.append((ok.astype(np.int32), "sum"))
+                elif op.kind == "sum":
+                    lanes.append((xp.where(ok, v, 0).astype(dt), "sum"))
+                else:
+                    lanes.append((xp.where(
+                        ok, v, _sentinel(op.kind, dt)).astype(dt), op.kind))
+            seg_rows, *seg = _segment_scan(xp, start, lanes)
+            seg = iter(seg)
+            reduced = [seg_rows if op.arg_index < 0 else next(seg)
+                       for op in partial_ops]
 
         # 3. the D segment ends to the front, in order
         C = min(ENTRY_CHUNK, N)
         n_pad = -(-N // C) * C
-        pos = xp.arange(N, dtype=np.int32)
-        ends = lax.sort(xp.where(end, pos, pos + np.int32(N)))
-        ends = xp.concatenate([ends, xp.zeros((n_pad - N,), np.int32)])
-        D = end.sum(dtype=np.int32)
+        with kernel_scope(xp, "hash.ends"):
+            pos = xp.arange(N, dtype=np.int32)
+            ends = lax.sort(xp.where(end, pos, pos + np.int32(N)))
+            ends = xp.concatenate([ends, xp.zeros((n_pad - N,), np.int32)])
+            D = end.sum(dtype=np.int32)
 
         # 4. offer them to the table a chunk at a time: the trip count,
         # and so the cost of the scatters, follows D
@@ -426,13 +432,14 @@ def build_fused_hash_worker(plan: PhysicalPlan, xp,
                     [put(b, p) for b, p in zip(oparts, eparts)],
                     put(orows, erows))
 
-        _, partials, _ = table_state
-        empty = lambda dt: xp.zeros((n_pad,), dt)
-        state, n_spilled, spill, okeys, oparts, orows = lax.fori_loop(
-            0, (D + C - 1) // C, offer,
-            (table_state, np.int32(0), empty(bool),
-             [(empty(kdt), empty(np.int8)) for kdt in key_dtypes],
-             [empty(p.dtype) for p in partials], empty(np.int64)))
+        with kernel_scope(xp, "hash.offer"):
+            _, partials, _ = table_state
+            empty = lambda dt: xp.zeros((n_pad,), dt)
+            state, n_spilled, spill, okeys, oparts, orows = lax.fori_loop(
+                0, (D + C - 1) // C, offer,
+                (table_state, np.int32(0), empty(bool),
+                 [(empty(kdt), empty(np.int8)) for kdt in key_dtypes],
+                 [empty(p.dtype) for p in partials], empty(np.int64)))
         return state, (D, n_spilled, spill, tuple(okeys), tuple(oparts),
                        orows)
     return hash_fused

@@ -54,6 +54,7 @@ from citus_tpu.ops.hash_agg import (
     ENTRY_CHUNK, _fingerprint, _merge_entries, _mix, _probe_slots,
     empty_hash_state,
 )
+from citus_tpu.observability.trace import kernel_scope
 from citus_tpu.planner.bound import (
     BExpr, _as_mask, compile_expr, predicate_mask,
 )
@@ -386,12 +387,13 @@ def build_join_build(node: JoinNode, param_names: tuple, xp) -> Callable:
     # jit_join_build
     def join_build(table, child_tables, cols, valids, row_mask):
         N = row_mask.shape[0]
-        env = pre.env(cols, valids)
-        mask = pre.own_filter(env, row_mask)
-        keys, ok = _key_lanes(xp, key_fns, env, (N,))
-        through, _, probes = pre.first_pair(env, mask & ok, child_tables)
         C = min(ENTRY_CHUNK, N)
-        order, D = _pack(xp, through, C)
+        with kernel_scope(xp, "build.keys"):
+            env = pre.env(cols, valids)
+            mask = pre.own_filter(env, row_mask)
+            keys, ok = _key_lanes(xp, key_fns, env, (N,))
+            through, _, probes = pre.first_pair(env, mask & ok, child_tables)
+            order, D = _pack(xp, through, C)
 
         def offer(c, carry):
             state, counts = carry
@@ -468,8 +470,9 @@ def build_join_build(node: JoinNode, param_names: tuple, xp) -> Callable:
             tally[UNPLACED] = (live & ~ok).sum(dtype=np.int32)
             return (index, lane_tables, lo), counts + xp.stack(tally)
 
-        state, counts = lax.fori_loop(0, (D + C - 1) // C, offer, table)
-        return state, counts.at[PACKED_ROWS].add(D)
+        with kernel_scope(xp, "build.insert"):
+            state, counts = lax.fori_loop(0, (D + C - 1) // C, offer, table)
+            return state, counts.at[PACKED_ROWS].add(D)
     return join_build
 
 
@@ -514,14 +517,17 @@ def build_join_exchange(node: JoinNode, param_names: tuple, xp, n_dev: int,
     def join_exchange(table, child_tables, bounds, cols, valids, row_mask,
                       rnd):
         N = row_mask.shape[0]
-        env = pre.env(cols, valids)
-        mask = pre.own_filter(env, row_mask)
-        keys, ok = _key_lanes(xp, key_fns, env, (N,))
-        h = hash_int64(keys[hash_lane][0], xp)
-        target = (h[:, None] >= bounds[None, 1:]).sum(axis=1, dtype=np.int32)
-        whole = lambda a, dt: xp.broadcast_to(xp.asarray(a, dt), (N,))
-        lanes = tuple(whole(c, c.dtype) for c in cols[:n_own]) \
-            + tuple(whole(v, bool) for v in valids[:n_own])
+        with kernel_scope(xp, "exchange.target"):
+            env = pre.env(cols, valids)
+            mask = pre.own_filter(env, row_mask)
+            keys, ok = _key_lanes(xp, key_fns, env, (N,))
+            h = hash_int64(keys[hash_lane][0], xp)
+            target = (h[:, None] >= bounds[None, 1:]).sum(
+                axis=1, dtype=np.int32)
+            whole = lambda a, dt: xp.broadcast_to(xp.asarray(a, dt), (N,))
+            lanes = tuple(whole(c, c.dtype) for c in cols[:n_own]) \
+                + tuple(whole(v, bool) for v in valids[:n_own])
+        # (the exchange and the build step name their own steps)
         received, rvalid, counts = exchange_rows(
             lanes, target, mask & ok, n_dev,
             exchange_capacity(N, n_dev, block_rows), rnd)
@@ -614,18 +620,20 @@ def build_join_probe(node: JoinNode, param_names: tuple, xp,
         N = row_mask.shape[0]
         C = block_capacity(N, block_rows)
         CH = lookup_chunk(N)
-        env = pre.env(cols, valids)
-        own = pre.own_filter(env, row_mask)
-        lanes = pre.probe_lanes(env, own, child_tables)
-        if pre.filter_fn is not None and node.children:
-            order, flat = _pack_with(xp, own,
-                                     [a for lane in lanes for a in lane])
-            flat = iter(flat)
-            lanes = [tuple(next(flat) for _ in lane) for lane in lanes]
-            K = own.sum(dtype=np.int32)
-        else:
-            # a node without a filter keeps its bucket: nothing to pack
-            order, K = _marked(xp, own), np.int32(N)
+        with kernel_scope(xp, "probe.lanes"):
+            env = pre.env(cols, valids)
+            own = pre.own_filter(env, row_mask)
+            lanes = pre.probe_lanes(env, own, child_tables)
+        with kernel_scope(xp, "probe.pack"):
+            if pre.filter_fn is not None and node.children:
+                order, flat = _pack_with(xp, own,
+                                         [a for lane in lanes for a in lane])
+                flat = iter(flat)
+                lanes = [tuple(next(flat) for _ in lane) for lane in lanes]
+                K = own.sum(dtype=np.int32)
+            else:
+                # a node without a filter keeps its bucket: nothing to pack
+                order, K = _marked(xp, own), np.int32(N)
 
         def look(c, carry):
             slots, through, matched = carry
@@ -639,14 +647,11 @@ def build_join_probe(node: JoinNode, param_names: tuple, xp,
                     put(through, went),
                     matched + found.sum(dtype=np.int32))
 
-        slots, through, matched = lax.fori_loop(
-            0, (K + CH - 1) // CH, look,
-            (tuple(xp.zeros((N,), np.int32) for _ in lanes),
-             xp.zeros((N,), bool), np.int32(0)))
-        probes = [(slot, _lane_keys(xp, lane))
-                  for slot, lane in zip(slots, lanes)]
-        D = through.sum(dtype=np.int32)
-        lane = rnd * C + xp.arange(C, dtype=np.int32)
+        with kernel_scope(xp, "probe.lookup"):
+            slots, through, matched = lax.fori_loop(
+                0, (K + CH - 1) // CH, look,
+                (tuple(xp.zeros((N,), np.int32) for _ in lanes),
+                 xp.zeros((N,), bool), np.int32(0)))
 
         def whole_block(_):
             # the kept rows fit the block: they are its first rows
@@ -656,25 +661,31 @@ def build_join_probe(node: JoinNode, param_names: tuple, xp,
             again, _ = _pack(xp, through, C)
             return lax.dynamic_slice(again, (rnd * C,), (C,)), lane < D
 
-        among, live = lax.cond(K <= C, whole_block, packed_block, None)
-        live, slots = pre.later_pairs(among, live, probes, child_tables)
-        at = order[among]
-        at = xp.where(at >= N, 0, at)
-        take = lambda a: a[at] if xp.ndim(a) else a
-        block = {n: (take(v), take(m))
-                 for n, (v, m) in env.items() if n not in params}
-        block = pre.child_payloads(block, slots, child_tables)
-        if post_fn is not None:
-            penv = dict(block)
-            penv.update({n: env[n] for n in params})
-            live = live & predicate_mask(xp, post_fn, penv, live)
-        out_cols, out_valids = [], []
-        for name in node.out:
-            v, m = block[name]
-            v = xp.broadcast_to(xp.asarray(v), (C,))
-            out_cols.append(v)
-            out_valids.append(xp.broadcast_to(_as_mask(xp, m, v), (C,)))
-        counts = xp.stack([D, xp.where(rnd == 0, matched, 0),
-                           live.sum(dtype=np.int32), K])
+        with kernel_scope(xp, "probe.block"):
+            probes = [(slot, _lane_keys(xp, lane))
+                      for slot, lane in zip(slots, lanes)]
+            D = through.sum(dtype=np.int32)
+            lane = rnd * C + xp.arange(C, dtype=np.int32)
+            among, live = lax.cond(K <= C, whole_block, packed_block, None)
+        with kernel_scope(xp, "probe.payload"):
+            live, slots = pre.later_pairs(among, live, probes, child_tables)
+            at = order[among]
+            at = xp.where(at >= N, 0, at)
+            take = lambda a: a[at] if xp.ndim(a) else a
+            block = {n: (take(v), take(m))
+                     for n, (v, m) in env.items() if n not in params}
+            block = pre.child_payloads(block, slots, child_tables)
+            if post_fn is not None:
+                penv = dict(block)
+                penv.update({n: env[n] for n in params})
+                live = live & predicate_mask(xp, post_fn, penv, live)
+            out_cols, out_valids = [], []
+            for name in node.out:
+                v, m = block[name]
+                v = xp.broadcast_to(xp.asarray(v), (C,))
+                out_cols.append(v)
+                out_valids.append(xp.broadcast_to(_as_mask(xp, m, v), (C,)))
+            counts = xp.stack([D, xp.where(rnd == 0, matched, 0),
+                               live.sum(dtype=np.int32), K])
         return tuple(out_cols), tuple(out_valids), live, counts
     return join_probe

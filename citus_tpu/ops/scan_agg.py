@@ -29,6 +29,7 @@ from typing import Callable
 
 import numpy as np
 
+from citus_tpu.observability.trace import kernel_scope
 from citus_tpu.planner.bound import (
     _as_mask, compile_expr, param_env_names, predicate_mask,
 )
@@ -340,94 +341,101 @@ def build_worker_fn(plan: PhysicalPlan, xp) -> Callable:
             return row_mask
         return row_mask & predicate_mask(xp, filter_fn, env, row_mask)
 
+    def filtered(cols, valids, row_mask):
+        """-> (env, the rows that count), each under its scope."""
+        with kernel_scope(xp, "scan.env"):
+            env = make_env(cols, valids)
+        with kernel_scope(xp, "scan.filter"):
+            return env, eval_mask(env, row_mask)
+
     if mode.kind == "scalar":
         def worker_scalar(cols, valids, row_mask):
-            env = make_env(cols, valids)
-            mask = eval_mask(env, row_mask)
+            env, mask = filtered(cols, valids, row_mask)
             outs = []
-            for op in partial_ops:
-                if op.arg_index < 0:
-                    outs.append(xp.sum(mask, dtype=np.int64))
-                    continue
-                v, valid = arg_fns[op.arg_index](env)
-                ok = mask & _as_mask(xp, valid, mask)
-                dt = np.dtype(op.dtype)
-                if op.kind == "count":
-                    outs.append(xp.sum(ok, dtype=np.int64))
-                elif op.kind == "sum":
-                    outs.append(xp.sum(xp.where(ok, v, 0).astype(dt)))
-                elif op.kind == "min":
-                    outs.append(xp.min(xp.where(ok, v, dt.type(_sentinel("min", dt))).astype(dt)))
-                elif op.kind == "max":
-                    outs.append(xp.max(xp.where(ok, v, dt.type(_sentinel("max", dt))).astype(dt)))
-                elif op.kind == "ddsk":
-                    # DDSketch log-bucket histogram: per-row bucket id,
-                    # one-hot segment sum into [M] — combinable across
-                    # shards with the same psum as plain sum partials.
-                    # numpy would materialize the [M, N] one-hot (M=2048
-                    # — 16x HLL's), so the host backend bincounts instead
-                    from citus_tpu.planner.aggregates import (
-                        DDSK_M, ddsk_bucket_indexes,
-                    )
-                    bucket = ddsk_bucket_indexes(xp, xp.asarray(v))
-                    if xp.__name__ == "numpy":
-                        outs.append(np.bincount(
-                            bucket[np.asarray(ok)],
-                            minlength=DDSK_M).astype(np.int64))
-                    else:
+            with kernel_scope(xp, "scan.reduce"):
+                for op in partial_ops:
+                    if op.arg_index < 0:
+                        outs.append(xp.sum(mask, dtype=np.int64))
+                        continue
+                    v, valid = arg_fns[op.arg_index](env)
+                    ok = mask & _as_mask(xp, valid, mask)
+                    dt = np.dtype(op.dtype)
+                    if op.kind == "count":
+                        outs.append(xp.sum(ok, dtype=np.int64))
+                    elif op.kind == "sum":
+                        outs.append(xp.sum(xp.where(ok, v, 0).astype(dt)))
+                    elif op.kind == "min":
+                        outs.append(xp.min(xp.where(ok, v, dt.type(_sentinel("min", dt))).astype(dt)))
+                    elif op.kind == "max":
+                        outs.append(xp.max(xp.where(ok, v, dt.type(_sentinel("max", dt))).astype(dt)))
+                    elif op.kind == "ddsk":
+                        # DDSketch log-bucket histogram: per-row bucket id,
+                        # one-hot segment sum into [M] — combinable across
+                        # shards with the same psum as plain sum partials.
+                        # numpy would materialize the [M, N] one-hot (M=2048
+                        # — 16x HLL's), so the host backend bincounts instead
+                        from citus_tpu.planner.aggregates import (
+                            DDSK_M, ddsk_bucket_indexes,
+                        )
+                        bucket = ddsk_bucket_indexes(xp, xp.asarray(v))
+                        if xp.__name__ == "numpy":
+                            outs.append(np.bincount(
+                                bucket[np.asarray(ok)],
+                                minlength=DDSK_M).astype(np.int64))
+                        else:
+                            onehot = bucket[None, :] == xp.arange(
+                                DDSK_M, dtype=np.int32)[:, None]
+                            outs.append(xp.sum(
+                                (onehot & ok[None, :]).astype(np.int64), axis=1))
+                    elif op.kind == "topk":
+                        # heavy-hitter count sketch: hashed bucket per row,
+                        # one-hot segment sum into [M] — psum-combinable
+                        # like ddsk (numpy bincounts for the same reason)
+                        from citus_tpu.planner.aggregates import (
+                            TOPK_M, topk_buckets,
+                        )
+                        bucket = topk_buckets(xp, xp.asarray(v).astype(np.int64))
+                        if xp.__name__ == "numpy":
+                            outs.append(np.bincount(
+                                bucket[np.asarray(ok)],
+                                minlength=TOPK_M).astype(np.int64))
+                        else:
+                            onehot = bucket[None, :] == xp.arange(
+                                TOPK_M, dtype=np.int32)[:, None]
+                            outs.append(xp.sum(
+                                (onehot & ok[None, :]).astype(np.int64), axis=1))
+                    elif op.kind == "topkv":
+                        # companion value register: max value per hash
+                        # bucket (INT64_MIN = empty) — max-combinable
+                        from citus_tpu.planner.aggregates import (
+                            TOPK_M, TOPK_SENTINEL, topk_buckets,
+                        )
+                        v64 = xp.asarray(v).astype(np.int64)
+                        bucket = topk_buckets(xp, v64)
+                        upd = xp.where(ok, v64, TOPK_SENTINEL)
+                        if xp.__name__ == "numpy":
+                            acc = np.full((TOPK_M,), TOPK_SENTINEL, np.int64)
+                            outs.append(_np_scatter_max(acc, bucket, upd))
+                        else:
+                            onehot = bucket[None, :] == xp.arange(
+                                TOPK_M, dtype=np.int32)[:, None]
+                            outs.append(xp.max(
+                                xp.where(onehot, upd[None, :], TOPK_SENTINEL),
+                                axis=1))
+                    elif op.kind == "hll":
+                        # HyperLogLog registers: per-row (bucket, rho), then a
+                        # one-hot segment max into [m] — combinable across
+                        # shards with the same elementwise-max collective as
+                        # plain max partials
+                        from citus_tpu.planner.aggregates import (
+                            HLL_M, hll_rho_buckets, hll_value_bits,
+                        )
+                        bucket, rho = hll_rho_buckets(
+                            xp, hll_value_bits(xp, v), ok)
                         onehot = bucket[None, :] == xp.arange(
-                            DDSK_M, dtype=np.int32)[:, None]
-                        outs.append(xp.sum(
-                            (onehot & ok[None, :]).astype(np.int64), axis=1))
-                elif op.kind == "topk":
-                    # heavy-hitter count sketch: hashed bucket per row,
-                    # one-hot segment sum into [M] — psum-combinable
-                    # like ddsk (numpy bincounts for the same reason)
-                    from citus_tpu.planner.aggregates import (
-                        TOPK_M, topk_buckets,
-                    )
-                    bucket = topk_buckets(xp, xp.asarray(v).astype(np.int64))
-                    if xp.__name__ == "numpy":
-                        outs.append(np.bincount(
-                            bucket[np.asarray(ok)],
-                            minlength=TOPK_M).astype(np.int64))
-                    else:
-                        onehot = bucket[None, :] == xp.arange(
-                            TOPK_M, dtype=np.int32)[:, None]
-                        outs.append(xp.sum(
-                            (onehot & ok[None, :]).astype(np.int64), axis=1))
-                elif op.kind == "topkv":
-                    # companion value register: max value per hash
-                    # bucket (INT64_MIN = empty) — max-combinable
-                    from citus_tpu.planner.aggregates import (
-                        TOPK_M, TOPK_SENTINEL, topk_buckets,
-                    )
-                    v64 = xp.asarray(v).astype(np.int64)
-                    bucket = topk_buckets(xp, v64)
-                    upd = xp.where(ok, v64, TOPK_SENTINEL)
-                    if xp.__name__ == "numpy":
-                        acc = np.full((TOPK_M,), TOPK_SENTINEL, np.int64)
-                        outs.append(_np_scatter_max(acc, bucket, upd))
-                    else:
-                        onehot = bucket[None, :] == xp.arange(
-                            TOPK_M, dtype=np.int32)[:, None]
+                            HLL_M, dtype=np.int32)[:, None]
                         outs.append(xp.max(
-                            xp.where(onehot, upd[None, :], TOPK_SENTINEL),
-                            axis=1))
-                elif op.kind == "hll":
-                    # HyperLogLog registers: per-row (bucket, rho), then a
-                    # one-hot segment max into [m] — combinable across
-                    # shards with the same elementwise-max collective as
-                    # plain max partials
-                    from citus_tpu.planner.aggregates import (
-                        HLL_M, hll_rho_buckets, hll_value_bits,
-                    )
-                    bucket, rho = hll_rho_buckets(
-                        xp, hll_value_bits(xp, v), ok)
-                    onehot = bucket[None, :] == xp.arange(
-                        HLL_M, dtype=np.int32)[:, None]
-                    outs.append(xp.max(
-                        xp.where(onehot, rho[None, :], np.int32(0)), axis=1))
+                            xp.where(onehot, rho[None, :], np.int32(0)), axis=1))
             return tuple(outs)
         return worker_scalar
 
@@ -467,48 +475,49 @@ def build_worker_fn(plan: PhysicalPlan, xp) -> Callable:
             return (_np_scatter_min if kind == "min" else _np_scatter_max)(acc, gid, upd)
 
         def worker_direct(cols, valids, row_mask):
-            env = make_env(cols, valids)
-            mask = eval_mask(env, row_mask)
-            gid = group_id(env, mask)
-            # the middle reduction takes every count and int64 sum of the
-            # batch in ONE product: they queue here and fill their slots
-            # after the loop; float sums, min and max reduce as above
-            mm = _MatmulGroupSums(xp, gid, G) if reduction == "matmul" else None
-            outs = []
+            env, mask = filtered(cols, valids, row_mask)
+            with kernel_scope(xp, "scan.group_id"):
+                gid = group_id(env, mask)
+            with kernel_scope(xp, "scan.reduce"):
+                # the middle reduction takes every count and int64 sum of the
+                # batch in ONE product: they queue here and fill their slots
+                # after the loop; float sums, min and max reduce as above
+                mm = _MatmulGroupSums(xp, gid, G) if reduction == "matmul" else None
+                outs = []
 
-            def count_of(ok):
+                def count_of(ok):
+                    if mm is not None:
+                        return mm.count(ok)
+                    return seg_sum(gid, xp.where(ok, 1, 0).astype(np.int64),
+                                   np.dtype(np.int64))
+
+                for i, op in enumerate(partial_ops):
+                    dt = np.dtype(op.dtype)
+                    if op.arg_index < 0:
+                        outs.append(count_of(mask))
+                        continue
+                    if i in shadow_of and shadow_of[i][0] in mm.sums:
+                        # the float64 overflow guard of an int64 sum that the
+                        # product already holds limb by limb: read from those
+                        outs.append(mm.shadow(*shadow_of[i]))
+                        continue
+                    v, valid = arg_fns[op.arg_index](env)
+                    ok = mask & _as_mask(xp, valid, mask)
+                    if op.kind == "count":
+                        outs.append(count_of(ok))
+                    elif op.kind == "sum" and mm is not None and dt == np.int64:
+                        outs.append(mm.sum_int64(op.arg_index, v, ok))
+                    elif op.kind == "sum":
+                        outs.append(seg_sum(gid, xp.where(ok, v, 0).astype(dt), dt))
+                    else:
+                        sent = dt.type(_sentinel(op.kind, dt))
+                        upd = xp.where(ok, v, sent).astype(dt)
+                        outs.append(seg_minmax(gid, upd, dt, op.kind))
+                outs.append(count_of(mask))
                 if mm is not None:
-                    return mm.count(ok)
-                return seg_sum(gid, xp.where(ok, 1, 0).astype(np.int64),
-                               np.dtype(np.int64))
-
-            for i, op in enumerate(partial_ops):
-                dt = np.dtype(op.dtype)
-                if op.arg_index < 0:
-                    outs.append(count_of(mask))
-                    continue
-                if i in shadow_of and shadow_of[i][0] in mm.sums:
-                    # the float64 overflow guard of an int64 sum that the
-                    # product already holds limb by limb: read from those
-                    outs.append(mm.shadow(*shadow_of[i]))
-                    continue
-                v, valid = arg_fns[op.arg_index](env)
-                ok = mask & _as_mask(xp, valid, mask)
-                if op.kind == "count":
-                    outs.append(count_of(ok))
-                elif op.kind == "sum" and mm is not None and dt == np.int64:
-                    outs.append(mm.sum_int64(op.arg_index, v, ok))
-                elif op.kind == "sum":
-                    outs.append(seg_sum(gid, xp.where(ok, v, 0).astype(dt), dt))
-                else:
-                    sent = dt.type(_sentinel(op.kind, dt))
-                    upd = xp.where(ok, v, sent).astype(dt)
-                    outs.append(seg_minmax(gid, upd, dt, op.kind))
-            outs.append(count_of(mask))
-            if mm is not None:
-                assert planned in (None, len(mm.planes)), \
-                    f"product of {len(mm.planes)} planes, planned {planned}"
-                outs = mm.resolve(outs)
+                    assert planned in (None, len(mm.planes)), \
+                        f"product of {len(mm.planes)} planes, planned {planned}"
+                    outs = mm.resolve(outs)
             return tuple(outs)
         return worker_direct
 
@@ -516,8 +525,7 @@ def build_worker_fn(plan: PhysicalPlan, xp) -> Callable:
     key_fns = [compile_expr(k, xp) for k in plan.bound.group_keys]
 
     def worker_hash(cols, valids, row_mask):
-        env = make_env(cols, valids)
-        mask = eval_mask(env, row_mask)
+        env, mask = filtered(cols, valids, row_mask)
         keys = []
         for kf in key_fns:
             kv, kvalid = kf(env)
@@ -590,7 +598,9 @@ def build_fused_worker_fn(plan: PhysicalPlan, xp) -> Callable:
     kinds = combine_kinds(plan)
 
     def fused(acc, cols, valids, row_mask):
-        return fold_partials(xp, kinds, acc, worker(cols, valids, row_mask))
+        out = worker(cols, valids, row_mask)
+        with kernel_scope(xp, "scan.fold"):
+            return fold_partials(xp, kinds, acc, out)
 
     return fused
 

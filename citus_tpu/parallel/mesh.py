@@ -18,6 +18,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from citus_tpu.errors import ExecutionError
 from citus_tpu.executor.kernel_cache import jit_compile
+from citus_tpu.observability.trace import kernel_scope
 from citus_tpu.ops.scan_agg import fold_partials
 
 SHARD_AXIS = "shard"
@@ -90,16 +91,17 @@ def sharded_partial_agg(worker, combine_kinds: list[str], mesh: Mesh) -> Callabl
         row_mask = row_mask[0]
         partials = worker(cols, valids, row_mask)
         outs = []
-        for p, kind in zip(partials, combine_kinds):
-            if kind == "sum":
-                outs.append(jax.lax.psum(p, SHARD_AXIS))
-            else:
-                # TPU lowers only Sum all-reduces; min/max combine as an
-                # all_gather over ICI followed by a local reduction
-                g = jax.lax.all_gather(p, SHARD_AXIS)
-                outs.append(jnp.min(g, axis=0) if kind == "min"
-                            else jnp.max(g, axis=0))
-        return fold_partials(jnp, combine_kinds, acc, outs)
+        with kernel_scope(jnp, "scan.fold"):
+            for p, kind in zip(partials, combine_kinds):
+                if kind == "sum":
+                    outs.append(jax.lax.psum(p, SHARD_AXIS))
+                else:
+                    # TPU lowers only Sum all-reduces; min/max combine as
+                    # an all_gather over ICI followed by a local reduction
+                    g = jax.lax.all_gather(p, SHARD_AXIS)
+                    outs.append(jnp.min(g, axis=0) if kind == "min"
+                                else jnp.max(g, axis=0))
+            return fold_partials(jnp, combine_kinds, acc, outs)
 
     def run(acc, cols, valids, row_mask):
         in_specs = (

@@ -26,6 +26,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from citus_tpu.executor.kernel_cache import jit_compile
+from citus_tpu.observability.trace import kernel_scope
 from citus_tpu.parallel.mesh import SHARD_AXIS
 
 
@@ -78,15 +79,17 @@ def exchange_rows(values: tuple, target, mask, n_dev: int, capacity: int,
     device received, rows no round has taken yet).  One ``all_to_all`` a
     lane; nothing is dropped: what a block cannot hold waits for the
     next round."""
-    packed, pvalid, overflow = _pack_blocks(values, target, mask, n_dev,
-                                            capacity, rnd)
-    swap = lambda v: jax.lax.all_to_all(
-        v, SHARD_AXIS, split_axis=0, concat_axis=0).reshape(-1)
-    received = tuple(swap(v) for v in packed)
-    rvalid = swap(pvalid)
-    counts = jnp.stack([pvalid.sum(dtype=np.int32),
-                        rvalid.sum(dtype=np.int32),
-                        overflow.astype(np.int32)])
+    with kernel_scope(jnp, "exchange.pack"):
+        packed, pvalid, overflow = _pack_blocks(values, target, mask, n_dev,
+                                                capacity, rnd)
+    with kernel_scope(jnp, "exchange.all_to_all"):
+        swap = lambda v: jax.lax.all_to_all(
+            v, SHARD_AXIS, split_axis=0, concat_axis=0).reshape(-1)
+        received = tuple(swap(v) for v in packed)
+        rvalid = swap(pvalid)
+        counts = jnp.stack([pvalid.sum(dtype=np.int32),
+                            rvalid.sum(dtype=np.int32),
+                            overflow.astype(np.int32)])
     return received, rvalid, counts
 
 
