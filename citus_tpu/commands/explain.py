@@ -337,6 +337,12 @@ def _run_analyze(cl, stmt: A.Explain) -> list[str]:
             if "hash_slots_from" in pl:
                 # what bounded the derived table (executor.py _hash_slots)
                 line += f", slots from {pl['hash_slots_from']}"
+            if pl.get("hash_offer_slots"):
+                # the kernel's offer loop runs whole chunks of entry
+                # slots: the share of them that carried an entry
+                live = pl.get("hash_table_updates", 0) / pl["hash_offer_slots"]
+                line += (f", offer slots {pl['hash_offer_slots']} "
+                         f"({100 * live:.0f}% live)")
             if pl.get("hash_tables", 1) > 1:
                 # one table a device, each fed its own shards: apart
                 # where the keys hold the distribution column, else

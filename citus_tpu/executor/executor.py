@@ -546,11 +546,16 @@ class _SpillDrain:
     ``[n]``; a megabatched group's ``[qp, n]``, rider by rider (one
     accumulator each); the per-device tables' ``[n_dev, n]`` (``devices``
     of them), every device's into the ONE accumulator.  ``rows`` counts
-    the rows of the spilled entries, ``updates`` the entries offered."""
+    the rows of the spilled entries, ``updates`` the entries offered and
+    ``slots`` the entry slots the kernel's offer loop ran over for them
+    (whole chunks a batch and table: ``updates`` / ``slots`` is the
+    share of the loop's gathers and scatters that carried an entry)."""
 
-    def __init__(self, plan: PhysicalPlan, accs: list, devices: int = 0):
+    def __init__(self, plan: PhysicalPlan, accs: list, table_slots: int,
+                 devices: int = 0):
         self.plan, self.accs, self.devices = plan, accs, devices
-        self.rows = self.updates = 0
+        self.table_slots = table_slots
+        self.rows = self.updates = self.slots = 0
 
     def __call__(self, pending: list) -> None:
         # one span per drained window: the wait for the window's
@@ -558,15 +563,18 @@ class _SpillDrain:
         if not pending:
             return
         import jax
-        from citus_tpu.ops.hash_agg import merge_hash_tables_into
+        from citus_tpu.ops.hash_agg import merge_hash_tables_into, offer_slots
         q = self.devices or len(self.accs)
         with _trace.span("spill_drain") as dsp:
-            n_rows = n_updates = 0
+            n_rows = n_updates = n_slots = 0
             spilling = set()
             for _, (offered, n_spilled, lost, *entries) in pending:
                 offered = np.asarray(offered)
                 serial = offered.ndim == 0
-                n_updates += int(np.atleast_1d(offered)[:q].sum())
+                offered = np.atleast_1d(offered)[:q].astype(np.int64)
+                n_updates += int(offered.sum())
+                n_slots += int(offer_slots(offered, self.table_slots,
+                                           lost.shape[-1]).sum())
                 if not np.atleast_1d(np.asarray(n_spilled))[:q].any():
                     continue
                 lost = np.atleast_2d(np.asarray(lost))
@@ -602,8 +610,10 @@ class _SpillDrain:
                         keys, parts, rows, entry_mask=real)
             GLOBAL_COUNTERS.bump("hash_spill_rows", n_rows)
             GLOBAL_COUNTERS.bump("hash_table_updates", n_updates)
+            GLOBAL_COUNTERS.bump("hash_offer_slots", n_slots)
             self.rows += n_rows
             self.updates += n_updates
+            self.slots += n_slots
             if dsp.recording:
                 dsp.set(batches=len(pending), rows=n_rows,
                         devices=len(spilling) if self.devices
@@ -714,10 +724,10 @@ def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
             lambda: build_fused_hash_worker(plan, jnp, key_dtypes),
             donate_argnums=0), "jit_hash_fused", "hash_fused_dispatches")
         placement.bind(params)
-        drain = _SpillDrain(plan, [acc], devices=n_dev)
         with _trace.span("hash_init") as sp:
             S, slots_from = _hash_slots(cat, plan, settings, key_dtypes,
                                         placement=placement)
+            drain = _SpillDrain(plan, [acc], S, devices=n_dev)
             if mesh is None:
                 state = jax.device_put(empty_hash_state(plan, S, key_dtypes))
             else:
@@ -798,6 +808,7 @@ def _run_hash_device(cat: Catalog, plan: PhysicalPlan, settings: Settings,
     # the drain bumped its counters window by window
     record.figures["hash_spilled_rows"] = drain.rows
     record.figures["hash_table_updates"] = drain.updates
+    record.figures["hash_offer_slots"] = drain.slots
     # table rows the scan took, and the fullest device's share of them
     per_device = placement.device_rows if mesh is not None \
         else [sum(n for _, n, _ in record.task_times)]

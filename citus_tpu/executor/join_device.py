@@ -492,7 +492,6 @@ class _DeviceJoin:
     def _zero(self, alias: str):
         import jax
         import jax.numpy as jnp
-        from citus_tpu.ops.hash_agg import ENTRY_CHUNK
         node = self.nodes[alias]
         S = self.slots.setdefault(alias, self._slots_of(alias))
         n = self.n_dev if self.mesh is not None else 0
@@ -510,7 +509,7 @@ class _DeviceJoin:
             extra=(n,))
         # a direct table's lanes: every row of the build, and a chunk
         self.lane_rows[alias] = _pow2_at_least(self._rows_of(alias), 1024)
-        return zero(S, self.lane_rows[alias] + ENTRY_CHUNK,
+        return zero(S, self.lane_rows[alias] + J.BUILD_CHUNK,
                     np.int64(self.spans.get(alias, (0, 0))[0]))
 
     def _exchange_kernel(self):
@@ -850,10 +849,10 @@ class _DeviceJoin:
             lambda: build_fused_hash_worker(self.agg, jnp, key_dtypes),
             donate_argnums=0)
         acc = HostGroupAccumulator(len(self.keys.lanes), bj.partial_ops)
-        self.drain = _SpillDrain(self.agg, [acc],
-                                 devices=self.n_dev if self.mesh else 0)
         with _trace.span("hash_init") as sp:
             S, slots_from = self._agg_slots(key_dtypes)
+            self.drain = _SpillDrain(self.agg, [acc], S,
+                                     devices=self.n_dev if self.mesh else 0)
             agg = self.agg      # the cached kernel must not hold ``self``
             n_tables = self.n_dev if self.mesh is not None else 0
             zero = get_kernel(

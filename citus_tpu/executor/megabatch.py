@@ -433,7 +433,7 @@ def _batched_hash_agg(cat, plan, settings, group: list[_Waiter],
         lambda a: np.stack([a] * qp), empty_hash_state(plan, S, key_dtypes)))
     state = drive(plan, settings, placement, step, state, record,
                   stream=_iter_padded_batches(cat, plan, settings, record),
-                  on_sync=_SpillDrain(plan, accs))
+                  on_sync=_SpillDrain(plan, accs, S))
     host = jax.device_get(state)
     return [("hash_agg",
              (jax.tree_util.tree_map(lambda a: np.asarray(a)[qi], host),

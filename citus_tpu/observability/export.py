@@ -124,6 +124,7 @@ METRIC_HELP = {
     "group_rows_kept": "rows of grouped scans that passed the WHERE",
     "hash_groups_out": "groups of hash aggregations, before HAVING",
     "hash_table_updates": "entries (a batch's distinct keys) offered to device hash tables",
+    "hash_offer_slots": "entry slots the hash kernels' offer loops ran over for them (whole chunks a batch)",
     "hash_table_bytes_fetched": "bytes of device hash tables fetched",
     "hash_entries_fetched": "entries (slots) of device hash tables fetched",
     "group_keys": "group keys of the device joins that ran",

@@ -24,8 +24,10 @@ batch, nothing per padded or repeated row:
    every segment's count/sum/min/max on its last row;
 3. a second sort brings the D segment ends to the front;
 4. CHUNKED MATCH-OR-CLAIM: a loop whose trip count is ceil(D /
-   ``ENTRY_CHUNK``) at run time offers the entries to the table and
-   merges their partial states into their slots.
+   chunk) at run time offers the entries to the table and merges
+   their partial states into their slots; the chunk (``entry_chunk``)
+   is small where the table's lanes live in the chip's fast memory,
+   so that trips x chunk follows D.
 
 Placement is exact, never probabilistic: an entry takes a slot only
 when the slot's stored *key values* equal its keys exactly or the slot
@@ -280,10 +282,38 @@ def _segment_scan(xp, start, lanes):
     return vals
 
 
-#: entries offered to the table per round of the kernel's insert loop:
-#: the static shape of its scatters, so their cost follows the batch's
-#: distinct keys in steps of this many
-ENTRY_CHUNK = 1 << 16
+#: entries offered to the table per trip of the hash module's offer loop:
+#: the static length of a trip's gathers and scatters, which cost the
+#: same for an entry slot live or not, so a batch's D distinct keys cost
+#: ceil(D / chunk) * chunk of them.  A table whose lanes the chip keeps
+#: in fast memory (``FAST_TABLE_SLOTS``, measured on a TPU v5e: PERF.md
+#: section 6, PR 51) pays about the same for an entry slot at any chunk,
+#: so there the chunk is small and trips x chunk follows D: a join's
+#: block of 65,536 rows holding 7,000 groups takes one trip of 8,192,
+#: not one of 65,536.  A larger table pays more for an entry slot the
+#: smaller the chunk -- at 8,192 half as much again with 8,388,608
+#: slots and four times with 16,777,216 (its lanes are worked on in
+#: HBM, or moved whole a trip) -- so it keeps ``WIDE_CHUNK``.  The
+#: join's own build loops keep a chunk of their own (``ops/join.py``
+#: ``BUILD_CHUNK``).
+ENTRY_CHUNK = 1 << 13
+WIDE_CHUNK = 1 << 16
+FAST_TABLE_SLOTS = 1 << 22
+
+
+def entry_chunk(slots: int, n: int) -> int:
+    """Entries a trip of the offer loop offers a table of ``slots``
+    slots out of a batch of ``n`` rows: static, from the shapes."""
+    return min(ENTRY_CHUNK if slots <= FAST_TABLE_SLOTS else WIDE_CHUNK,
+               int(n))
+
+
+def offer_slots(offered, slots: int, n_pad: int):
+    """Entry slots the offer loop ran its gathers and scatters over for
+    a batch of ``offered`` entries (an integer or an array of them; the
+    table ``slots`` long, the spill arrays ``n_pad``): whole chunks."""
+    chunk = entry_chunk(slots, n_pad)
+    return -(-offered // chunk) * chunk
 
 
 def build_fused_hash_worker(plan: PhysicalPlan, xp,
@@ -304,7 +334,7 @@ def build_fused_hash_worker(plan: PhysicalPlan, xp,
     over the batch's D entries.  ``spill`` is ``(D, n_spilled,
     spill_mask, key_entries, partial_entries, row_entries)``: the
     scalars first, then the batch's entries compacted to the front of
-    arrays a whole number of ``ENTRY_CHUNK`` long (the layout
+    arrays a whole number of chunks long (``entry_chunk``; the layout
     ``merge_hash_tables_into`` reads), of which ``spill_mask`` marks the
     ones that lost both probes: the host fetches the scalars, the mask
     only if ``n_spilled`` is not 0, and of the lanes the marked entries
@@ -401,7 +431,7 @@ def build_fused_hash_worker(plan: PhysicalPlan, xp,
                        for op in partial_ops]
 
         # 3. the D segment ends to the front, in order
-        C = min(ENTRY_CHUNK, N)
+        C = entry_chunk(table_state[2].shape[0], N)
         n_pad = -(-N // C) * C
         with kernel_scope(xp, "hash.ends"):
             pos = xp.arange(N, dtype=np.int32)

@@ -51,8 +51,7 @@ from typing import Callable, NamedTuple, Optional
 import numpy as np
 
 from citus_tpu.ops.hash_agg import (
-    ENTRY_CHUNK, _fingerprint, _merge_entries, _mix, _probe_slots,
-    empty_hash_state,
+    _fingerprint, _merge_entries, _mix, _probe_slots, empty_hash_state,
 )
 from citus_tpu.observability.trace import kernel_scope
 from citus_tpu.planner.bound import (
@@ -64,6 +63,13 @@ JOIN_LEVELS = 5
 _LEVEL_SALT = (None, np.uint64(0xD6E8FEB86659FD93),
                np.uint64(0xA0761D6478BD642F), np.uint64(0xE7037ED1A0B428DB),
                np.uint64(0x8EBC6AF09C88C6E3))
+#: rows a build offers its table per trip of its loop (the exchange's
+#: build step too), and the room a direct table's lanes keep past their
+#: rows.  The hash module's chunk is smaller over a small table
+#: (``ops/hash_agg.py`` ``entry_chunk``), where its block holds a
+#: fraction of its rows as groups; a build offers every row it keeps,
+#: its loops' scopes have no measurement yet, so this one stays
+BUILD_CHUNK = 1 << 16
 #: slots a build row of a table: at a load of an eighth or less an entry
 #: finds all ``2 x JOIN_LEVELS`` of its slots taken once in 10^9
 SLOTS_PER_ROW = 8
@@ -387,7 +393,7 @@ def build_join_build(node: JoinNode, param_names: tuple, xp) -> Callable:
     # jit_join_build
     def join_build(table, child_tables, cols, valids, row_mask):
         N = row_mask.shape[0]
-        C = min(ENTRY_CHUNK, N)
+        C = min(BUILD_CHUNK, N)
         with kernel_scope(xp, "build.keys"):
             env = pre.env(cols, valids)
             mask = pre.own_filter(env, row_mask)

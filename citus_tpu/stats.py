@@ -231,6 +231,7 @@ class StatCounters:
         "hash_fused_dispatches",
         "hash_spill_rows",
         "hash_table_updates",
+        "hash_offer_slots",
         "hash_partials_pushed",
         "hash_groups_out",
         "hash_table_bytes_fetched",

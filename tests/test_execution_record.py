@@ -38,7 +38,8 @@ DIRECT = {"group_rows_in", "direct_groups", "direct_groups_out",
           "group_rows_kept", "direct_bytes_fetched", "direct_gid_keys",
           "direct_gid_keys_narrow", "direct_gid_divisions"}
 HASH = {"hash_tables", "hash_slots", "hash_slots_from", "hash_disjoint_on",
-        "hash_spilled_rows", "hash_table_updates", "hash_rows_in",
+        "hash_spilled_rows", "hash_table_updates", "hash_offer_slots",
+        "hash_rows_in",
         "hash_rows_in_max_device", "group_rows_in", "hash_tables_merged",
         "hash_occupancy_pct", "group_rows_kept", "hash_groups_out",
         "hash_table_bytes_fetched", "hash_entries_fetched", "group_top"}
