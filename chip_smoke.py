@@ -178,6 +178,35 @@ where c_custkey = o_custkey and l_orderkey = o_orderkey
 group by c_custkey, c_name, c_acctbal, c_phone, n_name, c_address, c_comment
 order by revenue desc limit 20"""
 
+# TPC-H Q5's shape in small tables of its own: six relations whose join
+# graph has a cycle (customer - orders - lineitem - supplier - customer),
+# the probe relation WITHOUT a filter and looked up in two tables a row
+Q5_ORDERS = 300_000
+Q5_DDL = (
+    ("q5_orders", "o_orderkey", """CREATE TABLE q5_orders (
+    o_orderkey bigint NOT NULL, o_custkey bigint, o_orderdate date)"""),
+    ("q5_lineitem", "l_orderkey", """CREATE TABLE q5_lineitem (
+    l_orderkey bigint NOT NULL, l_suppkey bigint,
+    l_extendedprice decimal(12,2), l_discount decimal(12,2))"""),
+    ("q5_customer", None, """CREATE TABLE q5_customer (
+    c_custkey bigint NOT NULL, c_nationkey integer)"""),
+    ("q5_supplier", None, """CREATE TABLE q5_supplier (
+    s_suppkey bigint NOT NULL, s_nationkey integer)"""),
+    ("q5_nation", None, """CREATE TABLE q5_nation (
+    n_nationkey integer NOT NULL, n_name text, n_regionkey integer)"""),
+    ("q5_region", None, """CREATE TABLE q5_region (
+    r_regionkey integer NOT NULL, r_name text)"""))
+Q5_DATE = datetime.date(1994, 1, 1)
+Q5_REGIONS = ("AFRICA", "AMERICA", "ASIA", "EUROPE", "MIDDLE EAST")
+Q_Q5 = f"""select n_name, sum(l_extendedprice * (1 - l_discount)) as revenue
+from q5_customer, q5_orders, q5_lineitem, q5_supplier, q5_nation, q5_region
+where c_custkey = o_custkey and l_orderkey = o_orderkey
+  and l_suppkey = s_suppkey and c_nationkey = s_nationkey
+  and s_nationkey = n_nationkey and n_regionkey = r_regionkey
+  and r_name = 'ASIA' and o_orderdate >= date '{Q5_DATE}'
+  and o_orderdate < date '{Q5_DATE}' + interval '1' year
+group by n_name order by revenue desc"""
+
 # float64 lanes: the TPU holds them as float32 pairs, so the hash
 # fingerprint and the HLL hash of a float value take a path of their own
 MEASURES_DDL = "CREATE TABLE measures (m_key bigint NOT NULL, x double precision)"
@@ -924,6 +953,103 @@ def leg_join_q10(run, rng, shards, n_orders):
                rows_probed=j["rows_probed"], rows_out=j["rows_out"])
 
 
+def leg_join_q5(run, rng, shards, n_orders):
+    """TPC-H Q5's shape on the device: the join GRAPH planned as a tree
+    rooted at the lineitems (orders a table a shard pair with the
+    customers' nation riding through it; supplier, under it nation and
+    region, a table a query) and ONE cycle filter, ``c_nationkey =
+    s_nationkey``, decided over the probe's blocks; the probe relation
+    has no filter, so every row is looked up, in two tables; the answer
+    is the numpy arm's and a plain numpy join's."""
+    cl = run.cl
+    orders = min(Q5_ORDERS, max(n_orders, 20_000))
+    customers, suppliers = max(orders // 10, 2000), max(orders // 150, 200)
+    for table, column, ddl in Q5_DDL:
+        cl.execute(ddl)
+        cl.execute(f"SELECT create_distributed_table('{table}', '{column}', "
+                   f"{shards})" if column
+                   else f"SELECT create_reference_table('{table}')")
+    n_region = np.arange(25) % 5
+    c_nation = rng.integers(0, 25, customers)
+    s_nation = rng.integers(0, 25, suppliers)
+    cl.copy_from("q5_region", columns={
+        "r_regionkey": np.arange(5, dtype=np.int32),
+        "r_name": list(Q5_REGIONS)})
+    cl.copy_from("q5_nation", columns={
+        "n_nationkey": np.arange(25, dtype=np.int32),
+        "n_name": [f"NATION{n:02d}" for n in range(25)],
+        "n_regionkey": n_region.astype(np.int32)})
+    cl.copy_from("q5_customer", columns={
+        "c_custkey": np.arange(1, customers + 1),
+        "c_nationkey": c_nation.astype(np.int32)})
+    cl.copy_from("q5_supplier", columns={
+        "s_suppkey": np.arange(1, suppliers + 1),
+        "s_nationkey": s_nation.astype(np.int32)})
+    o_key = np.arange(orders, dtype=np.int64) * 4 + 1
+    o_cust = rng.integers(1, customers + 1, orders)
+    o_date = days(Q5_DATE) + rng.integers(-400, 800, orders)
+    cl.copy_from("q5_orders", columns={
+        "o_orderkey": o_key, "o_custkey": o_cust,
+        "o_orderdate": o_date.astype(np.int32)})
+    at = np.repeat(np.arange(orders), rng.integers(1, 8, orders))
+    supp = rng.integers(1, suppliers + 1, at.size)
+    price = rng.integers(100, 10_000_000, at.size)
+    disc = rng.integers(0, 11, at.size)
+    cl.copy_from("q5_lineitem", columns={
+        "l_orderkey": o_key[at], "l_suppkey": supp,
+        "l_extendedprice": price / 100.0, "l_discount": disc / 100.0})
+
+    r, d, el, ev = run.run(Q_Q5)
+    j = r.explain.get("join", {})
+    check(r.explain["strategy"] == "join:colocated" and j.get("on") == "device"
+          and d.get("join_host_fallbacks", 0) == 0, f"Q5: {r.explain}")
+    check(j["tree"] == {
+        "q5_orders": "q5_lineitem", "q5_supplier": "q5_lineitem",
+        "q5_customer": "q5_orders", "q5_nation": "q5_supplier",
+        "q5_region": "q5_nation"}, f"Q5 tree: {j['tree']}")
+    check(len(j["cycle_filters"]) == 1 and j["probe_children"] == 2
+          and d.get("join_cycle_filters") == 1,
+          f"Q5 cycle filters: {j['cycle_filters']}")
+    check(j["rows_looked_up"] == j["rows_probed"],
+          f"Q5: an unfiltered probe looked up {j['rows_looked_up']} of "
+          f"{j['rows_probed']} rows")
+    check({"jit_join_probe", "jit_hash_fused"} <= set(kernel_slots(ev)),
+          f"Q5: slots {kernel_slots(ev)}")
+    # the plain join, in numpy
+    year = (o_date[at] >= days(Q5_DATE)) & (o_date[at] < days(
+        datetime.date(Q5_DATE.year + 1, 1, 1)))
+    of_supp = s_nation[supp - 1]
+    both = year & (n_region[of_supp] == Q5_REGIONS.index("ASIA"))
+    keep = both & (c_nation[o_cust[at] - 1] == of_supp)
+    revenue = np.zeros(25, np.int64)
+    np.add.at(revenue, of_supp[keep], (price * (100 - disc))[keep])
+    nations = np.flatnonzero(np.bincount(of_supp[keep], minlength=25))
+    nations = nations[np.argsort(-revenue[nations])]
+    check(len(set(revenue[nations].tolist())) == nations.size,
+          "Q5: two nations tie on revenue; take another seed")
+    want = [(f"NATION{n:02d}", dec(revenue[n], 4)) for n in nations.tolist()]
+    check(r.rows == want, f"Q5 answer {r.rows[:2]} want {want[:2]}")
+    check((j["cycle_rows_in"], j["cycle_rows_kept"])
+          == (int(both.sum()), int(keep.sum())),
+          f"Q5 cycle filter saw {j['cycle_rows_in']}, kept "
+          f"{j['cycle_rows_kept']}; the plain join {int(both.sum())}, "
+          f"{int(keep.sum())}")
+    cl.execute("SET citus.task_executor_backend = 'cpu'")
+    try:
+        oracle = cl.execute(Q_Q5)
+    finally:
+        cl.execute("SET citus.task_executor_backend = 'tpu'")
+    check(oracle.rows == r.rows, "Q5: the numpy arm answers otherwise")
+    run.record("7g Q5 on the device (a join graph with a cycle: a tree of "
+               "five builds and one cycle filter; an unfiltered probe, two "
+               "lookups a row)", "jit_join_probe", el, d,
+               groups=j["groups"], rows_probed=j["rows_probed"],
+               rows_matched=j["rows_matched"],
+               overflow_rounds=j["overflow_rounds"],
+               cycle_rows_in=j["cycle_rows_in"],
+               cycle_rows_kept=j["cycle_rows_kept"])
+
+
 def leg_join_repartition(run, ref, devices):
     """The single-hash repartition join on the device, on every device
     the machine has (TPC-H Q12's shape: ``orders`` is distributed on the
@@ -1152,6 +1278,7 @@ def main() -> int:
         leg_router(run, ref)
         leg_join_colocated(run, ref)
         leg_join_q10(run, rng, shards, n_orders)
+        leg_join_q5(run, rng, shards, n_orders)
         leg_join_repartition(run, ref, devices)
 
         memory = []

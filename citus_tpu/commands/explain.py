@@ -439,6 +439,14 @@ def _explain_join(cl, stmt: A.Explain) -> Result:
                 + (f"top {top['rows']} on device: {top['entries']} entries "
                    f"fetched" if isinstance(top, dict)
                    else f"top not cut on device: {top}"))
+            if j.get("cycle_filters"):
+                edges = ", ".join(f"{a} under {b}"
+                                  for a, b in j["tree"].items())
+                lines[-1] += (
+                    f"; tree: {edges}; cycle filters: "
+                    f"{' and '.join(j['cycle_filters'])} "
+                    f"({j['cycle_rows_kept']} of {j['cycle_rows_in']} rows "
+                    f"kept)")
             x = j.get("exchange")
             if x:
                 lines[-1] += (

@@ -1,11 +1,16 @@
 """The device path of a many-to-one join (``ops/join.py``): colocated,
 or a single-hash repartition.
 
-``run_device_join`` answers a ``join:colocated`` statement whose steps
-``planner/join_planner.py`` ``plan_device_join`` takes -- and a
+``run_device_join`` answers a ``join:colocated`` statement whose join
+graph ``planner/join_planner.py`` ``plan_device_join`` takes -- and a
 ``join:repartition`` one whose probe relation is distributed on the
 join key -- or says why the host path (``executor/join_executor.py``,
-the oracle) has to.
+the oracle) has to.  The plan is a tree of builds under the probe
+relation and, of a graph with a cycle (TPC-H Q5), the equalities left
+over: each joins the root's cross-relation conjuncts, so its two
+columns are payload of their builds through every level up to the
+root, and the probe decides it over each round's block
+(``explain["join"]``: ``tree``, ``cycle_filters``).
 
 One stream, one ``scan_loop.drive``, one decode thread a query: the
 batches of every relation in the order their tables are needed -- the
@@ -73,7 +78,8 @@ from citus_tpu.planner.bound import (
     BColumn, BKeyRef, compile_expr, param_env_names, walk,
 )
 from citus_tpu.planner.join_planner import (
-    BoundJoinSelect, DeviceJoinTree, dependent_group_keys, plan_device_join,
+    BoundJoinSelect, DeviceJoinTree, _and_all, _conjuncts,
+    dependent_group_keys, plan_device_join,
 )
 
 #: slots of the aggregate's table: the probe relation's rows over this,
@@ -134,7 +140,7 @@ def run_device_join(cat: Catalog, bj: BoundJoinSelect, settings: Settings,
                     t0: float):
     """-> the statement's Result, or the reason (a string) the host
     path answers it."""
-    from citus_tpu.catalog.stats import shard_row_counts
+    from citus_tpu.catalog.stats import column_bounds, shard_row_counts
     from citus_tpu.executor.executor import _hash_has_exact
     if bj.strategy == "repartition" \
             and not settings.planner.enable_repartition_joins:
@@ -146,11 +152,15 @@ def run_device_join(cat: Catalog, bj: BoundJoinSelect, settings: Settings,
                 shard_rows[alias] = shard_row_counts(cat, t)
             except Exception:
                 shard_rows[alias] = [0] * max(1, t.shard_count)
-    with _trace.span("plan_physical"):
+    tables_of = dict(bj.rels)
+    with _trace.span("plan_physical") as sp:
         tree = plan_device_join(
-            bj, {a: sum(c) for a, c in shard_rows.items()})
+            bj, {a: sum(c) for a, c in shard_rows.items()},
+            bounds=lambda a: column_bounds(cat, tables_of[a]))
         if isinstance(tree, str):
             return tree
+        if sp.recording:
+            sp.set(cycle_filters=len(tree.cycle_filters))
         if _hash_has_exact(bj):
             return "exact value-set partials"
         join = _DeviceJoin(cat, bj, settings, tree, shard_rows)
@@ -187,7 +197,10 @@ class _DeviceJoin:
             return g
 
         filters = {a: hoist(bj.rel_plans[a].filter) for a, _ in bj.rels}
-        post = hoist(bj.post_filter)
+        # the root's cross-relation conjuncts: the statement's own and
+        # the equalities of the join graph's edges off the tree
+        post = hoist(_and_all(_conjuncts(bj.post_filter)
+                              + list(tree.cycle_filters)))
         self.specs = specs
         self.param_names = tuple(param_env_names(specs))
         self.params = (
@@ -331,7 +344,7 @@ class _DeviceJoin:
         self.rows_in: dict = {}
         self.bytes_in: dict = {}
         self.built = {a: 0 for a in tree.builds}
-        self.totals = np.zeros(4, np.int64)
+        self.totals = np.zeros(J.N_PROBE_COUNTS, np.int64)
         self.probed = self.overflow_rounds = self.later_level = 0
         self.looked_up = 0       # groups whose dependants were looked up
         # the exchange: rounds whose counts are not home yet, and what
@@ -914,8 +927,17 @@ class _DeviceJoin:
 
         table_bytes = sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(
             [t[0] for t in self.tables.values()]))
+        cycle = bool(tree.cycle_filters)
         join = {
             "on": "device", "probe": tree.root,
+            "tree": dict(tree.parent),
+            "cycle_filters": [f"{e.left.name} = {e.right.name}"
+                              for e in tree.cycle_filters],
+            # rows the root's cross-relation conjuncts saw and kept,
+            # every round, where a cycle filter is among them
+            "cycle_rows_in": int(self.totals[J.SEEN]) if cycle else 0,
+            "cycle_rows_kept": int(self.totals[J.OUT]) if cycle else 0,
+            "probe_children": len(tree.children(tree.root)),
             "tables": {a: {"table": self.kind[a],
                            "slots": self.slots.get(a, 0),
                            "built_per": "shard" if self.per_shard[a]
@@ -975,6 +997,10 @@ class _DeviceJoin:
         GLOBAL_COUNTERS.bump("join_rows_looked_up", join["rows_looked_up"])
         GLOBAL_COUNTERS.bump("join_rows_matched", join["rows_matched"])
         GLOBAL_COUNTERS.bump("join_rows_out", join["rows_out"])
+        GLOBAL_COUNTERS.bump("join_cycle_filters", len(join["cycle_filters"]))
+        GLOBAL_COUNTERS.bump("join_cycle_rows_in", join["cycle_rows_in"])
+        GLOBAL_COUNTERS.bump("join_cycle_rows_kept", join["cycle_rows_kept"])
+        GLOBAL_COUNTERS.bump("join_probe_children", join["probe_children"])
         GLOBAL_COUNTERS.bump("join_overflow_rounds", join["overflow_rounds"])
         GLOBAL_COUNTERS.bump("join_table_bytes", join["table_bytes"])
         GLOBAL_COUNTERS.bump("hash_table_bytes_fetched", fetched)

@@ -9,7 +9,10 @@ edge to its parent, carrying the payload columns the root needs of it
 and of what lies below it.  The root's batches then probe its
 children's tables, and the rows that matched and passed every filter
 are PACKED into a fixed-capacity block -- the only rows the aggregate
-kernel ever sees.
+kernel ever sees.  A join GRAPH with a cycle is that tree and the
+equalities of the edges off it (*cycle filters*): both sides of one
+ride up as payload of their builds, and the root decides it over the
+block with its other cross-relation conjuncts (scope ``probe.filter``).
 
 The table is the aggregation's (``ops/hash_agg.py``): the state layout
 ``(key_tables, lane tables, rows)`` of ``empty_hash_state``, filled
@@ -81,8 +84,10 @@ COUNTS = 5
 #: what a probe round counts: rows packed (candidates for the block,
 #: over all rounds), looked-up rows with a partner in every child's first
 #: pair (a later round counts none again), rows handed on, rows looked up
-#: (those the node's own filter kept; its bucket where it has none)
-PACKED, MATCHED, OUT, LOOKED = range(4)
+#: (those the node's own filter kept; its bucket where it has none), rows
+#: of the block the cross-relation conjuncts saw (``OUT`` of them passed)
+PACKED, MATCHED, OUT, LOOKED, SEEN = range(5)
+N_PROBE_COUNTS = 5
 
 #: what an exchange round counts: rows this device sent, rows it
 #: received, rows of its batch that no round has taken yet
@@ -592,8 +597,8 @@ def build_join_probe(node: JoinNode, param_names: tuple, xp,
     where not given) with the columns ``node.out`` names -- the node's
     own and the payload gathered from the tables -- and the
     cross-relation conjuncts decided.  ``counts`` (``PACKED`` /
-    ``MATCHED`` / ``OUT`` / ``LOOKED``) say whether another round is
-    due: ``PACKED`` > (r + 1) x ``block_rows``.
+    ``MATCHED`` / ``OUT`` / ``LOOKED`` / ``SEEN``) say whether another
+    round is due: ``PACKED`` > (r + 1) x ``block_rows``.
 
     One path, whose cost follows the K rows the node's own filter
     keeps, which the kernel sees in its batch: (1) the filter and every
@@ -607,7 +612,10 @@ def build_join_probe(node: JoinNode, param_names: tuple, xp,
     (where K fits the block its first rows ARE the block: no second
     sort), the later pairs, the payload and the block's own columns
     are gathered for the block's rows alone, by slot and by original
-    position.  A node without a filter keeps its whole bucket: the
+    position, and the cross-relation conjuncts (the statement's and a
+    join graph's cycle filters: ``SEEN`` rows of the block reach them,
+    ``OUT`` pass) are decided over the block, scope ``probe.filter``.
+    A node without a filter keeps its whole bucket: the
     batch is its own packing and nothing is sorted.  ``LOOKED`` counts
     K a round (the bucket where nothing is filtered; the gathers issued
     are K to the chunk), ``MATCHED`` the looked-up rows with a partner
@@ -681,10 +689,14 @@ def build_join_probe(node: JoinNode, param_names: tuple, xp,
             block = {n: (take(v), take(m))
                      for n, (v, m) in env.items() if n not in params}
             block = pre.child_payloads(block, slots, child_tables)
-            if post_fn is not None:
+            seen = live.sum(dtype=np.int32)
+        if post_fn is not None:
+            # the cross-relation conjuncts, a cycle filter among them
+            with kernel_scope(xp, "probe.filter"):
                 penv = dict(block)
                 penv.update({n: env[n] for n in params})
                 live = live & predicate_mask(xp, post_fn, penv, live)
+        with kernel_scope(xp, "probe.payload"):
             out_cols, out_valids = [], []
             for name in node.out:
                 v, m = block[name]
@@ -692,6 +704,6 @@ def build_join_probe(node: JoinNode, param_names: tuple, xp,
                 out_cols.append(v)
                 out_valids.append(xp.broadcast_to(_as_mask(xp, m, v), (C,)))
             counts = xp.stack([D, xp.where(rnd == 0, matched, 0),
-                               live.sum(dtype=np.int32), K])
+                               live.sum(dtype=np.int32), K, seen])
         return tuple(out_cols), tuple(out_valids), live, counts
     return join_probe

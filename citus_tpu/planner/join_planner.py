@@ -494,6 +494,10 @@ class DeviceJoinTree:
     # key the root is distributed on); None = every relation meets its
     # partners where it lies (colocated, replicated)
     exchanged: Optional[tuple] = None
+    # the equalities of the edges off the tree (a join graph with a
+    # cycle): conjuncts the root decides with its ``post_filter``, each
+    # side a plain column its relation hands up
+    cycle_filters: list = field(default_factory=list)
 
     def children(self, alias: str) -> list:
         return [a for a in self.builds if self.parent[a] == alias]
@@ -638,18 +642,72 @@ def _exchange_of(bj: BoundJoinSelect, rel_rows: dict):
     return root, exchanged, lane
 
 
-def plan_device_join(bj: BoundJoinSelect, rel_rows: dict):
+def _repeats(keys: list, rows: int, known: Optional[dict]) -> bool:
+    """The catalog's count DISPROVES that ``keys`` (a build's side of an
+    edge) are unique over their table's ``rows`` rows: every lane a
+    plain integer or date column without NULLs that ``known`` (the
+    table's ``column_bounds``) bounds, and fewer distinct keys than rows
+    can be (the product of the lanes' spans ``max - min + 1``) --
+    ``s_nationkey``, span 25 over 100,000 suppliers, repeats;
+    ``s_suppkey``, span 100,000, may not."""
+    if not known or not rows:
+        return False
+    distinct = 1
+    for k in keys:
+        if not isinstance(k, BColumn) \
+                or not (k.type.is_integer or k.type.kind == T.DATE):
+            return False
+        lo, hi, has_nulls = known.get(k.name.split(".", 1)[-1]) \
+            or (None, None, True)
+        if lo is None or hi is None or has_nulls:
+            return False
+        distinct *= int(hi) - int(lo) + 1
+    return distinct < rows
+
+
+def plan_device_join(bj: BoundJoinSelect, rel_rows: dict, bounds=None):
     """-> the ``DeviceJoinTree`` of a join the device can run, or the
-    reason (a string) it goes to the host path: every step an inner
-    equi-join without a residual whose keys tie its relation to ONE
-    earlier relation, key lanes the device holds as integers, an
-    aggregate above.  The root is the distributed relation with the
-    most rows (``rel_rows``, from the catalog; the later in FROM on a
-    tie) -- the many side of a many-to-one join, which the build then
-    checks row by row; without a distributed relation, the largest.  Of
+    reason (a string) it goes to the host path.  The device runs an
+    inner equi-join with an aggregate above; this plans its GRAPH:
+
+    *Edges.*  Every ``left_keys[i] = right_keys[i]`` of every step
+    (inner, no residual) is an edge between the two relations its sides
+    name; the lanes between one pair of relations are ONE composite
+    edge.  A comma step that gained no key of its own is no edge at all.
+
+    *Root.*  The distributed relation with the most rows (``rel_rows``,
+    from the catalog; the later in FROM on a tie) -- the many side of a
+    many-to-one join; without a distributed relation, the largest.  Of
     a ``repartition`` strategy the single-hash kind (``_exchange_of``):
     the root is the relation distributed on the key, and the other
-    distributed relation a build whose edge is marked exchanged."""
+    distributed relation a build whose edge is marked exchanged.
+
+    *Tree.*  A spanning tree grown from the root in which every build
+    CAN be unique on its edge key, its own side of the edge to its
+    parent.  What the catalog holds decides: a unique index on the key
+    proves it; the footers' bounds disprove it by counting
+    (``_repeats``; ``bounds``: alias -> ``catalog/stats.py``
+    ``column_bounds`` of its table, None = nothing is disproved; nor is
+    anything in a graph WITHOUT a cycle, which has one spanning tree
+    and is planned as it stands); a key lane the device does not hold
+    as an integer hangs no build.  Of the edges that may hang a further
+    relation on the tree, first a proved one, then the one whose parent
+    has the more rows (the rule the root is chosen by), then by the
+    relations' names: the order of FROM and of the steps decides
+    NOTHING here -- ``from supplier, customer, ...`` plans what ``from
+    customer, ..., supplier`` does.  The build's own verdict
+    (``REPEATED``) stays the last word, on the device.
+
+    *Cycle filters.*  Every lane of an edge off the tree, both sides
+    plain columns of device key types, is an equality the root decides
+    among its cross-relation conjuncts (``DeviceJoinTree.
+    cycle_filters``): its two columns ride up as payload of their
+    builds, and NULL never equals (``predicate_mask``).
+
+    The host path answers, each for its own reason: a step that is not
+    an inner equi-join, a cycle filter over float / text lanes, a graph
+    with no spanning tree of unique builds (an expansion join), a
+    disconnected graph, no aggregate above."""
     qualified = bj.binder.qualified
     exchange = None
     if bj.strategy == "repartition":
@@ -662,38 +720,94 @@ def plan_device_join(bj: BoundJoinSelect, rel_rows: dict):
         return "no aggregate above the join"
     if not bj.steps:
         return "no join step"
-    links: dict = {}
+    order = [a for a, _ in bj.rels]
+    tables = dict(bj.rels)
+    # the graph: {(earlier, later in FROM): [(earlier's lane, later's)]}
+    edges: dict = {}
     for s in bj.steps:
-        if s.kind != "inner" or not s.left_keys:
-            return f"{s.kind} step"
-        if s.residual is not None:
-            return "residual ON condition"
         others = {_rel_of(lk, qualified) for lk in s.left_keys}
         mine = {_rel_of(rk, qualified) for rk in s.right_keys}
-        if mine != {s.right_alias} or len(others) != 1 or None in others:
-            return "a step's keys name more than two relations"
-        if not all(_device_key_type(k.type)
-                   for k in s.left_keys + s.right_keys):
-            return "a key lane the device does not hold as an integer"
-        links[s.right_alias] = (others.pop(), list(s.right_keys),
-                                list(s.left_keys))
-    order = [a for a, _ in bj.rels]
+        if s.kind not in ("inner", "cross"):
+            return "an edge off the tree under an outer step" \
+                if len(others) > 1 else f"{s.kind} step"
+        if s.residual is not None:
+            return "residual ON condition"
+        if s.kind == "cross" and not s.left_keys:
+            continue        # a relation the later steps' keys tie in
+        if mine != {s.right_alias} or None in others:
+            return "a step's key names no one relation"
+        for lk, rk in zip(s.left_keys, s.right_keys):
+            edges.setdefault((_rel_of(lk, qualified), s.right_alias),
+                             []).append((lk, rk))
     dist = [a for a, t in bj.rels if t.is_distributed]
     root = exchange[0] if exchange else max(
         dist or order, key=lambda a: (rel_rows.get(a, 0), order.index(a)))
-    # the steps' tree (each relation hangs on an earlier one), re-rooted
-    near: dict = {a: [] for a in order}
-    for a, (b, mine, theirs) in links.items():
-        near[a].append((b, mine, theirs))
-        near[b].append((a, theirs, mine))
-    parent, edge, walk_order = {}, {}, [root]
+
+    # each edge from either end: (parent, child, parent's lanes, child's)
+    hangs = []
+    for (a, b), lanes in edges.items():
+        theirs, mine = [l for l, _ in lanes], [r for _, r in lanes]
+        hangs += [(a, b, theirs, mine), (b, a, mine, theirs)]
+
+    # a graph that is a tree has ONE spanning tree and nothing to
+    # choose: it is planned as it stands and its builds' verdicts decide
+    choice = len(edges) >= len(order)
+
+    def verdict(child: str, keys: list) -> str:
+        if not all(_device_key_type(k.type) for k in keys):
+            return "type"
+        if len(keys) == 1 and isinstance(keys[0], BColumn) and any(
+                ix["column"] == keys[0].name.split(".", 1)[-1]
+                for ix in tables[child].unique_indexes):
+            return "proved"
+        if choice and bounds is not None and _repeats(
+                keys, rel_rows.get(child, 0), bounds(child)):
+            return "repeats"
+        return "may"
+
+    verdicts = {(a, b): verdict(b, mine) for a, b, _, mine in hangs
+                if b != root}
+    parent, edge = {}, {}
+    while True:
+        frontier = [h for h in hangs
+                    if (h[0] == root or h[0] in parent)
+                    and h[1] != root and h[1] not in parent
+                    and verdicts[h[0], h[1]] in ("proved", "may")]
+        if not frontier:
+            break
+        a, b, theirs, mine = min(frontier, key=lambda h: (
+            verdicts[h[0], h[1]] != "proved", -rel_rows.get(h[0], 0),
+            h[0], h[1]))
+        parent[b], edge[b] = a, (mine, theirs)
+    if len(parent) < len(order) - 1:
+        # why the tree stops short: the edges that reach a relation
+        # outside it, from inside
+        short = {verdicts[a, b] for a, b, _, _ in hangs
+                 if (a == root or a in parent) and b != root
+                 and b not in parent}
+        if "type" in short:
+            return "a key lane the device does not hold as an integer"
+        if "repeats" in short:
+            return "no spanning tree of unique builds (an expansion join)"
+        return "a disconnected join graph"
+    # the walk the streams follow, as the steps' own tree gave it: from
+    # the root, a relation's children in the order their steps stand
+    at = order.index
+    walk_order = [root]
     for a in walk_order:
-        for b, mine, theirs in near[a]:
-            if b != root and b not in parent:
-                parent[b] = a
-                edge[b] = (theirs, mine)
-                walk_order.append(b)
+        walk_order += sorted(
+            (b for b in parent if parent[b] == a),
+            key=lambda b: (max(at(a), at(b)), at(b)))
     tree = DeviceJoinTree(root, parent, edge, walk_order[:0:-1])
+    for (a, b), lanes in edges.items():
+        if parent.get(a) == b or parent.get(b) == a:
+            continue
+        for l, r in lanes:
+            if not (isinstance(l, BColumn) and isinstance(r, BColumn)
+                    and _device_key_type(l.type)
+                    and _device_key_type(r.type)):
+                return "a cycle filter over float / text lanes"
+            tree.cycle_filters.append(BBinOp("=", l, r, T.BOOL_T))
     if exchange:
         _, exchanged, lane = exchange
         if parent.get(exchanged) != root:

@@ -54,6 +54,14 @@ class StatCounters:
         "join_rows_matched",
         "join_rows_out",
         "join_overflow_rounds",
+        # a join graph with a cycle (planner/join_planner.py
+        # plan_device_join): the equalities planned as filters of the
+        # root, the block rows those saw and kept (every round), and the
+        # lookups a probed row takes (the root's children)
+        "join_cycle_filters",
+        "join_cycle_rows_in",
+        "join_cycle_rows_kept",
+        "join_probe_children",
         "join_table_bytes",
         "join_host_fallbacks",
         "tasks_dispatched",

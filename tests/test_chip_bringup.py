@@ -181,7 +181,7 @@ def test_smoke_rehearsal_passes_every_leg(tmp_path, n_dev):
     assert report["rehearsal"] is True and report["platform"] == "cpu"
     legs = {leg["leg"].split()[0]: leg for leg in report["legs"]}
     single = {"1", "2", "3", "3b", "4", "5", "5b", "5c", "5d", "5e", "5f",
-              "6", "7a", "7c", "7f"}
+              "6", "7a", "7c", "7f", "7g"}
     assert set(legs) == (single | {"7b", "7d", "7e"} if n_dev > 1
                          else single)
     assert all(leg["ok"] for leg in legs.values())
@@ -219,6 +219,11 @@ def test_smoke_rehearsal_passes_every_leg(tmp_path, n_dev):
     # the colocated join runs on the device whatever the device count
     assert legs["7c"]["kernel_slot"] == "jit_join_probe"
     assert legs["7c"]["rows_probed"] >= 12_000 > legs["7c"]["rows_out"] > 0
+    # Q5's shape: a join graph with a cycle, its filter decided on the
+    # device over the rows with a partner in both children
+    assert legs["7g"]["kernel_slot"] == "jit_join_probe"
+    assert legs["7g"]["rows_matched"] == legs["7g"]["cycle_rows_in"] \
+        > legs["7g"]["cycle_rows_kept"] > 0
 
 
 # ------------------------------------- what the chip refused or overflowed
