@@ -23,9 +23,13 @@ survivors packed into a block) and the aggregate's update over that
 block (``jit_hash_fused``, the device hash table every GROUP BY of
 unbounded cardinality uses).  A block that overflows takes further
 rounds (``join_overflow_round``), found where the loop waits for the
-device anyway.  The statement's literals reach the kernels as
-parameters (``auto_param.hoist_literals``): a new SEGMENT or DATE
-compiles nothing.  One fetch brings home the aggregate's table (at
+device anyway: round 0 leaves its lookups' slots and its packing on the
+device (the *carry*, held with the batch's inputs until that wait), and
+a further round is a second, small variant of ``jit_join_probe`` that
+cuts its block from them and looks nothing up.  The statement's
+literals reach the kernels as parameters
+(``auto_param.hoist_literals``): a new SEGMENT or DATE compiles
+nothing.  One fetch brings home the aggregate's table (at
 most ``AGG_SLOTS[1]`` entries; the groups it cannot hold spill to the
 host accumulator, exactly).
 
@@ -557,12 +561,12 @@ class _DeviceJoin:
         children = tuple(self.tables[c.alias] for c in node.children)
         if alias == self.tree.root:
             probe = self._probe_kernel()
-            bcols, bvalids, bmask, counts = probe(
-                children, cols, valids, row_mask, np.int32(0))
+            bcols, bvalids, bmask, counts, carry = probe(
+                children, cols, valids, row_mask)
             spill = self._aggregate(bcols, bvalids, bmask)
             self.probed += int(row_mask.size)
             return (counts,), (counts, children, (cols, valids, row_mask),
-                               spill)
+                               spill, carry)
         if first:
             self.tables[alias] = self._zero(alias)
             self.rows_in[alias] = self.bytes_in[alias] = 0
@@ -658,14 +662,17 @@ class _DeviceJoin:
                         np.int32(rnd))
                     c = jax.device_get(c)
 
-    def _probe_kernel(self):
+    def _probe_kernel(self, further: bool = False):
+        """Round 0 of a batch or, with ``further``, the kernel of its
+        later rounds: a block cut from the carry that round 0 left on
+        the device (the round's number goes to every device whole)."""
         import jax.numpy as jnp
         root = self.nodes[self.tree.root]
+        build = J.build_join_probe_round if further else J.build_join_probe
         return self._kernel(
-            "jit_join_probe",
-            lambda: J.build_join_probe(root, self.param_names, jnp,
-                                       self.block_rows),
-            extra=(self.block_rows,), replicated=(4,))
+            "jit_join_probe:round" if further else "jit_join_probe",
+            lambda: build(root, self.param_names, jnp, self.block_rows),
+            extra=(self.block_rows,), replicated=(5,) if further else ())
 
     def _aggregate(self, bcols, bvalids, bmask):
         pcols, pvalids = self.placement.pcols, self.placement.pvalids
@@ -704,24 +711,23 @@ class _DeviceJoin:
         rounds = [aux for _, aux in pending if aux is not None]
         if not rounds:
             return
-        probe = self._probe_kernel()
         spills = []
         with _trace.span("join_counts", rounds=len(rounds)):
             counts = jax.device_get([r[0] for r in rounds])
-        for (_, children, inputs, spill), c in zip(rounds, counts):
+        for (_, children, inputs, spill, carry), c in zip(rounds, counts):
             c = np.asarray(c, np.int64).reshape(self.n_dev, -1)
             spills.append((None, spill))
             C = J.block_capacity(int(inputs[2].shape[-1]), self.block_rows)
             for r in range(1, -(-int(c[:, J.PACKED].max()) // C)):
                 with _trace.span("join_overflow_round", round=r):
-                    bcols, bvalids, bmask, cr = probe(
-                        children, *inputs, np.int32(r))
+                    further = self._probe_kernel(further=True)
+                    bcols, bvalids, bmask, cr = further(
+                        children, *inputs, carry, np.int32(r))
                     spills.append(
                         (None, self._aggregate(bcols, bvalids, bmask)))
                     c[:, 1:] += np.asarray(cr, np.int64).reshape(
                         self.n_dev, -1)[:, 1:]
                 self.overflow_rounds += 1
-                self.probed += int(inputs[2].size)
             self.totals += c.sum(axis=0)
         self.drain(spills)
 

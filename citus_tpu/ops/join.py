@@ -43,7 +43,12 @@ packed WITH what their lookup reads (``_Prefix.probe_lanes``, carried
 as further operands of the packing sort: 5.6 ms for a batch of 4 M
 rows where the gather is 99), and a loop looks the first K packed rows
 up, a ``lookup_chunk`` a trip.  No second order, no setting: K is what
-the kernel sees in its batch.
+the kernel sees in its batch.  And a batch is looked up ONCE, whatever
+the rounds its block takes: round 0 returns, beside its block, what it
+worked out -- each child's slots, the packing of the rows that came
+through (the *carry*, ``_Block``) -- and a further round is a second,
+small kernel (``build_join_probe_round``) that cuts its block from the
+carry: no lookup loop, no sort, a block's rows of gathers.
 """
 
 from __future__ import annotations
@@ -83,9 +88,11 @@ COUNTS = 5
 
 #: what a probe round counts: rows packed (candidates for the block,
 #: over all rounds), looked-up rows with a partner in every child's first
-#: pair (a later round counts none again), rows handed on, rows looked up
-#: (those the node's own filter kept; its bucket where it has none), rows
-#: of the block the cross-relation conjuncts saw (``OUT`` of them passed)
+#: pair, rows handed on, rows looked up (those the node's own filter
+#: kept; its bucket where it has none), rows of the block the
+#: cross-relation conjuncts saw (``OUT`` of them passed).  A batch is
+#: looked up in its round 0 alone: a further round counts 0 ``LOOKED``
+#: and 0 ``MATCHED``
 PACKED, MATCHED, OUT, LOOKED, SEEN = range(5)
 N_PROBE_COUNTS = 5
 
@@ -338,18 +345,23 @@ class _Prefix:
         """For the packed rows ``at``: the slot of each child's partner
         (looked for in the later pairs where the first held none) and
         the rows that have one in every child."""
+        return self.partners(
+            live, [(slot[at], [(kv[at], kvm[at]) for kv, kvm in keys])
+                   for slot, keys in probes], child_tables)
+
+    def partners(self, live, found, child_tables):
+        """``later_pairs`` of rows that bring, per child, their own
+        ``(first pair's slot, keys)``."""
         from jax import lax
         xp = self.xp
         slots = []
         for ch, (slot, keys), (state, _) in zip(
-                self.node.children, probes, child_tables):
+                self.node.children, found, child_tables):
             S = _span(ch.kind, state)
-            slot = slot[at]
             if ch.kind == "direct":
                 live = live & (slot < S)
                 slots.append(slot)
                 continue
-            keys = [(kv[at], kvm[at]) for kv, kvm in keys]
             h = _fingerprint(xp, keys, live.shape)
 
             def look(level, slot, keys=keys, h=h, state=state, S=S):
@@ -587,18 +599,93 @@ def lookup_chunk(n: int) -> int:
     return n
 
 
+class _Block:
+    """What the probe's two kernels share: how a round's block is cut
+    from what round 0 of its batch worked out -- the *carry*
+    ``(slots, again, order, D)``: per child the slot each packed row's
+    lookup found (``[N]`` int32), the packed places of the rows that
+    came through, marked (``_marked``: a place past ``N`` is no row;
+    padded to whole blocks), the batch positions of the packed rows
+    (marked too; ``()`` for a node without a filter, whose batch is its
+    own packing) and the count of the rows that came through."""
+
+    def __init__(self, node: JoinNode, param_names: tuple, xp,
+                 block_rows: Optional[int]):
+        self.node, self.xp, self.block_rows = node, xp, block_rows
+        self.pre = _Prefix(node, param_names, xp)
+        self.post_fn = compile_expr(node.post_filter, xp) \
+            if node.post_filter is not None else None
+        self.params = tuple(param_names)
+        #: the kept rows are packed by a sort (else the batch stands)
+        self.sorts = self.pre.filter_fn is not None
+
+    def cut(self, child_tables, env, row_mask, carry, rnd):
+        """Round ``rnd``'s block of a batch -> (block cols, block
+        valids, block mask, counts): ``C`` gathers a column and no
+        more, whatever the batch."""
+        from jax import lax
+        xp, pre, node = self.xp, self.pre, self.node
+        slots, again, order, D = carry
+        N = row_mask.shape[0]
+        C = block_capacity(N, self.block_rows)
+        with kernel_scope(xp, "probe.block"):
+            among = lax.dynamic_slice(again, (rnd * C,), (C,))
+            live = among < N
+            among = xp.where(live, among, 0)
+            # the rows' places in the batch
+            at = order[among] if self.sorts \
+                else xp.where(row_mask[among], among, N)
+            at = xp.where(at >= N, 0, at)
+        with kernel_scope(xp, "probe.payload"):
+            take = lambda a: a[at] if xp.ndim(a) else a
+            block = {n: (take(v), take(m))
+                     for n, (v, m) in env.items() if n not in self.params}
+            penv = dict(block)
+            penv.update({n: env[n] for n in self.params})
+            # a hash child's keys of the block's rows, for its later
+            # pairs: from the rows' own columns, as ``probe_lanes``
+            # makes them over a batch
+            found = [(slot[among],
+                      [] if ch.kind == "direct"
+                      else _key_lanes(xp, fns, penv, (C,))[0])
+                     for ch, fns, slot in zip(
+                         node.children, pre.child_key_fns, slots)]
+            live, slots = pre.partners(live, found, child_tables)
+            block = pre.child_payloads(block, slots, child_tables)
+            seen = live.sum(dtype=np.int32)
+        if self.post_fn is not None:
+            # the cross-relation conjuncts, a cycle filter among them
+            with kernel_scope(xp, "probe.filter"):
+                penv.update(block)
+                live = live & predicate_mask(xp, self.post_fn, penv, live)
+        with kernel_scope(xp, "probe.payload"):
+            out_cols, out_valids = [], []
+            for name in node.out:
+                v, m = block[name]
+                v = xp.broadcast_to(xp.asarray(v), (C,))
+                out_cols.append(v)
+                out_valids.append(xp.broadcast_to(_as_mask(xp, m, v), (C,)))
+            counts = [np.int32(0)] * N_PROBE_COUNTS
+            counts[PACKED], counts[SEEN] = D, seen
+            counts[OUT] = live.sum(dtype=np.int32)
+        return tuple(out_cols), tuple(out_valids), live, counts
+
+
 def build_join_probe(node: JoinNode, param_names: tuple, xp,
                      block_rows: Optional[int] = None) -> Callable:
-    """The probe step of the root ``node``: (child_tables, cols, valids,
-    row_mask, round) -> (block cols, block valids, block mask, counts).
-    The rows that pass the node's filter and have a partner in every
-    child are packed, and round ``r`` hands on the ``r``-th
-    ``block_rows`` of them (a power of two from the batch's bucket
-    where not given) with the columns ``node.out`` names -- the node's
-    own and the payload gathered from the tables -- and the
-    cross-relation conjuncts decided.  ``counts`` (``PACKED`` /
-    ``MATCHED`` / ``OUT`` / ``LOOKED`` / ``SEEN``) say whether another
-    round is due: ``PACKED`` > (r + 1) x ``block_rows``.
+    """The probe step of the root ``node``, round 0 of a batch:
+    (child_tables, cols, valids, row_mask) -> (block cols, block valids,
+    block mask, counts, carry).  The rows that pass the node's filter
+    and have a partner in every child are packed, and round ``r`` hands
+    on the ``r``-th ``block_rows`` of them (a power of two from the
+    batch's bucket where not given) with the columns ``node.out`` names
+    -- the node's own and the payload gathered from the tables -- and
+    the cross-relation conjuncts decided.  This kernel hands on the
+    first block and the *carry* (``_Block``) that every further round's
+    kernel cuts its block from (``build_join_probe_round``).
+    ``counts`` (``PACKED`` / ``MATCHED`` / ``OUT`` / ``LOOKED`` /
+    ``SEEN``) say whether another round is due: ``PACKED`` >
+    (r + 1) x ``block_rows``.
 
     One path, whose cost follows the K rows the node's own filter
     keeps, which the kernel sees in its batch: (1) the filter and every
@@ -608,29 +695,26 @@ def build_join_probe(node: JoinNode, param_names: tuple, xp,
     a gather of its own; (3) a loop of ceil(K / ``lookup_chunk``)
     trips looks the first K packed rows up -- a slice of the lanes,
     ``look_up``'s gathers, a slice of the slots written back; (4) the
-    rows that came through are packed again into the round's block
-    (where K fits the block its first rows ARE the block: no second
-    sort), the later pairs, the payload and the block's own columns
-    are gathered for the block's rows alone, by slot and by original
-    position, and the cross-relation conjuncts (the statement's and a
-    join graph's cycle filters: ``SEEN`` rows of the block reach them,
-    ``OUT`` pass) are decided over the block, scope ``probe.filter``.
-    A node without a filter keeps its whole bucket: the
-    batch is its own packing and nothing is sorted.  ``LOOKED`` counts
-    K a round (the bucket where nothing is filtered; the gathers issued
-    are K to the chunk), ``MATCHED`` the looked-up rows with a partner
-    in every child's first pair (round 0 alone: a later round looks the
-    same rows up again)."""
+    rows that came through are packed again (where K fits the block its
+    first rows ARE the block: no second sort) and the block is cut: the
+    later pairs, the payload and the block's own columns are gathered
+    for the block's rows alone, by slot and by original position, and
+    the cross-relation conjuncts (the statement's and a join graph's
+    cycle filters: ``SEEN`` rows of the block reach them, ``OUT`` pass)
+    are decided over the block, scope ``probe.filter``.  A node without
+    a filter keeps its whole bucket: the batch is its own packing and
+    nothing is sorted.  ``LOOKED`` counts K (the bucket where nothing is
+    filtered; the gathers issued are K to the chunk), ``MATCHED`` the
+    looked-up rows with a partner in every child's first pair: once a
+    batch, whatever the rounds -- a later round looks nothing up."""
     from jax import lax
 
-    pre = _Prefix(node, param_names, xp)
-    post_fn = compile_expr(node.post_filter, xp) \
-        if node.post_filter is not None else None
-    params = tuple(param_names)
+    blk = _Block(node, param_names, xp, block_rows)
+    pre = blk.pre
 
     # named for its kernel slot: the XLA module in a device trace is
     # jit_join_probe
-    def join_probe(child_tables, cols, valids, row_mask, rnd):
+    def join_probe(child_tables, cols, valids, row_mask):
         N = row_mask.shape[0]
         C = block_capacity(N, block_rows)
         CH = lookup_chunk(N)
@@ -639,7 +723,7 @@ def build_join_probe(node: JoinNode, param_names: tuple, xp,
             own = pre.own_filter(env, row_mask)
             lanes = pre.probe_lanes(env, own, child_tables)
         with kernel_scope(xp, "probe.pack"):
-            if pre.filter_fn is not None and node.children:
+            if blk.sorts:
                 order, flat = _pack_with(xp, own,
                                          [a for lane in lanes for a in lane])
                 flat = iter(flat)
@@ -667,43 +751,38 @@ def build_join_probe(node: JoinNode, param_names: tuple, xp,
                 (tuple(xp.zeros((N,), np.int32) for _ in lanes),
                  xp.zeros((N,), bool), np.int32(0)))
 
-        def whole_block(_):
-            # the kept rows fit the block: they are its first rows
-            return xp.arange(C, dtype=np.int32), through[:C] & (rnd == 0)
-
-        def packed_block(_):
-            again, _ = _pack(xp, through, C)
-            return lax.dynamic_slice(again, (rnd * C,), (C,)), lane < D
-
         with kernel_scope(xp, "probe.block"):
-            probes = [(slot, _lane_keys(xp, lane))
-                      for slot, lane in zip(slots, lanes)]
             D = through.sum(dtype=np.int32)
-            lane = rnd * C + xp.arange(C, dtype=np.int32)
-            among, live = lax.cond(K <= C, whole_block, packed_block, None)
-        with kernel_scope(xp, "probe.payload"):
-            live, slots = pre.later_pairs(among, live, probes, child_tables)
-            at = order[among]
-            at = xp.where(at >= N, 0, at)
-            take = lambda a: a[at] if xp.ndim(a) else a
-            block = {n: (take(v), take(m))
-                     for n, (v, m) in env.items() if n not in params}
-            block = pre.child_payloads(block, slots, child_tables)
-            seen = live.sum(dtype=np.int32)
-        if post_fn is not None:
-            # the cross-relation conjuncts, a cycle filter among them
-            with kernel_scope(xp, "probe.filter"):
-                penv = dict(block)
-                penv.update({n: env[n] for n in params})
-                live = live & predicate_mask(xp, post_fn, penv, live)
-        with kernel_scope(xp, "probe.payload"):
-            out_cols, out_valids = [], []
-            for name in node.out:
-                v, m = block[name]
-                v = xp.broadcast_to(xp.asarray(v), (C,))
-                out_cols.append(v)
-                out_valids.append(xp.broadcast_to(_as_mask(xp, m, v), (C,)))
-            counts = xp.stack([D, xp.where(rnd == 0, matched, 0),
-                               live.sum(dtype=np.int32), K, seen])
-        return tuple(out_cols), tuple(out_valids), live, counts
+            # where the kept rows fit the block they are its first rows
+            again = lax.cond(K <= C, lambda _: _marked(xp, through),
+                             lambda _: _pack_with(xp, through)[0], None)
+            if -N % C:
+                again = xp.concatenate(
+                    [again, xp.full((-N % C,), N, np.int32)])
+        carry = (slots, again, order if blk.sorts else (), D)
+        out_cols, out_valids, live, counts = blk.cut(
+            child_tables, env, row_mask, carry, np.int32(0))
+        counts[MATCHED], counts[LOOKED] = matched, K
+        return out_cols, out_valids, live, xp.stack(counts), carry
+    return join_probe
+
+
+def build_join_probe_round(node: JoinNode, param_names: tuple, xp,
+                           block_rows: Optional[int] = None) -> Callable:
+    """A further round of the probe: (child_tables, cols, valids,
+    row_mask, carry, round) -> (block cols, block valids, block mask,
+    counts), round ``r`` >= 1 of the batch whose round 0
+    (``build_join_probe``) returned ``carry``.  It cuts its block from
+    the carry and fills it (``_Block.cut``: the later pairs, the
+    payload and own columns of the block's rows, the cross-relation
+    conjuncts) -- no lookup loop, no sort, no gather of more than a
+    block's rows -- so it counts no ``LOOKED`` and no ``MATCHED``."""
+    blk = _Block(node, param_names, xp, block_rows)
+
+    # named as round 0's kernel is: a second variant of the XLA module
+    # jit_join_probe in a device trace
+    def join_probe(child_tables, cols, valids, row_mask, carry, rnd):
+        out_cols, out_valids, live, counts = blk.cut(
+            child_tables, blk.pre.env(cols, valids), row_mask, carry, rnd)
+        return out_cols, out_valids, live, xp.stack(counts)
     return join_probe

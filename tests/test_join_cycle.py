@@ -406,23 +406,30 @@ def test_a_cycle_of_two_filters(cl, data):
 
 def test_an_unfiltered_probe_takes_overflow_rounds_with_a_cycle_filter(
         cl, data, monkeypatch):
+    sql = q5("ASIA", "1994-01-01")
+    # the batches' rows, padding included: a block that holds its batch
+    whole = cl.execute(sql).explain["join"]
+    padded = whole["rows_probed"]
+    assert whole["overflow_rounds"] == 0 and padded >= len(data.l_orderkey)
     # the capacity is the kernel builder's argument
     monkeypatch.setattr(JD._DeviceJoin, "block_rows", 4)
     before = GLOBAL_COUNTERS.snapshot()
-    dev, host, explain = both_arms(cl, q5("ASIA", "1994-01-01"))
+    dev, host, explain = both_arms(cl, sql)
     after = GLOBAL_COUNTERS.snapshot()
     want = data.q5("ASIA", "1994-01-01")
     assert dev == want and host == want and on_device(explain)
     j = explain["join"]
     seen, kept = data.cycle_counts("ASIA", "1994-01-01")
     # the rows with a partner in both children are many blocks of 4: a
-    # shard's one batch takes three further rounds and more
+    # shard's one batch takes three further rounds and more -- and is
+    # looked up ONCE: a further round cuts its block from what round 0
+    # left on the device
     shards = explain["tasks"]
     assert seen > 4 * 4 * shards and j["overflow_rounds"] >= 3 * shards
-    assert j["rows_probed"] == j["rows_looked_up"] \
-        > (1 + 3) * len(data.l_orderkey)
+    assert j["rows_probed"] == j["rows_looked_up"] == padded
     assert (j["cycle_rows_in"], j["cycle_rows_kept"]) == (seen, kept)
     d = lambda n: after.get(n, 0) - before.get(n, 0)
+    assert d("join_rows_looked_up") == d("join_rows_probed") == padded
     assert d("join_cycle_rows_in") == seen
     assert d("join_cycle_rows_kept") == kept
     assert d("join_cycle_filters") == 1 and d("join_probe_children") == 2

@@ -278,6 +278,37 @@ def test_a_batch_picks_the_filter_first_where_its_rows_fit_a_block(
                                   "where o_orderkey = l_orderkey").rows
 
 
+def test_a_probe_block_that_overflows_on_the_mesh_is_cut_from_the_carry(
+        cl, limit_devices, monkeypatch):
+    """Eight devices, a block of 16 rows a device: every device's
+    further rounds cut their blocks from the carry its round 0 left
+    (a pytree with a leading device axis, like every other result), so
+    the batches are looked up once whatever the rounds, and the answer
+    is the host arm's."""
+    limit_devices(8)
+    sql = ("select l_shipmode, count(*), sum(l_quantity), "
+           "min(o_orderpriority) from orders, lineitem "
+           "where o_orderkey = l_orderkey group by l_shipmode "
+           "order by l_shipmode")
+    whole = cl.execute(sql)        # blocks of 1,024: few rounds or none
+    assert on_device(whole.explain)
+    monkeypatch.setattr(JD._DeviceJoin, "block_rows", 16)
+    c0 = GLOBAL_COUNTERS.snapshot()
+    r = cl.execute(sql)
+    c1 = GLOBAL_COUNTERS.snapshot()
+    assert on_device(r.explain) \
+        and r.explain["shuffle"] == "all_to_all:device"
+    j = r.explain["join"]
+    assert j["overflow_rounds"] >= 3 * 8 \
+        > whole.explain["join"]["overflow_rounds"]
+    assert j["rows_looked_up"] == j["rows_probed"] \
+        == whole.explain["join"]["rows_probed"]
+    assert c1["join_rows_looked_up"] - c0.get("join_rows_looked_up", 0) \
+        == j["rows_probed"]
+    assert j["rows_out"] == whole.explain["join"]["rows_out"] > 16 * 8 * 3
+    assert r.rows == whole.rows == host_arm(cl, sql).rows
+
+
 # ----------------------------------------- (d) what goes to the host path
 
 
